@@ -22,11 +22,9 @@ Two lanes:
 - ``--ungrouped``: the per-parameter row (bench.
   make_eager_ungrouped_step — 183 small allreduces/step at the 809M
   20-layer geometry), where the fusion buffer and cycle time genuinely
-  bind and the tuner has a number to move (VERDICT r5 #4). Unlike the
-  grouped lane this one also runs on a CPU-only box: the knobs govern
-  the CONTROL plane (enqueue batching, negotiation cadence), which the
-  native core runs identically there — the row is labeled with its
-  substrate either way.
+  bind and the tuner has a number to move (VERDICT r5 #4).
+
+Both lanes need a TPU: a step time taken on the CPU is not this metric.
 
 Emits JSON rows and writes ``--out`` (e.g.
 ``benchmarks/results_r06_autotune.json``) with the warmup->converged
@@ -60,9 +58,10 @@ def _eager_loop(cfg, batch, seq, steps, warmup, make_step=None):
     import horovod_tpu.jax as hvd
     from horovod_tpu.jax import xla_ici
 
-    hvd.init()
-    if not xla_ici.active() and jax.devices()[0].platform != "cpu":
-        xla_ici.enable()
+    hvd.init()  # on a TPU, init brings the device plane up or raises
+    if not xla_ici.active():
+        raise RuntimeError("autotune_bench times the xla_ici device "
+                           "plane, which is off")
 
     data = bench._data(cfg, batch, seq)
     try:
@@ -132,29 +131,15 @@ def main():
     args = ap.parse_args()
 
     import bench
-    from horovod_tpu.models import LlamaConfig
 
-    on_cpu = jax.devices()[0].platform == "cpu"
-    if on_cpu and not args.ungrouped:
-        print("the grouped autotune lane needs an accelerator "
-              "(use --ungrouped for the control-plane lane); skipping",
-              file=sys.stderr)
-        return
+    bench.require_tpu("autotune_bench")
+    bench.enable_compile_cache()
 
     if args.ungrouped:
         make_step = bench.make_eager_ungrouped_step
-        if on_cpu:
-            # Same 183-allreduce CONTROL-plane shape (9 stacked leaves
-            # x 20 layers + 3), toy payloads: the fusion/cycle knobs
-            # act on enqueue batching and negotiation cadence, which
-            # the core runs identically on the CPU substrate.
-            cfg = LlamaConfig.tiny(n_layers=20, dtype="float32")
-            batch, seq = 2, 64
-            lane = "ungrouped-per-grad (tiny model, cpu control-plane)"
-        else:
-            cfg = bench._same_size_cfg("bfloat16")   # 809M, 20 layers
-            batch, seq = 4, 2048
-            lane = "ungrouped-per-grad 809M"
+        cfg = bench._same_size_cfg("bfloat16")   # 809M, 20 layers
+        batch, seq = 4, 2048
+        lane = "ungrouped-per-grad 809M"
         # Bursty per-grad traffic needs score windows spanning SEVERAL
         # steps (one gradient tree of bytes per step), or per-window
         # bytes/sec is dominated by where the window boundary lands in

@@ -29,10 +29,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 
 import jax
 
-# Peak HBM GB/s by device generation (v5e: 819 GB/s per chip).
-_HBM_PEAK = {"v4": 1228e9, "v5e": 819e9, "v5 lite": 819e9,
-             "v5p": 2765e9, "v6e": 1640e9, "cpu": 100e9}
-
 # (batch, prompt_len): bs1 is the latency point, bs16/bs64 throughput.
 CONFIGS = [(1, 128), (16, 128), (64, 128)]
 NEW_LONG, NEW_SHORT = 256, 32
@@ -92,18 +88,18 @@ def main():
     import bench
     from horovod_tpu.models import llama_init
     from horovod_tpu.models.generate import llama_generate
+    from horovod_tpu.utils.compile_cache import enable_compile_cache
+    from horovod_tpu.utils.devices import PEAK_HBM_BYTES_PER_S
 
-    if jax.devices()[0].platform == "cpu":
-        print("decode_bench needs an accelerator; skipping",
-              file=sys.stderr)
-        return
+    device = bench.require_tpu("decode_bench")
+    enable_compile_cache()
 
     cfg = bench._flagship_cfg()
     params = llama_init(cfg, jax.random.PRNGKey(0))
     n_params = sum(x.size for x in jax.tree.leaves(params))
     param_bytes = sum(x.size * x.dtype.itemsize
                       for x in jax.tree.leaves(params))
-    hbm_peak = bench.match_device_table(jax.devices()[0], _HBM_PEAK)
+    hbm_peak = bench.match_device_table(device, PEAK_HBM_BYTES_PER_S)
 
     def timed(gen, prompt, reps=3):
         # Materialize to HOST, not block_until_ready: on some PJRT

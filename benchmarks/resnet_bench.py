@@ -43,10 +43,11 @@ def main():
         resnet_loss,
     )
 
-    if jax.devices()[0].platform == "cpu":
-        print("resnet_bench needs an accelerator; skipping",
-              file=sys.stderr)
-        return
+    from horovod_tpu.utils.compile_cache import enable_compile_cache
+    from horovod_tpu.utils.devices import require_tpu
+
+    require_tpu("resnet_bench")
+    enable_compile_cache()
 
     cfg = ResNetConfig(depth=50)
     params, state = resnet_init(cfg, jax.random.PRNGKey(0))

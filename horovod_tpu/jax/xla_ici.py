@@ -5,19 +5,19 @@ Reference analog: the NCCL data-plane backend
 (``horovod/common/fusion_buffer_manager.cc``) — re-founded on XLA per
 SURVEY.md §7's key insight: Horovod's response cache ≅ a compiled-
 executable cache. Each fused group of device tensors becomes ONE jitted
-program — device-side concat → ``psum`` over the mesh axis → split, with
-pre/postscale folded in — compiled once per (op, shapes, dtype, scales,
-process-set) signature and replayed every later step. The C++ core keeps
-what it's good at: negotiation, ordering, fusion grouping, the response
+program — one variadic ``psum`` over the mesh axis, every tensor in its
+own shape, with pre/postscale folded in — compiled once per (op, shapes,
+dtype, scales, process-set) signature and replayed every later step. The
+C++ core keeps what it's good at: negotiation, ordering, fusion grouping, the response
 cache, and join handling over the host network. Because every member rank
 receives the identical fused ResponseList, the per-rank program launches
 line up into one collective over ICI (TPU pods) or the gloo CPU backend
 (tests).
 
 Topology: one device per rank ("rank-per-chip"). Multi-process runs
-require ``jax.distributed`` to be initialized with ``process_id`` equal to
-the Horovod rank; ``enable()`` does this itself from the controller
-address when possible.
+require ``jax.distributed`` to be initialized with one process per rank;
+``enable()`` does this itself from the controller address when possible,
+and learns which jax process is which rank by asking the ranks.
 """
 
 import ctypes
@@ -31,7 +31,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from horovod_tpu.common import process_sets
+from horovod_tpu.common import eager_ops, process_sets
 from horovod_tpu.common.basics import HorovodBasics
 from horovod_tpu.common.eager_ops import _DTYPE_TO_ENUM, ReduceOp
 from horovod_tpu.common.exceptions import HorovodInternalError
@@ -165,19 +165,27 @@ class XlaIciDataPlane:
                 jax.distributed.initialize(
                     coordinator_address=f"{addr}:{port}",
                     num_processes=size, process_id=rank)
-            if jax.process_count() != size or jax.process_index() != rank:
+            if jax.process_count() != size:
                 raise RuntimeError(
-                    f"jax.distributed topology (process "
-                    f"{jax.process_index()}/{jax.process_count()}) does not "
-                    f"match Horovod rank {rank}/{size}")
+                    f"jax.distributed sees {jax.process_count()} "
+                    f"processes, Horovod {size} ranks")
+            # Which jax process is which rank? Not the id handed to
+            # jax.distributed: a TPU runtime numbers the processes of a
+            # host by its own rules (four --tpu-pod ranks on a v5e 2x2
+            # came up as processes 1, 3, 2, 0). The ranks tell each
+            # other, over the host ring that is already up.
+            procs = eager_ops.allgather_async(
+                np.array([jax.process_index()], np.int32),
+                "xla_ici.process_index").synchronize()
             by_proc = {}
             for d in jax.devices():
                 by_proc.setdefault(d.process_index, []).append(d)
             self._devices = []
-            for p in range(size):
+            for r, p in enumerate(int(p) for p in procs):
                 devs = by_proc.get(p)
                 if not devs:
-                    raise RuntimeError(f"no jax device for process {p}")
+                    raise RuntimeError(f"no jax device for process {p} "
+                                       f"(rank {r})")
                 # Rank-per-chip: one device per process. If a process owns
                 # several (e.g. CPU tests), every rank still uses its first
                 # so device lists agree across ranks.
@@ -318,6 +326,16 @@ class XlaIciDataPlane:
             (group,) + tuple(local_2d.shape[1:]),
             NamedSharding(mesh, P("hvd")), [shard])
 
+    def _global_rows(self, mesh, group, arr):
+        """Lift this rank's array to the global one stacked over ranks
+        along dim 0 — no staging copy: the local buffer IS the shard, in
+        its own shape (a scalar rides as shape (1,))."""
+        local = jax.device_put(arr.reshape(1) if arr.ndim == 0 else arr,
+                               self._local_device)
+        return jax.make_array_from_single_device_arrays(
+            (group * local.shape[0],) + tuple(local.shape[1:]),
+            NamedSharding(mesh, P("hvd")), [local])
+
     def _store(self, names, ps_id, outs):
         with self._lock:
             for nm, o in zip(names, outs):
@@ -343,31 +361,29 @@ class XlaIciDataPlane:
                 self._exec_cache[sig] = fn
             if group == 1:
                 # Single-member set: the reduction is identity × scales,
-                # so the program takes the arrays in their ORIGINAL
-                # shapes — no flat staging copies, no concat buffer, and
-                # with donation the outputs alias the inputs outright
-                # (zero HBM transient; at flagship gradient sizes the
-                # concat path's transients would not even fit next to
-                # the model). One executable call replaces ~2n per-
-                # tensor lifts — the dominant dispatch cost on
-                # high-latency transports.
+                # so the program is just the scales and, with donation,
+                # the outputs alias the inputs outright (zero HBM
+                # transient). One executable call, no per-tensor lifts.
                 outs = list(fn(*arrs))
                 del arrs
                 self._store(names, ps_id, outs)
                 return
-            # Reshape + lift one tensor at a time, RELEASING the flat
-            # staging copy's predecessor as we go — with donation active
-            # the fused program then runs with only one generation of
-            # buffers live (the HBM fusion-buffer story, SURVEY §7).
+            # Lift each tensor IN ITS OWN SHAPE: the local buffer is the
+            # shard, so nothing is staged or copied on the way in, and
+            # with donation the program reduces in place (per-device
+            # input and output shapes are identical). The first cut of
+            # this path flattened every tensor to a [1, k] row and
+            # concatenated them — ~5x the payload in HBM, and a [1, k]
+            # reshape of a 0.7 GB gradient did not finish compiling in
+            # 400 s on a v5e (PR 21).
             gins = []
             for i in range(len(arrs)):
-                gins.append(self._global(mesh, group,
-                                         arrs[i].reshape(1, -1)))
+                gins.append(self._global_rows(mesh, group, arrs[i]))
                 arrs[i] = None
             del arrs
-            # Outputs come back already in their final shapes (reshape
-            # folded into the compiled program — no host-side copy).
-            outs = [g.addressable_data(0) for g in fn(*gins)]
+            outs = [g.addressable_data(0).reshape(shape)
+                    if not shape else g.addressable_data(0)
+                    for g, shape in zip(fn(*gins), shapes)]
             self._store(names, ps_id, outs)
         elif op_class == _OP_BROADCAST:
             arrs, _, _ = self._take_inputs(names, shapes, np_dtype, ps_id)
@@ -456,15 +472,8 @@ class XlaIciDataPlane:
 def _shard_map(fn, mesh, in_specs, out_specs):
     # check_vma off: outputs ARE replicated (psum/pmin/... results), but
     # the checker can't always prove it through the slice/scale epilogue.
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    # jax 0.4.x boxes: the experimental spelling, where the replication
-    # checker is still called check_rep.
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def _adasum_combine(x, group):
@@ -495,18 +504,23 @@ def _adasum_combine(x, group):
 
 
 def _reduce(buf, reduce_op, group):
+    """Reduce ``buf`` — one array, or a tuple reduced as ONE variadic
+    collective — over the "hvd" axis."""
     if reduce_op in (ReduceOp.SUM, ReduceOp.AVERAGE):
         red = lax.psum(buf, "hvd")
         if reduce_op == ReduceOp.AVERAGE:
-            red = red / group if jnp.issubdtype(red.dtype, jnp.floating) \
-                else red // group
+            red = jax.tree.map(
+                lambda r: r / group
+                if jnp.issubdtype(r.dtype, jnp.floating) else r // group,
+                red)
         return red
     if reduce_op == ReduceOp.MIN:
         return lax.pmin(buf, "hvd")
     if reduce_op == ReduceOp.MAX:
         return lax.pmax(buf, "hvd")
     if reduce_op == ReduceOp.PRODUCT:
-        return jnp.prod(lax.all_gather(buf, "hvd"), axis=0)
+        return jax.tree.map(
+            lambda b: jnp.prod(lax.all_gather(b, "hvd"), axis=0), buf)
     raise ValueError(f"reduce op {reduce_op} is not supported on the XLA "
                      "data plane (Adasum rides the host path)")
 
@@ -534,52 +548,41 @@ def _build_allreduce_local(reduce_op, scales, donate):
 
 
 def _build_allreduce(mesh, group, shapes, reduce_op, scales, donate=False):
-    """One program for the fused group: concat → reduce → split →
-    reshape-to-final. This IS the fusion buffer — it lives in HBM for
-    the duration of the program and XLA fuses the scale/concat/split
-    elementwise work around the collective (reference analog:
-    MemcpyInFusionBuffer + cuda_kernels.cu, done here by the compiler).
-    ``donate=True`` additionally donates the input blocks so the
-    outputs reuse their HBM (reference analog: the in-place fusion
-    buffer — safe only when the frontend promised the inputs are dead,
-    see ``enqueue_device(donate=...)``)."""
-    sizes = [max(_nelem(s), 1) for s in shapes]
+    """One program for the fused group: prescale → ONE variadic
+    collective over every tensor in its own shape → postscale. This IS
+    the fusion buffer — XLA combines the operands of the collective
+    itself, with no concat/split copies and no flattening (reference
+    analog: MemcpyInFusionBuffer + cuda_kernels.cu, done here by the
+    compiler). Each device's block is the tensor as the frontend handed
+    it over and each output block is the reduced tensor in the same
+    shape, so ``donate=True`` lets the program reduce in place
+    (reference analog: the in-place fusion buffer — safe only when the
+    frontend promised the inputs are dead, see
+    ``enqueue_device(donate=...)``)."""
 
-    def inner(*blocks):  # each (1, size_i)
-        parts = []
-        for b, (pre, _) in zip(blocks, scales):
-            x = b.reshape(-1)
-            if pre != 1.0:
-                x = x * np.asarray(pre, x.dtype)
-            parts.append(x)
+    def inner(*blocks):
+        parts = tuple(
+            b * np.asarray(pre, b.dtype) if pre != 1.0 else b
+            for b, (pre, _) in zip(blocks, scales))
         if reduce_op == ReduceOp.ADASUM:
             # Adasum is PER-TENSOR (the dot products that make it scale
             # insensitive are per-gradient — reference
             # Adasum::DispatchFusedAllreduce walks the fusion buffer
-            # tensor-by-tensor), so no concat fusion here; the stages
-            # still share the program and its collectives schedule.
-            red_parts = [_adasum_combine(p, group) for p in parts]
+            # tensor-by-tensor); the stages still share the program and
+            # its collectives schedule.
+            red = tuple(_adasum_combine(p, group) for p in parts)
         else:
-            buf = parts[0] if len(parts) == 1 else jnp.concatenate(parts)
-            red = _reduce(buf, reduce_op, group)
-            red_parts = None
-        outs, off = [], 0
-        for i, (sz, (_, post)) in enumerate(zip(sizes, scales)):
-            if red_parts is not None:
-                o = red_parts[i]
-            else:
-                o = lax.slice_in_dim(red, off, off + sz)
-                off += sz
-            if post != 1.0:
-                o = o * np.asarray(post, o.dtype)
-            # Final shape comes out of the compiled program directly so
-            # the host never reshape-copies the result.
-            outs.append(o.reshape(shapes[i] if shapes[i] else ()))
-        return tuple(outs)
+            red = _reduce(parts, reduce_op, group)
+        return tuple(
+            o * np.asarray(post, o.dtype) if post != 1.0 else o
+            for o, (_, post) in zip(red, scales))
 
+    # Every device emits its own copy of the result as "its shard": the
+    # global output mirrors the global input, which is what lets a
+    # donated input alias it.
     k = len(shapes)
-    out_specs = tuple(P(*(None,) * len(s)) if s else P() for s in shapes)
-    return jax.jit(_shard_map(inner, mesh, (P("hvd"),) * k, out_specs),
+    return jax.jit(_shard_map(inner, mesh, (P("hvd"),) * k,
+                              (P("hvd"),) * k),
                    donate_argnums=tuple(range(k)) if donate else ())
 
 

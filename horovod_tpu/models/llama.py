@@ -279,10 +279,14 @@ def _attention(q, k, v, mesh, seq_axis, seq_parallel="ring",
     # fallback names its output attn_out.
     from horovod_tpu.ops import flash_attention
 
-    if flash_block:
-        return flash_attention(q, k, v, causal=True,
-                               block_q=flash_block, block_k=flash_block)
-    return flash_attention(q, k, v, causal=True)
+    # The kernel shards itself over the mesh (a Mosaic call cannot be
+    # partitioned by GSPMD) — except under pipelining, where this body
+    # already runs inside the pipeline's own shard_map.
+    if mesh is not None and mesh.shape.get("pipe", 1) > 1:
+        mesh = None
+    blocks = {"block_q": flash_block, "block_k": flash_block} \
+        if flash_block else {}
+    return flash_attention(q, k, v, causal=True, mesh=mesh, **blocks)
 
 
 def _activation_spec(mesh):
@@ -611,10 +615,9 @@ def _build_layer_body(c, mesh, seq_axis, constrain_acts=True):
         # rope, no FFN gate matmul. The extra ~[B,T,2D] bf16 per layer
         # is the cheapest matmul-recompute elimination left after
         # attn+gate — FOR SHAPES WITH HBM HEADROOM: at the 16G-chip
-        # flagship bench shape it exceeds HBM (r5: the AOT compile
-        # helper crashes rather than reporting a clean OOM), so the
-        # mode is pinned by the CPU remat-equivalence test but has no
-        # on-chip flagship measurement.
+        # flagship bench shape it exceeded HBM in r5, so the mode is
+        # pinned by the CPU remat-equivalence test but has no on-chip
+        # flagship measurement.
         body = jax.checkpoint(
             layer,
             policy=jax.checkpoint_policies.save_only_these_names(

@@ -1,21 +1,23 @@
 """Split-program training step: grad and optimizer-apply as two jits.
 
-Why two programs instead of one fused train-step jit (measured, r5/r6):
+Why two programs instead of one fused train-step jit:
 
-1. the split layout is FASTER at flagship shape — the fused program's
-   interleaved adam update schedules worse (573 -> 552 ms/step, r5);
-2. it is the formulation that dodges this environment's AOT-compile-
-   helper crash on the MoE config: ``remat="moe"`` + microbatch
-   gradient accumulation compiled as ONE monolithic jit crashes the
-   helper (HTTP 500 — see benchmarks/aot_crash_repro.py), while the
-   same math as a small grad program called N times plus a trivial
-   apply program compiles each piece separately and never hands the
-   helper the monolith;
+1. the builders measured the split layout faster at flagship shape in
+   round 5 (the fused program's interleaved adam update scheduled
+   worse); that chip and compiler are gone and the comparison has not
+   been repeated on jax 0.9.0 — not measured;
+2. each program compiles on its own (the flagship grad program takes
+   35–37 s on the v5e, PERF.md; the apply program a tenth of that),
+   and the eager-Horovod lane reuses the very same grad program
+   around its allreduce;
 3. N-way microbatch gradient accumulation falls out naturally: the
    grad program runs once per microbatch into a donated accumulator,
-   so per-microbatch activation memory is 1/N of the full batch — the
-   enabler for expensive remat save-sets (``remat="moe"``) at bench
-   sizes.
+   so per-microbatch ACTIVATION memory is 1/N of the full batch. The
+   accumulate program, though, holds params, the accumulator and a
+   fresh gradient tree at once: where the gradient tree is as large as
+   the activations saved (the 1.4B flagship at 4 x 2048: 2-way
+   accumulation ran at 570 vs 552 ms/step on the v5e and, by the
+   compiler's account, saves no memory — PERF.md) it buys nothing.
 
 The two programs are connected by DONATED gradient buffers: the first
 microbatch's gradient outputs become the accumulator and each

@@ -5,6 +5,8 @@ get_host_assignments) and ``network.py``.
 """
 
 import dataclasses
+import glob
+import os
 import socket
 
 
@@ -121,3 +123,37 @@ def resolvable_addr_for(hosts):
         return socket.gethostbyname(socket.gethostname())
     finally:
         s.close()
+
+
+# Google's PCI vendor id and the device ids of its TPU chips (v3, v4,
+# v5p, v5e, v6e, 7x) — the table jax's own start-up probe uses.
+_GOOGLE_PCI_VENDOR = "0x1ae0"
+_TPU_PCI_DEVICES = {"0x0027", "0x0056", "0x005e", "0x0062", "0x0063",
+                    "0x006f", "0x0076"}
+
+
+def local_tpu_chips(sysfs="/sys/bus/pci/devices", dev="/dev"):
+    """Number of TPU chips this host can open: chips on the PCI bus that
+    also have a device node (``/dev/accel*`` up to v4, one
+    ``/dev/vfio/<group>`` per chip from v5e on). A sandbox may list a
+    whole host's chips on the bus and hand over only some of them.
+
+    Deliberately jax-free: the ``--tpu-pod`` launcher counts chips with
+    it before spawning one rank per chip, and a chip belongs to one
+    process at a time — a launcher that asked ``jax.local_devices()``
+    would hold every chip its ranks are about to open.
+    """
+    on_bus = 0
+    for vendor_path in glob.glob(os.path.join(sysfs, "*", "vendor")):
+        try:
+            with open(vendor_path) as f:
+                if f.read().strip() != _GOOGLE_PCI_VENDOR:
+                    continue
+            with open(os.path.join(os.path.dirname(vendor_path),
+                                   "device")) as f:
+                on_bus += f.read().strip() in _TPU_PCI_DEVICES
+        except OSError:
+            continue
+    nodes = len(glob.glob(os.path.join(dev, "accel[0-9]*"))) \
+        + len(glob.glob(os.path.join(dev, "vfio", "[0-9]*")))
+    return min(on_bus, nodes)

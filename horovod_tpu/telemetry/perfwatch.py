@@ -2,8 +2,8 @@
 
 ``python -m horovod_tpu.telemetry.perfwatch`` consumes either a
 :class:`~horovod_tpu.telemetry.exporters.MetricsScraper` JSONL flight
-recorder or bench JSON rows (``bench.py`` output / the committed
-``BENCH_r0*.json`` trajectory) and answers ONE question with an exit
+recorder or bench JSON rows (``bench.py`` output, or a driver artifact
+that embeds such rows) and answers ONE question with an exit
 code CI can gate on: did step time, bus bandwidth, or overlap
 efficiency regress?
 
@@ -203,8 +203,7 @@ def changepoint(series):
 def load_rows(path):
     """Rows from a bench/scrape file: JSONL (one object per line, the
     bench and scraper formats), a JSON array, or a driver artifact
-    whose ``tail`` string embeds JSON rows between log lines (the
-    committed ``BENCH_r0*.json`` shape)."""
+    whose ``tail`` string embeds JSON rows between log lines."""
     with open(path) as f:
         text = f.read()
     try:
@@ -341,7 +340,7 @@ def main(argv=None):
                     help="MetricsScraper JSONL flight recorder")
     ap.add_argument("--bench", nargs="*", default=None,
                     help="bench row files (JSONL / JSON array / "
-                         "BENCH_r0*.json driver artifacts), "
+                         "driver artifacts with a `tail`), "
                          "concatenated in order")
     ap.add_argument("--threshold", type=float, default=0.25,
                     help="relative breach threshold (default 0.25)")

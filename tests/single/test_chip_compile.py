@@ -1,0 +1,225 @@
+"""What guards the chip path without a chip.
+
+1. Compile-only, for a *described* ``v5e:2x2`` device (the TPU compiler
+   is installed here; no chip is attached): the main path's kernels at
+   their real widths must lower to ``tpu_custom_call`` and be accepted by
+   the chip's compiler — tiling, VMEM and alignment faults that
+   interpret mode cannot see fail here, at no chip time. Nothing runs;
+   this says nothing about results or speed.
+2. ``chip_smoke.py`` without a TPU must fail and print no result.
+3. The compile cache goes where ``JAX_COMPILATION_CACHE_DIR`` says, else
+   to the fixed in-checkout path.
+4. Peak tables know this chip and refuse what they do not know.
+"""
+
+import os
+import subprocess
+import sys
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from horovod_tpu.ops import _platform
+
+REPO = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+pytestmark = pytest.mark.quick
+
+
+@pytest.fixture(scope="module")
+def v5e_chip():
+    """One device of a described (not attached) v5e 2x2 host."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        from jax.experimental import topologies
+
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no libtpu / old jax here
+        pytest.skip(f"cannot describe a v5e:2x2 topology: {e}")
+    return jax.sharding.SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def for_tpu(v5e_chip, monkeypatch):
+    """Steer the kernel wrappers onto their TPU branch (tracers for a
+    described device still report the CPU backend), with the persistent
+    compile cache off: an executable compiled for an unattached chip can
+    be written to it but never read back."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    monkeypatch.setattr(_platform, "operand_platform", lambda *a: "tpu")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+
+    def compile_for_chip(fn, *shapes):
+        args = [jax.ShapeDtypeStruct(s, d, sharding=v5e_chip)
+                for s, d in shapes]
+        return jax.jit(fn).lower(*args).compile().as_text()
+
+    yield compile_for_chip
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
+# The flagship's attention shape: B4 T2048 H16 Hkv4 D128.
+_Q, _KV = ((4, 2048, 16, 128), BF16), ((4, 2048, 4, 128), BF16)
+
+
+def _flash_fwd(q, k, v):
+    from horovod_tpu.ops import flash_attention
+
+    return flash_attention(q, k, v, causal=True)
+
+
+def _flash_fwd_bwd(q, k, v):
+    return jax.grad(lambda *a: _flash_fwd(*a).astype(F32).sum(),
+                    argnums=(0, 1, 2))(q, k, v)
+
+
+def _flash_chunk(q, k, v, q_off, kv_off):
+    # One ring-attention step, kernel layout [B, H, T, D], with traced
+    # global offsets; the lse cotangent exercises the folded backward.
+    from horovod_tpu.ops.flash_attention import flash_attention_chunk
+
+    def f(q, k, v):
+        o, lse = flash_attention_chunk(q, k, v, q_off, kv_off)
+        return o.astype(F32).sum() + lse.sum()
+
+    return jax.grad(f, argnums=(0, 1, 2))(q, k, v)
+
+
+def _flash_biased(q, k, v, bias):
+    from horovod_tpu.ops import flash_attention
+
+    return jax.grad(lambda q, k, v: flash_attention(
+        q, k, v, causal=False, kv_bias=bias).astype(F32).sum(),
+        argnums=(0, 1, 2))(q, k, v)
+
+
+def _decode(q, ck, cv, pos):
+    from horovod_tpu.ops.decode_attention import decode_attention
+
+    return decode_attention(q, ck, cv, pos)
+
+
+def _gmm(lhs, rhs, group_sizes):
+    from horovod_tpu.ops.grouped_moe import _grouped_mm
+
+    return jax.grad(lambda lhs, rhs: _grouped_mm(
+        lhs, rhs, group_sizes).astype(F32).sum(), argnums=(0, 1))(lhs, rhs)
+
+
+@pytest.mark.parametrize("fn,shapes", [
+    pytest.param(_flash_fwd, (_Q, _KV, _KV), id="flash-fwd-flagship"),
+    pytest.param(_flash_fwd_bwd, (_Q, _KV, _KV),
+                 id="flash-fwd+bwd-flagship"),
+    pytest.param(_flash_chunk,
+                 (((1, 16, 2048, 128), BF16), ((1, 4, 2048, 128), BF16),
+                  ((1, 4, 2048, 128), BF16), ((), I32), ((), I32)),
+                 id="flash-chunk-offsets"),
+    # BERT-base attention over a padded batch: B32 T512 H12 D64.
+    pytest.param(_flash_biased,
+                 (((32, 512, 12, 64), BF16),) * 3 + (((32, 512), F32),),
+                 id="flash-biased-bert"),
+    # Serving: batch 16, 640 cache slots, 16 query / 4 KV heads of 128.
+    pytest.param(_decode,
+                 (((16, 1, 16, 128), BF16), ((16, 4, 640, 128), BF16),
+                  ((16, 4, 640, 128), BF16), ((), I32)),
+                 id="decode-serving"),
+    # benchmarks/moe_bench.py: 4 x 2048 tokens top-2 over E4, D2048 F4096.
+    pytest.param(_gmm,
+                 (((16384, 2048), BF16), ((4, 2048, 4096), BF16),
+                  ((4,), I32)),
+                 id="megablox-gmm-moe-bench"),
+])
+def test_kernel_compiles_for_described_v5e(for_tpu, fn, shapes):
+    assert "tpu_custom_call" in for_tpu(fn, *shapes)
+
+
+def test_interpret_mode_on_tpu_operands_raises(monkeypatch):
+    """A TPU run must never crawl through the pallas interpreter."""
+    monkeypatch.setattr(_platform, "operand_platform", lambda *a: "tpu")
+    with pytest.raises(RuntimeError, match="interpret mode"):
+        _platform.use_pallas("flash_attention", (), interpret=True)
+    assert _platform.use_pallas("flash_attention", ()) is True
+
+
+def test_kernel_choice_follows_the_operands_device():
+    x = jnp.ones((2, 2))
+    assert _platform.operand_platform(x) == "cpu"
+    assert _platform.use_pallas("flash_attention", (x,)) is False
+    assert _platform.use_pallas("flash_attention", (x,), interpret=True)
+    assert jax.jit(lambda y: _platform.operand_platform(y) == "cpu")(x)
+
+
+def test_chip_smoke_without_a_tpu_fails_and_prints_no_result():
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    out = subprocess.run([sys.executable, os.path.join(REPO,
+                                                       "chip_smoke.py")],
+                         env=env, capture_output=True, text=True,
+                         timeout=120)
+    assert out.returncode != 0
+    assert '"ok": true' not in out.stdout
+    assert "needs a TPU" in out.stderr
+
+
+def test_compile_cache_goes_where_the_environment_says(monkeypatch,
+                                                       tmp_path):
+    from horovod_tpu.utils import compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    tracebacks = jax.config.jax_include_full_tracebacks_in_locations
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert compile_cache.enable_compile_cache() == str(tmp_path)
+        # jax reads the variable itself; nothing is set in code
+        assert jax.config.jax_compilation_cache_dir == before
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        fixed = os.path.join(REPO, ".jax_cache")
+        assert compile_cache.enable_compile_cache() == fixed
+        assert jax.config.jax_compilation_cache_dir == fixed
+        assert compile_cache.enable_compile_cache() == fixed  # never moves
+        # kernel-bearing programs must not key on the caller's stack
+        assert not jax.config.jax_include_full_tracebacks_in_locations
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+        jax.config.update("jax_include_full_tracebacks_in_locations",
+                          tracebacks)
+
+
+@pytest.mark.parametrize("table", ["bench-flops", "step-timer",
+                                   "decode-bench-hbm"])
+def test_peak_tables_know_this_chip_and_refuse_others(table, monkeypatch):
+    import bench
+    from horovod_tpu.telemetry import step_timer
+    from horovod_tpu.utils import devices
+
+    class Device:
+        def __init__(self, kind):
+            self.device_kind = kind
+
+    if table == "bench-flops":
+        def lookup(kind):
+            return bench._peak_flops(Device(kind))
+        want = 197e12
+    elif table == "step-timer":
+        def lookup(kind):
+            monkeypatch.setattr(jax, "devices", lambda: [Device(kind)])
+            return step_timer._device_peak_flops()
+        want = 197e12
+    else:  # the table benchmarks/decode_bench.py computes MBU against
+        def lookup(kind):
+            return bench.match_device_table(
+                Device(kind), devices.PEAK_HBM_BYTES_PER_S)
+        want = 819e9
+    assert lookup("TPU v5 lite") == want
+    assert lookup("TPU v5e") == want
+    for unknown in ("cpu", "TPU v9 ultra", ""):
+        with pytest.raises(KeyError, match="not in the peak table"):
+            lookup(unknown)
