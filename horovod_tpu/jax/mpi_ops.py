@@ -21,6 +21,7 @@ import numpy as np
 from horovod_tpu.common import eager_ops
 from horovod_tpu.common.eager_ops import ReduceOp
 from horovod_tpu.jax import xla_ici
+from horovod_tpu.utils.spans import span
 
 # Reference-compatible reduce-op aliases (horovod/torch/mpi_ops.py).
 Average = ReduceOp.AVERAGE
@@ -270,6 +271,22 @@ def grouped_allreduce_async(tensors, names=None, op=Average,
     complete atomically). Reference analog: hvd.grouped_allreduce
     (horovod/common/group_table.cc). ``donate`` as in
     :func:`allreduce_async` (device plane only)."""
+    with span("hvd.enqueue") as s:
+        if s.is_enabled():
+            s.set_metadata(tensors=len(tensors),
+                           bytes=sum(getattr(t, "nbytes", 0)
+                                     for t in tensors))
+        return _enqueue_grouped_allreduce(
+            tensors, names, op, prescale_factor, postscale_factor,
+            process_set_id, donate)
+
+
+def _enqueue_grouped_allreduce(tensors, names=None, op=Average,
+                               prescale_factor=1.0, postscale_factor=1.0,
+                               process_set_id=0, donate=False):
+    """:func:`grouped_allreduce_async` without its ``hvd.enqueue`` span:
+    for a caller that has opened the span itself round more of the work
+    (``allreduce_gradients``: flatten and compress come first)."""
     if names is None:
         base = _auto_name("grouped_allreduce")
         names = [f"{base}.{i}" for i in range(len(tensors))]

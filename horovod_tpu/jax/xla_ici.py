@@ -35,6 +35,7 @@ from horovod_tpu.common import eager_ops, process_sets
 from horovod_tpu.common.basics import HorovodBasics
 from horovod_tpu.common.eager_ops import _DTYPE_TO_ENUM, ReduceOp
 from horovod_tpu.common.exceptions import HorovodInternalError
+from horovod_tpu.utils.spans import span
 
 _basics = HorovodBasics()
 
@@ -46,6 +47,10 @@ _OP_ALLGATHER = 1
 _OP_BROADCAST = 2
 _OP_ALLTOALL = 3
 _OP_REDUCESCATTER = 4
+
+_OP_NAMES = {_OP_ALLREDUCE: "allreduce", _OP_ALLGATHER: "allgather",
+             _OP_BROADCAST: "broadcast", _OP_ALLTOALL: "alltoall",
+             _OP_REDUCESCATTER: "reducescatter"}
 
 _EXEC_FN = ctypes.CFUNCTYPE(
     ctypes.c_int32,                    # return: 0 ok, nonzero = error
@@ -274,13 +279,25 @@ class XlaIciDataPlane:
 
     def _execute(self, op_class, n, names_p, shapes_p, dtype, reduce_op,
                  root_rank, ps_id, sizes_p, n_sizes, err_p, err_cap):
+        # One span a fused response, on the core's thread: take the
+        # inputs, look the program up (or build it), launch, store the
+        # outputs. The launch is asynchronous; the device's time is in
+        # the device trace under jit_hvd_<op>.
         try:
-            names = [names_p[i].decode() for i in range(n)]
-            shapes = _decode_shapes(shapes_p, n)
-            np_dtype = _ENUM_TO_DTYPE[dtype]
-            rank_sizes = tuple(int(sizes_p[i]) for i in range(n_sizes))
-            self._run(op_class, names, shapes, np_dtype, reduce_op,
-                      root_rank, ps_id, rank_sizes)
+            with span("hvd.device_exec") as s:
+                names = [names_p[i].decode() for i in range(n)]
+                shapes = _decode_shapes(shapes_p, n)
+                np_dtype = _ENUM_TO_DTYPE[dtype]
+                rank_sizes = tuple(int(sizes_p[i]) for i in range(n_sizes))
+                cached = len(self._exec_cache)
+                self._run(op_class, names, shapes, np_dtype, reduce_op,
+                          root_rank, ps_id, rank_sizes)
+                if s.is_enabled():
+                    s.set_metadata(
+                        op=_OP_NAMES.get(op_class, op_class), tensors=n,
+                        bytes=sum(map(_nelem, shapes)) * np_dtype.itemsize,
+                        executable_cache="miss" if len(self._exec_cache)
+                        > cached else "hit")
             return 0
         except Exception as e:  # noqa: BLE001 — crosses the C boundary
             msg = f"xla_ici: {type(e).__name__}: {e}".encode()[:err_cap - 1]
@@ -532,7 +549,7 @@ def _build_allreduce_local(reduce_op, scales, donate):
     is just the pre/post scales — and with donation, pure buffer
     aliasing. Original shapes in, original shapes out."""
 
-    def inner(*xs):
+    def hvd_allreduce(*xs):
         outs = []
         for x, (pre, post) in zip(xs, scales):
             if pre != 1.0:
@@ -543,7 +560,7 @@ def _build_allreduce_local(reduce_op, scales, donate):
         return tuple(outs)
 
     return jax.jit(
-        inner,
+        hvd_allreduce,
         donate_argnums=tuple(range(len(scales))) if donate else ())
 
 
@@ -560,7 +577,7 @@ def _build_allreduce(mesh, group, shapes, reduce_op, scales, donate=False):
     frontend promised the inputs are dead, see
     ``enqueue_device(donate=...)``)."""
 
-    def inner(*blocks):
+    def hvd_allreduce(*blocks):
         parts = tuple(
             b * np.asarray(pre, b.dtype) if pre != 1.0 else b
             for b, (pre, _) in zip(blocks, scales))
@@ -581,13 +598,13 @@ def _build_allreduce(mesh, group, shapes, reduce_op, scales, donate=False):
     # global output mirrors the global input, which is what lets a
     # donated input alias it.
     k = len(shapes)
-    return jax.jit(_shard_map(inner, mesh, (P("hvd"),) * k,
+    return jax.jit(_shard_map(hvd_allreduce, mesh, (P("hvd"),) * k,
                               (P("hvd"),) * k),
                    donate_argnums=tuple(range(k)) if donate else ())
 
 
 def _build_broadcast(mesh, root_pos):
-    def inner(block):  # (1, n)
+    def hvd_broadcast(block):  # (1, n)
         x = block.reshape(-1)
         idx = lax.axis_index("hvd")
         if jnp.issubdtype(x.dtype, jnp.bool_):
@@ -597,20 +614,20 @@ def _build_broadcast(mesh, root_pos):
         contrib = jnp.where(idx == root_pos, x, jnp.zeros_like(x))
         return lax.psum(contrib, "hvd")
 
-    return jax.jit(_shard_map(inner, mesh, P("hvd"), P(None)))
+    return jax.jit(_shard_map(hvd_broadcast, mesh, P("hvd"), P(None)))
 
 
 def _build_allgather(mesh, dims):
-    def inner(block):  # (1, max_d, restf)
+    def hvd_allgather(block):  # (1, max_d, restf)
         g = lax.all_gather(block[0], "hvd")  # (group, max_d, restf)
         segs = [lax.slice_in_dim(g[i], 0, d) for i, d in enumerate(dims)]
         return jnp.concatenate(segs, axis=0)
 
-    return jax.jit(_shard_map(inner, mesh, P("hvd"), P(None)))
+    return jax.jit(_shard_map(hvd_allgather, mesh, P("hvd"), P(None)))
 
 
 def _build_alltoall(mesh, group):
-    def inner(block):  # (1, first, restf)
+    def hvd_alltoall(block):  # (1, first, restf)
         x = block[0]
         first, restf = x.shape
         x = x.reshape(group, first // group, restf)
@@ -619,13 +636,13 @@ def _build_alltoall(mesh, group):
 
     # Output differs per rank: stays sharded over "hvd", each process
     # reads its own shard.
-    return jax.jit(_shard_map(inner, mesh, P("hvd"), P("hvd")))
+    return jax.jit(_shard_map(hvd_alltoall, mesh, P("hvd"), P("hvd")))
 
 
 def _build_reducescatter(mesh, group, reduce_op, scale, off, nrows):
     pre, post = scale
 
-    def inner(block):  # (1, first, restf)
+    def hvd_reducescatter(block):  # (1, first, restf)
         x = block[0]
         if pre != 1.0:
             x = x * np.asarray(pre, x.dtype)
@@ -635,7 +652,7 @@ def _build_reducescatter(mesh, group, reduce_op, scale, off, nrows):
             out = out * np.asarray(post, out.dtype)
         return out
 
-    return jax.jit(_shard_map(inner, mesh, P("hvd"), P(None)))
+    return jax.jit(_shard_map(hvd_reducescatter, mesh, P("hvd"), P(None)))
 
 
 # Module-level singleton; frontends share it.
@@ -708,13 +725,7 @@ class DeviceHandle:
 
 
 # Response::ResponseType values accepted by hvdtpu_enqueue_device.
-_ENQUEUE_OPS = {
-    "allreduce": _OP_ALLREDUCE,
-    "allgather": _OP_ALLGATHER,
-    "broadcast": _OP_BROADCAST,
-    "alltoall": _OP_ALLTOALL,
-    "reducescatter": _OP_REDUCESCATTER,
-}
+_ENQUEUE_OPS = {name: op for op, name in _OP_NAMES.items()}
 
 
 def alltoall_group_size(process_set_id):

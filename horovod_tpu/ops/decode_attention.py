@@ -117,6 +117,7 @@ def decode_attention(q, cache_k, cache_v, pos):
         ),
         out_shape=jax.ShapeDtypeStruct((b, hkv, n_rep, d), q.dtype),
         interpret=_INTERPRET,
+        metadata={"kernel": "hvd_decode_attention"},
     )(pos_arr, qg, cache_k, cache_v)
     return out.reshape(b, 1, hq, d)
 

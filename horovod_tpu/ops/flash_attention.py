@@ -275,24 +275,32 @@ def _bwd_dq_kernel(*refs, scale, causal, has_bias, has_offsets):
         dq_ref[:, :] = dq_acc[:, :].astype(dq_ref.dtype)
 
 
-def _pallas_dispatch(kernel, grid, in_specs, out_specs, out_shape, args,
-                     offsets, scratch_shapes):
+def _pallas_dispatch(name, kernel, grid, in_specs, out_specs, out_shape,
+                     args, offsets, scratch_shapes):
     """Shared fwd/bwd dispatch: plain grid, or scalar-prefetch grid
     spec when dynamic offsets ride along (the SMEM scalars arrive
     before the kernel body and every index map). ``scratch_shapes``
     are the f32 VMEM accumulators that persist across the inner grid
-    dimension."""
+    dimension. ``name`` (``hvd_flash_fwd``, ``hvd_flash_bwd_dq``,
+    ``hvd_flash_bwd_dkv``) tells the three kernels apart in a device
+    trace: as ``metadata`` it rides in the custom call's
+    ``frontend_attributes={kernel_metadata={"kernel":...}}``, which an
+    op's event on the v5e shows (read off a chip trace, PR 25). The
+    pallas ``name=`` would not: it names the instruction only while
+    jax keeps full tracebacks in locations, and
+    ``enable_compile_cache()`` turns those off."""
+    metadata = {"kernel": name}
     if offsets is not None:
         return pl.pallas_call(
             kernel,
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=1, grid=grid, in_specs=in_specs,
                 out_specs=out_specs, scratch_shapes=scratch_shapes),
-            out_shape=out_shape, interpret=_INTERPRET,
+            out_shape=out_shape, interpret=_INTERPRET, metadata=metadata,
         )(offsets, *args)
     return pl.pallas_call(
         kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
-        out_shape=out_shape, interpret=_INTERPRET,
+        out_shape=out_shape, interpret=_INTERPRET, metadata=metadata,
         scratch_shapes=scratch_shapes)(*args)
 
 
@@ -364,8 +372,8 @@ def _flash_fwd_impl(q, k, v, bias, causal, block_q, block_k,
         pltpu.VMEM((block_q, 1), jnp.float32),   # m
         pltpu.VMEM((block_q, 1), jnp.float32),   # l
     ]
-    return _pallas_dispatch(kernel, grid, in_specs, out_specs, out_shape,
-                            args, offsets, scratch)
+    return _pallas_dispatch("hvd_flash_fwd", kernel, grid, in_specs,
+                            out_specs, out_shape, args, offsets, scratch)
 
 
 def _flash_fwd(q, k, v, causal, block_q, block_k):
@@ -405,9 +413,9 @@ def _flash_bwd_impl(q, k, v, bias, o, lse, do, causal, block_q, block_k,
         # becomes p*(dp - delta + dlse), i.e. delta -= dlse.
         delta = delta - dlse.astype(jnp.float32)
 
-    def call(kernel, grid, in_specs, out_specs, out_shape, args,
+    def call(name, kernel, grid, in_specs, out_specs, out_shape, args,
              scratch):
-        return _pallas_dispatch(kernel, grid, in_specs, out_specs,
+        return _pallas_dispatch(name, kernel, grid, in_specs, out_specs,
                                 out_shape, args, offsets, scratch)
 
     # dkv: grid (b, h, jk, iq) — q/do/lse/delta stream over the inner
@@ -440,7 +448,8 @@ def _flash_bwd_impl(q, k, v, bias, o, lse, do, causal, block_q, block_k,
     # (one cheap XLA reduction — keeps the kernel free of cross-kv-head
     # accumulation state).
     dk, dv = call(
-        dkv_kernel, (b, h, tk // block_k, t // block_q), in_specs,
+        "hvd_flash_bwd_dkv", dkv_kernel,
+        (b, h, tk // block_k, t // block_q), in_specs,
         [
             pl.BlockSpec((None, None, block_k, d),
                          lambda bi, hi, jk, iq, *a: (bi, hi, jk, 0)),
@@ -486,7 +495,8 @@ def _flash_bwd_impl(q, k, v, bias, o, lse, do, causal, block_q, block_k,
                          lambda bi, hi, qi, ji, *a: (bi, 0, ji)))
         args.append(bias)
     dq = call(
-        dq_kernel, (b, h, t // block_q, tk // block_k), in_specs,
+        "hvd_flash_bwd_dq", dq_kernel,
+        (b, h, t // block_q, tk // block_k), in_specs,
         pl.BlockSpec((None, None, block_q, d),
                      lambda bi, hi, qi, ji, *a: (bi, hi, qi, 0)),
         jax.ShapeDtypeStruct(q.shape, q.dtype), args,

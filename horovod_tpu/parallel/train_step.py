@@ -61,6 +61,8 @@ from typing import Any, NamedTuple
 import jax
 import jax.numpy as jnp
 
+from horovod_tpu.utils.spans import span
+
 
 class TrainStep(NamedTuple):
     init: Any   # init(params) -> carry
@@ -118,6 +120,17 @@ def _wrap_step_telemetry(inner_step, telemetry, flops_programs):
     return step
 
 
+def _spanned(dispatch):
+    """``step`` inside the program span ``hvd.spmd.step``: the host's
+    dispatch of one step's programs, on the profiler's clock when a
+    trace is being taken (docs/metrics.md "Program spans")."""
+    def step(carry, batch):
+        with span("hvd.spmd.step"):
+            return dispatch(carry, batch)
+
+    return step
+
+
 def _make_fused_zero_train_step(loss_fn, optimizer, zero, *, n, jk,
                                 telemetry):
     """The fused (one-program) ZeRO-1 step layout (docs/fusion.md).
@@ -164,6 +177,7 @@ def _make_fused_zero_train_step(loss_fn, optimizer, zero, *, n, jk,
                                                  mbs[-1], opt)
             return loss, (params, opt)
 
+    step = _spanned(step)
     if telemetry is not None:
         def _flops_programs(carry, batch):
             params, opt = carry
@@ -254,21 +268,26 @@ def make_split_train_step(loss_fn, optimizer, *, microbatches=1,
 
         apply_fn, zero_init = make_zero_apply(optimizer, zero,
                                               jit_kwargs=jk)
-    elif fused:
-        @functools.partial(jax.jit, donate_argnums=(1, 2), **jk)
-        def apply_fn(grads, params, opt):
-            return optimizer.apply(params, grads, opt)
     else:
-        @functools.partial(jax.jit, donate_argnums=(1, 2), **jk)
-        def apply_fn(grads, params, opt):
-            import optax  # deferred: parallel/ imports without optax
+        if fused:
+            def hvd_apply(grads, params, opt):
+                return optimizer.apply(params, grads, opt)
+        else:
+            def hvd_apply(grads, params, opt):
+                import optax  # deferred: parallel/ imports without optax
 
-            updates, opt = optimizer.update(grads, opt, params)
-            return optax.apply_updates(params, updates), opt
+                updates, opt = optimizer.update(grads, opt, params)
+                return optax.apply_updates(params, updates), opt
 
+        apply_fn = jax.jit(hvd_apply, donate_argnums=(1, 2), **jk)
+
+    # The jitted functions are named for what they are: a device trace
+    # shows jit_hvd_grad and jit_hvd_apply, and a reader finds them so.
     if n == 1:
-        grad_fn = jax.jit(
-            lambda p, d: jax.value_and_grad(loss_fn)(p, d), **jk)
+        def hvd_grad(p, d):
+            return jax.value_and_grad(loss_fn)(p, d)
+
+        grad_fn = jax.jit(hvd_grad, **jk)
 
         def step(carry, batch):
             params, opt = carry
@@ -294,13 +313,15 @@ def make_split_train_step(loss_fn, optimizer, *, microbatches=1,
         # test_interleaved_composes_with_split_train_step). Keep the
         # two-program layout unless that equivalence test passes with
         # the fold on every substrate.
-        grad_first = jax.jit(
-            lambda p, d: jax.value_and_grad(scaled_loss)(p, d), **jk)
+        def hvd_grad(p, d):
+            return jax.value_and_grad(scaled_loss)(p, d)
 
-        @functools.partial(jax.jit, donate_argnums=(1, 2), **jk)
-        def grad_acc(params, loss_acc, acc, d):
+        def hvd_grad_acc(params, loss_acc, acc, d):
             loss, g = jax.value_and_grad(scaled_loss)(params, d)
             return loss_acc + loss, jax.tree.map(jnp.add, acc, g)
+
+        grad_first = jax.jit(hvd_grad, **jk)
+        grad_acc = jax.jit(hvd_grad_acc, donate_argnums=(1, 2), **jk)
 
         def step(carry, batch):
             params, opt = carry
@@ -311,6 +332,7 @@ def make_split_train_step(loss_fn, optimizer, *, microbatches=1,
             params, opt = apply_fn(grads, params, opt)
             return loss, (params, opt)
 
+    step = _spanned(step)
     if telemetry is not None:
         def _flops_programs(carry, batch):
             params, opt = carry

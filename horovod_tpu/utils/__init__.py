@@ -1,6 +1,1 @@
-"""Shared utilities (profiling reports, misc tooling)."""
-
-from horovod_tpu.utils.xplane_report import (  # noqa: F401
-    device_op_report,
-    format_report,
-)
+"""Shared utilities (compile cache, device checks, misc tooling)."""

@@ -13,6 +13,7 @@
 """
 
 import os
+import re
 import subprocess
 import sys
 
@@ -139,6 +140,28 @@ def _gmm(lhs, rhs, group_sizes):
 ])
 def test_kernel_compiles_for_described_v5e(for_tpu, fn, shapes):
     assert "tpu_custom_call" in for_tpu(fn, *shapes)
+
+
+@pytest.mark.parametrize("locations", ["whole", "cut"])
+def test_flash_kernels_keep_their_names_in_the_compiled_program(
+        for_tpu, locations):
+    """What a device trace shows of a kernel is its instruction in the
+    compiled program. ``enable_compile_cache()`` cuts the locations and
+    the instruction is then ``%tpu_custom_call.N`` (as on the v5e,
+    PR 25); the kernel metadata tells the three kernels apart whether
+    locations are whole or cut."""
+    was = jax.config.jax_include_full_tracebacks_in_locations
+    jax.config.update("jax_include_full_tracebacks_in_locations",
+                      locations == "whole")
+    try:
+        # (a fresh function: jax's lowering cache does not key on it)
+        text = for_tpu(lambda *a: _flash_fwd_bwd(*a), _Q, _KV, _KV)
+    finally:
+        jax.config.update("jax_include_full_tracebacks_in_locations", was)
+    for name in ("hvd_flash_fwd", "hvd_flash_bwd_dq", "hvd_flash_bwd_dkv"):
+        assert re.search(
+            r"custom-call\([^\n]*kernel_metadata=\{\s*\"kernel\":\""
+            + name + r"\"\s*\}", text), name
 
 
 def test_interpret_mode_on_tpu_operands_raises(monkeypatch):
