@@ -25,7 +25,9 @@ Rank policy: with a single jax process but multiple Horovod ranks
 (host-ring data parallelism), only rank 0 writes — replicas hold
 identical state, and concurrent writers to one directory would race.
 With ``jax.distributed`` initialized (TPU pods / the xla_ici plane),
-every process participates — orbax coordinates the multi-host write.
+every process participates — orbax coordinates the multi-host write
+through ``jax.distributed``'s client, and a rank's own replica of the
+state is written by orbax's primary process.
 """
 
 import os
@@ -91,18 +93,34 @@ def decode_pytree(tree):
     return jax.tree.map(dec, tree, is_leaf=is_marker)
 
 
-def _sanitize_scalars(state):
-    """Orbax's StandardCheckpointHandler restricts leaves to
-    ``(int, float, np.ndarray, jax.Array)`` on recent versions (0.7.x
-    validates on save); numpy SCALARS (``np.int64(7)`` — the natural
-    type of a step counter) fail that check. Promote them to 0-d
-    ndarrays, which round-trip equivalently (``int(x)``/``float(x)``
-    and arithmetic behave the same on restore)."""
+def _storable(state):
+    """The leaves as orbax's StandardCheckpointHandler takes them.
+
+    It restricts leaves to ``(int, float, np.ndarray, jax.Array)`` on
+    recent versions (0.7.x validates on save); numpy SCALARS
+    (``np.int64(7)`` — the natural type of a step counter) fail that
+    check. Promote them to 0-d ndarrays, which round-trip equivalently
+    (``int(x)``/``float(x)`` and arithmetic behave the same on restore).
+
+    With one jax process per rank it also refuses a ``jax.Array`` that
+    lives on this process alone ("Cannot serialize host local
+    jax.Array"), which is what a Horovod rank's parameters are: every
+    rank holds a replica on its own chip. Such a leaf goes in as the
+    host's copy, which orbax's primary process writes. Arrays sharded
+    over the processes stay arrays: every process writes its shards.
+    """
     import numpy as np
 
-    return jax.tree.map(
-        lambda x: np.asarray(x) if isinstance(x, np.generic) else x,
-        state)
+    one_process_a_rank = jax.process_count() > 1
+
+    def storable(x):
+        if isinstance(x, np.generic) or (
+                one_process_a_rank and isinstance(x, jax.Array)
+                and x.is_fully_addressable):
+            return np.asarray(x)
+        return x
+
+    return jax.tree.map(storable, state)
 
 
 def save(path, state, force=True, sync=False):
@@ -119,7 +137,7 @@ def save(path, state, force=True, sync=False):
         ocp = _ocp()
         with ocp.StandardCheckpointer() as cp:
             cp.save(os.path.abspath(os.fspath(path)),
-                    _sanitize_scalars(state), force=force)
+                    _storable(state), force=force)
     if sync and _basics.is_initialized() and _basics.size() > 1:
         from horovod_tpu.common import eager_ops
 
@@ -178,7 +196,7 @@ class CheckpointManager:
             return False
         ocp = _ocp()
         saved = self._mgr.save(
-            int(step), args=ocp.args.StandardSave(_sanitize_scalars(state)))
+            int(step), args=ocp.args.StandardSave(_storable(state)))
         if wait:
             self._mgr.wait_until_finished()
         return saved

@@ -3,14 +3,15 @@
 Every program that compiles for the chip (``chip_smoke.py`` children,
 ``bench.py``, ``benchmarks/*.py``) calls :func:`enable_compile_cache`
 before its first compile, so processes that follow one another — a
-smoke's phases, a launcher's ranks, a second run in the same checkout —
-find each other's executables instead of paying the compile again.
+smoke's phases, the ranks of a launcher's next run, a second run in the
+same checkout — find executables instead of paying the compile again.
 
 The directory is part of the cache key's lookup, so it must not move:
 ``JAX_COMPILATION_CACHE_DIR`` when the environment sets it (JAX reads
 that variable itself; no directory is set in code then), otherwise the
 fixed ``<checkout>/.jax_cache`` (git-ignored) — never a temp name, a pid
-or a timestamp. Tests never call this: they keep the cache off.
+or a timestamp. Tests keep the cache off (the one that checks the ranks'
+entries switches it on in child processes, in a temporary directory).
 
 One more thing has to hold still for the cache to hit: a pallas kernel
 is serialized into its program together with the FULL Python traceback
@@ -20,6 +21,34 @@ call site after any edit that moves a line in any frame above it
 (measured on the v5e, PR 21: three ~37 s compiles of one grad program in
 one smoke run). Locations are therefore cut to the frame of the op.
 
+Who writes what, where (one directory, every process a writer):
+
+- jax 0.9.0 writes an entry only in the process whose ``jax.distributed``
+  process id is 0 (``jax/_src/compiler.py:_cache_write``: "contention
+  for writes on some filesystems"); any process reads. The ranks of a
+  multi-process job (``horovodrun --tpu-pod``; ``xla_ici.enable()``
+  gives rank r process id r) would compile again on every launch, all
+  but rank 0. :func:`enable_compile_cache` therefore makes every
+  process a writer (:func:`_let_every_rank_write`, which says what it
+  leans on). ``jax.distributed`` itself, and its client, which orbax's
+  multi-process checkpoints need, stay as jax has them.
+- A program that runs on one chip holds that chip in its key (jax strips
+  the device assignment from the key on ``gpu`` alone), so four ranks
+  keep four copies of the grad and apply programs, each found again only
+  by the rank on the same chip. One copy for all would need an
+  executable that is not bound to a device. The multi-process program
+  (``jit_hvd_allreduce``) has one key on all ranks; all four write it.
+- Two writers of one key: with a maximum size set
+  (``JAX_COMPILATION_CACHE_MAX_SIZE``, as the chip machine does) jax's
+  ``LRUCache`` takes a file lock around every read and write, and
+  ``put`` skips a key that exists: the first writer wins and nobody
+  reads a half-written file. With no maximum there is no lock, and a
+  reader can meet a torn entry; jax then warns and compiles
+  (``jax_raise_persistent_cache_errors`` is off by default and nothing
+  here turns it on): slower once, never wrong.
+- Past the maximum size jax evicts the entries read longest ago, and
+  their ranks' next launch compiles again. Measured sizes: PERF.md.
+
 What the cache did for this process is counted here too
 (:func:`compile_stats`): jax reports every trip through its compile path
 and every cache hit and miss to listeners, and
@@ -27,8 +56,13 @@ and every cache hit and miss to listeners, and
 point that switches the cache on has the counter.
 """
 
+import inspect
+import logging
 import os
 import threading
+import warnings
+
+logger = logging.getLogger(__name__)
 
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 _RETRIEVAL_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
@@ -75,6 +109,62 @@ def compile_stats():
             "compile_s": s["compile_path_s"] - s["cache_retrieval_s"],
             "cache_retrieval_s": s["cache_retrieval_s"]}
 
+
+def _let_every_rank_write():
+    """Make this process a writer of the persistent cache whatever its
+    ``jax.distributed`` process id, without touching that id.
+
+    Leans on two private functions of jax 0.9.0, looked up by name and
+    arguments: ``compiler._cache_write``, which the compile path calls
+    through its module after every miss and which returns at once where
+    the process id is not 0, and ``compilation_cache
+    .put_executable_and_time``, which does the writing. Process 0, and
+    whatever jax would not write anywhere (host callbacks, a compile
+    under ``jax_persistent_cache_min_compile_time_secs``), stay with
+    jax's function. Where either looks different, jax is left as it is
+    and the rank's log says so.
+    """
+    import jax
+    from jax._src import compilation_cache, compiler, distributed
+
+    jax_write = getattr(compiler, "_cache_write", None)
+    put = getattr(compilation_cache, "put_executable_and_time", None)
+    try:
+        seam = [list(inspect.signature(jax_write).parameters),
+                list(inspect.signature(put).parameters)]
+    except (TypeError, ValueError):   # gone, or not a function
+        seam = None
+    if seam != [["cache_key", "compile_time_secs", "module_name", "backend",
+                 "executable", "host_callbacks"],
+                ["cache_key", "module_name", "executable", "backend",
+                 "compile_time"]]:
+        logger.warning(
+            "jax %s: the persistent cache's writer is not the one this "
+            "module knows (jax 0.9.0); ranks other than 0 will not write "
+            "compile-cache entries and compile again on every launch",
+            jax.__version__)
+        return
+
+    def write_on_every_rank(cache_key, compile_time_secs, module_name,
+                            backend, executable, host_callbacks):
+        if (distributed.global_state.process_id == 0 or host_callbacks
+                or compile_time_secs
+                < jax.config.jax_persistent_cache_min_compile_time_secs):
+            return jax_write(cache_key, compile_time_secs, module_name,
+                             backend, executable, host_callbacks)
+        try:
+            put(cache_key, module_name, executable, backend,
+                int(compile_time_secs))
+        except Exception as ex:  # noqa: BLE001 — as jax's writer does
+            if jax.config.jax_raise_persistent_cache_errors:
+                raise
+            warnings.warn(
+                "Error writing persistent compilation cache entry for "
+                f"'{module_name}': {type(ex).__name__}: {ex}")
+
+    compiler._cache_write = write_on_every_rank
+
+
 # <checkout>/.jax_cache: this file is <checkout>/horovod_tpu/utils/.
 CHECKOUT_CACHE_DIR = os.path.join(
     os.path.dirname(os.path.dirname(os.path.dirname(
@@ -96,6 +186,7 @@ def enable_compile_cache():
     if listen:
         jax.monitoring.register_event_listener(_on_event)
         jax.monitoring.register_event_duration_secs_listener(_on_duration)
+        _let_every_rank_write()
     jax.config.update("jax_include_full_tracebacks_in_locations", False)
     placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if placed:
