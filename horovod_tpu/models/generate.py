@@ -39,7 +39,12 @@ import jax.numpy as jnp
 from jax import lax
 
 from horovod_tpu.models.llama import _ffn as _llama_ffn
-from horovod_tpu.models.llama import _rmsnorm, _rope, moe_route
+from horovod_tpu.models.llama import (
+    _project_qkv,
+    _rmsnorm,
+    _rope,
+    moe_route,
+)
 
 
 def _ffn(h, lp, c):
@@ -60,6 +65,7 @@ def _moe_ffn_topk(h, lp, c):
     but E/K-times wasteful for a single decoded token). Routing (same
     router, same gate normalization) matches llama._moe_ffn; a single
     token can never overflow per-expert capacity, so no drop divergence.
+    ``c.norm_topk_prob`` is honoured through the same moe_route.
 
     The gathers materialize one [K,D,F]-sized weight copy per token, so
     this path only wins while B*T*K < E — _decode_ffn falls back to the
@@ -68,7 +74,8 @@ def _moe_ffn_topk(h, lp, c):
     """
     dt = c.compute_dtype
     K = c.n_experts_per_token
-    gate_vals, gate_idx, _aux = moe_route(h, lp["router"], K)  # [B,T,K]
+    gate_vals, gate_idx, _aux = moe_route(h, lp["router"], K,
+                                          c.norm_topk_prob)    # [B,T,K]
     wg = lp["moe_gate"].astype(dt)[gate_idx]                # [B,T,K,D,F]
     wu = lp["moe_up"].astype(dt)[gate_idx]
     wd = lp["moe_down"].astype(dt)[gate_idx]                # [B,T,K,F,D]
@@ -90,13 +97,13 @@ def _decode_ffn(h, lp, c):
     return _ffn(h, lp, c)
 
 
-def _layer_kv(h, lp, c, positions):
-    """Project h -> rope'd (k, v) for one layer. h [B,T,D] normalized."""
-    dt = c.compute_dtype
-    b, t = h.shape[0], h.shape[1]
-    k = (h @ lp["wk"].astype(dt)).reshape(b, t, c.n_kv_heads, c.head_dim)
-    v = (h @ lp["wv"].astype(dt)).reshape(b, t, c.n_kv_heads, c.head_dim)
-    return _rope(k, positions, c.rope_theta), v
+def _layer_qkv(h, lp, c, positions):
+    """Project h [B,T,D] (normalized) -> rope'd q, rope'd k, and v for
+    one layer, through llama.py's own projection (q/k norms included
+    where the config has them), so decode cannot drift from training."""
+    q, k, v = _project_qkv(h, lp, c)
+    return (_rope(q, positions, c.rope_theta),
+            _rope(k, positions, c.rope_theta), v)
 
 
 def _decode_attention(q, cache_k, cache_v, pos):
@@ -134,9 +141,7 @@ def _attend_step(x, lp, c, cache_k, cache_v, li, pos):
     # attention/FFN boundaries that need it.
     positions = jnp.broadcast_to(pos, (b, 1))
     h = _rmsnorm(x, lp["attn_norm"].astype(dt), c.norm_eps)
-    q = (h @ lp["wq"].astype(dt)).reshape(b, 1, c.n_heads, c.head_dim)
-    q = _rope(q, positions, c.rope_theta)
-    k_new, v_new = _layer_kv(h[:, None, :], lp, c, positions)
+    q, k_new, v_new = _layer_qkv(h[:, None, :], lp, c, positions)
     # Caches live heads-major [L, B, Hkv, S, D] (the attention-kernel
     # layout); the new token's [B, 1, Hkv, D] projects to [B, Hkv, 1, D].
     cache_k = lax.dynamic_update_slice(
@@ -167,9 +172,7 @@ def _prefill(params, prompt, c, pad_to):
 
     def prefill_layer(x, lp):
         h = _rmsnorm(x, lp["attn_norm"].astype(dt), c.norm_eps)
-        q = (h @ lp["wq"].astype(dt)).reshape(b, t0, c.n_heads, c.head_dim)
-        q = _rope(q, positions, c.rope_theta)
-        k, v = _layer_kv(h, lp, c, positions)
+        q, k, v = _layer_qkv(h, lp, c, positions)
         # Flash kernel (not blockwise): a long prompt must not
         # materialize the [B,H,T,T] score tensor.
         from horovod_tpu.ops import flash_attention
@@ -247,10 +250,7 @@ def llama_decode_step(params, tokens, cache_k, cache_v, lengths, config,
     def layer(x, xs):
         lp, ck, cv, ks, vs = xs
         h = _rmsnorm(x, lp["attn_norm"].astype(dt), c.norm_eps)
-        q = (h @ lp["wq"].astype(dt)).reshape(b, 1, c.n_heads,
-                                              c.head_dim)
-        q = _rope(q, positions, c.rope_theta)
-        k_new, v_new = _layer_kv(h[:, None, :], lp, c, positions)
+        q, k_new, v_new = _layer_qkv(h[:, None, :], lp, c, positions)
         attn = decode_attention_ragged(
             q, ck, cv, lengths,
             k_new.transpose(0, 2, 1, 3), v_new.transpose(0, 2, 1, 3),

@@ -59,6 +59,15 @@ class LlamaConfig:
     n_experts_per_token: int = 2
     capacity_factor: float = 1.25
     moe_aux_weight: float = 0.01
+    # Renormalise the K chosen router probabilities to sum to 1
+    # (mixtral) or use them as the softmax gave them (OLMoE publishes
+    # ``norm_topk_prob: false``).
+    norm_topk_prob: bool = True
+    # RMSNorm of the q and k projections, each over its WHOLE projected
+    # width (one gain vector per projection and layer), before the split
+    # into heads and RoPE: OLMoE's attention. Adds the ``q_norm`` /
+    # ``k_norm`` leaves; off, the parameter tree has neither.
+    qk_norm: bool = False
     # Expert dispatch implementation: "grouped" = dropless sorted
     # grouped-GEMM (megablox; no capacity padding, no one-hot dispatch
     # einsums, no dropped tokens — fastest on a single program),
@@ -180,6 +189,9 @@ def llama_init(config, key):
                     c.n_heads * hd),
         "mlp_norm": jnp.ones((L, c.d_model), pd),
     }
+    if c.qk_norm:
+        layers["q_norm"] = jnp.ones((L, c.n_heads * hd), pd)
+        layers["k_norm"] = jnp.ones((L, c.n_kv_heads * hd), pd)
     if c.n_experts > 0:
         E = c.n_experts
         layers.update({
@@ -217,6 +229,8 @@ def llama_partition_rules(pipeline=False):
     lead = "pipe" if pipeline else None
     return [
         (r"embed", P(("tensor", "fsdp"), None)),
+        # attn_norm, mlp_norm and, where the config has them, q_norm and
+        # k_norm: a gain vector per layer, replicated.
         (r"layers/.*norm", P(lead, None)),
         (r"layers/w[qkv]$", P(lead, "fsdp", "tensor")),
         (r"layers/wo", P(lead, "tensor", "fsdp")),
@@ -294,27 +308,64 @@ def _activation_spec(mesh):
     return P(("data", "fsdp"), "seq", None)
 
 
-def moe_route(h, router_w, n_experts_per_token):
-    """The ONE router: f32 logits matmul, softmax, top-K, epsilon-
-    guarded gate normalization, and the Switch load-balancing aux loss
-    (E * <fraction top-1 routed to e> . <mean prob of e>, minimized =1
-    at uniform routing). Shared by the GShard dispatch below, the
+def _head_proj(h, w, gain, c):
+    """``h [..., D] @ w`` split into heads ``[..., heads, head_dim]``;
+    with a ``gain`` (``qk_norm``) the RMSNorm over the whole projected
+    width comes first. The ONE q/k/v projection of training, prefill and
+    cached decode (models/generate.py)."""
+    y = h @ w.astype(c.compute_dtype)
+    if gain is not None:
+        y = _rmsnorm(y, gain.astype(c.compute_dtype), c.norm_eps)
+    return y.reshape(*y.shape[:-1], -1, c.head_dim)
+
+
+def _project_qkv(h, lp, c):
+    """Normalized ``h`` -> (q, k, v) in heads, BEFORE RoPE."""
+    return (_head_proj(h, lp["wq"], lp["q_norm"] if c.qk_norm else None, c),
+            _head_proj(h, lp["wk"], lp["k_norm"] if c.qk_norm else None, c),
+            _head_proj(h, lp["wv"], None, c))
+
+
+def moe_route(h, router_w, n_experts_per_token, norm_topk_prob=True):
+    """The ONE router: f32 logits matmul, softmax, top-K, the K
+    probabilities renormalised (epsilon-guarded) where ``norm_topk_prob``
+    and as the softmax gave them where not, and the load-balancing
+    statistics of these tokens. Shared by the GShard dispatch below, the
     dropless grouped dispatch (ops/grouped_moe.py), and cached decode
     (models/generate.py) so the three can never drift.
 
-    ``h`` is [..., D] with any leading shape; returns
-    (gate_vals [..., K] f32-normalized, gate_idx [..., K] int32, aux).
+    ``h`` is [..., D] with any leading shape; returns (gate_vals
+    [..., K] f32, gate_idx [..., K] int32, balance [2, E] f32): row 0
+    the share of tokens that chose expert e, summed over all K choices
+    (data: no gradient), row 1 the mean router probability of e.
+    :func:`moe_balance_loss` turns them into the aux term.
     """
     E = router_w.shape[-1]
     logits = h.astype(jnp.float32) @ router_w.astype(jnp.float32)
     probs = jax.nn.softmax(logits, axis=-1)                # [..., E]
     gate_vals, gate_idx = lax.top_k(probs, n_experts_per_token)
-    gate_vals = gate_vals / jnp.maximum(
-        gate_vals.sum(-1, keepdims=True), 1e-9)
+    if norm_topk_prob:
+        gate_vals = gate_vals / jnp.maximum(
+            gate_vals.sum(-1, keepdims=True), 1e-9)
     lead = tuple(range(probs.ndim - 1))
-    top1 = jax.nn.one_hot(gate_idx[..., 0], E, dtype=jnp.float32)
-    aux = E * jnp.sum(top1.mean(lead) * probs.mean(lead))
-    return gate_vals, gate_idx, aux
+    chosen = jax.nn.one_hot(gate_idx, E, dtype=jnp.float32).sum(-2)
+    return gate_vals, gate_idx, jnp.stack([chosen.mean(lead),
+                                           probs.mean(lead)])
+
+
+def moe_balance_loss(balance):
+    """The load-balancing aux term from :func:`moe_route`'s statistics,
+    pooled over every leading axis of ``balance [..., 2, E]``:
+    ``E * sum_e <share of tokens that chose e> * <mean probability of
+    e>``, all K choices counted (Hugging Face's
+    ``load_balancing_loss_func``; K at perfectly uniform routing, E at
+    total collapse). Layers see equally many tokens, so pooling the
+    stacked per-layer statistics is pooling all layers' tokens, as the
+    published loss does. A pipeline stage cannot see another stage's or
+    microbatch's tokens and applies it per layer and microbatch. Dense
+    layers carry zero-width statistics: 0."""
+    pooled = balance.mean(tuple(range(balance.ndim - 2)))
+    return balance.shape[-1] * jnp.sum(pooled[0] * pooled[1])
 
 
 def _moe_ffn(h, lp, c, mesh):
@@ -330,13 +381,14 @@ def _moe_ffn(h, lp, c, mesh):
     constraint so GSPMD inserts the token all-to-alls — the TPU analog
     of expert-parallel dispatch. Reference analog: none (Horovod has no
     MoE); design follows the GShard/Switch public formulation.
-    Returns (out [B,T,D], aux loss).
+    Returns (out [B,T,D], moe_route's balance statistics).
     """
     B, T, D = h.shape
     E, K = c.n_experts, c.n_experts_per_token
     C = max(int(T * K * c.capacity_factor / E), 1)
 
-    gate_vals, gate_idx, aux = moe_route(h, lp["router"], K)  # [B,T,K]
+    gate_vals, gate_idx, aux = moe_route(h, lp["router"], K,
+                                         c.norm_topk_prob)     # [B,T,K]
 
     # Position of each (token, slot) in its expert's per-group capacity
     # buffer, filling slot 0 for every token before slot 1 (priority to
@@ -384,7 +436,9 @@ def _moe_ffn(h, lp, c, mesh):
 
 def _ffn(h, lp, c, mesh=None):
     """One layer's FFN on normalized activations: dense siglu MLP, or
-    top-k expert routing for MoE configs. Returns (y, aux_loss).
+    top-k expert routing for MoE configs. Returns (y, balance): the
+    router's load-balancing statistics [2, E], zero-width for a dense
+    layer (see moe_balance_loss).
     Shared by llama_forward and the cached decode path (generate.py) so
     the two can never diverge."""
     dt = c.compute_dtype
@@ -407,7 +461,7 @@ def _ffn(h, lp, c, mesh=None):
     gate_pre = checkpoint_name(h @ lp["w_gate"].astype(dt), "ffn_gate")
     up = checkpoint_name(h @ lp["w_up"].astype(dt), "ffn_up")
     return ((jax.nn.silu(gate_pre) * up) @ lp["w_down"].astype(dt),
-            jnp.zeros((), jnp.float32))
+            jnp.zeros((2, 0), jnp.float32))
 
 
 def llama_forward(params, tokens, config, mesh=None, seq_axis="seq",
@@ -417,8 +471,9 @@ def llama_forward(params, tokens, config, mesh=None, seq_axis="seq",
     Under jit with a mesh, activations get sharding constraints so GSPMD
     lays out batch over data/fsdp and sequence over seq; the attention op
     switches to ring attention when seq parallelism is active. With
-    ``return_aux`` the MoE load-balancing loss (mean over layers; 0 for
-    dense configs) is returned alongside the logits.
+    ``return_aux`` the MoE load-balancing loss (moe_balance_loss over
+    all layers' tokens; 0 for dense configs) is returned alongside the
+    logits.
     """
     c = config
     dt = c.compute_dtype
@@ -460,9 +515,9 @@ def llama_forward(params, tokens, config, mesh=None, seq_axis="seq",
         x = ys.reshape(b, t, x.shape[-1])
         aux = aux_total / (c.n_layers * M)
     else:
-        x, aux_per_layer = lax.scan(body, x, params["layers"],
-                                    unroll=c.scan_unroll)
-        aux = jnp.mean(aux_per_layer)
+        x, balance = lax.scan(body, x, params["layers"],
+                              unroll=c.scan_unroll)
+        aux = moe_balance_loss(balance)
 
     x = _rmsnorm(x, params["final_norm"].astype(dt), c.norm_eps)
     # bf16 operands, f32 accumulation: full MXU rate without giving up
@@ -474,6 +529,18 @@ def llama_forward(params, tokens, config, mesh=None, seq_axis="seq",
     return logits
 
 
+def llama_expert_load(params, tokens, config):
+    """How the router spread ``tokens`` [B, T] over the experts:
+    [n_layers, n_experts] float32, the number of tokens that chose expert
+    e at each layer, all K choices counted (a row sums to B*T*K: the
+    grouped dispatch computes every one of them). From the layers' own
+    ``moe_route`` statistics, single program, no mesh."""
+    x = params["embed"].astype(config.compute_dtype)[tokens]
+    _, balance = lax.scan(_build_layer_body(config, None, None), x,
+                          params["layers"])
+    return balance[:, 0] * tokens.size
+
+
 def _constrain(x, mesh):
     if mesh is None:
         return x
@@ -483,10 +550,11 @@ def _constrain(x, mesh):
 
 def _stage_scan(body):
     """One pipeline stage = a scan of ``body`` over its layer block
-    (shared by the gpipe and 1f1b paths)."""
+    (shared by the gpipe and 1f1b paths); its aux is the sum of the
+    block's per-layer balance losses on this microbatch."""
     def stage_fn(lp_stage, x_mb):
-        x_out, aux_layers = lax.scan(body, x_mb, lp_stage)
-        return x_out, jnp.sum(aux_layers)
+        x_out, balance = lax.scan(body, x_mb, lp_stage)
+        return x_out, jnp.sum(jax.vmap(moe_balance_loss)(balance))
     return stage_fn
 
 
@@ -539,11 +607,7 @@ def _build_layer_body(c, mesh, seq_axis, constrain_acts=True):
         bb, tt = x.shape[0], x.shape[1]
         positions = jnp.broadcast_to(jnp.arange(tt), (bb, tt))
         h = _rmsnorm(x, lp["attn_norm"].astype(dt), c.norm_eps)
-        q = (h @ lp["wq"].astype(dt)).reshape(bb, tt, c.n_heads, c.head_dim)
-        kk = (h @ lp["wk"].astype(dt)).reshape(bb, tt, c.n_kv_heads,
-                                               c.head_dim)
-        vv = (h @ lp["wv"].astype(dt)).reshape(bb, tt, c.n_kv_heads,
-                                               c.head_dim)
+        q, kk, vv = _project_qkv(h, lp, c)
         # Named for remat="attn+gate+qkv": saving the POST-rope q/k and
         # v ([B,T,H(kv),D] bf16 — ~67 MB/layer at bench shapes) lets
         # backward skip the wq/wk/wv matmul + rope re-runs entirely

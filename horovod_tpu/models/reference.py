@@ -1,0 +1,132 @@
+"""Plain float32 references of the architectures the llama path runs
+beyond the dense decoder. What the program's kernels, sorts, scans and
+remat modes are compared against (tests/single/test_olmoe_reference.py;
+the chip benchmark keeps a copy of its own, chipbench/models/olmoe.py).
+
+OLMoE (arXiv:2409.02060; Hugging Face ``modeling_olmoe.py``), as
+published:
+
+- pre-norm residual block, ``x += Attn(RMSNorm(x))``,
+  ``x += MoE(RMSNorm(x))``;
+- attention: ``q = RMSNorm_q(W_q h)``, ``k = RMSNorm_k(W_k h)``, each
+  norm over the WHOLE projected width, before the split into heads;
+  half-split RoPE; causal ``softmax(q k / sqrt(head_dim)) v``; ``W_o``;
+- experts: router logits ``W_r h``, softmax in float32 over all
+  experts, the K largest probabilities and their experts, renormalised
+  only where ``norm_topk_prob`` (OLMoE: not),
+  ``y = sum_k p_k W_down,k(silu(W_gate,k h) * W_up,k h)``;
+- loss: token cross-entropy + ``moe_aux_weight`` x the load-balancing
+  term over ALL layers' tokens pooled,
+  ``E * sum_{slot,e} f_{slot,e} P_e`` (``f`` the share of tokens whose
+  ``slot``-th choice is ``e``, ``P`` the mean probability of ``e``).
+
+Written to share nothing with ``models/llama.py`` or
+``ops/grouped_moe.py``: an explicit mask, a Python loop over layers,
+every expert computed for every token and weighted by its routing
+probability (zero for the experts not chosen), the K choices found by
+K arg-maxes; no kernel, no sort, no scan, no remat. It reads the
+program's parameter tree and the ``LlamaConfig`` fields as data.
+
+Departures from the published description:
+
+- the paper's router z-loss (coefficient 0.001) is not in the public
+  ``config.json`` and not in Hugging Face's loss: left out;
+- parameters stored in bf16 are read as float32 (exact); everything
+  after that is float32 at ``"highest"`` matmul precision;
+- Hugging Face weights the balance statistics by the attention mask
+  where one is given; here, as in the program, a loss mask leaves the
+  aux term alone: every position counts in it.
+"""
+
+import jax
+import jax.numpy as jnp
+
+F32 = jnp.float32
+
+
+def _rms(x, gain, eps):
+    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) \
+        * gain
+
+
+def _top_k_weights(probs, k, renormalise):
+    """probs [..., E] -> (weights [..., E]: the probability of each of
+    the k largest, 0 elsewhere; choices [..., k, E] one-hot)."""
+    n = probs.shape[-1]
+    left, weights, choices = probs, jnp.zeros_like(probs), []
+    for _ in range(k):
+        pick = jax.nn.one_hot(jnp.argmax(left, -1), n, dtype=F32)
+        choices.append(pick)
+        weights = weights + pick * probs
+        left = jnp.where(pick > 0, -1.0, left)
+    if renormalise:
+        weights = weights / jnp.sum(weights, -1, keepdims=True)
+    return weights, jnp.stack(choices, -2)
+
+
+def olmoe_forward(params, tokens, cfg):
+    """tokens [B, T] -> (logits [B, T, vocab] f32, aux): the published
+    forward pass and load-balancing term (see the module docstring)."""
+    hd = cfg.d_model // cfg.n_heads
+    rep = cfg.n_heads // cfg.n_kv_heads
+    b, t = tokens.shape
+    inv = cfg.rope_theta ** (-jnp.arange(0, hd // 2, dtype=F32)
+                             / (hd // 2))
+    ang = jnp.arange(t, dtype=F32)[:, None] * inv           # [T, hd/2]
+    cos, sin = jnp.cos(ang)[None, :, None], jnp.sin(ang)[None, :, None]
+
+    def rope(x):
+        x1, x2 = x[..., :hd // 2], x[..., hd // 2:]
+        return jnp.concatenate([x1 * cos - x2 * sin,
+                                x1 * sin + x2 * cos], -1)
+
+    mask = jnp.tril(jnp.ones((t, t), bool))
+    all_probs, all_choices = [], []
+    with jax.default_matmul_precision("highest"):
+        x = params["embed"].astype(F32)[tokens]
+        for i in range(cfg.n_layers):
+            lp = jax.tree.map(lambda w: w[i].astype(F32),
+                              params["layers"])
+            h = _rms(x, lp["attn_norm"], cfg.norm_eps)
+            q = _rms(h @ lp["wq"], lp["q_norm"], cfg.norm_eps)
+            k = _rms(h @ lp["wk"], lp["k_norm"], cfg.norm_eps)
+            q = rope(q.reshape(b, t, cfg.n_heads, hd))
+            k = rope(k.reshape(b, t, cfg.n_kv_heads, hd))
+            v = (h @ lp["wv"]).reshape(b, t, cfg.n_kv_heads, hd)
+            k, v = jnp.repeat(k, rep, 2), jnp.repeat(v, rep, 2)
+            s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / (hd ** 0.5)
+            p = jax.nn.softmax(jnp.where(mask, s, -jnp.inf), -1)
+            a = jnp.einsum("bhqk,bkhd->bqhd", p, v).reshape(b, t, -1)
+            x = x + a @ lp["wo"]
+
+            h = _rms(x, lp["mlp_norm"], cfg.norm_eps)
+            probs = jax.nn.softmax(h @ lp["router"], -1)     # [B,T,E]
+            weights, choices = _top_k_weights(
+                probs, cfg.n_experts_per_token, cfg.norm_topk_prob)
+            act = jax.nn.silu(jnp.einsum("btd,edf->btef", h,
+                                         lp["moe_gate"])) \
+                * jnp.einsum("btd,edf->btef", h, lp["moe_up"])
+            y = jnp.einsum("btef,efd->bted", act, lp["moe_down"])
+            x = x + jnp.einsum("bte,bted->btd", weights, y)
+            all_probs.append(probs)
+            all_choices.append(choices)
+        x = _rms(x, params["final_norm"].astype(F32), cfg.norm_eps)
+        logits = x @ params["lm_head"].astype(F32)
+    # All layers' tokens pooled, as load_balancing_loss_func does.
+    n = cfg.n_experts
+    f = jnp.mean(jnp.concatenate(all_choices, 0).reshape(
+        -1, cfg.n_experts_per_token, n), 0)                 # [K, E]
+    prob = jnp.mean(jnp.concatenate(all_probs, 0).reshape(-1, n), 0)
+    return logits, n * jnp.sum(f * prob[None, :])
+
+
+def olmoe_loss(params, batch, cfg):
+    """Token cross-entropy, the mean over the positions ``batch["mask"]``
+    keeps (all without one), + ``cfg.moe_aux_weight`` x the aux term;
+    ``jax.grad`` of this is the reference gradient."""
+    logits, aux = olmoe_forward(params, batch["tokens"], cfg)
+    logp = jax.nn.log_softmax(logits, -1)
+    nll = -jnp.take_along_axis(logp, batch["targets"][..., None],
+                               -1)[..., 0]
+    mask = batch.get("mask", jnp.ones_like(nll))
+    return jnp.sum(nll * mask) / jnp.sum(mask) + cfg.moe_aux_weight * aux

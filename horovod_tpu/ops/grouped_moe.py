@@ -185,10 +185,11 @@ def _grouped_mm(lhs, rhs, group_sizes):
 def grouped_moe_ffn(h, lp, c):
     """Dropless top-K routed expert FFN over ``h`` [B, T, D] with the
     layer params ``lp`` (router [D, E], moe_gate/moe_up [E, D, F],
-    moe_down [E, F, D]). Returns (out [B, T, D], aux loss) — the same
-    contract, router math, gate normalization, and Switch aux loss as
-    the GShard path (``models/llama.py:_moe_ffn``), with no capacity
-    dropping (every token-slot is computed).
+    moe_down [E, F, D]). Returns (out [B, T, D], balance statistics) —
+    the same contract, router math, gate normalization
+    (``c.norm_topk_prob``) and load-balancing statistics as the GShard
+    path (``models/llama.py:_moe_ffn``), with no capacity dropping
+    (every token-slot is computed).
     """
     B, T, D = h.shape
     E, K = c.n_experts, c.n_experts_per_token
@@ -196,11 +197,12 @@ def grouped_moe_ffn(h, lp, c):
     dt = c.compute_dtype
     hf = h.reshape(S, D)
 
-    # Shared router (llama.moe_route): identical math and aux value to
+    # Shared router (llama.moe_route): identical math and statistics to
     # the GShard path's (means over flat S == means over (B, T)).
     from horovod_tpu.models.llama import moe_route
 
-    gate_vals, gate_idx, aux = moe_route(hf, lp["router"], K)  # [S, K]
+    gate_vals, gate_idx, aux = moe_route(hf, lp["router"], K,
+                                         c.norm_topk_prob)     # [S, K]
 
     # Sort the S*K (token, k) slots by routed expert. Indices are data
     # (not differentiated); stop_gradient keeps the int chain out of
@@ -228,7 +230,7 @@ def grouped_moe_ffn(h, lp, c):
                            group_sizes)            # [S*K, D]
 
     # Un-permute to slot order (inverse-gather VJP) and combine with
-    # the normalized gate weights. Named for the "attn+moe" remat mode:
+    # the gate weights. Named for the "attn+moe" remat mode:
     # the router's combine-weight gradient needs y_slots (d gate_vals =
     # <dy, y_slots>), which is what forces the backward remat to re-run
     # the down-projection gmm — saving it trades [S*K, D] bf16 per

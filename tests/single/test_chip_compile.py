@@ -137,6 +137,18 @@ def _gmm(lhs, rhs, group_sizes):
                  (((16384, 2048), BF16), ((4, 2048, 4096), BF16),
                   ((4,), I32)),
                  id="megablox-gmm-moe-bench"),
+    # OLMoE-1B-7B's expert layer at the chip cell's size: 8192 tokens x
+    # 8 choices into 64 experts; gate/up [2048 -> 1024] and down
+    # [1024 -> 2048] straddle the 1024 tile, so each backward direction
+    # runs on its own clamp (ops/grouped_moe.py:_bwd_tilings).
+    pytest.param(_gmm,
+                 (((65536, 2048), BF16), ((64, 2048, 1024), BF16),
+                  ((64,), I32)),
+                 id="megablox-gmm-olmoe-gate-up"),
+    pytest.param(_gmm,
+                 (((65536, 1024), BF16), ((64, 1024, 2048), BF16),
+                  ((64,), I32)),
+                 id="megablox-gmm-olmoe-down"),
 ])
 def test_kernel_compiles_for_described_v5e(for_tpu, fn, shapes):
     assert "tpu_custom_call" in for_tpu(fn, *shapes)
