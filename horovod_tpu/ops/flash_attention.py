@@ -9,21 +9,38 @@ just the output and the per-row logsumexp.
 Kernel design (v5e-friendly):
 - layout [B, H, T, D]; 4-D grid over (batch, head, outer-block,
   inner-block) with the INNER loop as the last grid dimension, so
-  every operand is streamed block-by-block: VMEM residency is
-  O(block_q·block_k + (block_q+block_k)·D) — independent of sequence
-  length. (The round-3 kernels kept whole-(b,h) K/V or Q/dO slices
-  resident, which capped the single-chip backward at T≈4096 with a
-  scoped-VMEM compile error.)
+  q, k, v, do and the outputs are streamed block-by-block; the forward
+  holds O(block_q·block_k + (block_q+block_k)·D) of VMEM whatever the
+  sequence length. (The round-3 kernels kept whole-(b,h) K/V or Q/dO
+  slices resident, which capped the single-chip backward at T≈4096 with
+  a scoped-VMEM compile error.)
 - online-softmax / gradient accumulators are f32 VMEM scratch that
   persists across the inner grid steps; outputs are written on the
   last inner step. bf16 matmul inputs (MXU native),
   `preferred_element_type=f32`.
-- causal masking by global position iota; whole causally-irrelevant
-  blocks are skipped with `pl.when` (the block's DMA still streams,
-  but it costs bandwidth only — no MXU work).
-- backward = two kernels (dkv over kv-blocks with q streamed, dq over
-  q-blocks with kv streamed), the standard flash decomposition with
-  the saved logsumexp.
+- three kinds of score tile, forward and backward alike, told apart
+  from the blocks' GLOBAL positions (`_tile_kinds`; `tile_counts` says
+  how many of each a call has): SKIPPED, wholly above the diagonal
+  (`pl.when`: no MXU or VPU work, and no DMA either: its index maps
+  name the block of the nearest tile that runs, which is already
+  there); INTERIOR, wholly below it, computed with no iota, compare or
+  select; STRADDLING, masked by global position.
+  Causal at 1024 x 1024 blocks a (batch, head) at T = 4096 has
+  6 / 6 / 4 of them, at T = 8192 28 / 28 / 8; a non-causal call has
+  interior tiles only.
+- the softmax scale is applied to the [block_q, D] q tile, and to the
+  finished dq / dk accumulators, never to a [block_q, block_k] plane;
+  the zero-valid-key guard of the backward runs only where such a row
+  can exist (a bias; a straddling tile of a call with offsets).
+- backward = ONE kernel (`hvd_flash_bwd_fused`), grid (b, h, jk, iq):
+  per tile s, p = exp(s - lse), dp and ds are formed once and feed all
+  three products, dv += p^T do, dk += ds^T q, dq += ds k (5 matmuls;
+  the dkv + dq pair it replaced did 7 and the elementwise chain
+  twice). dk / dv accumulate per jk as in the forward's mirror image;
+  dq accumulates in an f32 VMEM scratch of the whole (b, h) slice,
+  [T, D] (2 MiB at T = 4096, 8 MiB at 16384), and each block of it is
+  cast and written while the last jk passes: no f32 dq in HBM, no
+  second pass. The kernel asks for its own VMEM (`_bwd_vmem_bytes`).
 
 Operands that live off-TPU take the XLA blockwise implementation
 (pallas interpret mode is too slow for real runs; CPU tests exercise the
@@ -52,65 +69,116 @@ _NEG = -1e30
 _INTERPRET = False
 
 
+def _tile_kinds(causal, q_first, block_q, kv_first, block_k):
+    """``(interior, straddling)`` of the score tile whose first GLOBAL
+    query row is ``q_first`` and whose first GLOBAL key is ``kv_first``.
+    Interior: the tile's last key is visible to its first row, so no
+    element is masked. Straddling: the diagonal crosses it. Neither: no
+    element is valid and the tile is skipped. One formula for Python
+    ints (``tile_counts``) and for traced scalars (the kernels, where
+    the bases are program ids plus, with offsets, SMEM values)."""
+    if not causal:
+        return True, False
+    kv_last = kv_first + block_k - 1
+    interior = kv_last <= q_first
+    straddling = (kv_first <= q_first + block_q - 1) & (kv_last > q_first)
+    return interior, straddling
+
+
+def tile_counts(t, tk, block_q, block_k, causal, q_offset=0, kv_offset=0):
+    """``(skipped, interior, straddling)`` score tiles of one (batch,
+    head) slice, by the predicate the kernels run on: what the forward
+    and the backward skip, run without a mask, and run with one. Causal
+    at 1024 x 1024 blocks: T = 4096 gives 6 / 6 / 4, T = 8192 gives
+    28 / 28 / 8; a non-causal call has interior tiles only."""
+    interior = straddling = 0
+    for iq in range(t // block_q):
+        for jk in range(tk // block_k):
+            inside, crossing = _tile_kinds(
+                causal, q_offset + iq * block_q, block_q,
+                kv_offset + jk * block_k, block_k)
+            interior += bool(inside)
+            straddling += bool(crossing)
+    n = (t // block_q) * (tk // block_k)
+    return n - interior - straddling, interior, straddling
+
+
+def _for_each_kind(causal, q_first, block_q, kv_first, block_k, update):
+    """Run ``update(masked)`` as one score tile needs it: not at all
+    (skipped), unmasked (interior), or masked (straddling)."""
+    interior, straddling = _tile_kinds(causal, q_first, block_q,
+                                       kv_first, block_k)
+    pl.when(interior)(functools.partial(update, False))
+    pl.when(straddling)(functools.partial(update, True))
+
+
+def _scores(qs, k, q_first, kv_first, masked, bias):
+    """Scores of one tile from the SCALED q tile: ``qs k^T`` in f32, the
+    causal mask only where the diagonal crosses the tile (``masked``;
+    ``q_first`` / ``kv_first`` are the GLOBAL positions of its first
+    row and first key), the per-key bias row where the call has one."""
+    s = jax.lax.dot_general(qs, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+    if masked:
+        # A column of row positions against a row of key positions: no
+        # [block_q, block_k] iota plane is ever built.
+        q_pos = q_first + lax.broadcasted_iota(
+            jnp.int32, (s.shape[0], 1), 0)
+        kv_pos = kv_first + lax.broadcasted_iota(
+            jnp.int32, (1, s.shape[1]), 1)
+        s = jnp.where(q_pos >= kv_pos, s, _NEG)
+    if bias is not None:
+        s = s + bias
+    return s
+
+
+def _scaled(q, scale):
+    # The softmax scale goes onto the [block_q, D] q tile, not onto the
+    # [block_q, block_k] score plane (one rounding to the operand dtype,
+    # the same tile in the forward and the backward).
+    return (q.astype(jnp.float32) * scale).astype(q.dtype)
+
+
 def _fwd_kernel(*refs, scale, causal, has_bias, has_offsets):
     # refs = ([offs_ref,] q_ref, k_ref, v_ref, [bias_ref,] o_ref,
-    # lse_ref, acc_ref, m_ref, l_ref). grid = (b, h, iq, jj): q/o/lse
-    # blocks are keyed by iq (constant across the inner jj steps), k/v
-    # stream per jj; the online-softmax state lives in f32 VMEM scratch
-    # persisted across jj and the output is written on the last step.
+    # lse_ref, qs_ref, acc_ref, m_ref, l_ref). grid = (b, h, iq, jj):
+    # q/o/lse blocks are keyed by iq (constant across the inner jj
+    # steps), k/v stream per jj; the scaled q tile and the
+    # online-softmax state live in VMEM scratch persisted across jj and
+    # the output is written on the last step.
     # bias is a per-key additive f32 row [1, Tk] (padding masks).
     # offs_ref is an SMEM int32 [2] = (q_offset, kv_offset): GLOBAL
     # positions for causal masking when the call sees only a chunk of
     # the sequence (ring attention steps) — dynamic, so one compiled
     # kernel serves every ring step.
     if has_offsets:
-        offs_ref, q_ref, k_ref, v_ref, *rest = refs
-    else:
-        (q_ref, k_ref, v_ref), rest = refs[:3], list(refs[3:])
-        offs_ref = None
-    if has_bias:
-        bias_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref = rest
-    else:
-        o_ref, lse_ref, acc_ref, m_ref, l_ref = rest
-        bias_ref = None
+        offs_ref, *refs = refs
+    q_ref, k_ref, v_ref, *rest = refs
+    bias_ref = rest.pop(0) if has_bias else None
+    o_ref, lse_ref, qs_ref, acc_ref, m_ref, l_ref = rest
     bq, d = q_ref.shape
     bk = k_ref.shape[0]
-    iq = pl.program_id(2)
     jj = pl.program_id(3)
     n_jj = pl.num_programs(3)
 
     @pl.when(jj == 0)
     def _init():
+        qs_ref[:, :] = _scaled(q_ref[:, :], scale)
         acc_ref[:, :] = jnp.zeros((bq, d), jnp.float32)
         m_ref[:, :] = jnp.full((bq, 1), _NEG, jnp.float32)
         l_ref[:, :] = jnp.zeros((bq, 1), jnp.float32)
 
-    q_base = offs_ref[0] if has_offsets else 0
-    kv_base = offs_ref[1] if has_offsets else 0
-    # Whole-block causal skip: the block's first GLOBAL kv position must
-    # not be past this q block's last GLOBAL row (with offsets the bases
-    # are scalar-prefetched SMEM values, so the predicate is dynamic —
-    # a causal ring's fully-future chunks cost zero matmuls).
-    relevant = True
-    if causal:
-        relevant = kv_base + jj * bk <= q_base + (iq + 1) * bq - 1
+    # Three kinds of tile by GLOBAL position (with offsets the bases are
+    # scalar-prefetched SMEM values, so the predicates are dynamic — a
+    # causal ring's fully-future chunks cost zero matmuls, its
+    # fully-past chunks no mask).
+    q_first = (offs_ref[0] if has_offsets else 0) + pl.program_id(2) * bq
+    kv_first = (offs_ref[1] if has_offsets else 0) + jj * bk
 
-    @pl.when(relevant)
-    def _update():
-        q = q_ref[:, :]
-        k_blk = k_ref[:, :]
+    def update(masked):
         v_blk = v_ref[:, :]
-        s = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        if causal:
-            q_pos = q_base + iq * bq + lax.broadcasted_iota(
-                jnp.int32, (bq, bk), 0)
-            kv_pos = kv_base + jj * bk + lax.broadcasted_iota(
-                jnp.int32, (bq, bk), 1)
-            s = jnp.where(q_pos >= kv_pos, s, _NEG)
-        if has_bias:
-            s = s + bias_ref[:, :]
+        s = _scores(qs_ref[:, :], k_ref[:, :], q_first, kv_first, masked,
+                    bias_ref[:, :] if has_bias else None)
         m = m_ref[:, :]
         m_new = jnp.maximum(m, s.max(axis=1, keepdims=True))
         p = jnp.exp(s - m_new)
@@ -120,6 +188,8 @@ def _fwd_kernel(*refs, scale, causal, has_bias, has_offsets):
             p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         m_ref[:, :] = m_new
+
+    _for_each_kind(causal, q_first, bq, kv_first, bk, update)
 
     @pl.when(jj == n_jj - 1)
     def _finish():
@@ -136,172 +206,111 @@ def _fwd_kernel(*refs, scale, causal, has_bias, has_offsets):
         lse_ref[:, :] = m_ref[:, :] + jnp.log(l)
 
 
-def _bwd_dkv_kernel(*refs, scale, causal, has_bias, has_offsets):
+def _bwd_kernel(*refs, scale, causal, has_bias, has_offsets):
+    # The whole backward in one pass: per score tile s, p, dp and ds are
+    # formed ONCE and feed all three products (5 matmuls).
     # grid = (b, h, jk, iq): k/v/dk/dv blocks are keyed by jk (constant
-    # across the inner iq steps), q/do/lse/delta stream per iq; dk/dv
-    # accumulate in f32 VMEM scratch and are written on the last step.
+    # across the inner iq steps), q/do/lse/delta stream per iq. dk/dv
+    # accumulate in f32 VMEM scratch per jk and are written on the last
+    # iq; dq accumulates in an f32 VMEM scratch of the whole (b, h)
+    # slice, [T // block_q, block_q, D] indexed by iq, and each of its
+    # blocks is cast and written while the last jk passes over it (the
+    # dq out block is keyed by iq only then, see _flash_bwd_impl).
     if has_offsets:
-        offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, \
-            *rest = refs
-    else:
-        q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest = refs
-        offs_ref = None
-    if has_bias:
-        bias_ref, dk_ref, dv_ref, dk_acc, dv_acc = rest
-    else:
-        dk_ref, dv_ref, dk_acc, dv_acc = rest
-        bias_ref = None
+        offs_ref, *refs = refs
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest = refs
+    bias_ref = rest.pop(0) if has_bias else None
+    dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc = rest
     bk, d = k_ref.shape
     bq = q_ref.shape[0]
     jk = pl.program_id(2)
     iq = pl.program_id(3)
-    n_iq = pl.num_programs(3)
 
     @pl.when(iq == 0)
-    def _init():
+    def _init_dkv():
         dk_acc[:, :] = jnp.zeros((bk, d), jnp.float32)
         dv_acc[:, :] = jnp.zeros((bk, d), jnp.float32)
 
-    q_base = offs_ref[0] if has_offsets else 0
-    kv_base = offs_ref[1] if has_offsets else 0
-    relevant = True
-    if causal:
-        # This q block contributes iff its last GLOBAL row reaches the
-        # kv block's first GLOBAL position.
-        relevant = q_base + (iq + 1) * bq - 1 >= kv_base + jk * bk
+    @pl.when(jk == 0)
+    def _init_dq():
+        dq_acc[iq] = jnp.zeros((bq, d), jnp.float32)
 
-    @pl.when(relevant)
-    def _update():
+    q_first = (offs_ref[0] if has_offsets else 0) + iq * bq
+    kv_first = (offs_ref[1] if has_offsets else 0) + jk * bk
+
+    def update(masked):
+        q = q_ref[:, :]
         k = k_ref[:, :]
-        v = v_ref[:, :]
-        qi = q_ref[:, :]
-        doi = do_ref[:, :]
-        lse = lse_ref[:, :]
-        delta = delta_ref[:, :]
-        s = jax.lax.dot_general(
-            qi, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        if causal:
-            q_pos = q_base + iq * bq + lax.broadcasted_iota(
-                jnp.int32, (bq, bk), 0)
-            kv_pos = kv_base + jk * bk + lax.broadcasted_iota(
-                jnp.int32, (bq, bk), 1)
-            s = jnp.where(q_pos >= kv_pos, s, _NEG)
-        if has_bias:
-            s = s + bias_ref[:, :]
-        # For a q row with ZERO valid keys lse is itself ~_NEG, so
-        # exp(s - lse) rounds to 1 per masked key — guard on s directly
-        # (valid rows are unaffected: their masked keys underflow to 0).
-        p = jnp.where(s > _NEG / 2, jnp.exp(s - lse), 0.0)  # [bq, bk]
+        do = do_ref[:, :]
+        s = _scores(_scaled(q, scale), k, q_first, kv_first, masked,
+                    bias_ref[:, :] if has_bias else None)
+        p = jnp.exp(s - lse_ref[:, :])  # [bq, bk]
+        if has_bias or (masked and has_offsets):
+            # A q row with ZERO valid keys (a padded batch row; a ring
+            # chunk that starts inside this q block) has lse ~_NEG
+            # itself, so exp(s - lse) rounds to 1 per masked key: guard
+            # on s directly. A plain causal call always has the
+            # diagonal key, and an interior tile no masked one.
+            p = jnp.where(s > _NEG / 2, p, 0.0)
         dv_acc[:, :] = dv_acc[:, :] + jax.lax.dot_general(
-            p.astype(doi.dtype), doi, (((0,), (0,)), ((), ())),
+            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         dp = jax.lax.dot_general(
-            doi, v, (((1,), (1,)), ((), ())),
+            do, v_ref[:, :], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * scale
+        # ds without the softmax scale: dq and dk take it once, as the
+        # accumulators are written.
+        ds = (p * (dp - delta_ref[:, :])).astype(q.dtype)
         dk_acc[:, :] = dk_acc[:, :] + jax.lax.dot_general(
-            ds.astype(qi.dtype), qi, (((0,), (0,)), ((), ())),
+            ds, q, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        dq_acc[iq] = dq_acc[iq] + jax.lax.dot_general(
+            ds, k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    @pl.when(iq == n_iq - 1)
-    def _finish():
-        dk_ref[:, :] = dk_acc[:, :].astype(dk_ref.dtype)
+    _for_each_kind(causal, q_first, bq, kv_first, bk, update)
+
+    @pl.when(iq == pl.num_programs(3) - 1)
+    def _finish_dkv():
+        dk_ref[:, :] = (dk_acc[:, :] * scale).astype(dk_ref.dtype)
         dv_ref[:, :] = dv_acc[:, :].astype(dv_ref.dtype)
 
-
-def _bwd_dq_kernel(*refs, scale, causal, has_bias, has_offsets):
-    # grid = (b, h, iq, jj): q/do/lse/delta/dq blocks are keyed by iq
-    # (constant across the inner jj steps), k/v stream per jj; dq
-    # accumulates in f32 VMEM scratch, written on the last step.
-    if has_offsets:
-        offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, \
-            *rest = refs
-    else:
-        q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest = refs
-        offs_ref = None
-    if has_bias:
-        bias_ref, dq_ref, dq_acc = rest
-    else:
-        dq_ref, dq_acc = rest
-        bias_ref = None
-    bq, d = q_ref.shape
-    bk = k_ref.shape[0]
-    iq = pl.program_id(2)
-    jj = pl.program_id(3)
-    n_jj = pl.num_programs(3)
-
-    @pl.when(jj == 0)
-    def _init():
-        dq_acc[:, :] = jnp.zeros((bq, d), jnp.float32)
-
-    q_base = offs_ref[0] if has_offsets else 0
-    kv_base = offs_ref[1] if has_offsets else 0
-    relevant = True
-    if causal:
-        relevant = kv_base + jj * bk <= q_base + (iq + 1) * bq - 1
-
-    @pl.when(relevant)
-    def _update():
-        q = q_ref[:, :]
-        do = do_ref[:, :]
-        lse = lse_ref[:, :]
-        delta = delta_ref[:, :]
-        k_blk = k_ref[:, :]
-        v_blk = v_ref[:, :]
-        s = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        if causal:
-            q_pos = q_base + iq * bq + lax.broadcasted_iota(
-                jnp.int32, (bq, bk), 0)
-            kv_pos = kv_base + jj * bk + lax.broadcasted_iota(
-                jnp.int32, (bq, bk), 1)
-            s = jnp.where(q_pos >= kv_pos, s, _NEG)
-        if has_bias:
-            s = s + bias_ref[:, :]
-        # Same zero-valid-key guard as the dkv kernel (see there).
-        p = jnp.where(s > _NEG / 2, jnp.exp(s - lse), 0.0)
-        dp = jax.lax.dot_general(
-            do, v_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * scale
-        dq_acc[:, :] = dq_acc[:, :] + jax.lax.dot_general(
-            ds.astype(k_blk.dtype), k_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-
-    @pl.when(jj == n_jj - 1)
-    def _finish():
-        dq_ref[:, :] = dq_acc[:, :].astype(dq_ref.dtype)
+    @pl.when(jk == pl.num_programs(2) - 1)
+    def _finish_dq():
+        dq_ref[:, :] = (dq_acc[iq] * scale).astype(dq_ref.dtype)
 
 
 def _pallas_dispatch(name, kernel, grid, in_specs, out_specs, out_shape,
-                     args, offsets, scratch_shapes):
+                     args, offsets, scratch_shapes, vmem_limit_bytes=None):
     """Shared fwd/bwd dispatch: plain grid, or scalar-prefetch grid
     spec when dynamic offsets ride along (the SMEM scalars arrive
     before the kernel body and every index map). ``scratch_shapes``
-    are the f32 VMEM accumulators that persist across the inner grid
-    dimension. ``name`` (``hvd_flash_fwd``, ``hvd_flash_bwd_dq``,
-    ``hvd_flash_bwd_dkv``) tells the three kernels apart in a device
-    trace: as ``metadata`` it rides in the custom call's
+    are the VMEM buffers that persist across the inner grid
+    dimensions. ``name`` (``hvd_flash_fwd``, ``hvd_flash_bwd_fused``)
+    tells the kernels apart in a device trace: as ``metadata`` it rides
+    in the custom call's
     ``frontend_attributes={kernel_metadata={"kernel":...}}``, which an
     op's event on the v5e shows (read off a chip trace, PR 25). The
     pallas ``name=`` would not: it names the instruction only while
     jax keeps full tracebacks in locations, and
-    ``enable_compile_cache()`` turns those off."""
-    metadata = {"kernel": name}
+    ``enable_compile_cache()`` turns those off. ``vmem_limit_bytes``:
+    what the kernel itself asks of VMEM, where the compiler's default
+    scope (16 MiB on the v5e) is not enough."""
+    common = dict(
+        out_shape=out_shape, interpret=_INTERPRET,
+        metadata={"kernel": name},
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_limit_bytes))
     if offsets is not None:
         return pl.pallas_call(
             kernel,
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=1, grid=grid, in_specs=in_specs,
                 out_specs=out_specs, scratch_shapes=scratch_shapes),
-            out_shape=out_shape, interpret=_INTERPRET, metadata=metadata,
-        )(offsets, *args)
+            **common)(offsets, *args)
     return pl.pallas_call(
         kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
-        out_shape=out_shape, interpret=_INTERPRET, metadata=metadata,
-        scratch_shapes=scratch_shapes)(*args)
+        scratch_shapes=scratch_shapes, **common)(*args)
 
 
 def _pick_block(t, want):
@@ -343,19 +352,33 @@ def _flash_fwd_impl(q, k, v, bias, causal, block_q, block_k,
                                has_offsets=has_offsets)
     # With scalar prefetch the index maps receive the scalar ref as a
     # trailing arg; *a soaks it up either way.
+    n_jj = tk // block_k
+
+    def live(qi, ji, a):
+        # A skipped tile (this kv block wholly after the q block) names
+        # the block of the last tile that runs: an unchanged block index
+        # starts no DMA, so the skip costs a grid step and nothing else.
+        if not causal:
+            return ji
+        q_base, kv_base = (a[0][0], a[0][1]) if a else (0, 0)
+        q_last = q_base + (qi + 1) * block_q - 1
+        return jnp.minimum(
+            ji, jnp.clip((q_last - kv_base) // block_k, 0, n_jj - 1))
+
+    kv_spec = pl.BlockSpec(
+        (None, None, block_k, d),
+        lambda bi, hi, qi, ji, *a: (bi, hi // n_rep, live(qi, ji, a), 0))
     in_specs = [
         pl.BlockSpec((None, None, block_q, d),
                      lambda bi, hi, qi, ji, *a: (bi, hi, qi, 0)),
-        pl.BlockSpec((None, None, block_k, d),
-                     lambda bi, hi, qi, ji, *a: (bi, hi // n_rep, ji, 0)),
-        pl.BlockSpec((None, None, block_k, d),
-                     lambda bi, hi, qi, ji, *a: (bi, hi // n_rep, ji, 0)),
+        kv_spec, kv_spec,
     ]
     args = [q, k, v]
     if has_bias:
         in_specs.append(
             pl.BlockSpec((None, 1, block_k),
-                         lambda bi, hi, qi, ji, *a: (bi, 0, ji)))
+                         lambda bi, hi, qi, ji, *a: (bi, 0,
+                                                     live(qi, ji, a))))
         args.append(bias)
     out_specs = [
         pl.BlockSpec((None, None, block_q, d),
@@ -368,6 +391,7 @@ def _flash_fwd_impl(q, k, v, bias, causal, block_q, block_k,
         jax.ShapeDtypeStruct((b, h, t, 1), jnp.float32),
     ]
     scratch = [
+        pltpu.VMEM((block_q, d), q.dtype),       # scaled q
         pltpu.VMEM((block_q, d), jnp.float32),   # acc
         pltpu.VMEM((block_q, 1), jnp.float32),   # m
         pltpu.VMEM((block_q, 1), jnp.float32),   # l
@@ -397,6 +421,18 @@ def _flash_biased_fwd(q, k, v, bias, causal, block_q, block_k):
     return o, (q, k, v, bias, o, lse)
 
 
+def _bwd_vmem_bytes(t, block_q, block_k, d, itemsize):
+    """VMEM the one-pass backward asks for: its blocks (the pipeline
+    holds each twice; a [block_q, 1] f32 row block fills whole 128-lane
+    tiles), its scratch (dq for the whole [T, D] slice, dk and dv for
+    one block) and room for the f32 / operand-dtype planes of one score
+    tile (s, p, dp, ds and their casts: six f32 planes' worth)."""
+    blocks = (3 * block_q + 4 * block_k) * d * itemsize \
+        + 2 * block_q * 128 * 4
+    scratch = (t + 2 * block_k) * d * 4
+    return 2 * blocks + scratch + 6 * block_q * block_k * 4
+
+
 def _flash_bwd_impl(q, k, v, bias, o, lse, do, causal, block_q, block_k,
                     offsets=None, dlse=None):
     b, h, t, d = q.shape
@@ -413,94 +449,71 @@ def _flash_bwd_impl(q, k, v, bias, o, lse, do, causal, block_q, block_k,
         # becomes p*(dp - delta + dlse), i.e. delta -= dlse.
         delta = delta - dlse.astype(jnp.float32)
 
-    def call(name, kernel, grid, in_specs, out_specs, out_shape, args,
-             scratch):
-        return _pallas_dispatch(name, kernel, grid, in_specs, out_specs,
-                                out_shape, args, offsets, scratch)
+    n_jk, n_iq = tk // block_k, t // block_q
+    kernel = functools.partial(_bwd_kernel, scale=scale, causal=causal,
+                               has_bias=has_bias, has_offsets=has_offsets)
+    # grid (b, h, jk, iq) — q/do/lse/delta stream over the inner iq
+    # dimension, k/v and the dk/dv accumulators stay pinned per jk.
 
-    # dkv: grid (b, h, jk, iq) — q/do/lse/delta stream over the inner
-    # iq dimension, k/v and the dk/dv accumulators stay pinned per jk.
-    dkv_kernel = functools.partial(_bwd_dkv_kernel, scale=scale,
-                                   causal=causal, has_bias=has_bias,
-                                   has_offsets=has_offsets)
-    in_specs = [
-        pl.BlockSpec((None, None, block_q, d),
-                     lambda bi, hi, jk, iq, *a: (bi, hi, iq, 0)),
-        pl.BlockSpec((None, None, block_k, d),
-                     lambda bi, hi, jk, iq, *a: (bi, hi // n_rep, jk, 0)),
-        pl.BlockSpec((None, None, block_k, d),
-                     lambda bi, hi, jk, iq, *a: (bi, hi // n_rep, jk, 0)),
-        pl.BlockSpec((None, None, block_q, d),
-                     lambda bi, hi, jk, iq, *a: (bi, hi, iq, 0)),
-        pl.BlockSpec((None, None, block_q, 1),
-                     lambda bi, hi, jk, iq, *a: (bi, hi, iq, 0)),
-        pl.BlockSpec((None, None, block_q, 1),
-                     lambda bi, hi, jk, iq, *a: (bi, hi, iq, 0)),
-    ]
+    def live(jk, iq, a):
+        # As in the forward: a skipped tile (this q block wholly before
+        # the kv block) names the block of the first tile that runs.
+        if not causal:
+            return iq
+        q_base, kv_base = (a[0][0], a[0][1]) if a else (0, 0)
+        return jnp.maximum(
+            iq, jnp.clip((kv_base + jk * block_k - q_base) // block_q,
+                         0, n_iq - 1))
+
+    q_spec = pl.BlockSpec(
+        (None, None, block_q, d),
+        lambda bi, hi, jk, iq, *a: (bi, hi, live(jk, iq, a), 0))
+    kv_spec = pl.BlockSpec(
+        (None, None, block_k, d),
+        lambda bi, hi, jk, iq, *a: (bi, hi // n_rep, jk, 0))
+    row_spec = pl.BlockSpec(
+        (None, None, block_q, 1),
+        lambda bi, hi, jk, iq, *a: (bi, hi, live(jk, iq, a), 0))
+    in_specs = [q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec]
     args = [q, k, v, do, lse, delta]
     if has_bias:
         in_specs.append(
             pl.BlockSpec((None, 1, block_k),
                          lambda bi, hi, jk, iq, *a: (bi, 0, jk)))
         args.append(bias)
+    # dq's out block follows iq only while the LAST jk passes (then each
+    # finished block is written, cast, as the next one is entered);
+    # before that it rests on block 0 and nothing is written back: no
+    # f32 dq and no second pass in HBM.
+    dq_spec = pl.BlockSpec(
+        (None, None, block_q, d),
+        lambda bi, hi, jk, iq, *a: (bi, hi,
+                                    jnp.where(jk == n_jk - 1, iq, 0), 0))
     # dk/dv come out PER QUERY HEAD ([B, H, Tk, D]); the sum over each
     # kv-head's n_rep sharing query heads happens outside the kernel
     # (one cheap XLA reduction — keeps the kernel free of cross-kv-head
     # accumulation state).
-    dk, dv = call(
-        "hvd_flash_bwd_dkv", dkv_kernel,
-        (b, h, tk // block_k, t // block_q), in_specs,
+    dkv_spec = pl.BlockSpec((None, None, block_k, d),
+                            lambda bi, hi, jk, iq, *a: (bi, hi, jk, 0))
+    dq, dk, dv = _pallas_dispatch(
+        "hvd_flash_bwd_fused", kernel, (b, h, n_jk, n_iq), in_specs,
+        [dq_spec, dkv_spec, dkv_spec],
         [
-            pl.BlockSpec((None, None, block_k, d),
-                         lambda bi, hi, jk, iq, *a: (bi, hi, jk, 0)),
-            pl.BlockSpec((None, None, block_k, d),
-                         lambda bi, hi, jk, iq, *a: (bi, hi, jk, 0)),
-        ],
-        [
+            jax.ShapeDtypeStruct(q.shape, q.dtype),
             jax.ShapeDtypeStruct((b, h, tk, d), k.dtype),
             jax.ShapeDtypeStruct((b, h, tk, d), v.dtype),
         ],
-        args,
-        [pltpu.VMEM((block_k, d), jnp.float32),
-         pltpu.VMEM((block_k, d), jnp.float32)])
+        args, offsets,
+        [pltpu.VMEM((n_iq, block_q, d), jnp.float32),
+         pltpu.VMEM((block_k, d), jnp.float32),
+         pltpu.VMEM((block_k, d), jnp.float32)],
+        vmem_limit_bytes=_bwd_vmem_bytes(t, block_q, block_k, d,
+                                         q.dtype.itemsize))
     if n_rep > 1:
         dk = dk.astype(jnp.float32).reshape(b, hkv, n_rep, tk, d) \
             .sum(axis=2).astype(k.dtype)
         dv = dv.astype(jnp.float32).reshape(b, hkv, n_rep, tk, d) \
             .sum(axis=2).astype(v.dtype)
-
-    # dq: grid (b, h, iq, jj) — k/v stream over the inner jj dimension,
-    # q/do/lse/delta and the dq accumulator stay pinned per iq.
-    dq_kernel = functools.partial(_bwd_dq_kernel, scale=scale,
-                                  causal=causal, has_bias=has_bias,
-                                  has_offsets=has_offsets)
-    in_specs = [
-        pl.BlockSpec((None, None, block_q, d),
-                     lambda bi, hi, qi, ji, *a: (bi, hi, qi, 0)),
-        pl.BlockSpec((None, None, block_k, d),
-                     lambda bi, hi, qi, ji, *a: (bi, hi // n_rep, ji, 0)),
-        pl.BlockSpec((None, None, block_k, d),
-                     lambda bi, hi, qi, ji, *a: (bi, hi // n_rep, ji, 0)),
-        pl.BlockSpec((None, None, block_q, d),
-                     lambda bi, hi, qi, ji, *a: (bi, hi, qi, 0)),
-        pl.BlockSpec((None, None, block_q, 1),
-                     lambda bi, hi, qi, ji, *a: (bi, hi, qi, 0)),
-        pl.BlockSpec((None, None, block_q, 1),
-                     lambda bi, hi, qi, ji, *a: (bi, hi, qi, 0)),
-    ]
-    args = [q, k, v, do, lse, delta]
-    if has_bias:
-        in_specs.append(
-            pl.BlockSpec((None, 1, block_k),
-                         lambda bi, hi, qi, ji, *a: (bi, 0, ji)))
-        args.append(bias)
-    dq = call(
-        "hvd_flash_bwd_dq", dq_kernel,
-        (b, h, t // block_q, tk // block_k), in_specs,
-        pl.BlockSpec((None, None, block_q, d),
-                     lambda bi, hi, qi, ji, *a: (bi, hi, qi, 0)),
-        jax.ShapeDtypeStruct(q.shape, q.dtype), args,
-        [pltpu.VMEM((block_q, d), jnp.float32)])
     return dq, dk, dv
 
 

@@ -302,3 +302,272 @@ def test_zero_valid_key_rows_zero_output_and_grads():
     for g, name in zip((dq, dk, dv), "qkv"):
         np.testing.assert_array_equal(
             np.asarray(g), 0.0, err_msg=f"d{name} leaked")
+
+
+# --- PR 28: one-pass backward, three kinds of tile ----------------------
+# Every case below runs at 3 x 3 tiles or more, so that skipped, interior
+# and straddling tiles all occur, and compares values and all three
+# gradients with reference math.
+
+def _chunk_ref(q, k, v, q_off, kv_off, causal=True):
+    """Reference for one chunk with GLOBAL positions: (o, lse, rows with
+    a valid key). A row without one has output 0 (its lse is not
+    compared: the kernel leaves it at ~-1e30)."""
+    d = q.shape[-1]
+    n_rep = q.shape[1] // k.shape[1]
+    k, v = jnp.repeat(k, n_rep, 1), jnp.repeat(v, n_rep, 1)
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) / (d ** 0.5)
+    mask = jnp.ones(s.shape[-2:], bool)
+    if causal:
+        mask = (q_off + jnp.arange(q.shape[2]))[:, None] \
+            >= (kv_off + jnp.arange(k.shape[2]))[None, :]
+    has_key = mask.any(-1)[None, None, :, None]
+    s = jnp.where(mask, s, -1e30)
+    o = jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, -1), v)
+    lse = jax.nn.logsumexp(s, -1, keepdims=True)
+    return jnp.where(has_key, o, 0.0), lse, has_key
+
+
+def _assert_grads_close(got, want):
+    for a, b, name in zip(got, want, "qkv"):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=5e-3, atol=5e-4,
+                                   err_msg=f"d{name} mismatch")
+
+
+@pytest.mark.parametrize("block_q,block_k", [(32, 16), (16, 32)])
+def test_unequal_blocks_values_and_grads(block_q, block_k):
+    q, k, v = _qkv(seed=3, T=96)
+    w = jax.random.normal(jax.random.PRNGKey(4), q.shape)
+    assert all(fa_mod.tile_counts(96, 96, block_q, block_k, True))
+
+    def f(*a):
+        return (fa_mod._flash(*a, True, block_q, block_k) * w).sum()
+
+    def fr(*a):
+        return (_ref(*a, causal=True) * w).sum()
+
+    np.testing.assert_allclose(
+        np.asarray(fa_mod._flash(q, k, v, True, block_q, block_k)),
+        np.asarray(_ref(q, k, v, causal=True)), rtol=2e-4, atol=2e-4)
+    _assert_grads_close(jax.grad(f, (0, 1, 2))(q, k, v),
+                        jax.grad(fr, (0, 1, 2))(q, k, v))
+
+
+@pytest.mark.parametrize("causal", [False, True])
+def test_gqa_four_query_heads_a_kv_head(causal):
+    B, T, H, HKV, D = 2, 48, 8, 2, 8
+    ks = jax.random.split(jax.random.PRNGKey(6), 4)
+    q = jax.random.normal(ks[0], (B, H, T, D), jnp.float32)
+    k = jax.random.normal(ks[1], (B, HKV, T, D), jnp.float32)
+    v = jax.random.normal(ks[2], (B, HKV, T, D), jnp.float32)
+    w = jax.random.normal(ks[3], q.shape)
+    assert fa_mod.tile_counts(T, T, 16, 16, causal) == \
+        ((3, 3, 3) if causal else (0, 9, 0))
+
+    def f(*a):
+        return (fa_mod._flash(*a, causal, 16, 16) * w).sum()
+
+    def fr(*a):
+        return (_chunk_ref(*a, 0, 0, causal)[0] * w).sum()
+
+    np.testing.assert_allclose(
+        np.asarray(fa_mod._flash(q, k, v, causal, 16, 16)),
+        np.asarray(_chunk_ref(q, k, v, 0, 0, causal)[0]),
+        rtol=2e-4, atol=2e-4)
+    _assert_grads_close(jax.grad(f, (0, 1, 2))(q, k, v),
+                        jax.grad(fr, (0, 1, 2))(q, k, v))
+
+
+# (q_offset, kv_offset) of a 48-row chunk against a 48-key chunk at
+# 16 x 16 blocks, with the tiles each has (skipped, interior, straddling).
+_CHUNKS = {"past": ((96, 0), (0, 9, 0)), "future": ((0, 96), (9, 0, 0)),
+           # keys 40..87 under rows 32..79: rows 32..39 have no key at
+           # all inside a tile that runs
+           "crossing": ((32, 40), (3, 1, 5))}
+
+
+@pytest.mark.parametrize("chunk", sorted(_CHUNKS))
+def test_offset_chunks_with_lse_cotangent(chunk):
+    (q_off, kv_off), tiles = _CHUNKS[chunk]
+    assert fa_mod.tile_counts(48, 48, 16, 16, True, q_off, kv_off) == tiles
+    B, T, H, HKV, D = 1, 48, 4, 2, 8
+    ks = jax.random.split(jax.random.PRNGKey(8), 5)
+    q = jax.random.normal(ks[0], (B, H, T, D), jnp.float32)
+    k = jax.random.normal(ks[1], (B, HKV, T, D), jnp.float32)
+    v = jax.random.normal(ks[2], (B, HKV, T, D), jnp.float32)
+    w = jax.random.normal(ks[3], q.shape)
+    u = jax.random.normal(ks[4], (B, H, T, 1))
+    has_key = _chunk_ref(q, k, v, q_off, kv_off)[2]
+
+    def run(*a):
+        # traced offsets, as a ring step passes them
+        return jax.jit(lambda qo, ko: fa_mod.flash_attention_chunk(
+            *a, qo, ko, causal=True, block_q=16, block_k=16))(q_off, kv_off)
+
+    def loss(fn):
+        def _l(*a):
+            o, lse = fn(*a)[:2]
+            return (o * w).sum() + (jnp.where(has_key, lse, 0.0) * u).sum()
+        return _l
+
+    o, lse = run(q, k, v)
+    o_ref, lse_ref, _ = _chunk_ref(q, k, v, q_off, kv_off)
+    np.testing.assert_allclose(np.asarray(o), np.asarray(o_ref),
+                               rtol=2e-4, atol=2e-4)
+    np.testing.assert_allclose(
+        np.asarray(jnp.where(has_key, lse, 0.0)),
+        np.asarray(jnp.where(has_key, lse_ref, 0.0)), rtol=2e-4, atol=2e-4)
+    assert np.all(np.asarray(lse)[~np.broadcast_to(has_key, lse.shape)]
+                  < -1e29)
+    got = jax.grad(loss(run), (0, 1, 2))(q, k, v)
+    want = jax.grad(loss(lambda *a: _chunk_ref(*a, q_off, kv_off)),
+                    (0, 1, 2))(q, k, v)
+    _assert_grads_close(got, want)
+    if chunk == "future":
+        for g in got:
+            np.testing.assert_array_equal(np.asarray(g), 0.0)
+
+
+@pytest.mark.parametrize("causal", [False, True])
+def test_bias_with_a_fully_padded_batch_row(causal):
+    """Batch row 0 has its last 20 keys padded, batch row 1 every key:
+    row 1's output and every gradient through it are exactly zero, row 0
+    matches the reference."""
+    B, T = 2, 48
+    q, k, v = _qkv(seed=12, B=B, T=T)
+    w = jax.random.normal(jax.random.PRNGKey(13), q.shape)
+    pad = jnp.stack([jnp.arange(T) >= T - 20, jnp.ones(T, bool)])
+    bias = jnp.where(pad, -1e30, 0.0).astype(jnp.float32)[:, None, :]
+    live = jnp.array([1.0, 0.0])[:, None, None, None]
+
+    def f(*a):
+        return (fa_mod._flash_biased(*a, bias, causal, 16, 16) * w).sum()
+
+    def fr(*a):
+        return (_ref(*a, bias=bias, causal=causal) * live * w).sum()
+
+    out = fa_mod._flash_biased(q, k, v, bias, causal, 16, 16)
+    np.testing.assert_array_equal(np.asarray(out[1]), 0.0)
+    np.testing.assert_allclose(
+        np.asarray(out[0]),
+        np.asarray(_ref(q, k, v, bias=bias, causal=causal)[0]),
+        rtol=2e-4, atol=2e-4)
+    got = jax.grad(f, (0, 1, 2))(q, k, v)
+    _assert_grads_close(got, jax.grad(fr, (0, 1, 2))(q, k, v))
+    for g in got:
+        np.testing.assert_array_equal(np.asarray(g[1]), 0.0)
+
+
+def _brute_force_tiles(t, tk, bq, bk, causal, q_off, kv_off):
+    mask = np.ones((t, tk), bool)
+    if causal:
+        mask = (q_off + np.arange(t))[:, None] >= (kv_off + np.arange(tk))
+    tiles = mask.reshape(t // bq, bq, tk // bk, bk).transpose(0, 2, 1, 3)
+    full, some = tiles.all((2, 3)), tiles.any((2, 3))
+    return ~some, full, some & ~full  # skipped, interior, straddling
+
+
+_GRIDS = [
+    # t, tk, block_q, block_k, causal, q_offset, kv_offset
+    (4096, 4096, 1024, 1024, True, 0, 0),
+    (8192, 8192, 1024, 1024, True, 0, 0),
+    (4096, 4096, 1024, 1024, False, 0, 0),
+    (96, 96, 32, 16, True, 0, 0),
+    (96, 96, 16, 32, True, 0, 0),
+    (48, 48, 16, 16, True, 96, 0),
+    (48, 48, 16, 16, True, 0, 96),
+    (48, 48, 16, 16, True, 32, 40),
+    (48, 96, 16, 32, True, 50, 7),
+    (64, 32, 16, 16, True, 15, 16),
+]
+
+
+@pytest.mark.parametrize("grid", _GRIDS, ids=lambda g: "-".join(map(str, g)))
+def test_tile_counts_match_a_brute_force_mask(grid):
+    kinds = _brute_force_tiles(*grid)
+    assert fa_mod.tile_counts(*grid) == tuple(int(x.sum()) for x in kinds)
+
+
+def test_tile_counts_quoted_in_the_docs():
+    assert fa_mod.tile_counts(4096, 4096, 1024, 1024, True) == (6, 6, 4)
+    assert fa_mod.tile_counts(8192, 8192, 1024, 1024, True) == (28, 28, 8)
+    assert fa_mod.tile_counts(4096, 4096, 1024, 1024, False) == (0, 16, 0)
+
+
+@pytest.mark.parametrize("grid", [g for g in _GRIDS if g[4]],
+                         ids=lambda g: "-".join(map(str, g)))
+def test_kernel_predicate_with_traced_offsets_matches_the_mask(grid):
+    """The predicate the kernels branch on (`_tile_kinds`), fed what a
+    kernel feeds it — offsets that are only known at run time plus
+    block index times block size — sorts every tile as the mask does."""
+    t, tk, bq, bk, causal, q_off, kv_off = grid
+    skipped, interior, straddling = _brute_force_tiles(*grid)
+
+    @jax.jit
+    def kinds(q_offset, kv_offset):
+        iq = jnp.arange(t // bq)[:, None]
+        jk = jnp.arange(tk // bk)[None, :]
+        return fa_mod._tile_kinds(causal, q_offset + iq * bq, bq,
+                                  kv_offset + jk * bk, bk)
+
+    inside, crossing = kinds(jnp.int32(q_off), jnp.int32(kv_off))
+    np.testing.assert_array_equal(np.asarray(inside), interior)
+    np.testing.assert_array_equal(np.asarray(crossing), straddling)
+    assert not np.any(np.asarray(inside & crossing))
+    np.testing.assert_array_equal(np.asarray(~(inside | crossing)), skipped)
+
+
+@pytest.mark.parametrize("case", ["causal", "offsets", "bias", "unequal"])
+def test_lane_wide_blocks(case):
+    """Blocks of 256 and 512 (whole 128-wide lane tiles, as on the
+    chip; the cases above run blocks of 16 and 32): values and gradients
+    at 3 x 3 tiles and more, plain causal, with a chunk offset that no
+    block boundary meets (and an lse cotangent), with a padded tail,
+    with unequal blocks."""
+    B, H, HKV, D = 1, 2, 1, 8
+    T, bq, bk = (1536, 512, 256) if case == "unequal" else (768, 256, 256)
+    q_off, kv_off = (300, 140) if case == "offsets" else (0, 0)
+    ks = jax.random.split(jax.random.PRNGKey(21), 5)
+    q = jax.random.normal(ks[0], (B, H, T, D), jnp.float32)
+    k = jax.random.normal(ks[1], (B, HKV, T, D), jnp.float32)
+    v = jax.random.normal(ks[2], (B, HKV, T, D), jnp.float32)
+    w = jax.random.normal(ks[3], q.shape)
+    u = jax.random.normal(ks[4], (B, H, T, 1))
+    assert all(fa_mod.tile_counts(T, T, bq, bk, True, q_off, kv_off))
+    bias = None
+    if case == "bias":
+        bias = jnp.where(jnp.arange(T) >= T - 200, -1e30,
+                         0.0).astype(jnp.float32)[None, None, :]
+
+    def run(*a):
+        if case == "bias":
+            return fa_mod._flash_biased(*a, bias, True, bq, bk), None
+        if case == "offsets":
+            return jax.jit(lambda qo, ko: fa_mod.flash_attention_chunk(
+                *a, qo, ko, causal=True, block_q=bq, block_k=bk))(
+                    q_off, kv_off)
+        return fa_mod._flash(*a, True, bq, bk), None
+
+    def ref(*a):
+        if case == "bias":
+            rep = [jnp.repeat(x, H // HKV, 1) for x in a[1:]]
+            return _ref(a[0], *rep, bias=bias, causal=True), None
+        return _chunk_ref(*a, q_off, kv_off)[:2]
+
+    has_key = _chunk_ref(q, k, v, q_off, kv_off)[2]
+
+    def loss(fn):
+        def _l(*a):
+            o, lse = fn(*a)
+            extra = 0.0 if case != "offsets" else \
+                (jnp.where(has_key, lse, 0.0) * u).sum()
+            return (o * w).sum() + extra
+        return _l
+
+    np.testing.assert_allclose(np.asarray(run(q, k, v)[0]),
+                               np.asarray(ref(q, k, v)[0]),
+                               rtol=2e-4, atol=2e-4)
+    _assert_grads_close(jax.grad(loss(run), (0, 1, 2))(q, k, v),
+                        jax.grad(loss(ref), (0, 1, 2))(q, k, v))

@@ -209,8 +209,7 @@ def test_programs_are_named_for_what_they_are(program):
     assert f"module @{want} " in fn.lower(*args).as_text()
 
 
-@pytest.mark.parametrize("kernel", ["hvd_flash_fwd", "hvd_flash_bwd_dq",
-                                    "hvd_flash_bwd_dkv"])
+@pytest.mark.parametrize("kernel", ["hvd_flash_fwd", "hvd_flash_bwd_fused"])
 def test_flash_kernels_can_be_told_apart_by_name(kernel, monkeypatch):
     import importlib
 
