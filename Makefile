@@ -72,7 +72,7 @@ model-check:
 # (see docs/analysis.md).
 lint: model-check
 	@if command -v ruff >/dev/null 2>&1; then \
-	  ruff check horovod_tpu bench.py; \
+	  ruff check horovod_tpu; \
 	else \
 	  echo "lint: ruff not installed; skipping style pass"; \
 	fi

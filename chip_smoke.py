@@ -17,8 +17,8 @@ On success the LAST line is exactly
 
     {"ok": true, "device": {"platform": "tpu", "kind": ..., "count": N}}
 
-One chip, all at the flagship's full width (``bench._flagship_cfg``:
-d_model 2048, d_ff 13312, 16/4 heads x 128, vocab 32768, 14 layers):
+One chip, all at the flagship's full width (``_flagship_cfg``: d_model
+2048, d_ff 13312, 16/4 heads x 128, vocab 32768, 14 layers):
 
 - ``build``       ``make core`` — the eager lane's native runtime.
 - ``kernels``     flash fwd+bwd at the flagship shape vs
@@ -53,7 +53,7 @@ import traceback
 
 REPO = os.path.dirname(os.path.abspath(__file__))
 
-# The flagship bench shape: batch 4 x seq 2048, uncut. Compile-only
+# The flagship's shape: batch 4 x seq 2048, uncut. A compile-only
 # memory_analysis() for a described v5e said the grad program would not
 # fit (2.85 GB params + 2.85 GB grads + 6.15 GB temporaries beside 5.7
 # GB of adam moments = 17.6 GB against 16 GiB); the chip run says it
@@ -86,6 +86,96 @@ POD_LOSS_RTOL = 1e-2
 
 def emit(obj):
     print(json.dumps(obj), flush=True)
+
+
+# --------------------------------------------------- the model and its step
+
+
+def _flagship_cfg():
+    """The 1.43B pure-bf16 decoder this smoke trains: the widest
+    Llama-family geometry whose params, grads and adam moments fit one
+    16 GB chip beside 4 x 2048 tokens. head_dim 128 feeds the MXU
+    full-depth contractions in the flash kernel, fewer-but-wider layers
+    amortize the per-layer fixed costs, 4:1 GQA is the llama-3/mistral
+    ratio. It is no published configuration (ROADMAP, named debts)."""
+    from horovod_tpu.models import LlamaConfig
+
+    return LlamaConfig(vocab_size=32768, d_model=2048, n_layers=14,
+                       n_heads=16, n_kv_heads=4, d_ff=13312,
+                       dtype="bfloat16", remat="attn+gate",
+                       param_dtype="bfloat16")
+
+
+def _step_jit_kwargs():
+    """Compiler options of the train-step jits: the stock 16 MB
+    scoped-VMEM budget under-buffers the big fused matmuls at these
+    shapes, so 64 MB — what the benchmark's LM configurations set too
+    (``chipbench/configs/*.json``)."""
+    import jax
+
+    if jax.devices()[0].platform != "tpu":
+        return {}  # a TPU compiler option; the CPU rehearsal omits it
+    return {"compiler_options": {"xla_tpu_scoped_vmem_limit_kib":
+                                 "65536"}}
+
+
+def _data(cfg, batch, seq):
+    import jax
+    import jax.numpy as jnp
+
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (batch, seq), 0,
+                                cfg.vocab_size)
+    return {"tokens": tokens, "targets": jnp.roll(tokens, -1, 1)}
+
+
+def make_eager_step(cfg):
+    """The eager-Horovod step (hvd must already be initialized): jitted
+    grad program, ``hvd.grouped_allreduce`` of the gradient tree over
+    the device plane, jitted adam apply. Returns ``(step, (params,
+    opt))`` with ``step(carry, data) -> (loss, carry)``."""
+    import functools
+
+    import jax
+    import optax
+
+    import horovod_tpu.jax as hvd
+    from horovod_tpu.jax.optimizer import allreduce_gradients
+    from horovod_tpu.models import llama_init, llama_loss
+
+    # COMMITTED to the device from the start: the data plane's staging
+    # device_put commits the gradients, so apply_fn outputs would flip
+    # params from uncommitted to committed after step one — a new jit
+    # signature, i.e. a silent mid-loop recompile of grad_fn.
+    # This process's device: under a multi-rank launch jax.devices()[0]
+    # is rank 0's chip.
+    dev = jax.local_devices()[0]
+    params = jax.device_put(llama_init(cfg, jax.random.PRNGKey(0)), dev)
+    tx = optax.adam(3e-4)
+    opt = jax.device_put(tx.init(params), dev)
+
+    grad_fn = jax.jit(
+        lambda p, d: jax.value_and_grad(llama_loss)(p, d, cfg),
+        **_step_jit_kwargs())
+
+    # Grads are NOT donated here: they arrive as donation-ALIASED
+    # outputs of the device-plane identity program, and XLA refuses to
+    # re-donate an aliased buffer (the "donated buffers were not
+    # usable" warning) — listing them would only add noise. params/opt
+    # donation is what matters for the peak.
+    @functools.partial(jax.jit, donate_argnums=(1, 2))
+    def apply_fn(grads, params, opt):
+        updates, opt = tx.update(grads, opt, params)
+        return optax.apply_updates(params, updates), opt
+
+    def step(carry, data):
+        params, opt = carry
+        loss, grads = grad_fn(params, data)
+        # Donated: the fused device program reuses the gradients' HBM.
+        grads = allreduce_gradients(grads, op=hvd.Average, donate=True)
+        params, opt = apply_fn(grads, params, opt)
+        return loss, (params, opt)
+
+    return step, (params, opt)
 
 
 # --------------------------------------------------------------- children
@@ -240,10 +330,8 @@ def _time_grad_compile(c, run):
     second shows the cache hit across processes."""
     import jax
 
-    import bench
-
     grad = jax.jit(lambda p, d: jax.value_and_grad(run.loss_fn)(p, d),
-                   **bench._step_jit_kwargs())
+                   **_step_jit_kwargs())
     t0 = time.perf_counter()
     lowered = grad.lower(run.params_abs, run.data)
     if not c.rehearse and "tpu_custom_call" not in lowered.as_text():
@@ -290,11 +378,10 @@ def _train_setup(c, batch=BATCH):
 
     import jax
 
-    import bench
     from horovod_tpu.models import LlamaConfig, llama_init, llama_loss
 
     cfg, seq = (LlamaConfig.tiny(dtype="float32"), 64) if c.rehearse \
-        else (bench._flagship_cfg(), SEQ)
+        else (_flagship_cfg(), SEQ)
     params_abs = jax.eval_shape(lambda k: llama_init(cfg, k),
                                 jax.random.PRNGKey(0))
     leaves = jax.tree.leaves(params_abs)
@@ -303,7 +390,7 @@ def _train_setup(c, batch=BATCH):
           n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
           vocab=cfg.vocab_size, batch=batch, seq=seq)
     return types.SimpleNamespace(
-        cfg=cfg, data=bench._data(cfg, batch, seq), params_abs=params_abs,
+        cfg=cfg, data=_data(cfg, batch, seq), params_abs=params_abs,
         loss_fn=lambda p, d: llama_loss(p, d, cfg),
         grad_bytes=sum(x.size * x.dtype.itemsize for x in leaves))
 
@@ -313,14 +400,13 @@ def phase_spmd_train(rehearse):
     import jax
     import optax
 
-    import bench
     from horovod_tpu.models import llama_init
     from horovod_tpu.parallel import make_split_train_step
 
     run = _train_setup(c)
     compile_s = _time_grad_compile(c, run)
     ts = make_split_train_step(run.loss_fn, optax.adam(3e-4),
-                               jit_kwargs=bench._step_jit_kwargs())
+                               jit_kwargs=_step_jit_kwargs())
     carry = ts.init(llama_init(run.cfg, jax.random.PRNGKey(0)))
     losses, _, step_ms = _run_steps(c, ts.step, carry, run.data, STEPS)
     c.passed(losses=losses, step_ms=step_ms, grad_compile_s=compile_s,
@@ -363,15 +449,13 @@ def phase_eager_train(rehearse):
     c = _Child("eager_train", rehearse)
     import jax
 
-    import bench
-
     import horovod_tpu.jax as hvd
 
     run = _train_setup(c)
     compile_s = _time_grad_compile(c, run)
     _hvd_init_on_plane(c)
     try:
-        step, carry, _ = bench.make_eager_step(run.cfg)
+        step, carry = make_eager_step(run.cfg)
         losses, _, step_ms = _run_steps(c, step, carry, run.data, STEPS)
         device = _assert_device_plane(c, hvd, run.grad_bytes, STEPS)
     finally:
@@ -388,7 +472,6 @@ def phase_pod_rank(rehearse):
     import jax.numpy as jnp
     import numpy as np
 
-    import bench
     import horovod_tpu.jax as hvd
 
     try:
@@ -401,7 +484,7 @@ def phase_pod_rank(rehearse):
         rows = POD_BATCH // size
         local = jax.tree.map(lambda x: x[rank * rows:(rank + 1) * rows],
                              run.data)
-        step, carry, _ = bench.make_eager_step(run.cfg)
+        step, carry = make_eager_step(run.cfg)
 
         def global_loss(loss, i):  # equal shards: mean of rank means
             return hvd.allreduce(loss, name=f"loss.{i}", op=hvd.Average)
@@ -431,7 +514,6 @@ def phase_mesh_spmd(rehearse):
     import jax
     import optax
 
-    import bench
     from horovod_tpu import parallel
     from horovod_tpu.models import (
         llama_init,
@@ -453,7 +535,7 @@ def phase_mesh_spmd(rehearse):
         run.data, parallel.named_sharding(mesh, ("data", "fsdp"), "seq"))
     ts = parallel.make_split_train_step(
         lambda p, d: llama_loss(p, d, cfg, mesh), optax.adam(3e-4),
-        jit_kwargs=bench._step_jit_kwargs())
+        jit_kwargs=_step_jit_kwargs())
     losses, (params, _), step_ms = _run_steps(
         c, ts.step, ts.init(params), data, POD_STEPS)
     shard_devices = sorted(str(s.device) for s in

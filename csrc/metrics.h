@@ -108,8 +108,8 @@ class Metrics {
   // PeerFailure surfaced (EOF ~ instant; stalls ~ the wire deadline).
   LatencyHistogram fault_detect_us;
   // Per-phase control-plane latency (ControlPhase above): the scaling
-  // profile the simworld harness and `bench.py --scale` read to indict
-  // O(N) suspects at 64-256 ranks (docs/scale.md).
+  // profile the simworld harness reads to indict O(N) suspects at
+  // 64-256 ranks (docs/scale.md).
   LatencyHistogram control_phase_us[kPhaseCount];
 
   std::atomic<int64_t> cycles{0};

@@ -219,8 +219,8 @@ bool ParameterManager::Update(int64_t bytes) {
     // score. That bias made bytes/sec REWARD small cycle times —
     // windows close inside the burst where instantaneous throughput
     // is high — while the realized step time is worst exactly there
-    // (measured r6, benchmarks/results_r06_autotune.json: the
-    // per-grad lane's knob landscape inverts). Wall-clock windows
+    // (seen in round 6: the per-grad lane's knob landscape
+    // inverts). Wall-clock windows
     // make the score proportional to end-to-end training throughput,
     // which is the number the tuner exists to move. Exception: a
     // carried-over gap of a whole window or more is a knob-UNRELATED

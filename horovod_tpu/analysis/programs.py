@@ -3,8 +3,8 @@
 Every program the training stack jits — the monolithic and split llama
 train steps, both fused optimizer applies, and all three pipeline
 schedule engines — is buildable here with abstract inputs, so the CLI
-(``python -m horovod_tpu.analysis.lint --all``), ``make lint``,
-``bench.py --lint``, and the pytest fixture all lint the SAME set.
+(``python -m horovod_tpu.analysis.lint --all``), ``make lint`` and
+the pytest fixture all lint the SAME set.
 Adding a program here is how a future subsystem buys pre-launch
 collective-consistency checking for free.
 

@@ -162,7 +162,7 @@ def DistributedFusedAdam(learning_rate, b1=0.9, b2=0.999, eps=1e-8,
     The allreduce is an eager collective (enqueue -> negotiate ->
     cached device-program replay), so ``apply`` itself must stay
     OUTSIDE jit; the update math runs as its own jitted program — the
-    same split-program layout ``bench.py``'s eager row measures.
+    same split-program layout ``chip_smoke.py``'s eager step runs.
 
     ``zero=True`` switches to the ZeRO-1 sharded path (docs/zero.md):
     gradients are packed into fused buckets (``bucket_bytes``,
@@ -199,7 +199,7 @@ def DistributedFusedAdam(learning_rate, b1=0.9, b2=0.999, eps=1e-8,
 
     # Grads are NOT donated into the update jit: they arrive as
     # donation-aliased outputs of the device-plane program and XLA
-    # refuses to re-donate an aliased buffer (see bench.py's eager
+    # refuses to re-donate an aliased buffer (see chip_smoke.py's
     # apply_fn). params/state donation is what bounds the peak.
     jitted_apply = jax.jit(inner.apply, donate_argnums=(0, 2))
 

@@ -114,7 +114,7 @@ class LlamaConfig:
     # Pallas flash-attention block size (both the q and k grid blocks;
     # 0 = the kernel default, 1024 — the measured optimum of
     # {256,512,1024,2048}² at t2048, docs/benchmarks.md r4). Exposed so
-    # bench.py --sweep can re-sweep the attention block shapes when the
+    # a sweep on the chip can re-try the attention block shapes when the
     # geometry moves; ring/ulysses SP paths keep their own defaults.
     flash_block: int = 0
     # Parameter STORAGE dtype ("float32" default). "bfloat16" halves
@@ -666,8 +666,8 @@ def _build_layer_body(c, mesh, seq_axis, constrain_acts=True):
         # Save the whole grouped-expert chain (x_sorted, pre-silu gate,
         # up, y_slots — ~[S*K, 2F+2D] bf16 per layer): backward re-runs
         # NO grouped matmul. The HBM price usually needs microbatched
-        # steps (gradient accumulation) at bench sizes; see
-        # benchmarks/moe_bench.py.
+        # steps (gradient accumulation) at real sizes; see
+        # parallel.make_split_train_step's ``microbatches``.
         body = jax.checkpoint(
             layer,
             policy=jax.checkpoint_policies.save_only_these_names(

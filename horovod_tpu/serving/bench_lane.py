@@ -5,10 +5,9 @@ continuous-batching engine (single rank, no wire — the scheduler/paged
 -cache/decode-step stack is what's being measured) against a seeded
 Poisson arrival trace on a tiny llama config, once per KV block format
 (f32 and int8), and prints one schema-stamped JSON row per format —
-the ``serving_latency`` family ``bench.py`` emits and
-``perfwatch``/``bench.py --diff`` watch (p50/p99 up and
-sustained_tok_s down are the bad directions; registered in
-telemetry/perfwatch.py).
+the ``serving_latency`` family ``perfwatch`` and its ``--diff``
+watch (p50/p99 up and sustained_tok_s down are the bad directions;
+registered in telemetry/perfwatch.py).
 
 It also emits the ``serving_trace_overhead`` row: the same engine
 driven CLOSED-LOOP (all requests submitted up front, no arrival
@@ -18,10 +17,8 @@ bar mirrors the r15 events-overhead criterion: < 2% sustained tok/s
 regression with tracing on (``overhead_pct`` is perfwatch-watched, up
 = bad).
 
-Substrate-independent (CPU jax) like ``ring_busbw``: the driver's
-bench capture gets serving rows on any box. bench.py runs this module
-as a SUBPROCESS so the flagship lane's virgin-device-heap requirement
-is untouched.
+Substrate-independent (CPU jax): what it prints on the CPU proves the
+rows' contract (tests/single/test_serving.py), never a rate.
 """
 
 import json

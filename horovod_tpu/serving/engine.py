@@ -124,8 +124,8 @@ class DecodeEngine:
         # of the jitted step; survivors fall back to decode_wait after
         # it. One transition pair per row per step is the ledger's
         # resolution (tail_report aggregates the alternation), cheap
-        # enough that `bench.py --serving` pins the whole tracing cost
-        # under 2% of sustained tok/s.
+        # enough that serving/bench_lane.py's serving_trace_overhead
+        # row holds the whole tracing cost under 2% of sustained tok/s.
         for seq in live:
             reqtrace.record_request("decode_active", seq.rid,
                                     aux=seq.cached)

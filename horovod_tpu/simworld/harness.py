@@ -48,7 +48,7 @@ def scaling_profile(world_sizes=DEFAULT_WORLD_SIZES,
     """The ``control_plane_scaling`` bench rows: for every world size,
     one flat-star row and one tree row — BOTH curves, so the sub-linear
     claim for the tree gather is checkable against its own baseline in
-    the same run (`bench.py --scale`). Per row: world standup, mean
+    the same run. Per row: world standup, mean
     negotiation+allreduce round, and the gather/broadcast phase stats
     the curves are drawn from."""
     rows = []

@@ -24,8 +24,8 @@ Three consumers:
   time series, served at the ``/fleet`` debug endpoint. Each poll
   evaluates the declared SLOs (:mod:`telemetry.slo`) per rank and
   records typed ``slo_breach`` ring events.
-- ``bench.py --fleet-util`` — the perfwatch-gated ``fleet_utilization``
-  row over the simworld synthesized fleet (docs/benchmarks.md).
+- ``make fleet-obs-smoke`` — the whole fold proven on a simworld
+  synthesized fleet (docs/fleet.md).
 
 Bucket claiming is by PRIORITY (stall > exposed wire > negotiation >
 serving decode > prefill > queued), each bucket claiming only wall time

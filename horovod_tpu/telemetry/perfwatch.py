@@ -2,8 +2,8 @@
 
 ``python -m horovod_tpu.telemetry.perfwatch`` consumes either a
 :class:`~horovod_tpu.telemetry.exporters.MetricsScraper` JSONL flight
-recorder or bench JSON rows (``bench.py`` output, or a driver artifact
-that embeds such rows) and answers ONE question with an exit
+recorder or JSON measurement rows (one object per line, or a driver
+artifact that embeds such rows) and answers ONE question with an exit
 code CI can gate on: did step time, bus bandwidth, or overlap
 efficiency regress?
 
@@ -22,12 +22,12 @@ Two detectors, both deliberately simple enough to reason about:
 
 ``--budget`` turns the report into a gate: nonzero exit on any flagged
 regression — the CI lane and the autoscaler's instability gate consume
-it. ``bench.py --diff old.json new.json`` is the two-point companion
-(explicit per-row deltas between two bench row files).
+it. ``--diff OLD NEW [--diff-threshold X]`` is the two-point companion
+(:func:`diff_rows`: explicit per-row deltas between two row files).
 
-Rows carry a ``schema`` version (stamped by ``bench.py``'s ``emit``);
-mixed schema versions in one input are refused loudly instead of
-mis-compared (exit 2).
+Rows may carry a ``schema`` version stamp; mixed schema versions in one
+input, or two ``--diff`` inputs whose stamps differ, are refused loudly
+instead of mis-compared (non-zero exit).
 """
 
 import argparse
@@ -35,7 +35,7 @@ import json
 import sys
 
 # Fields that IDENTIFY a row (the join/grouping key) rather than
-# measure it — shared with bench.py's --diff so the two tools can never
+# measure it — one list for the watch and for --diff, so the two can never
 # disagree about what distinguishes rows of one metric family.
 ROW_IDENTITY_FIELDS = ("metric", "config", "name", "schedule", "bench",
                        "ranks", "bytes", "payload_bytes", "bucket_bytes",
@@ -69,7 +69,7 @@ DEFAULT_WATCH = {
     "wire_gbps": "down",
     "overlap_efficiency": "down",
     "mfu": "down",
-    # Serving rows (bench.py --serving / serving_latency family):
+    # Serving rows (serving/bench_lane.py, the serving_latency family):
     # request latency percentiles regress UP, sustained decode
     # throughput regresses DOWN — watched from day one so the CI gate
     # covers the serving lane the moment it emits rows.
@@ -81,7 +81,7 @@ DEFAULT_WATCH = {
     # serving_trace_overhead lane): the flight recorder / request
     # tracing getting more expensive IS a perf regression.
     "overhead_pct": "up",
-    # Fleet rank-seconds rows (bench.py --fleet-util, docs/fleet.md):
+    # Fleet rank-seconds rows (fleet_utilization, docs/fleet.md):
     # utilization falling, the unattributed share growing, breaches
     # appearing, or the aggregation itself slowing down at fleet scale
     # are each regressions in their own right.
@@ -243,8 +243,8 @@ def check_schema(rows, what="rows"):
     if len(versions) > 1:
         raise SystemExit(
             f"perfwatch: refusing to compare {what} with MIXED schema "
-            f"versions {sorted(versions)} — re-emit with one bench/"
-            "scraper generation (rows are stamped by bench.py emit())")
+            f"versions {sorted(versions)} — re-emit with one generation "
+            "of the program that writes them")
     return versions.pop() if versions else 0
 
 
@@ -331,6 +331,80 @@ def watch(series_map, rel_threshold=0.25, consecutive=2, min_points=4):
     return verdicts
 
 
+# ---- two-point row diffing (--diff OLD NEW) ---------------------------
+
+# Fields that are neither identity nor comparable measurements.
+_DIFF_SKIP_FIELDS = {"schema", "unit", "error", "ts", "wall_s", "tail"}
+
+
+def _diff_key(row, seen):
+    key = tuple((f, row.get(f)) for f in ROW_IDENTITY_FIELDS if f in row)
+    n = seen.get(key, 0)
+    seen[key] = n + 1
+    return key + (("occurrence", n),) if n else key
+
+
+def _key_str(key):
+    return "/".join(str(v) for _, v in key if v is not None)
+
+
+def diff_rows(old_path, new_path, threshold=0.0):
+    """Compare two row files; returns (lines, worst_rel_change): rows
+    matched by their identity fields, one line per numeric measurement
+    field. Refuses mismatched `schema` stamps — a renamed column diffed
+    by name is a silent lie, so format drift must fail loudly. Rows with
+    a nested `points` list are flattened to one pseudo-row per point
+    first, so per-size measurements diff like any other field."""
+    old_rows, new_rows = load_rows(old_path), load_rows(new_path)
+    old_schema = check_schema(old_rows, what=old_path)
+    new_schema = check_schema(new_rows, what=new_path)
+    if old_schema != new_schema:
+        raise SystemExit(
+            f"perfwatch --diff: refusing to compare schema {old_schema} "
+            f"({old_path}) against schema {new_schema} ({new_path}) — "
+            "row formats differ; re-run the older side on this tree")
+    seen_old, seen_new = {}, {}
+    old_by_key = {_diff_key(r, seen_old): r for r in flatten_rows(old_rows)}
+    new_by_key = {_diff_key(r, seen_new): r for r in flatten_rows(new_rows)}
+    lines = [f"{'row':<52} {'field':<24} {'old':>12} {'new':>12} "
+             f"{'delta':>9}"]
+    worst = 0.0
+    for key in old_by_key:
+        if key not in new_by_key:
+            lines.append(f"{_key_str(key):<52} (only in {old_path})")
+            continue
+        old, new = old_by_key[key], new_by_key[key]
+        for field in sorted(set(old) & set(new)):
+            ov, nv = old[field], new[field]
+            if (field in _DIFF_SKIP_FIELDS
+                    or any(f == field for f, _ in key)
+                    or not isinstance(ov, (int, float))
+                    or not isinstance(nv, (int, float))
+                    or isinstance(ov, bool) or isinstance(nv, bool)):
+                continue
+            if ov:
+                rel = (nv - ov) / abs(ov)
+                delta = f"{rel:>+8.1%}"
+            elif nv:
+                # 0 -> x has no finite relative change: shown, never
+                # threshold-dropped, and it moves the worst tally (a
+                # counter appearing — crc_errors, stalls — IS news).
+                rel = None
+                delta = "    (new)"
+            else:
+                rel = 0.0
+                delta = f"{0.0:>+8.1%}"
+            if rel is not None and abs(rel) < threshold:
+                continue
+            worst = max(worst, abs(rel) if rel is not None else 1.0)
+            lines.append(f"{_key_str(key):<52} {field:<24} "
+                         f"{ov:>12.6g} {nv:>12.6g} {delta}")
+    for key in new_by_key:
+        if key not in old_by_key:
+            lines.append(f"{_key_str(key):<52} (only in {new_path})")
+    return lines, worst
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser(
         prog="python -m horovod_tpu.telemetry.perfwatch",
@@ -339,9 +413,15 @@ def main(argv=None):
     ap.add_argument("--jsonl", default=None,
                     help="MetricsScraper JSONL flight recorder")
     ap.add_argument("--bench", nargs="*", default=None,
-                    help="bench row files (JSONL / JSON array / "
+                    help="row files (JSONL / JSON array / "
                          "driver artifacts with a `tail`), "
                          "concatenated in order")
+    ap.add_argument("--diff", nargs=2, metavar=("OLD", "NEW"),
+                    help="print per-row deltas between two row files "
+                         "and exit (no detectors run)")
+    ap.add_argument("--diff-threshold", type=float, default=0.0,
+                    help="hide deltas under this relative change "
+                         "(0 -> x rows are always shown)")
     ap.add_argument("--threshold", type=float, default=0.25,
                     help="relative breach threshold (default 0.25)")
     ap.add_argument("--consecutive", type=int, default=2,
@@ -352,8 +432,13 @@ def main(argv=None):
                     help="emit verdicts as JSON rows")
     args = ap.parse_args(argv)
 
+    if args.diff:
+        lines, worst = diff_rows(*args.diff, threshold=args.diff_threshold)
+        print("\n".join(lines))
+        print(f"perfwatch --diff: worst relative change {worst:+.1%}")
+        return 0
     if not args.jsonl and not args.bench:
-        ap.error("need --jsonl and/or --bench input")
+        ap.error("need --jsonl, --bench or --diff input")
     series_map = {}
     if args.jsonl:
         rows = load_rows(args.jsonl)

@@ -408,8 +408,8 @@ class StepTimer:
 
 def analytic_bubble(schedule, S, M, num_virtual=1):
     """The schedule's predicted idle fraction, from the same closed
-    forms / tables the engines execute (``parallel.pipeline``; same
-    numbers bench.py's ``pipeline_bubble`` rows emit). Schedules:
+    forms / tables the engines execute (``parallel.pipeline``).
+    Schedules:
     ``gpipe``, ``1f1b`` (lockstep), ``interleaved_1f1b``."""
     if schedule == "gpipe":
         return 2 * (S - 1) / (2 * M + 2 * (S - 1))

@@ -1,7 +1,7 @@
 """Where JAX's persistent compilation cache lives for this checkout.
 
 Every program that compiles for the chip (``chip_smoke.py`` children,
-``bench.py``, ``benchmarks/*.py``) calls :func:`enable_compile_cache`
+``chipbench/run.py``'s cells) calls :func:`enable_compile_cache`
 before its first compile, so processes that follow one another — a
 smoke's phases, the ranks of a launcher's next run, a second run in the
 same checkout — find executables instead of paying the compile again.

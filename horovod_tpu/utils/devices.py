@@ -2,8 +2,8 @@
 that there is one.
 
 One table, keyed by substrings of ``device_kind`` (a v5e reports
-``"TPU v5 lite"``), shared by ``bench.py`` (MFU), ``StepTimer.mfu`` and
-``benchmarks/decode_bench.py`` (MBU). A device that is not in the table
+``"TPU v5 lite"``), shared by ``StepTimer.mfu`` (FLOP/s) and whatever
+takes a bandwidth share (HBM bytes/s). A device that is not in the table
 is an error, never a default: a utilization against a made-up peak is
 not a measurement.
 

@@ -56,7 +56,7 @@ def pytest_configure(config):
         "markers",
         "slow: heavyweight lanes (e.g. the 256-rank simulated world) "
         "excluded from the tier-1 budget via -m 'not slow'; covered by "
-        "the full suite and bench.py --scale")
+        "the full suite")
     _ensure_core_built()
 
 

@@ -120,7 +120,7 @@ def _gmm(lhs, rhs, group_sizes):
     pytest.param(_flash_fwd_bwd, (_Q, _KV, _KV),
                  id="flash-fwd+bwd-flagship"),
     # The chip cells' attention shapes (and the 8k and 16k sequences of
-    # PERF.md section 7 and benchmarks/long_context_bench.py). No
+    # PERF.md section 7). No
     # compiler option rides along here, so a kernel that needs more VMEM
     # than the compiler's default scope has to ask for it itself.
     pytest.param(_flash_fwd_bwd,
@@ -150,7 +150,7 @@ def _gmm(lhs, rhs, group_sizes):
                  (((16, 1, 16, 128), BF16), ((16, 4, 640, 128), BF16),
                   ((16, 4, 640, 128), BF16), ((), I32)),
                  id="decode-serving"),
-    # benchmarks/moe_bench.py: 4 x 2048 tokens top-2 over E4, D2048 F4096.
+    # A small dense-ish MoE: 4 x 2048 tokens top-2 over E4, D2048 F4096.
     pytest.param(_gmm,
                  (((16384, 2048), BF16), ((4, 2048, 4096), BF16),
                   ((4,), I32)),
@@ -278,7 +278,6 @@ def test_compile_cache_goes_where_the_environment_says(monkeypatch,
 @pytest.mark.parametrize("table", ["bench-flops", "step-timer",
                                    "decode-bench-hbm"])
 def test_peak_tables_know_this_chip_and_refuse_others(table, monkeypatch):
-    import bench
     from horovod_tpu.telemetry import step_timer
     from horovod_tpu.utils import devices
 
@@ -288,16 +287,17 @@ def test_peak_tables_know_this_chip_and_refuse_others(table, monkeypatch):
 
     if table == "bench-flops":
         def lookup(kind):
-            return bench._peak_flops(Device(kind))
+            return devices.match_device_table(
+                Device(kind), devices.PEAK_BF16_FLOPS)
         want = 197e12
     elif table == "step-timer":
         def lookup(kind):
             monkeypatch.setattr(jax, "devices", lambda: [Device(kind)])
             return step_timer._device_peak_flops()
         want = 197e12
-    else:  # the table benchmarks/decode_bench.py computes MBU against
+    else:  # the table a decode step's bandwidth share is taken against
         def lookup(kind):
-            return bench.match_device_table(
+            return devices.match_device_table(
                 Device(kind), devices.PEAK_HBM_BYTES_PER_S)
         want = 819e9
     assert lookup("TPU v5 lite") == want

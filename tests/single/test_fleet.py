@@ -12,7 +12,6 @@ import time
 
 import pytest
 
-import bench
 from horovod_tpu.simworld import harness
 from horovod_tpu.telemetry import (
     critpath,
@@ -233,8 +232,8 @@ def test_simworld_fleet_analysis_64_ranks(tmp_path):
 
 def test_simworld_256_rank_aggregation_stays_interactive(tmp_path):
     """The acceptance bar: the 256-rank fleet fold must stay an
-    interactive operation (< 2 s; bench.py --fleet-util watches the
-    same number as `analyze_s`)."""
+    interactive operation (< 2 s; perfwatch watches the same number
+    as `analyze_s` of a `fleet_utilization` row)."""
     harness.write_sim_step_dumps(str(tmp_path), ranks=256, steps=4,
                                  slow_rank=85, waits=True, serving=True)
     t0 = time.perf_counter()
@@ -427,7 +426,7 @@ def test_report_cli_fleet(tmp_path, capsys):
     assert saved["fleet"]["worst_rank"] == 2
 
 
-# ---- perfwatch / bench --diff over fleet_utilization rows -------------
+# ---- perfwatch and its --diff over fleet_utilization rows -------------
 
 
 def _fleet_row(util, ranks=64, breaches=0, analyze_s=0.1):
@@ -471,14 +470,14 @@ def test_perfwatch_never_cross_joins_world_sizes():
     assert all(not v["regressed"] for v in perfwatch.watch(series))
 
 
-def test_bench_diff_over_fleet_rows(tmp_path):
+def test_perfwatch_diff_over_fleet_rows(tmp_path):
     old = str(tmp_path / "old.json")
     new = str(tmp_path / "new.json")
     with open(old, "w") as f:
         f.write(json.dumps(_fleet_row(0.8, breaches=1)) + "\n")
     with open(new, "w") as f:
         f.write(json.dumps(_fleet_row(0.4, breaches=3)) + "\n")
-    lines, worst = bench._diff_rows(old, new)
+    lines, worst = perfwatch.diff_rows(old, new)
     text = "\n".join(lines)
     assert "utilization" in text and "-50.0%" in text, text
     assert "breaches" in text, text

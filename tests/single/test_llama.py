@@ -87,7 +87,7 @@ def test_sharded_train_step_matches_single_device():
 
 
 def test_flash_block_is_pure_scheduling():
-    """LlamaConfig.flash_block (the bench --sweep knob for the pallas
+    """LlamaConfig.flash_block (the sweep knob for the pallas
     q/k grid blocks) must not change the math: loss and grads match the
     kernel-default config. Runs the REAL pallas kernels in interpret
     mode (the XLA fallback ignores the block args, which would make

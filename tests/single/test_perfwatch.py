@@ -1,11 +1,14 @@
-"""Perf-regression sentinel (docs/benchmarks.md "perfwatch"): the EWMA
+"""Perf-regression sentinel (docs/metrics.md, "perfwatch"): the EWMA
 baseline flags an injected 2x step-time regression at the right row, a
 ±5% noise trace stays quiet, the changepoint localizes the regime
 shift, the schema guard refuses mixed row formats, and the --budget CLI
 gate exits nonzero exactly when a watched series regressed."""
 
 import json
+import os
 import random
+import subprocess
+import sys
 
 import pytest
 
@@ -105,7 +108,7 @@ def test_scraper_series_derivation():
 
 
 def test_real_bench_row_shapes_are_watchable():
-    """The gate must bite on the rows bench.py ACTUALLY emits: per-size
+    """The gate must bite on the rows a lane ACTUALLY emits: per-size
     busbw lives in a nested `points` list, step time is `step_s`, and
     the MFU headline is the generic `value` (down = regression only
     because the metric name says mfu)."""
@@ -185,3 +188,39 @@ def test_budget_cli_json_rows(tmp_path, capsys):
             for line in capsys.readouterr().out.splitlines()]
     assert rows and rows[0]["regressed"], rows
     assert rows[0]["changepoint_index"] == 8, rows
+
+
+def _diff_cmd(*args):
+    """``python -m horovod_tpu.telemetry.perfwatch --diff ...`` as a
+    user types it: (exit code, stdout, stderr)."""
+    repo = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    out = subprocess.run(
+        [sys.executable, "-m", "horovod_tpu.telemetry.perfwatch",
+         "--diff", *args], cwd=repo, text=True, capture_output=True,
+        timeout=120)
+    return out.returncode, out.stdout, out.stderr
+
+
+def test_diff_command_prints_relative_change(tmp_path):
+    old = _write_rows(tmp_path / "old.jsonl", [0.100])
+    new = _write_rows(tmp_path / "new.jsonl", [0.125])
+    rc, out, err = _diff_cmd(old, new)
+    assert rc == 0, (rc, out, err)
+    row = [ln for ln in out.splitlines() if "step_s" in ln]
+    assert row and "+25.0%" in row[0] and "eager" in row[0], out
+    assert "worst relative change +25.0%" in out, out
+    # Under the threshold the field is hidden, the exit code the same.
+    rc, out, err = _diff_cmd(old, new, "--diff-threshold", "0.5")
+    assert rc == 0 and "step_s" not in out, (rc, out, err)
+
+
+def test_diff_command_refuses_mismatched_schema_stamps(tmp_path):
+    old = _write_rows(tmp_path / "old.jsonl", [0.1])
+    new = tmp_path / "new.jsonl"
+    new.write_text(json.dumps(
+        {"metric": "eager", "step_s": 0.1, "schema": 2}) + "\n")
+    rc, out, err = _diff_cmd(old, str(new))
+    assert rc != 0, (rc, out, err)
+    assert "refusing to compare schema 1" in err, err
+    assert "step_s" not in out, out

@@ -350,8 +350,8 @@ def test_perfwatch_watches_serving_rows():
 def test_diff_and_perfwatch_on_real_serving_row_files(real_rows,
                                                      tmp_path):
     """The --diff/perfwatch contract on serving rows, exercised from
-    two REAL row files (bench-lane output written to disk, schema-
-    stamped like bench.py emit does):
+    two REAL row files (bench-lane output written to disk, with a
+    schema stamp):
 
     - identity separation: rows join strictly on the full identity
       (arrival_rps/block_size included) — a changed block_size makes a
@@ -362,13 +362,12 @@ def test_diff_and_perfwatch_on_real_serving_row_files(real_rows,
     """
     import copy
 
-    from bench import _diff_rows
     from horovod_tpu.telemetry import perfwatch as pw
 
     old_rows = copy.deepcopy(real_rows)
     new_rows = copy.deepcopy(real_rows)
     for r in old_rows + new_rows:
-        r.setdefault("schema", 1)  # what bench.py emit() stamps
+        r.setdefault("schema", 1)
     # Regress the f32 row's p99 3x in the new file; move the int8
     # row's block geometry so it becomes a DIFFERENT identity.
     new_rows[0]["p99_ms"] = old_rows[0]["p99_ms"] * 3.0 + 1.0
@@ -377,7 +376,7 @@ def test_diff_and_perfwatch_on_real_serving_row_files(real_rows,
     old_path.write_text(json.dumps(old_rows))
     new_path.write_text(json.dumps(new_rows))
 
-    lines, worst = _diff_rows(str(old_path), str(new_path))
+    lines, worst = pw.diff_rows(str(old_path), str(new_path))
     text = "\n".join(lines)
     f32_p99 = [ln for ln in lines
                if "f32" in ln and "p99_ms" in ln]

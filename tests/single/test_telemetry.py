@@ -263,7 +263,7 @@ def test_bubble_measured_vs_analytic():
     rep2 = telemetry.bubble_report("interleaved_1f1b", S, M, V,
                                    step_time * 1.25, t_sub)
     assert rep2["excess"] > 0.15
-    # Analytic forms match bench.py's pipeline_bubble rows.
+    # Analytic forms of the three schedules.
     assert telemetry.analytic_bubble("gpipe", S, M) == pytest.approx(
         2 * (S - 1) / (2 * M + 2 * (S - 1)))
     assert telemetry.analytic_bubble("1f1b", S, M) == pytest.approx(
