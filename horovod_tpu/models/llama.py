@@ -29,8 +29,10 @@ from jax.sharding import PartitionSpec as P
 
 from horovod_tpu.parallel.ring_attention import ring_self_attention
 
-# Extra residual names the "moe" remat mode saves beyond "attn+moe".
-_MOE_EXTRA_SAVE = ("moe_x_sorted", "moe_gate_act", "moe_up_act")
+# Residual names of ops/grouped_moe.py: what "attn+moe" saves beyond
+# "attn", and what "moe" saves beyond that.
+_MOE_SAVE = ("moe_perm", "moe_w_sorted")
+_MOE_EXTRA_SAVE = ("moe_gate_act", "moe_up_act")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -654,24 +656,28 @@ def _build_layer_body(c, mesh, seq_axis, constrain_acts=True):
             "(n_experts > 0 and moe_impl='grouped', or 'auto' with no "
             "mesh); use remat='attn' or 'attn+gate' here")
     elif c.remat == "attn+moe":
-        # "attn" plus the grouped-MoE y_slots residual ([S*K, D] bf16
-        # per layer): the router's combine-weight gradient consumes
-        # y_slots, so without it the backward remat must re-run the
-        # down-projection grouped GEMM per layer.
+        # "attn" plus the grouped-MoE routing: the sorted order with
+        # its inverse (2 x S*K int32) and the gate weights in that
+        # order, so backward sorts nothing; it gathers the rows into
+        # expert order again (saving them costs three passes over
+        # [S*K, D]: jax's reduce_precision on a residual no fusion
+        # produces, and the layer scan's stacking and unstacking) and
+        # re-runs the gate and up grouped GEMMs. (No gradient needs the down projection's
+        # output: the gate weights scale its input rows.)
         body = jax.checkpoint(
             layer,
             policy=jax.checkpoint_policies.save_only_these_names(
-                "attn_out", "flash_o", "flash_lse", "moe_y_slots"))
+                "attn_out", "flash_o", "flash_lse", *_MOE_SAVE))
     elif c.remat == "moe":
-        # Save the whole grouped-expert chain (x_sorted, pre-silu gate,
-        # up, y_slots — ~[S*K, 2F+2D] bf16 per layer): backward re-runs
-        # NO grouped matmul. The HBM price usually needs microbatched
-        # steps (gradient accumulation) at real sizes; see
+        # Also save the pre-silu gate and the up projection ([S*K, 2F]
+        # bf16 per layer): backward re-runs NO grouped matmul. The HBM
+        # price usually needs microbatched steps (gradient
+        # accumulation) at real sizes; see
         # parallel.make_split_train_step's ``microbatches``.
         body = jax.checkpoint(
             layer,
             policy=jax.checkpoint_policies.save_only_these_names(
-                "attn_out", "flash_o", "flash_lse", "moe_y_slots",
+                "attn_out", "flash_o", "flash_lse", *_MOE_SAVE,
                 *_MOE_EXTRA_SAVE))
     elif c.remat == "attn+gate+qkv":
         # "attn+gate" plus the post-rope q/k/v: backward re-runs only

@@ -13,13 +13,24 @@ structural taxes on a single chip:
    charges straight to the implementation.
 
 This path removes both: flatten the (token, k) slots, ``argsort`` them
-by routed expert (16K int32 keys — microseconds), gather the activation
-rows once, and run the three expert projections as ragged grouped
-matmuls (``jax.experimental.pallas.ops.tpu.megablox.gmm`` — measured at
-dense-matmul throughput on v5e). Every token-slot is computed — no
-capacity, no dropped tokens (dropless), no padding FLOPs. The
-un-permutation is a custom-VJP gather whose backward is the inverse
-gather, so no XLA scatter ever appears on the hot path.
+by routed expert (64K int32 keys: microseconds), gather the activation
+rows once into expert order, and run the three expert projections as
+ragged grouped matmuls (``jax.experimental.pallas.ops.tpu.megablox.gmm``
+— measured at dense-matmul throughput on v5e). Every token-slot is
+computed — no capacity, no dropped tokens (dropless), no padding FLOPs.
+
+How the routed rows move: the gate weight of a slot scales its activation row BEFORE the down projection, so the
+way back to token order is a plain sum of each token's K rows. Dispatch
+(one gather from the [S, D] tokens) and combine (one permutation gather
+of [S*K, D], reduced by K at once) are each other's transpose and each
+other's custom VJP: no XLA scatter on the hot path, and no residual in
+slot order. The slots are counted by a fused compare-and-sum and sorted
+twice a layer (the order, which the gate weights ride, and its inverse,
+handed to every VJP), and every gather promises the bounds its
+construction guarantees. No row kernel: Mosaic's DMAs address a 2-D
+bf16 array in HBM by tiles of 8 rows (a row lies interleaved with its
+pair in 16 pieces), so a kernel cannot fetch one routed row; XLA's
+gather can (readings: PERF.md section 6).
 
 Sharding: this path is for programs where the experts are NOT sharded
 over an ``expert`` mesh axis (single chip, or EP-free meshes) — the
@@ -56,52 +67,63 @@ _TILING_DLHS = (512, 1024, 1024)     # backward dlhs gmm (transposed rhs)
 _TILING_TGMM = (512, 1024, 1024)     # backward dW tgmm
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
-def _unpermute(x, perm, _n):
-    """``x[perm]`` where ``perm`` is a PERMUTATION (bijective): the VJP
-    is the gather by the inverse permutation — never an XLA scatter.
-    ``perm`` rides as a regular traced operand; its cotangent is the
-    symbolic zero for ints. ``_n`` is unused padding to keep the vjp
-    signature stable (nondiff static)."""
-    return jnp.take(x, perm, axis=0)
+def _rows(x, idx):
+    """``x[idx]`` along axis 0 for an index that is in bounds BY
+    CONSTRUCTION (a permutation, or ``order // K``): the bare gather.
+    ``jnp.take``'s default ``mode="fill"`` lowers to an in-bounds mask
+    over the indices and a select against NaN over the whole result."""
+    return x.at[idx].get(mode="promise_in_bounds")
 
 
-def _unpermute_fwd(x, perm, _n):
-    return jnp.take(x, perm, axis=0), perm
+# The routed rows move between token order [S, D] and expert order
+# [S*K, D] through two linear maps, each the other's transpose, so each
+# is the other's VJP and no XLA scatter ever appears on the hot path:
+#
+#   dispatch  G(h)[i] = h[tok[i]]               tok = order // K
+#   combine   C(z)[t] = sum_k z[inv[t, k]]      inv = argsort(order)
+#
+# ``tok`` [S*K] and ``inv`` [S, K] are data (int32; their cotangent is
+# the symbolic zero). Both directions hand BOTH to the other, so the
+# inverse permutation is sorted once, in the forward.
+
+@jax.custom_vjp
+def _dispatch(h, tok, inv):
+    """Rows of ``h`` [S, D] replicated K ways into expert order
+    [S*K, D] in ONE gather."""
+    return _rows(h, tok)
 
 
-def _unpermute_bwd(_n, perm, g):
-    # inverse gather: out[perm[i]] = g[i]  <=>  out = g[argsort(perm)]
-    return jnp.take(g, jnp.argsort(perm), axis=0), None
+def _dispatch_fwd(h, tok, inv):
+    return _dispatch(h, tok, inv), (tok, inv)
 
 
-_unpermute.defvjp(_unpermute_fwd, _unpermute_bwd)
+def _dispatch_bwd(res, g):
+    return _combine(g, *res), None, None
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _dispatch_gather(h, slot_token, sorted_order, K):
-    """Rows of ``h`` [S, D] replicated K ways and permuted into expert
-    order in ONE gather: out[i] = h[slot_token[i]] ([S*K, D]).
-
-    ``slot_token = sorted_order // K`` (token of each sorted slot). The
-    VJP avoids a duplicate-index scatter: un-permute the cotangent back
-    to (token, k) slot order with the inverse permutation, then sum the
-    K slots of each token — a reshape + reduce.
-    """
-    return jnp.take(h, slot_token, axis=0)
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
-def _dispatch_gather_fwd(h, slot_token, sorted_order, K):
-    return jnp.take(h, slot_token, axis=0), sorted_order
+@jax.custom_vjp
+def _combine(z, tok, inv):
+    """The K expert-order rows of each token summed back into token
+    order [S, D]: the rows are read where they lie (a permutation
+    gather) and reduced by K with f32 accumulation."""
+    S, K = inv.shape
+    rows = _rows(z, inv.reshape(S * K))
+    return rows.reshape(S, K, -1).sum(axis=1, dtype=jnp.float32).astype(
+        z.dtype)
 
 
-def _dispatch_gather_bwd(K, sorted_order, g):
-    flat = jnp.take(g, jnp.argsort(sorted_order), axis=0)  # slot order
-    dh = flat.reshape(-1, K, g.shape[-1]).sum(axis=1)
-    return dh, None, None
+def _combine_fwd(z, tok, inv):
+    return _combine(z, tok, inv), (tok, inv)
 
 
-_dispatch_gather.defvjp(_dispatch_gather_fwd, _dispatch_gather_bwd)
+def _combine_bwd(res, g):
+    return _dispatch(g, *res), None, None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
 
 
 def _clamp(tiling, m, k, n):
@@ -182,6 +204,50 @@ def _grouped_mm(lhs, rhs, group_sizes):
     return jnp.einsum("se,sk,ekn->sn", sel, lhs, rhs)
 
 
+def _sort_slots_impl(e_flat, w):
+    iota = lax.iota(jnp.int32, e_flat.shape[0])
+    _, order, w_sorted = lax.sort((e_flat, iota, w), num_keys=1,
+                                  is_stable=True)
+    inv = jnp.argsort(order).astype(jnp.int32)
+    return jnp.stack([order, inv]), w_sorted
+
+
+@jax.custom_vjp
+def _sort_slots(e_flat, w):
+    """Sort the (token, k) slots by routed expert ``e_flat`` [S*K].
+    Returns ``(order, inv)`` stacked [2, S*K] int32 and the slots' gate
+    weights ``w`` [S*K] in that order: ``order`` lists the slots expert
+    by expert (stable: token order within an expert), ``inv =
+    argsort(order)`` says where each slot went. The weights ride the
+    first sort as a payload (cheaper than a gather of scalars); their
+    VJP is the gather by ``inv``."""
+    return _sort_slots_impl(e_flat, w)
+
+
+def _sort_slots_fwd(e_flat, w):
+    # Named HERE, so that the VJP's residual is the named value and a
+    # remat policy that saves these names ("attn+moe", "moe") re-runs
+    # no sort in the backward.
+    perm, w_sorted = _sort_slots_impl(e_flat, w)
+    perm = checkpoint_name(perm, "moe_perm")
+    return (perm, checkpoint_name(w_sorted, "moe_w_sorted")), perm[1]
+
+
+def _sort_slots_bwd(inv, g):
+    return None, _rows(g[1], inv)
+
+
+_sort_slots.defvjp(_sort_slots_fwd, _sort_slots_bwd)
+
+
+def _group_sizes(e_flat, n_experts):
+    """Slots per expert [E] int32, empty experts included: a fused
+    compare-and-reduce over [S*K, E], exact in int32 (``bincount`` is a
+    scatter-add of S*K ones)."""
+    experts = jnp.arange(n_experts, dtype=e_flat.dtype)
+    return jnp.sum(e_flat[:, None] == experts, axis=0, dtype=jnp.int32)
+
+
 def grouped_moe_ffn(h, lp, c):
     """Dropless top-K routed expert FFN over ``h`` [B, T, D] with the
     layer params ``lp`` (router [D, E], moe_gate/moe_up [E, D, F],
@@ -204,39 +270,41 @@ def grouped_moe_ffn(h, lp, c):
     gate_vals, gate_idx, aux = moe_route(hf, lp["router"], K,
                                          c.norm_topk_prob)     # [S, K]
 
-    # Sort the S*K (token, k) slots by routed expert. Indices are data
-    # (not differentiated); stop_gradient keeps the int chain out of
-    # the autodiff graph entirely.
+    # Sort the S*K (token, k) slots by routed expert: two sorts, the
+    # order (the gate weights ride it) and its inverse. Indices are
+    # data (not differentiated); stop_gradient keeps the int chain out
+    # of the autodiff graph entirely.
     e_flat = lax.stop_gradient(gate_idx.reshape(S * K))
-    order = jnp.argsort(e_flat)                    # sorted slot -> slot
-    group_sizes = jnp.bincount(e_flat, length=E).astype(jnp.int32)
+    (order, inv), w_sorted = _sort_slots(
+        e_flat, gate_vals.astype(dt).reshape(S * K))
+    tok, inv = order // K, inv.reshape(S, K)
+    group_sizes = _group_sizes(e_flat, E)
+
+    # Not named for any remat mode: gathering the rows again in the
+    # backward (from h, a source XLA holds in VMEM) is cheaper than
+    # saving them.
+    x_sorted = _dispatch(hf.astype(dt), tok, inv)
 
     # Residual names for the "moe" remat mode (save the expert-GEMM
-    # chain so backward re-runs NO grouped matmul): x_sorted is the
-    # tgmm lhs for dW_gate/dW_up; the PRE-silu gate is what silu's vjp
-    # needs; up pairs with it for the product rule.
-    x_sorted = checkpoint_name(
-        _dispatch_gather(hf.astype(dt), order // K, order, K),
-        "moe_x_sorted")
-
+    # chain so backward re-runs NO grouped matmul): the PRE-silu gate
+    # is what silu's vjp needs; up pairs with it for the product rule.
     gate_pre = checkpoint_name(
         _grouped_mm(x_sorted, lp["moe_gate"].astype(dt), group_sizes),
         "moe_gate_act")
     up = checkpoint_name(
         _grouped_mm(x_sorted, lp["moe_up"].astype(dt), group_sizes),
         "moe_up_act")
-    y_sorted = _grouped_mm(jax.nn.silu(gate_pre) * up,
-                           lp["moe_down"].astype(dt),
-                           group_sizes)            # [S*K, D]
 
-    # Un-permute to slot order (inverse-gather VJP) and combine with
-    # the gate weights. Named for the "attn+moe" remat mode:
-    # the router's combine-weight gradient needs y_slots (d gate_vals =
-    # <dy, y_slots>), which is what forces the backward remat to re-run
-    # the down-projection gmm — saving it trades [S*K, D] bf16 per
-    # layer for that re-run.
-    y_slots = checkpoint_name(
-        _unpermute(y_sorted, jnp.argsort(order), S * K), "moe_y_slots")
-    y = (y_slots.reshape(S, K, D)
-         * gate_vals.astype(dt)[..., None]).sum(axis=1)
+    # The gate weight of each slot scales its ACTIVATION row (in the
+    # compute dtype, in the elementwise pass that exists anyway), not
+    # its output row: sum_k w * (a @ Wd) = sum_k (w * a) @ Wd. The
+    # combine is then a plain sum of K rows, its backward a plain
+    # gather of dy, and the router's gradient d w = <d(w * a), a> comes
+    # out of the silu * up backward pass at width F: nothing is saved
+    # in slot order, and the down-projection's output is no residual of
+    # anything.
+    y_sorted = _grouped_mm(
+        jax.nn.silu(gate_pre) * up * w_sorted[:, None],
+        lp["moe_down"].astype(dt), group_sizes)    # [S*K, D]
+    y = _combine(y_sorted, tok, inv)
     return y.reshape(B, T, D), aux

@@ -195,6 +195,33 @@ def test_flash_kernels_keep_their_names_in_the_compiled_program(
     assert sorted(names) == ["hvd_flash_bwd_fused", "hvd_flash_fwd"]
 
 
+def test_routed_rows_move_through_bare_gathers_on_the_v5e(for_tpu):
+    """OLMoE's row movement (8192 tokens x 8 choices into 64 experts,
+    ``ops/grouped_moe.py``), forward + backward, as the chip's compiler
+    emits it: five gathers (dispatch and combine, their VJPs, the gate
+    weights' gradient), two sorts, no scatter for the group count or in
+    any VJP, and no select pass over a gathered ``[65536,2048]`` (what
+    ``jnp.take``'s default mode costs there)."""
+    from horovod_tpu.ops import grouped_moe
+
+    S, K, D, E = 8192, 8, 2048, 64
+
+    def rows(h, w, e_flat):
+        def f(h, w):
+            (order, inv), ws = grouped_moe._sort_slots(e_flat, w)
+            tok, inv = order // K, inv.reshape(S, K)
+            x = grouped_moe._dispatch(h, tok, inv) * ws[:, None]
+            return (grouped_moe._combine(x, tok, inv).astype(F32).sum()
+                    * grouped_moe._group_sizes(e_flat, E).sum())
+
+        return jax.value_and_grad(f, (0, 1))(h, w)
+
+    text = for_tpu(rows, ((S, D), BF16), ((S * K,), BF16), ((S * K,), I32))
+    count = lambda op: len(re.findall(rf" {op}\(", text))   # noqa: E731
+    assert (count("gather"), count("scatter"), count("sort")) == (5, 0, 2)
+    assert not re.search(r"= bf16\[65536,2048\]\S* select\(", text)
+
+
 def test_grad_program_of_a_one_layer_llama_holds_one_flash_bwd_call(
         v5e_chip, monkeypatch):
     """``jit_hvd_grad`` as the split step lowers it for the described

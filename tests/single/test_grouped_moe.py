@@ -15,9 +15,11 @@ interpret mode cannot differentiate).
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from horovod_tpu.models import LlamaConfig, llama_init, llama_loss
 from horovod_tpu.models.llama import _moe_ffn, moe_balance_loss
+from horovod_tpu.ops import grouped_moe
 from horovod_tpu.ops.grouped_moe import grouped_moe_ffn
 
 
@@ -39,8 +41,18 @@ def _dropless_cfg(**kw):
     return LlamaConfig.tiny_moe(dtype="float32", remat=False, **kw)
 
 
-def test_grouped_moe_matches_gshard_when_dropless():
-    cfg = _dropless_cfg()
+# The module's old bench routing (top-2 of 4) and OLMoE's (top-8 of 64,
+# gate weights as the softmax gave them) at a small width.
+ROUTINGS = [
+    pytest.param({}, id="k2-e4"),
+    pytest.param(dict(n_experts=64, n_experts_per_token=8,
+                      norm_topk_prob=False), id="k8-e64"),
+]
+
+
+@pytest.mark.parametrize("routing", ROUTINGS)
+def test_grouped_moe_matches_gshard_when_dropless(routing):
+    cfg = _dropless_cfg(**routing)
     lp = _layer0(cfg)
     h = _h(cfg)
     y_ref, aux_ref = _moe_ffn(h, lp, cfg, None)
@@ -51,8 +63,11 @@ def test_grouped_moe_matches_gshard_when_dropless():
                                rtol=1e-6)
 
 
-def test_grouped_moe_gradients_match_gshard():
-    cfg = _dropless_cfg()
+@pytest.mark.parametrize("routing", ROUTINGS)
+def test_grouped_moe_gradients_match_gshard(routing):
+    # h, the router and the three expert matrices (the other leaves of
+    # the layer get an exact zero from both).
+    cfg = _dropless_cfg(**routing)
     lp = _layer0(cfg)
     h = _h(cfg)
 
@@ -135,3 +150,124 @@ def test_bwd_tilings_clamp_per_direction():
     dlhs, tgmm = _bwd_tilings(16384, 2048, 4096)
     assert dlhs == (512, 1024, 1024), dlhs
     assert tgmm == (512, 1024, 1024), tgmm
+
+
+def _slots(case, E=8):
+    """Routed expert of every (token, k) slot, [S*K] int32."""
+    rng = np.random.RandomState(0)
+    return jnp.asarray({
+        # experts 1, 4 and 7 get nothing
+        "empty-experts": rng.choice([0, 2, 3, 5, 6], 96),
+        "one-expert": np.full(64, 5),
+        # 7 tokens x top-3: S and S*K no power of two
+        "s-not-a-power-of-two": rng.randint(0, E, 21),
+        "uniform": rng.randint(0, E, 256),
+    }[case], jnp.int32)
+
+
+SLOT_CASES = ["empty-experts", "one-expert", "s-not-a-power-of-two",
+              "uniform"]
+
+
+@pytest.mark.parametrize("case", SLOT_CASES)
+def test_group_sizes_equal_bincount(case):
+    e_flat = _slots(case)
+    got = grouped_moe._group_sizes(e_flat, 8)
+    assert got.dtype == jnp.int32 and got.shape == (8,)
+    np.testing.assert_array_equal(
+        got, np.bincount(np.asarray(e_flat), minlength=8))
+
+
+def _sorted(e_flat):
+    w = jnp.arange(e_flat.size, dtype=jnp.float32)
+    (order, inv), w_sorted = grouped_moe._sort_slots(e_flat, w)
+    return order, inv, w_sorted
+
+
+@pytest.mark.parametrize("case", SLOT_CASES)
+def test_sorted_order_and_the_inverse_handed_to_the_vjps(case):
+    e_flat = _slots(case)
+    order, inv, w_sorted = _sorted(e_flat)
+    assert order.dtype == inv.dtype == jnp.int32
+    # stable: experts ascending, token order within an expert
+    np.testing.assert_array_equal(
+        order, np.argsort(np.asarray(e_flat), kind="stable"))
+    np.testing.assert_array_equal(inv, np.argsort(np.asarray(order)))
+    np.testing.assert_array_equal(np.asarray(order)[np.asarray(inv)],
+                                  np.arange(e_flat.size))
+    # the weights rode the sort, and their VJP is the gather by inv
+    np.testing.assert_array_equal(w_sorted, np.asarray(order))
+    w = jax.random.normal(jax.random.PRNGKey(2), (e_flat.size,))
+    ws, pull = jax.vjp(lambda w: grouped_moe._sort_slots(e_flat, w)[1], w)
+    ws_ref, pull_ref = jax.vjp(lambda w: w[order], w)
+    np.testing.assert_array_equal(ws, ws_ref)
+    np.testing.assert_array_equal(pull(w)[0], pull_ref(w)[0])
+
+
+@pytest.mark.parametrize("case", SLOT_CASES)
+def test_dispatch_and_combine_are_each_others_vjp(case):
+    """Against plain indexing under autodiff (whose VJPs are scatter-
+    adds): every token repeats K times in ``tok``, experts tie."""
+    K = 3 if case == "s-not-a-power-of-two" else 4
+    e_flat = _slots(case)
+    S, D = e_flat.size // K, 16
+    order, inv, _ = _sorted(e_flat)
+    tok, inv = order // K, inv.reshape(S, K)
+    h = jax.random.normal(jax.random.PRNGKey(0), (S, D))
+    z = jax.random.normal(jax.random.PRNGKey(1), (S * K, D))
+
+    x, pull = jax.vjp(lambda h: grouped_moe._dispatch(h, tok, inv), h)
+    x_ref, pull_ref = jax.vjp(lambda h: h[tok], h)
+    np.testing.assert_array_equal(x, x_ref)
+    np.testing.assert_allclose(pull(z)[0], pull_ref(z)[0], rtol=1e-6,
+                               atol=1e-6)
+
+    y, pull = jax.vjp(lambda z: grouped_moe._combine(z, tok, inv), z)
+    y_ref, pull_ref = jax.vjp(
+        lambda z: z[inv.reshape(-1)].reshape(S, K, D).sum(1), z)
+    np.testing.assert_allclose(y, y_ref, rtol=1e-6, atol=1e-6)
+    np.testing.assert_array_equal(pull(h)[0], pull_ref(h)[0])
+
+
+def test_combine_accumulates_the_k_rows_in_float32():
+    # 1 + 8 * 2**-9 is 1.015625 in float32 and, added one bf16 rounding
+    # at a time, still 1.0.
+    z = jnp.asarray([[1.0]] + [[2.0 ** -9]] * 8, jnp.bfloat16)
+    inv = jnp.arange(9, dtype=jnp.int32).reshape(1, 9)
+    y = grouped_moe._combine(z, jnp.zeros(9, jnp.int32), inv)
+    assert y.dtype == jnp.bfloat16
+    assert float(y[0, 0]) == 1.015625
+
+
+def test_rows_lowers_to_a_bare_gather():
+    """No select over the gathered rows: ``jnp.take``'s default
+    ``mode="fill"`` masks the result against NaN. (The whole row
+    movement, forward and backward, as the v5e's compiler emits it:
+    ``test_chip_compile.py``.)"""
+    idx = _slots("uniform") // 4
+    D = 16
+    h = jnp.ones((idx.size // 4, D), jnp.bfloat16)
+
+    def selects_over_rows(text):
+        return [ln for ln in text.splitlines()
+                if "stablehlo.select" in ln and f"x{D}xbf16" in ln]
+
+    filled = jax.jit(lambda x, i: jnp.take(x, i, axis=0)).lower(
+        h, idx).as_text()
+    assert selects_over_rows(filled)           # what the check can see
+    text = jax.jit(grouped_moe._rows).lower(h, idx).as_text()
+    assert text.count('"stablehlo.gather"(') == 1
+    assert not selects_over_rows(text)
+
+
+@pytest.mark.parametrize("remat,sorts", [("attn", 4), ("attn+moe", 2),
+                                         ("moe", 2)])
+def test_remat_modes_that_save_the_order_sort_nothing_in_backward(
+        remat, sorts):
+    cfg = LlamaConfig.tiny_moe(dtype="float32", remat=remat)
+    params = llama_init(cfg, jax.random.PRNGKey(0))
+    tokens = jnp.zeros((2, 16), jnp.int32)
+    batch = {"tokens": tokens, "targets": tokens}
+    text = jax.jit(jax.grad(lambda p: llama_loss(p, batch, cfg))).lower(
+        params).as_text()
+    assert text.count("stablehlo.sort") == sorts

@@ -743,8 +743,9 @@ def test_remat_modes_agree_on_gradients_moe():
             lambda p: llama_loss(p, batch, cfg)))(params)
 
     ref_loss, ref_grads = loss_and_grads(False)
-    # attn+moe / moe cover the grouped path's saved residuals
-    # (y_slots; x_sorted/gate/up) — remat must stay scheduling-only.
+    # attn+moe / moe cover the grouped path's saved residuals (the
+    # sorted order, its inverse and the gate weights in that order;
+    # pre-silu gate and up) — remat must stay scheduling-only.
     for mode in ("attn", "attn+gate", "attn+gate+qkv", "attn+ffn",
                  "attn+moe", "moe", "dots", "full"):
         loss, grads = loss_and_grads(mode)

@@ -178,8 +178,19 @@ def test_remat_modes_give_the_same_loss_and_gradients(remat):
     loss_r, grads_r = jax.value_and_grad(llama_loss)(
         params, batch, dataclasses.replace(cfg, remat=remat))
     np.testing.assert_allclose(loss_r, loss, rtol=1e-6)
-    for a, b in zip(jax.tree.leaves(grads_r), jax.tree.leaves(grads)):
-        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-7)
+    # Every leaf to the bounds this test has always had, but the
+    # embedding's gradient: since the gate weight scales the expert
+    # activations, XLA's CPU fusions round silu * up * w differently
+    # where remat re-runs them, and the sum of every token's dh into
+    # the embedding reads 4.4e-7 (0.9 float32 ulp of its largest
+    # element, 5.72) over rtol * |b| at entries near zero, in every
+    # mode. Its atol is 2 ulps of that element.
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(grads_r),
+                            jax.tree.leaves(grads)):
+        atol = 1e-7
+        if jax.tree_util.keystr(path) == "['embed']":
+            atol = 2 * np.spacing(np.abs(b).max())
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=atol)
 
 
 def test_the_dense_configuration_is_what_it_was():
