@@ -47,6 +47,20 @@ from horovod_tpu.models.llama import (
 )
 
 
+def require_decodable(c):
+    """Decode and serving implement the uniform decoder (dense or
+    routed FFN, whole-width q/k-norm). The fields of the newer
+    architectures (a window, a layer pattern, a share of the experts,
+    an output gate, ...) are training only until the cache and this
+    module have them: refuse, never ignore one."""
+    fields = c.training_only_fields()
+    if fields:
+        raise ValueError(
+            f"LlamaConfig fields {fields} are training only "
+            "(llama_forward / llama_loss): prefill, cached decode and "
+            "serving do not implement them yet")
+
+
 def _ffn(h, lp, c):
     """llama.py's shared FFN, aux loss dropped (decode does not train).
     Serves prefill, dense decode, and MoE decode at large batch;
@@ -212,6 +226,7 @@ def llama_prefill(params, prompt, config, pad_to=0):
     tables), so sequence growth never re-allocates a monolithic
     buffer. Greedy only: the serving lane's elastic re-queue guarantee
     is token-identity, which sampling would break."""
+    require_decodable(config)
     x, cache_k, cache_v = _prefill(params, prompt, config, pad_to)
     logits = _lm_logits(params, x[:, -1:, :], config)[:, 0, :]
     return (jnp.argmax(logits, axis=-1).astype(prompt.dtype),
@@ -242,6 +257,7 @@ def llama_decode_step(params, tokens, cache_k, cache_v, lengths, config,
     from horovod_tpu.ops.decode_attention import decode_attention_ragged
 
     c = config
+    require_decodable(c)
     dt = c.compute_dtype
     b = tokens.shape[0]
     x = params["embed"].astype(dt)[tokens]          # [B, D]
@@ -295,6 +311,7 @@ def llama_generate(params, prompt, config, max_new_tokens,
     temperature is static because it selects greedy vs sampled tracing.
     """
     c = config
+    require_decodable(c)
     dt = c.compute_dtype
     b, t0 = prompt.shape
     if key is None:

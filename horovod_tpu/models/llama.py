@@ -69,7 +69,10 @@ class LlamaConfig:
     # width (one gain vector per projection and layer), before the split
     # into heads and RoPE: OLMoE's attention. Adds the ``q_norm`` /
     # ``k_norm`` leaves; off, the parameter tree has neither.
-    qk_norm: bool = False
+    # ``"head"``: the norm runs over EACH head's ``head_dim`` after the
+    # split, one gain of ``head_dim`` a projection and layer, shared by
+    # the heads (afmoe).
+    qk_norm: "bool | str" = False
     # Expert dispatch implementation: "grouped" = dropless sorted
     # grouped-GEMM (megablox; no capacity padding, no one-hot dispatch
     # einsums, no dropped tokens — fastest on a single program),
@@ -125,10 +128,124 @@ class LlamaConfig:
     # lets >1B-param configs fit; use fp32 when running few-hundred-M
     # models where master-precision weights are free.
     param_dtype: str = "float32"
+    # --- Published keys of architectures beyond the uniform decoder.
+    # Each is off at its default, and a configuration that leaves all of
+    # them there builds the parameter tree and the program it always
+    # did. Training only: models/generate.py refuses them
+    # (``training_only_fields``).
+    # Width of one head where it is not d_model / n_heads (``head_dim``
+    # in config.json; 0 = the quotient).
+    d_head: int = 0
+    # Sliding-window attention: a layer of type ``sliding_attention``
+    # sees its last ``sliding_window`` keys (itself included).
+    # ``layer_types`` names each layer's attention as Hugging Face's
+    # afmoe does: ``"sliding_attention"`` = the window AND RoPE,
+    # ``"full_attention"`` = every earlier key and NO position encoding.
+    # Empty: every layer full, with RoPE (the llama family).
+    sliding_window: int = 0
+    layer_types: tuple = ()
+    # The first ``n_dense_layers`` layers of a sparse-expert model keep
+    # the dense FFN of width ``d_ff`` (``num_dense_layers``); the expert
+    # layers' experts are ``moe_d_ff`` wide (``moe_intermediate_size``;
+    # 0 = ``d_ff``, as mixtral and OLMoE publish it). Their parameters
+    # are stacked apart: ``params["dense_layers"]`` before
+    # ``params["layers"]``.
+    n_dense_layers: int = 0
+    moe_d_ff: int = 0
+    # Experts every token passes beside its routed ones
+    # (``num_shared_experts``): one SwiGLU of width n x moe_d_ff.
+    n_shared_experts: int = 0
+    # Router: ``score_func`` "softmax" or "sigmoid" over all experts.
+    # With "sigmoid" a layer has an ``expert_bias`` leaf (float32 [E],
+    # no gradient reaches it): the K experts are chosen by score + bias
+    # and weighted by the score alone, times ``route_scale``.
+    score_func: str = "softmax"
+    route_scale: float = 1.0
+    # ``x = embed[tokens] * sqrt(d_model)`` (``mup_enabled``).
+    scale_embed: bool = False
+    # Attention's output is gated before ``wo``: ``a * sigmoid(h @ wg)``.
+    attn_gate: bool = False
+    # Four norms a layer: the attention's and the FFN's OUTPUT pass an
+    # RMSNorm of their own (``post_attn_norm``, ``post_mlp_norm``)
+    # before joining the residual stream.
+    post_norm: bool = False
+    # The share of an expert-parallel deployment this program holds:
+    # experts ``first_expert .. first_expert + n_experts_held - 1`` of
+    # each expert layer (0 held = all). The router scores and chooses
+    # over all ``n_experts``; the layer computes the shared expert plus
+    # the chosen experts it holds, and what the absent ones would add is
+    # left out (ops/grouped_moe.py; grouped dispatch only).
+    first_expert: int = 0
+    n_experts_held: int = 0
+
+    def __post_init__(self):
+        if self.layer_types and len(self.layer_types) != self.n_layers:
+            raise ValueError(
+                f"layer_types names {len(self.layer_types)} layers, "
+                f"n_layers is {self.n_layers}")
+        if any(t not in ("sliding_attention", "full_attention")
+               for t in self.layer_types):
+            raise ValueError(f"unknown layer type in {self.layer_types}")
+        if "sliding_attention" in self.layer_types \
+                and self.sliding_window <= 0:
+            raise ValueError("sliding_attention layers need a "
+                             "sliding_window")
+        if self.n_dense_layers and not (
+                self.n_experts > 0
+                and self.n_dense_layers < self.n_layers):
+            raise ValueError("n_dense_layers counts the leading dense "
+                             "layers of a sparse-expert model: fewer "
+                             "than n_layers, with n_experts > 0")
+        if self.score_func not in ("softmax", "sigmoid"):
+            raise ValueError(f"unknown score_func {self.score_func!r}")
+        if self.qk_norm not in (False, True, "head"):
+            raise ValueError(f"unknown qk_norm {self.qk_norm!r}")
+        if (self.first_expert or self.n_experts_held) and not (
+                0 <= self.first_expert
+                and 0 < self.n_experts_held
+                and self.first_expert + self.n_experts_held
+                <= self.n_experts):
+            raise ValueError(
+                f"experts {self.first_expert}..+{self.n_experts_held} "
+                f"are no share of {self.n_experts}")
 
     @property
     def head_dim(self):
-        return self.d_model // self.n_heads
+        return self.d_head or self.d_model // self.n_heads
+
+    @property
+    def expert_width(self):
+        return self.moe_d_ff or self.d_ff
+
+    @property
+    def experts_here(self):
+        """How many experts' weights a layer of this program holds."""
+        return self.n_experts_held or self.n_experts
+
+    def layer_kinds(self):
+        """One ``(dense_ffn, window, rope)`` a layer, in order: what
+        tells the layer programs of this model apart."""
+        kinds = []
+        for i in range(self.n_layers):
+            sliding = bool(self.layer_types) \
+                and self.layer_types[i] == "sliding_attention"
+            kinds.append((self.n_experts == 0 or i < self.n_dense_layers,
+                          self.sliding_window if sliding else 0,
+                          sliding or not self.layer_types))
+        return kinds
+
+    def training_only_fields(self):
+        """Names of the fields set here that only the training path
+        (``llama_forward`` / ``llama_loss``) implements; decode, serving
+        and the pipeline schedules refuse a configuration with any."""
+        d = LlamaConfig()
+        return [f for f in ("d_head", "sliding_window", "layer_types",
+                            "n_dense_layers", "moe_d_ff",
+                            "n_shared_experts", "score_func",
+                            "route_scale", "scale_embed", "attn_gate",
+                            "post_norm", "first_expert", "n_experts_held")
+                if getattr(self, f) != getattr(d, f)] \
+            + (["qk_norm"] if self.qk_norm == "head" else [])
 
     @property
     def compute_dtype(self):
@@ -165,7 +282,9 @@ def llama_init(config, key):
     float32 by default — "master weights" — or bfloat16 for the
     pure-bf16 large-model recipe).
 
-    Per-layer tensors are stacked on a leading n_layers axis for scan.
+    Per-layer tensors are stacked on a leading n_layers axis for scan;
+    a sparse-expert model's leading dense layers (``n_dense_layers``)
+    are a stack of their own, ``params["dense_layers"]``.
     """
     c = config
     hd = c.head_dim
@@ -179,36 +298,66 @@ def llama_init(config, key):
         return (jax.random.normal(key, shape, jnp.float32)
                 * (fan_in ** -0.5)).astype(pd)
 
-    L = c.n_layers
-    layers = {
-        "attn_norm": jnp.ones((L, c.d_model), pd),
-        "wq": dense(next(k), (L, c.d_model, c.n_heads * hd), c.d_model),
-        "wk": dense(next(k), (L, c.d_model, c.n_kv_heads * hd),
-                    c.d_model),
-        "wv": dense(next(k), (L, c.d_model, c.n_kv_heads * hd),
-                    c.d_model),
-        "wo": dense(next(k), (L, c.n_heads * hd, c.d_model),
-                    c.n_heads * hd),
-        "mlp_norm": jnp.ones((L, c.d_model), pd),
-    }
-    if c.qk_norm:
-        layers["q_norm"] = jnp.ones((L, c.n_heads * hd), pd)
-        layers["k_norm"] = jnp.ones((L, c.n_kv_heads * hd), pd)
-    if c.n_experts > 0:
-        E = c.n_experts
+    def stack(k, x, L, dense_ffn):
+        """``L`` layers of one FFN kind, stacked. ``k`` deals the keys
+        of the leaves every configuration has, in the order it always
+        did; ``x`` those of the leaves only the newer fields add, so
+        that an older configuration's weights do not move."""
+        layers = {
+            "attn_norm": jnp.ones((L, c.d_model), pd),
+            "wq": dense(next(k), (L, c.d_model, c.n_heads * hd),
+                        c.d_model),
+            "wk": dense(next(k), (L, c.d_model, c.n_kv_heads * hd),
+                        c.d_model),
+            "wv": dense(next(k), (L, c.d_model, c.n_kv_heads * hd),
+                        c.d_model),
+            "wo": dense(next(k), (L, c.n_heads * hd, c.d_model),
+                        c.n_heads * hd),
+            "mlp_norm": jnp.ones((L, c.d_model), pd),
+        }
+        if c.qk_norm == "head":
+            layers["q_norm"] = jnp.ones((L, hd), pd)
+            layers["k_norm"] = jnp.ones((L, hd), pd)
+        elif c.qk_norm:
+            layers["q_norm"] = jnp.ones((L, c.n_heads * hd), pd)
+            layers["k_norm"] = jnp.ones((L, c.n_kv_heads * hd), pd)
+        if c.attn_gate:
+            layers["wg"] = dense(next(x), (L, c.d_model, c.n_heads * hd),
+                                 c.d_model)
+        if c.post_norm:
+            layers["post_attn_norm"] = jnp.ones((L, c.d_model), pd)
+            layers["post_mlp_norm"] = jnp.ones((L, c.d_model), pd)
+        if dense_ffn:
+            layers.update({
+                "w_gate": dense(next(k), (L, c.d_model, c.d_ff),
+                                c.d_model),
+                "w_up": dense(next(k), (L, c.d_model, c.d_ff), c.d_model),
+                "w_down": dense(next(k), (L, c.d_ff, c.d_model), c.d_ff),
+            })
+            return layers
+        E, H, F = c.n_experts, c.experts_here, c.expert_width
         layers.update({
             "router": dense(next(k), (L, c.d_model, E), c.d_model),
-            "moe_gate": dense(next(k), (L, E, c.d_model, c.d_ff),
-                              c.d_model),
-            "moe_up": dense(next(k), (L, E, c.d_model, c.d_ff), c.d_model),
-            "moe_down": dense(next(k), (L, E, c.d_ff, c.d_model), c.d_ff),
+            "moe_gate": dense(next(k), (L, H, c.d_model, F), c.d_model),
+            "moe_up": dense(next(k), (L, H, c.d_model, F), c.d_model),
+            "moe_down": dense(next(k), (L, H, F, c.d_model), F),
         })
-    else:
-        layers.update({
-            "w_gate": dense(next(k), (L, c.d_model, c.d_ff), c.d_model),
-            "w_up": dense(next(k), (L, c.d_model, c.d_ff), c.d_model),
-            "w_down": dense(next(k), (L, c.d_ff, c.d_model), c.d_ff),
-        })
+        if c.score_func == "sigmoid":
+            # float32 whatever param_dtype: it is compared with scores.
+            layers["expert_bias"] = jnp.zeros((L, E), jnp.float32)
+        if c.n_shared_experts:
+            Fs = c.n_shared_experts * F
+            layers.update({
+                "shared_gate": dense(next(x), (L, c.d_model, Fs),
+                                     c.d_model),
+                "shared_up": dense(next(x), (L, c.d_model, Fs),
+                                   c.d_model),
+                "shared_down": dense(next(x), (L, Fs, c.d_model), Fs),
+            })
+        return layers
+
+    layers = stack(k, iter(jax.random.split(jax.random.fold_in(key, 1), 8)),
+                   c.n_layers - c.n_dense_layers, c.n_experts == 0)
     params = {
         "embed": (jax.random.normal(next(k), (c.vocab_size, c.d_model),
                                     jnp.float32) * 0.02).astype(pd),
@@ -216,6 +365,10 @@ def llama_init(config, key):
         "final_norm": jnp.ones(c.d_model, pd),
         "lm_head": dense(next(k), (c.d_model, c.vocab_size), c.d_model),
     }
+    if c.n_dense_layers:
+        lead = jax.random.split(jax.random.fold_in(key, 2), 16)
+        params["dense_layers"] = stack(iter(lead[:8]), iter(lead[8:]),
+                                       c.n_dense_layers, True)
     return params
 
 
@@ -234,14 +387,16 @@ def llama_partition_rules(pipeline=False):
         # attn_norm, mlp_norm and, where the config has them, q_norm and
         # k_norm: a gain vector per layer, replicated.
         (r"layers/.*norm", P(lead, None)),
-        (r"layers/w[qkv]$", P(lead, "fsdp", "tensor")),
+        # "layers/" also matches "dense_layers/": both stacks shard alike.
+        (r"layers/w[qkvg]$", P(lead, "fsdp", "tensor")),
         (r"layers/wo", P(lead, "tensor", "fsdp")),
-        (r"layers/w_(gate|up)", P(lead, "fsdp", "tensor")),
-        (r"layers/w_down", P(lead, "tensor", "fsdp")),
+        (r"layers/(w|shared)_(gate|up)", P(lead, "fsdp", "tensor")),
+        (r"layers/(w|shared)_down", P(lead, "tensor", "fsdp")),
         # MoE: experts shard over the "expert" mesh axis (EP); within an
         # expert the FFN shards like the dense MLP. The router is tiny and
         # stays replicated.
         (r"layers/router", P(lead, None, None)),
+        (r"layers/expert_bias", P(lead, None)),
         (r"layers/moe_(gate|up)", P(lead, "expert", "fsdp", "tensor")),
         (r"layers/moe_down", P(lead, "expert", "tensor", "fsdp")),
         (r"final_norm", P(None)),
@@ -267,13 +422,17 @@ def _rope(x, positions, theta):
 
 
 def _attention(q, k, v, mesh, seq_axis, seq_parallel="ring",
-               flash_block=0):
+               flash_block=0, window=0):
     # remat="attn" naming: the SP paths name their OUTPUT ("attn_out");
     # the flash path names its custom-VJP residuals internally
     # (flash_o/flash_lse) instead — naming the transposed output TOO
     # would save a ~671 MB duplicate of flash_o at bench shapes (the
     # transpose is a distinct buffer) for no backward work saved.
     if mesh is not None and seq_axis and mesh.shape.get(seq_axis, 1) > 1:
+        if window:
+            raise ValueError("sliding-window layers run on the flash "
+                             "kernel only: no sequence-parallel mesh "
+                             "axis (ring / ulysses know no window)")
         if seq_parallel == "ulysses":
             from horovod_tpu.parallel.ulysses import ulysses_self_attention
 
@@ -302,6 +461,8 @@ def _attention(q, k, v, mesh, seq_axis, seq_parallel="ring",
         mesh = None
     blocks = {"block_q": flash_block, "block_k": flash_block} \
         if flash_block else {}
+    if window:
+        blocks["window"] = window
     return flash_attention(q, k, v, causal=True, mesh=mesh, **blocks)
 
 
@@ -313,12 +474,17 @@ def _activation_spec(mesh):
 def _head_proj(h, w, gain, c):
     """``h [..., D] @ w`` split into heads ``[..., heads, head_dim]``;
     with a ``gain`` (``qk_norm``) the RMSNorm over the whole projected
-    width comes first. The ONE q/k/v projection of training, prefill and
-    cached decode (models/generate.py)."""
+    width comes first, or (``qk_norm="head"``) over each head after.
+    The ONE q/k/v projection of training, prefill and cached decode
+    (models/generate.py)."""
     y = h @ w.astype(c.compute_dtype)
-    if gain is not None:
+    if gain is not None and c.qk_norm != "head":
         y = _rmsnorm(y, gain.astype(c.compute_dtype), c.norm_eps)
-    return y.reshape(*y.shape[:-1], -1, c.head_dim)
+    y = y.reshape(*y.shape[:-1], -1, c.head_dim)
+    if gain is not None and c.qk_norm == "head":
+        # Over each head's own width, one gain shared by the heads.
+        y = _rmsnorm(y, gain.astype(c.compute_dtype), c.norm_eps)
+    return y
 
 
 def _project_qkv(h, lp, c):
@@ -328,13 +494,20 @@ def _project_qkv(h, lp, c):
             _head_proj(h, lp["wv"], None, c))
 
 
-def moe_route(h, router_w, n_experts_per_token, norm_topk_prob=True):
+def moe_route(h, router_w, n_experts_per_token, norm_topk_prob=True,
+              score_func="softmax", bias=None, route_scale=1.0):
     """The ONE router: f32 logits matmul, softmax, top-K, the K
     probabilities renormalised (epsilon-guarded) where ``norm_topk_prob``
     and as the softmax gave them where not, and the load-balancing
-    statistics of these tokens. Shared by the GShard dispatch below, the
-    dropless grouped dispatch (ops/grouped_moe.py), and cached decode
-    (models/generate.py) so the three can never drift.
+    statistics of these tokens. ``score_func="sigmoid"`` scores each
+    expert by the sigmoid of its logit instead; a ``bias`` [E] (float32,
+    data: no gradient) is added to the scores for the CHOICE of the K
+    experts only, their weights are the scores themselves;
+    ``route_scale`` multiplies the weights last (afmoe's ``route_norm``
+    is ``norm_topk_prob``; its 1e-20 guard is the one here, which no
+    sum of K sigmoids comes near). Shared by the GShard dispatch below,
+    the dropless grouped dispatch (ops/grouped_moe.py), and cached
+    decode (models/generate.py) so the three can never drift.
 
     ``h`` is [..., D] with any leading shape; returns (gate_vals
     [..., K] f32, gate_idx [..., K] int32, balance [2, E] f32): row 0
@@ -344,15 +517,34 @@ def moe_route(h, router_w, n_experts_per_token, norm_topk_prob=True):
     """
     E = router_w.shape[-1]
     logits = h.astype(jnp.float32) @ router_w.astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)                # [..., E]
-    gate_vals, gate_idx = lax.top_k(probs, n_experts_per_token)
+    if score_func == "sigmoid":
+        probs = jax.nn.sigmoid(logits)
+    else:
+        probs = jax.nn.softmax(logits, axis=-1)            # [..., E]
+    if bias is None:
+        gate_vals, gate_idx = lax.top_k(probs, n_experts_per_token)
+    else:
+        _, gate_idx = lax.top_k(
+            probs + lax.stop_gradient(bias.astype(jnp.float32)),
+            n_experts_per_token)
+        gate_vals = jnp.take_along_axis(probs, gate_idx, axis=-1)
     if norm_topk_prob:
         gate_vals = gate_vals / jnp.maximum(
             gate_vals.sum(-1, keepdims=True), 1e-9)
+    if route_scale != 1.0:
+        gate_vals = gate_vals * route_scale
     lead = tuple(range(probs.ndim - 1))
     chosen = jax.nn.one_hot(gate_idx, E, dtype=jnp.float32).sum(-2)
     return gate_vals, gate_idx, jnp.stack([chosen.mean(lead),
                                            probs.mean(lead)])
+
+
+def route_layer(h, lp, c):
+    """:func:`moe_route` with one expert layer's parameters and the
+    configuration's router fields: the one call every dispatch makes."""
+    return moe_route(h, lp["router"], c.n_experts_per_token,
+                     c.norm_topk_prob, c.score_func,
+                     lp.get("expert_bias"), c.route_scale)
 
 
 def moe_balance_loss(balance):
@@ -389,8 +581,11 @@ def _moe_ffn(h, lp, c, mesh):
     E, K = c.n_experts, c.n_experts_per_token
     C = max(int(T * K * c.capacity_factor / E), 1)
 
-    gate_vals, gate_idx, aux = moe_route(h, lp["router"], K,
-                                         c.norm_topk_prob)     # [B,T,K]
+    if c.n_experts_held:
+        raise ValueError("a share of the experts (n_experts_held) runs "
+                         "through the grouped dispatch only: "
+                         "moe_impl='grouped'")
+    gate_vals, gate_idx, aux = route_layer(h, lp, c)           # [B,T,K]
 
     # Position of each (token, slot) in its expert's per-group capacity
     # buffer, filling slot 0 for every token before slot 1 (priority to
@@ -436,24 +631,35 @@ def _moe_ffn(h, lp, c, mesh):
     return y, aux
 
 
+def _swiglu(h, gate, up, down, dt):
+    return (jax.nn.silu(h @ gate.astype(dt)) * (h @ up.astype(dt))) \
+        @ down.astype(dt)
+
+
 def _ffn(h, lp, c, mesh=None):
     """One layer's FFN on normalized activations: dense siglu MLP, or
-    top-k expert routing for MoE configs. Returns (y, balance): the
-    router's load-balancing statistics [2, E], zero-width for a dense
-    layer (see moe_balance_loss).
+    top-k expert routing (plus the shared expert, where the
+    configuration has one) for a layer whose parameters hold a router.
+    Returns (y, balance): the router's load-balancing statistics [2, E],
+    zero-width for a dense layer (see moe_balance_loss).
     Shared by llama_forward and the cached decode path (generate.py) so
     the two can never diverge."""
     dt = c.compute_dtype
-    if c.n_experts > 0:
+    if "router" in lp:
         if c.moe_impl == "grouped" or (c.moe_impl == "auto"
                                        and mesh is None):
             from horovod_tpu.ops.grouped_moe import grouped_moe_ffn
 
-            return grouped_moe_ffn(h, lp, c)
-        if c.moe_impl not in ("auto", "gshard"):
+            y, aux = grouped_moe_ffn(h, lp, c)
+        elif c.moe_impl not in ("auto", "gshard"):
             raise ValueError(f"unknown moe_impl {c.moe_impl!r}: "
                              "expected 'auto', 'grouped', or 'gshard'")
-        return _moe_ffn(h, lp, c, mesh)
+        else:
+            y, aux = _moe_ffn(h, lp, c, mesh)
+        if c.n_shared_experts:
+            y = y + _swiglu(h, lp["shared_gate"], lp["shared_up"],
+                            lp["shared_down"], dt)
+        return y, aux
     # Named for remat="attn+ffn": saving the two up-projections (the
     # bulk of a layer's recomputed matmul FLOPs) lets backward rebuild
     # silu(gate)*up elementwise instead of re-running both matmuls.
@@ -496,10 +702,8 @@ def llama_forward(params, tokens, config, mesh=None, seq_axis="seq",
         tokens = lax.with_sharding_constraint(
             tokens, jax.sharding.NamedSharding(mesh, P(("data", "fsdp"),
                                                        "seq")))
-    x = params["embed"].astype(dt)[tokens]
+    x = _embed(params, tokens, c)
     x = constrain(x)
-
-    body = _build_layer_body(c, mesh, seq_axis)
 
     n_stages = mesh.shape.get("pipe", 1) if mesh is not None else 1
     if n_stages > 1:
@@ -512,13 +716,13 @@ def llama_forward(params, tokens, config, mesh=None, seq_axis="seq",
 
         M = _validate_pipeline(c, b, mesh, seq_axis, n_stages)
         xs = x.reshape(M, b // M, t, x.shape[-1])
-        ys, aux_total = gpipe(_stage_scan(body), params["layers"], xs,
-                              mesh)
+        ys, aux_total = gpipe(
+            _stage_scan(_build_layer_body(c, mesh, seq_axis)),
+            params["layers"], xs, mesh)
         x = ys.reshape(b, t, x.shape[-1])
         aux = aux_total / (c.n_layers * M)
     else:
-        x, balance = lax.scan(body, x, params["layers"],
-                              unroll=c.scan_unroll)
+        x, balance = _run_layers(params, x, c, mesh, seq_axis)
         aux = moe_balance_loss(balance)
 
     x = _rmsnorm(x, params["final_norm"].astype(dt), c.norm_eps)
@@ -533,14 +737,48 @@ def llama_forward(params, tokens, config, mesh=None, seq_axis="seq",
 
 def llama_expert_load(params, tokens, config):
     """How the router spread ``tokens`` [B, T] over the experts:
-    [n_layers, n_experts] float32, the number of tokens that chose expert
+    [expert layers, n_experts] float32, the tokens that chose expert
     e at each layer, all K choices counted (a row sums to B*T*K: the
     grouped dispatch computes every one of them). From the layers' own
     ``moe_route`` statistics, single program, no mesh."""
-    x = params["embed"].astype(config.compute_dtype)[tokens]
-    _, balance = lax.scan(_build_layer_body(config, None, None), x,
-                          params["layers"])
+    _, balance = _run_layers(params, _embed(params, tokens, config),
+                             config, None, None)
     return balance[:, 0] * tokens.size
+
+
+def _embed(params, tokens, c):
+    x = params["embed"].astype(c.compute_dtype)[tokens]
+    if c.scale_embed:
+        x = x * jnp.asarray(c.d_model ** 0.5, x.dtype)
+    return x
+
+
+def _run_layers(params, x, c, mesh, seq_axis):
+    """The decoder stack on ``x`` [B, T, D]; returns (x, the expert
+    layers' balance statistics stacked [layers, 2, E]).
+
+    A uniform model (every layer one kind) is ONE ``lax.scan`` of the
+    layer body over the stacked parameters, as it always was. A layer
+    pattern (leading dense layers; window and full attention layers
+    mixed) is a different PROGRAM a kind: its layers run unrolled, each
+    the body of its own kind on a static slice of its parameter stack
+    (``dense_layers``, then ``layers``). Program size is O(depth) then;
+    a scan over whole periods of the pattern waits for a configuration
+    deep enough to need it."""
+    kinds = c.layer_kinds()
+    if len(set(kinds)) == 1:
+        return lax.scan(_build_layer_body(c, mesh, seq_axis), x,
+                        params["layers"], unroll=c.scan_unroll)
+    bodies = {kind: _build_layer_body(c, mesh, seq_axis, kind=kind)
+              for kind in set(kinds)}
+    balance = []
+    for at, kind in enumerate(kinds):
+        name, j = ("dense_layers", at) if at < c.n_dense_layers \
+            else ("layers", at - c.n_dense_layers)
+        x, bal = bodies[kind](x, jax.tree.map(lambda w: w[j], params[name]))
+        balance.append(bal)
+    # Dense layers carry zero-width statistics: the expert layers' only.
+    return x, jnp.stack([b for b in balance if b.shape[-1]] or balance)
 
 
 def _constrain(x, mesh):
@@ -566,6 +804,10 @@ def _validate_pipeline(c, b, mesh, seq_axis, n_stages):
     this layout (ring attention's own shard_map cannot nest inside the
     pipeline's)."""
     M = c.pipeline_microbatches or n_stages
+    if len(set(c.layer_kinds())) > 1:
+        raise ValueError("a layer pattern (leading dense layers, window "
+                         "and full attention mixed) has no pipeline "
+                         "schedule yet: a stage scans ONE layer program")
     if seq_axis and mesh.shape.get(seq_axis, 1) > 1:
         raise ValueError("pipeline (pipe>1) and sequence parallelism "
                          "(seq>1) cannot combine: ring attention's "
@@ -590,15 +832,20 @@ def _validate_pipeline(c, b, mesh, seq_axis, n_stages):
     return M
 
 
-def _build_layer_body(c, mesh, seq_axis, constrain_acts=True):
+def _build_layer_body(c, mesh, seq_axis, constrain_acts=True, kind=None):
     """One decoder layer as a scan body, wrapped in the configured
     remat policy — shared by llama_forward (single-device and gpipe)
     and the 1F1B training path. ``constrain_acts=False`` drops the
     per-activation sharding constraints (the 1F1B path differentiates
     INSIDE the pipe-manual shard_map, and XLA CPU aborts transposing
     with_sharding_constraint on auto axes there; GSPMD still lays out
-    activations by propagation from the sharded params)."""
+    activations by propagation from the sharded params). ``kind``:
+    this layer's ``(dense_ffn, window, rope)`` of
+    ``LlamaConfig.layer_kinds`` (default: the first layer's, the only
+    one a uniform model has); the FFN follows the parameters it is
+    handed (``_ffn``)."""
     dt = c.compute_dtype
+    _, window, rope = kind or c.layer_kinds()[0]
 
     def constrain(x):
         return _constrain(x, mesh) if constrain_acts else x
@@ -614,17 +861,28 @@ def _build_layer_body(c, mesh, seq_axis, constrain_acts=True):
         # v ([B,T,H(kv),D] bf16 — ~67 MB/layer at bench shapes) lets
         # backward skip the wq/wk/wv matmul + rope re-runs entirely
         # (attn_out/flash_o already cover wo's operands).
-        q = checkpoint_name(_rope(q, positions, c.rope_theta), "rope_q")
-        kk = checkpoint_name(_rope(kk, positions, c.rope_theta),
-                             "rope_k")
+        if rope:
+            q = _rope(q, positions, c.rope_theta)
+        q = checkpoint_name(q, "rope_q")
+        if rope:
+            kk = _rope(kk, positions, c.rope_theta)
+        kk = checkpoint_name(kk, "rope_k")
         vv = checkpoint_name(vv, "attn_v")
         # remat="attn" save-names applied inside _attention (per path).
         attn = _attention(q, kk, vv, mesh, seq_axis, c.seq_parallel,
-                          c.flash_block)
-        x = x + constrain(attn.reshape(bb, tt, -1) @ lp["wo"].astype(dt))
+                          c.flash_block, window).reshape(bb, tt, -1)
+        if c.attn_gate:
+            attn = attn * jax.nn.sigmoid(h @ lp["wg"].astype(dt))
+        attn = attn @ lp["wo"].astype(dt)
+        if c.post_norm:
+            attn = _rmsnorm(attn, lp["post_attn_norm"].astype(dt),
+                            c.norm_eps)
+        x = x + constrain(attn)
 
         h = _rmsnorm(x, lp["mlp_norm"].astype(dt), c.norm_eps)
         ff, aux = _ffn(h, lp, c, mesh)
+        if c.post_norm:
+            ff = _rmsnorm(ff, lp["post_mlp_norm"].astype(dt), c.norm_eps)
         x = x + constrain(ff)
         return x, aux
 
@@ -753,7 +1011,7 @@ def llama_loss(params, batch, config, mesh=None, seq_axis="seq"):
     else:
         mask = mask.astype(jnp.float32)
         loss = jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
-    if config.n_experts > 0:
+    if config.n_experts > 0 and config.moe_aux_weight:
         loss = loss + config.moe_aux_weight * aux
     return loss
 
@@ -890,7 +1148,7 @@ def _llama_loss_1f1b(params, batch, c, mesh, seq_axis, n_stages):
 
     schedule.defvjp(schedule_fwd, schedule_bwd)
 
-    x = _constrain(params["embed"].astype(dt)[tokens], mesh)
+    x = _constrain(_embed(params, tokens, c), mesh)
     xs = x.reshape(M, b // M, t, x.shape[-1])
     largs = (batch["targets"].reshape(M, b // M, t),
              mask.reshape(M, b // M, t))

@@ -1,7 +1,9 @@
 """Plain float32 references of the architectures the llama path runs
 beyond the dense decoder. What the program's kernels, sorts, scans and
-remat modes are compared against (tests/single/test_olmoe_reference.py;
-the chip benchmark keeps a copy of its own, chipbench/models/olmoe.py).
+remat modes are compared against (tests/single/test_olmoe_reference.py,
+tests/single/test_afmoe_reference.py; the chip benchmark keeps copies
+of its own, chipbench/models/olmoe.py and afmoe.py). OLMoE first;
+Trinity-Mini (afmoe) below it, with its own description.
 
 OLMoE (arXiv:2409.02060; Hugging Face ``modeling_olmoe.py``), as
 published:
@@ -130,3 +132,147 @@ def olmoe_loss(params, batch, cfg):
                                -1)[..., 0]
     mask = batch.get("mask", jnp.ones_like(nll))
     return jnp.sum(nll * mask) / jnp.sum(mask) + cfg.moe_aux_weight * aux
+
+
+# ---------------------------------------------------------------------
+# Trinity-Mini (arcee-ai, ``model_type`` ``afmoe``; Hugging Face
+# ``modeling_afmoe.py``), as published. ``RMS`` is an RMSNorm with its
+# own gain, eps ``norm_eps``:
+#
+# - embedding: ``x = E[tokens] * sqrt(d_model)`` (``mup_enabled``);
+# - attention of layer l: ``h = RMS_in(x)``; ``q = RMS_q(W_q h)``,
+#   ``k = RMS_k(W_k h)`` with the norm over EACH head's ``head_dim`` (one
+#   gain of ``head_dim`` a projection and layer, shared by the heads),
+#   ``v = W_v h``, ``g = W_g h``; half-split RoPE on q and k where
+#   ``layer_types[l]`` is ``sliding_attention``, NONE where it is
+#   ``full_attention``; key j is visible to query i where ``j <= i``, and
+#   on a sliding layer also ``j > i - sliding_window``;
+#   ``a = softmax(q k / sqrt(head_dim)) v``;
+#   ``x = x + RMS_post_attn(W_o (a * sigmoid(g)))``;
+# - FFN of layer l: ``h = RMS_pre_mlp(x)``; a dense layer
+#   (``l < n_dense_layers``) ``y = W_down(silu(W_gate h) * W_up h)``; an
+#   expert layer ``s = sigmoid(W_r h)`` over all experts, the K experts
+#   with the largest ``s + expert_bias``, ``w_k = route_scale * s_k /
+#   (sum of the chosen s + 1e-20)``, ``y = Shared(h) + sum_k w_k
+#   Expert_k(h)``, each a SwiGLU; ``x = x + RMS_post_mlp(y)``;
+# - ``logits = W_head RMS_final(x)``; loss = mean token cross-entropy.
+#
+# The share. Where ``cfg.n_experts_held`` is set the parameter tree holds
+# the matrices of experts ``first_expert .. first_expert + held - 1``
+# only: the router still scores and chooses over all ``n_experts``, and
+# the sum runs over the chosen experts that are held. What the absent
+# ones would add is left out and that partial result goes on to the next
+# layer, as in the program. A sliced vocabulary is a smaller vocabulary
+# (``vocab_rows`` of :func:`afmoe_loss` cuts an uncut model's logits the
+# same way).
+#
+# Departures: Hugging Face's forward returns no router loss and
+# ``config.json`` gives ``load_balance_coeff`` without a formula: no aux
+# term; ``expert_bias``'s update rule is the trainer's, not the model's:
+# it is read as data. Parameters stored in bf16 are read as float32.
+# Written like ``olmoe_forward``: explicit masks, a Python loop over
+# layers, every held expert computed for every token and weighted (zero
+# where not chosen), nothing shared with models/llama.py or ops/.
+# ---------------------------------------------------------------------
+
+def _swiglu(h, gate, up, down):
+    return (jax.nn.silu(h @ gate) * (h @ up)) @ down
+
+
+def afmoe_route(h, lp, cfg):
+    """``h`` [..., D] -> weights [..., E] over ALL experts: ``route_scale
+    * s / (sum of the chosen s + 1e-20)`` at the K experts with the
+    largest ``s + expert_bias``, 0 elsewhere."""
+    n = cfg.n_experts
+    s = jax.nn.sigmoid(h @ lp["router"])
+    left, chosen = s + lp["expert_bias"], jnp.zeros_like(s)
+    for _ in range(cfg.n_experts_per_token):
+        pick = jax.nn.one_hot(jnp.argmax(left, -1), n, dtype=F32)
+        chosen = chosen + pick
+        left = jnp.where(pick > 0, -jnp.inf, left)
+    w = chosen * s
+    return cfg.route_scale * w / (jnp.sum(w, -1, keepdims=True) + 1e-20)
+
+
+def afmoe_expert_layer(h, lp, cfg):
+    """The FFN of one expert layer on normalized ``h`` [B, T, D], in two
+    parts: ``(Shared(h), the routed sum over the experts ``lp`` holds)``.
+    ``lp`` is one layer's float32 parameters; its expert matrices hold
+    experts ``first_expert .. + n_experts_held - 1`` (all, where no
+    share is set)."""
+    first = cfg.first_expert
+    held = cfg.n_experts_held or cfg.n_experts
+    w = afmoe_route(h, lp, cfg)[..., first:first + held]
+    act = jax.nn.silu(jnp.einsum("btd,edf->btef", h, lp["moe_gate"])) \
+        * jnp.einsum("btd,edf->btef", h, lp["moe_up"])
+    y = jnp.einsum("btef,efd->bted", act, lp["moe_down"])
+    return (_swiglu(h, lp["shared_gate"], lp["shared_up"],
+                    lp["shared_down"]),
+            jnp.einsum("bte,bted->btd", w, y))
+
+
+def afmoe_forward(params, tokens, cfg):
+    """tokens [B, T] -> logits [B, T, vocab] f32 (see the description
+    above). ``params`` is the program's tree: ``dense_layers`` and
+    ``layers`` stacked, any storage dtype."""
+    hd = cfg.head_dim
+    rep = cfg.n_heads // cfg.n_kv_heads
+    b, t = tokens.shape
+    inv = cfg.rope_theta ** (-jnp.arange(0, hd // 2, dtype=F32)
+                             / (hd // 2))
+    ang = jnp.arange(t, dtype=F32)[:, None] * inv           # [T, hd/2]
+    cos, sin = jnp.cos(ang)[None, :, None], jnp.sin(ang)[None, :, None]
+
+    def rope(x):
+        x1, x2 = x[..., :hd // 2], x[..., hd // 2:]
+        return jnp.concatenate([x1 * cos - x2 * sin,
+                                x1 * sin + x2 * cos], -1)
+
+    i, j = jnp.arange(t)[:, None], jnp.arange(t)[None, :]
+    with jax.default_matmul_precision("highest"):
+        x = params["embed"].astype(F32)[tokens] * (cfg.d_model ** 0.5)
+        for l in range(cfg.n_layers):
+            dense = l < cfg.n_dense_layers
+            stack, at = (params["dense_layers"], l) if dense \
+                else (params["layers"], l - cfg.n_dense_layers)
+            lp = jax.tree.map(lambda w: w[at].astype(F32), stack)
+            sliding = cfg.layer_types[l] == "sliding_attention"
+            h = _rms(x, lp["attn_norm"], cfg.norm_eps)
+            q = _rms((h @ lp["wq"]).reshape(b, t, cfg.n_heads, hd),
+                     lp["q_norm"], cfg.norm_eps)
+            k = _rms((h @ lp["wk"]).reshape(b, t, cfg.n_kv_heads, hd),
+                     lp["k_norm"], cfg.norm_eps)
+            v = (h @ lp["wv"]).reshape(b, t, cfg.n_kv_heads, hd)
+            mask = j <= i
+            if sliding:
+                q, k = rope(q), rope(k)
+                mask = mask & (j > i - cfg.sliding_window)
+            k, v = jnp.repeat(k, rep, 2), jnp.repeat(v, rep, 2)
+            s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / (hd ** 0.5)
+            p = jax.nn.softmax(jnp.where(mask, s, -jnp.inf), -1)
+            a = jnp.einsum("bhqk,bkhd->bqhd", p, v).reshape(b, t, -1)
+            a = (a * jax.nn.sigmoid(h @ lp["wg"])) @ lp["wo"]
+            x = x + _rms(a, lp["post_attn_norm"], cfg.norm_eps)
+
+            h = _rms(x, lp["mlp_norm"], cfg.norm_eps)
+            if dense:
+                y = _swiglu(h, lp["w_gate"], lp["w_up"], lp["w_down"])
+            else:
+                y = sum(afmoe_expert_layer(h, lp, cfg))
+            x = x + _rms(y, lp["post_mlp_norm"], cfg.norm_eps)
+        x = _rms(x, params["final_norm"].astype(F32), cfg.norm_eps)
+        return x @ params["lm_head"].astype(F32)
+
+
+def afmoe_loss(params, batch, cfg, vocab_rows=None):
+    """Mean token cross-entropy over the positions ``batch["mask"]``
+    keeps (all without one); no aux term. ``vocab_rows``: the loss over
+    the first that many rows of the vocabulary, the other logits
+    removed (what a chip that holds that slice of the head computes).
+    ``jax.grad`` of this is the reference gradient."""
+    logits = afmoe_forward(params, batch["tokens"], cfg)
+    logp = jax.nn.log_softmax(logits[..., :vocab_rows], -1)
+    nll = -jnp.take_along_axis(logp, batch["targets"][..., None],
+                               -1)[..., 0]
+    mask = batch.get("mask", jnp.ones_like(nll))
+    return jnp.sum(nll * mask) / jnp.sum(mask)

@@ -27,7 +27,9 @@ Kernel design (v5e-friendly):
   select; STRADDLING, masked by global position.
   Causal at 1024 x 1024 blocks a (batch, head) at T = 4096 has
   6 / 6 / 4 of them, at T = 8192 28 / 28 / 8; a non-causal call has
-  interior tiles only.
+  interior tiles only. A call with a ``window`` sees a band under the
+  diagonal: tiles wholly below it are skipped too, and both edges mask
+  (T = 8192, window 2048: 43 / 7 / 14).
 - the softmax scale is applied to the [block_q, D] q tile, and to the
   finished dq / dk accumulators, never to a [block_q, block_k] plane;
   the zero-valid-key guard of the backward runs only where such a row
@@ -69,54 +71,73 @@ _NEG = -1e30
 _INTERPRET = False
 
 
-def _tile_kinds(causal, q_first, block_q, kv_first, block_k):
+def _tile_kinds(causal, q_first, block_q, kv_first, block_k, window=0):
     """``(interior, straddling)`` of the score tile whose first GLOBAL
     query row is ``q_first`` and whose first GLOBAL key is ``kv_first``.
     Interior: the tile's last key is visible to its first row, so no
     element is masked. Straddling: the diagonal crosses it. Neither: no
-    element is valid and the tile is skipped. One formula for Python
-    ints (``tile_counts``) and for traced scalars (the kernels, where
-    the bases are program ids plus, with offsets, SMEM values)."""
+    element is valid and the tile is skipped. With a ``window`` W (key j
+    visible to row i where ``i - W < j <= i``) the visible keys are a
+    BAND: a tile is interior only if its first key is also inside the
+    window of its last row, is skipped also where its last key lies at
+    or before ``q_first - W`` (wholly below the band), and straddles
+    where either edge crosses it. One formula for Python ints
+    (``tile_counts``) and for traced scalars (the kernels, where the
+    bases are program ids plus, with offsets, SMEM values); whether
+    there is a window is decided in Python, so a call without one
+    traces what it always did."""
     if not causal:
         return True, False
+    q_last = q_first + block_q - 1
     kv_last = kv_first + block_k - 1
     interior = kv_last <= q_first
-    straddling = (kv_first <= q_first + block_q - 1) & (kv_last > q_first)
+    straddling = (kv_first <= q_last) & (kv_last > q_first)
+    if window:
+        # The band's lower edge: wholly inside it, or wholly below it.
+        inside = kv_first > q_last - window
+        straddling = (kv_first <= q_last) & (kv_last > q_first - window) \
+            & ((kv_last > q_first) | (kv_first <= q_last - window))
+        interior = interior & inside
     return interior, straddling
 
 
-def tile_counts(t, tk, block_q, block_k, causal, q_offset=0, kv_offset=0):
+def tile_counts(t, tk, block_q, block_k, causal, q_offset=0, kv_offset=0,
+                window=0):
     """``(skipped, interior, straddling)`` score tiles of one (batch,
     head) slice, by the predicate the kernels run on: what the forward
     and the backward skip, run without a mask, and run with one. Causal
     at 1024 x 1024 blocks: T = 4096 gives 6 / 6 / 4, T = 8192 gives
-    28 / 28 / 8; a non-causal call has interior tiles only."""
+    28 / 28 / 8, and with a window of 2048 at T = 8192 43 / 7 / 14 (21
+    tiles run of the 36 a full layer runs); a non-causal call has
+    interior tiles only."""
     interior = straddling = 0
     for iq in range(t // block_q):
         for jk in range(tk // block_k):
             inside, crossing = _tile_kinds(
                 causal, q_offset + iq * block_q, block_q,
-                kv_offset + jk * block_k, block_k)
+                kv_offset + jk * block_k, block_k, window)
             interior += bool(inside)
             straddling += bool(crossing)
     n = (t // block_q) * (tk // block_k)
     return n - interior - straddling, interior, straddling
 
 
-def _for_each_kind(causal, q_first, block_q, kv_first, block_k, update):
+def _for_each_kind(causal, q_first, block_q, kv_first, block_k, update,
+                   window=0):
     """Run ``update(masked)`` as one score tile needs it: not at all
     (skipped), unmasked (interior), or masked (straddling)."""
     interior, straddling = _tile_kinds(causal, q_first, block_q,
-                                       kv_first, block_k)
+                                       kv_first, block_k, window)
     pl.when(interior)(functools.partial(update, False))
     pl.when(straddling)(functools.partial(update, True))
 
 
-def _scores(qs, k, q_first, kv_first, masked, bias):
+def _scores(qs, k, q_first, kv_first, masked, bias, window=0):
     """Scores of one tile from the SCALED q tile: ``qs k^T`` in f32, the
-    causal mask only where the diagonal crosses the tile (``masked``;
-    ``q_first`` / ``kv_first`` are the GLOBAL positions of its first
-    row and first key), the per-key bias row where the call has one."""
+    causal mask (and, with a ``window``, the band's lower edge) only
+    where an edge crosses the tile (``masked``; ``q_first`` /
+    ``kv_first`` are the GLOBAL positions of its first row and first
+    key), the per-key bias row where the call has one."""
     s = jax.lax.dot_general(qs, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)
     if masked:
@@ -126,7 +147,10 @@ def _scores(qs, k, q_first, kv_first, masked, bias):
             jnp.int32, (s.shape[0], 1), 0)
         kv_pos = kv_first + lax.broadcasted_iota(
             jnp.int32, (1, s.shape[1]), 1)
-        s = jnp.where(q_pos >= kv_pos, s, _NEG)
+        visible = q_pos >= kv_pos
+        if window:
+            visible = visible & (kv_pos > q_pos - window)
+        s = jnp.where(visible, s, _NEG)
     if bias is not None:
         s = s + bias
     return s
@@ -139,7 +163,7 @@ def _scaled(q, scale):
     return (q.astype(jnp.float32) * scale).astype(q.dtype)
 
 
-def _fwd_kernel(*refs, scale, causal, has_bias, has_offsets):
+def _fwd_kernel(*refs, scale, causal, has_bias, has_offsets, window=0):
     # refs = ([offs_ref,] q_ref, k_ref, v_ref, [bias_ref,] o_ref,
     # lse_ref, qs_ref, acc_ref, m_ref, l_ref). grid = (b, h, iq, jj):
     # q/o/lse blocks are keyed by iq (constant across the inner jj
@@ -178,7 +202,7 @@ def _fwd_kernel(*refs, scale, causal, has_bias, has_offsets):
     def update(masked):
         v_blk = v_ref[:, :]
         s = _scores(qs_ref[:, :], k_ref[:, :], q_first, kv_first, masked,
-                    bias_ref[:, :] if has_bias else None)
+                    bias_ref[:, :] if has_bias else None, window)
         m = m_ref[:, :]
         m_new = jnp.maximum(m, s.max(axis=1, keepdims=True))
         p = jnp.exp(s - m_new)
@@ -189,7 +213,7 @@ def _fwd_kernel(*refs, scale, causal, has_bias, has_offsets):
             preferred_element_type=jnp.float32)
         m_ref[:, :] = m_new
 
-    _for_each_kind(causal, q_first, bq, kv_first, bk, update)
+    _for_each_kind(causal, q_first, bq, kv_first, bk, update, window)
 
     @pl.when(jj == n_jj - 1)
     def _finish():
@@ -206,7 +230,7 @@ def _fwd_kernel(*refs, scale, causal, has_bias, has_offsets):
         lse_ref[:, :] = m_ref[:, :] + jnp.log(l)
 
 
-def _bwd_kernel(*refs, scale, causal, has_bias, has_offsets):
+def _bwd_kernel(*refs, scale, causal, has_bias, has_offsets, window=0):
     # The whole backward in one pass: per score tile s, p, dp and ds are
     # formed ONCE and feed all three products (5 matmuls).
     # grid = (b, h, jk, iq): k/v/dk/dv blocks are keyed by jk (constant
@@ -243,7 +267,7 @@ def _bwd_kernel(*refs, scale, causal, has_bias, has_offsets):
         k = k_ref[:, :]
         do = do_ref[:, :]
         s = _scores(_scaled(q, scale), k, q_first, kv_first, masked,
-                    bias_ref[:, :] if has_bias else None)
+                    bias_ref[:, :] if has_bias else None, window)
         p = jnp.exp(s - lse_ref[:, :])  # [bq, bk]
         if has_bias or (masked and has_offsets):
             # A q row with ZERO valid keys (a padded batch row; a ring
@@ -268,7 +292,7 @@ def _bwd_kernel(*refs, scale, causal, has_bias, has_offsets):
             ds, k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    _for_each_kind(causal, q_first, bq, kv_first, bk, update)
+    _for_each_kind(causal, q_first, bq, kv_first, bk, update, window)
 
     @pl.when(iq == pl.num_programs(3) - 1)
     def _finish_dkv():
@@ -281,7 +305,8 @@ def _bwd_kernel(*refs, scale, causal, has_bias, has_offsets):
 
 
 def _pallas_dispatch(name, kernel, grid, in_specs, out_specs, out_shape,
-                     args, offsets, scratch_shapes, vmem_limit_bytes=None):
+                     args, offsets, scratch_shapes, vmem_limit_bytes=None,
+                     window=0):
     """Shared fwd/bwd dispatch: plain grid, or scalar-prefetch grid
     spec when dynamic offsets ride along (the SMEM scalars arrive
     before the kernel body and every index map). ``scratch_shapes``
@@ -293,12 +318,17 @@ def _pallas_dispatch(name, kernel, grid, in_specs, out_specs, out_shape,
     op's event on the v5e shows (read off a chip trace, PR 25). The
     pallas ``name=`` would not: it names the instruction only while
     jax keeps full tracebacks in locations, and
-    ``enable_compile_cache()`` turns those off. ``vmem_limit_bytes``:
+    ``enable_compile_cache()`` turns those off. A call with a
+    ``window`` carries it beside the name,
+    ``kernel_metadata={"kernel":...,"window":"2048"}``: the same prefix,
+    so a reader by name finds both, and one by ``"window"`` only these.
+    ``vmem_limit_bytes``:
     what the kernel itself asks of VMEM, where the compiler's default
     scope (16 MiB on the v5e) is not enough."""
     common = dict(
         out_shape=out_shape, interpret=_INTERPRET,
-        metadata={"kernel": name},
+        metadata={"kernel": name, "window": str(window)} if window
+        else {"kernel": name},
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=vmem_limit_bytes))
     if offsets is not None:
@@ -322,9 +352,10 @@ def _pick_block(t, want):
     return b
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _flash(q, k, v, causal, block_q, block_k):
-    o, _ = _flash_fwd_impl(q, k, v, None, causal, block_q, block_k)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash(q, k, v, causal, block_q, block_k, window=0):
+    o, _ = _flash_fwd_impl(q, k, v, None, causal, block_q, block_k,
+                           window=window)
     return o
 
 
@@ -335,7 +366,7 @@ def _flash_biased(q, k, v, bias, causal, block_q, block_k):
 
 
 def _flash_fwd_impl(q, k, v, bias, causal, block_q, block_k,
-                    offsets=None):
+                    offsets=None, window=0):
     b, h, t, d = q.shape
     tk = k.shape[2]
     # GQA-native: k/v arrive UNREPEATED ([B, Hkv, T, D]); each query
@@ -349,7 +380,7 @@ def _flash_fwd_impl(q, k, v, bias, causal, block_q, block_k,
     has_offsets = offsets is not None
     kernel = functools.partial(_fwd_kernel, scale=scale,
                                causal=causal, has_bias=has_bias,
-                               has_offsets=has_offsets)
+                               has_offsets=has_offsets, window=window)
     # With scalar prefetch the index maps receive the scalar ref as a
     # trailing arg; *a soaks it up either way.
     n_jj = tk // block_k
@@ -362,8 +393,14 @@ def _flash_fwd_impl(q, k, v, bias, causal, block_q, block_k,
             return ji
         q_base, kv_base = (a[0][0], a[0][1]) if a else (0, 0)
         q_last = q_base + (qi + 1) * block_q - 1
-        return jnp.minimum(
-            ji, jnp.clip((q_last - kv_base) // block_k, 0, n_jj - 1))
+        last = jnp.clip((q_last - kv_base) // block_k, 0, n_jj - 1)
+        if not window:
+            return jnp.minimum(ji, last)
+        # Tiles wholly below the band come FIRST in a row of tiles:
+        # they name the block of the first tile that runs.
+        first = jnp.clip((q_last - block_q + 2 - window - kv_base)
+                         // block_k, 0, n_jj - 1)
+        return jnp.clip(ji, first, last)
 
     kv_spec = pl.BlockSpec(
         (None, None, block_k, d),
@@ -397,11 +434,13 @@ def _flash_fwd_impl(q, k, v, bias, causal, block_q, block_k,
         pltpu.VMEM((block_q, 1), jnp.float32),   # l
     ]
     return _pallas_dispatch("hvd_flash_fwd", kernel, grid, in_specs,
-                            out_specs, out_shape, args, offsets, scratch)
+                            out_specs, out_shape, args, offsets, scratch,
+                            window=window)
 
 
-def _flash_fwd(q, k, v, causal, block_q, block_k):
-    o, lse = _flash_fwd_impl(q, k, v, None, causal, block_q, block_k)
+def _flash_fwd(q, k, v, causal, block_q, block_k, window=0):
+    o, lse = _flash_fwd_impl(q, k, v, None, causal, block_q, block_k,
+                             window=window)
     # Residuals named for remat policies: an outer checkpoint_name on
     # the returned o covers only the PRIMAL output — the residual o/lse
     # here are distinct jaxpr vars, and leaving them unnamed makes
@@ -434,7 +473,7 @@ def _bwd_vmem_bytes(t, block_q, block_k, d, itemsize):
 
 
 def _flash_bwd_impl(q, k, v, bias, o, lse, do, causal, block_q, block_k,
-                    offsets=None, dlse=None):
+                    offsets=None, dlse=None, window=0):
     b, h, t, d = q.shape
     hkv = k.shape[1]
     tk = k.shape[2]
@@ -451,7 +490,8 @@ def _flash_bwd_impl(q, k, v, bias, o, lse, do, causal, block_q, block_k,
 
     n_jk, n_iq = tk // block_k, t // block_q
     kernel = functools.partial(_bwd_kernel, scale=scale, causal=causal,
-                               has_bias=has_bias, has_offsets=has_offsets)
+                               has_bias=has_bias, has_offsets=has_offsets,
+                               window=window)
     # grid (b, h, jk, iq) — q/do/lse/delta stream over the inner iq
     # dimension, k/v and the dk/dv accumulators stay pinned per jk.
 
@@ -461,9 +501,15 @@ def _flash_bwd_impl(q, k, v, bias, o, lse, do, causal, block_q, block_k,
         if not causal:
             return iq
         q_base, kv_base = (a[0][0], a[0][1]) if a else (0, 0)
-        return jnp.maximum(
-            iq, jnp.clip((kv_base + jk * block_k - q_base) // block_q,
-                         0, n_iq - 1))
+        kv_first = kv_base + jk * block_k
+        first = jnp.clip((kv_first - q_base) // block_q, 0, n_iq - 1)
+        if not window:
+            return jnp.maximum(iq, first)
+        # q blocks wholly below the band come LAST in a column of
+        # tiles: they name the block of the last tile that runs.
+        last = jnp.clip((kv_first + block_k + window - 2 - q_base)
+                        // block_q, 0, n_iq - 1)
+        return jnp.clip(iq, first, last)
 
     q_spec = pl.BlockSpec(
         (None, None, block_q, d),
@@ -508,7 +554,8 @@ def _flash_bwd_impl(q, k, v, bias, o, lse, do, causal, block_q, block_k,
          pltpu.VMEM((block_k, d), jnp.float32),
          pltpu.VMEM((block_k, d), jnp.float32)],
         vmem_limit_bytes=_bwd_vmem_bytes(t, block_q, block_k, d,
-                                         q.dtype.itemsize))
+                                         q.dtype.itemsize),
+        window=window)
     if n_rep > 1:
         dk = dk.astype(jnp.float32).reshape(b, hkv, n_rep, tk, d) \
             .sum(axis=2).astype(k.dtype)
@@ -517,10 +564,10 @@ def _flash_bwd_impl(q, k, v, bias, o, lse, do, causal, block_q, block_k,
     return dq, dk, dv
 
 
-def _flash_bwd(causal, block_q, block_k, res, do):
+def _flash_bwd(causal, block_q, block_k, window, res, do):
     q, k, v, o, lse = res
     return _flash_bwd_impl(q, k, v, None, o, lse, do, causal, block_q,
-                           block_k)
+                           block_k, window=window)
 
 
 def _flash_biased_bwd(causal, block_q, block_k, res, do):
@@ -625,7 +672,7 @@ def _kernel_mesh_specs(mesh, q, k):
 
 
 def flash_attention(q, k, v, causal=True, kv_bias=None, block_q=1024,
-                    block_k=1024, mesh=None):
+                    block_k=1024, mesh=None, window=0):
     """Flash attention. q,k,v: [B, T, H, D] (framework layout; kv heads
     may be fewer — GQA is handled natively: the kernels index kv-head
     ``query_head // n_rep``, so the expansion never materializes in
@@ -636,6 +683,13 @@ def flash_attention(q, k, v, causal=True, kv_bias=None, block_q=1024,
     (BERT-style bidirectional attention over ragged batches). It is
     treated as a CONSTANT (stop_gradient on every path): masks have no
     useful gradient, and the TPU kernel does not compute one.
+
+    ``window`` W > 0 (causal calls without a bias only): key j is
+    visible to query i where ``i - W < j <= i``, a band under the
+    diagonal (sliding-window attention). The kernels skip the tiles
+    wholly below the band as they skip those above the diagonal (no DMA,
+    no matmul), mask the tiles an edge crosses and run the rest bare;
+    ``tile_counts`` says how many of each. 0: every earlier key.
 
     Operands on a TPU: pallas kernel. Elsewhere: the XLA blockwise
     implementation (same math, used by CPU tests).
@@ -651,6 +705,9 @@ def flash_attention(q, k, v, causal=True, kv_bias=None, block_q=1024,
 
     if kv_bias is not None:
         kv_bias = lax.stop_gradient(kv_bias)
+    if window and (not causal or kv_bias is not None):
+        raise ValueError("flash_attention: a window needs causal=True "
+                         "and no kv_bias")
     n_rep = q.shape[2] // k.shape[2]
     # _INTERPRET forces the pallas path off-TPU so tests cover the real
     # kernel code (interpret mode) instead of the reference math.
@@ -667,8 +724,9 @@ def flash_attention(q, k, v, causal=True, kv_bias=None, block_q=1024,
                                       causal), "attn_out")
         from horovod_tpu.parallel.ring_attention import blockwise_attention
 
-        return checkpoint_name(blockwise_attention(q, k, v, causal=causal),
-                               "attn_out")
+        return checkpoint_name(
+            blockwise_attention(q, k, v, causal=causal, window=window),
+            "attn_out")
 
     def kernel(q, k, v, kv_bias=None):
         # [B,T,H,D] -> [B,H,T,D]; k/v stay at Hkv heads — the kernels
@@ -684,7 +742,7 @@ def flash_attention(q, k, v, causal=True, kv_bias=None, block_q=1024,
             bias = kv_bias.astype(jnp.float32)[:, None, :]  # [B, 1, Tk]
             o = _flash_biased(qt, kt, vt, bias, causal, bq, bk)
         else:
-            o = _flash(qt, kt, vt, causal, bq, bk)
+            o = _flash(qt, kt, vt, causal, bq, bk, window)
         return o.transpose(0, 2, 1, 3)
 
     operands = (q, k, v) if kv_bias is None else (q, k, v, kv_bias)

@@ -32,6 +32,14 @@ bf16 array in HBM by tiles of 8 rows (a row lies interleaved with its
 pair in 16 pieces), so a kernel cannot fetch one routed row; XLA's
 gather can (readings: PERF.md section 6).
 
+A share of the experts: a program that is ONE chip of an
+expert-parallel deployment (``LlamaConfig.first_expert``,
+``n_experts_held``) routes over all experts and computes the chosen
+ones it holds, without the exchange (``_held_experts_ffn``): the slots
+of absent experts sort last, the held rows are worked off in chunks of
+a static length, and a token's rows come back by a scatter-add, since
+most of a token's K slots are elsewhere.
+
 Sharding: this path is for programs where the experts are NOT sharded
 over an ``expert`` mesh axis (single chip, or EP-free meshes) — the
 sort is a per-program global op. Expert-parallel meshes keep the GShard
@@ -248,6 +256,128 @@ def _group_sizes(e_flat, n_experts):
     return jnp.sum(e_flat[:, None] == experts, axis=0, dtype=jnp.int32)
 
 
+# A program that holds a SHARE of the experts (``c.n_experts_held``)
+# moves rows in chunks of this many times the slots an even router would
+# hand it: one chunk at any load up to that, more only when more slots
+# land here.
+_HELD_ROW_BOUND = 2
+
+
+def _sum_rows(rows, tok, n_tokens):
+    """``out[t] = sum of rows[i] where tok[i] == t``, [n_tokens, D] in
+    ``rows``' dtype, accumulated in float32 (a scatter-add: a token has
+    0..K of the bounded rows, in no order)."""
+    out = jnp.zeros((n_tokens, rows.shape[1]), jnp.float32)
+    return out.at[tok].add(rows.astype(jnp.float32),
+                           mode="promise_in_bounds").astype(rows.dtype)
+
+
+# The share's two row movements, each the other's transpose and VJP as
+# ``_dispatch`` / ``_combine`` are, over the first R sorted slots only.
+# Rows ``valid`` does not cover are slots of experts held elsewhere: no
+# group of the grouped GEMMs covers them, which therefore leave them
+# UNWRITTEN (whatever the buffer held), so every sum masks them.
+
+@jax.custom_vjp
+def _dispatch_held(h, tok, valid):
+    return _rows(h, tok)
+
+
+def _dispatch_held_fwd(h, tok, valid):
+    return _rows(h, tok), (tok, valid, h.shape[0])
+
+
+def _dispatch_held_bwd(res, g):
+    tok, valid, n = res
+    return _sum_rows(jnp.where(valid[:, None], g, 0), tok, n), None, None
+
+
+_dispatch_held.defvjp(_dispatch_held_fwd, _dispatch_held_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _combine_held(z, tok, valid, n_tokens):
+    return _sum_rows(jnp.where(valid[:, None], z, 0), tok, n_tokens)
+
+
+def _combine_held_fwd(z, tok, valid, n_tokens):
+    return _combine_held(z, tok, valid, n_tokens), tok
+
+
+def _combine_held_bwd(n_tokens, tok, g):
+    return _rows(g, tok), None, None
+
+
+_combine_held.defvjp(_combine_held_fwd, _combine_held_bwd)
+
+
+def _held_experts_ffn(hf, lp, c, gate_vals, gate_idx):
+    """The routed part of an expert layer that holds experts
+    ``c.first_expert .. + c.n_experts_held - 1`` of ``c.n_experts``:
+    ``sum over the chosen experts held here of w_k Expert_k(h)`` for
+    the tokens ``hf`` [S, D], routed (``gate_vals``, ``gate_idx``
+    [S, K]) over ALL experts. Dropless at any load: no slot of a held
+    expert is left out.
+
+    The slots are sorted by LOCAL expert with those of absent experts
+    last, so the ``n`` held rows are the head of the order. They are
+    worked off in chunks of R rows, R = ``_HELD_ROW_BOUND`` x the even
+    share (S*K*held/E): gathered out of ``hf``, through the three
+    grouped GEMMs (which visit the tiles of their groups and nothing
+    else), summed back per token. The first chunk always runs; a further
+    one runs only if held rows reach it (``lax.cond`` in a ``lax.scan``
+    over the rest of the S*K slots), so up to R held rows the layer
+    moves R rows, not S*K, and every temporary is R rows long at any
+    load."""
+    S, D = hf.shape
+    K, H = c.n_experts_per_token, c.n_experts_held
+    dt = c.compute_dtype
+    local = lax.stop_gradient(gate_idx.reshape(S * K)) - c.first_expert
+    key = jnp.where((local >= 0) & (local < H), local, H)
+    (order, _), w_sorted = _sort_slots(
+        key, gate_vals.astype(dt).reshape(S * K))
+    ends = jnp.cumsum(_group_sizes(key, H))
+    n = ends[-1]
+    chunks = max(c.n_experts // (H * _HELD_ROW_BOUND), 1)
+    if (S * K) % chunks:
+        chunks = 1
+    R = S * K // chunks
+
+    def chunk(start):
+        """Sorted slots ``start .. start + R - 1`` -> [S, D]."""
+        tok = lax.dynamic_slice_in_dim(order, start, R) // K
+        valid = start + lax.iota(jnp.int32, R) < n
+        w = jnp.where(valid, lax.dynamic_slice_in_dim(w_sorted, start, R),
+                      0)
+        # each group's rows that fall inside this chunk
+        sizes = jnp.diff(jnp.clip(ends, start, start + R), prepend=start)
+        x_sorted = _dispatch_held(hf.astype(dt), tok, valid)
+        gate_pre = checkpoint_name(
+            _grouped_mm(x_sorted, lp["moe_gate"].astype(dt), sizes),
+            "moe_gate_act")
+        up = checkpoint_name(
+            _grouped_mm(x_sorted, lp["moe_up"].astype(dt), sizes),
+            "moe_up_act")
+        y_sorted = _grouped_mm(
+            jax.nn.silu(gate_pre) * up * w[:, None],
+            lp["moe_down"].astype(dt), sizes)
+        return _combine_held(y_sorted, tok, valid, S)
+
+    y = chunk(0)
+    if chunks == 1:
+        return y
+
+    # Saves nothing: a later chunk is the rare path, and a skipped one
+    # must not cost R rows of zero residuals.
+    later = jax.checkpoint(chunk)
+
+    def rest(y, start):
+        return lax.cond(start < n, lambda y: y + later(start),
+                        lambda y: y, y), None
+
+    return lax.scan(rest, y, R * jnp.arange(1, chunks, dtype=jnp.int32))[0]
+
+
 def grouped_moe_ffn(h, lp, c):
     """Dropless top-K routed expert FFN over ``h`` [B, T, D] with the
     layer params ``lp`` (router [D, E], moe_gate/moe_up [E, D, F],
@@ -255,7 +385,10 @@ def grouped_moe_ffn(h, lp, c):
     the same contract, router math, gate normalization
     (``c.norm_topk_prob``) and load-balancing statistics as the GShard
     path (``models/llama.py:_moe_ffn``), with no capacity dropping
-    (every token-slot is computed).
+    (every token-slot is computed). With a share of the experts
+    (``c.n_experts_held``; the expert matrices then hold those experts
+    only) the router is the same and the result is the held experts'
+    part: :func:`_held_experts_ffn`.
     """
     B, T, D = h.shape
     E, K = c.n_experts, c.n_experts_per_token
@@ -265,10 +398,12 @@ def grouped_moe_ffn(h, lp, c):
 
     # Shared router (llama.moe_route): identical math and statistics to
     # the GShard path's (means over flat S == means over (B, T)).
-    from horovod_tpu.models.llama import moe_route
+    from horovod_tpu.models.llama import route_layer
 
-    gate_vals, gate_idx, aux = moe_route(hf, lp["router"], K,
-                                         c.norm_topk_prob)     # [S, K]
+    gate_vals, gate_idx, aux = route_layer(hf, lp, c)          # [S, K]
+    if c.n_experts_held:
+        y = _held_experts_ffn(hf, lp, c, gate_vals, gate_idx)
+        return y.reshape(B, T, D), aux
 
     # Sort the S*K (token, k) slots by routed expert: two sorts, the
     # order (the gate weights ride it) and its inverse. Indices are

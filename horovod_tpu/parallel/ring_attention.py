@@ -30,17 +30,21 @@ def _repeat_kv(x, n_rep):
         .reshape(b, s, h * n_rep, d)
 
 
-def _attn_block(q, k, v, q_pos, kv_pos, causal, scale):
+def _attn_block(q, k, v, q_pos, kv_pos, causal, scale, window=0):
     """One flash-attention block: returns unnormalized (o, m, l) stats.
 
     q: [B, Tq, H, D]; k, v: [B, Tk, H, D]; positions are global indices.
     o is f32 [B, Tq, H, D]; m (running max) and l (sum of exp) are
-    f32 [B, H, Tq].
+    f32 [B, H, Tq]. ``window`` W > 0 (causal only): key j is visible to
+    row i where ``i - W < j <= i``.
     """
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
                    preferred_element_type=jnp.float32) * scale
     if causal:
         visible = kv_pos[None, None, None, :] <= q_pos[None, None, :, None]
+        if window:
+            visible &= kv_pos[None, None, None, :] \
+                > q_pos[None, None, :, None] - window
         s = jnp.where(visible, s, _NEG_BIG)
     m = jnp.max(s, axis=-1)
     p = jnp.exp(s - m[..., None])
@@ -64,18 +68,22 @@ def _combine(o, m, l, o_blk, m_blk, l_blk):
     return o_new, m_new, l_new
 
 
-def blockwise_attention(q, k, v, causal=True, q_offset=0, kv_offset=0):
+def blockwise_attention(q, k, v, causal=True, q_offset=0, kv_offset=0,
+                        window=0):
     """Plain (single-device) attention with global-position causal mask.
 
     q: [B, Tq, H, D]; k, v: [B, Tk, Hkv, D]. The offsets give the global
     index of the first q/kv position (used by ring steps and by decode).
+    ``window`` W > 0: sliding-window attention, each row sees its last W
+    keys (itself included); what ``ops.flash_attention(window=W)`` runs
+    off the TPU.
     """
     n_rep = q.shape[2] // k.shape[2]
     k, v = _repeat_kv(k, n_rep), _repeat_kv(v, n_rep)
     scale = q.shape[-1] ** -0.5
     q_pos = q_offset + jnp.arange(q.shape[1])
     kv_pos = kv_offset + jnp.arange(k.shape[1])
-    o, m, l = _attn_block(q, k, v, q_pos, kv_pos, causal, scale)
+    o, m, l = _attn_block(q, k, v, q_pos, kv_pos, causal, scale, window)
     l = jnp.maximum(l, 1e-30)
     out = o / jnp.transpose(l, (0, 2, 1))[..., None]
     return out.astype(q.dtype)

@@ -35,6 +35,9 @@ class DecodeEngine:
                  quantized=False):
         import jax.numpy as jnp
 
+        from horovod_tpu.models.generate import require_decodable
+
+        require_decodable(config)
         self.params = params
         self.config = config
         self._jnp = jnp

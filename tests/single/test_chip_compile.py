@@ -82,6 +82,14 @@ def _flash_fwd_bwd(q, k, v):
                     argnums=(0, 1, 2))(q, k, v)
 
 
+def _flash_window_fwd_bwd(q, k, v):
+    from horovod_tpu.ops import flash_attention
+
+    return jax.grad(lambda *a: flash_attention(
+        *a, causal=True, window=2048).astype(F32).sum(),
+        argnums=(0, 1, 2))(q, k, v)
+
+
 def _flash_chunk(q, k, v, q_off, kv_off):
     # One ring-attention step, kernel layout [B, H, T, D], with traced
     # global offsets; the lse cotangent exercises the folded backward.
@@ -115,6 +123,10 @@ def _gmm(lhs, rhs, group_sizes):
         lhs, rhs, group_sizes).astype(F32).sum(), argnums=(0, 1))(lhs, rhs)
 
 
+# Trinity-Mini's attention at the chip cell's size: B2 T8192 H32 Hkv4.
+_TQ, _TKV = ((2, 8192, 32, 128), BF16), ((2, 8192, 4, 128), BF16)
+
+
 @pytest.mark.parametrize("fn,shapes", [
     pytest.param(_flash_fwd, (_Q, _KV, _KV), id="flash-fwd-flagship"),
     pytest.param(_flash_fwd_bwd, (_Q, _KV, _KV),
@@ -137,6 +149,11 @@ def _gmm(lhs, rhs, group_sizes):
                  (((1, 16384, 16, 128), BF16),)
                  + (((1, 16384, 4, 128), BF16),) * 2,
                  id="flash-fwd+bwd-long-context-t16384"),
+    # Trinity-Mini's window layers (window 2048) and its full layer.
+    pytest.param(_flash_window_fwd_bwd, (_TQ, _TKV, _TKV),
+                 id="flash-window-fwd+bwd-trinitymini-b2s8192"),
+    pytest.param(_flash_fwd_bwd, (_TQ, _TKV, _TKV),
+                 id="flash-fwd+bwd-trinitymini-b2s8192"),
     pytest.param(_flash_chunk,
                  (((1, 16, 2048, 128), BF16), ((1, 4, 2048, 128), BF16),
                   ((1, 4, 2048, 128), BF16), ((), I32), ((), I32)),
@@ -167,6 +184,16 @@ def _gmm(lhs, rhs, group_sizes):
                  (((65536, 1024), BF16), ((64, 1024, 2048), BF16),
                   ((64,), I32)),
                  id="megablox-gmm-olmoe-down"),
+    # Trinity-Mini's share: one chunk of 32,768 sorted slots into the 16
+    # experts held (the groups cover the held rows, about half of it).
+    pytest.param(_gmm,
+                 (((32768, 2048), BF16), ((16, 2048, 1024), BF16),
+                  ((16,), I32)),
+                 id="megablox-gmm-trinitymini-share-gate-up"),
+    pytest.param(_gmm,
+                 (((32768, 1024), BF16), ((16, 1024, 2048), BF16),
+                  ((16,), I32)),
+                 id="megablox-gmm-trinitymini-share-down"),
 ])
 def test_kernel_compiles_for_described_v5e(for_tpu, fn, shapes):
     assert "tpu_custom_call" in for_tpu(fn, *shapes)
@@ -193,6 +220,20 @@ def test_flash_kernels_keep_their_names_in_the_compiled_program(
         r"custom-call\([^\n]*kernel_metadata=\{\s*\"kernel\":\"(\w+)\"\s*\}",
         text)
     assert sorted(names) == ["hvd_flash_bwd_fused", "hvd_flash_fwd"]
+
+
+def test_a_window_rides_in_the_kernel_metadata_and_nowhere_else(for_tpu):
+    """A flash call with a window carries it beside the kernel's name
+    (what ``flash_window_ms_per_step`` matches); one without compiles
+    to the call it always was (name alone: the test above)."""
+    text = for_tpu(lambda *a: (_flash_window_fwd_bwd(*a),
+                               _flash_fwd_bwd(*a)), _TQ, _TKV, _TKV)
+    found = re.findall(r"kernel_metadata=\{([^}]*)\}", text)
+    found = sorted(set("".join(m.split()) for m in found))
+    assert found == ['"kernel":"hvd_flash_bwd_fused"',
+                     '"kernel":"hvd_flash_bwd_fused","window":"2048"',
+                     '"kernel":"hvd_flash_fwd"',
+                     '"kernel":"hvd_flash_fwd","window":"2048"']
 
 
 def test_routed_rows_move_through_bare_gathers_on_the_v5e(for_tpu):

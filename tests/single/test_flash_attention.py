@@ -571,3 +571,117 @@ def test_lane_wide_blocks(case):
                                rtol=2e-4, atol=2e-4)
     _assert_grads_close(jax.grad(loss(run), (0, 1, 2))(q, k, v),
                         jax.grad(loss(ref), (0, 1, 2))(q, k, v))
+
+
+# ---------------------------------------------------------------------
+# The causal band (sliding-window attention): key j visible to row i
+# where i - W < j <= i.
+# ---------------------------------------------------------------------
+
+def _band_ref(q, k, v, window):
+    """Kernel layout [B, H(kv), T, D], an explicit band mask."""
+    rep = q.shape[1] // k.shape[1]
+    k, v = jnp.repeat(k, rep, 1), jnp.repeat(v, rep, 1)
+    t = q.shape[2]
+    i, j = jnp.arange(t)[:, None], jnp.arange(t)[None, :]
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) / (q.shape[-1] ** 0.5)
+    s = jnp.where((j <= i) & (j > i - window), s, -jnp.inf)
+    return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, -1), v)
+
+
+def _brute_force_band_tiles(t, bq, bk, window):
+    i, j = np.arange(t)[:, None], np.arange(t)[None, :]
+    mask = (j <= i) & (j > i - window)
+    tiles = mask.reshape(t // bq, bq, t // bk, bk).transpose(0, 2, 1, 3)
+    full, some = tiles.all((2, 3)), tiles.any((2, 3))
+    return ~some, full, some & ~full
+
+
+_BANDS = [
+    # t, block_q, block_k, window: smaller than, equal to and larger
+    # than a block; no multiple of one; unequal blocks; wider than T.
+    (8192, 1024, 1024, 2048), (4096, 1024, 1024, 2048),
+    (96, 16, 16, 5), (96, 16, 16, 16), (96, 16, 16, 17), (96, 16, 16, 40),
+    (96, 32, 16, 24), (96, 16, 32, 24), (96, 16, 16, 1), (96, 16, 16, 500),
+]
+
+
+@pytest.mark.parametrize("band", _BANDS, ids=lambda g: "-".join(map(str, g)))
+def test_tile_counts_with_a_window_match_a_brute_force_band(band):
+    t, bq, bk, window = band
+    kinds = _brute_force_band_tiles(*band)
+    assert fa_mod.tile_counts(t, t, bq, bk, True, window=window) \
+        == tuple(int(x.sum()) for x in kinds)
+    # the predicate on traced scalars, as a kernel feeds it
+    inside, crossing = jax.jit(lambda z: fa_mod._tile_kinds(
+        True, z + jnp.arange(t // bq)[:, None] * bq, bq,
+        z + jnp.arange(t // bk)[None, :] * bk, bk, window))(jnp.int32(0))
+    np.testing.assert_array_equal(np.asarray(inside), kinds[1])
+    np.testing.assert_array_equal(np.asarray(crossing), kinds[2])
+
+
+def test_window_tile_counts_quoted_in_the_docs():
+    # 21 tiles run of the 36 a full layer runs at T = 8192
+    assert fa_mod.tile_counts(8192, 8192, 1024, 1024, True,
+                              window=2048) == (43, 7, 14)
+    assert fa_mod.tile_counts(4096, 4096, 1024, 1024, True,
+                              window=2048) == (7, 3, 6)
+
+
+@pytest.mark.parametrize("window", [5, 16, 24, 40, 200])
+@pytest.mark.parametrize("t, bq, bk", [(96, 16, 16), (80, 16, 8)])
+def test_window_kernel_values_and_grads(t, bq, bk, window):
+    """T no power of two (and 80 no multiple of 32, so `_pick_block`
+    degrades); W smaller than, equal to and larger than a block, and
+    wider than the sequence (= plain causal). GQA, 2 query heads a
+    KV head."""
+    ks = jax.random.split(jax.random.PRNGKey(window), 4)
+    q = jax.random.normal(ks[0], (2, 4, t, 8), jnp.float32)
+    k = jax.random.normal(ks[1], (2, 2, t, 8), jnp.float32)
+    v = jax.random.normal(ks[2], (2, 2, t, 8), jnp.float32)
+    w = jax.random.normal(ks[3], q.shape)
+    out = fa_mod._flash(q, k, v, True, bq, bk, window)
+    np.testing.assert_allclose(np.asarray(out),
+                               np.asarray(_band_ref(q, k, v, window)),
+                               rtol=2e-4, atol=2e-4)
+    got = jax.grad(lambda *a: (fa_mod._flash(*a, True, bq, bk, window)
+                               * w).sum(), (0, 1, 2))(q, k, v)
+    ref = jax.grad(lambda *a: (_band_ref(*a, window) * w).sum(),
+                   (0, 1, 2))(q, k, v)
+    _assert_grads_close(got, ref)
+    if window >= t:   # a window wider than the sequence changes nothing
+        np.testing.assert_allclose(
+            np.asarray(out), np.asarray(fa_mod._flash(q, k, v, True, bq,
+                                                      bk, 0)),
+            rtol=1e-6, atol=1e-6)
+
+
+def test_window_public_api_both_paths_and_its_refusals():
+    """[B,T,H,D] layout through ``flash_attention``: the kernel
+    (interpret) and the off-TPU ``blockwise_attention`` path agree with
+    the explicit band; a window without ``causal`` or with a bias is
+    refused."""
+    from horovod_tpu.parallel.ring_attention import blockwise_attention
+
+    ks = jax.random.split(jax.random.PRNGKey(3), 3)
+    q = jax.random.normal(ks[0], (1, 72, 4, 8), jnp.float32)
+    k = jax.random.normal(ks[1], (1, 72, 2, 8), jnp.float32)
+    v = jax.random.normal(ks[2], (1, 72, 2, 8), jnp.float32)
+    ref = _band_ref(*(x.transpose(0, 2, 1, 3) for x in (q, k, v)),
+                    20).transpose(0, 2, 1, 3)
+    got = fa_mod.flash_attention(q, k, v, window=20, block_q=24,
+                                 block_k=24)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                               rtol=2e-4, atol=2e-4)
+    np.testing.assert_allclose(
+        np.asarray(blockwise_attention(q, k, v, window=20)),
+        np.asarray(ref), rtol=2e-4, atol=2e-4)
+    fa_mod._INTERPRET = False     # the reference branch of the same call
+    np.testing.assert_allclose(
+        np.asarray(fa_mod.flash_attention(q, k, v, window=20)),
+        np.asarray(ref), rtol=2e-4, atol=2e-4)
+    with pytest.raises(ValueError, match="window"):
+        fa_mod.flash_attention(q, k, v, causal=False, window=20)
+    with pytest.raises(ValueError, match="window"):
+        fa_mod.flash_attention(q, k, v, window=20,
+                               kv_bias=jnp.zeros((1, 72)))
