@@ -4,8 +4,10 @@ Design choices (vs a torch translation):
 - functional: params are a plain pytree; init/forward are pure functions
   compatible with jit/grad/shard_map.
 - scan-over-layers: per-layer params are stacked on a leading axis and the
-  decoder body is one ``lax.scan`` — O(1) XLA program size in depth, the
-  standard TPU idiom (compile time does not grow with n_layers).
+  dense decoder body is one ``lax.scan`` — O(1) XLA program size in depth,
+  the standard TPU idiom (compile time does not grow with n_layers). A
+  stack whose layers feed the stacked weights to an opaque kernel (the
+  grouped expert GEMMs) runs unrolled instead: ``_run_layers``.
 - remat: each scanned layer is wrapped in ``jax.checkpoint`` so activations
   are recomputed in backward — HBM for FLOPs, the right TPU trade.
 - bfloat16 compute; params stored in ``param_dtype`` (float32 default
@@ -29,10 +31,18 @@ from jax.sharding import PartitionSpec as P
 
 from horovod_tpu.parallel.ring_attention import ring_self_attention
 
-# Residual names of ops/grouped_moe.py: what "attn+moe" saves beyond
-# "attn", and what "moe" saves beyond that.
-_MOE_SAVE = ("moe_perm", "moe_w_sorted")
+# Residual names of ops/grouped_moe.py and ``moe_route``: what
+# "attn+moe" saves beyond "attn", and what "moe" saves beyond that. The
+# sorted order is saved WITH the top-k choice it was sorted from: every
+# discrete value of the backward (group sizes, the slot an expert's gate
+# gradient belongs to) then derives from the forward's choice and not
+# from a recomputed one, which may round a near-tie the other way
+# (``_top_k``).
+_MOE_SAVE = ("moe_perm", "moe_w_sorted", "moe_gate_idx")
 _MOE_EXTRA_SAVE = ("moe_gate_act", "moe_up_act")
+# The leaves the grouped GEMMs read: an unrolled stack hands them over
+# whole (``grouped_moe.LayerOfStack``), never sliced.
+_EXPERT_MATRICES = ("moe_gate", "moe_up", "moe_down")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -109,12 +119,12 @@ class LlamaConfig:
     # "ulysses" (all-to-all head/sequence reshard — needs
     # n_heads % seq_size == 0, cheaper at short per-device sequences).
     seq_parallel: str = "ring"
-    # Unroll factor for the scan-over-layers (1 = rolled, n_layers =
-    # fully unrolled). Unrolling turns the stacked-weight dynamic
-    # slices into static ones — on TPU that halves the per-layer weight
-    # copies feeding grouped-GEMM custom-calls (measured -5% MoE step
-    # time at bench shape) at the price of compile time and program
-    # size. Leave 1 for multi-chip pipeline meshes.
+    # Unroll factor of the DENSE layer scan (1 = rolled, n_layers =
+    # fully unrolled): scheduling only, at the price of compile time
+    # and program size; no cell or example sets it and no reading on
+    # this chip says it pays. It governs nothing else: a stack of
+    # grouped expert layers and a layer pattern run unrolled whatever it
+    # says (``_run_layers`` says why), a pipeline stage always scans.
     scan_unroll: int = 1
     # Pallas flash-attention block size (both the q and k grid blocks;
     # 0 = the kernel default, 1024 — the measured optimum of
@@ -494,6 +504,42 @@ def _project_qkv(h, lp, c):
             _head_proj(h, lp["wv"], None, c))
 
 
+@partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _top_k(probs, k):
+    """``lax.top_k`` over the last axis whose VJP scatters by the
+    indices the FORWARD chose, named ``moe_gate_idx``: every remat mode
+    that saves the sorted order (``_MOE_SAVE``) saves them with it.
+    ``lax.top_k``'s own VJP reads the indices of a RECOMPUTED top-k. A
+    recomputation that rounds two of a token's probabilities the other
+    way then hands slot k's gate gradient to another expert, and counts
+    other group sizes than the saved order was sorted by, so that every
+    row between two moved boundaries meets the wrong expert's matrix in
+    ``dlhs`` and ``tgmm``. Read on the chip once the layers ran unrolled
+    (under the scan the recomputation happened to round as the forward
+    did): the experts' gradients stood 0.04-0.15 and the router's
+    0.07-0.20 from the float32 reference, 0.02 with the choice saved
+    (PERF.md section 6, PR 33)."""
+    return tuple(lax.top_k(probs, k))
+
+
+def _top_k_fwd(probs, k):
+    vals, idx = lax.top_k(probs, k)
+    idx = checkpoint_name(idx, "moe_gate_idx")
+    return (vals, idx), (idx, jnp.zeros((0, probs.shape[-1]), probs.dtype))
+
+
+def _top_k_bwd(k, res, g):
+    idx, like = res                  # ``like``: the width E, no data
+    chosen = jax.linear_transpose(
+        lambda p: jnp.take_along_axis(p, idx, axis=-1,
+                                      mode="promise_in_bounds"),
+        jax.ShapeDtypeStruct(idx.shape[:-1] + like.shape[1:], like.dtype))
+    return chosen(g[0])
+
+
+_top_k.defvjp(_top_k_fwd, _top_k_bwd)
+
+
 def moe_route(h, router_w, n_experts_per_token, norm_topk_prob=True,
               score_func="softmax", bias=None, route_scale=1.0):
     """The ONE router: f32 logits matmul, softmax, top-K, the K
@@ -522,11 +568,12 @@ def moe_route(h, router_w, n_experts_per_token, norm_topk_prob=True,
     else:
         probs = jax.nn.softmax(logits, axis=-1)            # [..., E]
     if bias is None:
-        gate_vals, gate_idx = lax.top_k(probs, n_experts_per_token)
+        gate_vals, gate_idx = _top_k(probs, n_experts_per_token)
     else:
         _, gate_idx = lax.top_k(
             probs + lax.stop_gradient(bias.astype(jnp.float32)),
             n_experts_per_token)
+        gate_idx = checkpoint_name(gate_idx, "moe_gate_idx")
         gate_vals = jnp.take_along_axis(probs, gate_idx, axis=-1)
     if norm_topk_prob:
         gate_vals = gate_vals / jnp.maximum(
@@ -631,6 +678,13 @@ def _moe_ffn(h, lp, c, mesh):
     return y, aux
 
 
+def _grouped_dispatch(c, mesh):
+    """Whether this model's expert layers run the sorted grouped-GEMM
+    dispatch (``ops/grouped_moe.py``) and not the GShard einsums."""
+    return c.n_experts > 0 and (
+        c.moe_impl == "grouped" or (c.moe_impl == "auto" and mesh is None))
+
+
 def _swiglu(h, gate, up, down, dt):
     return (jax.nn.silu(h @ gate.astype(dt)) * (h @ up.astype(dt))) \
         @ down.astype(dt)
@@ -646,8 +700,7 @@ def _ffn(h, lp, c, mesh=None):
     the two can never diverge."""
     dt = c.compute_dtype
     if "router" in lp:
-        if c.moe_impl == "grouped" or (c.moe_impl == "auto"
-                                       and mesh is None):
+        if _grouped_dispatch(c, mesh):
             from horovod_tpu.ops.grouped_moe import grouped_moe_ffn
 
             y, aux = grouped_moe_ffn(h, lp, c)
@@ -757,25 +810,52 @@ def _run_layers(params, x, c, mesh, seq_axis):
     """The decoder stack on ``x`` [B, T, D]; returns (x, the expert
     layers' balance statistics stacked [layers, 2, E]).
 
-    A uniform model (every layer one kind) is ONE ``lax.scan`` of the
-    layer body over the stacked parameters, as it always was. A layer
-    pattern (leading dense layers; window and full attention layers
-    mixed) is a different PROGRAM a kind: its layers run unrolled, each
-    the body of its own kind on a static slice of its parameter stack
-    (``dense_layers``, then ``layers``). Program size is O(depth) then;
-    a scan over whole periods of the pattern waits for a configuration
-    deep enough to need it."""
+    One algorithm, L layer bodies over stacked parameters, whose
+    indexing is dynamic where the compiler can fuse it and static where
+    it cannot:
+
+    - a uniform DENSE stack (or GShard experts: einsums) is ONE
+      ``lax.scan`` of the layer body, ``unroll=c.scan_unroll``: XLA
+      fuses the scan's ``dynamic-slice`` into the matmul that reads the
+      weight and its ``dynamic-update-slice`` into the one that writes
+      the gradient, so the rolled loop costs nothing and the program is
+      O(1) in depth;
+    - a uniform stack of GROUPED expert layers (``_grouped_dispatch``)
+      runs unrolled, each body on a STATIC index of ``params["layers"]``.
+      Its matrices feed megablox custom calls, which no fusion enters:
+      under a scan each layer's three expert matrices were copied out of
+      the stack and their gradients copied back in, and the saved
+      gate/up activations stacked and unstacked: 20.5 ms of a 169.1 ms
+      step at OLMoE's widths, two layers (PERF.md section 6, PR 33);
+    - a layer pattern (leading dense layers; window and full attention
+      layers mixed) is a different PROGRAM a kind, so it runs unrolled
+      too, each layer the body of its own kind (``dense_layers``, then
+      ``layers``).
+
+    Unrolled, program size and compile time are O(depth). A pipeline
+    stage (``_stage_scan``) always scans: one layer program by contract."""
     kinds = c.layer_kinds()
-    if len(set(kinds)) == 1:
+    if len(set(kinds)) == 1 and not _grouped_dispatch(c, mesh):
         return lax.scan(_build_layer_body(c, mesh, seq_axis), x,
                         params["layers"], unroll=c.scan_unroll)
+    from horovod_tpu.ops.grouped_moe import LayerOfStack
+
     bodies = {kind: _build_layer_body(c, mesh, seq_axis, kind=kind)
               for kind in set(kinds)}
+    # A SHARE of the experts (``n_experts_held``) gets slices: its later
+    # chunks run under a ``lax.scan``, whose transpose carries a gradient
+    # accumulator the shape of whatever the body closes over, and whole
+    # stacks there raised the Trinity-Mini cell's grad program from 9.64
+    # to 14.03 GB (compiled for the described v5e, PR 33).
+    whole = _EXPERT_MATRICES \
+        if _grouped_dispatch(c, mesh) and not c.n_experts_held else ()
     balance = []
     for at, kind in enumerate(kinds):
         name, j = ("dense_layers", at) if at < c.n_dense_layers \
             else ("layers", at - c.n_dense_layers)
-        x, bal = bodies[kind](x, jax.tree.map(lambda w: w[j], params[name]))
+        lp = {k: LayerOfStack(w, j) if k in whole else w[j]
+              for k, w in params[name].items()}
+        x, bal = bodies[kind](x, lp)
         balance.append(bal)
     # Dense layers carry zero-width statistics: the expert layers' only.
     return x, jnp.stack([b for b in balance if b.shape[-1]] or balance)
@@ -902,10 +982,7 @@ def _build_layer_body(c, mesh, seq_axis, constrain_acts=True, kind=None):
             layer,
             policy=jax.checkpoint_policies.save_only_these_names(
                 "attn_out", "flash_o", "flash_lse"))
-    elif c.remat in ("attn+moe", "moe") and not (
-            c.n_experts > 0
-            and (c.moe_impl == "grouped"
-                 or (c.moe_impl == "auto" and mesh is None))):
+    elif c.remat in ("attn+moe", "moe") and not _grouped_dispatch(c, mesh):
         # These modes save residuals only grouped_moe_ffn emits; under
         # GShard dispatch (mesh present or moe_impl="gshard") or a
         # dense config they would silently degrade to plain "attn".
@@ -914,14 +991,15 @@ def _build_layer_body(c, mesh, seq_axis, constrain_acts=True, kind=None):
             "(n_experts > 0 and moe_impl='grouped', or 'auto' with no "
             "mesh); use remat='attn' or 'attn+gate' here")
     elif c.remat == "attn+moe":
-        # "attn" plus the grouped-MoE routing: the sorted order with
-        # its inverse (2 x S*K int32) and the gate weights in that
-        # order, so backward sorts nothing; it gathers the rows into
-        # expert order again (saving them costs three passes over
-        # [S*K, D]: jax's reduce_precision on a residual no fusion
-        # produces, and the layer scan's stacking and unstacking) and
-        # re-runs the gate and up grouped GEMMs. (No gradient needs the down projection's
-        # output: the gate weights scale its input rows.)
+        # "attn" plus the grouped-MoE routing: the top-k choice
+        # (S*K int32), the sorted order with its inverse (2 x S*K
+        # int32) and the gate weights in that order, so backward
+        # chooses and sorts nothing; it gathers the rows into expert
+        # order again (saving them costs three passes over [S*K, D]:
+        # jax's reduce_precision on a residual no fusion produces) and
+        # re-runs the gate and up grouped GEMMs. (No gradient needs the
+        # down projection's output: the gate weights scale its input
+        # rows.)
         body = jax.checkpoint(
             layer,
             policy=jax.checkpoint_policies.save_only_these_names(

@@ -51,6 +51,7 @@ the public dropless-MoE formulation (MegaBlocks) re-founded on TPU
 primitives.
 """
 
+import dataclasses
 import functools
 
 import jax
@@ -161,36 +162,75 @@ def _bwd_tilings(m, k, n):
             _clamp(_TILING_TGMM, m, k, n))   # tgmm: (m, k, n)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=())
-def _gmm_tpu(lhs, rhs, group_sizes):
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass(frozen=True)
+class LayerOfStack:
+    """Layer ``layer`` of expert matrices stacked ``[L, E, K, N]``, NOT
+    sliced out: what an unrolled layer stack (``models/llama.py:
+    _run_layers``) hands an expert layer in the place of ``stack[layer]``.
+    A Mosaic custom call is opaque to fusion, so a slice in front of it
+    is a copy of its own (268 MB a matrix at OLMoE's widths, 1.6 ms);
+    ``_grouped_mm`` hands the kernel the whole stack and the kernel
+    reads its tiles where they lie."""
+    stack: jax.Array
+    layer: int = dataclasses.field(metadata=dict(static=True))
+
+    def astype(self, dtype):
+        """The stack as it is where it already has ``dtype``; else the
+        slice, converted (one fused pass that the conversion needs
+        anyway; the whole stack converted would live a whole step)."""
+        if self.stack.dtype == dtype:
+            return self
+        return self.stack[self.layer].astype(dtype)
+
+
+def _one_layers_groups(stack, group_sizes, layer):
+    """``stack`` [L, E, K, N] as the L*E groups of ONE grouped matmul in
+    which only ``layer``'s E groups hold rows: megablox builds no tile
+    for an empty group (``visit_empty_groups=False``) and reads ``rhs``
+    at the group's index, so the kernel's DMAs fetch layer ``layer``'s
+    tiles straight out of the stack (the reshape is a bitcast)."""
+    L, E = stack.shape[:2]
+    sizes = jnp.pad(group_sizes, (layer * E, (L - 1 - layer) * E))
+    return stack.reshape(L * E, *stack.shape[2:]), sizes
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _gmm_tpu(lhs, stack, group_sizes, layer):
     from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
 
     m, k = lhs.shape
-    n = rhs.shape[-1]
-    return gmm(lhs, rhs, group_sizes,
+    n = stack.shape[-1]
+    return gmm(lhs, *_one_layers_groups(stack, group_sizes, layer),
                preferred_element_type=lhs.dtype,
                tiling=_clamp(_TILING, m, k, n))
 
 
-def _gmm_tpu_fwd(lhs, rhs, group_sizes):
-    return _gmm_tpu(lhs, rhs, group_sizes), (lhs, rhs, group_sizes)
+def _gmm_tpu_fwd(lhs, stack, group_sizes, layer):
+    return (_gmm_tpu(lhs, stack, group_sizes, layer),
+            (lhs, stack, group_sizes))
 
 
-def _gmm_tpu_bwd(res, grad):
+def _gmm_tpu_bwd(layer, res, grad):
     # Same decomposition as megablox's stock VJP (ops.py), but each
     # direction gets its own tiling: dlhs = grad @ rhs^T via gmm with
-    # transpose_rhs, dW via the transposed-lhs tgmm kernel.
+    # transpose_rhs, dW via the transposed-lhs tgmm kernel. tgmm zeroes
+    # an empty group's output, so it gets this layer's E groups alone
+    # and the stack's gradient is its result padded: summed over the
+    # layers XLA writes each stacked gradient in ONE pass
+    # (``pad_add_fusion``).
     from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm, tgmm
 
-    lhs, rhs, group_sizes = res
+    lhs, stack, group_sizes = res
     m, k = lhs.shape
-    n = rhs.shape[-1]
+    n = stack.shape[-1]
     dlhs_tiling, tgmm_tiling = _bwd_tilings(m, k, n)
-    dlhs = gmm(grad, rhs, group_sizes, lhs.dtype,
-               dlhs_tiling, transpose_rhs=True)
-    drhs = tgmm(lhs.swapaxes(0, 1), grad, group_sizes, rhs.dtype,
+    dlhs = gmm(grad, *_one_layers_groups(stack, group_sizes, layer),
+               lhs.dtype, dlhs_tiling, transpose_rhs=True)
+    drhs = tgmm(lhs.swapaxes(0, 1), grad, group_sizes, stack.dtype,
                 tgmm_tiling)
-    return dlhs, drhs, None
+    others = [(layer, stack.shape[0] - 1 - layer)] + [(0, 0)] * 3
+    return dlhs, jnp.pad(drhs[None], others), None
 
 
 _gmm_tpu.defvjp(_gmm_tpu_fwd, _gmm_tpu_bwd)
@@ -198,12 +238,17 @@ _gmm_tpu.defvjp(_gmm_tpu_fwd, _gmm_tpu_bwd)
 
 def _grouped_mm(lhs, rhs, group_sizes):
     """Ragged grouped matmul: rows of ``lhs`` [M, K] are grouped
-    contiguously per ``group_sizes`` [E]; ``rhs`` [E, K, N]. On TPU this
+    contiguously per ``group_sizes`` [E]; ``rhs`` [E, K, N], or a
+    :class:`LayerOfStack` of such. On TPU this
     is the megablox pallas kernel (dense-matmul throughput, f32
     accumulation) under our per-direction-tiling custom VJP. Off-TPU
     tests use an exact one-hot einsum (tiny shapes only)."""
-    if use_pallas("grouped_moe", (lhs, rhs)):
-        return _gmm_tpu(lhs, rhs, group_sizes)
+    of_stack = isinstance(rhs, LayerOfStack)
+    if use_pallas("grouped_moe", (lhs, rhs.stack if of_stack else rhs)):
+        stack, layer = (rhs.stack, rhs.layer) if of_stack else (rhs[None], 0)
+        return _gmm_tpu(lhs, stack, group_sizes, layer)
+    if of_stack:
+        rhs = rhs.stack[rhs.layer]
     # Exact reference: expert id per row from the group layout, then a
     # one-hot contraction (f32-exact; O(M*E*K*N) — test shapes only).
     eid = jnp.sum(jnp.arange(lhs.shape[0])[:, None]

@@ -123,6 +123,14 @@ def _gmm(lhs, rhs, group_sizes):
         lhs, rhs, group_sizes).astype(F32).sum(), argnums=(0, 1))(lhs, rhs)
 
 
+def _gmm_layer_1_of_2(lhs, stack, group_sizes):
+    """The grouped GEMM as an unrolled expert stack calls it: the whole
+    stack and a layer, not a slice."""
+    from horovod_tpu.ops.grouped_moe import LayerOfStack
+
+    return _gmm(lhs, LayerOfStack(stack, 1), group_sizes)
+
+
 # Trinity-Mini's attention at the chip cell's size: B2 T8192 H32 Hkv4.
 _TQ, _TKV = ((2, 8192, 32, 128), BF16), ((2, 8192, 4, 128), BF16)
 
@@ -184,6 +192,15 @@ _TQ, _TKV = ((2, 8192, 32, 128), BF16), ((2, 8192, 4, 128), BF16)
                  (((65536, 1024), BF16), ((64, 1024, 2048), BF16),
                   ((64,), I32)),
                  id="megablox-gmm-olmoe-down"),
+    # The same out of OLMoE's stack of two layers (PR 33).
+    pytest.param(_gmm_layer_1_of_2,
+                 (((65536, 2048), BF16), ((2, 64, 2048, 1024), BF16),
+                  ((64,), I32)),
+                 id="megablox-gmm-olmoe-gate-up-layer-of-stack"),
+    pytest.param(_gmm_layer_1_of_2,
+                 (((65536, 1024), BF16), ((2, 64, 1024, 2048), BF16),
+                  ((64,), I32)),
+                 id="megablox-gmm-olmoe-down-layer-of-stack"),
     # Trinity-Mini's share: one chunk of 32,768 sorted slots into the 16
     # experts held (the groups cover the held rows, about half of it).
     pytest.param(_gmm,
@@ -289,6 +306,47 @@ def test_grad_program_of_a_one_layer_llama_holds_one_flash_bwd_call(
     assert "@hvd_grad" in text
     assert re.findall(r"hvd_flash_\w+", text).count("hvd_flash_fwd") == 1
     assert re.findall(r"hvd_flash_bwd\w*", text) == ["hvd_flash_bwd_fused"]
+
+
+def test_no_copy_stands_between_the_stacked_experts_and_the_grouped_gemm(
+        for_tpu, v5e_chip):
+    """The gradient of a two-layer grouped expert model as the chip's
+    compiler emits it (PR 33): the stacked expert matrices reach every
+    ``gmm`` call through a ``bitcast`` of the parameter (a static slice
+    in front of a Mosaic call compiled to a ``slice_bitcast_fusion``, a
+    copy of every layer's matrices; a scan's ``dynamic-slice`` likewise),
+    nothing but ``tgmm`` produces an array of one layer's expert shape,
+    and each stacked gradient is written once (``pad_add_fusion``)."""
+    from horovod_tpu.models import LlamaConfig, llama_init, llama_loss
+
+    # Stacks of 268 MB, as OLMoE's: smaller ones the compiler moves
+    # through fast memory, with copies and custom calls of its own.
+    L, E, D, F = 2, 64, 1024, 2048
+    cfg = LlamaConfig(vocab_size=512, d_model=D, n_layers=L, n_heads=8,
+                      n_kv_heads=8, d_ff=F, n_experts=E,
+                      n_experts_per_token=2, moe_impl="grouped",
+                      dtype="bfloat16", param_dtype="bfloat16", remat="moe")
+    tokens = jax.ShapeDtypeStruct((2, 1024), I32)
+    params = jax.eval_shape(lambda k: llama_init(cfg, k),
+                            jax.random.PRNGKey(0))
+    params, batch = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=v5e_chip),
+        (params, {"tokens": tokens, "targets": tokens}))
+    text = jax.jit(jax.grad(lambda p, b: llama_loss(p, b, cfg))).lower(
+        params, batch).compile().as_text()
+    entry = text[text.index("ENTRY "):]
+    made = lambda shape: re.findall(                       # noqa: E731
+        rf"^\s+(?:ROOT )?(\S+) = bf16\[{shape}\]\S* ([\w\-]+)\(", entry, re.M)
+    for k, n in ((D, F), (F, D)):
+        one_layer = made(f"{E},{k},{n}")
+        assert one_layer and all(
+            name.startswith("%tgmm") and op == "custom-call"
+            for name, op in one_layer), one_layer
+        assert {op for _, op in made(f"{L * E},{k},{n}")} == {"bitcast"}
+        stacked = [(name, op) for name, op in made(f"{L},{E},{k},{n}")
+                   if op != "parameter"]
+        assert len(stacked) == (2 if (k, n) == (D, F) else 1), stacked
+        assert all(op == "fusion" for _, op in stacked), stacked
 
 
 def test_interpret_mode_on_tpu_operands_raises(monkeypatch):

@@ -264,7 +264,9 @@ def test_rows_lowers_to_a_bare_gather():
                                          ("moe", 2)])
 def test_remat_modes_that_save_the_order_sort_nothing_in_backward(
         remat, sorts):
-    cfg = LlamaConfig.tiny_moe(dtype="float32", remat=remat)
+    # One layer: a grouped expert stack runs unrolled (PR 33), so the
+    # program holds a layer body a layer; the counts are a body's.
+    cfg = LlamaConfig.tiny_moe(dtype="float32", remat=remat, n_layers=1)
     params = llama_init(cfg, jax.random.PRNGKey(0))
     tokens = jnp.zeros((2, 16), jnp.int32)
     batch = {"tokens": tokens, "targets": tokens}
