@@ -20,7 +20,9 @@ Design choices (vs a torch translation):
   caller via ``llama_partition_rules``; XLA/GSPMD inserts the collectives.
 """
 
+import collections
 import dataclasses
+import typing
 from functools import partial
 
 import jax
@@ -43,6 +45,25 @@ _MOE_EXTRA_SAVE = ("moe_gate_act", "moe_up_act")
 # The leaves the grouped GEMMs read: an unrolled stack hands them over
 # whole (``grouped_moe.LayerOfStack``), never sliced.
 _EXPERT_MATRICES = ("moe_gate", "moe_up", "moe_down")
+
+
+class LayerSpec(typing.NamedTuple):
+    """One layer of ``LlamaConfig.layer_plan``: WHERE its parameters
+    live (entry ``index`` of the stack ``params[stack]``) and WHAT it
+    runs (its token ``mixer``, "attention" or "conv"; a dense or an
+    expert FFN; an attention layer's window, 0 = none, and whether it
+    carries RoPE)."""
+    stack: str
+    index: int
+    mixer: str
+    dense_ffn: bool
+    window: int
+    rope: bool
+
+    @property
+    def kind(self):
+        """What tells this layer's PROGRAM from another's."""
+        return self[2:]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -148,18 +169,24 @@ class LlamaConfig:
     d_head: int = 0
     # Sliding-window attention: a layer of type ``sliding_attention``
     # sees its last ``sliding_window`` keys (itself included).
-    # ``layer_types`` names each layer's attention as Hugging Face's
-    # afmoe does: ``"sliding_attention"`` = the window AND RoPE,
-    # ``"full_attention"`` = every earlier key and NO position encoding.
+    # ``layer_types`` names each layer's token mixer as Hugging Face's
+    # configs do: ``"sliding_attention"`` = the window AND RoPE,
+    # ``"full_attention"`` = every earlier key, with RoPE where
+    # ``rope_full_attention`` says so (lfm2) and with NO position
+    # encoding where not (afmoe; the default), ``"conv"`` = no attention
+    # at all but a gated short convolution over the last ``conv_taps``
+    # positions (``_short_conv``; lfm2's ``conv_L_cache``).
     # Empty: every layer full, with RoPE (the llama family).
     sliding_window: int = 0
     layer_types: tuple = ()
+    rope_full_attention: bool = False
+    conv_taps: int = 0
     # The first ``n_dense_layers`` layers of a sparse-expert model keep
     # the dense FFN of width ``d_ff`` (``num_dense_layers``); the expert
     # layers' experts are ``moe_d_ff`` wide (``moe_intermediate_size``;
     # 0 = ``d_ff``, as mixtral and OLMoE publish it). Their parameters
     # are stacked apart: ``params["dense_layers"]`` before
-    # ``params["layers"]``.
+    # ``params["layers"]`` (``layer_plan`` is the rule).
     n_dense_layers: int = 0
     moe_d_ff: int = 0
     # Experts every token passes beside its routed ones
@@ -187,15 +214,22 @@ class LlamaConfig:
     # left out (ops/grouped_moe.py; grouped dispatch only).
     first_expert: int = 0
     n_experts_held: int = 0
+    # The head IS the embedding matrix (``tie_embedding``): one leaf,
+    # ``embed`` [vocab, d_model], read by the lookup and by the logits;
+    # its gradient is the sum of both uses. No ``lm_head`` leaf.
+    tie_embeddings: bool = False
 
     def __post_init__(self):
         if self.layer_types and len(self.layer_types) != self.n_layers:
             raise ValueError(
                 f"layer_types names {len(self.layer_types)} layers, "
                 f"n_layers is {self.n_layers}")
-        if any(t not in ("sliding_attention", "full_attention")
+        if any(t not in ("sliding_attention", "full_attention", "conv")
                for t in self.layer_types):
             raise ValueError(f"unknown layer type in {self.layer_types}")
+        if ("conv" in self.layer_types) != (self.conv_taps > 0):
+            raise ValueError("conv layers and conv_taps come together: "
+                             f"{self.layer_types}, {self.conv_taps} taps")
         if "sliding_attention" in self.layer_types \
                 and self.sliding_window <= 0:
             raise ValueError("sliding_attention layers need a "
@@ -232,17 +266,41 @@ class LlamaConfig:
         """How many experts' weights a layer of this program holds."""
         return self.n_experts_held or self.n_experts
 
+    def layer_plan(self):
+        """One :data:`LayerSpec` a layer, in order: the ONE rule for
+        where a layer's parameters live and which program it runs, read
+        by ``llama_init``, ``_run_layers`` and whatever walks the tree
+        (the references, the benchmark's adapters). Layers with the same
+        leaves are stacked together: ``layers`` (attention; the only
+        stack of a uniform model), ``conv_layers`` (a conv layer has
+        ``conv_in``, ``conv_w``, ``conv_out`` and no ``wq`` .. ``wo``),
+        and the leading dense layers of a sparse-expert model apart as
+        ``dense_layers`` / ``dense_conv_layers``. Every name ends in
+        ``layers``: ``llama_partition_rules`` shards them alike."""
+        plan, filled = [], collections.Counter()
+        for i in range(self.n_layers):
+            kind = self.layer_types[i] if self.layer_types \
+                else "full_attention"
+            conv, sliding = kind == "conv", kind == "sliding_attention"
+            stack = ("dense_" if i < self.n_dense_layers else "") \
+                + ("conv_" if conv else "") + "layers"
+            plan.append(LayerSpec(
+                stack, filled[stack], "conv" if conv else "attention",
+                self.n_experts == 0 or i < self.n_dense_layers,
+                self.sliding_window if sliding else 0,
+                not conv and (sliding or not self.layer_types
+                              or self.rope_full_attention)))
+            filled[stack] += 1
+        return plan
+
     def layer_kinds(self):
         """One ``(dense_ffn, window, rope)`` a layer, in order: what
-        tells the layer programs of this model apart."""
-        kinds = []
-        for i in range(self.n_layers):
-            sliding = bool(self.layer_types) \
-                and self.layer_types[i] == "sliding_attention"
-            kinds.append((self.n_experts == 0 or i < self.n_dense_layers,
-                          self.sliding_window if sliding else 0,
-                          sliding or not self.layer_types))
-        return kinds
+        told the layer programs of a model apart while every layer's
+        mixer was attention, and what the benchmark's accepted adapter
+        still reads (chipbench/models/afmoe.py). ``layer_plan`` has the
+        mixer too."""
+        return [(spec.dense_ffn, spec.window, spec.rope)
+                for spec in self.layer_plan()]
 
     def training_only_fields(self):
         """Names of the fields set here that only the training path
@@ -253,7 +311,9 @@ class LlamaConfig:
                             "n_dense_layers", "moe_d_ff",
                             "n_shared_experts", "score_func",
                             "route_scale", "scale_embed", "attn_gate",
-                            "post_norm", "first_expert", "n_experts_held")
+                            "post_norm", "first_expert", "n_experts_held",
+                            "rope_full_attention", "conv_taps",
+                            "tie_embeddings")
                 if getattr(self, f) != getattr(d, f)] \
             + (["qk_norm"] if self.qk_norm == "head" else [])
 
@@ -292,9 +352,10 @@ def llama_init(config, key):
     float32 by default — "master weights" — or bfloat16 for the
     pure-bf16 large-model recipe).
 
-    Per-layer tensors are stacked on a leading n_layers axis for scan;
-    a sparse-expert model's leading dense layers (``n_dense_layers``)
-    are a stack of their own, ``params["dense_layers"]``.
+    Per-layer tensors are stacked on a leading axis for scan, one stack
+    a kind of layer (``LlamaConfig.layer_plan``): a uniform model has
+    ``params["layers"]`` alone; a sparse-expert model's leading dense
+    layers and a hybrid's conv layers are stacks of their own.
     """
     c = config
     hd = c.head_dim
@@ -308,32 +369,45 @@ def llama_init(config, key):
         return (jax.random.normal(key, shape, jnp.float32)
                 * (fan_in ** -0.5)).astype(pd)
 
-    def stack(k, x, L, dense_ffn):
-        """``L`` layers of one FFN kind, stacked. ``k`` deals the keys
-        of the leaves every configuration has, in the order it always
-        did; ``x`` those of the leaves only the newer fields add, so
-        that an older configuration's weights do not move."""
-        layers = {
-            "attn_norm": jnp.ones((L, c.d_model), pd),
-            "wq": dense(next(k), (L, c.d_model, c.n_heads * hd),
-                        c.d_model),
-            "wk": dense(next(k), (L, c.d_model, c.n_kv_heads * hd),
-                        c.d_model),
-            "wv": dense(next(k), (L, c.d_model, c.n_kv_heads * hd),
-                        c.d_model),
-            "wo": dense(next(k), (L, c.n_heads * hd, c.d_model),
-                        c.n_heads * hd),
-            "mlp_norm": jnp.ones((L, c.d_model), pd),
-        }
-        if c.qk_norm == "head":
-            layers["q_norm"] = jnp.ones((L, hd), pd)
-            layers["k_norm"] = jnp.ones((L, hd), pd)
-        elif c.qk_norm:
-            layers["q_norm"] = jnp.ones((L, c.n_heads * hd), pd)
-            layers["k_norm"] = jnp.ones((L, c.n_kv_heads * hd), pd)
-        if c.attn_gate:
-            layers["wg"] = dense(next(x), (L, c.d_model, c.n_heads * hd),
-                                 c.d_model)
+    def stack(k, x, L, mixer, dense_ffn):
+        """``L`` layers of one kind, stacked. ``k`` deals the keys of
+        the leaves every configuration has, in the order it always did;
+        ``x`` those of the leaves only the newer fields add, so that an
+        older configuration's weights do not move."""
+        if mixer == "conv":
+            layers = {
+                "conv_norm": jnp.ones((L, c.d_model), pd),
+                "conv_in": dense(next(k), (L, c.d_model, 3 * c.d_model),
+                                 c.d_model),
+                "conv_w": dense(next(k), (L, c.conv_taps, c.d_model),
+                                c.conv_taps),
+                "conv_out": dense(next(k), (L, c.d_model, c.d_model),
+                                  c.d_model),
+                "mlp_norm": jnp.ones((L, c.d_model), pd),
+            }
+        else:
+            layers = {
+                "attn_norm": jnp.ones((L, c.d_model), pd),
+                "wq": dense(next(k), (L, c.d_model, c.n_heads * hd),
+                            c.d_model),
+                "wk": dense(next(k), (L, c.d_model, c.n_kv_heads * hd),
+                            c.d_model),
+                "wv": dense(next(k), (L, c.d_model, c.n_kv_heads * hd),
+                            c.d_model),
+                "wo": dense(next(k), (L, c.n_heads * hd, c.d_model),
+                            c.n_heads * hd),
+                "mlp_norm": jnp.ones((L, c.d_model), pd),
+            }
+            if c.qk_norm == "head":
+                layers["q_norm"] = jnp.ones((L, hd), pd)
+                layers["k_norm"] = jnp.ones((L, hd), pd)
+            elif c.qk_norm:
+                layers["q_norm"] = jnp.ones((L, c.n_heads * hd), pd)
+                layers["k_norm"] = jnp.ones((L, c.n_kv_heads * hd), pd)
+            if c.attn_gate:
+                layers["wg"] = dense(next(x),
+                                     (L, c.d_model, c.n_heads * hd),
+                                     c.d_model)
         if c.post_norm:
             layers["post_attn_norm"] = jnp.ones((L, c.d_model), pd)
             layers["post_mlp_norm"] = jnp.ones((L, c.d_model), pd)
@@ -366,19 +440,29 @@ def llama_init(config, key):
             })
         return layers
 
-    layers = stack(k, iter(jax.random.split(jax.random.fold_in(key, 1), 8)),
-                   c.n_layers - c.n_dense_layers, c.n_experts == 0)
-    params = {
-        "embed": (jax.random.normal(next(k), (c.vocab_size, c.d_model),
-                                    jnp.float32) * 0.02).astype(pd),
-        "layers": layers,
-        "final_norm": jnp.ones(c.d_model, pd),
-        "lm_head": dense(next(k), (c.d_model, c.vocab_size), c.d_model),
-    }
-    if c.n_dense_layers:
-        lead = jax.random.split(jax.random.fold_in(key, 2), 16)
-        params["dense_layers"] = stack(iter(lead[:8]), iter(lead[8:]),
-                                       c.n_dense_layers, True)
+    # The stacks this model has, each with its kind and depth. "layers"
+    # draws from ``k`` ahead of the embedding and the head, as it always
+    # did; every other stack from a fold of its own.
+    stacks = {}
+    for spec in c.layer_plan():
+        stacks[spec.stack] = (spec.mixer, spec.dense_ffn, spec.index + 1)
+    folds = {"dense_layers": 2, "conv_layers": 3, "dense_conv_layers": 4}
+    params = {}
+    for name in sorted(stacks, key=lambda n: n != "layers"):
+        mixer, dense_ffn, L = stacks[name]
+        if name == "layers":
+            dealt = k, iter(jax.random.split(jax.random.fold_in(key, 1), 8))
+        else:
+            lead = jax.random.split(jax.random.fold_in(key, folds[name]),
+                                    16)
+            dealt = iter(lead[:8]), iter(lead[8:])
+        params[name] = stack(*dealt, L, mixer, dense_ffn)
+    params["embed"] = (jax.random.normal(
+        next(k), (c.vocab_size, c.d_model), jnp.float32) * 0.02).astype(pd)
+    params["final_norm"] = jnp.ones(c.d_model, pd)
+    if not c.tie_embeddings:
+        params["lm_head"] = dense(next(k), (c.d_model, c.vocab_size),
+                                  c.d_model)
     return params
 
 
@@ -397,9 +481,15 @@ def llama_partition_rules(pipeline=False):
         # attn_norm, mlp_norm and, where the config has them, q_norm and
         # k_norm: a gain vector per layer, replicated.
         (r"layers/.*norm", P(lead, None)),
-        # "layers/" also matches "dense_layers/": both stacks shard alike.
+        # "layers/" matches every stack of ``LlamaConfig.layer_plan``
+        # ("dense_layers/", "conv_layers/", ...): all shard alike.
         (r"layers/w[qkvg]$", P(lead, "fsdp", "tensor")),
         (r"layers/wo", P(lead, "tensor", "fsdp")),
+        # The short convolution: projections like attention's, the taps
+        # (one weight a channel and tap) replicated.
+        (r"layers/conv_in", P(lead, "fsdp", "tensor")),
+        (r"layers/conv_out", P(lead, "tensor", "fsdp")),
+        (r"layers/conv_w", P(lead, None, None)),
         (r"layers/(w|shared)_(gate|up)", P(lead, "fsdp", "tensor")),
         (r"layers/(w|shared)_down", P(lead, "tensor", "fsdp")),
         # MoE: experts shard over the "expert" mesh axis (EP); within an
@@ -502,6 +592,36 @@ def _project_qkv(h, lp, c):
     return (_head_proj(h, lp["wq"], lp["q_norm"] if c.qk_norm else None, c),
             _head_proj(h, lp["wk"], lp["k_norm"] if c.qk_norm else None, c),
             _head_proj(h, lp["wv"], None, c))
+
+
+def gated_short_conv(proj, w):
+    """The chain between a conv layer's two projections: ``proj``
+    [B, T, 3D] is ``[B, C, z]`` side by side, ``w`` [taps, D] one weight
+    a channel and tap -> ``C * c`` [B, T, D] with ``c_t = sum_j w_j *
+    (B * z)_{t - (taps-1) + j}``, zero before position 0 (depthwise and
+    causal). Shifts, not ``lax.conv``: three multiply-adds a channel
+    that the compiler fuses into one elementwise pass; in ``proj``'s
+    dtype, the taps summed in float32. Traced under the scope
+    ``hvd_short_conv`` (chipbench's ``short_conv_ms_per_step``)."""
+    with jax.named_scope("hvd_short_conv"):
+        gate_in, gate_out, z = jnp.split(proj, 3, axis=-1)
+        u = gate_in * z
+        w = w.astype(jnp.float32)
+        taps, t = w.shape[0], u.shape[1]
+        conv = u.astype(jnp.float32) * w[taps - 1]
+        for back in range(1, taps):      # u as it was ``back`` tokens ago
+            past = jnp.pad(u, ((0, 0), (back, 0), (0, 0)))[:, :t]
+            conv = conv + past.astype(jnp.float32) * w[taps - 1 - back]
+        return gate_out * conv.astype(proj.dtype)
+
+
+def _short_conv(h, lp, c):
+    """lfm2's gated short convolution, the token mixer of a ``conv``
+    layer, on normalized ``h`` [B, T, D]: ``W_out (C * conv(B * z))``
+    with ``[B, C, z] = split3(W_in h)`` (:func:`gated_short_conv`)."""
+    dt = c.compute_dtype
+    return gated_short_conv(h @ lp["conv_in"].astype(dt), lp["conv_w"]) \
+        @ lp["conv_out"].astype(dt)
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(1,))
@@ -781,8 +901,15 @@ def llama_forward(params, tokens, config, mesh=None, seq_axis="seq",
     x = _rmsnorm(x, params["final_norm"].astype(dt), c.norm_eps)
     # bf16 operands, f32 accumulation: full MXU rate without giving up
     # the f32 logits downstream softmax stability needs.
-    logits = jnp.matmul(x, params["lm_head"].astype(dt),
-                        preferred_element_type=jnp.float32)
+    if c.tie_embeddings:
+        # The embedding matrix [vocab, D] contracted over D where it
+        # lies: no transposed copy; its gradient is the sum of this use
+        # and the lookup's.
+        logits = jnp.einsum("btd,vd->btv", x, params["embed"].astype(dt),
+                            preferred_element_type=jnp.float32)
+    else:
+        logits = jnp.matmul(x, params["lm_head"].astype(dt),
+                            preferred_element_type=jnp.float32)
     if return_aux:
         return logits, aux
     return logits
@@ -828,20 +955,22 @@ def _run_layers(params, x, c, mesh, seq_axis):
       gate/up activations stacked and unstacked: 20.5 ms of a 169.1 ms
       step at OLMoE's widths, two layers (PERF.md section 6, PR 33);
     - a layer pattern (leading dense layers; window and full attention
-      layers mixed) is a different PROGRAM a kind, so it runs unrolled
-      too, each layer the body of its own kind (``dense_layers``, then
-      ``layers``).
+      layers mixed; conv layers beside attention layers) is a different
+      PROGRAM a kind, so it runs unrolled too, each layer the body of
+      its own kind on its own entry of its own stack
+      (``LlamaConfig.layer_plan``).
 
     Unrolled, program size and compile time are O(depth). A pipeline
     stage (``_stage_scan``) always scans: one layer program by contract."""
-    kinds = c.layer_kinds()
-    if len(set(kinds)) == 1 and not _grouped_dispatch(c, mesh):
+    plan = c.layer_plan()
+    kinds = {spec.kind for spec in plan}
+    if len(kinds) == 1 and not _grouped_dispatch(c, mesh):
         return lax.scan(_build_layer_body(c, mesh, seq_axis), x,
-                        params["layers"], unroll=c.scan_unroll)
+                        params[plan[0].stack], unroll=c.scan_unroll)
     from horovod_tpu.ops.grouped_moe import LayerOfStack
 
     bodies = {kind: _build_layer_body(c, mesh, seq_axis, kind=kind)
-              for kind in set(kinds)}
+              for kind in kinds}
     # A SHARE of the experts (``n_experts_held``) gets slices: its later
     # chunks run under a ``lax.scan``, whose transpose carries a gradient
     # accumulator the shape of whatever the body closes over, and whole
@@ -850,12 +979,10 @@ def _run_layers(params, x, c, mesh, seq_axis):
     whole = _EXPERT_MATRICES \
         if _grouped_dispatch(c, mesh) and not c.n_experts_held else ()
     balance = []
-    for at, kind in enumerate(kinds):
-        name, j = ("dense_layers", at) if at < c.n_dense_layers \
-            else ("layers", at - c.n_dense_layers)
-        lp = {k: LayerOfStack(w, j) if k in whole else w[j]
-              for k, w in params[name].items()}
-        x, bal = bodies[kind](x, lp)
+    for spec in plan:
+        lp = {k: LayerOfStack(w, spec.index) if k in whole
+              else w[spec.index] for k, w in params[spec.stack].items()}
+        x, bal = bodies[spec.kind](x, lp)
         balance.append(bal)
     # Dense layers carry zero-width statistics: the expert layers' only.
     return x, jnp.stack([b for b in balance if b.shape[-1]] or balance)
@@ -884,10 +1011,14 @@ def _validate_pipeline(c, b, mesh, seq_axis, n_stages):
     this layout (ring attention's own shard_map cannot nest inside the
     pipeline's)."""
     M = c.pipeline_microbatches or n_stages
-    if len(set(c.layer_kinds())) > 1:
+    plan = c.layer_plan()
+    if len({spec.kind for spec in plan}) > 1 or plan[0].mixer == "conv" \
+            or c.tie_embeddings:
         raise ValueError("a layer pattern (leading dense layers, window "
-                         "and full attention mixed) has no pipeline "
-                         "schedule yet: a stage scans ONE layer program")
+                         "and full attention mixed), conv layers and a "
+                         "tied head have no pipeline schedule yet: a "
+                         "stage scans ONE attention layer program of "
+                         "params['layers'], the last stage reads lm_head")
     if seq_axis and mesh.shape.get(seq_axis, 1) > 1:
         raise ValueError("pipeline (pipe>1) and sequence parallelism "
                          "(seq>1) cannot combine: ring attention's "
@@ -920,17 +1051,20 @@ def _build_layer_body(c, mesh, seq_axis, constrain_acts=True, kind=None):
     INSIDE the pipe-manual shard_map, and XLA CPU aborts transposing
     with_sharding_constraint on auto axes there; GSPMD still lays out
     activations by propagation from the sharded params). ``kind``:
-    this layer's ``(dense_ffn, window, rope)`` of
-    ``LlamaConfig.layer_kinds`` (default: the first layer's, the only
+    this layer's ``(mixer, dense_ffn, window, rope)`` of
+    ``LlamaConfig.layer_plan`` (default: the first layer's, the only
     one a uniform model has); the FFN follows the parameters it is
     handed (``_ffn``)."""
     dt = c.compute_dtype
-    _, window, rope = kind or c.layer_kinds()[0]
+    mixer, _, window, rope = kind or c.layer_plan()[0].kind
 
     def constrain(x):
         return _constrain(x, mesh) if constrain_acts else x
 
     def layer(x, lp):
+        if mixer == "conv":
+            h = _rmsnorm(x, lp["conv_norm"].astype(dt), c.norm_eps)
+            return ffn(x, _short_conv(h, lp, c), lp)
         # Shapes from x, not the enclosing scope: under pipelining the
         # layer sees microbatches smaller than the full batch.
         bb, tt = x.shape[0], x.shape[1]
@@ -953,11 +1087,14 @@ def _build_layer_body(c, mesh, seq_axis, constrain_acts=True, kind=None):
                           c.flash_block, window).reshape(bb, tt, -1)
         if c.attn_gate:
             attn = attn * jax.nn.sigmoid(h @ lp["wg"].astype(dt))
-        attn = attn @ lp["wo"].astype(dt)
+        return ffn(x, attn @ lp["wo"].astype(dt), lp)
+
+    def ffn(x, mixed, lp):
+        """The mixer's output joins the stream; then the FFN's."""
         if c.post_norm:
-            attn = _rmsnorm(attn, lp["post_attn_norm"].astype(dt),
-                            c.norm_eps)
-        x = x + constrain(attn)
+            mixed = _rmsnorm(mixed, lp["post_attn_norm"].astype(dt),
+                             c.norm_eps)
+        x = x + constrain(mixed)
 
         h = _rmsnorm(x, lp["mlp_norm"].astype(dt), c.norm_eps)
         ff, aux = _ffn(h, lp, c, mesh)

@@ -1,9 +1,10 @@
 """Plain float32 references of the architectures the llama path runs
 beyond the dense decoder. What the program's kernels, sorts, scans and
 remat modes are compared against (tests/single/test_olmoe_reference.py,
-tests/single/test_afmoe_reference.py; the chip benchmark keeps copies
-of its own, chipbench/models/olmoe.py and afmoe.py). OLMoE first;
-Trinity-Mini (afmoe) below it, with its own description.
+tests/single/test_afmoe_reference.py, tests/single/test_lfm2_reference.py;
+the chip benchmark keeps copies of its own, chipbench/models/olmoe.py,
+afmoe.py and lfm2moe.py). OLMoE first; Trinity-Mini (afmoe) and
+LFM2-8B-A1B (lfm2_moe) below it, each with its own description.
 
 OLMoE (arXiv:2409.02060; Hugging Face ``modeling_olmoe.py``), as
 published:
@@ -271,6 +272,168 @@ def afmoe_loss(params, batch, cfg, vocab_rows=None):
     removed (what a chip that holds that slice of the head computes).
     ``jax.grad`` of this is the reference gradient."""
     logits = afmoe_forward(params, batch["tokens"], cfg)
+    logp = jax.nn.log_softmax(logits[..., :vocab_rows], -1)
+    nll = -jnp.take_along_axis(logp, batch["targets"][..., None],
+                               -1)[..., 0]
+    mask = batch.get("mask", jnp.ones_like(nll))
+    return jnp.sum(nll * mask) / jnp.sum(mask)
+
+
+# ---------------------------------------------------------------------
+# LFM2-8B-A1B (LiquidAI, ``model_type`` ``lfm2_moe``; Hugging Face
+# ``modeling_lfm2_moe.py``), as published. Pre-norm, two RMSNorms a
+# layer (``operator_norm`` and ``ffn_norm`` there; ``conv_norm`` or
+# ``attn_norm``, and ``mlp_norm`` in the program's tree), eps
+# ``norm_eps``, no bias anywhere: ``x = x + Mixer(RMS(x))``, then
+# ``x = x + FFN(RMS(x))``.
+#
+# - a ``conv`` layer's mixer, the gated short convolution:
+#   ``[B, C, z] = split3(W_in h)`` (``W_in`` d -> 3d); ``u = B * z``;
+#   ``c_t = sum_{j=0..L-1} w_j * u_{t-(L-1)+j}`` with ``u`` zero before
+#   position 0 (depthwise: one weight a channel and tap, ``conv_w``
+#   [L, d], ``L = conv_L_cache`` = 3; causal: ``nn.Conv1d`` with
+#   ``groups = d``, padding ``L - 1``, the tail cut); ``y = W_out (C *
+#   c)``. No position encoding, no softmax, no state beyond L-1 tokens;
+# - a ``full_attention`` layer's: ``q = RMS_q(W_q h)``, ``k = RMS_k(W_k
+#   h)`` over EACH head's ``head_dim`` (one gain a projection and layer,
+#   shared by the heads), ``v = W_v h``; half-split RoPE on q and k;
+#   causal ``softmax(q k / sqrt(head_dim)) v`` with grouped key/value
+#   heads; ``W_o``. No gate, no post-norm;
+# - FFN: the first ``n_dense_layers`` layers (all, in the family's dense
+#   models, which have no experts) a SwiGLU of width ``d_ff``; the
+#   others ``s = sigmoid(W_r h)`` over all experts, the K experts
+#   with the largest ``s + expert_bias``, ``w_k = route_scale * s_k /
+#   (sum of the chosen s + 1e-6)``, ``y = sum_k w_k Expert_k(h)``, each
+#   a SwiGLU of width ``moe_d_ff``. No shared expert;
+# - ``logits = E RMS_final(x)``: the head is the embedding matrix ``E``
+#   (``tie_embedding``); loss = mean token cross-entropy.
+#
+# The share: as for afmoe above (``n_experts_held``, ``first_expert``;
+# the vocabulary rows held are a smaller vocabulary).
+#
+# Departures: no router aux loss (the config has no coefficient);
+# ``expert_bias`` is read as data (its update is the trainer's); the
+# program's router guards the sum of the chosen scores with ``max(.,
+# 1e-9)`` where the published form adds 1e-6: this reference follows the
+# published form, and the two differ by under 1e-6 of a weight (four
+# sigmoids sum to about 2). Parameters stored in bf16 are read as
+# float32. Written like the two above: explicit mask, explicit shifts, a
+# Python loop over layers, every held expert computed for every token,
+# nothing shared with models/llama.py or ops/. It finds a layer's
+# parameters in the program's tree by its own count (``_lfm2_layer``),
+# not by ``LlamaConfig.layer_plan``.
+# ---------------------------------------------------------------------
+
+def lfm2_short_conv(h, lp):
+    """The conv mixer on normalized ``h`` [B, T, D] with one layer's
+    float32 parameters: three explicit shifted products."""
+    b, t, d = h.shape
+    bcz = h @ lp["conv_in"]
+    gate_in, gate_out, z = bcz[..., :d], bcz[..., d:2 * d], bcz[..., 2 * d:]
+    u = gate_in * z
+    taps = lp["conv_w"].shape[0]
+    conv = jnp.zeros_like(u)
+    for j in range(taps):
+        back = taps - 1 - j                  # u as it was ``back`` ago
+        past = jnp.concatenate(
+            [jnp.zeros((b, back, d), F32), u[:, :t - back]], 1)
+        conv = conv + lp["conv_w"][j] * past
+    return (gate_out * conv) @ lp["conv_out"]
+
+
+def lfm2_route(h, lp, cfg):
+    """``h`` [..., D] -> weights [..., E] over ALL experts: ``route_scale
+    * s / (sum of the chosen s + 1e-6)`` at the K experts with the
+    largest ``s + expert_bias``, 0 elsewhere."""
+    n = cfg.n_experts
+    s = jax.nn.sigmoid(h @ lp["router"])
+    left, chosen = s + lp["expert_bias"], jnp.zeros_like(s)
+    for _ in range(cfg.n_experts_per_token):
+        pick = jax.nn.one_hot(jnp.argmax(left, -1), n, dtype=F32)
+        chosen = chosen + pick
+        left = jnp.where(pick > 0, -jnp.inf, left)
+    w = chosen * s
+    return cfg.route_scale * w / (jnp.sum(w, -1, keepdims=True) + 1e-6)
+
+
+def lfm2_expert_layer(h, lp, cfg):
+    """The FFN of one expert layer on normalized ``h`` [B, T, D]: the
+    routed sum over the experts ``lp`` holds (``first_expert .. +
+    n_experts_held - 1``; all, where no share is set)."""
+    first = cfg.first_expert
+    held = cfg.n_experts_held or cfg.n_experts
+    w = lfm2_route(h, lp, cfg)[..., first:first + held]
+    act = jax.nn.silu(jnp.einsum("btd,edf->btef", h, lp["moe_gate"])) \
+        * jnp.einsum("btd,edf->btef", h, lp["moe_up"])
+    y = jnp.einsum("btef,efd->bted", act, lp["moe_down"])
+    return jnp.einsum("bte,bted->btd", w, y)
+
+
+def _lfm2_layer(params, cfg, l):
+    """Layer ``l``'s parameters out of the program's tree, float32: the
+    conv layers and the attention layers are stacked apart, and the
+    leading dense ones apart from both."""
+    def stack(i):
+        return ("dense_" if i < cfg.n_dense_layers else "") \
+            + ("conv_" if cfg.layer_types[i] == "conv" else "") + "layers"
+
+    at = sum(stack(i) == stack(l) for i in range(l))
+    return jax.tree.map(lambda w: w[at].astype(F32), params[stack(l)])
+
+
+def lfm2_forward(params, tokens, cfg):
+    """tokens [B, T] -> logits [B, T, vocab] f32 (see the description
+    above). ``params`` is the program's tree, any storage dtype."""
+    hd = cfg.head_dim
+    rep = cfg.n_heads // cfg.n_kv_heads
+    b, t = tokens.shape
+    inv = cfg.rope_theta ** (-jnp.arange(0, hd // 2, dtype=F32)
+                             / (hd // 2))
+    ang = jnp.arange(t, dtype=F32)[:, None] * inv           # [T, hd/2]
+    cos, sin = jnp.cos(ang)[None, :, None], jnp.sin(ang)[None, :, None]
+
+    def rope(x):
+        x1, x2 = x[..., :hd // 2], x[..., hd // 2:]
+        return jnp.concatenate([x1 * cos - x2 * sin,
+                                x1 * sin + x2 * cos], -1)
+
+    mask = jnp.arange(t)[None, :] <= jnp.arange(t)[:, None]
+    embed = params["embed"].astype(F32)
+    with jax.default_matmul_precision("highest"):
+        x = embed[tokens]
+        for l in range(cfg.n_layers):
+            lp = _lfm2_layer(params, cfg, l)
+            if cfg.layer_types[l] == "conv":
+                x = x + lfm2_short_conv(
+                    _rms(x, lp["conv_norm"], cfg.norm_eps), lp)
+            else:
+                h = _rms(x, lp["attn_norm"], cfg.norm_eps)
+                q = rope(_rms((h @ lp["wq"]).reshape(b, t, cfg.n_heads, hd),
+                              lp["q_norm"], cfg.norm_eps))
+                k = rope(_rms((h @ lp["wk"]).reshape(
+                    b, t, cfg.n_kv_heads, hd), lp["k_norm"], cfg.norm_eps))
+                v = (h @ lp["wv"]).reshape(b, t, cfg.n_kv_heads, hd)
+                k, v = jnp.repeat(k, rep, 2), jnp.repeat(v, rep, 2)
+                s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / (hd ** 0.5)
+                p = jax.nn.softmax(jnp.where(mask, s, -jnp.inf), -1)
+                a = jnp.einsum("bhqk,bkhd->bqhd", p, v).reshape(b, t, -1)
+                x = x + a @ lp["wo"]
+            h = _rms(x, lp["mlp_norm"], cfg.norm_eps)
+            if l < cfg.n_dense_layers or not cfg.n_experts:
+                x = x + _swiglu(h, lp["w_gate"], lp["w_up"], lp["w_down"])
+            else:
+                x = x + lfm2_expert_layer(h, lp, cfg)
+        x = _rms(x, params["final_norm"].astype(F32), cfg.norm_eps)
+        return x @ embed.T
+
+
+def lfm2_loss(params, batch, cfg, vocab_rows=None):
+    """Mean token cross-entropy over the positions ``batch["mask"]``
+    keeps (all without one); no aux term. ``vocab_rows``: the loss over
+    the first that many rows of the vocabulary, the other logits
+    removed. ``jax.grad`` of this is the reference gradient; the tied
+    matrix's is the sum of its two uses."""
+    logits = lfm2_forward(params, batch["tokens"], cfg)
     logp = jax.nn.log_softmax(logits[..., :vocab_rows], -1)
     nll = -jnp.take_along_axis(logp, batch["targets"][..., None],
                                -1)[..., 0]

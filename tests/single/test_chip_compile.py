@@ -211,6 +211,22 @@ _TQ, _TKV = ((2, 8192, 32, 128), BF16), ((2, 8192, 4, 128), BF16)
                  (((32768, 1024), BF16), ((16, 1024, 2048), BF16),
                   ((16,), I32)),
                  id="megablox-gmm-trinitymini-share-down"),
+    # LFM2-8B-A1B's attention at the chip cell's size: heads 64 wide,
+    # 32 on 8 (no head under 128 had run through the training kernels).
+    pytest.param(_flash_fwd_bwd,
+                 (((2, 8192, 32, 64), BF16),)
+                 + (((2, 8192, 8, 64), BF16),) * 2,
+                 id="flash-fwd+bwd-lfm2moe-b2s8192"),
+    # Its share: one chunk of 32,768 sorted slots into the 8 experts
+    # held, expert width 1792 = 1.75 of the 1024 tile.
+    pytest.param(_gmm,
+                 (((32768, 2048), BF16), ((8, 2048, 1792), BF16),
+                  ((8,), I32)),
+                 id="megablox-gmm-lfm2moe-share-gate-up"),
+    pytest.param(_gmm,
+                 (((32768, 1792), BF16), ((8, 1792, 2048), BF16),
+                  ((8,), I32)),
+                 id="megablox-gmm-lfm2moe-share-down"),
 ])
 def test_kernel_compiles_for_described_v5e(for_tpu, fn, shapes):
     assert "tpu_custom_call" in for_tpu(fn, *shapes)
