@@ -38,7 +38,12 @@ expert-parallel deployment (``LlamaConfig.first_expert``,
 ones it holds, without the exchange (``_held_experts_ffn``): the slots
 of absent experts sort last, the held rows are worked off in chunks of
 a static length, and a token's rows come back by a scatter-add, since
-most of a token's K slots are elsewhere.
+most of a token's K slots are elsewhere. A chunk's buffers are as long
+as the chunk; its rows are GATHERED block by block and only as far as
+the held rows reach (``held_blocks``: a gather costs by the row,
+whoever reads it), as the grouped GEMMs visit the tiles of their groups
+and nothing else. The scatter-add stays one call a chunk: in blocks it
+costs three times as much a row (``_sum_held``).
 
 Sharding: this path is for programs where the experts are NOT sharded
 over an ``expert`` mesh axis (single chip, or EP-free meshes) — the
@@ -308,49 +313,113 @@ def _group_sizes(e_flat, n_experts):
 _HELD_ROW_BOUND = 2
 
 
-def _sum_rows(rows, tok, n_tokens):
-    """``out[t] = sum of rows[i] where tok[i] == t``, [n_tokens, D] in
-    ``rows``' dtype, accumulated in float32 (a scatter-add: a token has
-    0..K of the bounded rows, in no order)."""
+# The share's row gathers run over blocks of this many rows of a chunk
+# and stop after the last block that held rows reach (``held_blocks``):
+# XLA's gather costs by the ROW (33 ns from HBM at 2048 columns of
+# bf16; in blocks 28, and 10 more to write a block into its place), so
+# a chunk half full is gathered in 0.6 of the time. One expert layer of
+# the LFM2 cell, forward and backward, 16,732 rows held of a chunk of
+# 32,768 (my chip runs, PR 35): 22.94 ms at 1024, 22.85 at 2048, 23.01
+# at 4096, 23.80 with the whole chunk gathered; at 65,536 rows held (two
+# chunks full) 60.63, 60.26, 60.18 and 60.19. A chunk whose length it
+# does not divide is gathered as one block.
+_HELD_BLOCK = 2048
+
+
+def held_blocks(n, start, chunk_rows, block_rows):
+    """How many blocks of ``block_rows`` rows the share's gathers visit
+    in the chunk of sorted slots ``start .. start + chunk_rows - 1`` of
+    a layer that holds ``n`` rows: those up to the last one a held row
+    lies in, ``ceil(clip(n - start, 0, chunk_rows) / block_rows)``.
+    Over ``chunk_rows // block_rows`` it is the share of the chunk that
+    is gathered (``docs/metrics.md``)."""
+    return (jnp.clip(n - start, 0, chunk_rows) + block_rows - 1) // block_rows
+
+
+def _block_rows(chunk_rows):
+    return _HELD_BLOCK if chunk_rows % _HELD_BLOCK == 0 else chunk_rows
+
+
+def _gather_held(x, tok, held):
+    """``x[tok]`` [R, D] for the first ``held`` of ``tok``'s R rows,
+    gathered block by block into place. Rows past the last visited block
+    are NOT WRITTEN (``lax.empty``: on the chip whatever the buffer held,
+    NaN for all anyone knows), those between ``held`` and its end are
+    rows nobody asked for: no group of the grouped GEMMs covers either,
+    and every sum over a chunk's rows selects by ``held`` first."""
+    R = tok.shape[0]
+    B = _block_rows(R)
+    shape = (R, x.shape[1])
+
+    def block(i, out):
+        rows = _rows(x, lax.dynamic_slice_in_dim(tok, i * B, B))
+        return lax.dynamic_update_slice_in_dim(out, rows, i * B, 0)
+
+    # The buffer is born inside a branch: the compiler allocates a
+    # buffer that depends on nothing at the program's start, and the
+    # layers' 24 of them (134 MB each at the cells' sizes) then live
+    # all at once. A zero-fill in its place is a pass of its own.
+    return lax.cond(
+        held > 0,
+        lambda: lax.fori_loop(0, held_blocks(held, 0, R, B), block,
+                              lax.empty(shape, x.dtype)),
+        lambda: jnp.zeros(shape, x.dtype))
+
+
+def _sum_held(rows, tok, held, n_tokens):
+    """``out[t] = sum of rows[i] where tok[i] == t and i < held``,
+    [n_tokens, D] in ``rows``' dtype, accumulated in float32 (a
+    scatter-add: a token has 0..K of the chunk's rows, in no order).
+    What ``rows`` holds from ``held`` on (the grouped GEMMs leave it
+    UNWRITTEN) is selected away, never multiplied: it may be NaN.
+
+    All R rows in ONE scatter-add, whatever ``held``: the compiler
+    sorts the indices, permutes the rows and sums sorted runs in one
+    fusion at 75 ns a row, and each call passes over the whole
+    accumulator first. Block by block as ``_gather_held`` it reads 245
+    ns a row, 4.6 ms where this takes 3.2 (PERF.md section 6, PR 35)."""
+    live = lax.iota(jnp.int32, tok.shape[0]) < held
     out = jnp.zeros((n_tokens, rows.shape[1]), jnp.float32)
-    return out.at[tok].add(rows.astype(jnp.float32),
-                           mode="promise_in_bounds").astype(rows.dtype)
+    return out.at[tok].add(
+        jnp.where(live[:, None], rows, 0).astype(jnp.float32),
+        mode="promise_in_bounds").astype(rows.dtype)
 
 
 # The share's two row movements, each the other's transpose and VJP as
-# ``_dispatch`` / ``_combine`` are, over the first R sorted slots only.
-# Rows ``valid`` does not cover are slots of experts held elsewhere: no
-# group of the grouped GEMMs covers them, which therefore leave them
-# UNWRITTEN (whatever the buffer held), so every sum masks them.
+# ``_dispatch`` / ``_combine`` are, over one chunk of R sorted slots of
+# which the first ``held`` are slots of experts held here. The gather's
+# loop has a trip count that follows ``held`` (a ``while``): legal
+# because it lives inside custom VJPs, which are never differentiated
+# again.
 
 @jax.custom_vjp
-def _dispatch_held(h, tok, valid):
-    return _rows(h, tok)
+def _dispatch_held(h, tok, held):
+    return _gather_held(h, tok, held)
 
 
-def _dispatch_held_fwd(h, tok, valid):
-    return _rows(h, tok), (tok, valid, h.shape[0])
+def _dispatch_held_fwd(h, tok, held):
+    return _gather_held(h, tok, held), (tok, held, h.shape[0])
 
 
 def _dispatch_held_bwd(res, g):
-    tok, valid, n = res
-    return _sum_rows(jnp.where(valid[:, None], g, 0), tok, n), None, None
+    tok, held, n_tokens = res
+    return _sum_held(g, tok, held, n_tokens), None, None
 
 
 _dispatch_held.defvjp(_dispatch_held_fwd, _dispatch_held_bwd)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _combine_held(z, tok, valid, n_tokens):
-    return _sum_rows(jnp.where(valid[:, None], z, 0), tok, n_tokens)
+def _combine_held(z, tok, held, n_tokens):
+    return _sum_held(z, tok, held, n_tokens)
 
 
-def _combine_held_fwd(z, tok, valid, n_tokens):
-    return _combine_held(z, tok, valid, n_tokens), tok
+def _combine_held_fwd(z, tok, held, n_tokens):
+    return _sum_held(z, tok, held, n_tokens), (tok, held)
 
 
-def _combine_held_bwd(n_tokens, tok, g):
-    return _rows(g, tok), None, None
+def _combine_held_bwd(n_tokens, res, g):
+    return _gather_held(g, *res), None, None
 
 
 _combine_held.defvjp(_combine_held_fwd, _combine_held_bwd)
@@ -371,9 +440,12 @@ def _held_experts_ffn(hf, lp, c, gate_vals, gate_idx):
     grouped GEMMs (which visit the tiles of their groups and nothing
     else), summed back per token. The first chunk always runs; a further
     one runs only if held rows reach it (``lax.cond`` in a ``lax.scan``
-    over the rest of the S*K slots), so up to R held rows the layer
-    moves R rows, not S*K, and every temporary is R rows long at any
-    load."""
+    over the rest of the S*K slots), so every temporary is R rows long
+    at any load. Within a chunk the gathers run over blocks of
+    ``_HELD_BLOCK`` rows and stop after the last block a held row lies
+    in (``held_blocks``): the layer gathers the rows it holds, rounded
+    up to a block, not R. The scatter-adds take all R rows, those from
+    the last held row on selected away (``_sum_held``)."""
     S, D = hf.shape
     K, H = c.n_experts_per_token, c.n_experts_held
     dt = c.compute_dtype
@@ -391,12 +463,12 @@ def _held_experts_ffn(hf, lp, c, gate_vals, gate_idx):
     def chunk(start):
         """Sorted slots ``start .. start + R - 1`` -> [S, D]."""
         tok = lax.dynamic_slice_in_dim(order, start, R) // K
-        valid = start + lax.iota(jnp.int32, R) < n
-        w = jnp.where(valid, lax.dynamic_slice_in_dim(w_sorted, start, R),
-                      0)
+        held = jnp.clip(n - start, 0, R)
+        w = jnp.where(lax.iota(jnp.int32, R) < held,
+                      lax.dynamic_slice_in_dim(w_sorted, start, R), 0)
         # each group's rows that fall inside this chunk
         sizes = jnp.diff(jnp.clip(ends, start, start + R), prepend=start)
-        x_sorted = _dispatch_held(hf.astype(dt), tok, valid)
+        x_sorted = _dispatch_held(hf.astype(dt), tok, held)
         gate_pre = checkpoint_name(
             _grouped_mm(x_sorted, lp["moe_gate"].astype(dt), sizes),
             "moe_gate_act")
@@ -406,7 +478,7 @@ def _held_experts_ffn(hf, lp, c, gate_vals, gate_idx):
         y_sorted = _grouped_mm(
             jax.nn.silu(gate_pre) * up * w[:, None],
             lp["moe_down"].astype(dt), sizes)
-        return _combine_held(y_sorted, tok, valid, S)
+        return _combine_held(y_sorted, tok, held, S)
 
     y = chunk(0)
     if chunks == 1:

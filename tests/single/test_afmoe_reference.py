@@ -235,13 +235,10 @@ def test_no_held_slot_is_dropped_at_any_load(load):
         assert np.all((held > 0) & (held <= chunk))
 
 
-@pytest.mark.parametrize("rows", [0, 100, 256, 257, 700, 1024])
-def test_the_chunks_of_the_share_cover_exactly_the_held_rows(rows):
-    """``_held_experts_ffn`` alone with a hand-made routing that sends
-    exactly ``rows`` of 1024 slots to the 2 held experts (chunks of 256:
-    none, part of the first, the first whole, one row into the second,
-    into the third, all four): the held experts' weighted outputs,
-    summed per token, and their gradients, against a dense count."""
+def _share_layer(rows):
+    """One expert layer that holds experts 4 and 5 of 16, 256 tokens,
+    and a hand-made routing that sends exactly ``rows`` of the 1024
+    slots to them (chunks of 256)."""
     cfg = _cfg(n_experts_held=2, first_expert=4)
     lp = jax.tree.map(lambda w: w[0], _params(cfg)["layers"])
     ks = jax.random.split(jax.random.PRNGKey(rows), 3)
@@ -252,6 +249,22 @@ def test_the_chunks_of_the_share_cover_exactly_the_held_rows(rows):
     idx = jnp.where(held, 4 + pick % 2, jnp.where(pick < 4, pick, pick + 2))
     idx = idx.reshape(256, 4).astype(jnp.int32)
     w = jax.random.uniform(ks[2], (256, 4), jnp.float32, 0.5, 1.5)
+    return cfg, lp, hf, idx, w
+
+
+@pytest.mark.parametrize("block", [64, 2048])
+@pytest.mark.parametrize("rows", [0, 100, 256, 257, 700, 1024])
+def test_the_chunks_of_the_share_cover_exactly_the_held_rows(
+        rows, block, monkeypatch):
+    """``_held_experts_ffn`` alone with a hand-made routing that sends
+    exactly ``rows`` of 1024 slots to the 2 held experts (chunks of 256:
+    none, part of the first, the first whole, one row into the second,
+    into the third, all four): the held experts' weighted outputs,
+    summed per token, and their gradients, against a dense count. The
+    rows of a chunk are gathered in four blocks of 64, and as one block
+    (2048, the chip's, does not divide 256)."""
+    monkeypatch.setattr(grouped_moe, "_HELD_BLOCK", block)
+    cfg, lp, hf, idx, w = _share_layer(rows)
 
     def program(hf, w, lp):
         return grouped_moe._held_experts_ffn(hf, lp, cfg, w, idx)
@@ -266,7 +279,7 @@ def test_the_chunks_of_the_share_cover_exactly_the_held_rows(rows):
         return out
 
     assert int(jnp.sum((idx == 4) | (idx == 5))) == rows
-    cot = jax.random.normal(ks[0], (256, cfg.d_model))
+    cot = jax.random.normal(jax.random.PRNGKey(rows + 1), hf.shape)
     got, vjp = jax.vjp(program, hf, w, lp)
     ref, ref_vjp = jax.vjp(dense, hf, w, lp)
     assert _err(got, ref) < TOL or (rows == 0 and not np.any(got))
@@ -277,6 +290,40 @@ def test_the_chunks_of_the_share_cover_exactly_the_held_rows(rows):
             assert _err(g, r) < TOL, name
         else:
             assert not np.any(np.asarray(g)), name
+
+
+def _row_movements(jaxpr, inside_while=False):
+    """(primitive, rows moved, inside a ``while``) of every gather and
+    scatter-add of whole rows in ``jaxpr`` and the jaxprs it holds."""
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        if name in ("gather", "scatter-add"):
+            moved = (eqn.outvars[0] if name == "gather"
+                     else eqn.invars[2]).aval
+            if moved.ndim == 2:
+                yield name, moved.shape[0], inside_while
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _row_movements(sub, inside_while or name == "while")
+
+
+def test_the_shares_gathers_are_no_chunk_long_and_its_sums_are(monkeypatch):
+    """A count: in the jaxpr of the share's gradient every gather of
+    rows runs inside a ``while`` over blocks of 64 rows (two in the
+    first chunk; three in the later chunks' ``cond``, whose checkpoint
+    gathers the rows again), none over a chunk of 256; the scatter-adds
+    (two and two: the checkpoint has no use for a second sum) take a
+    chunk each, outside any loop: in blocks they cost three times as
+    much a row on the chip (``grouped_moe._sum_held``)."""
+    monkeypatch.setattr(grouped_moe, "_HELD_BLOCK", 64)
+    cfg, lp, hf, idx, w = _share_layer(700)
+
+    def loss(hf, w, lp):
+        return jnp.sum(grouped_moe._held_experts_ffn(hf, lp, cfg, w, idx))
+
+    moves = sorted(_row_movements(
+        jax.make_jaxpr(jax.grad(loss, (0, 1, 2)))(hf, w, lp).jaxpr))
+    assert moves == [("gather", 64, True)] * 5 \
+        + [("scatter-add", 256, False)] * 4, moves
 
 
 def test_a_share_needs_the_grouped_dispatch():

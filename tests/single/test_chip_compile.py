@@ -296,6 +296,32 @@ def test_routed_rows_move_through_bare_gathers_on_the_v5e(for_tpu):
     assert not re.search(r"= bf16\[65536,2048\]\S* select\(", text)
 
 
+def test_a_shares_gather_buffer_is_born_in_a_branch_and_never_zero_filled(
+        for_tpu):
+    """The share's block-wise gather at the cells' sizes (a chunk of
+    32,768 rows out of 16,384 tokens) as the chip's compiler emits it
+    (PR 35): one ``while`` whose body gathers a block of
+    ``bf16[2048,2048]``, inside a ``conditional``; the chunk's buffer is
+    an ``AllocateBuffer`` in that branch's computation, not in the
+    entry computation (allocated there, a step's 24 buffers all live
+    from the program's start: 9.47 GB where 7.52 compile), and no
+    zero-fill of a chunk stands in the branch that holds rows."""
+    from horovod_tpu.ops import grouped_moe
+
+    text = for_tpu(grouped_moe._gather_held, ((16384, 2048), BF16),
+                   ((32768,), I32), ((), I32))
+    entry = text[text.index("ENTRY "):]
+    assert len(re.findall(r" while\(", text)) == 1
+    assert len(re.findall(r" conditional\(", entry)) == 1
+    assert re.search(r"= bf16\[2048,2048\]\S* fusion\(.*kind=kCustom", text)
+    assert not re.search(r"= bf16\[32768,2048\]\S* fusion\(.*kind=kCustom",
+                         text)
+    born = re.findall(r"= bf16\[32768,2048\]\S* custom-call\(\), "
+                      r'custom_call_target="AllocateBuffer"', text)
+    assert len(born) == 1 and "AllocateBuffer" not in entry
+    assert len(re.findall(r"= bf16\[32768,2048\]\S* broadcast\(", text)) == 1
+
+
 def test_grad_program_of_a_one_layer_llama_holds_one_flash_bwd_call(
         v5e_chip, monkeypatch):
     """``jit_hvd_grad`` as the split step lowers it for the described

@@ -239,6 +239,73 @@ def test_combine_accumulates_the_k_rows_in_float32():
     assert float(y[0, 0]) == 1.015625
 
 
+# The share's row movement (``_dispatch_held`` / ``_combine_held``): a
+# chunk of R = 64 sorted slots, gathered in blocks of B = 16. ``n`` held
+# rows in all, the chunk begins at sorted slot ``start``: it holds
+# ``clip(n - start, 0, R)`` of them.
+_B, _R = 16, 64
+HELD_CASES = [(0, 0), (1, 0), (_B - 1, 0), (_B, 0), (_B + 1, 0),
+              (_R - 1, 0), (_R, 0),
+              # a later chunk: rows stop before it, inside it, beyond it
+              (_R - 1, _R), (_R + _B + 1, _R), (3 * _R, _R)]
+
+
+@pytest.mark.parametrize("n,start", HELD_CASES)
+def test_the_shares_rows_move_as_the_one_shot_forms_move_them(
+        n, start, monkeypatch):
+    """The gathers block by block and the sums against the one-shot
+    forms kept here (the bare gather of R rows; a scatter-add of R
+    masked rows), values and both VJPs. Every row of the inputs from the
+    chunk's last held row on is NaN: one that reaches a sum fails the
+    comparison."""
+    monkeypatch.setattr(grouped_moe, "_HELD_BLOCK", _B)
+    S, D = 24, 8
+    ks = jax.random.split(jax.random.PRNGKey(n + start), 3)
+    held = int(np.clip(n - start, 0, _R))
+    valid = (jnp.arange(_R) < held)[:, None]
+    tok = jax.random.randint(ks[0], (_R,), 0, S)
+    h = jax.random.normal(ks[1], (S, D))
+    z = jnp.where(valid, jax.random.normal(ks[2], (_R, D)), jnp.nan)
+
+    def one_shot_sum(z):
+        return jnp.zeros((S, D)).at[tok].add(jnp.where(valid, z, 0))
+
+    @jax.jit
+    def blockwise(h, z, held):
+        x, pull_x = jax.vjp(
+            lambda h: grouped_moe._dispatch_held(h, tok, held), h)
+        y, pull_y = jax.vjp(
+            lambda z: grouped_moe._combine_held(z, tok, held, S), z)
+        return x, pull_x(z)[0], y, pull_y(h)[0]
+
+    x, dh, y, dz = blockwise(h, z, jnp.int32(held))
+    # rows from ``held`` on are nobody's: no group covers them
+    np.testing.assert_array_equal(x[:held], grouped_moe._rows(h, tok)[:held])
+    np.testing.assert_array_equal(dz[:held], grouped_moe._rows(h, tok)[:held])
+    np.testing.assert_allclose(dh, one_shot_sum(z), rtol=1e-6, atol=1e-6)
+    np.testing.assert_allclose(y, one_shot_sum(z), rtol=1e-6, atol=1e-6)
+    assert held > 0 or not (np.any(dh) or np.any(y))
+
+
+@pytest.mark.parametrize("n,start,blocks", [
+    (0, 0, 0), (1, 0, 1), (2048, 0, 1), (2049, 0, 2), (16384, 0, 8),
+    (16385, 0, 9), (32768, 0, 16), (40000, 0, 16),        # beyond it
+    (32768, 32768, 0), (32769, 32768, 1), (131072, 65536, 16)])
+def test_held_blocks_is_the_trip_count_of_the_gathers(n, start, blocks):
+    """``ceil(clip(n - start, 0, R) / B)`` at the edges, at the cells'
+    R = 32,768 and B = 2,048: none, a row, exactly a block, a full
+    chunk, beyond it, and the same in a later chunk."""
+    got = grouped_moe.held_blocks(jnp.int32(n), start, 32768, 2048)
+    assert got.dtype == jnp.int32 and int(got) == blocks
+    assert blocks == -(-min(max(n - start, 0), 32768) // 2048)
+
+
+def test_a_chunk_the_block_does_not_divide_is_gathered_as_one_block():
+    assert grouped_moe._HELD_BLOCK == 2048 and 32768 % 2048 == 0
+    assert grouped_moe._block_rows(32768) == 2048
+    assert grouped_moe._block_rows(3000) == 3000
+
+
 def test_rows_lowers_to_a_bare_gather():
     """No select over the gathered rows: ``jnp.take``'s default
     ``mode="fill"`` masks the result against NaN. (The whole row
