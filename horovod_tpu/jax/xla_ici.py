@@ -35,7 +35,7 @@ from horovod_tpu.common import eager_ops, process_sets
 from horovod_tpu.common.basics import HorovodBasics
 from horovod_tpu.common.eager_ops import _DTYPE_TO_ENUM, ReduceOp
 from horovod_tpu.common.exceptions import HorovodInternalError
-from horovod_tpu.utils.spans import span
+from horovod_tpu.utils.spans import register_program, scope, span
 
 _basics = HorovodBasics()
 
@@ -369,7 +369,8 @@ class XlaIciDataPlane:
             sig = (op_class, members, np_dtype.str, tuple(shapes), reduce_op,
                    scales, donate)
             fn = self._exec_cache.get(sig)
-            if fn is None:
+            new = fn is None
+            if new:
                 if group == 1:
                     fn = _build_allreduce_local(reduce_op, scales, donate)
                 else:
@@ -377,6 +378,8 @@ class XlaIciDataPlane:
                                           scales, donate)
                 self._exec_cache[sig] = fn
             if group == 1:
+                if new:
+                    register_program(fn, *arrs)
                 # Single-member set: the reduction is identity × scales,
                 # so the program is just the scales and, with donation,
                 # the outputs alias the inputs outright (zero HBM
@@ -398,6 +401,8 @@ class XlaIciDataPlane:
                 gins.append(self._global_rows(mesh, group, arrs[i]))
                 arrs[i] = None
             del arrs
+            if new:   # the program files itself (spans.scope_tables)
+                register_program(fn, *gins)
             outs = [g.addressable_data(0).reshape(shape)
                     if not shape else g.addressable_data(0)
                     for g, shape in zip(fn(*gins), shapes)]
@@ -549,6 +554,7 @@ def _build_allreduce_local(reduce_op, scales, donate):
     is just the pre/post scales — and with donation, pure buffer
     aliasing. Original shapes in, original shapes out."""
 
+    @scope("hvd.allreduce")
     def hvd_allreduce(*xs):
         outs = []
         for x, (pre, post) in zip(xs, scales):
@@ -577,6 +583,7 @@ def _build_allreduce(mesh, group, shapes, reduce_op, scales, donate=False):
     frontend promised the inputs are dead, see
     ``enqueue_device(donate=...)``)."""
 
+    @scope("hvd.allreduce")
     def hvd_allreduce(*blocks):
         parts = tuple(
             b * np.asarray(pre, b.dtype) if pre != 1.0 else b

@@ -32,6 +32,7 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 from horovod_tpu.parallel.ring_attention import ring_self_attention
+from horovod_tpu.utils.spans import scope
 
 # Residual names of ops/grouped_moe.py and ``moe_route``: what
 # "attn+moe" saves beyond "attn", and what "moe" saves beyond that. The
@@ -504,11 +505,13 @@ def llama_partition_rules(pipeline=False):
     ]
 
 
+@scope("hvd.norm")
 def _rmsnorm(x, scale, eps):
     var = jnp.mean(jnp.square(x.astype(jnp.float32)), -1, keepdims=True)
     return (x * jax.lax.rsqrt(var + eps)).astype(x.dtype) * scale
 
 
+@scope("hvd.attn.rope")
 def _rope(x, positions, theta):
     """Rotary embedding; positions are GLOBAL indices [B, T] so sequence
     sharding stays correct."""
@@ -521,6 +524,7 @@ def _rope(x, positions, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
 
 
+@scope("hvd.attn.core")
 def _attention(q, k, v, mesh, seq_axis, seq_parallel="ring",
                flash_block=0, window=0):
     # remat="attn" naming: the SP paths name their OUTPUT ("attn_out");
@@ -587,6 +591,7 @@ def _head_proj(h, w, gain, c):
     return y
 
 
+@scope("hvd.attn.proj")
 def _project_qkv(h, lp, c):
     """Normalized ``h`` -> (q, k, v) in heads, BEFORE RoPE."""
     return (_head_proj(h, lp["wq"], lp["q_norm"] if c.qk_norm else None, c),
@@ -594,6 +599,7 @@ def _project_qkv(h, lp, c):
             _head_proj(h, lp["wv"], None, c))
 
 
+@scope("hvd.conv.chain")
 def gated_short_conv(proj, w):
     """The chain between a conv layer's two projections: ``proj``
     [B, T, 3D] is ``[B, C, z]`` side by side, ``w`` [taps, D] one weight
@@ -601,20 +607,19 @@ def gated_short_conv(proj, w):
     (B * z)_{t - (taps-1) + j}``, zero before position 0 (depthwise and
     causal). Shifts, not ``lax.conv``: three multiply-adds a channel
     that the compiler fuses into one elementwise pass; in ``proj``'s
-    dtype, the taps summed in float32. Traced under the scope
-    ``hvd_short_conv`` (chipbench's ``short_conv_ms_per_step``)."""
-    with jax.named_scope("hvd_short_conv"):
-        gate_in, gate_out, z = jnp.split(proj, 3, axis=-1)
-        u = gate_in * z
-        w = w.astype(jnp.float32)
-        taps, t = w.shape[0], u.shape[1]
-        conv = u.astype(jnp.float32) * w[taps - 1]
-        for back in range(1, taps):      # u as it was ``back`` tokens ago
-            past = jnp.pad(u, ((0, 0), (back, 0), (0, 0)))[:, :t]
-            conv = conv + past.astype(jnp.float32) * w[taps - 1 - back]
-        return gate_out * conv.astype(proj.dtype)
+    dtype, the taps summed in float32."""
+    gate_in, gate_out, z = jnp.split(proj, 3, axis=-1)
+    u = gate_in * z
+    w = w.astype(jnp.float32)
+    taps, t = w.shape[0], u.shape[1]
+    conv = u.astype(jnp.float32) * w[taps - 1]
+    for back in range(1, taps):      # u as it was ``back`` tokens ago
+        past = jnp.pad(u, ((0, 0), (back, 0), (0, 0)))[:, :t]
+        conv = conv + past.astype(jnp.float32) * w[taps - 1 - back]
+    return gate_out * conv.astype(proj.dtype)
 
 
+@scope("hvd.conv.proj")
 def _short_conv(h, lp, c):
     """lfm2's gated short convolution, the token mixer of a ``conv``
     layer, on normalized ``h`` [B, T, D]: ``W_out (C * conv(B * z))``
@@ -660,6 +665,7 @@ def _top_k_bwd(k, res, g):
 _top_k.defvjp(_top_k_fwd, _top_k_bwd)
 
 
+@scope("hvd.moe.route")
 def moe_route(h, router_w, n_experts_per_token, norm_topk_prob=True,
               score_func="softmax", bias=None, route_scale=1.0):
     """The ONE router: f32 logits matmul, softmax, top-K, the K
@@ -714,6 +720,7 @@ def route_layer(h, lp, c):
                      lp.get("expert_bias"), c.route_scale)
 
 
+@scope("hvd.moe.route")
 def moe_balance_loss(balance):
     """The load-balancing aux term from :func:`moe_route`'s statistics,
     pooled over every leading axis of ``balance [..., 2, E]``:
@@ -758,20 +765,21 @@ def _moe_ffn(h, lp, c, mesh):
     # buffer, filling slot 0 for every token before slot 1 (priority to
     # the top-1 expert, as in GShard).
     dt = c.compute_dtype
-    dispatch = jnp.zeros((B, T, E, C), dt)
-    combine = jnp.zeros((B, T, E, C), dt)
-    counts = jnp.zeros((B, E), jnp.int32)
-    for slot in range(K):
-        oh = jax.nn.one_hot(gate_idx[..., slot], E,
-                            dtype=jnp.int32)                    # [B,T,E]
-        pos = jnp.cumsum(oh, axis=1) - 1 + counts[:, None, :]   # [B,T,E]
-        keep = (pos < C) & (oh > 0)
-        pos_oh = jax.nn.one_hot(pos, C, dtype=dt) \
-            * keep[..., None].astype(dt)                        # [B,T,E,C]
-        dispatch = dispatch + pos_oh
-        combine = combine + pos_oh * gate_vals[..., slot].astype(
-            dt)[..., None, None]
-        counts = counts + oh.sum(1)
+    with scope("hvd.moe.dispatch"):
+        dispatch = jnp.zeros((B, T, E, C), dt)
+        combine = jnp.zeros((B, T, E, C), dt)
+        counts = jnp.zeros((B, E), jnp.int32)
+        for slot in range(K):
+            oh = jax.nn.one_hot(gate_idx[..., slot], E,
+                                dtype=jnp.int32)                # [B,T,E]
+            pos = jnp.cumsum(oh, axis=1) - 1 + counts[:, None, :]
+            keep = (pos < C) & (oh > 0)
+            pos_oh = jax.nn.one_hot(pos, C, dtype=dt) \
+                * keep[..., None].astype(dt)                    # [B,T,E,C]
+            dispatch = dispatch + pos_oh
+            combine = combine + pos_oh * gate_vals[..., slot].astype(
+                dt)[..., None, None]
+            counts = counts + oh.sum(1)
 
     def constrain_e(z):
         if mesh is None:
@@ -787,14 +795,17 @@ def _moe_ffn(h, lp, c, mesh):
     dispatch = checkpoint_name(dispatch, "moe_dispatch")
     combine = checkpoint_name(combine, "moe_combine")
 
-    xe = constrain_e(jnp.einsum("btec,btd->becd", dispatch,
-                                h.astype(dt)))                # [B,E,C,D]
-    gate = jax.nn.silu(jnp.einsum("becd,edf->becf", xe,
-                                  lp["moe_gate"].astype(dt)))
-    up = jnp.einsum("becd,edf->becf", xe, lp["moe_up"].astype(dt))
-    ye = constrain_e(jnp.einsum("becf,efd->becd", gate * up,
-                                lp["moe_down"].astype(dt)))
-    y = jnp.einsum("btec,becd->btd", combine, ye)             # [B,T,D]
+    with scope("hvd.moe.dispatch"):
+        xe = constrain_e(jnp.einsum("btec,btd->becd", dispatch,
+                                    h.astype(dt)))            # [B,E,C,D]
+    with scope("hvd.moe.experts"):
+        gate = jax.nn.silu(jnp.einsum("becd,edf->becf", xe,
+                                      lp["moe_gate"].astype(dt)))
+        up = jnp.einsum("becd,edf->becf", xe, lp["moe_up"].astype(dt))
+        ye = constrain_e(jnp.einsum("becf,efd->becd", gate * up,
+                                    lp["moe_down"].astype(dt)))
+    with scope("hvd.moe.combine"):
+        y = jnp.einsum("btec,becd->btd", combine, ye)         # [B,T,D]
     return y, aux
 
 
@@ -805,6 +816,7 @@ def _grouped_dispatch(c, mesh):
         c.moe_impl == "grouped" or (c.moe_impl == "auto" and mesh is None))
 
 
+@scope("hvd.ffn")
 def _swiglu(h, gate, up, down, dt):
     return (jax.nn.silu(h @ gate.astype(dt)) * (h @ up.astype(dt))) \
         @ down.astype(dt)
@@ -839,10 +851,11 @@ def _ffn(h, lp, c, mesh=None):
     # The PRE-silu value is what must be saved — silu's own vjp needs
     # its primal input, so saving post-silu would still re-run the
     # matmul to regenerate it.
-    gate_pre = checkpoint_name(h @ lp["w_gate"].astype(dt), "ffn_gate")
-    up = checkpoint_name(h @ lp["w_up"].astype(dt), "ffn_up")
-    return ((jax.nn.silu(gate_pre) * up) @ lp["w_down"].astype(dt),
-            jnp.zeros((2, 0), jnp.float32))
+    with scope("hvd.ffn"):
+        gate_pre = checkpoint_name(h @ lp["w_gate"].astype(dt), "ffn_gate")
+        up = checkpoint_name(h @ lp["w_up"].astype(dt), "ffn_up")
+        y = (jax.nn.silu(gate_pre) * up) @ lp["w_down"].astype(dt)
+    return y, jnp.zeros((2, 0), jnp.float32)
 
 
 def llama_forward(params, tokens, config, mesh=None, seq_axis="seq",
@@ -901,15 +914,17 @@ def llama_forward(params, tokens, config, mesh=None, seq_axis="seq",
     x = _rmsnorm(x, params["final_norm"].astype(dt), c.norm_eps)
     # bf16 operands, f32 accumulation: full MXU rate without giving up
     # the f32 logits downstream softmax stability needs.
-    if c.tie_embeddings:
-        # The embedding matrix [vocab, D] contracted over D where it
-        # lies: no transposed copy; its gradient is the sum of this use
-        # and the lookup's.
-        logits = jnp.einsum("btd,vd->btv", x, params["embed"].astype(dt),
-                            preferred_element_type=jnp.float32)
-    else:
-        logits = jnp.matmul(x, params["lm_head"].astype(dt),
-                            preferred_element_type=jnp.float32)
+    with scope("hvd.head"):
+        if c.tie_embeddings:
+            # The embedding matrix [vocab, D] contracted over D where it
+            # lies: no transposed copy; its gradient is the sum of this
+            # use and the lookup's.
+            logits = jnp.einsum("btd,vd->btv", x,
+                                params["embed"].astype(dt),
+                                preferred_element_type=jnp.float32)
+        else:
+            logits = jnp.matmul(x, params["lm_head"].astype(dt),
+                                preferred_element_type=jnp.float32)
     if return_aux:
         return logits, aux
     return logits
@@ -926,6 +941,7 @@ def llama_expert_load(params, tokens, config):
     return balance[:, 0] * tokens.size
 
 
+@scope("hvd.embed")
 def _embed(params, tokens, c):
     x = params["embed"].astype(c.compute_dtype)[tokens]
     if c.scale_embed:
@@ -1084,10 +1100,13 @@ def _build_layer_body(c, mesh, seq_axis, constrain_acts=True, kind=None):
         vv = checkpoint_name(vv, "attn_v")
         # remat="attn" save-names applied inside _attention (per path).
         attn = _attention(q, kk, vv, mesh, seq_axis, c.seq_parallel,
-                          c.flash_block, window).reshape(bb, tt, -1)
-        if c.attn_gate:
-            attn = attn * jax.nn.sigmoid(h @ lp["wg"].astype(dt))
-        return ffn(x, attn @ lp["wo"].astype(dt), lp)
+                          c.flash_block, window)
+        with scope("hvd.attn.proj"):
+            attn = attn.reshape(bb, tt, -1)
+            if c.attn_gate:
+                attn = attn * jax.nn.sigmoid(h @ lp["wg"].astype(dt))
+            attn = attn @ lp["wo"].astype(dt)
+        return ffn(x, attn, lp)
 
     def ffn(x, mixed, lp):
         """The mixer's output joins the stream; then the FFN's."""
@@ -1221,16 +1240,18 @@ def llama_loss(params, batch, config, mesh=None, seq_axis="seq"):
                                 seq_axis, return_aux=True)
     nll = _token_nll(logits, batch["targets"])
     mask = batch.get("mask")
-    if mask is None:
-        loss = jnp.mean(nll)
-    else:
-        mask = mask.astype(jnp.float32)
-        loss = jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
+    with scope("hvd.loss"):
+        if mask is None:
+            loss = jnp.mean(nll)
+        else:
+            mask = mask.astype(jnp.float32)
+            loss = jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
     if config.n_experts > 0 and config.moe_aux_weight:
         loss = loss + config.moe_aux_weight * aux
     return loss
 
 
+@scope("hvd.loss")
 def _token_nll(logits, targets):
     """Per-token negative log-likelihood in logsumexp form: no second
     [B,T,vocab] f32 array for log_softmax — at bench shapes that array

@@ -21,6 +21,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from horovod_tpu.utils.spans import scope
+
 # depths per stage for each family member
 _DEPTHS = {
     18: ((2, 2, 2, 2), False),
@@ -140,53 +142,64 @@ def resnet_forward(params, state, x, config, train=True):
     bn = partial(_batch_norm, train=train, momentum=c.bn_momentum,
                  eps=c.bn_eps)
     new_state = {"stem": {}}
-    h = _conv(x.astype(dt), params["stem"]["conv"], stride=2, dtype=dt)
-    h, new_state["stem"]["bn"] = bn(h, params["stem"]["bn"],
-                                    state["stem"]["bn"])
-    h = jax.nn.relu(h)
-    h = lax.reduce_window(h, -jnp.inf, lax.max, (1, 3, 3, 1), (1, 2, 2, 1),
-                          "SAME")
+    with scope("hvd.cnn.stem"):
+        h = _conv(x.astype(dt), params["stem"]["conv"], stride=2, dtype=dt)
+        h, new_state["stem"]["bn"] = bn(h, params["stem"]["bn"],
+                                        state["stem"]["bn"])
+        h = jax.nn.relu(h)
+        h = lax.reduce_window(h, -jnp.inf, lax.max, (1, 3, 3, 1),
+                              (1, 2, 2, 1), "SAME")
     for s in range(len(c.stage_depths)):
         stage_state = []
         for b, bp in enumerate(params[f"stage{s}"]):
-            bs = state[f"stage{s}"][b]
-            nbs = {}
-            stride = 2 if (s > 0 and b == 0) else 1
-            shortcut = h
-            if "proj" in bp:
-                shortcut = _conv(h, bp["proj"], stride=stride, dtype=dt)
-                shortcut, nbs["proj_bn"] = bn(shortcut, bp["proj_bn"],
-                                              bs["proj_bn"])
-            if c.bottleneck:
-                y = _conv(h, bp["conv1"], dtype=dt)
-                y, nbs["bn1"] = bn(y, bp["bn1"], bs["bn1"])
-                y = jax.nn.relu(y)
-                y = _conv(y, bp["conv2"], stride=stride, dtype=dt)  # v1.5
-                y, nbs["bn2"] = bn(y, bp["bn2"], bs["bn2"])
-                y = jax.nn.relu(y)
-                y = _conv(y, bp["conv3"], dtype=dt)
-                y, nbs["bn3"] = bn(y, bp["bn3"], bs["bn3"])
-            else:
-                y = _conv(h, bp["conv1"], stride=stride, dtype=dt)
-                y, nbs["bn1"] = bn(y, bp["bn1"], bs["bn1"])
-                y = jax.nn.relu(y)
-                y = _conv(y, bp["conv2"], dtype=dt)
-                y, nbs["bn2"] = bn(y, bp["bn2"], bs["bn2"])
-            h = jax.nn.relu(y + shortcut)
+            h, nbs = _block(h, bp, state[f"stage{s}"][b], c, bn, dt,
+                            stride=2 if (s > 0 and b == 0) else 1,
+                            stage=s + 1)
             stage_state.append(nbs)
         new_state[f"stage{s}"] = stage_state
-    pooled = h.astype(jnp.float32).mean(axis=(1, 2))
-    logits = pooled @ params["head"]["w"] + params["head"]["b"]
+    with scope("hvd.cnn.head"):
+        pooled = h.astype(jnp.float32).mean(axis=(1, 2))
+        logits = pooled @ params["head"]["w"] + params["head"]["b"]
     return logits, new_state
+
+
+def _block(h, bp, bs, c, bn, dt, stride, stage):
+    """One residual block of stage ``stage`` (1-based; a fifth stage and
+    beyond would read as the fourth) -> (h, the block's new batch-norm
+    statistics)."""
+    nbs = {}
+    with scope(f"hvd.cnn.stage{stage}"):
+        shortcut = h
+        if "proj" in bp:
+            shortcut = _conv(h, bp["proj"], stride=stride, dtype=dt)
+            shortcut, nbs["proj_bn"] = bn(shortcut, bp["proj_bn"],
+                                          bs["proj_bn"])
+        if c.bottleneck:
+            y = _conv(h, bp["conv1"], dtype=dt)
+            y, nbs["bn1"] = bn(y, bp["bn1"], bs["bn1"])
+            y = jax.nn.relu(y)
+            y = _conv(y, bp["conv2"], stride=stride, dtype=dt)  # v1.5
+            y, nbs["bn2"] = bn(y, bp["bn2"], bs["bn2"])
+            y = jax.nn.relu(y)
+            y = _conv(y, bp["conv3"], dtype=dt)
+            y, nbs["bn3"] = bn(y, bp["bn3"], bs["bn3"])
+        else:
+            y = _conv(h, bp["conv1"], stride=stride, dtype=dt)
+            y, nbs["bn1"] = bn(y, bp["bn1"], bs["bn1"])
+            y = jax.nn.relu(y)
+            y = _conv(y, bp["conv2"], dtype=dt)
+            y, nbs["bn2"] = bn(y, bp["bn2"], bs["bn2"])
+        return jax.nn.relu(y + shortcut), nbs
 
 
 def resnet_loss(params, state, batch, config, train=True):
     """Softmax CE; batch = {"images": [N,H,W,3], "labels": [N]}."""
     logits, new_state = resnet_forward(params, state, batch["images"],
                                        config, train=train)
-    logp = jax.nn.log_softmax(logits)
-    nll = -jnp.take_along_axis(logp, batch["labels"][:, None], axis=-1)
-    return nll.mean(), new_state
+    with scope("hvd.cnn.head"):
+        logp = jax.nn.log_softmax(logits)
+        nll = -jnp.take_along_axis(logp, batch["labels"][:, None], axis=-1)
+        return nll.mean(), new_state
 
 
 def resnet_partition_rules():

@@ -65,6 +65,7 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
 from horovod_tpu.ops._platform import use_pallas
+from horovod_tpu.utils.spans import scope
 
 # Megablox tile sizes (m, k, n), clamped to the problem dims. Swept on
 # a v5e chip at bench shape (m=16K, D=2048, F=4096): large k/n tiles
@@ -101,6 +102,7 @@ def _rows(x, idx):
 # inverse permutation is sorted once, in the forward.
 
 @jax.custom_vjp
+@scope("hvd.moe.dispatch")
 def _dispatch(h, tok, inv):
     """Rows of ``h`` [S, D] replicated K ways into expert order
     [S*K, D] in ONE gather."""
@@ -119,6 +121,7 @@ _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
 @jax.custom_vjp
+@scope("hvd.moe.combine")
 def _combine(z, tok, inv):
     """The K expert-order rows of each token summed back into token
     order [S, D]: the rows are read where they lie (a permutation
@@ -241,6 +244,7 @@ def _gmm_tpu_bwd(layer, res, grad):
 _gmm_tpu.defvjp(_gmm_tpu_fwd, _gmm_tpu_bwd)
 
 
+@scope("hvd.moe.experts")
 def _grouped_mm(lhs, rhs, group_sizes):
     """Ragged grouped matmul: rows of ``lhs`` [M, K] are grouped
     contiguously per ``group_sizes`` [E]; ``rhs`` [E, K, N], or a
@@ -262,6 +266,7 @@ def _grouped_mm(lhs, rhs, group_sizes):
     return jnp.einsum("se,sk,ekn->sn", sel, lhs, rhs)
 
 
+@scope("hvd.moe.dispatch")
 def _sort_slots_impl(e_flat, w):
     iota = lax.iota(jnp.int32, e_flat.shape[0])
     _, order, w_sorted = lax.sort((e_flat, iota, w), num_keys=1,
@@ -291,6 +296,7 @@ def _sort_slots_fwd(e_flat, w):
     return (perm, checkpoint_name(w_sorted, "moe_w_sorted")), perm[1]
 
 
+@scope("hvd.moe.dispatch")
 def _sort_slots_bwd(inv, g):
     return None, _rows(g[1], inv)
 
@@ -298,6 +304,7 @@ def _sort_slots_bwd(inv, g):
 _sort_slots.defvjp(_sort_slots_fwd, _sort_slots_bwd)
 
 
+@scope("hvd.moe.dispatch")
 def _group_sizes(e_flat, n_experts):
     """Slots per expert [E] int32, empty experts included: a fused
     compare-and-reduce over [S*K, E], exact in int32 (``bincount`` is a
@@ -340,6 +347,7 @@ def _block_rows(chunk_rows):
     return _HELD_BLOCK if chunk_rows % _HELD_BLOCK == 0 else chunk_rows
 
 
+@scope("hvd.moe.dispatch")
 def _gather_held(x, tok, held):
     """``x[tok]`` [R, D] for the first ``held`` of ``tok``'s R rows,
     gathered block by block into place. Rows past the last visited block
@@ -366,6 +374,7 @@ def _gather_held(x, tok, held):
         lambda: jnp.zeros(shape, x.dtype))
 
 
+@scope("hvd.moe.combine")
 def _sum_held(rows, tok, held, n_tokens):
     """``out[t] = sum of rows[i] where tok[i] == t and i < held``,
     [n_tokens, D] in ``rows``' dtype, accumulated in float32 (a
@@ -425,6 +434,7 @@ def _combine_held_bwd(n_tokens, res, g):
 _combine_held.defvjp(_combine_held_fwd, _combine_held_bwd)
 
 
+@scope("hvd.moe.dispatch")
 def _held_experts_ffn(hf, lp, c, gate_vals, gate_idx):
     """The routed part of an expert layer that holds experts
     ``c.first_expert .. + c.n_experts_held - 1`` of ``c.n_experts``:
@@ -475,9 +485,9 @@ def _held_experts_ffn(hf, lp, c, gate_vals, gate_idx):
         up = checkpoint_name(
             _grouped_mm(x_sorted, lp["moe_up"].astype(dt), sizes),
             "moe_up_act")
-        y_sorted = _grouped_mm(
-            jax.nn.silu(gate_pre) * up * w[:, None],
-            lp["moe_down"].astype(dt), sizes)
+        with scope("hvd.moe.experts"):
+            act = jax.nn.silu(gate_pre) * up * w[:, None]
+        y_sorted = _grouped_mm(act, lp["moe_down"].astype(dt), sizes)
         return _combine_held(y_sorted, tok, held, S)
 
     y = chunk(0)
@@ -492,7 +502,9 @@ def _held_experts_ffn(hf, lp, c, gate_vals, gate_idx):
         return lax.cond(start < n, lambda y: y + later(start),
                         lambda y: y, y), None
 
-    return lax.scan(rest, y, R * jnp.arange(1, chunks, dtype=jnp.int32))[0]
+    with scope("hvd.moe.combine"):   # the later chunks' sums join y
+        return lax.scan(rest, y,
+                        R * jnp.arange(1, chunks, dtype=jnp.int32))[0]
 
 
 def grouped_moe_ffn(h, lp, c):
@@ -526,11 +538,12 @@ def grouped_moe_ffn(h, lp, c):
     # order (the gate weights ride it) and its inverse. Indices are
     # data (not differentiated); stop_gradient keeps the int chain out
     # of the autodiff graph entirely.
-    e_flat = lax.stop_gradient(gate_idx.reshape(S * K))
-    (order, inv), w_sorted = _sort_slots(
-        e_flat, gate_vals.astype(dt).reshape(S * K))
-    tok, inv = order // K, inv.reshape(S, K)
-    group_sizes = _group_sizes(e_flat, E)
+    with scope("hvd.moe.dispatch"):
+        e_flat = lax.stop_gradient(gate_idx.reshape(S * K))
+        (order, inv), w_sorted = _sort_slots(
+            e_flat, gate_vals.astype(dt).reshape(S * K))
+        tok, inv = order // K, inv.reshape(S, K)
+        group_sizes = _group_sizes(e_flat, E)
 
     # Not named for any remat mode: gathering the rows again in the
     # backward (from h, a source XLA holds in VMEM) is cheaper than
@@ -555,8 +568,9 @@ def grouped_moe_ffn(h, lp, c):
     # out of the silu * up backward pass at width F: nothing is saved
     # in slot order, and the down-projection's output is no residual of
     # anything.
-    y_sorted = _grouped_mm(
-        jax.nn.silu(gate_pre) * up * w_sorted[:, None],
-        lp["moe_down"].astype(dt), group_sizes)    # [S*K, D]
+    with scope("hvd.moe.experts"):
+        act = jax.nn.silu(gate_pre) * up * w_sorted[:, None]
+    y_sorted = _grouped_mm(act, lp["moe_down"].astype(dt),
+                           group_sizes)            # [S*K, D]
     y = _combine(y_sorted, tok, inv)
     return y.reshape(B, T, D), aux

@@ -56,12 +56,13 @@ as a program-structure choice instead of an optimizer wrapper.
 """
 
 import functools
+import types
 from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
 
-from horovod_tpu.utils.spans import span
+from horovod_tpu.utils.spans import files_itself, scope, span
 
 
 class TrainStep(NamedTuple):
@@ -270,9 +271,11 @@ def make_split_train_step(loss_fn, optimizer, *, microbatches=1,
                                               jit_kwargs=jk)
     else:
         if fused:
+            @scope("hvd.apply")
             def hvd_apply(grads, params, opt):
                 return optimizer.apply(params, grads, opt)
         else:
+            @scope("hvd.apply")
             def hvd_apply(grads, params, opt):
                 import optax  # deferred: parallel/ imports without optax
 
@@ -283,16 +286,22 @@ def make_split_train_step(loss_fn, optimizer, *, microbatches=1,
 
     # The jitted functions are named for what they are: a device trace
     # shows jit_hvd_grad and jit_hvd_apply, and a reader finds them so.
+    # The step reaches them through ``run``, where each files itself at
+    # its first call (``spans.scope_tables``) and stands bare after it.
+    run = types.SimpleNamespace(apply=apply_fn)
+    if zero is None:   # the ZeRO apply is no one program
+        files_itself(run, "apply", apply_fn)
     if n == 1:
         def hvd_grad(p, d):
             return jax.value_and_grad(loss_fn)(p, d)
 
         grad_fn = jax.jit(hvd_grad, **jk)
+        files_itself(run, "grad", grad_fn)
 
         def step(carry, batch):
             params, opt = carry
-            loss, grads = grad_fn(params, batch)
-            params, opt = apply_fn(grads, params, opt)
+            loss, grads = run.grad(params, batch)
+            params, opt = run.apply(grads, params, opt)
             return loss, (params, opt)
     else:
         def scaled_loss(p, d):
@@ -322,14 +331,16 @@ def make_split_train_step(loss_fn, optimizer, *, microbatches=1,
 
         grad_first = jax.jit(hvd_grad, **jk)
         grad_acc = jax.jit(hvd_grad_acc, donate_argnums=(1, 2), **jk)
+        files_itself(run, "grad", grad_first)
+        files_itself(run, "grad_acc", grad_acc)
 
         def step(carry, batch):
             params, opt = carry
             mbs = _split_microbatches(batch, n)
-            loss, grads = grad_first(params, mbs[0])
+            loss, grads = run.grad(params, mbs[0])
             for mb in mbs[1:]:
-                loss, grads = grad_acc(params, loss, grads, mb)
-            params, opt = apply_fn(grads, params, opt)
+                loss, grads = run.grad_acc(params, loss, grads, mb)
+            params, opt = run.apply(grads, params, opt)
             return loss, (params, opt)
 
     step = _spanned(step)

@@ -7,9 +7,6 @@ JSON snapshot and derives the handful of aggregates the exporters and
 """
 
 from horovod_tpu.common.basics import HorovodBasics
-# The program's spans on the profiler's clock live in a leaf module so
-# that the layers below telemetry can write them; here for its readers.
-from horovod_tpu.utils.spans import SPANS, span  # noqa: F401
 
 _basics = HorovodBasics()
 

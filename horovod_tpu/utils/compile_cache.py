@@ -14,12 +14,32 @@ or a timestamp. Tests keep the cache off (the one that checks the ranks'
 entries switches it on in child processes, in a temporary directory).
 
 One more thing has to hold still for the cache to hit: a pallas kernel
-is serialized into its program together with the FULL Python traceback
-of the trace (jax's default for MLIR locations), so the same train step
+is serialized into its program together with the MLIR locations of the
+trace, by jax's default the Python traceback, so the same train step
 gets a different cache key from every call site — and from the same
 call site after any edit that moves a line in any frame above it
 (measured on the v5e, PR 21: three ~37 s compiles of one grad program in
-one smoke run). Locations are therefore cut to the frame of the op.
+one smoke run). Locations therefore carry NO frame at all
+(``jax_traceback_in_locations_limit=0``): what is left of a location is
+the operation's name stack, ``jit(hvd_grad)/transpose(jvp(...))/
+checkpoint/rematted_computation/hvd.ffn/dot_general``, which is the
+program's own structure and moves with no caller and no line. Until
+PR 36 the cut was ``jax_include_full_tracebacks_in_locations=False``
+(the op's own file and line stayed, so every checkout and every edit of
+a model file had keys of its own). In that mode jax 0.9.0 also writes
+the bare primitive as ``op_name`` and drops the name stack of everything
+``jax.checkpoint`` re-emits, the recomputed forward and the whole
+backward of a layer body, so no device scope
+(``utils/spans.py:scope``) reached two thirds of a grad program.
+
+The name stack is what ``spans.scope_table`` reads out of a compiled
+program's text, so the text the cache hands back has to be this
+program's: jax leaves metadata out of the cache key by default ("
+executables loaded from the cache may have stale metadata"), and an
+entry written before a scope was added or renamed would answer with the
+old names. ``jax_compilation_cache_include_metadata_in_key`` is
+therefore on; with no frame in any location the metadata in the key is
+the name stacks and nothing else.
 
 Who writes what, where (one directory, every process a writer):
 
@@ -187,7 +207,11 @@ def enable_compile_cache():
         jax.monitoring.register_event_listener(_on_event)
         jax.monitoring.register_event_duration_secs_listener(_on_duration)
         _let_every_rank_write()
-    jax.config.update("jax_include_full_tracebacks_in_locations", False)
+    # Name stacks whole, no frame behind them, and in the key: see the
+    # module's docstring.
+    jax.config.update("jax_include_full_tracebacks_in_locations", True)
+    jax.config.update("jax_traceback_in_locations_limit", 0)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if placed:
         return placed

@@ -232,27 +232,43 @@ def test_kernel_compiles_for_described_v5e(for_tpu, fn, shapes):
     assert "tpu_custom_call" in for_tpu(fn, *shapes)
 
 
+_LOCATION_FLAGS = ("jax_include_full_tracebacks_in_locations",
+                   "jax_traceback_in_locations_limit",
+                   "jax_compilation_cache_include_metadata_in_key")
+
+
 @pytest.mark.parametrize("locations", ["whole", "cut"])
 def test_flash_kernels_keep_their_names_in_the_compiled_program(
-        for_tpu, locations):
+        for_tpu, locations, monkeypatch, tmp_path):
     """What a device trace shows of a kernel is its instruction in the
-    compiled program. ``enable_compile_cache()`` cuts the locations and
-    the instruction is then ``%tpu_custom_call.N`` (as on the v5e,
-    PR 25); the kernel metadata tells the kernels apart whether
-    locations are whole or cut. The backward is ONE kernel (PR 28) whose
-    name keeps the ``hvd_flash_bwd`` the benchmark's reader matches."""
-    was = jax.config.jax_include_full_tracebacks_in_locations
-    jax.config.update("jax_include_full_tracebacks_in_locations",
-                      locations == "whole")
+    compiled program, and the instruction's name follows the locations
+    (``%tpu_custom_call.N`` until PR 36, the name stack's last piece
+    since); the kernel metadata tells the kernels apart whether
+    locations are whole (jax's default) or cut as
+    ``enable_compile_cache()`` cuts them. Cut, the program names no
+    caller: no file, no line, no frame, in the text or in the kernels
+    serialized into it. The backward is ONE kernel (PR 28) whose name
+    keeps the ``hvd_flash_bwd`` the benchmark's reader matches."""
+    from horovod_tpu.utils import compile_cache
+
+    was = {f: getattr(jax.config, f) for f in _LOCATION_FLAGS}
+    cache_dir = jax.config.jax_compilation_cache_dir
     try:
+        if locations == "cut":
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+            compile_cache.enable_compile_cache()
         # (a fresh function: jax's lowering cache does not key on it)
         text = for_tpu(lambda *a: _flash_fwd_bwd(*a), _Q, _KV, _KV)
     finally:
-        jax.config.update("jax_include_full_tracebacks_in_locations", was)
+        for f, v in was.items():
+            jax.config.update(f, v)
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
     names = re.findall(
         r"custom-call\([^\n]*kernel_metadata=\{\s*\"kernel\":\"(\w+)\"\s*\}",
         text)
     assert sorted(names) == ["hvd_flash_bwd_fused", "hvd_flash_fwd"]
+    names_a_caller = "test_chip_compile" in text or "stack_frame_id" in text
+    assert names_a_caller == (locations == "whole")
 
 
 def test_a_window_rides_in_the_kernel_metadata_and_nowhere_else(for_tpu):
@@ -423,7 +439,7 @@ def test_compile_cache_goes_where_the_environment_says(monkeypatch,
     from horovod_tpu.utils import compile_cache
 
     before = jax.config.jax_compilation_cache_dir
-    tracebacks = jax.config.jax_include_full_tracebacks_in_locations
+    flags = {f: getattr(jax.config, f) for f in _LOCATION_FLAGS}
     try:
         monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
         assert compile_cache.enable_compile_cache() == str(tmp_path)
@@ -435,12 +451,17 @@ def test_compile_cache_goes_where_the_environment_says(monkeypatch,
         assert compile_cache.enable_compile_cache() == fixed
         assert jax.config.jax_compilation_cache_dir == fixed
         assert compile_cache.enable_compile_cache() == fixed  # never moves
-        # kernel-bearing programs must not key on the caller's stack
-        assert not jax.config.jax_include_full_tracebacks_in_locations
+        # No program keys on the caller's stack: a location is the
+        # operation's name stack (which rides into the compiled text
+        # and into the key) and no frame at all.
+        text = jax.jit(jax.named_scope("a_scope")(lambda x: x * 2)).lower(
+            jnp.ones(3)).as_text(debug_info=True)
+        assert "a_scope/mul" in text and ".py" not in text
+        assert jax.config.jax_compilation_cache_include_metadata_in_key
     finally:
         jax.config.update("jax_compilation_cache_dir", before)
-        jax.config.update("jax_include_full_tracebacks_in_locations",
-                          tracebacks)
+        for f, v in flags.items():
+            jax.config.update(f, v)
 
 
 @pytest.mark.parametrize("table", ["bench-flops", "step-timer",

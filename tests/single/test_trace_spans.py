@@ -157,11 +157,53 @@ def test_writing_a_span_does_not_pull_in_the_telemetry_package(module):
     subprocess.run([sys.executable, "-c", code], check=True, timeout=120)
 
 
-def test_telemetry_core_offers_the_same_helper():
-    from horovod_tpu.telemetry import core
+@pytest.mark.parametrize("microbatches,programs", [(1, 2), (3, 3)])
+def test_a_step_makes_no_more_python_calls_for_filing_its_programs(
+        microbatches, programs):
+    """The jitted programs file themselves (``spans.files_itself``) at
+    their first call and stand bare after it: from the second step on
+    the host's path through ``step`` is the span, the dispatch and the
+    split of the batch, as before PR 36."""
+    import sys
+
+    import jax.numpy as jnp
+    import optax
+
+    from horovod_tpu.parallel import make_split_train_step
     from horovod_tpu.utils import spans
 
-    assert core.span is spans.span and core.SPANS is spans.SPANS
+    spans._PROGRAMS.clear()
+    ts = make_split_train_step(lambda p, d: jnp.sum((p * d) ** 2),
+                               optax.sgd(0.1), microbatches=microbatches)
+    carry, batch = ts.init(jnp.ones((3, 4))), jnp.ones((3, 4))
+
+    def calls_of_a_step(carry):
+        seen = []
+
+        def profile(frame, event, _arg):
+            if event == "call" and "horovod_tpu" in frame.f_code.co_filename:
+                seen.append(frame.f_code.co_name)
+
+        sys.setprofile(profile)
+        try:
+            _, carry = ts.step(carry, batch)
+        finally:
+            sys.setprofile(None)
+        return seen, carry
+
+    first, carry = calls_of_a_step(carry)
+    second, carry = calls_of_a_step(carry)
+    third, carry = calls_of_a_step(carry)
+    # grad and apply; with microbatches the accumulating grad too
+    assert first.count("first") == programs
+    assert len(spans._PROGRAMS) == programs
+    steady = ["step", "span", "step"] + (
+        ["_split_microbatches", "<lambda>"] if microbatches > 1 else [])
+    assert sorted(second) == sorted(third)
+    assert [c for c in second if c not in ("<lambda>",)] \
+        == [c for c in steady if c != "<lambda>"]
+    assert "first" not in second and "register_program" not in second
+    spans._PROGRAMS.clear()
 
 
 @pytest.mark.parametrize("program", [
