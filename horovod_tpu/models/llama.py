@@ -1129,7 +1129,7 @@ def _build_layer_body(c, mesh, seq_axis, constrain_acts=True, kind=None):
     elif c.remat == "attn":
         # Full remat except the attention output and the flash kernel's
         # residuals (o + logsumexp — one [B,T,H*D] bf16 and one
-        # [B,H,T,1] f32 per layer): saving flash_lse is what actually
+        # [B,H,1,T] f32 per layer): saving flash_lse is what actually
         # stops backward from re-running the flash forward — the
         # custom-vjp residuals are distinct from the outer attn_out
         # var, so naming only attn_out still recomputed the kernel

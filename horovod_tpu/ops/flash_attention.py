@@ -36,13 +36,23 @@ Kernel design (v5e-friendly):
   can exist (a bias; a straddling tile of a call with offsets).
 - backward = ONE kernel (`hvd_flash_bwd_fused`), grid (b, h, jk, iq):
   per tile s, p = exp(s - lse), dp and ds are formed once and feed all
-  three products, dv += p^T do, dk += ds^T q, dq += ds k (5 matmuls;
-  the dkv + dq pair it replaced did 7 and the elementwise chain
-  twice). dk / dv accumulate per jk as in the forward's mirror image;
+  three products (5 matmuls; the dkv + dq pair it replaced did 7 and
+  the elementwise chain twice). The tile is held TRANSPOSED, keys on
+  the rows (s_t = k q^T): dv += p_t do and dk += ds_t q are plain
+  products, dq += ds_t^T k the one with a transposed LHS, and the row
+  statistics broadcast down the sublanes as they arrive.
+  dk / dv accumulate per jk as in the forward's mirror image;
   dq accumulates in an f32 VMEM scratch of the whole (b, h) slice,
   [T, D] (2 MiB at T = 4096, 8 MiB at 16384), and each block of it is
   cast and written while the last jk passes: no f32 dq in HBM, no
   second pass. The kernel asks for its own VMEM (`_bwd_vmem_bytes`).
+- the row statistics (the forward's `lse` result, the backward's `lse`
+  and `delta` operands) cross the call boundary as f32 [B, H, 1, T],
+  T on the lanes, in blocks [1, block_q]: 2 MB a call at B2 H32 T8192.
+  As [B, H, T, 1] each filled a whole 128-lane tile a value (268 MB),
+  and a layer under remat held that from its forward to its backward.
+  The forward turns its [block_q, 1] column into the row once a q
+  block (`_column_to_row`); the backward uses rows as they are.
 
 Operands that live off-TPU take the XLA blockwise implementation
 (pallas interpret mode is too slow for real runs; CPU tests exercise the
@@ -132,21 +142,32 @@ def _for_each_kind(causal, q_first, block_q, kv_first, block_k, update,
     pl.when(straddling)(functools.partial(update, True))
 
 
-def _scores(qs, k, q_first, kv_first, masked, bias, window=0):
-    """Scores of one tile from the SCALED q tile: ``qs k^T`` in f32, the
+def _positions(first, n, axis):
+    """GLOBAL positions ``first .. first + n`` along ``axis`` of a score
+    tile, one wide along the other: a column or a row, never a plane."""
+    shape = (n, 1) if axis == 0 else (1, n)
+    return first + lax.broadcasted_iota(jnp.int32, shape, axis)
+
+
+def _scores(qs, k, q_first, kv_first, masked, bias, window=0,
+            keys_on_rows=False):
+    """Scores of one tile from the SCALED q tile, in f32: ``qs k^T``
+    [block_q, block_k] (the forward), or with ``keys_on_rows`` its
+    transpose ``k qs^T`` [block_k, block_q] (the backward, whose row
+    statistics then broadcast down the sublanes as they arrive). The
     causal mask (and, with a ``window``, the band's lower edge) only
     where an edge crosses the tile (``masked``; ``q_first`` /
-    ``kv_first`` are the GLOBAL positions of its first row and first
-    key), the per-key bias row where the call has one."""
-    s = jax.lax.dot_general(qs, k, (((1,), (1,)), ((), ())),
+    ``kv_first`` are the GLOBAL positions of its first query and first
+    key), the per-key bias where the call has one (a row, or with
+    ``keys_on_rows`` a column)."""
+    a, b = (k, qs) if keys_on_rows else (qs, k)
+    s = jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)
     if masked:
-        # A column of row positions against a row of key positions: no
-        # [block_q, block_k] iota plane is ever built.
-        q_pos = q_first + lax.broadcasted_iota(
-            jnp.int32, (s.shape[0], 1), 0)
-        kv_pos = kv_first + lax.broadcasted_iota(
-            jnp.int32, (1, s.shape[1]), 1)
+        # Query positions along one axis against key positions along the
+        # other: no [block_q, block_k] iota plane is ever built.
+        q_pos = _positions(q_first, qs.shape[0], 1 if keys_on_rows else 0)
+        kv_pos = _positions(kv_first, k.shape[0], 0 if keys_on_rows else 1)
         visible = q_pos >= kv_pos
         if window:
             visible = visible & (kv_pos > q_pos - window)
@@ -154,6 +175,19 @@ def _scores(qs, k, q_first, kv_first, masked, bias, window=0):
     if bias is not None:
         s = s + bias
     return s
+
+
+def _column_to_row(col):
+    """[n, 1] -> [1, n]: a lane-broadcast, one 32-bit 2-D transpose, row
+    0 (128 vregs through the XLU at n = 1024). How a statistic the
+    kernel holds a row of q apiece leaves for HBM with T on the lanes."""
+    return jnp.broadcast_to(col, (col.shape[0], 128)).T[:1, :]
+
+
+def _row_to_column(row):
+    """[1, n] -> [n, 1], the other way: the per-key bias row, which the
+    backward's transposed tile wants down its rows."""
+    return jnp.broadcast_to(row, (128, row.shape[1])).T[:, :1]
 
 
 def _scaled(q, scale):
@@ -169,7 +203,9 @@ def _fwd_kernel(*refs, scale, causal, has_bias, has_offsets, window=0):
     # q/o/lse blocks are keyed by iq (constant across the inner jj
     # steps), k/v stream per jj; the scaled q tile and the
     # online-softmax state live in VMEM scratch persisted across jj and
-    # the output is written on the last step.
+    # the output is written on the last step. The state is a column
+    # ([block_q, 1], a value a row of scores); the lse block is a ROW,
+    # [1, block_q], so it lies in HBM with T on the lanes.
     # bias is a per-key additive f32 row [1, Tk] (padding masks).
     # offs_ref is an SMEM int32 [2] = (q_offset, kv_offset): GLOBAL
     # positions for causal masking when the call sees only a chunk of
@@ -227,7 +263,7 @@ def _fwd_kernel(*refs, scale, causal, has_bias, has_offsets, window=0):
         valid = m_ref[:, :] > _NEG / 2
         o_ref[:, :] = jnp.where(
             valid, acc_ref[:, :] / l, 0.0).astype(o_ref.dtype)
-        lse_ref[:, :] = m_ref[:, :] + jnp.log(l)
+        lse_ref[:, :] = _column_to_row(m_ref[:, :] + jnp.log(l))
 
 
 def _bwd_kernel(*refs, scale, causal, has_bias, has_offsets, window=0):
@@ -240,6 +276,11 @@ def _bwd_kernel(*refs, scale, causal, has_bias, has_offsets, window=0):
     # slice, [T // block_q, block_q, D] indexed by iq, and each of its
     # blocks is cast and written while the last jk passes over it (the
     # dq out block is keyed by iq only then, see _flash_bwd_impl).
+    # The tile is held TRANSPOSED, keys on the rows: s_t = k q^T is
+    # [block_k, block_q], so the lse and delta blocks, ROWS [1, block_q]
+    # as they lie in HBM (T on the lanes), broadcast down the sublanes
+    # where they are used; dv += p_t do and dk += ds_t q are plain
+    # products and dq += ds_t^T k is the one with a transposed LHS.
     if has_offsets:
         offs_ref, *refs = refs
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest = refs
@@ -266,30 +307,31 @@ def _bwd_kernel(*refs, scale, causal, has_bias, has_offsets, window=0):
         q = q_ref[:, :]
         k = k_ref[:, :]
         do = do_ref[:, :]
-        s = _scores(_scaled(q, scale), k, q_first, kv_first, masked,
-                    bias_ref[:, :] if has_bias else None, window)
-        p = jnp.exp(s - lse_ref[:, :])  # [bq, bk]
+        s_t = _scores(_scaled(q, scale), k, q_first, kv_first, masked,
+                      _row_to_column(bias_ref[:, :]) if has_bias else None,
+                      window, keys_on_rows=True)
+        p_t = jnp.exp(s_t - lse_ref[:, :])  # [bk, bq]
         if has_bias or (masked and has_offsets):
             # A q row with ZERO valid keys (a padded batch row; a ring
             # chunk that starts inside this q block) has lse ~_NEG
             # itself, so exp(s - lse) rounds to 1 per masked key: guard
             # on s directly. A plain causal call always has the
             # diagonal key, and an interior tile no masked one.
-            p = jnp.where(s > _NEG / 2, p, 0.0)
+            p_t = jnp.where(s_t > _NEG / 2, p_t, 0.0)
         dv_acc[:, :] = dv_acc[:, :] + jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+            p_t.astype(do.dtype), do, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(
-            do, v_ref[:, :], (((1,), (1,)), ((), ())),
+        dp_t = jax.lax.dot_general(
+            v_ref[:, :], do, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
         # ds without the softmax scale: dq and dk take it once, as the
         # accumulators are written.
-        ds = (p * (dp - delta_ref[:, :])).astype(q.dtype)
+        ds_t = (p_t * (dp_t - delta_ref[:, :])).astype(q.dtype)
         dk_acc[:, :] = dk_acc[:, :] + jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
+            ds_t, q, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         dq_acc[iq] = dq_acc[iq] + jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())),
+            ds_t, k, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
     _for_each_kind(causal, q_first, bq, kv_first, bk, update, window)
@@ -420,12 +462,12 @@ def _flash_fwd_impl(q, k, v, bias, causal, block_q, block_k,
     out_specs = [
         pl.BlockSpec((None, None, block_q, d),
                      lambda bi, hi, qi, ji, *a: (bi, hi, qi, 0)),
-        pl.BlockSpec((None, None, block_q, 1),
-                     lambda bi, hi, qi, ji, *a: (bi, hi, qi, 0)),
+        pl.BlockSpec((None, None, 1, block_q),
+                     lambda bi, hi, qi, ji, *a: (bi, hi, 0, qi)),
     ]
     out_shape = [
         jax.ShapeDtypeStruct(q.shape, q.dtype),
-        jax.ShapeDtypeStruct((b, h, t, 1), jnp.float32),
+        jax.ShapeDtypeStruct((b, h, 1, t), jnp.float32),
     ]
     scratch = [
         pltpu.VMEM((block_q, d), q.dtype),       # scaled q
@@ -445,9 +487,9 @@ def _flash_fwd(q, k, v, causal, block_q, block_k, window=0):
     # the returned o covers only the PRIMAL output — the residual o/lse
     # here are distinct jaxpr vars, and leaving them unnamed makes
     # jax.checkpoint re-run this whole kernel in the backward pass just
-    # to regenerate lse (a [B,H,T,1] f32 — ~1 MB/layer at bench shapes,
-    # vs a full flash forward to recompute). Profiled round 3: the
-    # rerun cost ~12% of the train step.
+    # to regenerate lse (a [B,H,1,T] f32, T on the lanes: 2 MB a layer
+    # at B2 H32 T8192, vs a full flash forward to recompute). Profiled
+    # round 3: the rerun cost ~12% of the train step.
     o = checkpoint_name(o, "flash_o")
     lse = checkpoint_name(lse, "flash_lse")
     return o, (q, k, v, o, lse)
@@ -462,12 +504,13 @@ def _flash_biased_fwd(q, k, v, bias, causal, block_q, block_k):
 
 def _bwd_vmem_bytes(t, block_q, block_k, d, itemsize):
     """VMEM the one-pass backward asks for: its blocks (the pipeline
-    holds each twice; a [block_q, 1] f32 row block fills whole 128-lane
-    tiles), its scratch (dq for the whole [T, D] slice, dk and dv for
-    one block) and room for the f32 / operand-dtype planes of one score
-    tile (s, p, dp, ds and their casts: six f32 planes' worth)."""
+    holds each twice; a [1, block_q] f32 block of lse or delta fills
+    whole 8-sublane tiles), its scratch (dq for the whole [T, D] slice,
+    dk and dv for one block) and room for the f32 / operand-dtype
+    planes of one score tile (s, p, dp, ds and their casts: six f32
+    planes' worth)."""
     blocks = (3 * block_q + 4 * block_k) * d * itemsize \
-        + 2 * block_q * 128 * 4
+        + 2 * 8 * block_q * 4
     scratch = (t + 2 * block_k) * d * 4
     return 2 * blocks + scratch + 6 * block_q * block_k * 4
 
@@ -482,7 +525,7 @@ def _flash_bwd_impl(q, k, v, bias, o, lse, do, causal, block_q, block_k,
     has_bias = bias is not None
     has_offsets = offsets is not None
     delta = (do.astype(jnp.float32)
-             * o.astype(jnp.float32)).sum(-1, keepdims=True)
+             * o.astype(jnp.float32)).sum(-1)[:, :, None, :]
     if dlse is not None:
         # An incoming lse cotangent folds into delta: ds = p*(dp - delta)
         # becomes p*(dp - delta + dlse), i.e. delta -= dlse.
@@ -494,6 +537,7 @@ def _flash_bwd_impl(q, k, v, bias, o, lse, do, causal, block_q, block_k,
                                window=window)
     # grid (b, h, jk, iq) — q/do/lse/delta stream over the inner iq
     # dimension, k/v and the dk/dv accumulators stay pinned per jk.
+    # lse and delta are [B, H, 1, T], read in blocks [1, block_q].
 
     def live(jk, iq, a):
         # As in the forward: a skipped tile (this q block wholly before
@@ -518,8 +562,8 @@ def _flash_bwd_impl(q, k, v, bias, o, lse, do, causal, block_q, block_k,
         (None, None, block_k, d),
         lambda bi, hi, jk, iq, *a: (bi, hi // n_rep, jk, 0))
     row_spec = pl.BlockSpec(
-        (None, None, block_q, 1),
-        lambda bi, hi, jk, iq, *a: (bi, hi, live(jk, iq, a), 0))
+        (None, None, 1, block_q),
+        lambda bi, hi, jk, iq, *a: (bi, hi, 0, live(jk, iq, a)))
     in_specs = [q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec]
     args = [q, k, v, do, lse, delta]
     if has_bias:
@@ -588,9 +632,10 @@ def _flash_offsets(q, k, v, offsets, causal, block_q, block_k):
     """Flash attention over a K/V CHUNK with dynamic global-position
     offsets (SMEM scalars — one compiled kernel serves every ring
     step). Returns (o, lse): the normalized chunk output plus its
-    logsumexp, exactly what ring attention's online-softmax merge
-    needs. q [B,H,Tq,D]; k,v [B,Hkv,Tk,D]; offsets int32 [2] =
-    (global q start, global kv start)."""
+    logsumexp as the kernel writes it, f32 [B,H,1,Tq], exactly what
+    ring attention's online-softmax merge needs. q [B,H,Tq,D]; k,v
+    [B,Hkv,Tk,D]; offsets int32 [2] = (global q start, global kv
+    start)."""
     return _flash_fwd_impl(q, k, v, None, causal, block_q, block_k,
                            offsets=offsets)
 
@@ -627,14 +672,15 @@ def flash_attention_chunk(q, k, v, q_offset, kv_offset, causal=True,
     local queries against ONE K/V chunk, with global positions for the
     causal mask. Layout [B, H(q)/Hkv(kv), T, D] (kernel layout — ring
     loops keep tensors there to avoid per-step transposes). Returns
-    ``(o, lse)`` ready for logsumexp merging; differentiable (the lse
-    cotangent folds into the backward's delta).
+    ``(o, lse)``, lse f32 [B, H, T], ready for logsumexp merging;
+    differentiable (the lse cotangent folds into the backward's delta).
     """
     bq = _pick_block(q.shape[2], block_q)
     bk = _pick_block(k.shape[2], block_k)
     offsets = jnp.stack([jnp.asarray(q_offset, jnp.int32),
                          jnp.asarray(kv_offset, jnp.int32)])
-    return _flash_offsets(q, k, v, offsets, causal, bq, bk)
+    o, lse = _flash_offsets(q, k, v, offsets, causal, bq, bk)
+    return o, lse[:, :, 0, :]
 
 
 def _masked_attention_xla(q, k, v, kv_bias, causal):

@@ -112,14 +112,15 @@ def _ring_attention_flash(q, k, v, axis_name, causal):
     vt = v.transpose(0, 2, 1, 3)
 
     o = jnp.zeros((b, h, tq, d), jnp.float32)
-    lse = jnp.full((b, h, tq, 1), -jnp.inf, jnp.float32)
+    lse = jnp.full((b, h, tq), -jnp.inf, jnp.float32)
     for step in range(n):
         src = (idx - step) % n  # whose shard we currently hold
         o_blk, lse_blk = flash_attention_chunk(
             qt, kt, vt, idx * tq, src * tk, causal=causal)
         new_lse = jnp.logaddexp(lse, lse_blk)
-        o = (jnp.exp(lse - new_lse) * o
-             + jnp.exp(lse_blk - new_lse) * o_blk.astype(jnp.float32))
+        o = (jnp.exp(lse - new_lse)[..., None] * o
+             + jnp.exp(lse_blk - new_lse)[..., None]
+             * o_blk.astype(jnp.float32))
         lse = new_lse
         if step != n - 1:
             perm = [(i, (i + 1) % n) for i in range(n)]

@@ -56,11 +56,15 @@ def for_tpu(v5e_chip, monkeypatch):
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
 
-    def compile_for_chip(fn, *shapes):
+    def executable(fn, *shapes):
         args = [jax.ShapeDtypeStruct(s, d, sharding=v5e_chip)
                 for s, d in shapes]
-        return jax.jit(fn).lower(*args).compile().as_text()
+        return jax.jit(fn).lower(*args).compile()
 
+    def compile_for_chip(fn, *shapes):
+        return executable(fn, *shapes).as_text()
+
+    compile_for_chip.executable = executable
     yield compile_for_chip
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
@@ -230,6 +234,83 @@ _TQ, _TKV = ((2, 8192, 32, 128), BF16), ((2, 8192, 4, 128), BF16)
 ])
 def test_kernel_compiles_for_described_v5e(for_tpu, fn, shapes):
     assert "tpu_custom_call" in for_tpu(fn, *shapes)
+
+
+# An f32 array one wide under the default tiling: every value fills a
+# whole 128-lane tile, 128 times its logical size in HBM.
+_LANE_PADDED = re.compile(r"f32\[[\d,]+,1\]\{[^}]*T\(8,128\)[^}]*\}")
+
+
+@pytest.mark.parametrize("fn,shapes,statistic", [
+    pytest.param(_flash_fwd_bwd,
+                 (((2, 4096, 32, 128), BF16),)
+                 + (((2, 4096, 8, 128), BF16),) * 2,
+                 "f32[2,32,1,4096]", id="mistral7b-b2s4096"),
+    pytest.param(_flash_fwd_bwd, (((2, 4096, 16, 128), BF16),) * 3,
+                 "f32[2,16,1,4096]", id="olmoe1b7b-b2s4096"),
+    pytest.param(_flash_fwd_bwd, (_TQ, _TKV, _TKV),
+                 "f32[2,32,1,8192]", id="trinitymini-b2s8192-full"),
+    pytest.param(_flash_window_fwd_bwd, (_TQ, _TKV, _TKV),
+                 "f32[2,32,1,8192]", id="trinitymini-b2s8192-window2048"),
+    pytest.param(_flash_fwd_bwd,
+                 (((2, 8192, 32, 64), BF16),)
+                 + (((2, 8192, 8, 64), BF16),) * 2,
+                 "f32[2,32,1,8192]", id="lfm2moe-b2s8192-heads64"),
+    pytest.param(_flash_chunk,
+                 (((1, 16, 2048, 128), BF16), ((1, 4, 2048, 128), BF16),
+                  ((1, 4, 2048, 128), BF16), ((), I32), ((), I32)),
+                 "f32[1,16,1,2048]", id="chunk-offsets-lse-cotangent"),
+])
+def test_flash_row_statistics_cross_hbm_lane_dense(for_tpu, fn, shapes,
+                                                   statistic):
+    """Forward + backward at the attention shapes the cells run, as the
+    chip's compiler emits them (PR 37): ``lse`` and ``delta`` cross the
+    Mosaic calls' boundary as ``f32[B,H,1,T]``, T on the lanes. Nothing
+    in the program, no operand or result of a ``tpu_custom_call`` and no
+    ``copy``, is an f32 array one wide under ``T(8,128)``: as
+    ``f32[B,H,T,1]`` each statistic was 268 MB at B2 H32 T8192 where 2 MB
+    are meant, and a ``copy`` of ``delta`` into that form stood in front
+    of every backward call."""
+    text = for_tpu(fn, *shapes)
+    assert text.count("tpu_custom_call") >= 2
+    assert not _LANE_PADDED.findall(text)
+    assert statistic in text
+
+
+def test_a_stack_under_remat_attn_holds_no_padded_statistics(for_tpu):
+    """Three attention layers at Trinity-Mini's shape (B2 T8192, 32
+    heads on 4 of 128), unrolled, each under
+    ``save_only_these_names("flash_o", "flash_lse")`` as remat ``attn``
+    saves them: the gradient program's temporaries. The parent's read
+    2,220,851,200 B here, holding three ``f32[2,32,8192,1]`` residuals
+    of 268,435,456 B each from forward to backward; the bound is that
+    less the three (this tree reads 1,193,633,792: the transients went
+    too)."""
+    from horovod_tpu.ops import flash_attention
+
+    B, T, H, HKV, D, DM, L = 2, 8192, 32, 4, 128, 2048, 3
+
+    def layer(x, w_qkv, w_o):
+        q, k, v = jnp.split(x @ w_qkv, [H * D, (H + HKV) * D], axis=-1)
+        a = flash_attention(q.reshape(B, T, H, D), k.reshape(B, T, HKV, D),
+                            v.reshape(B, T, HKV, D), causal=True)
+        return x + a.reshape(B, T, H * D) @ w_o
+
+    saved = jax.checkpoint(
+        layer, policy=jax.checkpoint_policies.save_only_these_names(
+            "flash_o", "flash_lse"))
+
+    def loss(x, w_qkv, w_o):
+        for i in range(L):
+            x = saved(x, w_qkv[i], w_o[i])
+        return x.astype(F32).sum()
+
+    compiled = for_tpu.executable(
+        jax.grad(loss, argnums=(0, 1, 2)), ((B, T, DM), BF16),
+        ((L, DM, (H + 2 * HKV) * D), BF16), ((L, H * D, DM), BF16))
+    assert not _LANE_PADDED.findall(compiled.as_text())
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < 2_220_851_200 - 3 * 268_435_456
 
 
 _LOCATION_FLAGS = ("jax_include_full_tracebacks_in_locations",

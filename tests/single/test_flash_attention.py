@@ -137,12 +137,14 @@ def test_fully_masked_row_stays_finite():
     assert np.isfinite(np.asarray(out)).all()
 
 
+@pytest.mark.parametrize("D", [8, 64, 128])
 @pytest.mark.parametrize("causal", [False, True])
-def test_chunked_offsets_kernel_matches_reference(causal):
+def test_chunked_offsets_kernel_matches_reference(causal, D):
     """flash_attention_chunk with dynamic global offsets (the ring-step
     kernel): two chunks merged by logsumexp must equal one full-width
-    attention — values and grads."""
-    B, T, H, D = 1, 32, 2, 8
+    attention — values and grads (each chunk's lse, f32 [B, H, T],
+    carries a cotangent through the merge)."""
+    B, T, H = 1, 32, 2
     q, k, v = _qkv(seed=5, B=B, T=T, H=H, D=D)
     half = T // 2
 
@@ -155,9 +157,10 @@ def test_chunked_offsets_kernel_matches_reference(causal):
                 block_q=16, block_k=16)
             o.append(ob.astype(jnp.float32))
             lse.append(lb)
+        assert lse[0].shape == q.shape[:3]     # f32 [B, H, T]
         new = jnp.logaddexp(lse[0], lse[1])
-        return (jnp.exp(lse[0] - new) * o[0]
-                + jnp.exp(lse[1] - new) * o[1])
+        return (jnp.exp(lse[0] - new)[..., None] * o[0]
+                + jnp.exp(lse[1] - new)[..., None] * o[1])
 
     out = merged(q, k, v)
     ref = _ref(q, k, v, causal=causal)
@@ -309,23 +312,27 @@ def test_zero_valid_key_rows_zero_output_and_grads():
 # and straddling tiles all occur, and compares values and all three
 # gradients with reference math.
 
-def _chunk_ref(q, k, v, q_off, kv_off, causal=True):
-    """Reference for one chunk with GLOBAL positions: (o, lse, rows with
-    a valid key). A row without one has output 0 (its lse is not
-    compared: the kernel leaves it at ~-1e30)."""
+def _chunk_ref(q, k, v, q_off, kv_off, causal=True, window=0, bias=None):
+    """Reference for one chunk with GLOBAL positions: (o, lse [B,H,T],
+    rows with a valid key [1,1,T]). A row without one has output 0 (its
+    lse is not compared: the kernel leaves it at ~-1e30). ``window``: the
+    causal band; ``bias``: f32 [B,1,Tk], added per key."""
     d = q.shape[-1]
     n_rep = q.shape[1] // k.shape[1]
     k, v = jnp.repeat(k, n_rep, 1), jnp.repeat(v, n_rep, 1)
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k) / (d ** 0.5)
     mask = jnp.ones(s.shape[-2:], bool)
     if causal:
-        mask = (q_off + jnp.arange(q.shape[2]))[:, None] \
-            >= (kv_off + jnp.arange(k.shape[2]))[None, :]
-    has_key = mask.any(-1)[None, None, :, None]
+        i = (q_off + jnp.arange(q.shape[2]))[:, None]
+        j = (kv_off + jnp.arange(k.shape[2]))[None, :]
+        mask = (i >= j) & (j > i - window) if window else i >= j
+    has_key = mask.any(-1)[None, None, :]
     s = jnp.where(mask, s, -1e30)
+    if bias is not None:
+        s = s + bias[:, None, :, :]
     o = jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, -1), v)
-    lse = jax.nn.logsumexp(s, -1, keepdims=True)
-    return jnp.where(has_key, o, 0.0), lse, has_key
+    lse = jax.nn.logsumexp(s, -1)
+    return jnp.where(has_key[..., None], o, 0.0), lse, has_key
 
 
 def _assert_grads_close(got, want):
@@ -398,7 +405,7 @@ def test_offset_chunks_with_lse_cotangent(chunk):
     k = jax.random.normal(ks[1], (B, HKV, T, D), jnp.float32)
     v = jax.random.normal(ks[2], (B, HKV, T, D), jnp.float32)
     w = jax.random.normal(ks[3], q.shape)
-    u = jax.random.normal(ks[4], (B, H, T, 1))
+    u = jax.random.normal(ks[4], (B, H, T))
     has_key = _chunk_ref(q, k, v, q_off, kv_off)[2]
 
     def run(*a):
@@ -519,58 +526,88 @@ def test_kernel_predicate_with_traced_offsets_matches_the_mask(grid):
     np.testing.assert_array_equal(np.asarray(~(inside | crossing)), skipped)
 
 
-@pytest.mark.parametrize("case", ["causal", "offsets", "bias", "unequal"])
+# case -> (what runs, head width, window). PR 37: heads 64 and 128 wide
+# (LFM2's and the other cells'), with and without a window, a bias and
+# offsets, each checked down to the statistic the kernels hand over.
+_LANE_WIDE = {
+    "causal": ("causal", 8, 0), "offsets": ("offsets", 8, 0),
+    "bias": ("bias", 8, 0), "unequal": ("causal", 8, 0),
+    "causal-d64": ("causal", 64, 0), "causal-d128": ("causal", 128, 0),
+    "window-d64": ("causal", 64, 600), "window-d128": ("causal", 128, 600),
+    "bias-d64": ("bias", 64, 0), "bias-d128": ("bias", 128, 0),
+    "offsets-d64": ("offsets", 64, 0), "offsets-d128": ("offsets", 128, 0),
+}
+
+
+@pytest.mark.parametrize("case", list(_LANE_WIDE))
 def test_lane_wide_blocks(case):
     """Blocks of 256 and 512 (whole 128-wide lane tiles, as on the
     chip; the cases above run blocks of 16 and 32): values and gradients
     at 3 x 3 tiles and more, plain causal, with a chunk offset that no
     block boundary meets (and an lse cotangent), with a padded tail,
-    with unequal blocks."""
-    B, H, HKV, D = 1, 2, 1, 8
+    with unequal blocks, under a band that no block boundary meets; and
+    the log-sum-exp as it crosses the call boundary, f32 [B, H, 1, T]
+    (T on the lanes), through ``_flash``'s, ``_flash_biased``'s and
+    ``flash_attention_chunk``'s forward."""
+    kind, D, window = _LANE_WIDE[case]
+    B, H, HKV = 1, 2, 1
     T, bq, bk = (1536, 512, 256) if case == "unequal" else (768, 256, 256)
-    q_off, kv_off = (300, 140) if case == "offsets" else (0, 0)
+    if window:   # five blocks: a tile wholly below the band as well
+        T = 1280
+        assert fa_mod.tile_counts(T, T, bq, bk, True, 0, 0, window) \
+            == (11, 4, 10)
+    q_off, kv_off = (300, 140) if kind == "offsets" else (0, 0)
     ks = jax.random.split(jax.random.PRNGKey(21), 5)
     q = jax.random.normal(ks[0], (B, H, T, D), jnp.float32)
     k = jax.random.normal(ks[1], (B, HKV, T, D), jnp.float32)
     v = jax.random.normal(ks[2], (B, HKV, T, D), jnp.float32)
     w = jax.random.normal(ks[3], q.shape)
-    u = jax.random.normal(ks[4], (B, H, T, 1))
-    assert all(fa_mod.tile_counts(T, T, bq, bk, True, q_off, kv_off))
+    u = jax.random.normal(ks[4], (B, H, T))
+    assert all(fa_mod.tile_counts(T, T, bq, bk, True, q_off, kv_off,
+                                  window))
     bias = None
-    if case == "bias":
+    if kind == "bias":
         bias = jnp.where(jnp.arange(T) >= T - 200, -1e30,
                          0.0).astype(jnp.float32)[None, None, :]
 
     def run(*a):
-        if case == "bias":
+        if kind == "bias":
             return fa_mod._flash_biased(*a, bias, True, bq, bk), None
-        if case == "offsets":
+        if kind == "offsets":
             return jax.jit(lambda qo, ko: fa_mod.flash_attention_chunk(
                 *a, qo, ko, causal=True, block_q=bq, block_k=bk))(
                     q_off, kv_off)
-        return fa_mod._flash(*a, True, bq, bk), None
+        return fa_mod._flash(*a, True, bq, bk, window), None
 
     def ref(*a):
-        if case == "bias":
-            rep = [jnp.repeat(x, H // HKV, 1) for x in a[1:]]
-            return _ref(a[0], *rep, bias=bias, causal=True), None
-        return _chunk_ref(*a, q_off, kv_off)[:2]
+        return _chunk_ref(*a, q_off, kv_off, window=window, bias=bias)[:2]
 
     has_key = _chunk_ref(q, k, v, q_off, kv_off)[2]
 
     def loss(fn):
         def _l(*a):
             o, lse = fn(*a)
-            extra = 0.0 if case != "offsets" else \
+            extra = 0.0 if kind != "offsets" else \
                 (jnp.where(has_key, lse, 0.0) * u).sum()
             return (o * w).sum() + extra
         return _l
 
+    o_ref, lse_ref = ref(q, k, v)
     np.testing.assert_allclose(np.asarray(run(q, k, v)[0]),
-                               np.asarray(ref(q, k, v)[0]),
-                               rtol=2e-4, atol=2e-4)
+                               np.asarray(o_ref), rtol=2e-4, atol=2e-4)
     _assert_grads_close(jax.grad(loss(run), (0, 1, 2))(q, k, v),
                         jax.grad(loss(ref), (0, 1, 2))(q, k, v))
+    # The statistic the forward hands the backward (the residual named
+    # flash_lse) and a ring step its merge.
+    offsets = jnp.array([q_off, kv_off], jnp.int32) \
+        if kind == "offsets" else None
+    lse = fa_mod._flash_fwd_impl(q, k, v, bias, True, bq, bk,
+                                 offsets=offsets, window=window)[1]
+    assert lse.shape == (B, H, 1, T) and lse.dtype == jnp.float32
+    np.testing.assert_allclose(
+        np.asarray(jnp.where(has_key, lse[:, :, 0], 0.0)),
+        np.asarray(jnp.where(has_key, lse_ref, 0.0)),
+        rtol=2e-4, atol=2e-4)
 
 
 # ---------------------------------------------------------------------
