@@ -114,7 +114,9 @@ def _decode_ffn(h, lp, c):
 def _layer_qkv(h, lp, c, positions):
     """Project h [B,T,D] (normalized) -> rope'd q, rope'd k, and v for
     one layer, through llama.py's own projection (q/k norms included
-    where the config has them), so decode cannot drift from training."""
+    where the config has them), so decode cannot drift from training.
+    RoPE turns the whole head: ``require_decodable`` refuses a
+    configuration with ``partial_rotary``."""
     q, k, v = _project_qkv(h, lp, c)
     return (_rope(q, positions, c.rope_theta),
             _rope(k, positions, c.rope_theta), v)

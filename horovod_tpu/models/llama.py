@@ -51,8 +51,8 @@ _EXPERT_MATRICES = ("moe_gate", "moe_up", "moe_down")
 class LayerSpec(typing.NamedTuple):
     """One layer of ``LlamaConfig.layer_plan``: WHERE its parameters
     live (entry ``index`` of the stack ``params[stack]``) and WHAT it
-    runs (its token ``mixer``, "attention" or "conv"; a dense or an
-    expert FFN; an attention layer's window, 0 = none, and whether it
+    runs (its token ``mixer``, "attention", "conv" or "linear"; a dense
+    or an expert FFN; an attention layer's window, 0 = none, and whether it
     carries RoPE)."""
     stack: str
     index: int
@@ -80,9 +80,12 @@ class LlamaConfig:
     dtype: str = "bfloat16"
     # Rematerialization: True/"full" recomputes the whole layer in
     # backward (min HBM, ~1/3 extra FLOPs); "attn" saves only the flash
-    # kernel's residuals; "attn+gate" also saves the pre-silu FFN gate
-    # (skips one matmul re-run per layer — best measured MFU at bench
-    # shapes); "attn+ffn" saves both up-projections (more HBM); "dots"
+    # kernel's residuals; "attn/ffn" the same with the mixer (a
+    # linear_attention mixer's two stages) and the FFN recomputed one
+    # after the other, not together; "attn+gate" also saves the
+    # pre-silu FFN gate (skips one matmul re-run per layer — best
+    # measured MFU at bench shapes); "attn+ffn" saves both
+    # up-projections (more HBM); "dots"
     # saves every matmul output and recomputes only elementwise work;
     # False/"none" saves everything.
     remat: "bool | str" = True
@@ -176,7 +179,11 @@ class LlamaConfig:
     # ``rope_full_attention`` says so (lfm2) and with NO position
     # encoding where not (afmoe; the default), ``"conv"`` = no attention
     # at all but a gated short convolution over the last ``conv_taps``
-    # positions (``_short_conv``; lfm2's ``conv_L_cache``).
+    # positions (``_short_conv``; lfm2's ``conv_L_cache``),
+    # ``"linear_attention"`` = a Gated DeltaNet mixer
+    # (``_gated_delta_net``: the ``linear_*`` sizes below; its depthwise
+    # convolution has ``conv_taps`` taps, qwen3_next's
+    # ``linear_conv_kernel_dim``).
     # Empty: every layer full, with RoPE (the llama family).
     sliding_window: int = 0
     layer_types: tuple = ()
@@ -219,18 +226,55 @@ class LlamaConfig:
     # ``embed`` [vocab, d_model], read by the lookup and by the logits;
     # its gradient is the sum of both uses. No ``lm_head`` leaf.
     tie_embeddings: bool = False
+    # A ``linear_attention`` layer's heads (qwen3_next's
+    # ``linear_num_key_heads``, ``linear_num_value_heads``,
+    # ``linear_key_head_dim``, ``linear_value_head_dim``): a key head
+    # serves value_heads / key_heads consecutive value heads.
+    linear_key_heads: int = 0
+    linear_value_heads: int = 0
+    linear_key_dim: int = 0
+    linear_value_dim: int = 0
+    # RoPE turns the FIRST ``partial_rotary`` dimensions of a head and
+    # passes the rest (``partial_rotary_factor`` x ``head_dim``; 0 = the
+    # whole head).
+    partial_rotary: int = 0
+    # The shared expert's output is gated: ``sigmoid(h @ shared_score)``
+    # (``shared_score`` [D, 1]) times its SwiGLU.
+    shared_expert_gate: bool = False
 
     def __post_init__(self):
         if self.layer_types and len(self.layer_types) != self.n_layers:
             raise ValueError(
                 f"layer_types names {len(self.layer_types)} layers, "
                 f"n_layers is {self.n_layers}")
-        if any(t not in ("sliding_attention", "full_attention", "conv")
-               for t in self.layer_types):
+        if any(t not in ("sliding_attention", "full_attention", "conv",
+                         "linear_attention") for t in self.layer_types):
             raise ValueError(f"unknown layer type in {self.layer_types}")
-        if ("conv" in self.layer_types) != (self.conv_taps > 0):
-            raise ValueError("conv layers and conv_taps come together: "
+        linear = "linear_attention" in self.layer_types
+        if ("conv" in self.layer_types or linear) != (self.conv_taps > 0):
+            raise ValueError("conv and linear_attention layers and "
+                             "conv_taps come together: "
                              f"{self.layer_types}, {self.conv_taps} taps")
+        sizes = (self.linear_key_heads, self.linear_value_heads,
+                 self.linear_key_dim, self.linear_value_dim)
+        if linear != all(sizes) or (not linear and any(sizes)):
+            raise ValueError(
+                "linear_attention layers and their four sizes "
+                "(linear_key_heads, linear_value_heads, linear_key_dim, "
+                f"linear_value_dim) come together: {sizes}: the mixer's "
+                "projections and its state have no other source")
+        if linear and self.linear_value_heads % self.linear_key_heads:
+            raise ValueError(
+                f"{self.linear_value_heads} value heads are no multiple "
+                f"of {self.linear_key_heads} key heads: each key head "
+                "serves a whole number of value heads")
+        if self.partial_rotary % 2 or self.partial_rotary > self.head_dim:
+            raise ValueError(
+                f"partial_rotary {self.partial_rotary}: RoPE turns pairs "
+                f"of dimensions, at most a head's {self.head_dim}")
+        if self.shared_expert_gate and not self.n_shared_experts:
+            raise ValueError("shared_expert_gate gates a shared expert: "
+                             "n_shared_experts is 0")
         if "sliding_attention" in self.layer_types \
                 and self.sliding_window <= 0:
             raise ValueError("sliding_attention layers need a "
@@ -275,6 +319,7 @@ class LlamaConfig:
         leaves are stacked together: ``layers`` (attention; the only
         stack of a uniform model), ``conv_layers`` (a conv layer has
         ``conv_in``, ``conv_w``, ``conv_out`` and no ``wq`` .. ``wo``),
+        ``linear_layers`` (a Gated DeltaNet layer: the ``gdn_*`` leaves),
         and the leading dense layers of a sparse-expert model apart as
         ``dense_layers`` / ``dense_conv_layers``. Every name ends in
         ``layers``: ``llama_partition_rules`` shards them alike."""
@@ -282,15 +327,18 @@ class LlamaConfig:
         for i in range(self.n_layers):
             kind = self.layer_types[i] if self.layer_types \
                 else "full_attention"
-            conv, sliding = kind == "conv", kind == "sliding_attention"
+            sliding = kind == "sliding_attention"
+            mixer = {"conv": "conv", "linear_attention": "linear"}.get(
+                kind, "attention")
             stack = ("dense_" if i < self.n_dense_layers else "") \
-                + ("conv_" if conv else "") + "layers"
+                + ("" if mixer == "attention" else mixer + "_") + "layers"
             plan.append(LayerSpec(
-                stack, filled[stack], "conv" if conv else "attention",
+                stack, filled[stack], mixer,
                 self.n_experts == 0 or i < self.n_dense_layers,
                 self.sliding_window if sliding else 0,
-                not conv and (sliding or not self.layer_types
-                              or self.rope_full_attention)))
+                mixer == "attention" and (
+                    sliding or not self.layer_types
+                    or self.rope_full_attention)))
             filled[stack] += 1
         return plan
 
@@ -314,7 +362,10 @@ class LlamaConfig:
                             "route_scale", "scale_embed", "attn_gate",
                             "post_norm", "first_expert", "n_experts_held",
                             "rope_full_attention", "conv_taps",
-                            "tie_embeddings")
+                            "tie_embeddings", "linear_key_heads",
+                            "linear_value_heads", "linear_key_dim",
+                            "linear_value_dim", "partial_rotary",
+                            "shared_expert_gate")
                 if getattr(self, f) != getattr(d, f)] \
             + (["qk_norm"] if self.qk_norm == "head" else [])
 
@@ -386,6 +437,30 @@ def llama_init(config, key):
                                   c.d_model),
                 "mlp_norm": jnp.ones((L, c.d_model), pd),
             }
+        elif mixer == "linear":
+            # [q | k | v | z] and [b | a] side by side, each head by
+            # head (qwen3_next's checkpoint groups the same columns by
+            # key head: a permutation).
+            kw = c.linear_key_heads * c.linear_key_dim
+            vw = c.linear_value_heads * c.linear_value_dim
+            layers = {
+                "gdn_norm": jnp.ones((L, c.d_model), pd),
+                "gdn_in": dense(next(k), (L, c.d_model, 2 * kw + 2 * vw),
+                                c.d_model),
+                "gdn_ba": dense(next(x),
+                                (L, c.d_model, 2 * c.linear_value_heads),
+                                c.d_model),
+                "gdn_conv": dense(next(k), (L, c.conv_taps, 2 * kw + vw),
+                                  c.conv_taps),
+                # Hugging Face's start: A uniform over (0, 16), dt_bias 1.
+                "gdn_a_log": jnp.log(jax.random.uniform(
+                    next(x), (L, c.linear_value_heads), jnp.float32,
+                    1e-3, 16.0)).astype(pd),
+                "gdn_dt_bias": jnp.ones((L, c.linear_value_heads), pd),
+                "gdn_out_norm": jnp.ones((L, c.linear_value_dim), pd),
+                "gdn_out": dense(next(k), (L, vw, c.d_model), vw),
+                "mlp_norm": jnp.ones((L, c.d_model), pd),
+            }
         else:
             layers = {
                 "attn_norm": jnp.ones((L, c.d_model), pd),
@@ -439,6 +514,9 @@ def llama_init(config, key):
                                    c.d_model),
                 "shared_down": dense(next(x), (L, Fs, c.d_model), Fs),
             })
+            if c.shared_expert_gate:
+                layers["shared_score"] = dense(next(x), (L, c.d_model, 1),
+                                               c.d_model)
         return layers
 
     # The stacks this model has, each with its kind and depth. "layers"
@@ -447,7 +525,8 @@ def llama_init(config, key):
     stacks = {}
     for spec in c.layer_plan():
         stacks[spec.stack] = (spec.mixer, spec.dense_ffn, spec.index + 1)
-    folds = {"dense_layers": 2, "conv_layers": 3, "dense_conv_layers": 4}
+    folds = {"dense_layers": 2, "conv_layers": 3, "dense_conv_layers": 4,
+             "linear_layers": 5, "dense_linear_layers": 6}
     params = {}
     for name in sorted(stacks, key=lambda n: n != "layers"):
         mixer, dense_ffn, L = stacks[name]
@@ -491,12 +570,18 @@ def llama_partition_rules(pipeline=False):
         (r"layers/conv_in", P(lead, "fsdp", "tensor")),
         (r"layers/conv_out", P(lead, "tensor", "fsdp")),
         (r"layers/conv_w", P(lead, None, None)),
+        # Gated DeltaNet: data and fsdp only (the mixer refuses a tensor
+        # or sequence axis); the taps and the per-head gates replicated.
+        (r"layers/gdn_in", P(lead, "fsdp", None)),
+        (r"layers/gdn_out$", P(lead, None, "fsdp")),
+        (r"layers/gdn_(ba|conv)", P(lead, None, None)),
+        (r"layers/gdn_(a_log|dt_bias)", P(lead, None)),
         (r"layers/(w|shared)_(gate|up)", P(lead, "fsdp", "tensor")),
         (r"layers/(w|shared)_down", P(lead, "tensor", "fsdp")),
         # MoE: experts shard over the "expert" mesh axis (EP); within an
         # expert the FFN shards like the dense MLP. The router is tiny and
         # stays replicated.
-        (r"layers/router", P(lead, None, None)),
+        (r"layers/(router|shared_score)", P(lead, None, None)),
         (r"layers/expert_bias", P(lead, None)),
         (r"layers/moe_(gate|up)", P(lead, "expert", "fsdp", "tensor")),
         (r"layers/moe_down", P(lead, "expert", "tensor", "fsdp")),
@@ -512,16 +597,23 @@ def _rmsnorm(x, scale, eps):
 
 
 @scope("hvd.attn.rope")
-def _rope(x, positions, theta):
-    """Rotary embedding; positions are GLOBAL indices [B, T] so sequence
-    sharding stays correct."""
+def _rope(x, positions, theta, rotary=0):
+    """Rotary embedding (half-split) of ``x`` [B, T, H, D]; positions
+    are GLOBAL indices [B, T] so sequence sharding stays correct.
+    ``rotary``: the leading dimensions of a head that turn (their own
+    two halves paired, frequencies over ``rotary``), the rest pass as
+    they are; 0 = the whole head."""
+    rest = None
+    if rotary and rotary < x.shape[-1]:
+        x, rest = x[..., :rotary], x[..., rotary:]
     b, t, h, d = x.shape
     freqs = theta ** (-jnp.arange(0, d // 2, dtype=jnp.float32) / (d // 2))
     angles = positions[:, :, None].astype(jnp.float32) * freqs  # [B,T,d/2]
     cos = jnp.cos(angles)[:, :, None, :].astype(x.dtype)
     sin = jnp.sin(angles)[:, :, None, :].astype(x.dtype)
     x1, x2 = jnp.split(x, 2, axis=-1)
-    return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
+    turned = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
+    return turned if rest is None else jnp.concatenate([turned, rest], -1)
 
 
 @scope("hvd.attn.core")
@@ -609,14 +701,22 @@ def gated_short_conv(proj, w):
     that the compiler fuses into one elementwise pass; in ``proj``'s
     dtype, the taps summed in float32."""
     gate_in, gate_out, z = jnp.split(proj, 3, axis=-1)
-    u = gate_in * z
+    return gate_out * _causal_taps(gate_in * z, w).astype(proj.dtype)
+
+
+def _causal_taps(u, w):
+    """``c_t = sum_j w_j * u_{t - (taps-1) + j}`` of ``u`` [B, T, D]
+    under the taps ``w`` [taps, D], zero before position 0: a depthwise
+    causal convolution as shifted multiply-adds, summed in float32 (and
+    returned so). The one convolution of the conv and the
+    linear_attention mixers."""
     w = w.astype(jnp.float32)
     taps, t = w.shape[0], u.shape[1]
     conv = u.astype(jnp.float32) * w[taps - 1]
     for back in range(1, taps):      # u as it was ``back`` tokens ago
         past = jnp.pad(u, ((0, 0), (back, 0), (0, 0)))[:, :t]
         conv = conv + past.astype(jnp.float32) * w[taps - 1 - back]
-    return gate_out * conv.astype(proj.dtype)
+    return conv
 
 
 @scope("hvd.conv.proj")
@@ -627,6 +727,74 @@ def _short_conv(h, lp, c):
     dt = c.compute_dtype
     return gated_short_conv(h @ lp["conv_in"].astype(dt), lp["conv_w"]) \
         @ lp["conv_out"].astype(dt)
+
+
+def _gated_delta_net(x, lp, c, mesh, seq_axis, stage=lambda f: f):
+    """qwen3_next's Gated DeltaNet, the token mixer of a
+    ``linear_attention`` layer, on the residual stream ``x`` [B, T, D]
+    -> what it adds. Two stages. Before the rule: the layer's norm;
+    ``[q, k, v, z] = h W_in``, ``[b, a] = h W_ba``; a depthwise causal
+    convolution of ``conv_taps`` taps and SiLU over ``[q, k, v]``; ``q``
+    and ``k`` L2-normalised a head, ``q`` scaled by ``dk^-1/2``; ``beta
+    = sigmoid(b)`` and ``g = -exp(A_log) * softplus(a + dt_bias)`` a
+    value head and token, in float32. The rule and what follows it: the
+    gated delta rule (``ops/gated_delta_rule.py``), a key head repeated
+    for the value heads it serves; ``RMSNorm(o) * SiLU(z)`` a head; the
+    output projection. ``stage`` wraps each (remat "attn/ffn": a
+    checkpoint of its own, so that the first's residuals and the
+    rule's are never alive together)."""
+    from horovod_tpu.ops.gated_delta_rule import gated_delta_rule
+
+    if mesh is not None and (
+            (seq_axis and mesh.shape.get(seq_axis, 1) > 1)
+            or mesh.shape.get("tensor", 1) > 1):
+        raise ValueError(
+            "a linear_attention layer runs whole on each device of the "
+            "data and fsdp axes: its state passes from token to token "
+            "(no sequence axis) and a key head's state serves "
+            "value_heads / key_heads value heads (no tensor axis yet)")
+    dt, f32 = c.compute_dtype, jnp.float32
+    b, t, _ = x.shape
+    hk, hv = c.linear_key_heads, c.linear_value_heads
+    dk, dv = c.linear_key_dim, c.linear_value_dim
+
+    def before(x, lp):
+        h = _rmsnorm(x, lp["gdn_norm"].astype(dt), c.norm_eps)
+        with scope("hvd.gdn.proj"):
+            qkvz = h @ lp["gdn_in"].astype(dt)
+            ba = h @ lp["gdn_ba"].astype(dt)
+        with scope("hvd.gdn.chain"):
+            u, z = jnp.split(qkvz, [2 * hk * dk + hv * dv], axis=-1)
+            u = jax.nn.silu(_causal_taps(u, lp["gdn_conv"]).astype(dt))
+            q, k, v = jnp.split(u, [hk * dk, 2 * hk * dk], axis=-1)
+
+            def unit(x):     # [B, T, hk*dk] -> unit vectors a key head
+                x = x.reshape(b, t, hk, dk).astype(f32)
+                return x * lax.rsqrt(jnp.sum(x * x, -1, keepdims=True)
+                                     + 1e-6)
+
+            bb, aa = jnp.split(ba.astype(f32), 2, axis=-1)
+            g = -jnp.exp(lp["gdn_a_log"].astype(f32)) * jax.nn.softplus(
+                aa + lp["gdn_dt_bias"].astype(f32))
+            return ((unit(q) * dk ** -0.5).astype(dt), unit(k).astype(dt),
+                    v.reshape(b, t, hv, dv), z.reshape(b, t, hv, dv), g,
+                    jax.nn.sigmoid(bb))
+
+    def rule_and_after(q, k, v, z, g, beta, lp):
+        with scope("hvd.gdn.chain"):
+            q, k = (jnp.repeat(a, hv // hk, axis=2) for a in (q, k))
+        with scope("hvd.gdn.core"):
+            o = gated_delta_rule(q, k, v, g, beta)
+        with scope("hvd.gdn.chain"):
+            o = o.astype(f32)
+            o = (o * lax.rsqrt(jnp.mean(o * o, -1, keepdims=True)
+                               + c.norm_eps)).astype(dt) \
+                * lp["gdn_out_norm"].astype(dt)
+            o = o * jax.nn.silu(z.astype(f32)).astype(dt)
+        with scope("hvd.gdn.proj"):
+            return o.reshape(b, t, hv * dv) @ lp["gdn_out"].astype(dt)
+
+    return stage(rule_and_after)(*stage(before)(x, lp), lp)
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(1,))
@@ -842,8 +1010,13 @@ def _ffn(h, lp, c, mesh=None):
         else:
             y, aux = _moe_ffn(h, lp, c, mesh)
         if c.n_shared_experts:
-            y = y + _swiglu(h, lp["shared_gate"], lp["shared_up"],
-                            lp["shared_down"], dt)
+            shared = _swiglu(h, lp["shared_gate"], lp["shared_up"],
+                             lp["shared_down"], dt)
+            if c.shared_expert_gate:
+                with scope("hvd.ffn"):
+                    shared = shared * jax.nn.sigmoid(
+                        h @ lp["shared_score"].astype(dt))
+            y = y + shared
         return y, aux
     # Named for remat="attn+ffn": saving the two up-projections (the
     # bulk of a layer's recomputed matmul FLOPs) lets backward rebuild
@@ -1028,11 +1201,14 @@ def _validate_pipeline(c, b, mesh, seq_axis, n_stages):
     pipeline's)."""
     M = c.pipeline_microbatches or n_stages
     plan = c.layer_plan()
-    if len({spec.kind for spec in plan}) > 1 or plan[0].mixer == "conv" \
-            or c.tie_embeddings:
+    if len({spec.kind for spec in plan}) > 1 \
+            or plan[0].mixer != "attention" or c.tie_embeddings \
+            or c.partial_rotary or c.shared_expert_gate:
         raise ValueError("a layer pattern (leading dense layers, window "
-                         "and full attention mixed), conv layers and a "
-                         "tied head have no pipeline schedule yet: a "
+                         "and full attention mixed), conv and "
+                         "linear_attention layers, a partial RoPE, a "
+                         "gated shared expert and a tied head have no "
+                         "pipeline schedule yet: a "
                          "stage scans ONE attention layer program of "
                          "params['layers'], the last stage reads lm_head")
     if seq_axis and mesh.shape.get(seq_axis, 1) > 1:
@@ -1078,9 +1254,16 @@ def _build_layer_body(c, mesh, seq_axis, constrain_acts=True, kind=None):
         return _constrain(x, mesh) if constrain_acts else x
 
     def layer(x, lp):
+        return ffn(x, mix(x, lp), lp)
+
+    def mix(x, lp, stage=lambda f: f):
+        """The token mixer on the stream (its norm first) -> what it
+        adds. ``stage``: ``_gated_delta_net``'s."""
         if mixer == "conv":
             h = _rmsnorm(x, lp["conv_norm"].astype(dt), c.norm_eps)
-            return ffn(x, _short_conv(h, lp, c), lp)
+            return _short_conv(h, lp, c)
+        if mixer == "linear":
+            return _gated_delta_net(x, lp, c, mesh, seq_axis, stage)
         # Shapes from x, not the enclosing scope: under pipelining the
         # layer sees microbatches smaller than the full batch.
         bb, tt = x.shape[0], x.shape[1]
@@ -1092,10 +1275,10 @@ def _build_layer_body(c, mesh, seq_axis, constrain_acts=True, kind=None):
         # backward skip the wq/wk/wv matmul + rope re-runs entirely
         # (attn_out/flash_o already cover wo's operands).
         if rope:
-            q = _rope(q, positions, c.rope_theta)
+            q = _rope(q, positions, c.rope_theta, c.partial_rotary)
         q = checkpoint_name(q, "rope_q")
         if rope:
-            kk = _rope(kk, positions, c.rope_theta)
+            kk = _rope(kk, positions, c.rope_theta, c.partial_rotary)
         kk = checkpoint_name(kk, "rope_k")
         vv = checkpoint_name(vv, "attn_v")
         # remat="attn" save-names applied inside _attention (per path).
@@ -1106,7 +1289,7 @@ def _build_layer_body(c, mesh, seq_axis, constrain_acts=True, kind=None):
             if c.attn_gate:
                 attn = attn * jax.nn.sigmoid(h @ lp["wg"].astype(dt))
             attn = attn @ lp["wo"].astype(dt)
-        return ffn(x, attn, lp)
+        return attn
 
     def ffn(x, mixed, lp):
         """The mixer's output joins the stream; then the FFN's."""
@@ -1138,6 +1321,29 @@ def _build_layer_body(c, mesh, seq_axis, constrain_acts=True, kind=None):
             layer,
             policy=jax.checkpoint_policies.save_only_these_names(
                 "attn_out", "flash_o", "flash_lse"))
+    elif c.remat == "attn/ffn":
+        # "attn" with the mixer and the FFN each under a checkpoint of
+        # its own (the mixer's output, [B,T,D], is saved between them),
+        # and a linear_attention mixer under two (what the rule reads,
+        # q, k, v, z and the gates, saved between them): the backward
+        # pass recomputes and differentiates the FFN, drops its
+        # residuals, and only then recomputes the mixer, stage by stage.
+        # A checkpoint's recomputation waits for its cotangent, so the
+        # stages' residuals are never alive together; under one
+        # checkpoint a layer they all are. Three linear_attention
+        # layers and one attention layer beside a share of the experts
+        # at 2 x 8192 tokens, the grad program's temporaries compiled
+        # for the described v5e (PR 38): "attn" 11.35 GiB, the mixer
+        # under ONE checkpoint 9.00, this 7.74. No FLOP more.
+        once = partial(jax.checkpoint,
+                       policy=jax.checkpoint_policies.save_only_these_names(
+                           "attn_out", "flash_o", "flash_lse"))
+        mix_once = partial(mix, stage=once) if mixer == "linear" \
+            else once(mix)
+        ffn_once = once(ffn)
+
+        def body(x, lp):
+            return ffn_once(x, mix_once(x, lp), lp)
     elif c.remat in ("attn+moe", "moe") and not _grouped_dispatch(c, mesh):
         # These modes save residuals only grouped_moe_ffn emits; under
         # GShard dispatch (mesh present or moe_impl="gshard") or a
@@ -1208,9 +1414,9 @@ def _build_layer_body(c, mesh, seq_axis, constrain_acts=True, kind=None):
         body = jax.checkpoint(layer)
     else:
         raise ValueError(f"unknown remat mode {c.remat!r}: expected "
-                         "True/'full', 'dots', 'attn', 'attn+gate', "
-                         "'attn+gate+qkv', 'attn+ffn', 'attn+moe', "
-                         "'moe', or False/'none'")
+                         "True/'full', 'dots', 'attn', 'attn/ffn', "
+                         "'attn+gate', 'attn+gate+qkv', 'attn+ffn', "
+                         "'attn+moe', 'moe', or False/'none'")
 
     return body
 

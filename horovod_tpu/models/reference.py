@@ -1,10 +1,12 @@
 """Plain float32 references of the architectures the llama path runs
 beyond the dense decoder. What the program's kernels, sorts, scans and
 remat modes are compared against (tests/single/test_olmoe_reference.py,
-tests/single/test_afmoe_reference.py, tests/single/test_lfm2_reference.py;
-the chip benchmark keeps copies of its own, chipbench/models/olmoe.py,
-afmoe.py and lfm2moe.py). OLMoE first; Trinity-Mini (afmoe) and
-LFM2-8B-A1B (lfm2_moe) below it, each with its own description.
+tests/single/test_afmoe_reference.py, tests/single/test_lfm2_reference.py,
+tests/single/test_qwen3next_reference.py; the chip benchmark keeps
+copies of its own, chipbench/models/olmoe.py, afmoe.py, lfm2moe.py and
+qwen3next.py). OLMoE first; Trinity-Mini (afmoe), LFM2-8B-A1B
+(lfm2_moe) and Qwen3-Next-80B-A3B (qwen3_next) below it, each with its
+own description.
 
 OLMoE (arXiv:2409.02060; Hugging Face ``modeling_olmoe.py``), as
 published:
@@ -434,6 +436,190 @@ def lfm2_loss(params, batch, cfg, vocab_rows=None):
     removed. ``jax.grad`` of this is the reference gradient; the tied
     matrix's is the sum of its two uses."""
     logits = lfm2_forward(params, batch["tokens"], cfg)
+    logp = jax.nn.log_softmax(logits[..., :vocab_rows], -1)
+    nll = -jnp.take_along_axis(logp, batch["targets"][..., None],
+                               -1)[..., 0]
+    mask = batch.get("mask", jnp.ones_like(nll))
+    return jnp.sum(nll * mask) / jnp.sum(mask)
+
+
+# ---------------------------------------------------------------------
+# Qwen3-Next-80B-A3B (Qwen, ``model_type`` ``qwen3_next``; Hugging Face
+# ``modeling_qwen3_next.py``), as published. Pre-norm, two RMSNorms a
+# layer and one after the last, eps ``rms_norm_eps``, no bias anywhere:
+# ``x = x + Mixer(RMS(x))``, then ``x = x + MoE(RMS(x))``; every layer
+# is an expert layer. The norms of the residual stream and the q/k norms
+# are the zero-centred form ``x / rms(x) * (1 + w)``; the program's tree
+# stores ``g = 1 + w`` (the same function and gradient), which is what
+# this reference reads.
+#
+# - a ``linear_attention`` layer's mixer, Gated DeltaNet (``Hk`` key
+#   heads and ``Hv`` value heads of ``dk`` and ``dv``):
+#   1. ``[q, k, v, z] = h W_in`` (``gdn_in``, columns ``[q | k | v |
+#      z]``, each head by head), ``[b, a] = h W_ba`` (``gdn_ba``, ``[b |
+#      a]``). The published checkpoint groups the same columns by key
+#      head: a permutation of columns;
+#   2. ``u = [q, k, v]``; ``u' = SiLU(conv(u))``, depthwise, causal,
+#      ``conv_taps`` taps (``gdn_conv`` [taps, channels]), zero before
+#      position 0, no bias;
+#   3. ``beta = sigmoid(b)``; ``g = -exp(A_log) * softplus(a +
+#      dt_bias)``, one scalar a value head and token;
+#   4. ``q``, ``k`` L2-normalised over ``dk`` (``x * rsqrt(sum x^2 +
+#      1e-6)``), key head ``i`` serving value heads ``i * Hv/Hk .. +
+#      Hv/Hk - 1``; ``q`` scaled by ``dk^-1/2``;
+#   5. a value head's state ``S`` [dk, dv], ``S_0 = 0``; for each token
+#      ``t``: ``S <- exp(g_t) S``; ``r_t = v_t - S^T k_t``; ``S <- S +
+#      k_t (beta_t r_t)^T``; ``o_t = S^T q_t``;
+#   6. ``y = RMS_dv(o; w) * SiLU(z)`` a head (this norm's gain is plain
+#      ``w``), then ``y W_o`` (``gdn_out``);
+# - a ``full_attention`` layer's: ``[q, gate] = h W_q`` (the program's
+#   tree: ``wq`` and ``wg``, the same linear map under another layout),
+#   ``k = h W_k``, ``v = h W_v``; RMSNorm of ``q`` and ``k`` over each
+#   head's ``head_dim``, one gain shared by the heads; half-split RoPE
+#   on the FIRST ``partial_rotary`` dimensions of a head, the rest pass;
+#   causal ``softmax(q k / sqrt(head_dim)) v`` with grouped key/value
+#   heads; ``(attn * sigmoid(gate)) W_o``;
+# - the expert layer: ``p = softmax(h W_r)`` over all experts, the K
+#   largest renormalised to sum 1 (``norm_topk_prob``), ``y = sum_k p_k
+#   SwiGLU_k(h) + sigmoid(h w_sg) * SwiGLU_shared(h)`` (``shared_score``
+#   [D, 1]);
+# - ``logits = RMS_final(x) W_head``, untied; loss = mean token
+#   cross-entropy.
+#
+# The share: as for afmoe above. Departures: no router aux loss (the
+# catalog's row gives no coefficient); the multi-token-prediction head
+# is left out; parameters stored in bf16 are read as float32. Step 5 is
+# a ``lax.scan`` over TOKENS exactly as written: no chunks, no WY form,
+# nothing of ops/gated_delta_rule.py.
+# ---------------------------------------------------------------------
+
+def qwen3next_delta_rule(q, k, v, g, beta):
+    """Step 5 for ``q``, ``k`` [B, T, H, dk], ``v`` [B, T, H, dv], ``g``,
+    ``beta`` [B, T, H], float32, one state a value head -> ``o`` [B, T,
+    H, dv]. Products as multiply-and-sum: float32 on any device."""
+    def token(S, x):
+        q, k, v, g, beta = x                         # [B, H, ...]
+        S = jnp.exp(g)[..., None, None] * S
+        r = v - jnp.sum(S * k[..., None], -2)
+        S = S + k[..., None] * (beta[..., None] * r)[..., None, :]
+        return S, jnp.sum(S * q[..., None], -2)
+
+    b, _, h, dk = q.shape
+    _, o = jax.lax.scan(
+        token, jnp.zeros((b, h, dk, v.shape[-1]), F32),
+        tuple(jnp.moveaxis(x, 1, 0) for x in (q, k, v, g, beta)))
+    return jnp.moveaxis(o, 0, 1)
+
+
+def qwen3next_gated_delta_net(h, lp, cfg):
+    """The ``linear_attention`` mixer on normalized ``h`` [B, T, D] with
+    one layer's float32 parameters: steps 1-6 above."""
+    b, t, _ = h.shape
+    hk, hv = cfg.linear_key_heads, cfg.linear_value_heads
+    dk, dv = cfg.linear_key_dim, cfg.linear_value_dim
+    kw, vw = hk * dk, hv * dv
+    qkvz, ba = h @ lp["gdn_in"], h @ lp["gdn_ba"]
+    u, z = qkvz[..., :2 * kw + vw], qkvz[..., 2 * kw + vw:]
+    taps, conv = lp["gdn_conv"].shape[0], jnp.zeros_like(u)
+    for j in range(taps):
+        back = taps - 1 - j                  # u as it was ``back`` ago
+        conv = conv + lp["gdn_conv"][j] * jnp.concatenate(
+            [jnp.zeros((b, back, u.shape[-1]), F32), u[:, :t - back]], 1)
+    u = jax.nn.silu(conv)
+
+    def unit(x):
+        return x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + 1e-6)
+
+    q = unit(u[..., :kw].reshape(b, t, hk, dk)) * dk ** -0.5
+    k = unit(u[..., kw:2 * kw].reshape(b, t, hk, dk))
+    v = u[..., 2 * kw:].reshape(b, t, hv, dv)
+    beta = jax.nn.sigmoid(ba[..., :hv])
+    g = -jnp.exp(lp["gdn_a_log"]) * jax.nn.softplus(
+        ba[..., hv:] + lp["gdn_dt_bias"])
+    o = qwen3next_delta_rule(jnp.repeat(q, hv // hk, 2),
+                             jnp.repeat(k, hv // hk, 2), v, g, beta)
+    y = _rms(o, lp["gdn_out_norm"], cfg.norm_eps) \
+        * jax.nn.silu(z.reshape(b, t, hv, dv))
+    return y.reshape(b, t, vw) @ lp["gdn_out"]
+
+
+def qwen3next_expert_layer(h, lp, cfg):
+    """The FFN of one layer on normalized ``h`` [B, T, D]: the routed
+    sum over the experts ``lp`` holds (``first_expert .. +
+    n_experts_held - 1``; all, where no share is set) plus the gated
+    shared expert."""
+    first = cfg.first_expert
+    held = cfg.n_experts_held or cfg.n_experts
+    w, _ = _top_k_weights(jax.nn.softmax(h @ lp["router"], -1),
+                          cfg.n_experts_per_token, cfg.norm_topk_prob)
+    act = jax.nn.silu(jnp.einsum("btd,edf->btef", h, lp["moe_gate"])) \
+        * jnp.einsum("btd,edf->btef", h, lp["moe_up"])
+    y = jnp.einsum("btef,efd->bted", act, lp["moe_down"])
+    return jnp.einsum("bte,bted->btd", w[..., first:first + held], y) \
+        + jax.nn.sigmoid(h @ lp["shared_score"]) * _swiglu(
+            h, lp["shared_gate"], lp["shared_up"], lp["shared_down"])
+
+
+def _qwen3next_layer(params, cfg, l):
+    """Layer ``l``'s parameters out of the program's tree, float32: the
+    linear_attention layers and the full_attention layers are stacked
+    apart (by this file's own count, not ``LlamaConfig.layer_plan``)."""
+    def stack(i):
+        return ("linear_" if cfg.layer_types[i] == "linear_attention"
+                else "") + "layers"
+
+    at = sum(stack(i) == stack(l) for i in range(l))
+    return jax.tree.map(lambda w: w[at].astype(F32), params[stack(l)])
+
+
+def qwen3next_forward(params, tokens, cfg):
+    """tokens [B, T] -> logits [B, T, vocab] f32 (see the description
+    above). ``params`` is the program's tree, any storage dtype."""
+    hd, rot = cfg.head_dim, cfg.partial_rotary or cfg.head_dim
+    rep = cfg.n_heads // cfg.n_kv_heads
+    b, t = tokens.shape
+    inv = cfg.rope_theta ** (-jnp.arange(0, rot // 2, dtype=F32)
+                             / (rot // 2))
+    ang = jnp.arange(t, dtype=F32)[:, None] * inv           # [T, rot/2]
+    cos, sin = jnp.cos(ang)[None, :, None], jnp.sin(ang)[None, :, None]
+
+    def rope(x):
+        x1, x2, rest = x[..., :rot // 2], x[..., rot // 2:rot], x[..., rot:]
+        return jnp.concatenate([x1 * cos - x2 * sin,
+                                x1 * sin + x2 * cos, rest], -1)
+
+    mask = jnp.arange(t)[None, :] <= jnp.arange(t)[:, None]
+    with jax.default_matmul_precision("highest"):
+        x = params["embed"].astype(F32)[tokens]
+        for l in range(cfg.n_layers):
+            lp = _qwen3next_layer(params, cfg, l)
+            if cfg.layer_types[l] == "linear_attention":
+                x = x + qwen3next_gated_delta_net(
+                    _rms(x, lp["gdn_norm"], cfg.norm_eps), lp, cfg)
+            else:
+                h = _rms(x, lp["attn_norm"], cfg.norm_eps)
+                q = rope(_rms((h @ lp["wq"]).reshape(b, t, cfg.n_heads, hd),
+                              lp["q_norm"], cfg.norm_eps))
+                k = rope(_rms((h @ lp["wk"]).reshape(
+                    b, t, cfg.n_kv_heads, hd), lp["k_norm"], cfg.norm_eps))
+                v = (h @ lp["wv"]).reshape(b, t, cfg.n_kv_heads, hd)
+                k, v = jnp.repeat(k, rep, 2), jnp.repeat(v, rep, 2)
+                s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / (hd ** 0.5)
+                p = jax.nn.softmax(jnp.where(mask, s, -jnp.inf), -1)
+                a = jnp.einsum("bhqk,bkhd->bqhd", p, v).reshape(b, t, -1)
+                x = x + (a * jax.nn.sigmoid(h @ lp["wg"])) @ lp["wo"]
+            x = x + qwen3next_expert_layer(
+                _rms(x, lp["mlp_norm"], cfg.norm_eps), lp, cfg)
+        x = _rms(x, params["final_norm"].astype(F32), cfg.norm_eps)
+        return x @ params["lm_head"].astype(F32)
+
+
+def qwen3next_loss(params, batch, cfg, vocab_rows=None):
+    """Mean token cross-entropy over the positions ``batch["mask"]``
+    keeps (all without one); no aux term. ``vocab_rows``: the loss over
+    the first that many rows of the vocabulary, the other logits
+    removed. ``jax.grad`` of this is the reference gradient."""
+    logits = qwen3next_forward(params, batch["tokens"], cfg)
     logp = jax.nn.log_softmax(logits[..., :vocab_rows], -1)
     nll = -jnp.take_along_axis(logp, batch["targets"][..., None],
                                -1)[..., 0]

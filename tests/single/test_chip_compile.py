@@ -231,6 +231,23 @@ _TQ, _TKV = ((2, 8192, 32, 128), BF16), ((2, 8192, 4, 128), BF16)
                  (((32768, 1792), BF16), ((8, 1792, 2048), BF16),
                   ((8,), I32)),
                  id="megablox-gmm-lfm2moe-share-down"),
+    # Qwen3-Next-80B-A3B's full attention at the chip cell's size: heads
+    # 256 wide, 16 on 2 (no head over 128 and no group of 8 had run
+    # through the training kernels).
+    pytest.param(_flash_fwd_bwd,
+                 (((2, 8192, 16, 256), BF16),)
+                 + (((2, 8192, 2, 256), BF16),) * 2,
+                 id="flash-fwd+bwd-qwen3next-b2s8192"),
+    # Its share: one chunk of 20,480 sorted slots into the 32 experts
+    # held, expert width 512 = half of the 1024 tile.
+    pytest.param(_gmm,
+                 (((20480, 2048), BF16), ((32, 2048, 512), BF16),
+                  ((32,), I32)),
+                 id="megablox-gmm-qwen3next-share-gate-up"),
+    pytest.param(_gmm,
+                 (((20480, 512), BF16), ((32, 512, 2048), BF16),
+                  ((32,), I32)),
+                 id="megablox-gmm-qwen3next-share-down"),
 ])
 def test_kernel_compiles_for_described_v5e(for_tpu, fn, shapes):
     assert "tpu_custom_call" in for_tpu(fn, *shapes)
@@ -256,6 +273,10 @@ _LANE_PADDED = re.compile(r"f32\[[\d,]+,1\]\{[^}]*T\(8,128\)[^}]*\}")
                  (((2, 8192, 32, 64), BF16),)
                  + (((2, 8192, 8, 64), BF16),) * 2,
                  "f32[2,32,1,8192]", id="lfm2moe-b2s8192-heads64"),
+    pytest.param(_flash_fwd_bwd,
+                 (((2, 8192, 16, 256), BF16),)
+                 + (((2, 8192, 2, 256), BF16),) * 2,
+                 "f32[2,16,1,8192]", id="qwen3next-b2s8192-heads256"),
     pytest.param(_flash_chunk,
                  (((1, 16, 2048, 128), BF16), ((1, 4, 2048, 128), BF16),
                   ((1, 4, 2048, 128), BF16), ((), I32), ((), I32)),
@@ -275,6 +296,29 @@ def test_flash_row_statistics_cross_hbm_lane_dense(for_tpu, fn, shapes,
     assert text.count("tpu_custom_call") >= 2
     assert not _LANE_PADDED.findall(text)
     assert statistic in text
+
+
+def _delta_rule_fwd_bwd(q, k, v, g, beta):
+    from horovod_tpu.ops.gated_delta_rule import gated_delta_rule
+
+    return jax.grad(lambda *a: gated_delta_rule(*a).astype(F32).sum(),
+                    argnums=(0, 1, 2, 3, 4))(q, k, v, g, beta)
+
+
+def test_gated_delta_rule_compiles_for_described_v5e(for_tpu):
+    """Qwen3-Next's delta rule at the chip cell's size (B2 T8192, 32
+    value heads of 128), forward and backward, as the chip's compiler
+    takes it: no kernel of ours (plain jax.numpy), so what is held to is
+    the chunked form: the scans run over the 128 CHUNKS (their states
+    [128, 2, 32, 128, 128] float32), no operand is token-major (a scan
+    over the 8192 tokens would slice one), and the triangular systems
+    are 64 wide."""
+    head = ((2, 8192, 32, 128), BF16)
+    gate = ((2, 8192, 32), F32)
+    text = for_tpu(_delta_rule_fwd_bwd, head, head, head, gate, gate)
+    assert "f32[128,2,32,128,128]" in text        # the states kept
+    assert "[8192,2,32," not in text              # no token-major scan
+    assert re.search(r"f32\[2,128,32,(1,)?64,64\]", text)   # (I + A)^-1
 
 
 def test_a_stack_under_remat_attn_holds_no_padded_statistics(for_tpu):
