@@ -1161,10 +1161,11 @@ def _run_layers(params, x, c, mesh, seq_axis):
     bodies = {kind: _build_layer_body(c, mesh, seq_axis, kind=kind)
               for kind in kinds}
     # A SHARE of the experts (``n_experts_held``) gets slices: its later
-    # chunks run under a ``lax.scan``, whose transpose carries a gradient
-    # accumulator the shape of whatever the body closes over, and whole
-    # stacks there raised the Trinity-Mini cell's grad program from 9.64
-    # to 14.03 GB (compiled for the described v5e, PR 33).
+    # chunks are a loop whose backward carries a gradient accumulator the
+    # shape of each operand it is handed (``grouped_moe._later_chunks``;
+    # a transposed ``lax.scan`` before it), and whole stacks there raised
+    # the Trinity-Mini cell's grad program from 9.64 to 14.03 GB
+    # (compiled for the described v5e, PR 33).
     whole = _EXPERT_MATRICES \
         if _grouped_dispatch(c, mesh) and not c.n_experts_held else ()
     balance = []

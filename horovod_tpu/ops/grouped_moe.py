@@ -434,6 +434,94 @@ def _combine_held_bwd(n_tokens, res, g):
 _combine_held.defvjp(_combine_held_fwd, _combine_held_bwd)
 
 
+def held_chunks(n, chunk_rows, chunks):
+    """How many of a layer's ``chunks`` chunks of ``chunk_rows`` sorted
+    slots run when it holds ``n`` rows: the first always, a later one
+    only if a held row lies in it, ``clip(ceil(n / chunk_rows), 1,
+    chunks)``. Less one it is the trip count of the later chunks' loop,
+    forward and backward (``docs/metrics.md``)."""
+    return jnp.clip((n + chunk_rows - 1) // chunk_rows, 1, chunks)
+
+
+def _held_chunk(c, R, hf, gate, up, down, w_sorted, order, ends, n, start):
+    """Sorted slots ``start .. start + R - 1`` of a share's layer
+    (:func:`_held_experts_ffn`) -> their part of the sum, [S, D]."""
+    S, K = hf.shape[0], c.n_experts_per_token
+    dt = c.compute_dtype
+    tok = lax.dynamic_slice_in_dim(order, start, R) // K
+    held = jnp.clip(n - start, 0, R)
+    w = jnp.where(lax.iota(jnp.int32, R) < held,
+                  lax.dynamic_slice_in_dim(w_sorted, start, R), 0)
+    # each group's rows that fall inside this chunk
+    sizes = jnp.diff(jnp.clip(ends, start, start + R), prepend=start)
+    x_sorted = _dispatch_held(hf.astype(dt), tok, held)
+    gate_pre = checkpoint_name(
+        _grouped_mm(x_sorted, gate.astype(dt), sizes), "moe_gate_act")
+    up = checkpoint_name(
+        _grouped_mm(x_sorted, up.astype(dt), sizes), "moe_up_act")
+    with scope("hvd.moe.experts"):
+        act = jax.nn.silu(gate_pre) * up * w[:, None]
+    y_sorted = _grouped_mm(act, down.astype(dt), sizes)
+    return _combine_held(y_sorted, tok, held, S)
+
+
+# The chunks after the first: ``y`` plus each chunk that held rows
+# reach, in chunk order. A loop whose trip count follows the rows held
+# (``held_chunks``), forward and backward, so a chunk that no row
+# reaches is no iteration at all: legal for the reason ``_gather_held``'s
+# loop is, and the reason for the custom VJP (a ``lax.scan`` of a
+# ``lax.cond``, differentiated, stacks every operand once an iteration
+# as a residual and re-sums every cotangent, whether the chunk runs or
+# not: 1.88 GB a layer of the Qwen3-Next cell, where none ever does;
+# PERF.md section 6, PR 41). Nothing is saved but the operands, which
+# live anyway; the backward runs each chunk again from them and pulls
+# the cotangent through it, the last chunk first as a transposed scan
+# would, into accumulators that start from zero: one zero cotangent an
+# operand is what a layer still pays when no later chunk runs.
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _later_chunks(c, R, y, operands, slots):
+    order, _, n = slots
+
+    def add(i, y):
+        return y + _held_chunk(c, R, *operands, *slots, i * R)
+
+    with scope("hvd.moe.combine"):   # the later chunks' sums join y
+        return lax.fori_loop(1, held_chunks(n, R, order.shape[0] // R),
+                             add, y)
+
+
+def _later_chunks_fwd(c, R, y, operands, slots):
+    return _later_chunks(c, R, y, operands, slots), (operands, slots)
+
+
+def _later_chunks_bwd(c, R, res, g):
+    operands, slots = res
+    order, _, n = slots
+    ran = held_chunks(n, R, order.shape[0] // R)
+
+    def pull(i, cts):
+        _, vjp = jax.vjp(
+            lambda *ops: _held_chunk(c, R, *ops, *slots, (ran - i) * R),
+            *operands)
+        return jax.tree.map(jnp.add, cts, vjp(g))
+
+    def zeros():
+        return jax.tree.map(jnp.zeros_like, operands)
+
+    # Born in a branch, as ``_gather_held``'s buffer is: zero-filled in
+    # the entry computation the accumulators raised the LFM2 cell's peak
+    # by 0.15 GB on the chip (its grad program as compiled for the
+    # described v5e 7.39 GB where the parent's and this read 7.12).
+    with scope("hvd.moe.combine"):
+        cts = lax.cond(ran > 1,
+                       lambda: lax.fori_loop(1, ran, pull, zeros()), zeros)
+    return g, cts, None
+
+
+_later_chunks.defvjp(_later_chunks_fwd, _later_chunks_bwd)
+
+
 @scope("hvd.moe.dispatch")
 def _held_experts_ffn(hf, lp, c, gate_vals, gate_idx):
     """The routed part of an expert layer that holds experts
@@ -448,10 +536,12 @@ def _held_experts_ffn(hf, lp, c, gate_vals, gate_idx):
     worked off in chunks of R rows, R = ``_HELD_ROW_BOUND`` x the even
     share (S*K*held/E): gathered out of ``hf``, through the three
     grouped GEMMs (which visit the tiles of their groups and nothing
-    else), summed back per token. The first chunk always runs; a further
-    one runs only if held rows reach it (``lax.cond`` in a ``lax.scan``
-    over the rest of the S*K slots), so every temporary is R rows long
-    at any load. Within a chunk the gathers run over blocks of
+    else), summed back per token. The first chunk always runs, under
+    plain autodiff; the others are a loop that runs as many times as
+    held rows reach a further chunk (``held_chunks``; ``_later_chunks``,
+    a custom VJP whose backward is the same loop), so every temporary
+    is R rows long at any load and a chunk nobody's rows reach costs
+    nothing. Within a chunk the gathers run over blocks of
     ``_HELD_BLOCK`` rows and stop after the last block a held row lies
     in (``held_blocks``): the layer gathers the rows it holds, rounded
     up to a block, not R. The scatter-adds take all R rows, those from
@@ -464,47 +554,16 @@ def _held_experts_ffn(hf, lp, c, gate_vals, gate_idx):
     (order, _), w_sorted = _sort_slots(
         key, gate_vals.astype(dt).reshape(S * K))
     ends = jnp.cumsum(_group_sizes(key, H))
-    n = ends[-1]
     chunks = max(c.n_experts // (H * _HELD_ROW_BOUND), 1)
     if (S * K) % chunks:
         chunks = 1
     R = S * K // chunks
-
-    def chunk(start):
-        """Sorted slots ``start .. start + R - 1`` -> [S, D]."""
-        tok = lax.dynamic_slice_in_dim(order, start, R) // K
-        held = jnp.clip(n - start, 0, R)
-        w = jnp.where(lax.iota(jnp.int32, R) < held,
-                      lax.dynamic_slice_in_dim(w_sorted, start, R), 0)
-        # each group's rows that fall inside this chunk
-        sizes = jnp.diff(jnp.clip(ends, start, start + R), prepend=start)
-        x_sorted = _dispatch_held(hf.astype(dt), tok, held)
-        gate_pre = checkpoint_name(
-            _grouped_mm(x_sorted, lp["moe_gate"].astype(dt), sizes),
-            "moe_gate_act")
-        up = checkpoint_name(
-            _grouped_mm(x_sorted, lp["moe_up"].astype(dt), sizes),
-            "moe_up_act")
-        with scope("hvd.moe.experts"):
-            act = jax.nn.silu(gate_pre) * up * w[:, None]
-        y_sorted = _grouped_mm(act, lp["moe_down"].astype(dt), sizes)
-        return _combine_held(y_sorted, tok, held, S)
-
-    y = chunk(0)
+    operands = (hf, lp["moe_gate"], lp["moe_up"], lp["moe_down"], w_sorted)
+    slots = (order, ends, ends[-1])
+    y = _held_chunk(c, R, *operands, *slots, 0)
     if chunks == 1:
         return y
-
-    # Saves nothing: a later chunk is the rare path, and a skipped one
-    # must not cost R rows of zero residuals.
-    later = jax.checkpoint(chunk)
-
-    def rest(y, start):
-        return lax.cond(start < n, lambda y: y + later(start),
-                        lambda y: y, y), None
-
-    with scope("hvd.moe.combine"):   # the later chunks' sums join y
-        return lax.scan(rest, y,
-                        R * jnp.arange(1, chunks, dtype=jnp.int32))[0]
+    return _later_chunks(c, R, y, operands, slots)
 
 
 def grouped_moe_ffn(h, lp, c):

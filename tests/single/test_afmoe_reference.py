@@ -235,11 +235,12 @@ def test_no_held_slot_is_dropped_at_any_load(load):
         assert np.all((held > 0) & (held <= chunk))
 
 
-def _share_layer(rows):
-    """One expert layer that holds experts 4 and 5 of 16, 256 tokens,
-    and a hand-made routing that sends exactly ``rows`` of the 1024
-    slots to them (chunks of 256)."""
-    cfg = _cfg(n_experts_held=2, first_expert=4)
+def _share_layer(rows, held=2):
+    """One expert layer that holds experts 4 and 5 of 16 (``held`` of
+    them from 4 on), 256 tokens, and a hand-made routing that sends
+    exactly ``rows`` of the 1024 slots to experts 4 and 5 (chunks of
+    256 where two are held)."""
+    cfg = _cfg(n_experts_held=held, first_expert=4)
     lp = jax.tree.map(lambda w: w[0], _params(cfg)["layers"])
     ks = jax.random.split(jax.random.PRNGKey(rows), 3)
     hf = jax.random.normal(ks[0], (256, cfg.d_model))
@@ -292,28 +293,39 @@ def test_the_chunks_of_the_share_cover_exactly_the_held_rows(
             assert not np.any(np.asarray(g)), name
 
 
-def _row_movements(jaxpr, inside_while=False):
+def _equations(jaxpr, inside_while=False):
+    """(equation, inside a ``while``) of ``jaxpr`` and the jaxprs it
+    holds."""
+    for eqn in jaxpr.eqns:
+        yield eqn, inside_while
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _equations(
+                sub, inside_while or eqn.primitive.name == "while")
+
+
+def _row_movements(jaxpr):
     """(primitive, rows moved, inside a ``while``) of every gather and
     scatter-add of whole rows in ``jaxpr`` and the jaxprs it holds."""
-    for eqn in jaxpr.eqns:
+    for eqn, inside_while in _equations(jaxpr):
         name = eqn.primitive.name
         if name in ("gather", "scatter-add"):
             moved = (eqn.outvars[0] if name == "gather"
                      else eqn.invars[2]).aval
             if moved.ndim == 2:
                 yield name, moved.shape[0], inside_while
-        for sub in jax.core.jaxprs_in_params(eqn.params):
-            yield from _row_movements(sub, inside_while or name == "while")
 
 
 def test_the_shares_gathers_are_no_chunk_long_and_its_sums_are(monkeypatch):
     """A count: in the jaxpr of the share's gradient every gather of
     rows runs inside a ``while`` over blocks of 64 rows (two in the
-    first chunk; three in the later chunks' ``cond``, whose checkpoint
+    first chunk; three in the later chunks' loops, whose backward
     gathers the rows again), none over a chunk of 256; the scatter-adds
-    (two and two: the checkpoint has no use for a second sum) take a
-    chunk each, outside any loop: in blocks they cost three times as
-    much a row on the chip (``grouped_moe._sum_held``)."""
+    take a chunk each: in blocks they cost three times as much a row on
+    the chip (``grouped_moe._sum_held``). The first chunk's two stand
+    outside any loop; the later chunks' three inside the loop over the
+    chunks that held rows reach, one forward and two backward (the sum
+    of the chunk run again, which nothing reads and the compiler drops,
+    and the tokens' gradient)."""
     monkeypatch.setattr(grouped_moe, "_HELD_BLOCK", 64)
     cfg, lp, hf, idx, w = _share_layer(700)
 
@@ -323,7 +335,38 @@ def test_the_shares_gathers_are_no_chunk_long_and_its_sums_are(monkeypatch):
     moves = sorted(_row_movements(
         jax.make_jaxpr(jax.grad(loss, (0, 1, 2)))(hf, w, lp).jaxpr))
     assert moves == [("gather", 64, True)] * 5 \
-        + [("scatter-add", 256, False)] * 4, moves
+        + [("scatter-add", 256, False)] * 2 \
+        + [("scatter-add", 256, True)] * 3, moves
+
+
+@pytest.mark.parametrize("held,chunks", [(4, 2), (2, 4), (1, 8)])
+def test_nothing_is_stacked_by_chunk(held, chunks):
+    """The later chunks are a loop whose trip count follows the rows
+    held, not a differentiated ``lax.scan`` over all of them (which
+    stacked the tokens and the three expert matrices once a later chunk
+    as residuals, 1.88 GB a layer of the Qwen3-Next cell, whether a
+    chunk ran or not): in the jaxpr of the share's gradient, at the
+    cells' 2, 4 and 8 chunks, no ``scan``, and no value of the tokens'
+    or an expert matrix's shape with a leading ``chunks - 1`` (or
+    ``chunks``)."""
+    cfg, lp, hf, idx, w = _share_layer(700, held=held)
+    assert cfg.n_experts // (held * grouped_moe._HELD_ROW_BOUND) == chunks
+
+    def loss(hf, w, lp):
+        return jnp.sum(grouped_moe._held_experts_ffn(hf, lp, cfg, w, idx))
+
+    eqns = [eqn for eqn, _ in _equations(
+        jax.make_jaxpr(jax.grad(loss, (0, 1, 2)))(hf, w, lp).jaxpr)]
+    names = {eqn.primitive.name for eqn in eqns}
+    assert "scan" not in names and "while" in names
+    whole = {hf.shape} | {lp[k].shape for k in
+                          ("moe_gate", "moe_up", "moe_down")}
+    shapes = [(eqn.primitive.name, getattr(v.aval, "shape", ()))
+              for eqn in eqns for v in eqn.outvars]
+    stacked = [(name, s) for name, s in shapes
+               if len(s) > 2 and s[0] in (chunks - 1, chunks)
+               and s[1:] in whole]
+    assert not stacked, stacked
 
 
 def test_a_share_needs_the_grouped_dispatch():

@@ -463,6 +463,46 @@ def test_a_shares_gather_buffer_is_born_in_a_branch_and_never_zero_filled(
     assert len(re.findall(r"= bf16\[32768,2048\]\S* broadcast\(", text)) == 1
 
 
+def test_a_shares_gradient_holds_nothing_stacked_by_chunk(for_tpu):
+    """The gradient of a share's expert layer at the Qwen3-Next cell's
+    shape (16,384 tokens, 32 of 512 experts held, 10 a token: 8 chunks
+    of 20,480 slots) as the chip's compiler emits it (PR 41): the seven
+    later chunks are one ``while`` forward and one backward (in the
+    branch its accumulators are born in) whose trip count follows the
+    rows held, with no tokens and no expert matrix
+    stacked seven times as a residual (a differentiated ``lax.scan``
+    over them carried 1.88 GB of those, 3.49 GB of temporaries in
+    all)."""
+    from horovod_tpu.models import LlamaConfig
+    from horovod_tpu.ops import grouped_moe
+
+    S, D, F, E, H, K = 16384, 2048, 512, 512, 32, 10
+    cfg = LlamaConfig(vocab_size=512, d_model=D, n_layers=1, n_heads=16,
+                      n_kv_heads=2, d_ff=F, n_experts=E,
+                      n_experts_per_token=K, n_experts_held=H,
+                      moe_impl="grouped", dtype="bfloat16",
+                      param_dtype="bfloat16")
+
+    def loss(hf, w, gate, up, down, idx):
+        lp = {"moe_gate": gate, "moe_up": up, "moe_down": down}
+        y = grouped_moe._held_experts_ffn(hf, lp, cfg, w, idx)
+        return jnp.sum(jnp.square(y.astype(F32)))
+
+    exe = for_tpu.executable(
+        jax.grad(loss, (0, 1, 2, 3, 4)), ((S, D), BF16), ((S, K), BF16),
+        ((H, D, F), BF16), ((H, D, F), BF16), ((H, F, D), BF16),
+        ((S, K), I32))
+    text = exe.as_text()
+    loops = re.findall(r' while\(.*op_name="[^"]*?(transpose\()?jvp'
+                       r'[^"]*hvd\.moe\.combine/(?:cond/branch_1_fun/)?while"',
+                       text)
+    assert sorted(loops) == ["", "transpose("], loops
+    for stacked in ("[7,16384,2048]", "[7,32,2048,512]", "[7,32,512,2048]",
+                    "[8,16384,2048]", "[8,32,2048,512]", "[8,32,512,2048]"):
+        assert stacked not in text, stacked
+    assert exe.memory_analysis().temp_size_in_bytes < 2.0e9
+
+
 def test_grad_program_of_a_one_layer_llama_holds_one_flash_bwd_call(
         v5e_chip, monkeypatch):
     """``jit_hvd_grad`` as the split step lowers it for the described

@@ -300,6 +300,27 @@ def test_held_blocks_is_the_trip_count_of_the_gathers(n, start, blocks):
     assert blocks == -(-min(max(n - start, 0), 32768) // 2048)
 
 
+@pytest.mark.parametrize("chunks", [2, 4, 8])
+@pytest.mark.parametrize("n", ["none", "a row", "a chunk", "a chunk and a row",
+                               "two chunks", "all"])
+def test_held_chunks_is_the_trip_count_of_the_chunk_loop(n, chunks):
+    """``clip(ceil(n / R), 1, chunks)`` at the edges, at the cells' 2
+    (LFM2, R = 32,768), 4 (Trinity-Mini, 32,768) and 8 chunks
+    (Qwen3-Next, 20,480): no row and one row run the first chunk alone,
+    as a full chunk does; one row more reaches the second; every slot
+    held runs them all. The later chunks' loop runs from 1 to it."""
+    R = 20480 if chunks == 8 else 32768
+    n, ran = {"none": (0, 1), "a row": (1, 1), "a chunk": (R, 1),
+              "a chunk and a row": (R + 1, 2), "two chunks": (2 * R, 2),
+              "all": (chunks * R, chunks)}[n]
+    got = grouped_moe.held_chunks(jnp.int32(n), R, chunks)
+    assert got.dtype == jnp.int32 and int(got) == ran
+    assert ran == min(max(-(-n // R), 1), chunks)
+    # every held row lies in a chunk that runs, and the last one that
+    # runs holds one (but the first, which always runs)
+    assert n <= ran * R and (ran == 1 or n > (ran - 1) * R)
+
+
 def test_a_chunk_the_block_does_not_divide_is_gathered_as_one_block():
     assert grouped_moe._HELD_BLOCK == 2048 and 32768 % 2048 == 0
     assert grouped_moe._block_rows(32768) == 2048
