@@ -305,20 +305,51 @@ def _delta_rule_fwd_bwd(q, k, v, g, beta):
                     argnums=(0, 1, 2, 3, 4))(q, k, v, g, beta)
 
 
+_RULE_HEAD, _RULE_GATE = ((2, 8192, 32, 128), BF16), ((2, 8192, 32), F32)
+_RULE_KERNELS = ("hvd_gdn_state_fwd", "hvd_gdn_state_bwd")
+
+
 def test_gated_delta_rule_compiles_for_described_v5e(for_tpu):
     """Qwen3-Next's delta rule at the chip cell's size (B2 T8192, 32
     value heads of 128), forward and backward, as the chip's compiler
-    takes it: no kernel of ours (plain jax.numpy), so what is held to is
-    the chunked form: the scans run over the 128 CHUNKS (their states
-    [128, 2, 32, 128, 128] float32), no operand is token-major (a scan
-    over the 8192 tokens would slice one), and the triangular systems
-    are 64 wide."""
-    head = ((2, 8192, 32, 128), BF16)
-    gate = ((2, 8192, 32), F32)
-    text = for_tpu(_delta_rule_fwd_bwd, head, head, head, gate, gate)
+    takes it: the state-carrying pass is the kernel pair, each by the
+    name a device trace shows (``kernel_metadata``), and no ``while``
+    over chunks is left; the states kept are the 128 CHUNKS' ([128, 2,
+    32, 128, 128] float32), no operand is token-major (a scan over the
+    8192 tokens would slice one), and the triangular systems are 64
+    wide."""
+    text = for_tpu(_delta_rule_fwd_bwd, _RULE_HEAD, _RULE_HEAD, _RULE_HEAD,
+                   _RULE_GATE, _RULE_GATE)
+    for name in _RULE_KERNELS:
+        assert f'"kernel":"{name}"' in text, name
+    assert " while(" not in text
     assert "f32[128,2,32,128,128]" in text        # the states kept
     assert "[8192,2,32," not in text              # no token-major scan
     assert re.search(r"f32\[2,128,32,(1,)?64,64\]", text)   # (I + A)^-1
+
+
+def test_three_layers_of_the_rule_lower_each_kernel_once(for_tpu, v5e_chip):
+    """The set-up budget's guard (PERF.md section 6, PR 44). A
+    ``pallas_call`` is lowered to Mosaic at every site of every trace,
+    compile cache or not; behind ONE jitted wrapper the three layers'
+    forward, forward again under remat, and backward lower to one
+    private function a kernel form that every site calls: the backward
+    once, the forward twice (keeping the states, and not)."""
+    from horovod_tpu.ops.gated_delta_rule import gated_delta_rule
+
+    def loss(q, k, v, g, beta):
+        for _ in range(3):
+            v = jax.checkpoint(gated_delta_rule)(q, k, v, g, beta)
+        return v.astype(F32).sum()
+
+    args = [jax.ShapeDtypeStruct(s, d, sharding=v5e_chip)
+            for s, d in (_RULE_HEAD,) * 3 + (_RULE_GATE,) * 2]
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        *args).as_text()
+    assert [text.count(name) for name in _RULE_KERNELS] == [2, 1]
+    assert text.count("tpu_custom_call") == 3
+    assert text.count("call @_kernel_fwd") >= 5   # the sites are calls
+    assert text.count("call @_kernel_bwd") == 3
 
 
 def test_a_stack_under_remat_attn_holds_no_padded_statistics(for_tpu):

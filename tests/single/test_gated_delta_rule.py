@@ -3,12 +3,20 @@ the recurrence token by token as it is written: forward and all five
 gradients, for one, two and several chunks, weak and strong decays,
 write strengths near 0 and near 1. Float32 on the CPU: the two differ in
 the order of float32 additions (and the chunked form's triangular
-solve), 2e-5 of the largest entry. Small sizes."""
+solve), 2e-5 of the largest entry. Small sizes.
+
+The state-carrying pass has two carriers, a ``lax.scan`` (the CPU's, and
+the reference here) and a Pallas kernel pair (a TPU's): the pair runs in
+interpret mode against the scan, values and all six gradients of
+``_carry_state``, and against the recurrence through the whole rule."""
+
+import functools
 
 import jax
 import jax.numpy as jnp
 import pytest
 
+from horovod_tpu.ops import gated_delta_rule as module
 from horovod_tpu.ops.gated_delta_rule import CHUNK, gated_delta_rule
 
 pytestmark = pytest.mark.quick
@@ -122,3 +130,129 @@ def test_a_sequence_that_is_no_multiple_of_the_chunk_is_refused():
     ref = token_by_token(q[:, :48], k[:, :48], v[:, :48], g[:, :48],
                          beta[:, :48])
     assert float(jnp.max(jnp.abs(out - ref))) < 1e-5
+
+
+# ---------------------------------------------------------------------
+# The kernel pair, interpreted, against the scan and the recurrence.
+# ---------------------------------------------------------------------
+
+@pytest.fixture
+def kernels(monkeypatch):
+    """-> ``take(heads)``: from then on the rule's state pass is the
+    kernel pair in interpret mode, a grid step taking at most so many
+    heads."""
+    def take(heads):
+        monkeypatch.setattr(module, "_INTERPRET", True)
+        monkeypatch.setattr(module, "HEADS_A_STEP", heads)
+    return take
+
+
+@functools.partial(jax.jit, static_argnums=tuple(range(7)))
+def _state_operands(b, n, h, c, dk, dv, dtype):
+    """``_carry_state``'s six, as the factor stage lays them."""
+    ks = jax.random.split(jax.random.PRNGKey(n), 7)
+
+    def normal(key, *shape):
+        return (0.3 * jax.random.normal(key, (b, n, h) + shape)
+                ).astype(dtype)
+
+    return (normal(ks[0], c, dk), normal(ks[1], c, c), normal(ks[2], c, dv),
+            normal(ks[3], c, dk), normal(ks[4], c, dk),
+            jax.random.uniform(ks[5], (b, n, h), minval=0.3, maxval=1.0),
+            normal(ks[6], c, dv))
+
+
+@jax.jit
+def _state_readings(*operands):
+    """(o, and the gradients of sum(o * weight) in the six)."""
+    *x, weight = operands
+
+    def f(*x):
+        o = module._carry_state(*x)
+        return jnp.sum(o.astype(jnp.float32)
+                       * weight.astype(jnp.float32)), o
+
+    grads, o = jax.grad(f, argnums=tuple(range(6)), has_aux=True)(*x)
+    return (o,) + grads
+
+
+STATE_NAMES = ("o", "dqg", "dp", "du", "dw", "dkd", "ddc")
+
+
+# (chunks, heads, heads a step): heads a step that divide the heads, and
+# that do not (three by two, six by four: the largest divisor under it);
+# one chunk alone runs through the whole rule, below
+@pytest.mark.parametrize("dtype,n,h,heads", [
+    (jnp.float32, 3, 3, 2), (jnp.bfloat16, 3, 4, 2), (jnp.bfloat16, 5, 6, 4)])
+def test_the_kernel_pair_is_the_scan(kernels, dtype, n, h, heads):
+    """Same operands, same roundings, float32 state in both: ``o`` and
+    five gradients to the last bit of the operands' dtype; ``dc``'s,
+    summed in another order, to float32 rounding. ``dk`` 16 beside
+    ``dv`` 32."""
+    operands = _state_operands(1, n, h, 16, 16, 32, dtype)
+    _state_readings.clear_cache()     # the carrier is chosen in a trace
+    ref = _state_readings(*operands)
+    kernels(heads)
+    _state_readings.clear_cache()
+    got = _state_readings(*operands)
+    for name, a, b in zip(STATE_NAMES, got, ref):
+        assert a.dtype == b.dtype and a.shape == b.shape, name
+        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+        err = float(jnp.max(jnp.abs(a - b)))
+        scale = float(jnp.max(jnp.abs(b)))
+        assert err <= (2e-6 * scale if name == "ddc" else 0.0), (name, err)
+
+
+def test_a_step_takes_a_divisor_of_the_heads(kernels):
+    qg = jnp.zeros((1, 1, 6, 16, 16))
+    for want, takes in ((8, 6), (4, 3), (2, 2), (1, 1)):
+        kernels(want)
+        assert module._step(qg) == {"hb": takes, "interpret": True}
+
+
+@pytest.mark.parametrize("chunks,heads", [(1, 8), (3, 2)])
+def test_the_rule_on_the_kernel_pair_is_the_recurrence(
+        kernels, chunks, heads):
+    """``gated_delta_rule``'s value and five gradients with the kernels
+    carrying the state, weak decay and strong, ``dk`` 16 beside ``dv``
+    24, three heads."""
+    kernels(heads)
+    # not ``_GRADS``: that jit has traced the rule on the scan
+    readings = jax.jit(jax.grad(_weighted(gated_delta_rule),
+                                argnums=(0, 1, 2, 3, 4), has_aux=True))
+    for decay in (0.01, 5.0):
+        operands = _operands(chunks * CHUNK, decay, "mixed", seed=chunks,
+                             shape=(1, 3, 16, 24))
+        with jax.default_matmul_precision("highest"):
+            grads, out = readings(*operands)
+            ref = _readings(token_by_token, *operands)
+        for name, a, b in zip(NAMES, (out,) + grads, ref):
+            err = float(jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b)))
+            assert err < TOL, (name, decay, err)
+
+
+def test_bf16_operands_keep_a_float32_state_in_the_kernels(kernels):
+    operands = _operands(3 * CHUNK, 0.3, "mixed", shape=(1, 4, 32, 16))
+    low = tuple(x.astype(jnp.bfloat16) for x in operands[:3]) \
+        + operands[3:5]
+    on_scan = jax.jit(gated_delta_rule)(*low)
+    kernels(2)
+    got = jax.jit(gated_delta_rule)(*low)
+    assert got.dtype == jnp.bfloat16
+    # a bf16 state would differ from the scan's float32 one by 2^-8
+    assert float(jnp.max(jnp.abs(got.astype(jnp.float32)
+                                 - on_scan.astype(jnp.float32)))) == 0.0
+    ref = token_by_token(*(x.astype(jnp.float32) for x in low))
+    err = float(jnp.max(jnp.abs(got.astype(jnp.float32) - ref))
+                / jnp.max(jnp.abs(ref)))
+    assert err < 2e-2, err
+
+
+def test_interpret_mode_on_a_tpu_is_refused(kernels, monkeypatch):
+    from horovod_tpu.ops import _platform
+
+    kernels(2)
+    monkeypatch.setattr(_platform, "operand_platform", lambda *a: "tpu")
+    with pytest.raises(RuntimeError, match="interpret mode"):
+        module._carry_state(*_state_operands(1, 1, 2, 16, 16, 16,
+                                             jnp.float32)[:6])
