@@ -729,6 +729,39 @@ def _short_conv(h, lp, c):
         @ lp["conv_out"].astype(dt)
 
 
+def _gdn_chain_in(qkvz, taps, hk, hv, dk, dv):
+    """The chain's first stage as an expression: ``qkvz`` [B, T, 2 hk dk
+    + 2 hv dv] = ``[q, k, v, z]`` side by side -> the convolution and
+    SiLU over ``[q, k, v]``, ``q`` and ``k`` unit vectors a key head in
+    float32 (``q`` times ``dk^-1/2``), everything by heads. What runs
+    off the TPU, and what ``ops/gdn_chain.py:chain_in`` is held to."""
+    from horovod_tpu.ops.gdn_chain import UNIT_EPS
+
+    dt, f32 = qkvz.dtype, jnp.float32
+    b, t, _ = qkvz.shape
+    u, z = jnp.split(qkvz, [2 * hk * dk + hv * dv], axis=-1)
+    u = jax.nn.silu(_causal_taps(u, taps).astype(dt))
+    q, k, v = jnp.split(u, [hk * dk, 2 * hk * dk], axis=-1)
+
+    def unit(x):     # [B, T, hk*dk] -> unit vectors a key head
+        x = x.reshape(b, t, hk, dk).astype(f32)
+        return x * lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + UNIT_EPS)
+
+    return ((unit(q) * dk ** -0.5).astype(dt), unit(k).astype(dt),
+            v.reshape(b, t, hv, dv), z.reshape(b, t, hv, dv))
+
+
+def _gdn_chain_out(o, z, gain, eps):
+    """The chain's second stage as an expression: ``RMSNorm(o) * gain *
+    SiLU(z)`` a value head, ``o`` and ``z`` [B, T, hv, dv], statistics
+    in float32 (``ops/gdn_chain.py:chain_out`` on the TPU)."""
+    dt, f32 = o.dtype, jnp.float32
+    o = o.astype(f32)
+    o = (o * lax.rsqrt(jnp.mean(o * o, -1, keepdims=True) + eps)
+         ).astype(dt) * gain
+    return o * jax.nn.silu(z.astype(f32)).astype(dt)
+
+
 def _gated_delta_net(x, lp, c, mesh, seq_axis, stage=lambda f: f):
     """qwen3_next's Gated DeltaNet, the token mixer of a
     ``linear_attention`` layer, on the residual stream ``x`` [B, T, D]
@@ -742,7 +775,11 @@ def _gated_delta_net(x, lp, c, mesh, seq_axis, stage=lambda f: f):
     for the value heads it serves; ``RMSNorm(o) * SiLU(z)`` a head; the
     output projection. ``stage`` wraps each (remat "attn/ffn": a
     checkpoint of its own, so that the first's residuals and the
-    rule's are never alive together)."""
+    rule's are never alive together). The chain round the rule runs as
+    ``ops/gdn_chain.py``'s kernel pairs where the operands live on a TPU
+    (and keys and values are one size), as ``_gdn_chain_in`` /
+    ``_gdn_chain_out`` elsewhere."""
+    from horovod_tpu.ops import gdn_chain
     from horovod_tpu.ops.gated_delta_rule import gated_delta_rule
 
     if mesh is not None and (
@@ -757,6 +794,7 @@ def _gated_delta_net(x, lp, c, mesh, seq_axis, stage=lambda f: f):
     b, t, _ = x.shape
     hk, hv = c.linear_key_heads, c.linear_value_heads
     dk, dv = c.linear_key_dim, c.linear_value_dim
+    kernels = gdn_chain.on_kernels(x, dk, dv)
 
     def before(x, lp):
         h = _rmsnorm(x, lp["gdn_norm"].astype(dt), c.norm_eps)
@@ -764,21 +802,16 @@ def _gated_delta_net(x, lp, c, mesh, seq_axis, stage=lambda f: f):
             qkvz = h @ lp["gdn_in"].astype(dt)
             ba = h @ lp["gdn_ba"].astype(dt)
         with scope("hvd.gdn.chain"):
-            u, z = jnp.split(qkvz, [2 * hk * dk + hv * dv], axis=-1)
-            u = jax.nn.silu(_causal_taps(u, lp["gdn_conv"]).astype(dt))
-            q, k, v = jnp.split(u, [hk * dk, 2 * hk * dk], axis=-1)
-
-            def unit(x):     # [B, T, hk*dk] -> unit vectors a key head
-                x = x.reshape(b, t, hk, dk).astype(f32)
-                return x * lax.rsqrt(jnp.sum(x * x, -1, keepdims=True)
-                                     + 1e-6)
-
+            if kernels:     # heads side by side, z too: chain_out's
+                q, k, v, z = gdn_chain.chain_in(qkvz, lp["gdn_conv"], hk, hv)
+                q, k, v = (a.reshape(b, t, -1, dk) for a in (q, k, v))
+            else:
+                q, k, v, z = _gdn_chain_in(qkvz, lp["gdn_conv"], hk, hv,
+                                           dk, dv)
             bb, aa = jnp.split(ba.astype(f32), 2, axis=-1)
             g = -jnp.exp(lp["gdn_a_log"].astype(f32)) * jax.nn.softplus(
                 aa + lp["gdn_dt_bias"].astype(f32))
-            return ((unit(q) * dk ** -0.5).astype(dt), unit(k).astype(dt),
-                    v.reshape(b, t, hv, dv), z.reshape(b, t, hv, dv), g,
-                    jax.nn.sigmoid(bb))
+            return q, k, v, z, g, jax.nn.sigmoid(bb)
 
     def rule_and_after(q, k, v, z, g, beta, lp):
         with scope("hvd.gdn.chain"):
@@ -786,13 +819,15 @@ def _gated_delta_net(x, lp, c, mesh, seq_axis, stage=lambda f: f):
         with scope("hvd.gdn.core"):
             o = gated_delta_rule(q, k, v, g, beta)
         with scope("hvd.gdn.chain"):
-            o = o.astype(f32)
-            o = (o * lax.rsqrt(jnp.mean(o * o, -1, keepdims=True)
-                               + c.norm_eps)).astype(dt) \
-                * lp["gdn_out_norm"].astype(dt)
-            o = o * jax.nn.silu(z.astype(f32)).astype(dt)
+            gain = lp["gdn_out_norm"].astype(dt)
+            if kernels:
+                o = gdn_chain.chain_out(o.reshape(b, t, hv * dv), z, gain,
+                                        c.norm_eps)
+            else:
+                o = _gdn_chain_out(o, z, gain, c.norm_eps).reshape(
+                    b, t, hv * dv)
         with scope("hvd.gdn.proj"):
-            return o.reshape(b, t, hv * dv) @ lp["gdn_out"].astype(dt)
+            return o @ lp["gdn_out"].astype(dt)
 
     return stage(rule_and_after)(*stage(before)(x, lp), lp)
 

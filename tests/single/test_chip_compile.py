@@ -12,6 +12,7 @@
 4. Peak tables know this chip and refuse what they do not know.
 """
 
+import functools
 import os
 import re
 import subprocess
@@ -350,6 +351,85 @@ def test_three_layers_of_the_rule_lower_each_kernel_once(for_tpu, v5e_chip):
     assert text.count("tpu_custom_call") == 3
     assert text.count("call @_kernel_fwd") >= 5   # the sites are calls
     assert text.count("call @_kernel_bwd") == 3
+
+
+_CHAIN_KERNELS = ("hvd_gdn_chain_in_fwd", "hvd_gdn_chain_in_bwd",
+                  "hvd_gdn_chain_out_fwd", "hvd_gdn_chain_out_bwd")
+# Qwen3-Next's linear mixer at the chip cell's size: B2 T8192, 16 key
+# heads serving 32 value heads, all 128 wide, four taps.
+_CHAIN = (((2, 8192, 96 * 128), BF16), ((4, 64 * 128), BF16),
+          ((128,), BF16))
+
+
+def _chain_fwd_bwd(qkvz, taps, gain):
+    """The chain round a stand-in for the rule (``v`` scaled by ``q
+    k``: every output is read), values and gradients."""
+    from horovod_tpu.ops import gdn_chain
+
+    def loss(qkvz, taps, gain):
+        q, k, v, z = gdn_chain.chain_in(qkvz, taps, 16, 32)
+        o = v * jnp.tile(q * k, (1, 1, 2))
+        return gdn_chain.chain_out(o, z, gain, 1e-6).astype(F32).sum()
+
+    return jax.value_and_grad(loss, argnums=(0, 1, 2))(qkvz, taps, gain)
+
+
+def test_gdn_chain_compiles_for_described_v5e(for_tpu):
+    """The chain's two kernel pairs at the chip cell's size as the
+    chip's compiler takes them (blocks of 256 tokens by 8 heads' lanes, the
+    backward's cotangents parked where a column block has none), each
+    by the name a device trace shows."""
+    text = for_tpu(_chain_fwd_bwd, *_CHAIN)
+    for name in _CHAIN_KERNELS:
+        assert f'"kernel":"{name}"' in text, name
+
+
+def test_three_mixers_lower_each_chain_kernel_once_a_form(v5e_chip,
+                                                         for_tpu):
+    """The set-up budget again (``test_three_layers_of_the_rule_...``),
+    on the mixer itself at the chip cell's size, three layers, a
+    checkpoint a stage with remat "attn/ffn"'s policy: a Mosaic lowering
+    a kernel FORM whatever the layers, each site a call of its kernel's
+    jitted wrapper. Stage one's forward runs three times (the
+    recomputation has no reader for ``q``, ``k``, ``v``: its backward
+    starts from ``qkvz``), stage two's six (the output projection's
+    gradient reads the gated norm again), and those six are TWO lowered
+    functions of one text: the recomputation's comes through the
+    checkpoint's partial evaluation with a jaxpr of its own. Five
+    lowerings a program, not fifteen."""
+    from horovod_tpu.models import llama
+
+    cfg = llama.LlamaConfig(
+        vocab_size=256, d_model=2048, n_layers=2, n_heads=16, n_kv_heads=2,
+        d_head=256, d_ff=512, norm_eps=1e-6, conv_taps=4,
+        layer_types=("linear_attention", "full_attention"),
+        linear_key_heads=16, linear_value_heads=32, linear_key_dim=128,
+        linear_value_dim=128, dtype="bfloat16", param_dtype="bfloat16",
+        remat="attn/ffn")
+    once = functools.partial(
+        jax.checkpoint, policy=jax.checkpoint_policies.save_only_these_names(
+            "attn_out", "flash_o", "flash_lse"))
+    leaves = {"gdn_norm": (2048,), "gdn_in": (2048, 96 * 128),
+              "gdn_ba": (2048, 64), "gdn_conv": (4, 64 * 128),
+              "gdn_a_log": (32,), "gdn_dt_bias": (32,),
+              "gdn_out_norm": (128,), "gdn_out": (32 * 128, 2048)}
+
+    def loss(x, lp):
+        for _ in range(3):
+            x = x + llama._gated_delta_net(x, lp, cfg, None, None, once)
+        return x.astype(F32).sum()
+
+    def spec(shape):
+        return jax.ShapeDtypeStruct(shape, BF16, sharding=v5e_chip)
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        spec((2, 8192, 2048)), {k: spec(v) for k, v in leaves.items()}
+    ).as_text()
+    assert [text.count(name) for name in _CHAIN_KERNELS] == [1, 1, 2, 1]
+    for wrapper, sites in (("_in_fwd", 3), ("_in_bwd", 3),
+                           ("_out_fwd", 6), ("_out_bwd", 3)):
+        assert len(re.findall(rf"call @{wrapper}(_\d+)?\(", text)) \
+            == sites, wrapper
 
 
 def test_a_stack_under_remat_attn_holds_no_padded_statistics(for_tpu):
