@@ -1061,6 +1061,8 @@ Status Controller::ComputeResponseList(std::vector<Request> requests,
         Status s = RecvFrame(control_fds_[r], &frame, hb_ms);
         RequestList rl;
         if (s.ok()) {
+          GlobalMetrics().gather_frames.fetch_add(1,
+                                                  std::memory_order_relaxed);
           s = ParseRequestList(frame, &rl);
           if (s.ok() && rl.epoch != cfg_.epoch) {
             s = Status::PeerFailure(
@@ -1206,6 +1208,7 @@ Status Controller::TreeCoordinatorGather(int64_t hb_ms,
       }
       return s;
     }
+    GlobalMetrics().gather_frames.fetch_add(1, std::memory_order_relaxed);
     std::vector<std::string> frames;
     if (!SplitBundle(bundle, &frames)) {
       return Status::PeerFailure(

@@ -111,6 +111,11 @@ class Metrics {
   // profile the simworld harness reads to indict O(N) suspects at
   // 64-256 ranks (docs/scale.md).
   LatencyHistogram control_phase_us[kPhaseCount];
+  // Frames the coordinator received in its request gathers, one after
+  // the other: size-1 a cycle from the flat star, its direct children's
+  // bundles from the control tree. The count behind the gather
+  // histogram's growth, free of the host's load (simworld reports it).
+  std::atomic<int64_t> gather_frames{0};
 
   std::atomic<int64_t> cycles{0};
   std::atomic<int64_t> cycle_stalls{0};      // loop overran its budget

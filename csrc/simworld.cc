@@ -290,6 +290,7 @@ int hvdtpu_simworld_run(int ranks, int tree_fanout, int64_t elems,
   // Clean per-phase profile for THIS world size (the whole point of
   // the harness); rendezvous is recorded below as world standup.
   for (auto& h : GlobalMetrics().control_phase_us) h.Reset();
+  GlobalMetrics().gather_frames.store(0);
 
   SimWorld w;
   if (!w.Build(ranks, tree_fanout)) {
@@ -376,6 +377,8 @@ int hvdtpu_simworld_run(int ranks, int tree_fanout, int64_t elems,
     }
   }
   json += "},";
+  AppendJson(json, "\"gather_frames\":%lld,",
+             (long long)GlobalMetrics().gather_frames.load());
   AppendJson(json, "\"allreduce_ok\":%s,", data_ok ? "true" : "false");
   if (kill_rank >= 0) {
     AppendJson(json, "\"fault\":{\"injected_rank\":%d,\"typed_faults\":"

@@ -7,9 +7,7 @@
     assert not analysis.errors(diags)
 
 The analyzer traces with ``jax.make_jaxpr(fn, axis_env=...)`` so
-collective axis names bind WITHOUT shard_map or real devices — the same
-code path works on jax 0.4.x CPU boxes (where the pipeline schedules
-run under vmap emulation) and on the jax>=0.6 TPU substrate.
+collective axis names bind WITHOUT shard_map or real devices.
 """
 
 import re
@@ -20,7 +18,9 @@ from horovod_tpu.analysis import checks
 from horovod_tpu.analysis import diagnostics as D
 from horovod_tpu.analysis.extract import extract
 
-_UNBOUND_RE = re.compile(r"unbound axis name:?\s*([\w./-]+)")
+# jax 0.9.0: "Found an unbound axis name: rank. To fix this, ..." — a
+# full stop may stand inside a name, never at its end.
+_UNBOUND_RE = re.compile(r"unbound axis name:\s*([\w/-]+(?:\.[\w/-]+)*)")
 
 #: how many distinct undeclared axis names one trace may reveal before
 #: we give up retrying (each retry binds one more name)

@@ -22,11 +22,15 @@ so the checks can reason per path. Alongside, it tracks:
   backward compute from one that bunches every scatter after the last
   flop — check C7's raw material.
 
-Nothing here needs ``jax.shard_map``: programs are traced by the caller
-with ``jax.make_jaxpr(fn, axis_env=...)``, which binds collective axis
-names on every jax this repo supports (0.4.x through current), so the
-analyzer runs identically on the old-jax CPU boxes that drive the
-pipeline schedules through the vmap-emulation path.
+Nothing here needs ``jax.shard_map`` or devices: programs are traced by
+the caller with ``jax.make_jaxpr(fn, axis_env=...)``, which binds
+collective axis names by itself.
+
+A ``jax.jit`` call inside a program is one equation whose params carry
+``donated_invars`` and the body ``jaxpr`` (:func:`_is_jit`); its
+primitive's NAME is jax's to change (``pjit`` once, ``jit`` in 0.9.0),
+so nothing here matches it. Paths label it ``pjit:<name>`` whatever jax
+calls it: allow-lists are written against that label.
 """
 
 import dataclasses
@@ -217,11 +221,9 @@ class _Walker:
                     # control flow). Taints map positionally when arity
                     # lines up; otherwise fall back to the conservative
                     # any() join.
-                    if prim == "pjit":
+                    if _is_jit(eqn):
                         self._record_donation(eqn, path)
-                    label = (f"{prim}:{eqn.params['name']}"
-                             if prim == "pjit" and "name" in eqn.params
-                             else prim)
+                    label = _body_label(eqn)
                     sub_path = f"{path}/{label}" if path else label
                     merged_out = False
                     for s in sub:
@@ -421,6 +423,18 @@ class _Walker:
         return found
 
 
+def _is_jit(eqn):
+    """A ``jax.jit`` call site, known by what it carries."""
+    return "donated_invars" in eqn.params and "jaxpr" in eqn.params
+
+
+def _body_label(eqn):
+    """Path piece for a body-carrying equation."""
+    if _is_jit(eqn) and "name" in eqn.params:
+        return f"pjit:{eqn.params['name']}"
+    return eqn.primitive.name
+
+
 def _size(aval):
     n = 1
     for d in getattr(aval, "shape", ()):
@@ -518,9 +532,7 @@ def build_profile(closed_jaxpr, path=""):
         else:
             bodies = _Walker._sub_jaxprs(eqn)
             if bodies:
-                label = (f"{prim}:{eqn.params['name']}"
-                         if prim == "pjit" and "name" in eqn.params
-                         else prim)
+                label = _body_label(eqn)
                 for s in bodies:
                     emit_all(build_profile(s, sub(label)))
             else:

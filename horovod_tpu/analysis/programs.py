@@ -12,8 +12,7 @@ Pipeline programs are linted at the per-device ``inner`` level (built
 by ``parallel.pipeline.build_pipeline_inner`` from the same
 ``models.llama`` stage/loss programs the engines run) with the
 host-schedule prediction attached — no mesh, devices, or shard_map
-required, which is what keeps the full check suite running on the
-jax 0.4.x CPU boxes that execute the schedules under vmap emulation.
+required.
 """
 
 import dataclasses
@@ -148,9 +147,10 @@ _ZERO_SHARDS = 4
 
 def _split_zero(config):
     """The ZeRO-1 split step (``make_split_train_step(zero=...)``),
-    traced end-to-end under the vmap emulation: proves the restructured
+    traced end-to-end with its shards as a vmapped axis (a
+    ``ZeroConfig`` without a mesh): proves the restructured
     step traces cleanly and that its apply program's donations (full
-    params + sharded opt state) alias 1:1 (C4). The vmap emulation
+    params + sharded opt state) alias 1:1 (C4). The vmap
     lowers the named-axis collectives away at trace time, so the REAL
     collective signature is linted separately via
     ``zero1_shard_apply``."""

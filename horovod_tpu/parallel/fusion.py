@@ -20,7 +20,7 @@ Three layers, bottom up:
    exist only to feed it — the bucket pack chains) float to the
    earliest point their inputs are ready, while every other equation
    keeps its original order. The result is topologically valid by
-   construction and bit-identical math in a different schedule; hvdlint
+   construction and the same equations in a different schedule; hvdlint
    C7 (``analysis/checks.py``) verifies the interleaving statically.
 
 2. **Program segmentation** — :func:`segment_closed_jaxpr` splits a
@@ -53,13 +53,7 @@ from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
-
-try:  # jax >= 0.5 moves the jaxpr types
-    from jax.extend import core as _jcore
-
-    _jcore.Jaxpr  # noqa: B018 — probe the attribute
-except (ImportError, AttributeError):  # the 0.4.x boxes
-    from jax import core as _jcore
+from jax.extend import core as _jcore
 
 
 # ---- the fusion knob -------------------------------------------------
@@ -259,8 +253,13 @@ def segment_closed_jaxpr(closed, cuts, jit_kwargs=None):
     """Split ``closed`` at equation indices ``cuts`` (ascending,
     exclusive prefix lengths) into a :class:`SegmentedProgram`. Each
     segment is its own jit over exactly the live values crossing its
-    boundaries; running the segments back-to-back replays the original
-    program's math (pinned by tests/single/test_fusion_pass.py)."""
+    boundaries; running the segments back-to-back runs the original
+    program's equations in their order. Each segment is an XLA program
+    of its own, so its fusions (a multiply-add contracted into one
+    rounding) are not those of the whole program or of an eager call:
+    values agree to the last place or two, bit for bit only when both
+    run a primitive at a time (tests/single/test_fusion_pass.py pins
+    both)."""
     jaxpr = closed.jaxpr
     eqns = list(jaxpr.eqns)
     cuts = [c for c in sorted(set(cuts)) if 0 < c < len(eqns)]
@@ -445,8 +444,8 @@ def make_fused_zero_programs(loss_fn, optimizer, zero, *,
 
     Each program is traced flat, rescheduled by
     :func:`interleave_collectives`, and run through ``_zero_spmd`` —
-    ``jax.shard_map`` on real meshes, the vmap(axis_name) emulation on
-    the jax-0.4.x CPU substrate — with params/opt donated.
+    ``jax.shard_map`` over ``zero.mesh``, a vmapped axis without one —
+    with params/opt donated.
     """
     from horovod_tpu.parallel.zero import (
         _optimizer_hyper,

@@ -23,55 +23,37 @@ from jax.sharding import PartitionSpec as P
 
 
 def _pipe_spmd(inner, mesh, axis, split_in, split_out):
-    """Run ``inner`` manual over the pipe axis.
-
-    With ``jax.shard_map`` (jax >= 0.6 — the accelerator/driver
-    substrate) this is the partial-manual shard_map the docstring above
-    describes. Older jax (0.4.x dev boxes) lacks it and its
-    ``jax.experimental`` ancestor miscompiles partial-auto meshes on
-    CPU ("PartitionId instruction is not supported"), so there the
-    schedules run under ``jax.vmap(..., axis_name=axis)`` instead:
-    axis-split arguments are reshaped ``[S*k, ...] -> [S, k, ...]`` and
-    mapped, which gives identical collective semantics (psum /
-    ppermute / axis_index resolve against the vmapped axis) — the whole
-    pipeline stack stays testable on such boxes, with GSPMD free to
-    lay out the emulated program however it likes.
+    """Run ``inner`` manual over the pipe axis: the partial-manual
+    ``shard_map`` the docstring above describes.
 
     ``split_in`` / ``split_out`` are per-argument booleans: True means
     the leading dim splits over ``axis`` (shard_map spec ``P(axis)``),
     False means replicated (``P()``).
+
+    The ``shard_map`` comes back under ``jax.jit``: a partial-manual
+    ``shard_map`` (``axis_names`` a subset of the mesh's) refuses an
+    eager call ("out_specs refers to 'data'..."), and an eval loop that
+    logs ``llama_loss`` without a surrounding jit is such a call. Under
+    a caller's jit the inner one is inlined; an eager caller compiles
+    it anew each call (``inner`` is a fresh closure), so a step that
+    runs more than once belongs under ``jax.jit``.
     """
-    S = mesh.shape[axis]
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            inner, mesh=mesh,
-            in_specs=tuple(P(axis) if s else P() for s in split_in),
-            out_specs=tuple(P(axis) if s else P() for s in split_out),
-            axis_names={axis}, check_vma=False)
-
-    def emulated(*args):
-        split = lambda a: jax.tree.map(  # noqa: E731
-            lambda x: x.reshape((S, x.shape[0] // S) + x.shape[1:]), a)
-        args = tuple(split(a) if s else a
-                     for a, s in zip(args, split_in))
-        outs = jax.vmap(inner,
-                        in_axes=tuple(0 if s else None
-                                      for s in split_in),
-                        out_axes=0, axis_name=axis)(*args)
-        merge = lambda o: jax.tree.map(  # noqa: E731
-            lambda x: x.reshape((x.shape[0] * x.shape[1],)
-                                + x.shape[2:]), o)
-        first = lambda o: jax.tree.map(lambda x: x[0], o)  # noqa: E731
-        return tuple(merge(o) if s else first(o)
-                     for o, s in zip(outs, split_out))
-
-    return emulated
+    return jax.jit(jax.shard_map(
+        inner, mesh=mesh,
+        in_specs=tuple(P(axis) if s else P() for s in split_in),
+        out_specs=tuple(P(axis) if s else P() for s in split_out),
+        axis_names={axis}, check_vma=False))
 
 
 def _cast_f32_on_cpu(mesh, xs):
     """XLA CPU's AllReducePromotion pass crashes on the bf16 allreduces
     the pipeline schedules generate (collection/cotangent psums inside
-    manual collectives). CPU is the test substrate, so run the schedule
+    manual collectives): on jaxlib 0.9.0 the differentiated gpipe
+    schedule aborts the process with ``hlo_instruction.cc:1585] Invalid
+    binary instruction opcode copy`` out of
+    ``xla::AllReducePromotion::RunImpl()``
+    (``test_llama.py::test_pipeline_bf16_compiles_on_cpu`` without this
+    cast, PR 46). CPU is the test substrate, so run the schedule
     in f32 there — TPU keeps native bf16. Returns ``(xs, dtype to cast
     schedule outputs back to, or None)``; shared by gpipe /
     one_f_one_b / interleaved_one_f_one_b so the workaround cannot
@@ -550,9 +532,8 @@ def _chunk_permutation(n_layers, S, V):
 def _interleaved_inner(stage_fn, loss_fn, sched, aux_cotangent, axis):
     """Per-device program for the interleaved schedule (the body that
     runs manual over the pipe axis). Factored out of
-    :func:`interleaved_one_f_one_b` so tests can execute it under
-    ``jax.vmap(..., axis_name=axis)`` — a faithful collective emulation
-    on hosts whose jax lacks ``jax.shard_map``.
+    :func:`interleaved_one_f_one_b` so hvdlint can trace it standalone
+    and tests can execute it under ``jax.vmap(..., axis_name=axis)``.
 
     ``sp`` leaves carry the device's ``V`` chunk blocks stacked
     (device-major permuted, leading dim ``V * Lb``).
@@ -769,10 +750,9 @@ def build_pipeline_inner(schedule, stage_fn, loss_fn=None, *, S, M,
 
     This is the program-builder hook ``horovod_tpu.analysis`` (hvdlint)
     traces: the returned ``inner`` is exactly what the engines hand to
-    ``_pipe_spmd``, so linting it covers the real collective sequence —
-    and because it is traced with ``jax.make_jaxpr(axis_env=[(axis,
-    S)])`` rather than ``shard_map``, the check runs identically on
-    jax 0.4.x boxes (where the engines execute under vmap emulation).
+    ``_pipe_spmd``, so linting it covers the real collective sequence;
+    it is traced with ``jax.make_jaxpr(axis_env=[(axis, S)])``, which
+    needs neither a mesh nor devices.
 
     ``schedule="gpipe"`` returns ``inner(sp, xs)``; the 1F1B variants
     return ``inner(sp, hp, xs, largs)`` and require ``loss_fn``.

@@ -34,9 +34,9 @@ Two lanes share this module's layout math:
 
 - the **jitted lane**: ``make_split_train_step(..., zero=ZeroConfig())``
   wires :func:`make_zero_apply` in as the apply program — a manual-
-  over-axis SPMD program (``jax.shard_map`` where available, the
-  pipeline package's ``vmap(axis_name=...)`` emulation on jax 0.4.x
-  boxes) whose per-bucket reduce-scatter/allgather pairs are exactly
+  over-axis SPMD program (``jax.shard_map`` over ``ZeroConfig.mesh``;
+  without a mesh the shards are a ``vmap(axis_name=...)`` axis of one
+  program) whose per-bucket reduce-scatter/allgather pairs are exactly
   what the latency-hiding scheduler overlaps with compute on TPU, and
   what hvdlint's C6 pairing check verifies statically;
 - the **eager lane**: ``hvd.DistributedFusedAdam(zero=True)``
@@ -86,9 +86,11 @@ class ZeroConfig:
     ``axis`` — mesh-axis name the shards live on (default ``"data"``:
     pure data-parallel replicas are exactly the ranks whose optimizer
     copies are redundant). ``size`` — shard count; defaults to
-    ``mesh.shape[axis]`` when ``mesh`` is given. ``mesh`` — used by the
-    real ``jax.shard_map`` path; on jax 0.4.x boxes the apply runs
-    under the vmap(axis_name) emulation and only ``size`` matters.
+    ``mesh.shape[axis]`` when ``mesh`` is given. ``mesh`` — the apply
+    runs as a ``jax.shard_map`` over it; without one the ``size``
+    shards are a vmapped axis of one program (logical shards, on any
+    number of devices: ``__graft_entry__.py``'s dry run and hvdlint's
+    ``llama_train_step_split_zero1`` take this path).
     ``bucket_bytes`` — fused-bucket granularity (shard boundaries align
     with bucket boundaries by construction).
 
@@ -148,15 +150,16 @@ class BucketLayout:
     def pack(self, leaves):
         """leaves -> list of flat padded 1-D arrays, one per bucket.
 
-        Deliberately built from ``dynamic_update_slice`` writes into a
-        zeros bucket instead of ``jnp.concatenate``: on the jax-0.4.x
-        CPU substrate, GSPMD miscompiles a jitted concatenate whose
-        operand is a reshape of an axis-sharded array (the PHYSICAL
-        per-device layout leaks into the result — elements come back
-        strided; two-line repro in tests/single/test_zero.py::
-        test_pack_of_sharded_leaves_is_layout_exact). The update-slice
-        chain lowers to plain copies and is exact under every sharding;
-        XLA fuses it to the same memcpys the concat would have been.
+        Built from ``dynamic_update_slice`` writes into a zeros bucket
+        instead of ``jnp.concatenate``: an earlier jax's GSPMD
+        miscompiled a jitted concatenate whose operand is a reshape of
+        an axis-sharded array (the PHYSICAL per-device layout leaked
+        into the result — elements came back strided). jax 0.9.0 gets
+        that two-line repro right (PR 46); the chain stays because it
+        is the text of every shipped ZeRO program, lowers to plain
+        copies, and is exact under every sharding
+        (tests/single/test_zero.py::
+        test_pack_of_sharded_leaves_is_layout_exact pins pack itself).
         """
         out = []
         for b in self.buckets:
@@ -292,20 +295,20 @@ def _optimizer_hyper(optimizer):
 def _zero_spmd(inner, axis, size, mesh, split_in, split_out,
                inter_axis=None, inter_size=1):
     """Run ``inner`` manual over the zero axis: ``jax.shard_map`` when
-    this jax has it AND a mesh was provided, else the same
-    ``vmap(axis_name=...)`` emulation the pipeline schedules use on
-    jax 0.4.x boxes (identical collective semantics; GSPMD lays the
-    emulated program out freely). ``split_in``/``split_out`` are
+    a mesh was provided; without one the shards are LOGICAL, a
+    ``vmap(axis_name=...)`` axis of one program (identical collective
+    semantics; GSPMD lays the program out freely on whatever devices
+    there are). ``split_in``/``split_out`` are
     per-argument booleans: True = leading dim splits over ``axis``
     (every leaf of that argument), False = replicated.
 
     ``inter_axis`` (the cross-plane ZeRO split) binds a second named
     axis the inner program psums its gradient shards over. Data stays
     replicated across it (each inter member holds the same accumulated
-    grads under the emulation; the real multi-slice run feeds per-slice
-    grads), so the emulation maps a dummy over the axis and every
+    grads on the logical path; the real multi-slice run feeds per-slice
+    grads), so the logical path maps a dummy over the axis and every
     member computes the identical result — index 0 is returned."""
-    if mesh is not None and hasattr(jax, "shard_map"):
+    if mesh is not None:
         from jax.sharding import PartitionSpec as P
 
         names = {axis} if inter_axis is None else {axis, inter_axis}
@@ -315,7 +318,7 @@ def _zero_spmd(inner, axis, size, mesh, split_in, split_out,
             out_specs=tuple(P(axis) if s else P() for s in split_out),
             axis_names=names, check_vma=False)
 
-    def emulated(*args):
+    def logical(*args):
         split = lambda a: jax.tree.map(  # noqa: E731
             lambda x: x.reshape((size, x.shape[0] // size) + x.shape[1:]),
             a)
@@ -332,19 +335,19 @@ def _zero_spmd(inner, axis, size, mesh, split_in, split_out,
                      for o, s in zip(outs, split_out))
 
     if inter_axis is None:
-        return emulated
+        return logical
 
-    def emulated_hier(*args):
+    def logical_hier(*args):
         # Bind the inter axis via a dummy mapped operand (vmap needs at
         # least one); all real args replicate across it. Every member's
         # result is identical post-psum, so member 0 stands for all.
         dummy = jnp.zeros((inter_size,), jnp.float32)
-        outs = jax.vmap(lambda _d, *a: emulated(*a),
+        outs = jax.vmap(lambda _d, *a: logical(*a),
                         in_axes=(0,) + (None,) * len(args),
                         out_axes=0, axis_name=inter_axis)(dummy, *args)
         return jax.tree.map(lambda x: x[0], outs)
 
-    return emulated_hier
+    return logical_hier
 
 
 def build_zero_apply_inner(hyper, layout, axis, size, inter_axis=None,

@@ -41,6 +41,7 @@ class _Worker:
         self.host = host
         self.local_index = local_index  # slot on its host at spawn time
         self.seq = next(_spawn_seq)     # spawn age: survivors < respawns
+        self.assigned_epoch = 0         # newest epoch published to it
         self.kill_event = threading.Event()
         self.driver_killed = False      # deliberate kill, not a failure
         self.thread = None
@@ -149,7 +150,8 @@ class ElasticDriver:
             if changed or rereg or needed:
                 self._reconcile_needed.clear()
                 self._reconcile(notify=bool(added),
-                                force_cut=bool(rereg) or needed)
+                                force_cut=bool(rereg) or needed,
+                                for_survivors=bool(rereg) and not needed)
 
     def _spawn(self, host, local_index):
         worker_id = f"{host}:{uuid.uuid4().hex[:8]}"
@@ -218,13 +220,18 @@ class ElasticDriver:
             self._manager.blacklist(worker.host)
         self._reconcile_needed.set()
 
-    def _reconcile(self, notify=False, force_cut=False):
-        """Match the fleet to the current host view and cut a new epoch."""
+    def _reconcile(self, notify=False, force_cut=False,
+                   for_survivors=False):
+        """Match the fleet to the current host view and cut a new epoch.
+
+        ``for_survivors``: the cut is forced by survivors' re-registrations
+        alone, no exit has been reaped for it."""
         # The upcoming cut covers any pending re-registrations; drain them
         # so the monitor doesn't cut a second (ghost) epoch for the same
         # recovery.
-        force_cut = bool(self._rendezvous.take_reregistrations()) \
-            or force_cut
+        late = bool(self._rendezvous.take_reregistrations())
+        for_survivors = for_survivors or (late and not force_cut)
+        force_cut = late or force_cut
         with self._lock:
             fleet_done = (not self._workers and self._final_codes
                           and all(c == 0 for c in self._final_codes))
@@ -307,19 +314,38 @@ class ElasticDriver:
                 if info and info.get("notify_port"):
                     notify_worker(w.host if not util.is_local_host(w.host)
                                   else "127.0.0.1", info["notify_port"])
-        self._cut_epoch(alive)
+        # Survivors ask for an epoch and the fleet looks whole: somebody
+        # is dead and not reaped yet, or everybody lives and will ask.
+        self._cut_epoch(alive, asked_only=for_survivors and not spawned
+                        and not killed)
 
-    def _cut_epoch(self, workers):
-        """Wait for registrations, then publish rank assignments."""
+    def _cut_epoch(self, workers, asked_only=False):
+        """Wait for registrations, then publish rank assignments.
+
+        ``asked_only``: a registration counts only once it asks for an
+        epoch newer than the one this worker was last given (a fresh
+        spawn's first, a survivor's re-registration). The one a worker
+        made BEFORE its epoch stays on the server until the worker's
+        exit is reaped, and a survivor can re-register sooner than that
+        (its peer's EOF reaches it at once; the reaper thread waits on
+        the scheduler, longer under load): counted, the dead worker is
+        dealt a rank of the new epoch, the survivor's init into that
+        world fails, and the job goes on from fresh respawns with the
+        committed state gone. Where the driver itself saw the exit
+        (a respawn is in ``workers``), a survivor that only polls for
+        the next epoch is taken as it always was."""
         deadline = time.monotonic() + self._start_timeout
         ids = {w.worker_id for w in workers}
         while time.monotonic() < deadline:
-            registered = set(self._rendezvous.registered_workers())
+            registered = self._rendezvous.registered_workers()
             with self._lock:
                 ids &= set(self._workers)  # drop workers that died meanwhile
+                waiting = {i for i in ids if i in registered and (
+                    not asked_only or registered[i].get("last_epoch", 0)
+                    >= self._workers[i].assigned_epoch)}
             if not ids:
                 break  # whole cohort exited; fall through to the guard
-            if ids <= registered:
+            if ids <= waiting:
                 break
             time.sleep(0.1)
         else:
@@ -381,6 +407,8 @@ class ElasticDriver:
                 "controller_port": controller_port,
             }
         epoch = self._rendezvous.start_epoch(assignments)
+        for w in workers:
+            w.assigned_epoch = epoch
         # Survivors that re-registered while we waited for respawn
         # registrations are satisfied by the epoch just published — drain
         # their flags so the monitor doesn't cut a ghost epoch for them.

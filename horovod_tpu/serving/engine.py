@@ -194,6 +194,18 @@ class DecodeEngine:
 
     # ---- drive to completion (bench / offline lane) --------------------
 
+    def serve_alone(self, req):
+        """``req``'s tokens from this engine with nothing beside it and
+        nothing lost: the uninterrupted run the elastic replay is held
+        to. Static shapes make it what ANY batch composition gives.
+        With an int8 pool it is NOT ``llama_generate``'s continuation
+        wherever two logits lie within the quantization error (the
+        tiny test model: 2.6081 against 2.6000 of a spread of 5.8);
+        prompt and first token, computed before any cache is read,
+        always are."""
+        self.submit(req)
+        return self.run_until_idle()[req.rid]
+
     def run_until_idle(self, max_steps=100000):
         """Decode until nothing is waiting or running. Returns the
         completed {rid: tokens} map."""

@@ -10,10 +10,12 @@ mid-trace, with admitted sequences in flight. Asserts:
    a 1-rank world in place (r12/r14 elastic), re-queues the dead
    rank's in-flight requests, and EVERY trace request completes on the
    survivor;
-2. greedy output is TOKEN-IDENTICAL to ``llama_generate`` for every
-   request — a request's answer does not depend on whether its first
-   home died (the static-shape engine + source-side quantization
-   determinism, docs/serving.md);
+2. greedy output is TOKEN-IDENTICAL to the uninterrupted run for every
+   request — an engine of the same int8 pool format that serves the
+   request alone and loses nobody; prompt and first token are
+   ``llama_generate``'s. A request's answer does not depend on whether
+   its first home died (the static-shape engine + source-side
+   quantization determinism, docs/serving.md);
 3. the victim really died by SIGKILL (exit code pins the chaos, not a
    clean shutdown);
 4. request-scoped tracing EXPLAINS the latency cliff
@@ -74,23 +76,27 @@ def worker():
             # re-queue and finish them.
             os.kill(os.getpid(), signal.SIGKILL)
 
-    loop = ServingLoop(params, cfg, trace, block_size=8, n_blocks=64,
-                       max_batch=4, max_context=32, quantized=True,
-                       steps_per_round=2, prefill_per_round=2,
-                       round_hook=hook)
+    geometry = dict(block_size=8, n_blocks=64, max_batch=4,
+                    max_context=32, quantized=True, steps_per_round=2,
+                    prefill_per_round=2)
+    loop = ServingLoop(params, cfg, trace, round_hook=hook, **geometry)
     report = loop.run()
     if b.rank() == 0:
         assert report["faults_survived"] >= 1, report
         assert report["served"] == len(trace), (
             report["served"], len(trace))
         for req in trace:
-            ref = np.asarray(llama_generate(
+            n = len(req.prompt) + 1
+            head = np.asarray(llama_generate(
                 params, jax.numpy.asarray(req.prompt[None, :]), cfg,
-                req.max_new_tokens))[0]
+                1))[0]
+            ref = ServingLoop(params, cfg, (),
+                              **geometry).engine.serve_alone(req)
             got = report["completed"][req.rid]
-            assert np.array_equal(got, ref), (
-                f"rid {req.rid}: served tokens diverge from "
-                f"llama_generate\n got {got}\n ref {ref}")
+            assert np.array_equal(got[:n], head) and np.array_equal(
+                got, ref), (
+                f"rid {req.rid}: served tokens diverge from the "
+                f"uninterrupted run\n got {got}\n ref {ref}")
         summary = {k: report[k] for k in
                    ("requests", "served", "generated_tokens",
                     "faults_survived", "evictions", "rounds",

@@ -51,8 +51,7 @@ def collective_bytes(fn, *args, axis_env=None):
     ``axis_env`` is a list of ``(axis_name, size)`` pairs binding the
     collective axes (same contract as ``analysis.lint``); args may be
     abstract (``jax.ShapeDtypeStruct``). Traced with ``jax.make_jaxpr``
-    — no devices, mesh, or shard_map needed, so the predictor runs on
-    the jax 0.4.x boxes too.
+    — no devices, mesh, or shard_map needed.
     """
     import jax
 

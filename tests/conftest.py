@@ -7,6 +7,7 @@ JAX onto the CPU platform with 8 virtual devices so mesh/sharding tests
 code path the driver's `dryrun_multichip` validates.
 """
 
+import contextlib
 import os
 import subprocess
 import sys
@@ -24,6 +25,7 @@ if "xla_force_host_platform_device_count" not in _flags:
     ).strip()
 
 import jax  # noqa: E402  (after the env setup above, before any backend use)
+import pytest  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
 
@@ -58,6 +60,35 @@ def pytest_configure(config):
         "excluded from the tier-1 budget via -m 'not slow'; covered by "
         "the full suite")
     _ensure_core_built()
+
+
+_LAUNCHER_ENV = ("HOROVOD_", "OMPI_", "SLURM_")
+
+
+@contextlib.contextmanager
+def launcher_env_restored():
+    """On leaving, every ``os.environ`` name under a launcher's prefix
+    is as it was on entering: written ones gone, changed ones back."""
+    before = {k: v for k, v in os.environ.items()
+              if k.startswith(_LAUNCHER_ENV)}
+    try:
+        yield
+    finally:
+        for k in [k for k in os.environ if k.startswith(_LAUNCHER_ENV)]:
+            if k not in before:
+                del os.environ[k]
+        os.environ.update(before)
+
+
+@pytest.fixture(autouse=True)
+def _launcher_env_restored():
+    """What a test leaves in ``os.environ`` under a launcher's prefix
+    goes with the test: ``hvd.init()`` translates ``OMPI_*``/``SLURM_*``
+    into ``HOROVOD_RANK``/``SIZE``/... by writing ``os.environ``, which
+    ``monkeypatch`` cannot undo, and every later test of this xdist
+    worker whose child inherits a rank then fails in company."""
+    with launcher_env_restored():
+        yield
 
 
 def pytest_collection_modifyitems(config, items):

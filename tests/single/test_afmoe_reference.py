@@ -88,12 +88,36 @@ def _err(got, ref):
                                                  1e-30))
 
 
+# Compiled once a configuration (``cfg`` static), as the cell runs them:
+# called eagerly, jax compiles these programs a primitive at a time
+# (27 + 13 s for one configuration's two gradients where the jitted
+# ones take 5 + 4). The EAGER call, which users make too, stays in
+# ``test_older_configurations_build_the_tree_and_program_they_did``.
+_forward = jax.jit(llama_forward, static_argnums=2)
+_ref_forward = jax.jit(afmoe_forward, static_argnums=2)
+_loss_and_grads = jax.jit(jax.value_and_grad(llama_loss), static_argnums=2)
+_ref_loss_and_grads = jax.jit(jax.value_and_grad(afmoe_loss),
+                              static_argnums=2)
+
+
+def _all_readings(forward, loss):
+    """Logits, loss and gradients as ONE program a configuration."""
+    return jax.jit(lambda params, batch, cfg: (
+        forward(params, batch["tokens"], cfg),
+        jax.value_and_grad(loss)(params, batch, cfg)), static_argnums=2)
+
+
+_readings = _all_readings(llama_forward, llama_loss)
+_ref_readings = _all_readings(afmoe_forward, afmoe_loss)
+
+
 def _assert_model_matches(cfg, seed=0):
     params, batch = _params(cfg, seed), _batch(cfg)
-    assert _err(llama_forward(params, batch["tokens"], cfg),
-                afmoe_forward(params, batch["tokens"], cfg)) < TOL
-    loss, grads = jax.value_and_grad(llama_loss)(params, batch, cfg)
-    ref_loss, ref = jax.value_and_grad(afmoe_loss)(params, batch, cfg)
+    logits, (loss, grads) = _readings(params, batch, cfg)
+    # the reference has no remat: one compile of it serves every mode
+    ref_logits, (ref_loss, ref) = _ref_readings(
+        params, batch, dataclasses.replace(cfg, remat=False))
+    assert _err(logits, ref_logits) < TOL
     assert abs(float(loss) - float(ref_loss)) < TOL * float(ref_loss)
     flat, _ = jax.tree_util.tree_flatten_with_path(grads)
     for (path, g), r in zip(flat, jax.tree.leaves(ref)):
@@ -133,8 +157,8 @@ def test_the_layer_pattern_and_the_share_in_other_shapes(case):
     else:
         cfg = _cfg(dtype="bfloat16")
         params, batch = _params(cfg), _batch(cfg)
-        assert _err(llama_forward(params, batch["tokens"], cfg),
-                    afmoe_forward(params, batch["tokens"], cfg)) > 100 * TOL
+        assert _err(_forward(params, batch["tokens"], cfg),
+                    _ref_forward(params, batch["tokens"], cfg)) > 100 * TOL
         return
     _assert_model_matches(cfg)
 
@@ -216,8 +240,8 @@ def test_no_held_slot_is_dropped_at_any_load(load):
             .at[:, 4:6].set(-4.0 if load == "none" else 4.0)
     params["layers"]["expert_bias"] = bias
     batch = _batch(cfg, (2, 128))
-    loss, grads = jax.value_and_grad(llama_loss)(params, batch, cfg)
-    ref_loss, ref = jax.value_and_grad(afmoe_loss)(params, batch, cfg)
+    loss, grads = _loss_and_grads(params, batch, cfg)
+    ref_loss, ref = _ref_loss_and_grads(params, batch, cfg)
     assert abs(float(loss) - float(ref_loss)) < TOL * float(ref_loss)
     for name in ("moe_gate", "moe_down", "router", "shared_up", "wg"):
         assert _err(grads["layers"][name], ref["layers"][name]) < TOL, name
@@ -281,8 +305,8 @@ def test_the_chunks_of_the_share_cover_exactly_the_held_rows(
 
     assert int(jnp.sum((idx == 4) | (idx == 5))) == rows
     cot = jax.random.normal(jax.random.PRNGKey(rows + 1), hf.shape)
-    got, vjp = jax.vjp(program, hf, w, lp)
-    ref, ref_vjp = jax.vjp(dense, hf, w, lp)
+    got, vjp = jax.vjp(jax.jit(program), hf, w, lp)
+    ref, ref_vjp = jax.vjp(jax.jit(dense), hf, w, lp)
     assert _err(got, ref) < TOL or (rows == 0 and not np.any(got))
     for g, r, name in zip(jax.tree.leaves(vjp(cot)),
                           jax.tree.leaves(ref_vjp(cot)),
@@ -455,6 +479,9 @@ _OLD = {
 
 @pytest.mark.parametrize("which", sorted(_OLD))
 def test_older_configurations_build_the_tree_and_program_they_did(which):
+    """The loss to the last bit, by the EAGER call of ``llama_loss``:
+    this file's one case that runs the model a primitive at a time, as
+    a user without ``jax.jit`` does."""
     import collections
     import re
 

@@ -5,9 +5,7 @@ Four deliberately-broken programs — one per static check class the
 last rounds' bugs motivated — must each fire the EXACT diagnostic
 (id + location); every shipped train-step/pipeline/optimizer
 combination must lint clean. The whole suite runs on jaxpr tracing
-with ``axis_env`` only: no shard_map, no multi-device mesh — which is
-precisely what keeps it green on the old-jax (0.4.x) CPU boxes where
-the pipeline engines execute under vmap emulation
+with ``axis_env`` only: no shard_map, no multi-device mesh
 (``test_full_suite_without_shard_map`` pins that).
 """
 
@@ -400,12 +398,10 @@ def test_shipped_moe_program_is_clean(hvdlint_shipped, name):
 
 
 def test_full_suite_without_shard_map(monkeypatch):
-    """The analyzer must run end-to-end on boxes whose jax lacks
-    ``jax.shard_map`` (the 0.4.x CPU substrate, where pipelines execute
-    under vmap emulation). Force the attribute away and run the whole
-    shipped-program sweep."""
-    if hasattr(jax, "shard_map"):
-        monkeypatch.delattr(jax, "shard_map")
+    """The analyzer traces with ``axis_env`` and never asks for
+    ``jax.shard_map``, a mesh or devices: take the attribute away and
+    run the whole shipped-program sweep."""
+    monkeypatch.delattr(jax, "shard_map")
     results = programs.lint_all()
     assert set(results) == set(programs.program_names())
     bad = {n: [d.format() for d in ds]

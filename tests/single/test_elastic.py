@@ -191,7 +191,7 @@ def _reconcile_driver(hosts):
         return w
 
     d._spawn = fake_spawn
-    d._cut_epoch = lambda workers: d._cuts.append(list(workers))
+    d._cut_epoch = lambda workers, **kw: d._cuts.append(list(workers))
     return d
 
 
@@ -291,5 +291,63 @@ def test_cut_epoch_rank_layout_survivor_first():
             assert a["size"] == 4 and a["local_size"] == 2
             assert a["cross_rank"] == a["rank"] // a["local_size"]
             assert a["cross_size"] == 2
+    finally:
+        driver._rendezvous.stop()
+
+
+def test_cut_epoch_deals_no_rank_to_an_exit_it_has_not_reaped():
+    """A worker dies and the survivor re-registers BEFORE the driver's
+    reaper thread has taken the dead worker out of the fleet
+    (``ElasticDriver._cut_epoch``'s ``asked_only``; the cause of
+    tests/integration/test_elastic_keras.py's two failures in company:
+    both ranks re-entered at epoch 0). The cut waits for the reaper,
+    and then for the respawn."""
+    from horovod_tpu.runner.elastic.driver import ElasticDriver, _Worker
+
+    driver = ElasticDriver.__new__(ElasticDriver)
+    driver._lock = threading.RLock()
+    driver._min_np = 2
+    driver._start_timeout = 10
+    driver._final_codes = []
+    driver._reconcile_needed = threading.Event()
+    driver._verbose = False
+    driver._rendezvous = RendezvousServer()
+    try:
+        victim = _Worker("h:victim", "h", 0)
+        survivor = _Worker("h:survivor", "h", 1)
+        driver._workers = {w.worker_id: w for w in (victim, survivor)}
+        client = RendezvousClient("127.0.0.1", driver._rendezvous.port)
+        for w in (victim, survivor):
+            client.register(w.worker_id, w.host, w.local_index, None)
+        driver._cut_epoch([victim, survivor])
+        assert driver._rendezvous.epoch == 1
+
+        # The victim is dead and not reaped; the survivor asks for a
+        # newer epoch than the one it consumed.
+        client.register(survivor.worker_id, "h", 1, None, last_epoch=1)
+        cut = threading.Thread(target=driver._cut_epoch,
+                               args=([victim, survivor],),
+                               kwargs={"asked_only": True})
+        cut.start()
+        time.sleep(0.5)
+        assert driver._rendezvous.epoch == 1, "a rank for a dead worker"
+
+        # The reaper runs (what _on_worker_exit does), a respawn
+        # registers: the next cut is the survivor and the respawn, the
+        # survivor rank 0.
+        driver._final_codes.append(17)
+        del driver._workers[victim.worker_id]
+        driver._rendezvous.forget_worker(victim.worker_id)
+        cut.join(timeout=10)
+        assert not cut.is_alive()
+        assert driver._rendezvous.epoch == 1       # one worker < min_np
+        assert driver._reconcile_needed.is_set()
+        respawn = _Worker("h:respawn", "h", 0)
+        driver._workers[respawn.worker_id] = respawn
+        client.register(respawn.worker_id, "h", 0, None)
+        driver._cut_epoch([survivor, respawn])
+        asg = client.poll_assignment(survivor.worker_id, timeout=5,
+                                     min_epoch=2)
+        assert (asg["epoch"], asg["rank"], asg["size"]) == (2, 0, 2)
     finally:
         driver._rendezvous.stop()

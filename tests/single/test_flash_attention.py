@@ -22,16 +22,6 @@ def _interpret_mode():
     fa_mod._INTERPRET = False
 
 
-def _skip_without_shard_map():
-    """The ring/ulysses mesh comparisons drive jax.shard_map directly
-    (same gate as tests/single/test_llama.py): on jax 0.4.x boxes only
-    jax.experimental.shard_map exists, with check_rep instead of
-    check_vma — skip rather than fail there; the driver's newer-jax box
-    runs them."""
-    if not hasattr(jax, "shard_map"):
-        pytest.skip("needs jax.shard_map (jax >= 0.6)")
-
-
 def _qkv(seed=0, B=1, T=32, H=2, D=8):
     k1, k2, k3 = jax.random.split(jax.random.PRNGKey(seed), 3)
     shape = (B, H, T, D)  # kernel layout
@@ -180,8 +170,8 @@ def test_chunked_offsets_kernel_matches_reference(causal, D):
 def test_ring_attention_flash_path_matches_blockwise(causal):
     """The flash ring path (pallas chunk kernel + logsumexp merge,
     interpret mode) must match the XLA blockwise ring on a real
-    sharded mesh — values and grads, including GQA kv heads."""
-    _skip_without_shard_map()
+    sharded mesh — values and grads, including GQA kv heads. Jitted,
+    but for the forward call of ``[False]``, which stays eager."""
     import jax.numpy as jnp
     from jax.sharding import Mesh, PartitionSpec as P
 
@@ -205,16 +195,20 @@ def test_ring_attention_flash_path_matches_blockwise(causal):
 
         return _r
 
-    out_flash = run(True)(q, k, v)
-    out_block = run(False)(q, k, v)
+    # Compiled once; [False] keeps the forward call EAGER, which users
+    # make too (a primitive a compile: the gradients alone took 90 s
+    # of a case that way).
+    once = jax.jit if causal else (lambda f: f)
+    out_flash = once(run(True))(q, k, v)
+    out_block = once(run(False))(q, k, v)
     np.testing.assert_allclose(np.asarray(out_flash),
                                np.asarray(out_block),
                                rtol=2e-4, atol=2e-4)
 
-    gf = jax.grad(lambda *a: (run(True)(*a) ** 2).sum(), (0, 1, 2))(
-        q, k, v)
-    gb = jax.grad(lambda *a: (run(False)(*a) ** 2).sum(), (0, 1, 2))(
-        q, k, v)
+    gf = jax.jit(jax.grad(lambda *a: (run(True)(*a) ** 2).sum(),
+                          (0, 1, 2)))(q, k, v)
+    gb = jax.jit(jax.grad(lambda *a: (run(False)(*a) ** 2).sum(),
+                          (0, 1, 2)))(q, k, v)
     for a, b, name in zip(gf, gb, "qkv"):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=5e-3, atol=5e-4,
@@ -226,7 +220,6 @@ def test_ulysses_flash_path_matches_blockwise(causal):
     """Ulysses' post-all-to-all local attention through the pallas
     kernels (interpret) must match its blockwise path — incl. the GQA
     grouping that survives the head split."""
-    _skip_without_shard_map()
     from jax.sharding import Mesh, PartitionSpec as P
 
     from horovod_tpu.parallel.ulysses import ulysses_attention

@@ -82,8 +82,9 @@ def test_stage_one_is_the_expression(kernels, dtype, T, tokens, a_pass):
     for name, a, b in zip("qkvz", got(qkvz, w), ref(qkvz, w)):
         assert a.dtype == dtype
         _close(a, b, dtype, name)
-    grads = [jax.grad(lambda *x, f=f: _weighted(f(*x), weights), (0, 1))(
-        qkvz, w) for f in (got, ref)]
+    grads = [jax.jit(jax.grad(
+        lambda *x, f=f: _weighted(f(*x), weights), (0, 1)))(qkvz, w)
+        for f in (got, ref)]
     for name, a, b in zip(("d qkvz", "d taps"), *grads):
         assert a.dtype == dtype
         _close(a, b, dtype, name)
@@ -137,7 +138,8 @@ def test_stage_two_is_the_expression(kernels, dtype, T, tokens, heads):
     ref = llama._gdn_chain_out
     assert got(o, z, gain, eps).dtype == dtype
     _close(got(o, z, gain, eps), ref(o, z, gain, eps), dtype, "out")
-    grads = [jax.grad(loss(f), (0, 1, 2))(o, z, gain) for f in (got, ref)]
+    grads = [jax.jit(jax.grad(loss(f), (0, 1, 2)))(o, z, gain)
+             for f in (got, ref)]
     for name, a, b in zip(("d o", "d z", "d gain"), *grads):
         assert a.dtype == dtype
         _close(a, b, dtype, name)
@@ -166,8 +168,8 @@ def _mixer_readings(cfg, lp, x):
         out = llama._gated_delta_net(x, lp, cfg, None, None, jax.checkpoint)
         return jnp.sum(out.astype(F32) ** 2), out
 
-    (_, out), (dx, dlp) = jax.value_and_grad(loss, (0, 1), has_aux=True)(
-        x, lp)
+    (_, out), (dx, dlp) = jax.jit(jax.value_and_grad(
+        loss, (0, 1), has_aux=True))(x, lp)
     return {"out": out, "d x": dx, **dlp}
 
 

@@ -18,16 +18,20 @@ from horovod_tpu.models import (
 )
 
 
-def _reference_greedy(params, prompt, cfg, n):
+def _reference_greedy(params, prompt, cfg, n, forward=llama_forward):
     toks = prompt
     for _ in range(n):
-        logits = llama_forward(params, toks, cfg)
+        logits = forward(params, toks, cfg)
         nxt = jnp.argmax(logits[:, -1, :], axis=-1).astype(prompt.dtype)
         toks = jnp.concatenate([toks, nxt[:, None]], axis=1)
     return toks
 
 
 def test_greedy_decode_matches_full_forward():
+    """The reference chain by the EAGER call of ``llama_forward``, which
+    users make too: this file's one case that runs the model a
+    primitive at a time (the MoE chain below compiles each length
+    once)."""
     cfg = LlamaConfig.tiny(dtype="float32", n_layers=2)
     params = llama_init(cfg, jax.random.PRNGKey(0))
     prompt = jax.random.randint(jax.random.PRNGKey(1), (2, 7), 0,
@@ -63,7 +67,8 @@ def test_moe_greedy_decode_matches_full_forward():
     prompt = jax.random.randint(jax.random.PRNGKey(3), (2, 6), 0,
                                 cfg.vocab_size)
     out = llama_generate(params, prompt, cfg, max_new_tokens=5)
-    ref = _reference_greedy(params, prompt, cfg, 5)
+    ref = _reference_greedy(params, prompt, cfg, 5,
+                            jax.jit(llama_forward, static_argnums=2))
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
 
 

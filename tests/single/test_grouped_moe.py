@@ -55,8 +55,9 @@ def test_grouped_moe_matches_gshard_when_dropless(routing):
     cfg = _dropless_cfg(**routing)
     lp = _layer0(cfg)
     h = _h(cfg)
-    y_ref, aux_ref = _moe_ffn(h, lp, cfg, None)
-    y, aux = grouped_moe_ffn(h, lp, cfg)
+    y_ref, aux_ref = jax.jit(lambda h, lp: _moe_ffn(h, lp, cfg, None))(
+        h, lp)
+    y, aux = jax.jit(lambda h, lp: grouped_moe_ffn(h, lp, cfg))(h, lp)
     np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
                                rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(np.asarray(aux), np.asarray(aux_ref),
@@ -76,10 +77,10 @@ def test_grouped_moe_gradients_match_gshard(routing):
         return ((y.astype(jnp.float32) ** 2).mean()
                 + 0.01 * moe_balance_loss(aux))
 
-    g_ref = jax.grad(lambda h, lp: loss(
-        lambda a, b: _moe_ffn(a, b, cfg, None), h, lp), (0, 1))(h, lp)
-    g = jax.grad(lambda h, lp: loss(
-        lambda a, b: grouped_moe_ffn(a, b, cfg), h, lp), (0, 1))(h, lp)
+    g_ref = jax.jit(jax.grad(lambda h, lp: loss(
+        lambda a, b: _moe_ffn(a, b, cfg, None), h, lp), (0, 1)))(h, lp)
+    g = jax.jit(jax.grad(lambda h, lp: loss(
+        lambda a, b: grouped_moe_ffn(a, b, cfg), h, lp), (0, 1)))(h, lp)
     np.testing.assert_allclose(np.asarray(g[0]), np.asarray(g_ref[0]),
                                rtol=2e-5, atol=2e-6, err_msg="dh")
     for name in g[1]:
@@ -110,7 +111,9 @@ def test_grouped_moe_is_dropless_where_gshard_drops():
 
 def test_llama_forward_grouped_impl_end_to_end():
     # moe_impl="auto" with no mesh resolves to the grouped path; the
-    # full forward + loss must be finite and trainable.
+    # full forward + loss must be finite and trainable. By the EAGER
+    # call of ``llama_loss``'s gradient, which users make too: this
+    # file's one case that runs the model a primitive at a time.
     cfg = LlamaConfig.tiny_moe(dtype="float32", remat=False)
     assert cfg.moe_impl == "auto"
     params = llama_init(cfg, jax.random.PRNGKey(0))

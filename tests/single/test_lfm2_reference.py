@@ -88,12 +88,36 @@ def _err(got, ref):
                                                  1e-30))
 
 
+# Compiled once a configuration (``cfg`` static), as the cell runs them:
+# called eagerly, jax compiles these programs a primitive at a time. The
+# EAGER call, which users make too, stays in
+# ``test_the_models_the_benchmark_had_build_what_they_built``.
+_forward = jax.jit(llama_forward, static_argnums=2)
+_ref_forward = jax.jit(lfm2_forward, static_argnums=2)
+_loss = jax.jit(llama_loss, static_argnums=2)
+_ref_loss = jax.jit(lfm2_loss, static_argnums=2,
+                    static_argnames="vocab_rows")
+_grads = jax.jit(jax.grad(llama_loss), static_argnums=2)
+
+
+def _all_readings(forward, loss):
+    """Logits, loss and gradients as ONE program a configuration."""
+    return jax.jit(lambda params, batch, cfg: (
+        forward(params, batch["tokens"], cfg),
+        jax.value_and_grad(loss)(params, batch, cfg)), static_argnums=2)
+
+
+_readings = _all_readings(llama_forward, llama_loss)
+_ref_readings = _all_readings(lfm2_forward, lfm2_loss)
+
+
 def _assert_model_matches(cfg, seed=0):
     params, batch = _params(cfg, seed), _batch(cfg)
-    assert _err(llama_forward(params, batch["tokens"], cfg),
-                lfm2_forward(params, batch["tokens"], cfg)) < TOL
-    loss, grads = jax.value_and_grad(llama_loss)(params, batch, cfg)
-    ref_loss, ref = jax.value_and_grad(lfm2_loss)(params, batch, cfg)
+    logits, (loss, grads) = _readings(params, batch, cfg)
+    # the reference has no remat: one compile of it serves every mode
+    ref_logits, (ref_loss, ref) = _ref_readings(
+        params, batch, dataclasses.replace(cfg, remat=False))
+    assert _err(logits, ref_logits) < TOL
     assert abs(float(loss) - float(ref_loss)) < TOL * float(ref_loss)
     flat, _ = jax.tree_util.tree_flatten_with_path(grads)
     for (path, g), r in zip(flat, jax.tree.leaves(ref)):
@@ -152,8 +176,8 @@ def test_the_layer_pattern_and_the_share_in_other_shapes(case):
                  "bf16-fails": dict(dtype="bfloat16")}[case]
         cfg = _cfg(**wrong)
         params, batch = _params(cfg), _batch(cfg)
-        assert _err(llama_forward(params, batch["tokens"], cfg),
-                    lfm2_forward(params, batch["tokens"], cfg)) > 100 * TOL
+        assert _err(_forward(params, batch["tokens"], cfg),
+                    _ref_forward(params, batch["tokens"], cfg)) > 100 * TOL
         return
     _assert_model_matches(cfg)
 
@@ -264,18 +288,17 @@ def test_loss_over_the_tied_vocabulary_slice():
     full = _params(uncut)
     held = dict(full, embed=full["embed"][:32])
     batch = _batch(cfg)
-    want = lfm2_loss(full, batch, uncut, vocab_rows=32)
-    assert abs(float(llama_loss(held, batch, cfg)) - float(want)) \
+    want = _ref_loss(full, batch, uncut, vocab_rows=32)
+    assert abs(float(_loss(held, batch, cfg)) - float(want)) \
         < TOL * float(want)
-    assert abs(float(lfm2_loss(held, batch, cfg)) - float(want)) \
+    assert abs(float(_ref_loss(held, batch, cfg)) - float(want)) \
         < TOL * float(want)
-    assert abs(float(lfm2_loss(full, batch, uncut)) - float(want)) > 0.1
+    assert abs(float(_ref_loss(full, batch, uncut)) - float(want)) > 0.1
     # the tied gradient is the sum of both uses: the lookup's alone (the
     # head cut off the graph) and the head's alone differ from it
-    g = jax.grad(llama_loss)(held, batch, cfg)["embed"]
+    g = _grads(held, batch, cfg)["embed"]
     untied = dataclasses.replace(cfg, tie_embeddings=False)
-    apart = jax.grad(llama_loss)(
-        dict(held, lm_head=held["embed"].T), batch, untied)
+    apart = _grads(dict(held, lm_head=held["embed"].T), batch, untied)
     assert _err(g, apart["embed"] + apart["lm_head"].T) < TOL
     assert _err(g, apart["embed"]) > 0.1 and _err(g, apart["lm_head"].T) \
         > 1e-3
@@ -350,6 +373,9 @@ _BEFORE = {
 
 @pytest.mark.parametrize("which", sorted(_BEFORE))
 def test_the_models_the_benchmark_had_build_what_they_built(which):
+    """The loss to the last bit, by the EAGER call of ``llama_loss``:
+    this file's cases that run the model a primitive at a time, as a
+    user without ``jax.jit`` does."""
     cfg, stacks, loss = _BEFORE[which]
     params = llama_init(cfg, jax.random.PRNGKey(0))
     assert sorted(params) == sorted(stacks + ["embed", "final_norm",

@@ -120,17 +120,8 @@ def test_flash_block_is_pure_scheduling():
         fa_mod._INTERPRET = False
 
 
-def _skip_without_shard_map():
-    # The ring/ulysses/pipeline paths build on jax.shard_map; older jax
-    # (< 0.6, e.g. a CPU-only dev box) only has the experimental alias.
-    if not hasattr(jax, "shard_map"):
-        import pytest
-        pytest.skip("needs jax.shard_map (jax >= 0.6)")
-
-
 def test_seq_parallel_forward_matches():
     """Ring-attention path (seq=4) must match the single-device forward."""
-    _skip_without_shard_map()
     cfg = LlamaConfig.tiny(dtype="float32", n_layers=2)
     params = llama_init(cfg, jax.random.PRNGKey(0))
     tokens = jax.random.randint(jax.random.PRNGKey(3), (2, 32), 0,
@@ -151,7 +142,6 @@ def test_seq_parallel_forward_matches():
 def test_seq_parallel_ulysses_matches():
     """Ulysses path (seq_parallel="ulysses", seq=4) must match the
     single-device forward (tiny config has 4 heads -> divisible)."""
-    _skip_without_shard_map()
     cfg = LlamaConfig.tiny(dtype="float32", n_layers=2,
                            seq_parallel="ulysses")
     params = llama_init(cfg, jax.random.PRNGKey(0))
@@ -261,7 +251,6 @@ def test_moe_expert_parallel_matches_single_device():
 # ---- pipeline parallelism (GPipe over the "pipe" axis) ----
 
 def _skip_unless_8():
-    _skip_without_shard_map()
     if len(jax.devices()) < 8:
         import pytest
         pytest.skip("needs 8 virtual devices")

@@ -75,6 +75,26 @@ def _err(got, ref):
     return float(np.max(np.abs(got - ref)) / np.max(np.abs(ref)))
 
 
+# Compiled once a configuration (``cfg`` static), as the cell runs them:
+# called eagerly, jax compiles these programs a primitive at a time. The
+# EAGER call, which users make too, stays in
+# ``test_the_dense_configuration_is_what_it_was``.
+_forward = jax.jit(llama_forward, static_argnums=2)
+_ref_forward = jax.jit(olmoe_forward, static_argnums=2)
+_loss_and_grads = jax.jit(jax.value_and_grad(llama_loss), static_argnums=2)
+
+
+def _all_readings(forward, loss):
+    """Logits, loss and gradients as ONE program a configuration."""
+    return jax.jit(lambda params, batch, cfg: (
+        forward(params, batch["tokens"], cfg),
+        jax.value_and_grad(loss)(params, batch, cfg)), static_argnums=2)
+
+
+_readings = _all_readings(llama_forward, llama_loss)
+_ref_readings = _all_readings(olmoe_forward, olmoe_loss)
+
+
 @pytest.mark.parametrize("norm_topk_prob, dtype, agrees", [
     (False, "float32", True),      # OLMoE as published
     (True, "float32", True),       # renormalised top-k, same path
@@ -84,11 +104,9 @@ def test_logits_loss_and_every_gradient_leaf(norm_topk_prob, dtype,
                                              agrees):
     cfg = _cfg(norm_topk_prob=norm_topk_prob, dtype=dtype)
     params, batch = _params(cfg), _batch(cfg)
-    logits = llama_forward(params, batch["tokens"], cfg)
-    loss, grads = jax.value_and_grad(llama_loss)(params, batch, cfg)
-    ref_logits, _ = olmoe_forward(params, batch["tokens"], cfg)
-    ref_loss, ref_grads = jax.value_and_grad(olmoe_loss)(params, batch,
-                                                         cfg)
+    logits, (loss, grads) = _readings(params, batch, cfg)
+    (ref_logits, _), (ref_loss, ref_grads) = _ref_readings(params, batch,
+                                                           cfg)
     errs = {"logits": _err(logits, ref_logits),
             "loss": _err(loss, ref_loss)}
     assert set(grads["layers"]) == set(ref_grads["layers"]) \
@@ -106,10 +124,10 @@ def test_norm_topk_prob_changes_the_result():
     cfg = _cfg()
     params, batch = _params(cfg), _batch(cfg)
     renorm = dataclasses.replace(cfg, norm_topk_prob=True)
-    a = llama_forward(params, batch["tokens"], cfg)
-    b = llama_forward(params, batch["tokens"], renorm)
+    a = _forward(params, batch["tokens"], cfg)
+    b = _forward(params, batch["tokens"], renorm)
     assert _err(a, b) > 1e-2
-    assert _err(olmoe_forward(params, batch["tokens"], renorm)[0], b) < TOL
+    assert _err(_ref_forward(params, batch["tokens"], renorm)[0], b) < TOL
 
 
 def test_aux_term_against_a_hand_count():
@@ -157,7 +175,7 @@ def test_prefill_then_cached_decode_against_the_full_forward(batch):
     params = _params(cfg)
     tokens = _batch(cfg, (batch, 16))["tokens"]
     t0, n_new = 12, 4
-    ref, _ = olmoe_forward(params, tokens, cfg)
+    ref, _ = _ref_forward(params, tokens, cfg)
 
     x, cache_k, cache_v = gen._prefill(params, tokens[:, :t0], cfg, n_new)
     assert _err(gen._lm_logits(params, x, cfg), ref[:, :t0]) < TOL
@@ -174,8 +192,8 @@ def test_prefill_then_cached_decode_against_the_full_forward(batch):
 def test_remat_modes_give_the_same_loss_and_gradients(remat):
     cfg = _cfg()
     params, batch = _params(cfg), _batch(cfg)
-    loss, grads = jax.value_and_grad(llama_loss)(params, batch, cfg)
-    loss_r, grads_r = jax.value_and_grad(llama_loss)(
+    loss, grads = _loss_and_grads(params, batch, cfg)
+    loss_r, grads_r = _loss_and_grads(
         params, batch, dataclasses.replace(cfg, remat=remat))
     np.testing.assert_allclose(loss_r, loss, rtol=1e-6)
     # Every leaf to the bounds this test has always had, but the
@@ -196,7 +214,10 @@ def test_remat_modes_give_the_same_loss_and_gradients(remat):
 def test_the_dense_configuration_is_what_it_was():
     """No q/k-norm or router leaf where the config has none, and the
     loss of a seeded dense model is the value read at the commit before
-    the q/k norm and the new router existed (4d73205, float32, CPU)."""
+    the q/k norm and the new router existed (4d73205, float32, CPU).
+    By the EAGER calls of ``llama_forward`` and ``llama_loss``: this
+    file's one case that runs the model a primitive at a time, as a
+    user without ``jax.jit`` does."""
     cfg = LlamaConfig.tiny(dtype="float32", remat=False)
     params = llama_init(cfg, jax.random.PRNGKey(0))
     assert sorted(params["layers"]) == [

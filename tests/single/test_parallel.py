@@ -6,6 +6,8 @@ asserted against single-device closed forms, in the reference's analytic
 spirit (SURVEY.md §4).
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -87,6 +89,10 @@ def test_blockwise_attention_matches_reference(causal, kv_heads):
 @pytest.mark.parametrize("causal", [True, False])
 @pytest.mark.parametrize("seq_size", [4, 8])
 def test_ring_attention_exact(causal, seq_size):
+    """Compiled once under ``jax.jit``; the case ``[4-False]`` keeps the
+    EAGER call, which users make too (eager, jax compiles the ring a
+    primitive at a time: 23 to 50 s a case where the jitted one takes
+    three)."""
     mesh = parallel.create_mesh(data=8 // seq_size, seq=seq_size)
     rng = np.random.RandomState(1)
     b, t, h, hkv, d = 2, 32, 4, 2, 8
@@ -94,7 +100,11 @@ def test_ring_attention_exact(causal, seq_size):
     k = jnp.asarray(rng.randn(b, t, hkv, d), jnp.float32)
     v = jnp.asarray(rng.randn(b, t, hkv, d), jnp.float32)
 
-    out = parallel.ring_self_attention(q, k, v, mesh, causal=causal)
+    ring = functools.partial(parallel.ring_self_attention, mesh=mesh,
+                             causal=causal)
+    if (seq_size, causal) != (4, False):
+        ring = jax.jit(ring)
+    out = ring(q, k, v)
     np.testing.assert_allclose(np.asarray(out),
                                _reference_attention(q, k, v, causal),
                                rtol=2e-5, atol=2e-5)
@@ -114,8 +124,8 @@ def test_ring_attention_gradients_match():
     def loss_plain(q, k, v):
         return jnp.sum(blockwise_attention(q, k, v) ** 2)
 
-    g_ring = jax.grad(loss_ring, argnums=(0, 1, 2))(q, k, v)
-    g_plain = jax.grad(loss_plain, argnums=(0, 1, 2))(q, k, v)
+    g_ring = jax.jit(jax.grad(loss_ring, argnums=(0, 1, 2)))(q, k, v)
+    g_plain = jax.jit(jax.grad(loss_plain, argnums=(0, 1, 2)))(q, k, v)
     for gr, gp in zip(g_ring, g_plain):
         np.testing.assert_allclose(np.asarray(gr), np.asarray(gp),
                                    rtol=1e-4, atol=1e-4)
@@ -170,8 +180,8 @@ def test_ulysses_attention_gradients_match():
     def loss_plain(q, k, v):
         return jnp.sum(blockwise_attention(q, k, v) ** 2)
 
-    g_u = jax.grad(loss_uly, argnums=(0, 1, 2))(q, k, v)
-    g_p = jax.grad(loss_plain, argnums=(0, 1, 2))(q, k, v)
+    g_u = jax.jit(jax.grad(loss_uly, argnums=(0, 1, 2)))(q, k, v)
+    g_p = jax.jit(jax.grad(loss_plain, argnums=(0, 1, 2)))(q, k, v)
     for gu, gp in zip(g_u, g_p):
         np.testing.assert_allclose(np.asarray(gu), np.asarray(gp),
                                    rtol=2e-5, atol=2e-5)

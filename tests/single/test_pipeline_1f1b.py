@@ -24,10 +24,6 @@ from horovod_tpu.parallel.sharding import apply_sharding, named_sharding
 
 
 def _skip_unless_8():
-    # No jax.shard_map requirement anymore: on older jax (< 0.6, e.g. a
-    # CPU-only dev box) the schedules run through pipeline._pipe_spmd's
-    # vmap(axis_name=...) emulation, which has identical collective
-    # semantics — only the device count gates these tests now.
     if len(jax.devices()) < 8:
         pytest.skip("needs 8 virtual devices")
 

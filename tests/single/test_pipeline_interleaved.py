@@ -7,10 +7,8 @@ Two layers of pinning:
   (build_interleaved_schedule) — slot counts, bubble fractions, the
   >=1.5x V=1 -> V=2 bubble shrink the round-6 acceptance bar names,
   ragged ``M % (S*V)`` remainders;
-- gradient-equivalence tests run the full llama path. On jax >= 0.6
-  they exercise the real partial-manual ``jax.shard_map``; on older
-  boxes ``pipeline._pipe_spmd`` transparently substitutes the
-  vmap(axis_name=...) emulation, so these pins run everywhere.
+- gradient-equivalence tests run the full llama path through the
+  partial-manual ``jax.shard_map`` of ``pipeline._pipe_spmd``.
 """
 
 import dataclasses

@@ -228,8 +228,10 @@ def test_four_shares_and_one_shared_expert_are_the_uncut_layer():
     h = jax.random.normal(jax.random.PRNGKey(3), (2, 8, 64))
     no_shared = dict(lp, shared_down=jnp.zeros_like(lp["shared_down"]))
     with jax.default_matmul_precision("highest"):
-        ref = qwen3next_expert_layer(h, lp, whole)
-        shared = ref - qwen3next_expert_layer(h, no_shared, whole)
+        uncut = jax.jit(
+            lambda h, lp: qwen3next_expert_layer(h, lp, whole))
+        ref = uncut(h, lp)
+        shared = ref - uncut(h, no_shared)
         parts, parts_ref = shared, shared
         for first in (0, 4, 8, 12):
             share = dataclasses.replace(whole, first_expert=first,

@@ -33,7 +33,17 @@ def _tiny_resnet(depth=18):
                         compute_dtype="float32")
 
 
+# Compiled once a configuration: called eagerly, jax compiles a ResNet
+# a primitive at a time (25-33 s a case where these take a few).
+_init = jax.jit(resnet_init, static_argnums=0)
+_forward = jax.jit(resnet_forward, static_argnums=3,
+                   static_argnames="train")
+
+
 def test_resnet_forward_shapes():
+    """By the EAGER calls of ``resnet_init`` and ``resnet_forward``,
+    which users make too: this file's one ResNet case that runs a
+    primitive at a time."""
     cfg = _tiny_resnet()
     params, state = resnet_init(cfg, jax.random.PRNGKey(0))
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 32, 32, 3))
@@ -47,18 +57,18 @@ def test_resnet_forward_shapes():
 
 def test_resnet_bottleneck_variant():
     cfg = _tiny_resnet(depth=50)
-    params, state = resnet_init(cfg, jax.random.PRNGKey(0))
+    params, state = _init(cfg, jax.random.PRNGKey(0))
     assert "conv3" in params["stage0"][0]  # bottleneck blocks
     x = jnp.zeros((1, 32, 32, 3))
-    logits, _ = resnet_forward(params, state, x, cfg, train=False)
+    logits, _ = _forward(params, state, x, cfg, train=False)
     assert logits.shape == (1, 7)
 
 
 def test_resnet_eval_uses_running_stats():
     cfg = _tiny_resnet()
-    params, state = resnet_init(cfg, jax.random.PRNGKey(0))
+    params, state = _init(cfg, jax.random.PRNGKey(0))
     x = jax.random.normal(jax.random.PRNGKey(2), (2, 32, 32, 3))
-    l1, s1 = resnet_forward(params, state, x, cfg, train=False)
+    l1, s1 = _forward(params, state, x, cfg, train=False)
     # eval must not mutate state
     assert np.allclose(np.asarray(s1["stem"]["bn"]["mean"]),
                        np.asarray(state["stem"]["bn"]["mean"]))

@@ -243,7 +243,10 @@ def test_launcher_env_translation(monkeypatch):
 
     for k in ("HOROVOD_RANK", "HOROVOD_SIZE", "HOROVOD_LOCAL_RANK",
               "HOROVOD_LOCAL_SIZE"):
-        monkeypatch.delenv(k, raising=False)
+        # setenv first: delenv of an absent name records nothing, and
+        # what the translation then writes would outlive the test.
+        monkeypatch.setenv(k, "")
+        monkeypatch.delenv(k)
     monkeypatch.setenv("OMPI_COMM_WORLD_RANK", "3")
     monkeypatch.setenv("OMPI_COMM_WORLD_SIZE", "8")
     monkeypatch.setenv("OMPI_COMM_WORLD_LOCAL_RANK", "1")
@@ -257,6 +260,32 @@ def test_launcher_env_translation(monkeypatch):
     monkeypatch.setenv("HOROVOD_RANK", "0")
     HorovodBasics._translate_launcher_env()
     assert os.environ["HOROVOD_RANK"] == "0"
+
+
+def test_launcher_env_written_by_a_test_ends_with_it(request,
+                                                     monkeypatch):
+    """The translation writes ``os.environ`` itself; tests/conftest.py
+    takes what a test wrote under HOROVOD_/OMPI_/SLURM_ away after it,
+    so the next test of this worker sees HOROVOD_RANK absent."""
+    from horovod_tpu.common.basics import HorovodBasics
+    from tests.conftest import launcher_env_restored
+
+    assert "_launcher_env_restored" in request.fixturenames  # autouse
+    for k in ("HOROVOD_RANK", "HOROVOD_SIZE"):
+        monkeypatch.setenv(k, "")
+        monkeypatch.delenv(k)
+    monkeypatch.setenv("HOROVOD_CYCLE_TIME", "5")
+    monkeypatch.setenv("OMPI_COMM_WORLD_RANK", "3")
+    monkeypatch.setenv("OMPI_COMM_WORLD_SIZE", "8")
+    with launcher_env_restored():  # one test's body
+        HorovodBasics._translate_launcher_env()
+        os.environ["HOROVOD_CYCLE_TIME"] = "1"
+        os.environ["SLURM_WRITTEN_BY_A_TEST"] = "3"
+        assert os.environ["HOROVOD_RANK"] == "3"
+    assert not {"HOROVOD_RANK", "HOROVOD_SIZE",
+                "SLURM_WRITTEN_BY_A_TEST"} & set(os.environ)
+    assert os.environ["HOROVOD_CYCLE_TIME"] == "5"
+    assert os.environ["OMPI_COMM_WORLD_RANK"] == "3"
 
 
 def _interactive_fn(scale):

@@ -8,8 +8,8 @@ allreduce, in one process. Pins:
 - the per-phase control-plane profile (gather/broadcast/rendezvous
   histograms) comes out of every run — the scaling-curve plumbing;
 - the tree gather beats the flat star's GROWTH: sub-linear vs the
-  sequential baseline between 32 and 128 ranks (ratioed, so a loaded
-  CI box shifts both sides together);
+  sequential baseline between 32 and 128 ranks, by the count of frames
+  the coordinator receives a gather (no wall-clock);
 - an injected kill surfaces typed PeerFailure attribution naming the
   dead rank on the survivors, flat and tree.
 """
@@ -49,22 +49,28 @@ def test_256_rank_world_completes_negotiation_and_allreduce():
 
 
 def test_tree_gather_grows_sublinearly_vs_flat():
-    """The tentpole claim, pinned at CI-safe sizes: growing the world
-    32 -> 128 (4x) must grow the tree gather's mean latency by LESS
-    than it grows the flat star's. Ratio-of-ratios, so machine speed
-    and load cancel; 1.35x headroom on top keeps a noisy box green
-    while still failing if the tree gather ever degenerates to
-    sequential behavior."""
+    """The tentpole claim, pinned at CI-safe sizes and by COUNT: the
+    frames the coordinator receives one after the other in a gather
+    (``gather_frames``, counted where it receives them). Growing the
+    world 32 -> 128 (4x) grows the flat star's 31 -> 127; the tree's
+    stay its fanout. The gather's latency histogram is still read, as
+    plumbing; no wall-clock ratio is asserted (under six xdist workers
+    the flat star's 8 ms gathers and the tree's 4 ms wander by more
+    than their difference)."""
 
-    def gather_mean(ranks, fanout):
+    def frames_a_gather(ranks, fanout):
         rep = _run(ranks, tree_fanout=fanout, elems=64, rounds=6)
         assert rep["rc"] == 0, rep
         h = rep["phases"]["gather"]
-        return h["sum_us"] / h["count"]
+        assert h["count"] == 6 and h["sum_us"] > 0, rep
+        assert rep["gather_frames"] % h["count"] == 0, rep
+        return rep["gather_frames"] // h["count"]
 
-    flat_growth = gather_mean(128, 0) / max(gather_mean(32, 0), 1.0)
-    tree_growth = gather_mean(128, 8) / max(gather_mean(32, 8), 1.0)
-    assert tree_growth < flat_growth * 1.35, (
+    flat = [frames_a_gather(n, 0) for n in (32, 128)]
+    tree = [frames_a_gather(n, 8) for n in (32, 128)]
+    assert flat == [31, 127] and tree == [8, 8], (flat, tree)
+    flat_growth, tree_growth = flat[1] / flat[0], tree[1] / tree[0]
+    assert tree_growth < flat_growth, (
         f"tree gather grew {tree_growth:.2f}x from 32->128 ranks vs "
         f"flat {flat_growth:.2f}x — not sub-linear vs the baseline")
 

@@ -7,9 +7,8 @@ Pins, in order of how expensively they were learned:
   "(r+1)%N" off-by-one can no longer be re-derived wrong;
 - bucket layout: dtype grouping, bucket_bytes chunking, padding to the
   shard count, pack/unpack roundtrip, shard-aligned boundaries;
-- pack stays LAYOUT-EXACT for GSPMD-sharded leaves (the jax-0.4.x CPU
-  concatenate miscompile this module's dynamic_update_slice pack dodges
-  — see BucketLayout.pack);
+- pack stays LAYOUT-EXACT for GSPMD-sharded leaves (see
+  BucketLayout.pack);
 - sharded-vs-replicated parity at N in {2, 4}: grads (via loss),
   params, and optimizer state of the zero split step match the r06
   replicated ``fused_adam`` step and ``optax.adam``, for both the plain
@@ -171,11 +170,10 @@ def test_pack_shard_equals_sliced_pack():
 
 
 def test_pack_of_sharded_leaves_is_layout_exact():
-    """THE reason pack uses dynamic_update_slice: on this substrate a
-    jitted concatenate-of-reshape over an axis-sharded leaf returns the
-    physical per-device layout (strided garbage). Run the repro in a
-    subprocess with 4 forced host devices and pin pack's output against
-    the unsharded truth."""
+    """Pack of an axis-sharded leaf in a subprocess with 4 forced host
+    devices, against the unsharded truth (an earlier jax returned the
+    physical per-device layout from a jitted concatenate-of-reshape
+    here, which is why pack writes update-slices)."""
     import subprocess
     import sys
 
