@@ -2,11 +2,12 @@
 beyond the dense decoder. What the program's kernels, sorts, scans and
 remat modes are compared against (tests/single/test_olmoe_reference.py,
 tests/single/test_afmoe_reference.py, tests/single/test_lfm2_reference.py,
-tests/single/test_qwen3next_reference.py; the chip benchmark keeps
-copies of its own, chipbench/models/olmoe.py, afmoe.py, lfm2moe.py and
-qwen3next.py). OLMoE first; Trinity-Mini (afmoe), LFM2-8B-A1B
-(lfm2_moe) and Qwen3-Next-80B-A3B (qwen3_next) below it, each with its
-own description.
+tests/single/test_qwen3next_reference.py,
+tests/single/test_jamba_reference.py; the chip benchmark keeps copies of
+its own, chipbench/models/olmoe.py, afmoe.py, lfm2moe.py, qwen3next.py
+and jamba.py). OLMoE first; Trinity-Mini (afmoe), LFM2-8B-A1B
+(lfm2_moe), Qwen3-Next-80B-A3B (qwen3_next) and AI21-Jamba2-3B (jamba)
+below it, each with its own description.
 
 OLMoE (arXiv:2409.02060; Hugging Face ``modeling_olmoe.py``), as
 published:
@@ -620,6 +621,157 @@ def qwen3next_loss(params, batch, cfg, vocab_rows=None):
     the first that many rows of the vocabulary, the other logits
     removed. ``jax.grad`` of this is the reference gradient."""
     logits = qwen3next_forward(params, batch["tokens"], cfg)
+    logp = jax.nn.log_softmax(logits[..., :vocab_rows], -1)
+    nll = -jnp.take_along_axis(logp, batch["targets"][..., None],
+                               -1)[..., 0]
+    mask = batch.get("mask", jnp.ones_like(nll))
+    return jnp.sum(nll * mask) / jnp.sum(mask)
+
+
+# ---------------------------------------------------------------------
+# AI21-Jamba2-3B (AI21 Labs, ``model_type`` ``jamba``; Hugging Face
+# ``modeling_jamba.py``), as published. Pre-norm, two RMSNorms a layer
+# and one after the last, eps ``rms_norm_eps``, NO position encoding
+# anywhere: ``x = x + Mixer(RMS(x))``, then ``x = x + FFN(RMS(x))``.
+# Layer ``i`` is an attention layer where ``i % attn_layer_period ==
+# attn_layer_offset`` (14 and 7), else a Mamba layer (``layer_types``
+# ``full_attention`` and ``mamba`` in the program's configuration).
+#
+# - a ``mamba`` layer's mixer (``d_inner = mamba_expand * hidden``
+#   channels, ``N = mamba_d_state`` states a channel, ``R =
+#   mamba_dt_rank``), a sequence at a time:
+#   1. ``[u, z] = h W_in`` (``ssm_in``, columns ``[u | z]``), no bias;
+#   2. ``u = SiLU(conv(u) + b_conv)``: depthwise, causal,
+#      ``mamba_d_conv`` taps (``ssm_conv`` [taps, channels], tap ``j``
+#      meeting ``u_{t - (taps-1) + j}``, zero before position 0;
+#      ``nn.Conv1d`` with ``groups = d_inner``, padding ``taps - 1``, the
+#      tail cut), bias ``ssm_conv_bias`` (``mamba_conv_bias``);
+#   3. ``[r, B, C] = u W_x`` (``ssm_x``, columns ``[r | B | C]``, R, N
+#      and N wide), no bias; each under an RMSNorm of its own
+#      (``dt_layernorm``, ``b_layernorm``, ``c_layernorm``: gains
+#      ``ssm_dt_norm``, ``ssm_b_norm``, ``ssm_c_norm``);
+#   4. ``dt = softplus(r W_dt + b_dt)`` (``ssm_dt``, ``ssm_dt_bias``);
+#      ``A = -exp(A_log)`` (``ssm_a_log`` [channels, N]);
+#   5. a channel's state ``s`` [N], ``s_0 = 0``; for each token ``t``:
+#      ``s_t = exp(dt_t A) * s_{t-1} + dt_t u_t B_t``;
+#      ``y_t = s_t . C_t + D u_t`` (``ssm_d``);
+#   6. ``(y * SiLU(z)) W_out`` (``ssm_out``), no bias;
+# - an attention layer's: ``q = h W_q``, ``k = h W_k``, ``v = h W_v``,
+#   ``n_heads`` query heads on ``n_kv_heads`` key/value heads (20 on 1),
+#   causal ``softmax(q k / sqrt(head_dim)) v``, ``W_o``. No RoPE, no
+#   bias, no gate, no q/k norm;
+# - FFN: a SwiGLU of width ``d_ff`` in EVERY layer (``num_experts`` 1:
+#   ``expert_layer_offset`` / ``_period`` then select layers whose one
+#   "expert" is the same SwiGLU; no router, no auxiliary loss);
+# - ``logits = E RMS_final(x)``: the head is the embedding matrix ``E``
+#   (``tie_word_embeddings``); loss = mean token cross-entropy.
+#
+# Departures: parameters stored in bf16 are read as float32;
+# ``num_logits_to_keep`` and ``use_mamba_kernels`` are runtime keys and
+# change no result. Step 5 is a ``lax.scan`` over TOKENS exactly as
+# written: no chunk, no kernel, nothing of ops/selective_scan.py. It
+# finds a layer's parameters in the program's tree by its own count.
+# ---------------------------------------------------------------------
+
+def jamba_selective_scan(u, dt, A, Bm, Cm, D):
+    """Step 5 for ``u``, ``dt`` [B, T, C], ``A`` [C, N], ``Bm``, ``Cm``
+    [B, T, N], ``D`` [C], float32 -> ``y`` [B, T, C]."""
+    def token(s, x):
+        u, dt, Bt, Ct = x                       # [B, C], [B, C], [B, N]
+        s = jnp.exp(dt[..., None] * A) * s \
+            + (dt * u)[..., None] * Bt[:, None, :]
+        return s, jnp.sum(s * Ct[:, None, :], -1) + D * u
+
+    b, _, c = u.shape
+    _, y = jax.lax.scan(
+        token, jnp.zeros((b, c, A.shape[1]), F32),
+        tuple(jnp.moveaxis(x, 1, 0) for x in (u, dt, Bm, Cm)))
+    return jnp.moveaxis(y, 0, 1)
+
+
+def jamba_mamba_mixer(h, lp, cfg):
+    """The mamba mixer on normalized ``h`` [B, T, D] with one layer's
+    float32 parameters (steps 1-6 above)."""
+    b, t, _ = h.shape
+    n, r = cfg.mamba_d_state, cfg.mamba_dt_rank
+    uz = h @ lp["ssm_in"]
+    di = uz.shape[-1] // 2
+    u, z = uz[..., :di], uz[..., di:]
+    taps, conv = lp["ssm_conv"].shape[0], jnp.zeros_like(u)
+    for j in range(taps):
+        back = taps - 1 - j                  # u as it was ``back`` ago
+        conv = conv + lp["ssm_conv"][j] * jnp.concatenate(
+            [jnp.zeros((b, back, di), F32), u[:, :t - back]], 1)
+    u = jax.nn.silu(conv + lp.get("ssm_conv_bias", 0.0))
+    rbc = u @ lp["ssm_x"]
+    dt = jax.nn.softplus(
+        _rms(rbc[..., :r], lp["ssm_dt_norm"], cfg.norm_eps) @ lp["ssm_dt"]
+        + lp["ssm_dt_bias"])
+    y = jamba_selective_scan(
+        u, dt, -jnp.exp(lp["ssm_a_log"]),
+        _rms(rbc[..., r:r + n], lp["ssm_b_norm"], cfg.norm_eps),
+        _rms(rbc[..., r + n:], lp["ssm_c_norm"], cfg.norm_eps),
+        lp["ssm_d"])
+    return (y * jax.nn.silu(z)) @ lp["ssm_out"]
+
+
+def _jamba_layer(params, cfg, l):
+    """Layer ``l``'s parameters out of the program's tree, float32: the
+    attention layers are one stack, ``layers``, and each RUN of
+    consecutive mamba layers a stack of its own: ``mamba_layers``, then
+    ``mamba_1_layers`` and so on."""
+    types = cfg.layer_types
+
+    def stack(i):
+        if types[i] != "mamba":
+            return "layers"
+        run = sum(types[j] == "mamba" and (j == 0 or types[j - 1] != "mamba")
+                  for j in range(i + 1)) - 1
+        return f"mamba_{run}_layers" if run else "mamba_layers"
+
+    at = sum(stack(i) == stack(l) for i in range(l))
+    return jax.tree.map(lambda w: w[at].astype(F32), params[stack(l)])
+
+
+def jamba_forward(params, tokens, cfg):
+    """tokens [B, T] -> logits [B, T, vocab] f32 (see the description
+    above). ``params`` is the program's tree, any storage dtype."""
+    hd = cfg.head_dim
+    rep = cfg.n_heads // cfg.n_kv_heads
+    b, t = tokens.shape
+    mask = jnp.arange(t)[None, :] <= jnp.arange(t)[:, None]
+    embed = params["embed"].astype(F32)
+    with jax.default_matmul_precision("highest"):
+        x = embed[tokens]
+        for l in range(cfg.n_layers):
+            lp = _jamba_layer(params, cfg, l)
+            if cfg.layer_types[l] == "mamba":
+                x = x + jamba_mamba_mixer(
+                    _rms(x, lp["ssm_norm"], cfg.norm_eps), lp, cfg)
+            else:
+                h = _rms(x, lp["attn_norm"], cfg.norm_eps)
+                q = (h @ lp["wq"]).reshape(b, t, cfg.n_heads, hd)
+                k = (h @ lp["wk"]).reshape(b, t, cfg.n_kv_heads, hd)
+                v = (h @ lp["wv"]).reshape(b, t, cfg.n_kv_heads, hd)
+                k, v = jnp.repeat(k, rep, 2), jnp.repeat(v, rep, 2)
+                s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / (hd ** 0.5)
+                p = jax.nn.softmax(jnp.where(mask, s, -jnp.inf), -1)
+                a = jnp.einsum("bhqk,bkhd->bqhd", p, v).reshape(b, t, -1)
+                x = x + a @ lp["wo"]
+            h = _rms(x, lp["mlp_norm"], cfg.norm_eps)
+            x = x + _swiglu(h, lp["w_gate"], lp["w_up"], lp["w_down"])
+        x = _rms(x, params["final_norm"].astype(F32), cfg.norm_eps)
+        return x @ (embed.T if cfg.tie_embeddings
+                    else params["lm_head"].astype(F32))
+
+
+def jamba_loss(params, batch, cfg, vocab_rows=None):
+    """Mean token cross-entropy over the positions ``batch["mask"]``
+    keeps (all without one); no aux term. ``vocab_rows``: the loss over
+    the first that many rows of the vocabulary, the other logits
+    removed. ``jax.grad`` of this is the reference gradient; the tied
+    matrix's is the sum of its two uses."""
+    logits = jamba_forward(params, batch["tokens"], cfg)
     logp = jax.nn.log_softmax(logits[..., :vocab_rows], -1)
     nll = -jnp.take_along_axis(logp, batch["targets"][..., None],
                                -1)[..., 0]

@@ -22,8 +22,12 @@ Why two programs instead of one fused train-step jit:
 The two programs are connected by DONATED gradient buffers: the first
 microbatch's gradient outputs become the accumulator and each
 accumulation step donates it forward, so exactly one params-sized
-gradient tree is live per step. The apply program donates only params
-and optimizer state — its outputs are exactly one params tree plus one
+gradient tree is live per step. Where the device has no room for the
+gradients of a second step beside the first's (``holds_two_gradients``),
+the single-microbatch grad program takes the buffers the apply has just
+read as a donated argument and writes into them (``grad_program``): one
+set of gradient buffers goes round, and a step can be enqueued ahead.
+The apply program donates only params and optimizer state — its outputs are exactly one params tree plus one
 state tree, which those donate into 1:1, so a donated gradient tree
 could never alias an output and only produced XLA's "donated buffers
 were not usable" warning (see apply_fn below).
@@ -84,6 +88,81 @@ def _split_microbatches(batch, n):
     mb = b // n
     return [jax.tree.map(lambda x: x[i * mb:(i + 1) * mb], batch)
             for i in range(n)]
+
+
+def holds_two_gradients(params, opt):
+    """Whether the device that holds ``params`` has room for a SECOND
+    set of gradients beside the state of a step: parameters, optimizer
+    state, the gradients being applied, the gradients of the step
+    enqueued behind them, and one gradient's worth of temporaries. Read
+    off the device's own account (``memory_stats()["bytes_limit"]``); a
+    device that gives none (the CPU), or parameters that are being
+    traced, hold whatever is asked of them."""
+    leaves = jax.tree.leaves(params)
+    if not leaves or any(isinstance(x, jax.core.Tracer) for x in leaves):
+        return True
+    stats = next(iter(leaves[0].devices())).memory_stats() or {}
+    if "bytes_limit" not in stats:
+        return True
+    def size(tree):
+        return sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(tree))
+
+    return size(params) * 4 + size(opt) <= stats["bytes_limit"]
+
+
+# Gradient buffers that nothing reads any more, by what they hold (tree
+# and leaves' shapes and dtypes): what the recycled grad program of the
+# next step writes into. One set a model goes round through here, and a
+# second step object of the same model in the process (a benchmark's
+# check of the step it timed) takes the set the first one left.
+_SPARE_GRADIENTS = {}
+
+
+def _held(tree):
+    leaves, treedef = jax.tree.flatten(tree)
+    return treedef, tuple((x.shape, x.dtype) for x in leaves)
+
+
+def spare_gradients(params):
+    """Gradient buffers for ``params`` to hand to the recycled grad
+    program (which consumes them): the set the last step left, else
+    zeros."""
+    spare = _SPARE_GRADIENTS.pop(_held(params), None)
+    if spare is None or any(x.is_deleted() for x in jax.tree.leaves(spare)):
+        spare = jax.tree.map(jnp.zeros_like, params)
+    return spare
+
+
+def leave_gradients(grads):
+    """``grads`` have been handed to the apply program: the next
+    recycled grad program may write into them."""
+    _SPARE_GRADIENTS[_held(grads)] = grads
+
+
+def drop_spare_gradients():
+    """Free the gradient buffers left for a next step that will not
+    come (a process that goes on to other work on a full device)."""
+    _SPARE_GRADIENTS.clear()
+
+
+def grad_program(loss_fn, recycled, jit_kwargs):
+    """The split step's grad program, ``jit_hvd_grad`` in a device
+    trace: ``(params, batch) -> (loss, grads)``. ``recycled``: it takes
+    a third argument, the gradient buffers the apply program has just
+    read, DONATED, and writes its gradients into them, so that
+    enqueuing a step allocates no second set of gradients (the argument
+    is read by nothing: ``keep_unused`` keeps it for the aliasing)."""
+    if recycled:
+        def hvd_grad(p, d, spare):
+            return jax.value_and_grad(loss_fn)(p, d)
+
+        return jax.jit(hvd_grad, donate_argnums=(2,), keep_unused=True,
+                       **jit_kwargs)
+
+    def hvd_grad(p, d):
+        return jax.value_and_grad(loss_fn)(p, d)
+
+    return jax.jit(hvd_grad, **jit_kwargs)
 
 
 def _register_split_flops(timer, programs):
@@ -292,14 +371,31 @@ def make_split_train_step(loss_fn, optimizer, *, microbatches=1,
     if zero is None:   # the ZeRO apply is no one program
         files_itself(run, "apply", apply_fn)
     if n == 1:
-        def hvd_grad(p, d):
-            return jax.value_and_grad(loss_fn)(p, d)
-
-        grad_fn = jax.jit(hvd_grad, **jk)
+        grad_fn = grad_program(loss_fn, False, jk)
         files_itself(run, "grad", grad_fn)
+        run.recycled = None    # decided at the first step
+
+        def decide(params, opt):
+            """The first step's, once: a state that fills the device
+            (1.6 B parameters at 8 B each on 16 GB). The step enqueued
+            behind this one could not allocate its gradients while these
+            are alive, so every enqueue would wait for the apply, the
+            host on the device's critical path; one set of buffers goes
+            round instead (PERF.md section 6, PR 47)."""
+            run.recycled = not holds_two_gradients(params, opt)
+            if run.recycled:
+                files_itself(run, "grad", grad_program(loss_fn, True, jk))
 
         def step(carry, batch):
             params, opt = carry
+            if run.recycled is None:
+                decide(params, opt)
+            if run.recycled:
+                loss, grads = run.grad(params, batch,
+                                       spare_gradients(params))
+                params, opt = run.apply(grads, params, opt)
+                leave_gradients(grads)
+                return loss, (params, opt)
             loss, grads = run.grad(params, batch)
             params, opt = run.apply(grads, params, opt)
             return loss, (params, opt)
@@ -349,8 +445,12 @@ def make_split_train_step(loss_fn, optimizer, *, microbatches=1,
             params, opt = carry
             if n == 1:
                 g_abs = jax.eval_shape(grad_fn, params, batch)
-                return [(grad_fn, (params, batch), 1),
-                        (apply_fn, (g_abs[1], params, opt), 1)]
+                if holds_two_gradients(params, opt):
+                    grad = (grad_fn, (params, batch), 1)
+                else:
+                    grad = (grad_program(loss_fn, True, jk),
+                            (params, batch, g_abs[1]), 1)
+                return [grad, (apply_fn, (g_abs[1], params, opt), 1)]
             mb0 = _split_microbatches(batch, n)[0]
             l_abs, g_abs = jax.eval_shape(grad_first, params, mb0)
             return [(grad_first, (params, mb0), 1),

@@ -353,6 +353,61 @@ def test_three_layers_of_the_rule_lower_each_kernel_once(for_tpu, v5e_chip):
     assert text.count("call @_kernel_bwd") == 3
 
 
+def _selective_scan_fwd_bwd(u, dt, A, Bm, Cm, D):
+    from horovod_tpu.ops.selective_scan import selective_scan
+
+    return jax.grad(lambda *a: selective_scan(*a).astype(F32).sum(),
+                    argnums=(0, 1, 2, 3, 4, 5))(u, dt, A, Bm, Cm, D)
+
+
+# Jamba2-3B's mamba mixer at the chip cell's size: B1 T8192, 5120
+# channels of 16 states.
+_SCAN = (((1, 8192, 5120), BF16), ((1, 8192, 5120), F32),
+         ((5120, 16), F32), ((1, 8192, 16), BF16), ((1, 8192, 16), BF16),
+         ((5120,), F32))
+_SCAN_KERNELS = ("hvd_ssm_scan_fwd", "hvd_ssm_scan_bwd")
+
+
+def test_selective_scan_compiles_for_described_v5e(for_tpu):
+    """Jamba2's selective scan at the chip cell's size, forward and
+    backward, as the chip's compiler takes it: the kernel pair, each by
+    the name a device trace shows (``kernel_metadata``), and no
+    ``while`` over tokens or chunks is left; the states kept are the 64
+    CHUNKS' ([1, 64, 16, 5120] float32), and nothing the size of the
+    scan materialised ([8192, 5120, 16] in any order) exists."""
+    text = for_tpu(_selective_scan_fwd_bwd, *_SCAN)
+    for name in _SCAN_KERNELS:
+        assert f'"kernel":"{name}"' in text, name
+    assert " while(" not in text
+    assert "f32[1,64,16,5120]" in text            # the states kept
+    assert not re.search(r"\[(1,)?(8192,5120,16|8192,16,5120|"
+                         r"5120,16,8192|16,5120,8192)\]", text)
+
+
+def test_thirteen_layers_of_the_scan_lower_each_kernel_once(v5e_chip,
+                                                           for_tpu):
+    """The set-up budget's guard (PERF.md section 6, PR 44's mechanism):
+    behind ONE jitted wrapper the thirteen layers' forward, forward
+    again under remat, and backward lower to one private function a
+    kernel form that every site calls: the backward once, the forward
+    twice (keeping the chunks' states, and not)."""
+    from horovod_tpu.ops.selective_scan import selective_scan
+
+    def loss(u, dt, A, Bm, Cm, D):
+        for _ in range(13):
+            u = jax.checkpoint(selective_scan)(u, dt, A, Bm, Cm, D)
+        return u.astype(F32).sum()
+
+    args = [jax.ShapeDtypeStruct(s, d, sharding=v5e_chip)
+            for s, d in _SCAN]
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4, 5))).lower(
+        *args).as_text()
+    assert [text.count(name) for name in _SCAN_KERNELS] == [2, 1]
+    assert text.count("tpu_custom_call") == 3
+    assert text.count("call @_kernel_fwd") >= 25  # the sites are calls
+    assert text.count("call @_kernel_bwd") == 13
+
+
 _CHAIN_KERNELS = ("hvd_gdn_chain_in_fwd", "hvd_gdn_chain_in_bwd",
                   "hvd_gdn_chain_out_fwd", "hvd_gdn_chain_out_bwd")
 # Qwen3-Next's linear mixer at the chip cell's size: B2 T8192, 16 key
