@@ -709,15 +709,34 @@ def _rope(x, positions, theta, rotary=0):
     return turned if rest is None else jnp.concatenate([turned, rest], -1)
 
 
+def _over_sequence(mesh, seq_axis):
+    """True where ``mesh`` splits the sequence: attention then runs on a
+    ring or Ulysses, and the flash kernels nowhere."""
+    return bool(mesh is not None and seq_axis
+                and mesh.shape.get(seq_axis, 1) > 1)
+
+
+def _kernel_mesh(mesh):
+    """The mesh a Mosaic call shards itself over (GSPMD cannot partition
+    one): ``mesh``, except under pipelining, where the layer body
+    already runs inside the pipeline's own shard_map."""
+    if mesh is not None and mesh.shape.get("pipe", 1) > 1:
+        return None
+    return mesh
+
+
 @scope("hvd.attn.core")
 def _attention(q, k, v, mesh, seq_axis, seq_parallel="ring",
-               flash_block=0, window=0):
+               flash_block=0, window=0, head_major=False):
+    """``q`` [B, T, H, D], ``k``, ``v`` [B, T, Hkv, D] -> [B, T, H, D];
+    ``head_major``: the operands arrive as the flash kernels take them,
+    [B, H, T, D] (``ops/qk_prep.py``; never over a sequence axis)."""
     # remat="attn" naming: the SP paths name their OUTPUT ("attn_out");
     # the flash path names its custom-VJP residuals internally
     # (flash_o/flash_lse) instead — naming the transposed output TOO
     # would save a ~671 MB duplicate of flash_o at bench shapes (the
     # transpose is a distinct buffer) for no backward work saved.
-    if mesh is not None and seq_axis and mesh.shape.get(seq_axis, 1) > 1:
+    if _over_sequence(mesh, seq_axis):
         if window:
             raise ValueError("sliding-window layers run on the flash "
                              "kernel only: no sequence-parallel mesh "
@@ -741,23 +760,30 @@ def _attention(q, k, v, mesh, seq_axis, seq_parallel="ring",
     # owns the remat naming for both of its paths: the pallas kernels
     # name their VJP residuals (flash_o/flash_lse), the off-TPU
     # fallback names its output attn_out.
-    from horovod_tpu.ops import flash_attention
+    from horovod_tpu.ops.flash_attention import (
+        flash_attention, flash_attention_head_major)
 
-    # The kernel shards itself over the mesh (a Mosaic call cannot be
-    # partitioned by GSPMD) — except under pipelining, where this body
-    # already runs inside the pipeline's own shard_map.
-    if mesh is not None and mesh.shape.get("pipe", 1) > 1:
-        mesh = None
     blocks = {"block_q": flash_block, "block_k": flash_block} \
         if flash_block else {}
     if window:
         blocks["window"] = window
-    return flash_attention(q, k, v, causal=True, mesh=mesh, **blocks)
+    return (flash_attention_head_major if head_major else flash_attention)(
+        q, k, v, causal=True, mesh=_kernel_mesh(mesh), **blocks)
 
 
 def _activation_spec(mesh):
     """[B, T, D] activations: batch over data+fsdp, seq over seq axis."""
     return P(("data", "fsdp"), "seq", None)
+
+
+def _flat_proj(h, w, gain, c):
+    """``h [..., D] @ w`` as the matmul leaves it, ``[..., heads *
+    head_dim]``, behind the RMSNorm over the whole projected width where
+    the configuration has that one (``qk_norm`` True)."""
+    y = h @ w.astype(c.compute_dtype)
+    if gain is not None and c.qk_norm != "head":
+        y = _rmsnorm(y, gain.astype(c.compute_dtype), c.norm_eps)
+    return y
 
 
 def _head_proj(h, w, gain, c):
@@ -766,9 +792,7 @@ def _head_proj(h, w, gain, c):
     width comes first, or (``qk_norm="head"``) over each head after.
     The ONE q/k/v projection of training, prefill and cached decode
     (models/generate.py)."""
-    y = h @ w.astype(c.compute_dtype)
-    if gain is not None and c.qk_norm != "head":
-        y = _rmsnorm(y, gain.astype(c.compute_dtype), c.norm_eps)
+    y = _flat_proj(h, w, gain, c)
     y = y.reshape(*y.shape[:-1], -1, c.head_dim)
     if gain is not None and c.qk_norm == "head":
         # Over each head's own width, one gain shared by the heads.
@@ -782,6 +806,25 @@ def _project_qkv(h, lp, c):
     return (_head_proj(h, lp["wq"], lp["q_norm"] if c.qk_norm else None, c),
             _head_proj(h, lp["wk"], lp["k_norm"] if c.qk_norm else None, c),
             _head_proj(h, lp["wv"], None, c))
+
+
+def _prepared_qkv(h, lp, c, positions, rope, mesh):
+    """``_project_qkv`` and (where the layer has ``rope``) ``_rope`` for
+    the flash kernels, HEAD-MAJOR: q [B, H, T, d], k, v [B, Hkv, T, d]. The
+    projections stay as the matmuls leave them and ``ops/qk_prep.py``'s
+    kernel pair does the rest in one pass (``qk_prep.on_kernels`` says
+    where)."""
+    from horovod_tpu.ops.qk_prep import qk_prep
+
+    dt = c.compute_dtype
+    with scope("hvd.attn.proj"):
+        flat = [_flat_proj(h, lp[w], lp[g] if g and c.qk_norm else None, c)
+                for w, g in (("wq", "q_norm"), ("wk", "k_norm"),
+                             ("wv", None))]
+    gains = [lp[g].astype(dt) if c.qk_norm == "head" else None
+             for g in ("q_norm", "k_norm")]
+    return qk_prep(*flat, *gains, positions, c.rope_theta if rope else None,
+                   c.head_dim, c.norm_eps, _kernel_mesh(mesh))
 
 
 @scope("hvd.conv.chain")
@@ -1487,21 +1530,31 @@ def _build_layer_body(c, mesh, seq_axis, constrain_acts=True, kind=None):
         bb, tt = x.shape[0], x.shape[1]
         positions = jnp.broadcast_to(jnp.arange(tt), (bb, tt))
         h = _rmsnorm(x, lp["attn_norm"].astype(dt), c.norm_eps)
-        q, kk, vv = _project_qkv(h, lp, c)
+        # One pass on the chip (ops/qk_prep.py), the expressions
+        # elsewhere: which, is read off the input.
+        from horovod_tpu.ops import qk_prep
+
+        head_major = qk_prep.on_kernels(
+            x, c.head_dim, c.qk_norm == "head",
+            (c.partial_rotary or c.head_dim) if rope else 0,
+            _over_sequence(mesh, seq_axis))
+        if head_major:
+            q, kk, vv = _prepared_qkv(h, lp, c, positions, rope, mesh)
+        else:
+            q, kk, vv = _project_qkv(h, lp, c)
+            if rope:
+                q = _rope(q, positions, c.rope_theta, c.partial_rotary)
+                kk = _rope(kk, positions, c.rope_theta, c.partial_rotary)
         # Named for remat="attn+gate+qkv": saving the POST-rope q/k and
         # v ([B,T,H(kv),D] bf16 — ~67 MB/layer at bench shapes) lets
         # backward skip the wq/wk/wv matmul + rope re-runs entirely
         # (attn_out/flash_o already cover wo's operands).
-        if rope:
-            q = _rope(q, positions, c.rope_theta, c.partial_rotary)
         q = checkpoint_name(q, "rope_q")
-        if rope:
-            kk = _rope(kk, positions, c.rope_theta, c.partial_rotary)
         kk = checkpoint_name(kk, "rope_k")
         vv = checkpoint_name(vv, "attn_v")
         # remat="attn" save-names applied inside _attention (per path).
         attn = _attention(q, kk, vv, mesh, seq_axis, c.seq_parallel,
-                          c.flash_block, window)
+                          c.flash_block, window, head_major)
         with scope("hvd.attn.proj"):
             attn = attn.reshape(bb, tt, -1)
             if c.attn_gate:

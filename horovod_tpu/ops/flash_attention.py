@@ -697,24 +697,74 @@ def _masked_attention_xla(q, k, v, kv_bias, causal):
     return jnp.einsum("bhqk,bkhd->bqhd", p, v)
 
 
-def _kernel_mesh_specs(mesh, q, k):
+def _kernel_mesh_specs(mesh, batch_size, heads, kv_heads):
     """PartitionSpecs that split the kernel call over ``mesh``: batch
     over the data-parallel axes, heads over ``tensor`` when it divides
     both head counts (GQA groups stay aligned), everything else whole.
-    Returns ``(qkv_spec_for(heads), bias_spec)``."""
-    from jax.sharding import PartitionSpec as P
-
+    Returns ``(batch axes, heads axis)``, either None where the mesh
+    does not split it."""
     batch = tuple(a for a in ("data", "fsdp") if mesh.shape.get(a, 1) > 1)
     n_batch = math.prod(mesh.shape[a] for a in batch)
-    if q.shape[0] % n_batch:
+    if batch_size % n_batch:
         raise ValueError(
-            f"flash_attention: batch {q.shape[0]} does not divide over "
+            f"flash_attention: batch {batch_size} does not divide over "
             f"mesh axes {batch} (size {n_batch}); the pallas kernel "
             "cannot pad a shard the way GSPMD would")
     tp = mesh.shape.get("tensor", 1)
-    heads = "tensor" if tp > 1 and q.shape[2] % tp == 0 \
-        and k.shape[2] % tp == 0 else None
-    return P(batch or None, None, heads, None), P(batch or None, None)
+    return batch or None, "tensor" if tp > 1 and heads % tp == 0 \
+        and kv_heads % tp == 0 else None
+
+
+def _head_major(qt, kt, vt, kv_bias=None, *, causal, block_q, block_k,
+                window):
+    """The kernels on their own layout: ``qt`` [B, H, T, D], ``kt``,
+    ``vt`` [B, Hkv, T, D] (the kernels index kv-head = query-head //
+    n_rep, so GQA expansion never hits HBM) -> [B, T, H, D]."""
+    t = qt.shape[2]
+    bq = _pick_block(t, block_q)
+    bk = _pick_block(t, block_k)
+    if kv_bias is not None:
+        bias = kv_bias.astype(jnp.float32)[:, None, :]  # [B, 1, Tk]
+        o = _flash_biased(qt, kt, vt, bias, causal, bq, bk)
+    else:
+        o = _flash(qt, kt, vt, causal, bq, bk, window)
+    return o.transpose(0, 2, 1, 3)
+
+
+def _over_mesh(kernel, mesh, operands, heads_at):
+    """``kernel`` on ``operands`` (q, k, v and perhaps a [B, Tk] bias),
+    whose heads are dimension ``heads_at`` -> [B, T, H, D]; each device
+    of a multi-device ``mesh`` on its own shard."""
+    from jax.sharding import PartitionSpec as P
+
+    if mesh is None or mesh.size == 1:
+        return kernel(*operands)
+    q, k = operands[:2]
+    batch, heads = _kernel_mesh_specs(mesh, q.shape[0], q.shape[heads_at],
+                                      k.shape[heads_at])
+    spec = [batch, None, None, None]
+    spec[heads_at] = heads
+    in_specs = (P(*spec),) * 3 + (P(batch, None),) * (len(operands) - 3)
+    return jax.shard_map(kernel, mesh=mesh, in_specs=in_specs,
+                         out_specs=P(batch, None, heads, None),
+                         check_vma=False)(*operands)
+
+
+def flash_attention_head_major(q, k, v, causal=True, block_q=1024,
+                               block_k=1024, mesh=None, window=0):
+    """:func:`flash_attention` for operands that arrive as the kernels
+    take them (``ops/qk_prep.py`` writes them so): ``q`` [B, H, T, D],
+    ``k``, ``v`` [B, Hkv, T, D] -> [B, T, H, D], with no transpose in
+    front of the kernel. No bias; off the TPU the same reference math."""
+    if window and not causal:
+        raise ValueError("flash_attention: a window needs causal=True")
+    if not use_pallas("flash_attention", (q, k, v), _INTERPRET):
+        return flash_attention(*(x.transpose(0, 2, 1, 3) for x in (q, k, v)),
+                               causal=causal, window=window)
+    return _over_mesh(
+        functools.partial(_head_major, causal=causal, block_q=block_q,
+                          block_k=block_k, window=window),
+        mesh, (q, k, v), 1)
 
 
 def flash_attention(q, k, v, causal=True, kv_bias=None, block_q=1024,
@@ -775,27 +825,9 @@ def flash_attention(q, k, v, causal=True, kv_bias=None, block_q=1024,
             "attn_out")
 
     def kernel(q, k, v, kv_bias=None):
-        # [B,T,H,D] -> [B,H,T,D]; k/v stay at Hkv heads — the kernels
-        # index kv-head = query-head // n_rep, so GQA expansion never
-        # hits HBM.
-        qt = q.transpose(0, 2, 1, 3)
-        kt = k.transpose(0, 2, 1, 3)
-        vt = v.transpose(0, 2, 1, 3)
-        t = qt.shape[2]
-        bq = _pick_block(t, block_q)
-        bk = _pick_block(t, block_k)
-        if kv_bias is not None:
-            bias = kv_bias.astype(jnp.float32)[:, None, :]  # [B, 1, Tk]
-            o = _flash_biased(qt, kt, vt, bias, causal, bq, bk)
-        else:
-            o = _flash(qt, kt, vt, causal, bq, bk, window)
-        return o.transpose(0, 2, 1, 3)
+        return _head_major(*(x.transpose(0, 2, 1, 3) for x in (q, k, v)),
+                           kv_bias, causal=causal, block_q=block_q,
+                           block_k=block_k, window=window)
 
-    operands = (q, k, v) if kv_bias is None else (q, k, v, kv_bias)
-    if mesh is None or mesh.size == 1:
-        return kernel(*operands)
-    qkv_spec, bias_spec = _kernel_mesh_specs(mesh, q, k)
-    in_specs = (qkv_spec,) * 3 + ((bias_spec,) if kv_bias is not None
-                                  else ())
-    return jax.shard_map(kernel, mesh=mesh, in_specs=in_specs,
-                         out_specs=qkv_spec, check_vma=False)(*operands)
+    return _over_mesh(kernel, mesh,
+                      (q, k, v) if kv_bias is None else (q, k, v, kv_bias), 2)

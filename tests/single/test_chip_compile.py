@@ -487,6 +487,80 @@ def test_three_mixers_lower_each_chain_kernel_once_a_form(v5e_chip,
             == sites, wrapper
 
 
+def _seam(norm, theta, d):
+    """The seam at a cell's size, both directions: (yq, yk, yv and the
+    two gains) -> the gradients of a sum of squares over q, k and v (a
+    cotangent that needs the forward's values, or no forward runs)."""
+    def fwd_bwd(yq, yk, yv, gq, gk):
+        from horovod_tpu.ops.qk_prep import qk_prep
+
+        B, T = yq.shape[:2]
+        positions = jnp.broadcast_to(jnp.arange(T), (B, T))
+
+        def loss(yq, yk, yv, gq, gk):
+            gains = (gq, gk) if norm else (None, None)
+            return sum((x.astype(F32) ** 2).sum() for x in qk_prep(
+                yq, yk, yv, *gains, positions, theta, d, 1e-5))
+
+        return jax.grad(loss, (0, 1, 2, 3, 4) if norm else (0, 1, 2))(
+            yq, yk, yv, gq, gk)
+    return fwd_bwd
+
+
+@pytest.mark.parametrize("what, B, T, H, Hkv, d, norm, theta", [
+    # Trinity-Mini's window layers; its full layer (no RoPE: the same
+    # kernels on the table [1, 0]); Mistral's and OLMoE's; heads 256 wide
+    ("norm a head and RoPE", 2, 8192, 32, 4, 128, True, 1e4),
+    ("norm a head, no RoPE", 2, 8192, 32, 4, 128, True, None),
+    ("RoPE alone, 8 KV heads", 2, 4096, 32, 8, 128, False, 1e6),
+    ("heads of 256", 2, 8192, 16, 2, 256, True, 1e7),
+])
+def test_qk_prep_compiles_for_described_v5e(for_tpu, what, B, T, H, Hkv, d,
+                                            norm, theta):
+    """The seam's kernel pair at the chip cells' sizes as the chip's
+    compiler takes it (a grid step of 256 tokens at full width, the VMEM
+    it asks for itself), each kernel by the name a device trace shows."""
+    text = for_tpu(_seam(norm, theta, d), ((B, T, H * d), BF16),
+                   ((B, T, Hkv * d), BF16), ((B, T, Hkv * d), BF16),
+                   ((d,), BF16), ((d,), BF16))
+    for name in ("hvd_qk_prep_fwd", "hvd_qk_prep_bwd"):
+        assert f'"kernel":"{name}"' in text, (what, name)
+
+
+def test_layers_on_the_seam_lower_two_kernels_and_transpose_no_operand(
+        v5e_chip, monkeypatch):
+    """Three attention layers with a q/k norm a head, RoPE on two of
+    them (Trinity-Mini's pattern, remat "attn"), lowered for the
+    described chip: the seam's forward kernel twice (the recomputation
+    comes with a jaxpr of its own) and its backward once WHATEVER the
+    layers, the layer without RoPE among them; no transpose makes a
+    ``[B, Hkv, T, d]`` (k, v) and one a layer makes a ``[B, H, T, d]``
+    (``do``: ``o``'s way back is not the seam's; with the expressions
+    the forward's q, the recomputed q and ``do``)."""
+    from horovod_tpu.models import LlamaConfig, llama_init, llama_loss
+
+    monkeypatch.setattr(_platform, "operand_platform", lambda *a: "tpu")
+    S, Fl = "sliding_attention", "full_attention"
+    cfg = LlamaConfig(vocab_size=512, d_model=512, n_layers=3, n_heads=4,
+                      n_kv_heads=2, d_head=128, d_ff=512, qk_norm="head",
+                      layer_types=(S, S, Fl), sliding_window=1024,
+                      dtype="bfloat16", param_dtype="bfloat16", remat="attn")
+    tokens = jax.ShapeDtypeStruct((2, 2048), I32, sharding=v5e_chip)
+    params = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=v5e_chip),
+        jax.eval_shape(lambda k: llama_init(cfg, k), jax.random.PRNGKey(0)))
+    text = jax.jit(jax.grad(lambda p, d: llama_loss(p, d, cfg))).lower(
+        params, {"tokens": tokens, "targets": tokens}).as_text()
+    assert text.count("hvd_qk_prep_fwd") == 2
+    assert text.count("hvd_qk_prep_bwd") == 1
+    for wrapper, sites in (("_fwd", 6), ("_bwd", 3)):
+        assert len(re.findall(rf"call @{wrapper}(_\d+)?\(", text)) \
+            == sites, wrapper
+    made = re.findall(r"stablehlo\.transpose.*-> tensor<2x(\d+)x2048x128x",
+                      text)
+    assert made == ["4"] * 3, made
+
+
 def test_a_stack_under_remat_attn_holds_no_padded_statistics(for_tpu):
     """Three attention layers at Trinity-Mini's shape (B2 T8192, 32
     heads on 4 of 128), unrolled, each under

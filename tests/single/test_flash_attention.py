@@ -715,3 +715,54 @@ def test_window_public_api_both_paths_and_its_refusals():
     with pytest.raises(ValueError, match="window"):
         fa_mod.flash_attention(q, k, v, window=20,
                                kv_bias=jnp.zeros((1, 72)))
+
+
+@pytest.mark.parametrize("mesh_shape", [None, (2, 2)],
+                         ids=["one-device", "data2-tensor2"])
+@pytest.mark.parametrize("window", [0, 20])
+@pytest.mark.parametrize("interpret", [True, False],
+                         ids=["kernel", "reference"])
+def test_head_major_entry_is_the_public_entry(interpret, window, mesh_shape):
+    """``flash_attention_head_major`` on [B, H, T, D] operands (what
+    ``ops/qk_prep.py`` writes) gives what ``flash_attention`` gives on
+    the same operands as [B, T, H, D], values and gradients, with a
+    window and without, on one device and with the kernel sharding
+    itself over a mesh; off the TPU it takes the same reference math."""
+    mesh = None
+    if mesh_shape:
+        if len(jax.devices()) < 4:
+            pytest.skip("needs four devices")
+        mesh = jax.sharding.Mesh(
+            np.array(jax.devices()[:4]).reshape(mesh_shape),
+            ("data", "tensor"))
+    fa_mod._INTERPRET = interpret
+    ks = jax.random.split(jax.random.PRNGKey(window), 4)
+    q = jax.random.normal(ks[0], (2, 48, 4, 8), jnp.float32)
+    k = jax.random.normal(ks[1], (2, 48, 2, 8), jnp.float32)
+    v = jax.random.normal(ks[2], (2, 48, 2, 8), jnp.float32)
+    w = jax.random.normal(ks[3], q.shape)
+    blocks = dict(block_q=16, block_k=16, window=window, mesh=mesh)
+
+    def public(q, k, v):
+        return fa_mod.flash_attention(q, k, v, **blocks)
+
+    def head_major(q, k, v):
+        return fa_mod.flash_attention_head_major(
+            *(x.transpose(0, 2, 1, 3) for x in (q, k, v)), **blocks)
+
+    def readings(fn):
+        def loss(*a):
+            out = fn(*a)
+            return (out * w).sum(), out
+        (_, out), grads = jax.jit(jax.value_and_grad(
+            loss, (0, 1, 2), has_aux=True))(q, k, v)
+        return out, grads
+
+    ref, ref_grads = readings(public)
+    got, got_grads = readings(head_major)
+    assert got.shape == q.shape
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                               rtol=1e-6, atol=1e-6)
+    _assert_grads_close(got_grads, ref_grads)
+    with pytest.raises(ValueError, match="window"):
+        fa_mod.flash_attention_head_major(q, k, v, causal=False, window=20)

@@ -796,3 +796,77 @@ def test_moe_remat_modes_rejected_without_grouped_dispatch():
     with pytest.raises(ValueError, match="grouped MoE dispatch"):
         llama_forward(llama_init(gshard, jax.random.PRNGKey(0)), tokens,
                       gshard)
+
+
+def _seam_model(**kw):
+    """Two layers whose heads are a whole 128-lane slab, q and k normed
+    a head: what ``ops/qk_prep.py``'s predicate takes."""
+    cfg = LlamaConfig.tiny(dtype="float32", n_layers=2, n_heads=2,
+                           n_kv_heads=1, d_head=128, qk_norm="head",
+                           remat=False, **kw)
+    params = llama_init(cfg, jax.random.PRNGKey(0))
+    # gains away from their initial ones, so that their gradients and
+    # their place in the chain are read
+    for name in ("q_norm", "k_norm"):
+        params["layers"][name] = 1 + 0.1 * jax.random.normal(
+            jax.random.PRNGKey(2), params["layers"][name].shape)
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 32), 0,
+                                cfg.vocab_size)
+    return cfg, params, {"tokens": tokens, "targets": jnp.roll(tokens, -1, 1)}
+
+
+def _loss_and_grads(cfg, params, batch):
+    return jax.jit(jax.value_and_grad(
+        lambda p: llama_loss(p, batch, cfg)))(params)
+
+
+def test_seam_kernels_match_the_expressions(monkeypatch):
+    """A ``qk_norm="head"`` model with the seam on its kernel pair
+    (interpret mode; the attention behind it on the reference math, fed
+    head-major) against the expressions ``_head_proj`` + ``_rope``: loss
+    and every gradient, under no remat and under the two modes that name
+    what the seam hands on (``rope_q``, ``rope_k``, ``attn_v``). The
+    file's bounds, the absolute one a millionth of a leaf's largest
+    value where that is above 1 (the embedding's gradient sums many
+    tokens a row: 1.7 at the largest, 1.5e-6 off in one element of
+    16,384, where the kernels' backward adds in another order)."""
+    from horovod_tpu.ops import qk_prep
+
+    cfg, params, batch = _seam_model()
+    ref_loss, ref_grads = _loss_and_grads(cfg, params, batch)
+    monkeypatch.setattr(qk_prep, "_INTERPRET", True)
+    monkeypatch.setattr(qk_prep, "TOKENS_A_STEP", 16)
+    for mode in (False, "attn", "attn+gate+qkv"):
+        loss, grads = _loss_and_grads(
+            dataclasses.replace(cfg, remat=mode), params, batch)
+        np.testing.assert_allclose(float(loss), float(ref_loss),
+                                   rtol=1e-6, err_msg=str(mode))
+        jax.tree.map(
+            lambda a, b: np.testing.assert_allclose(
+                np.asarray(a), np.asarray(b), rtol=1e-5,
+                atol=1e-6 * max(1.0, float(jnp.abs(b).max())),
+                err_msg=str(mode)),
+            grads, ref_grads)
+
+
+def test_seam_kernels_hand_the_remat_policies_their_names(monkeypatch):
+    """``attn+gate+qkv`` saves the seam's outputs by name, head-major
+    now: with the kernels on, the backward of a layer runs
+    ``hvd_qk_prep_fwd`` once under ``attn`` (the recomputation) and not
+    at all under ``attn+gate+qkv``."""
+    from horovod_tpu.ops import qk_prep
+
+    cfg, params, batch = _seam_model()
+    monkeypatch.setattr(qk_prep, "_INTERPRET", True)
+
+    def forward_calls(mode):
+        c = dataclasses.replace(cfg, remat=mode, n_layers=1)
+        p = jax.tree.map(lambda a: a, params)
+        p["layers"] = jax.tree.map(lambda a: a[:1], params["layers"])
+        jaxpr = jax.make_jaxpr(jax.grad(
+            lambda p: llama_loss(p, batch, c)))(p)
+        return str(jaxpr).count("hvd_qk_prep_fwd"), \
+            str(jaxpr).count("hvd_qk_prep_bwd")
+
+    assert forward_calls("attn") == (2, 1)
+    assert forward_calls("attn+gate+qkv") == (1, 1)
