@@ -23,49 +23,118 @@ WY form)::
     O  = (Q * exp(gamma)) S + tril(Q K^T * exp(gamma_i - gamma_j)) V'
     S <- exp(gamma_C) S + (K * exp(gamma_C - gamma))^T V'
 
-Everything that does not depend on ``S`` (``U``, ``W``, the in-chunk
-scores, the decayed ``Q`` and ``K``) is computed for ALL chunks at once,
-by ordinary batched operations that autodiff differentiates. Only the
-three lines that read ``S`` run chunk after chunk: ``_carry_state``,
-MXU matmuls over the T/C chunks with the ``[B, H, dk, dv]`` state
-carried, and a ``custom_vjp``: its forward keeps the state each chunk
-STARTED from (T/C x [B, H, dk, dv] float32: 537 MB at B2 T8192 H32 d128,
-alive for one layer's backward under the layer's remat) and its backward
-is one reverse pass that recomputes ``V'`` (one matmul) and carries the
-state's cotangent. Kept and not recomputed: the states are what a
-recomputation would have to run the whole forward pass again for, and
-one layer's are a thirtieth of the chip.
+Nothing but ``S`` crosses a chunk, so the work falls into three parts:
 
-One algorithm, two carriers; which one runs is read off the operands
-(``ops/_platform.py``, as the flash kernels decide it), never off an
-option:
+1. what leads to ``T``: the decays ``gamma`` and ``exp(gamma_i -
+   gamma_j)``, ``K K^T``, ``A`` and the solve, for ALL chunks at once,
+   by ordinary batched operations that autodiff differentiates. ``T``
+   is born here, in XLA (``lax.linalg.triangular_solve``), rounded to
+   the compute dtype, on every carrier;
+2. the WY factors, each a function of a chunk's own ``q``, ``k``, ``v``,
+   ``gamma``, ``beta`` and ``T``::
 
-- operands on a TPU: a Pallas kernel pair. ``hvd_gdn_state_fwd`` walks a
-  grid ``(B, H / hb, T / C)`` whose last axis, the chunks, is sequential;
-  the state of ``hb`` heads lives in a float32 VMEM scratch for the whole
-  sequence and never crosses HBM (but where the states are kept).
-  ``hvd_gdn_state_bwd`` walks the same grid from the last chunk to the
-  first with the state's cotangent in the scratch. Both read the factors
-  where the stage above leaves them, ``[B, N, H, C, ...]`` (an index map
-  takes block ``(b, n, h)`` as readily as ``(n, b, h)``); only the kept
-  states are chunk-major. The names are the calls' ``kernel_metadata``,
-  what a device trace shows of them. Each kernel sits behind ONE jitted
-  function: every layer and phase of a program calls one lowered copy (a
-  ``pallas_call`` is lowered to Mosaic wherever it is traced, compile
-  cache or not, and a set-up pays for each);
-- elsewhere: ``_scan_state``, a ``lax.scan`` over chunk-major operands
-  (the CPU's path, and the tests' reference for the kernels, which run
-  there in interpret mode under ``_INTERPRET``).
+       qg = Q * exp(gamma)                 kd = K * exp(gamma_C - gamma)
+       p  = tril(Q K^T * exp(gamma_i - gamma_j))
+       bv = diag(beta) V                   bk = diag(beta) (K * exp(gamma))
+       u  = T bv                           w  = T bk
+
+3. the three lines that read ``S``, chunk after chunk::
+
+       new = u - w S;   o = qg S + p new;   S <- exp(gamma_C) S + kd^T new
+
+One algorithm, two carriers for parts 2 and 3; which one runs is read
+off the operands (``ops/_platform.py``, as the flash kernels decide it),
+never off an option:
+
+- operands on a TPU: a Pallas kernel pair that takes a chunk's RAW
+  operands and ``T`` and forms the factors in VMEM, a grid step at a
+  time: ``qg``, ``p``, ``u``, ``w``, ``kd`` (134 MB each at B2 T8192 H32
+  d128, ``p`` 67) and their five cotangents are born and die there, in
+  the forward, the forward under remat and the backward; none crosses
+  HBM, none is a residual. ``hvd_gdn_rule_fwd`` walks a grid ``(B, H /
+  hb, T / C)`` whose last axis, the chunks, is sequential; the state of
+  ``hb`` heads lives in a float32 VMEM scratch for the whole sequence
+  and never crosses HBM (but where the states are kept).
+  ``hvd_gdn_rule_bwd`` walks the same grid from the last chunk to the
+  first with the state's cotangent in the scratch: it forms the factors
+  again, runs the scan's reverse step, and takes the factors'
+  cotangents on to the operands' in the same step (the equations
+  below). ``q``, ``k``, ``v`` and ``T`` are read where part 1 leaves
+  them, ``[B, N, H, C, ...]`` (an index map takes block ``(b, n, h)``
+  as readily as ``(n, b, h)``); only the kept states are chunk-major.
+  The gates cross HBM lane-dense, ``[B, H, N, C]`` float32, a
+  sequence's block staying in VMEM and a chunk's row ``[hb, 1, C]``
+  read from it; a gate scales ROWS of a ``[C, d]`` tile, so the kernel
+  turns the row down the sublanes first (``_down``: a select against
+  the identity and a lane sum, exact) and the row sums that are the
+  gates' gradients back (``_along``). The names are the calls'
+  ``kernel_metadata``, what a device trace shows of them. Each kernel
+  sits behind ONE jitted function: every layer and phase of a program
+  calls one lowered copy (a ``pallas_call`` is lowered to Mosaic
+  wherever it is traced, compile cache or not, and a set-up pays for
+  each): the forward twice (keeping the states, and not), the backward
+  once;
+- elsewhere: ``_scan_rule``, the factors by batched operations that
+  autodiff differentiates, each through HBM, and ``_scan_state``, a
+  ``lax.scan`` over chunk-major operands under a ``custom_vjp`` (the
+  CPU's path, and the tests' reference for the kernels, which run there
+  in interpret mode under ``_INTERPRET``).
+
+Either way the forward keeps the state each chunk STARTED from (T/C x
+[B, H, dk, dv] float32: 537 MB at B2 T8192 H32 d128, alive for one
+layer's backward under the layer's remat) and the backward is one
+reverse pass that recomputes ``new`` and carries the state's cotangent.
+Kept and not recomputed: the states are what a recomputation would have
+to run the whole forward pass again for, and one layer's are a thirtieth
+of the chip.
+
+The kernels' backward, a chunk from ``do`` and ``dS``, the cotangent of
+the state the chunk ENDS with (``r()`` rounds to the compute dtype where
+the scan hands a factor's cotangent to autodiff; products of two
+operands are MXU matmuls, ``*`` is elementwise, ``rows()`` sums along a
+token's row, ``cols()`` down a column)::
+
+    the scan's step (``_scan_state_bwd``):
+    du   = r(p^T do + kd dS)             (= dnew)
+    dqg  = r(do S^T)    dp = r(do new^T)
+    dw   = r(-du S^T)   dkd = r(new dS^T)    ddc = sum(S * dS)
+    dS  <- exp(gamma_C) dS + qg^T do - w^T du
+
+    the factors' own:
+    dT   = du bv^T + dw bk^T             (out: autodiff takes it through
+                                          the solve, A, K K^T, the mask)
+    dbv  = r(T^T du)    dbk = r(T^T dw)
+    dpd  = dp * exp(gamma_i - gamma_j)   (0 above the diagonal)
+    dq   = dqg * exp(gamma) + r(dpd) K
+    dk   = r(dpd)^T Q + dkd * exp(gamma_C - gamma)
+           + dbk * beta * exp(gamma)
+    dv   = dbv * beta
+    dbeta  = rows(dbv * V) + exp(gamma) * rows(dbk * K)
+    dgamma = exp(gamma) * rows(dqg * Q) + beta * exp(gamma) * rows(dbk * K)
+             - exp(gamma_C - gamma) * rows(dkd * K)
+             + rows(dpd * Q K^T) - cols(dpd * Q K^T)
+    dgamma_C += ddc * exp(gamma_C)
+                + sum(exp(gamma_C - gamma) * rows(dkd * K))
+
+``dk`` has a second share (``K K^T``'s) and ``dbeta``, ``dgamma`` a
+second each (``A``'s): autodiff's, through part 1, added outside.
+
+The seam left for the inverse: ``T`` enters the kernels as an operand
+and ``dT`` leaves as a result. A kernel that forms ``T`` itself (a block
+inverse on the MXU from ``K K^T``, which it can form too) changes where
+those two are born and nothing else here.
 
 Precision: the decays (``gamma``, every ``exp``, all of non-positive
 arguments, so none overflows), the inverse (forward substitution, not a
-Neumann series: stable whatever the keys) and the state are float32; the
-matmuls take operands in the compute dtype (``q``'s; the inverse, the
-decayed ``Q`` and ``K`` and the state rounded to it as they enter one)
-and accumulate in float32.
+Neumann series: stable whatever the keys), the state and every
+accumulation are float32; the matmuls take operands in the compute dtype
+(``q``'s; the inverse, the factors and the state rounded to it as they
+enter one) and accumulate in float32. Both carriers round at the same
+places: on the same operands ``o`` is the same to the last bit.
 """
 
 import functools
+import types
 
 import jax
 import jax.numpy as jnp
@@ -169,18 +238,129 @@ _scan_state.defvjp(_scan_state_fwd, _scan_state_bwd)
 
 
 # ---------------------------------------------------------------------
-# The same pass as a Pallas TPU kernel pair: the state in VMEM.
+# The rule as a Pallas TPU kernel pair: the factors and the state in VMEM.
 # ---------------------------------------------------------------------
 
-def _fwd_kernel(qg_ref, p_ref, u_ref, w_ref, kd_ref, dc_ref, o_ref, *rest):
+def _eye(C):
+    i = lax.broadcasted_iota(jnp.int32, (C, C), 0)
+    return i, lax.broadcasted_iota(jnp.int32, (C, C), 1)
+
+
+def _down(row):
+    """A gate a token [hb, 1, C], along the lanes as it crosses HBM ->
+    [hb, C, 1], down the sublanes, where it scales the rows of a ``[C,
+    d]`` tile. Exactly: a sum of one entry and zeros."""
+    i, j = _eye(row.shape[-1])
+    return jnp.sum(jnp.where(i == j, row, 0.0), axis=-1, keepdims=True)
+
+
+def _along(col):
+    """``_down``'s inverse: [hb, C, 1] -> [hb, 1, C]."""
+    i, j = _eye(col.shape[-2])
+    return jnp.sum(jnp.where(i == j, col, 0.0), axis=-2, keepdims=True)
+
+
+def _rowsum(x):
+    return jnp.sum(x, axis=-1, keepdims=True)
+
+
+def _forget(last, S):
+    """``exp(gamma_C) S`` for ``last`` = ``gamma_C`` [hb, 1, 1]. Mosaic
+    broadcasts along the lanes or down the sublanes, not both at once
+    (and folds two in a row into one): along the lanes, the ``exp``,
+    and the product is the second."""
+    return jnp.exp(jnp.broadcast_to(last, last.shape[:-1] + S.shape[-1:])) * S
+
+
+def _factors(q, k, v, gamma, beta, inv):
+    """The WY factors of a chunk of ``hb`` heads, ``gated_delta_rule``'s
+    lines and roundings: ``q``, ``k`` [hb, C, dk], ``v`` [hb, C, dv],
+    ``inv`` [hb, C, C] in the compute dtype, the gates ``gamma``,
+    ``beta`` [hb, 1, C] float32 -> what the three lines that read the
+    state take, and what the backward takes besides."""
+    dt, C = q.dtype, q.shape[-2]
+    i, j = _eye(C)
+    g, b = _down(gamma), _down(beta)
+    last = gamma[..., C - 1:]                               # [hb, 1, 1]
+    eg, ekd = jnp.exp(g), jnp.exp(last - g)
+    decay = jnp.exp(jnp.where(i >= j, g - gamma, -jnp.inf))
+    qk = _mm("hck,hjk->hcj", q, k)
+    qf, kf, vf = q.astype(F32), k.astype(F32), v.astype(F32)
+    beg = b * eg
+    bv, bk = (b * vf).astype(dt), (beg * kf).astype(dt)
+    return types.SimpleNamespace(
+        qg=(qf * eg).astype(dt), p=(qk * decay).astype(dt),
+        u=_mm("hcj,hjv->hcv", inv, bv).astype(dt),
+        w=_mm("hcj,hjk->hck", inv, bk).astype(dt),
+        kd=(kf * ekd).astype(dt), last=last, bv=bv, bk=bk, b=b, eg=eg,
+        beg=beg, ekd=ekd, decay=decay, qk=qk, qf=qf, kf=kf, vf=vf)
+
+
+def _chunk_fwd(q, k, v, gamma, beta, inv, S):
+    """A chunk of ``hb`` heads from the state ``S`` [hb, dk, dv] float32
+    it starts with -> (``o`` [hb, C, dv] float32, the state it ends
+    with): ``_chunk``'s lines on factors that never leave VMEM."""
+    f = _factors(q, k, v, gamma, beta, inv)
+    Sd = S.astype(q.dtype)
+    new = (f.u.astype(F32) - _mm("hck,hkv->hcv", f.w, Sd)).astype(q.dtype)
+    o = _mm("hck,hkv->hcv", f.qg, Sd) + _mm("hcj,hjv->hcv", f.p, new)
+    return o, _forget(f.last, S) + _mm("hck,hcv->hkv", f.kd, new)
+
+
+def _chunk_bwd(q, k, v, gamma, beta, inv, S, dS, do):
+    """The chunk's backward, ``dS`` the cotangent of the state it ENDS
+    with: the factors again, ``_scan_state_bwd``'s ``step``, then the
+    factors' own transposes (the module docstring's equations) ->
+    (``dq``, ``dk``, ``dv``, ``dinv`` float32, ``dgamma``, ``dbeta``
+    [hb, 1, C], the cotangent of the state it STARTED with)."""
+    dt, C = q.dtype, q.shape[-2]
+    f = _factors(q, k, v, gamma, beta, inv)
+    qg, p, w, kd = f.qg, f.p, f.w, f.kd
+    b, eg, beg, ekd, qf, kf = f.b, f.eg, f.beg, f.ekd, f.qf, f.kf
+    Sd, dSd = S.astype(dt), dS.astype(dt)
+    new = (f.u.astype(F32) - _mm("hck,hkv->hcv", w, Sd)).astype(dt)
+    du = (_mm("hcj,hcv->hjv", p, do)
+          + _mm("hck,hkv->hcv", kd, dSd)).astype(dt)
+    # rounded where the scan hands them to autodiff: the factors' dtype
+    dqg = _mm("hcv,hkv->hck", do, Sd).astype(dt).astype(F32)
+    dp = _mm("hcv,hjv->hcj", do, new).astype(dt).astype(F32)
+    dw = (-_mm("hcv,hkv->hck", du, Sd)).astype(dt)
+    dkd = _mm("hcv,hkv->hck", new, dSd).astype(dt).astype(F32)
+    ddc = jnp.sum(_rowsum(S * dS), axis=-2, keepdims=True)   # [hb, 1, 1]
+    dS = _forget(f.last, dS) + _mm("hck,hcv->hkv", qg, do) \
+        - _mm("hck,hcv->hkv", w, du)
+    # through u = inv bv and w = inv bk
+    dinv = _mm("hcv,hjv->hcj", du, f.bv) \
+        + _mm("hck,hjk->hcj", dw, f.bk)
+    dbv = _mm("hjc,hjv->hcv", inv, du).astype(dt).astype(F32)
+    dbk = _mm("hjc,hjk->hck", inv, dw).astype(dt).astype(F32)
+    # through p = q k^T * decay
+    dpd = dp * f.decay
+    dqk, m = dpd.astype(dt), dpd * f.qk
+    dq = dqg * eg + _mm("hcj,hjk->hck", dqk, k)
+    dk = _mm("hcj,hck->hjk", dqk, q) + dkd * ekd + dbk * beg
+    dv = dbv * b
+    # the gates: down the sublanes as the row sums come, then along
+    rk, rkd = _rowsum(dbk * kf), ekd * _rowsum(dkd * kf)
+    dbeta = _rowsum(dbv * f.vf) + eg * rk
+    dgamma = eg * _rowsum(dqg * qf) + beg * rk - rkd + _rowsum(m)
+    # gamma_C, the chunk's last: kd's total and dc's
+    dlast = ddc * jnp.exp(f.last) + jnp.sum(rkd, axis=-2, keepdims=True)
+    _, j = _eye(C)
+    dgamma = _along(dgamma) - jnp.sum(m, axis=-2, keepdims=True) \
+        + jnp.where(j[:1] == C - 1, dlast, 0.0)
+    return dq, dk, dv, dinv, dgamma, _along(dbeta), dS
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, gamma_ref, beta_ref, inv_ref, o_ref,
+                *rest):
     """One grid step: a chunk of ``hb`` heads from the state in
     ``S_ref`` [hb, dk, dv] float32, which lives across the chunk axis
-    (the last, sequential one). ``_chunk``'s lines, rounding where it
-    rounds, the heads the batch of a batched matmul. ``rest`` =
-    (states_ref, S_ref) where the states are kept, else (S_ref,)."""
+    (the last, sequential one). ``rest`` = (states_ref, S_ref) where
+    the states are kept, else (S_ref,)."""
     S_ref = rest[-1]
     n = pl.program_id(2)
-    dt = qg_ref.dtype
+    row = pl.ds(n, 1)
 
     @pl.when(n == 0)
     def _start():
@@ -189,48 +369,35 @@ def _fwd_kernel(qg_ref, p_ref, u_ref, w_ref, kd_ref, dc_ref, o_ref, *rest):
     S = S_ref[...]
     if len(rest) == 2:
         rest[0][...] = S
-    Sd = S.astype(dt)
-    new = (u_ref[...].astype(F32)
-           - _mm("hck,hkv->hcv", w_ref[...], Sd)).astype(dt)
-    o_ref[...] = (_mm("hck,hkv->hcv", qg_ref[...], Sd)
-                  + _mm("hcj,hjv->hcv", p_ref[...], new)
-                  ).astype(o_ref.dtype)
-    S_ref[...] = dc_ref[:, pl.ds(n, 1), :] * S \
-        + _mm("hck,hcv->hkv", kd_ref[...], new)
+    o, S_ref[...] = _chunk_fwd(
+        q_ref[...], k_ref[...], v_ref[...], gamma_ref[:, row, :],
+        beta_ref[:, row, :], inv_ref[...], S)
+    o_ref[...] = o.astype(o_ref.dtype)
 
 
-def _bwd_kernel(qg_ref, p_ref, u_ref, w_ref, kd_ref, dc_ref, states_ref,
-                do_ref, dqg_ref, dp_ref, du_ref, dw_ref, dkd_ref, ddc_ref,
-                dS_ref):
+def _bwd_kernel(q_ref, k_ref, v_ref, gamma_ref, beta_ref, inv_ref,
+                states_ref, do_ref, dq_ref, dk_ref, dv_ref, dinv_ref,
+                dgamma_ref, dbeta_ref, dS_ref):
     """The reverse pass: grid step ``n`` holds chunk ``N - 1 - n`` (the
     index maps count down); ``dS_ref`` is the cotangent of the state
-    that chunk ends with. ``_scan_state_bwd``'s ``step``."""
+    that chunk ends with."""
     n = pl.program_id(2)
     row = pl.ds(pl.num_programs(2) - 1 - n, 1)
-    dt = qg_ref.dtype
 
     @pl.when(n == 0)
     def _start():
         dS_ref[...] = jnp.zeros_like(dS_ref)
 
-    S, dS = states_ref[...], dS_ref[...]
-    Sd, dSd = S.astype(dt), dS.astype(dt)
-    do, w = do_ref[...], w_ref[...]
-    new = (u_ref[...].astype(F32)
-           - _mm("hck,hkv->hcv", w, Sd)).astype(dt)
-    dnew = (_mm("hcj,hcv->hjv", p_ref[...], do)
-            + _mm("hck,hkv->hcv", kd_ref[...], dSd)).astype(dt)
-    dqg_ref[...] = _mm("hcv,hkv->hck", do, Sd).astype(dqg_ref.dtype)
-    dp_ref[...] = _mm("hcv,hjv->hcj", do, new).astype(dp_ref.dtype)
-    du_ref[...] = dnew.astype(du_ref.dtype)
-    dw_ref[...] = (-_mm("hcv,hkv->hck", dnew, Sd)).astype(dw_ref.dtype)
-    dkd_ref[...] = _mm("hcv,hkv->hck", new, dSd).astype(dkd_ref.dtype)
-    # dc's gradient, sum(S * dS): summed over dk here and over dv by the
-    # caller, a row of a block that stays for the whole sequence
-    ddc_ref[:, row, :] = jnp.sum(S * dS, axis=1, keepdims=True)
-    dS_ref[...] = dc_ref[:, row, :] * dS \
-        + _mm("hck,hcv->hkv", qg_ref[...], do) \
-        - _mm("hck,hcv->hkv", w, dnew)
+    dq, dk, dv, dinv, dgamma, dbeta, dS_ref[...] = _chunk_bwd(
+        q_ref[...], k_ref[...], v_ref[...], gamma_ref[:, row, :],
+        beta_ref[:, row, :], inv_ref[...], states_ref[...], dS_ref[...],
+        do_ref[...])
+    for ref, x in ((dq_ref, dq), (dk_ref, dk), (dv_ref, dv),
+                   (dinv_ref, dinv)):
+        ref[...] = x.astype(ref.dtype)
+    # a row of a block that stays for the whole sequence
+    dgamma_ref[:, row, :] = dgamma
+    dbeta_ref[:, row, :] = dbeta
 
 
 def _call(name, kernel, hb, operands, grid, in_specs, out_specs, out_shape,
@@ -250,35 +417,34 @@ def _call(name, kernel, hb, operands, grid, in_specs, out_specs, out_shape,
     )(*operands)
 
 
-def _specs(qg, u, hb, at):
-    """(grid, and the block specs) of ``hb`` heads of a chunk a step
-    over ``[B, N, H, C, ...]`` operands, ``at(n)`` the chunk grid step
-    ``n`` takes: ``chunks(last)`` over an operand ``last`` wide,
-    ``rows`` over ``dc``'s ``[B, H, N, dv]`` (a sequence's stay in
-    VMEM), ``states`` over the kept ``[N, B, H, dk, dv]``."""
-    B, N, H, C, dk = qg.shape
-    dv = u.shape[-1]
+def _specs(q, v, hb, at):
+    """(grid, and the block specs) of ``hb`` heads of a chunk a step,
+    ``at(n)`` the chunk grid step ``n`` takes: ``raw``, the six
+    operands' in their order (``q``, ``k``, ``v``, ``inv`` ``[B, N, H,
+    C, ...]``; a gate's ``[B, H, N, C]``, a sequence's staying in VMEM),
+    and ``states`` over the kept ``[N, B, H, dk, dv]``."""
+    B, N, H, C, dk = q.shape
+    dv = v.shape[-1]
 
     def chunks(last):
         return pl.BlockSpec((None, None, hb, C, last),
                             lambda b, h, n: (b, at(n), h, 0, 0))
 
-    rows = pl.BlockSpec((None, hb, N, dv), lambda b, h, n: (b, h, 0, 0))
+    rows = pl.BlockSpec((None, hb, N, C), lambda b, h, n: (b, h, 0, 0))
     states = pl.BlockSpec((None, None, hb, dk, dv),
                           lambda b, h, n: (at(n), b, h, 0, 0))
-    return (B, H // hb, N), chunks, rows, states
+    raw = [chunks(dk), chunks(dk), chunks(dv), rows, rows, chunks(C)]
+    return (B, H // hb, N), raw, states
 
 
-def _rows(dc, dv):
-    """``dc`` [B, N, H] -> [B, H, N, dv] float32: a lane-dense row a
-    chunk, which a kernel broadcasts down a state's ``dk`` sublanes."""
-    B, N, H = dc.shape
-    return jnp.broadcast_to(jnp.swapaxes(dc, 1, 2)[..., None],
-                            (B, H, N, dv))
+def _rows(gate):
+    """A gate [B, N, H, C] <-> [B, H, N, C]: a chunk's a lane-dense row
+    of a block that holds a sequence's."""
+    return jnp.swapaxes(gate, 1, 2)
 
 
 @functools.partial(jax.jit, static_argnames=("keep", "hb", "interpret"))
-def _kernel_fwd(qg, p, u, w, kd, dc, *, keep, hb, interpret):
+def _kernel_fwd(q, k, v, gamma, beta, inv, *, keep, hb, interpret):
     """-> [``o`` [B, N, H, C, dv]], and with ``keep`` the state every
     chunk started from, [N, B, H, dk, dv] float32. Jitted on its own:
     every site that enters it with these shapes calls ONE lowered
@@ -288,71 +454,95 @@ def _kernel_fwd(qg, p, u, w, kd, dc, *, keep, hb, interpret):
     this function; the call site's (its scope, its phase) stands before
     it only where the compiler inlines the call."""
     with scope("hvd.gdn.core"):
-        B, N, H, _, dk = qg.shape
-        dv = u.shape[-1]
-        grid, chunks, rows, states = _specs(qg, u, hb, lambda n: n)
-        out_specs = [chunks(dv)] + [states] * keep
-        out_shape = [jax.ShapeDtypeStruct(u.shape, u.dtype)] + [
-            jax.ShapeDtypeStruct((N, B, H, dk, dv), F32)] * keep
-        return _call("hvd_gdn_state_fwd", _fwd_kernel, hb,
-                     (qg, p, u, w, kd, _rows(dc, dv)), grid,
-                     [chunks(x.shape[-1]) for x in (qg, p, u, w, kd)]
-                     + [rows], out_specs, out_shape, interpret)
+        B, N, H, _, dk = q.shape
+        grid, raw, states = _specs(q, v, hb, lambda n: n)
+        out_shape = [jax.ShapeDtypeStruct(v.shape, v.dtype)] + [
+            jax.ShapeDtypeStruct((N, B, H, dk, v.shape[-1]), F32)] * keep
+        return _call("hvd_gdn_rule_fwd", _fwd_kernel, hb,
+                     (q, k, v, _rows(gamma), _rows(beta), inv), grid, raw,
+                     [raw[2]] + [states] * keep, out_shape, interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("hb", "interpret"))
-def _kernel_bwd(qg, p, u, w, kd, dc, states, do, *, hb, interpret):
+def _kernel_bwd(q, k, v, gamma, beta, inv, states, do, *, hb, interpret):
     """-> the six gradients, in their operands' shapes and dtypes."""
     with scope("hvd.gdn.core"):
-        B, N, H = dc.shape
-        dv = u.shape[-1]
-        grid, chunks, rows, kept = _specs(qg, u, hb, lambda n: N - 1 - n)
-        operands = (qg, p, u, w, kd)
-        blocks = [chunks(x.shape[-1]) for x in operands]
-        *grads, ddc = _call(
-            "hvd_gdn_state_bwd", _bwd_kernel, hb,
-            operands + (_rows(dc, dv), states, do.astype(qg.dtype)), grid,
-            blocks + [rows, kept, chunks(dv)], blocks + [rows],
-            [jax.ShapeDtypeStruct(x.shape, x.dtype) for x in operands]
-            + [jax.ShapeDtypeStruct((B, H, N, dv), F32)], interpret)
-        return (*grads, jnp.swapaxes(ddc.sum(-1), 1, 2).astype(dc.dtype))
+        N = q.shape[1]
+        grid, raw, kept = _specs(q, v, hb, lambda n: N - 1 - n)
+        gates = (_rows(gamma), _rows(beta))
+        *grads, dgamma, dbeta = _call(
+            "hvd_gdn_rule_bwd", _bwd_kernel, hb,
+            (q, k, v, *gates, inv, states, do.astype(q.dtype)), grid,
+            raw + [kept, raw[2]], [raw[i] for i in (0, 1, 2, 5, 3, 4)],
+            [jax.ShapeDtypeStruct(x.shape, x.dtype)
+             for x in (q, k, v, inv) + gates], interpret)
+        dq, dk, dv, dinv = grads
+        return dq, dk, dv, _rows(dgamma), _rows(dbeta), dinv
 
 
-def _step(qg):
+def _step(q):
     """What a grid step takes of these operands, and how it runs."""
-    return {"hb": _pick_block(qg.shape[2], HEADS_A_STEP),
+    return {"hb": _pick_block(q.shape[2], HEADS_A_STEP),
             "interpret": _INTERPRET}
 
 
 @jax.custom_vjp
-def _kernel_state(qg, p, u, w, kd, dc):
-    """``_scan_state`` on operands as the factors lie, ``[B, N, H, C,
-    ...]`` and ``dc`` [B, N, H] -> ``o`` [B, N, H, C, dv]: the kernels
-    take block ``(b, n, h)`` where the scan wants chunk ``n`` first."""
-    return _kernel_fwd(qg, p, u, w, kd, dc, keep=False, **_step(qg))[0]
+def _kernel_rule(q, k, v, gamma, beta, inv):
+    """``_scan_rule`` on the kernel pair: same operands, same ``o``."""
+    return _kernel_fwd(q, k, v, gamma, beta, inv, keep=False, **_step(q))[0]
 
 
-def _kernel_state_fwd(qg, p, u, w, kd, dc):
-    o, states = _kernel_fwd(qg, p, u, w, kd, dc, keep=True, **_step(qg))
-    return o, (qg, p, u, w, kd, dc, states)
+def _kernel_rule_fwd(q, k, v, gamma, beta, inv):
+    o, states = _kernel_fwd(q, k, v, gamma, beta, inv, keep=True, **_step(q))
+    return o, (q, k, v, gamma, beta, inv, states)
 
 
-def _kernel_state_bwd(res, do):
+def _kernel_rule_bwd(res, do):
     return _kernel_bwd(*res, do, **_step(res[0]))
 
 
-_kernel_state.defvjp(_kernel_state_fwd, _kernel_state_bwd)
+_kernel_rule.defvjp(_kernel_rule_fwd, _kernel_rule_bwd)
 
 
-def _carry_state(qg, p, u, w, kd, dc):
-    """The state-carrying pass on operands ``[B, N, H, C, ...]`` (``dc``
-    [B, N, H]) -> ``o`` [B, N, H, C, dv]. Operands on a TPU: the kernel
-    pair. Elsewhere the scan, which wants them chunk-major."""
-    operands = (qg, p, u, w, kd, dc)
-    if use_pallas("gated_delta_rule", operands, _INTERPRET):
-        return _kernel_state(*operands)
+def _decay(gamma):
+    """``exp(gamma_i - gamma_j)`` where i >= j, 0 above the diagonal:
+    ``gamma`` [..., C] -> [..., C, C]."""
+    C = gamma.shape[-1]
+    i, j = lax.iota(jnp.int32, C)[:, None], lax.iota(jnp.int32, C)
+    return jnp.exp(jnp.where(i >= j, gamma[..., :, None]
+                             - gamma[..., None, :], -jnp.inf))
+
+
+def _scan_rule(q, k, v, gamma, beta, inv, decay):
+    """The factors by batched operations that autodiff differentiates,
+    all chunks at once and each through HBM, then the scan, which wants
+    them chunk-major."""
+    dt = q.dtype
+    bv = (beta[..., None] * v.astype(F32)).astype(dt)
+    bk = (beta[..., None] * jnp.exp(gamma)[..., None]
+          * k.astype(F32)).astype(dt)
+    last = gamma[..., -1]
+    operands = (
+        (q.astype(F32) * jnp.exp(gamma)[..., None]).astype(dt),       # qg
+        (_mm("bnhck,bnhjk->bnhcj", q, k) * decay).astype(dt),         # p
+        _mm("bnhcj,bnhjv->bnhcv", inv, bv).astype(dt),                # u
+        _mm("bnhcj,bnhjk->bnhck", inv, bk).astype(dt),                # w
+        (k.astype(F32)
+         * jnp.exp(last[..., None] - gamma)[..., None]).astype(dt),   # kd
+        jnp.exp(last))                                                # dc
     return jnp.moveaxis(
         _scan_state(*(jnp.moveaxis(x, 1, 0) for x in operands)), 0, 1)
+
+
+def _rule_of_chunks(q, k, v, gamma, beta, inv, decay):
+    """Everything downstream of the inverse, on operands ``[B, N, H, C,
+    ...]`` (the gates ``[B, N, H, C]`` float32) -> ``o`` [B, N, H, C,
+    dv]. Operands on a TPU: the kernel pair, which forms its own decays.
+    Elsewhere the factors in XLA and the scan."""
+    operands = (q, k, v, gamma, beta, inv)
+    if use_pallas("gated_delta_rule", operands, _INTERPRET):
+        return _kernel_rule(*operands)
+    return _scan_rule(*operands, decay)
 
 
 def gated_delta_rule(q, k, v, g, beta, chunk=CHUNK):
@@ -378,10 +568,8 @@ def gated_delta_rule(q, k, v, g, beta, chunk=CHUNK):
     q, k, v, g, beta = (chunked(x) for x in (
         q, k, v, g.astype(F32), beta.astype(F32)))
     gamma = jnp.cumsum(g, -1)                                # [B,N,H,C]
+    decay = _decay(gamma)
     i, j = lax.iota(jnp.int32, chunk)[:, None], lax.iota(jnp.int32, chunk)
-    # exp(gamma_i - gamma_j) where i >= j, 0 above the diagonal
-    decay = jnp.exp(jnp.where(i >= j, gamma[..., :, None]
-                              - gamma[..., None, :], -jnp.inf))
     kk = _mm("bnhck,bnhjk->bnhcj", k, k)
     a = jnp.where(i > j, beta[..., None] * kk * decay, 0.0)
     eye = jnp.eye(chunk, dtype=F32)
@@ -390,19 +578,6 @@ def gated_delta_rule(q, k, v, g, beta, chunk=CHUNK):
     inv = lax.linalg.triangular_solve(
         a + eye, jnp.broadcast_to(eye, a.shape), left_side=True,
         lower=True, unit_diagonal=True).astype(dt)
-    bv = (beta[..., None] * v.astype(F32)).astype(dt)
-    bk = (beta[..., None] * jnp.exp(gamma)[..., None]
-          * k.astype(F32)).astype(dt)
-    last = gamma[..., -1]
-    operands = (
-        (q.astype(F32) * jnp.exp(gamma)[..., None]).astype(dt),       # qg
-        (_mm("bnhck,bnhjk->bnhcj", q, k) * decay).astype(dt),         # p
-        _mm("bnhcj,bnhjv->bnhcv", inv, bv).astype(dt),                # u
-        _mm("bnhcj,bnhjk->bnhck", inv, bk).astype(dt),                # w
-        (k.astype(F32)
-         * jnp.exp(last[..., None] - gamma)[..., None]).astype(dt),   # kd
-        jnp.exp(last))                                                # dc
-    o = _carry_state(*operands)
+    o = _rule_of_chunks(q, k, v, gamma, beta, inv, decay)
     # [B, N, H, C, dv] -> [B, T, H, dv]
     return jnp.moveaxis(o, 2, 3).reshape(B, T, H, dv)
-

@@ -40,7 +40,7 @@ reduction a row.
   behind the kernel); the token axis is sequential and its index maps
   count DOWN: the convolution's transpose needs the ``taps - 1`` tokens
   AFTER a tile, which the step before left in a VMEM scratch
-  (``hvd_gdn_state_bwd`` carries its state the same way). A cotangent
+  (``hvd_gdn_rule_bwd`` carries its state the same way). A cotangent
   that is not a column block's own is parked on block 0 and not fetched
   again. The taps' gradient accumulates over a sequence in float32, a
   sublane tile of partial sums a tap, and is summed outside.

@@ -307,18 +307,46 @@ def _delta_rule_fwd_bwd(q, k, v, g, beta):
 
 
 _RULE_HEAD, _RULE_GATE = ((2, 8192, 32, 128), BF16), ((2, 8192, 32), F32)
-_RULE_KERNELS = ("hvd_gdn_state_fwd", "hvd_gdn_state_bwd")
+_RULE_KERNELS = ("hvd_gdn_rule_fwd", "hvd_gdn_rule_bwd")
+_INSTRUCTION = re.compile(
+    r"^\s+(?:ROOT )?%(\S+) = (\(.*?\)|\S+) ([\w\-]+)\((.*?)\)")
+
+
+def _entry_instructions(text):
+    """({name: (result types, opcode, operand names)} of a compiled
+    program's ENTRY computation, the layouts cut off, {a Mosaic call's
+    ``kernel_metadata`` name: its instruction})."""
+    found, kernels, name = {}, {}, None
+    for line in text[text.index("\nENTRY "):].splitlines():
+        m = _INSTRUCTION.match(re.sub(r"\{[^{}]*\}", "", line))
+        if m:
+            name, types, opcode, operands = m.groups()
+            found[name] = (re.findall(r"\w+\[[\d,]*\]", types), opcode,
+                           re.findall(r"%([\w.\-]+)", operands))
+        kernel = re.search(r'"kernel":"(\w+)"', line)
+        # on a line of its own inside the instruction (a
+        # get-tuple-element of the call's carries the name too)
+        if kernel and found[name][1] == "custom-call":
+            kernels[kernel.group(1)] = name
+    return found, kernels
 
 
 def test_gated_delta_rule_compiles_for_described_v5e(for_tpu):
     """Qwen3-Next's delta rule at the chip cell's size (B2 T8192, 32
     value heads of 128), forward and backward, as the chip's compiler
-    takes it: the state-carrying pass is the kernel pair, each by the
-    name a device trace shows (``kernel_metadata``), and no ``while``
-    over chunks is left; the states kept are the 128 CHUNKS' ([128, 2,
-    32, 128, 128] float32), no operand is token-major (a scan over the
-    8192 tokens would slice one), and the triangular systems are 64
-    wide."""
+    takes it: everything downstream of the inverse is the kernel pair,
+    each by the name a device trace shows (``kernel_metadata``), and no
+    ``while`` over chunks is left; the states kept are the 128 CHUNKS'
+    ([128, 2, 32, 128, 128] float32), no operand is token-major (a scan
+    over the 8192 tokens would slice one), and the triangular systems
+    are 64 wide.
+
+    What PR 49 is for: the kernels take ``q``, ``k``, ``v``, the two
+    gates and the inverse, and no factor; ``qg``, ``u``, ``w``, ``kd``
+    (134 MB each) and their cotangents never cross HBM. What is left of
+    that shape outside the kernels: the chunked ``q``, ``k``, ``v``,
+    ``o``'s cotangent, and ``dk``'s two shares added (the kernel's and
+    ``K K^T``'s)."""
     text = for_tpu(_delta_rule_fwd_bwd, _RULE_HEAD, _RULE_HEAD, _RULE_HEAD,
                    _RULE_GATE, _RULE_GATE)
     for name in _RULE_KERNELS:
@@ -328,6 +356,21 @@ def test_gated_delta_rule_compiles_for_described_v5e(for_tpu):
     assert "[8192,2,32," not in text              # no token-major scan
     assert re.search(r"f32\[2,128,32,(1,)?64,64\]", text)   # (I + A)^-1
 
+    wide, square = "bf16[2,128,32,64,128]", "bf16[2,128,32,64,64]"
+    gate, states = "f32[2,32,128,64]", "f32[128,2,32,128,128]"
+    entry, kernels = _entry_instructions(text)
+    calls = {kernel: (entry[call][0],
+                      [entry[x][0][0] for x in entry[call][2]])
+             for kernel, call in kernels.items()}
+    raw = [wide, wide, wide, gate, gate, square]  # q k v gamma beta inv
+    assert calls["hvd_gdn_rule_fwd"] == ([wide, states], raw)
+    assert calls["hvd_gdn_rule_bwd"] == (
+        [wide, wide, wide, square, gate, gate], raw + [states, wide])
+    made = [name for name, (types, opcode, _) in entry.items()
+            if types == [wide] and opcode not in (
+                "get-tuple-element", "bitcast", "parameter")]
+    assert len(made) <= 5, made
+
 
 def test_three_layers_of_the_rule_lower_each_kernel_once(for_tpu, v5e_chip):
     """The set-up budget's guard (PERF.md section 6, PR 44). A
@@ -335,7 +378,9 @@ def test_three_layers_of_the_rule_lower_each_kernel_once(for_tpu, v5e_chip):
     compile cache or not; behind ONE jitted wrapper the three layers'
     forward, forward again under remat, and backward lower to one
     private function a kernel form that every site calls: the backward
-    once, the forward twice (keeping the states, and not)."""
+    once, the forward twice (keeping the states, and not). Three still,
+    now that the pair forms the WY factors too (PR 49): the backward's
+    factor half is no kernel of its own."""
     from horovod_tpu.ops.gated_delta_rule import gated_delta_rule
 
     def loss(q, k, v, g, beta):

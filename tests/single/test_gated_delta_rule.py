@@ -5,10 +5,12 @@ write strengths near 0 and near 1. Float32 on the CPU: the two differ in
 the order of float32 additions (and the chunked form's triangular
 solve), 2e-5 of the largest entry. Small sizes.
 
-The state-carrying pass has two carriers, a ``lax.scan`` (the CPU's, and
-the reference here) and a Pallas kernel pair (a TPU's): the pair runs in
-interpret mode against the scan, values and all six gradients of
-``_carry_state``, and against the recurrence through the whole rule."""
+Everything downstream of the inverse has two carriers: the factor stage
+in XLA and a ``lax.scan`` (the CPU's, and the reference here), and a
+Pallas kernel pair that forms the factors where it carries the state (a
+TPU's): the pair runs in interpret mode against the scan, values and all
+six gradients of ``_rule_of_chunks``, the inverse's among them, and
+against the recurrence through the whole rule."""
 
 import functools
 
@@ -148,27 +150,29 @@ def kernels(monkeypatch):
 
 
 @functools.partial(jax.jit, static_argnums=tuple(range(7)))
-def _state_operands(b, n, h, c, dk, dv, dtype):
-    """``_carry_state``'s six, as the factor stage lays them."""
+def _chunk_operands(b, n, h, c, dk, dv, dtype):
+    """``_rule_of_chunks``'s six and a weight for ``o``. ``inv`` is any
+    matrix, not an inverse: its cotangent is then checked entry by
+    entry, above the diagonal too."""
     ks = jax.random.split(jax.random.PRNGKey(n), 7)
 
     def normal(key, *shape):
         return (0.3 * jax.random.normal(key, (b, n, h) + shape)
                 ).astype(dtype)
 
-    return (normal(ks[0], c, dk), normal(ks[1], c, c), normal(ks[2], c, dv),
-            normal(ks[3], c, dk), normal(ks[4], c, dk),
-            jax.random.uniform(ks[5], (b, n, h), minval=0.3, maxval=1.0),
+    return (normal(ks[0], c, dk), normal(ks[1], c, dk), normal(ks[2], c, dv),
+            jnp.cumsum(-0.2 * jax.random.uniform(ks[3], (b, n, h, c)), -1),
+            jax.random.uniform(ks[4], (b, n, h, c)), normal(ks[5], c, c),
             normal(ks[6], c, dv))
 
 
 @jax.jit
-def _state_readings(*operands):
+def _chunk_readings(*operands):
     """(o, and the gradients of sum(o * weight) in the six)."""
     *x, weight = operands
 
     def f(*x):
-        o = module._carry_state(*x)
+        o = module._rule_of_chunks(*x, module._decay(x[3]))
         return jnp.sum(o.astype(jnp.float32)
                        * weight.astype(jnp.float32)), o
 
@@ -176,46 +180,77 @@ def _state_readings(*operands):
     return (o,) + grads
 
 
-STATE_NAMES = ("o", "dqg", "dp", "du", "dw", "dkd", "ddc")
+CHUNK_NAMES = ("o", "dq", "dk", "dv", "dgamma", "dbeta", "dinv")
+
+
+def _on_both_carriers(kernels, heads, operands):
+    """-> the readings on the scan and on the kernel pair."""
+    _chunk_readings.clear_cache()     # the carrier is chosen in a trace
+    ref = _chunk_readings(*operands)
+    kernels(heads)
+    _chunk_readings.clear_cache()
+    return ref, _chunk_readings(*operands)
 
 
 # (chunks, heads, heads a step): heads a step that divide the heads, and
 # that do not (three by two, six by four: the largest divisor under it);
-# one chunk alone runs through the whole rule, below
+# one chunk and several, in both dtypes
 @pytest.mark.parametrize("dtype,n,h,heads", [
-    (jnp.float32, 3, 3, 2), (jnp.bfloat16, 3, 4, 2), (jnp.bfloat16, 5, 6, 4)])
+    (jnp.float32, 3, 3, 2), (jnp.bfloat16, 3, 4, 2), (jnp.bfloat16, 5, 6, 4),
+    (jnp.float32, 1, 2, 2), (jnp.bfloat16, 1, 3, 2), (jnp.float32, 4, 4, 4)])
 def test_the_kernel_pair_is_the_scan(kernels, dtype, n, h, heads):
-    """Same operands, same roundings, float32 state in both: ``o`` and
-    five gradients to the last bit of the operands' dtype; ``dc``'s,
-    summed in another order, to float32 rounding. ``dk`` 16 beside
+    """Same operands, same roundings, float32 state in both: the factor
+    stage in XLA and the scan against the pair that forms the factors in
+    VMEM. ``o`` to the last bit of the operands' dtype. The gradients
+    in the operands' dtype are autodiff's but for where the pieces are
+    added (``dq``, ``dk`` and ``dinv`` each have two or three: autodiff
+    rounds each to the dtype and adds, the kernel adds in float32 and
+    rounds once): to float32 rounding, or one step of bfloat16. The
+    gates' are float32 row sums in another order. ``dk`` 16 beside
     ``dv`` 32."""
-    operands = _state_operands(1, n, h, 16, 16, 32, dtype)
-    _state_readings.clear_cache()     # the carrier is chosen in a trace
-    ref = _state_readings(*operands)
-    kernels(heads)
-    _state_readings.clear_cache()
-    got = _state_readings(*operands)
-    for name, a, b in zip(STATE_NAMES, got, ref):
+    ref, got = _on_both_carriers(
+        kernels, heads, _chunk_operands(1, n, h, 16, 16, 32, dtype))
+    for name, a, b in zip(CHUNK_NAMES, got, ref):
         assert a.dtype == b.dtype and a.shape == b.shape, name
         a, b = a.astype(jnp.float32), b.astype(jnp.float32)
         err = float(jnp.max(jnp.abs(a - b)))
         scale = float(jnp.max(jnp.abs(b)))
-        assert err <= (2e-6 * scale if name == "ddc" else 0.0), (name, err)
+        step = 2.0 ** -7 if a.dtype != dtype and name in (
+            "dq", "dk", "dinv") else 2e-6
+        assert err <= (0.0 if name == "o" else step * scale), (name, err)
+
+
+def test_the_kernels_cotangent_of_the_inverse_is_autodiffs(kernels):
+    """``dinv = du bv^T + dw bk^T`` is written by hand in the backward
+    kernel; autodiff of ``u = inv bv``, ``w = inv bk`` through the scan
+    is the reference, float32, every entry of every chunk (the solve's
+    own transpose, which takes it from there, stays autodiff's)."""
+    operands = _chunk_operands(2, 3, 2, 16, 16, 32, jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        ref, got = _on_both_carriers(kernels, 2, operands)
+    dinv, want = got[-1], ref[-1]
+    assert float(jnp.max(jnp.abs(want))) > 1e-2
+    assert float(jnp.max(jnp.abs(dinv - want))) \
+        <= 2e-6 * float(jnp.max(jnp.abs(want)))
+    # above the diagonal too: the kernel takes ``inv`` as it comes
+    upper = jnp.triu(jnp.ones((16, 16), bool), 1)
+    assert float(jnp.max(jnp.abs(jnp.where(upper, want, 0.0)))) > 1e-2
 
 
 def test_a_step_takes_a_divisor_of_the_heads(kernels):
-    qg = jnp.zeros((1, 1, 6, 16, 16))
+    q = jnp.zeros((1, 1, 6, 16, 16))
     for want, takes in ((8, 6), (4, 3), (2, 2), (1, 1)):
         kernels(want)
-        assert module._step(qg) == {"hb": takes, "interpret": True}
+        assert module._step(q) == {"hb": takes, "interpret": True}
 
 
-@pytest.mark.parametrize("chunks,heads", [(1, 8), (3, 2)])
+@pytest.mark.parametrize("chunks,heads", [(1, 8), (3, 2), (2, 1)])
 def test_the_rule_on_the_kernel_pair_is_the_recurrence(
         kernels, chunks, heads):
     """``gated_delta_rule``'s value and five gradients with the kernels
-    carrying the state, weak decay and strong, ``dk`` 16 beside ``dv``
-    24, three heads."""
+    forming the factors and carrying the state (the inverse, and the
+    way back through it, XLA's), weak decay and strong, ``dk`` 16
+    beside ``dv`` 24, three heads."""
     kernels(heads)
     # not ``_GRADS``: that jit has traced the rule on the scan
     readings = jax.jit(jax.grad(_weighted(gated_delta_rule),
@@ -253,6 +288,6 @@ def test_interpret_mode_on_a_tpu_is_refused(kernels, monkeypatch):
 
     kernels(2)
     monkeypatch.setattr(_platform, "operand_platform", lambda *a: "tpu")
+    operands = _chunk_operands(1, 1, 2, 16, 16, 16, jnp.float32)[:6]
     with pytest.raises(RuntimeError, match="interpret mode"):
-        module._carry_state(*_state_operands(1, 1, 2, 16, 16, 16,
-                                             jnp.float32)[:6])
+        module._rule_of_chunks(*operands, module._decay(operands[3]))
