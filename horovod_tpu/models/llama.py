@@ -51,9 +51,11 @@ _EXPERT_MATRICES = ("moe_gate", "moe_up", "moe_down")
 class LayerSpec(typing.NamedTuple):
     """One layer of ``LlamaConfig.layer_plan``: WHERE its parameters
     live (entry ``index`` of the stack ``params[stack]``) and WHAT it
-    runs (its token ``mixer``, "attention", "conv", "linear" or "mamba";
-    a dense or an expert FFN; an attention layer's window, 0 = none, and
-    whether it carries RoPE)."""
+    runs (its token ``mixer``, "attention", "conv", "linear", "mamba" or
+    "mamba2"; a dense or an expert FFN; an attention layer's window, 0 =
+    none, and whether it carries RoPE). A layer of ONE part
+    (``one_part_layers``) has no ``mixer`` or no FFN: ``mixer`` is None,
+    or ``dense_ffn`` is."""
     stack: str
     index: int
     mixer: str
@@ -256,31 +258,113 @@ class LlamaConfig:
     # array exists, forward or backward. 0: whole logits.
     # ``llama_forward`` returns whole logits whatever this says.
     loss_chunk: int = 0
+    # A layer is ONE part, a token mixer or a feed-forward part, under
+    # one norm and one residual add (nemotron_h's
+    # ``hybrid_override_pattern``): ``layer_types`` then holds
+    # ``"mamba2"`` and ``"full_attention"`` (a mixer and NO FFN) and
+    # ``"experts"`` (an expert layer and NO mixer), each stack holds its
+    # own leaves only (``layer_plan``). Off, every layer is a mixer and
+    # an FFN.
+    one_part_layers: bool = False
+    # A ``mamba2`` layer (``_mamba2``; Mamba-2's SSD mixer):
+    # ``ssd_heads`` heads of ``ssd_head_dim`` channels, ``ssd_state``
+    # states, ``B`` / ``C`` shared by the heads of each of
+    # ``ssd_groups`` groups, the recurrence in chunks of ``ssd_chunk``
+    # tokens (``ops/ssd.py``); its convolution has ``conv_taps`` taps,
+    # with a bias where ``mamba_conv_bias``.
+    ssd_heads: int = 0
+    ssd_head_dim: int = 0
+    ssd_state: int = 0
+    ssd_groups: int = 0
+    ssd_chunk: int = 0
+    # The feed-forward parts' form, dense, shared and routed alike:
+    # ``"swiglu"`` (three matrices, ``(silu(h Wg) * h Wu) Wd``) or
+    # ``"relu2"`` (two, ``relu(h Wu)^2 Wd``: no gate leaf exists).
+    ffn_act: str = "swiglu"
+    # The ROUTED experts work in a space ``moe_latent`` wide (0: in
+    # ``d_model``), behind ``moe_lat_down`` [D, l] and in front of
+    # ``moe_lat_up`` [l, D], one pair an expert layer; the router and the
+    # shared expert read the ``d_model``-wide input.
+    moe_latent: int = 0
+    # The shared expert's width where it is not ``n_shared_experts x
+    # moe_d_ff`` (``moe_shared_expert_intermediate_size``; 0 = that).
+    shared_d_ff: int = 0
+    # Multi-token prediction, training's form (DeepSeek-V3, section
+    # 2.2): ``mtp_layers`` modules (one is implemented) of the layer
+    # types ``mtp_types`` under ``params["mtp"]`` read the main model's
+    # last hidden state and the NEXT token's embedding and predict the
+    # token after it through the main model's head; ``llama_loss`` adds
+    # ``mtp_weight`` times that cross-entropy.
+    mtp_layers: int = 0
+    mtp_types: tuple = ()
+    mtp_weight: float = 0.0
 
     def __post_init__(self):
         if self.layer_types and len(self.layer_types) != self.n_layers:
             raise ValueError(
                 f"layer_types names {len(self.layer_types)} layers, "
                 f"n_layers is {self.n_layers}")
-        if any(t not in ("sliding_attention", "full_attention", "conv",
-                         "linear_attention", "mamba")
-               for t in self.layer_types):
-            raise ValueError(f"unknown layer type in {self.layer_types}")
-        linear = "linear_attention" in self.layer_types
-        mamba = "mamba" in self.layer_types
-        if ("conv" in self.layer_types or linear or mamba) \
+        types = self.layer_types + self.mtp_types
+        one_part = ("mamba2", "full_attention", "experts")
+        if any(t not in (one_part if self.one_part_layers else (
+                "sliding_attention", "full_attention", "conv",
+                "linear_attention", "mamba")) for t in types):
+            raise ValueError(
+                f"unknown layer type in {types}: with one_part_layers "
+                f"{one_part}, without it no 'mamba2' and no 'experts'")
+        linear = "linear_attention" in types
+        mamba, mamba2 = "mamba" in types, "mamba2" in types
+        if ("conv" in types or linear or mamba or mamba2) \
                 != (self.conv_taps > 0):
-            raise ValueError("conv, linear_attention and mamba layers and "
-                             "conv_taps come together: "
-                             f"{self.layer_types}, {self.conv_taps} taps")
+            raise ValueError("conv, linear_attention, mamba and mamba2 "
+                             "layers and conv_taps come together: "
+                             f"{types}, {self.conv_taps} taps")
         sizes = (self.mamba_d_state, self.mamba_dt_rank, self.mamba_expand)
-        if mamba != all(sizes) or (not mamba and (
-                any(sizes) or self.mamba_conv_bias)):
+        if mamba != all(sizes) or (not mamba and any(sizes)) or (
+                self.mamba_conv_bias and not (mamba or mamba2)):
             raise ValueError(
                 "mamba layers and their three sizes (mamba_d_state, "
                 f"mamba_dt_rank, mamba_expand) come together: {sizes}: "
                 "the mixer's projections and its state have no other "
                 "source")
+        sizes = (self.ssd_heads, self.ssd_head_dim, self.ssd_state,
+                 self.ssd_groups, self.ssd_chunk)
+        if mamba2 != all(sizes) or (not mamba2 and any(sizes)):
+            raise ValueError(
+                "mamba2 layers and their five sizes (ssd_heads, "
+                "ssd_head_dim, ssd_state, ssd_groups, ssd_chunk) come "
+                f"together: {sizes}: the mixer's projections and its "
+                "state have no other source")
+        if mamba2 and self.ssd_heads % self.ssd_groups:
+            raise ValueError(
+                f"{self.ssd_heads} ssd heads are no multiple of "
+                f"{self.ssd_groups} groups: each group's B and C serve a "
+                "whole number of heads")
+        if self.one_part_layers and "experts" in types \
+                and not self.n_experts:
+            raise ValueError("an 'experts' layer routes over n_experts: 0")
+        if self.one_part_layers and self.n_dense_layers:
+            raise ValueError("one_part_layers names every layer's part in "
+                             "layer_types: no n_dense_layers")
+        if self.one_part_layers and not self.layer_types:
+            raise ValueError("one_part_layers names every layer's part in "
+                             "layer_types: empty")
+        if self.ffn_act not in ("swiglu", "relu2"):
+            raise ValueError(f"unknown ffn_act {self.ffn_act!r}")
+        if (self.moe_latent or self.shared_d_ff) and not self.n_experts:
+            raise ValueError("moe_latent and shared_d_ff size an expert "
+                             "layer: n_experts is 0")
+        if self.shared_d_ff and not self.n_shared_experts:
+            raise ValueError("shared_d_ff is the shared expert's width: "
+                             "n_shared_experts is 0")
+        if self.mtp_layers not in (0, 1) or bool(self.mtp_layers) != bool(
+                self.mtp_types) or bool(self.mtp_layers) != (
+                self.mtp_weight > 0):
+            raise ValueError(
+                f"mtp_layers {self.mtp_layers}, mtp_types "
+                f"{self.mtp_types} and mtp_weight {self.mtp_weight} come "
+                "together, for ONE module (modules that share weights "
+                "over depths are not implemented)")
         if self.loss_chunk < 0:
             raise ValueError(f"loss_chunk {self.loss_chunk}: tokens a "
                              "block of the loss's head, 0 for whole "
@@ -338,16 +422,33 @@ class LlamaConfig:
         return self.mamba_expand * self.d_model
 
     @property
+    def ssd_d_inner(self):
+        """A mamba2 layer's channels."""
+        return self.ssd_heads * self.ssd_head_dim
+
+    @property
     def expert_width(self):
         return self.moe_d_ff or self.d_ff
+
+    @property
+    def shared_width(self):
+        """The shared expert's width."""
+        return self.shared_d_ff or self.n_shared_experts * self.expert_width
+
+    @property
+    def expert_d_in(self):
+        """The width of the rows the routed experts read and write."""
+        return self.moe_latent or self.d_model
 
     @property
     def experts_here(self):
         """How many experts' weights a layer of this program holds."""
         return self.n_experts_held or self.n_experts
 
-    def layer_plan(self):
-        """One :data:`LayerSpec` a layer, in order: the ONE rule for
+    def layer_plan(self, mtp=False):
+        """One :data:`LayerSpec` a layer, in order (``mtp``: a layer of
+        the MTP module, ``mtp_types``, whose stacks lie under
+        ``params["mtp"]``): the ONE rule for
         where a layer's parameters live and which program it runs, read
         by ``llama_init``, ``_run_layers`` and whatever walks the tree
         (the references, the benchmark's adapters). Layers with the same
@@ -360,19 +461,29 @@ class LlamaConfig:
         ``mamba_1_layers`` and so on, so that each run is a whole stack
         and ``_run_layers`` can scan it), and the leading dense layers
         of a sparse-expert model apart as
-        ``dense_layers`` / ``dense_conv_layers``. Every name ends in
+        ``dense_layers`` / ``dense_conv_layers``. Under
+        ``one_part_layers`` a layer is a mixer alone (``dense_ffn``
+        None: ``mamba2_layers`` with the ``ssd_*`` leaves, ``layers``
+        with attention's and no FFN's) or an expert layer alone
+        (``mixer`` None: ``expert_layers`` with the router's, the
+        experts' and the shared expert's leaves and no ``wq`` ..
+        ``wo``), under its one norm. Every name ends in
         ``layers``: ``llama_partition_rules`` shards them alike."""
         plan, filled = [], collections.Counter()
         runs = -1              # of mamba layers, so far
-        for i in range(self.n_layers):
-            kind = self.layer_types[i] if self.layer_types \
-                else "full_attention"
+        types = self.mtp_types if mtp else self.layer_types
+        for i in range(len(types) if mtp else self.n_layers):
+            kind = types[i] if types else "full_attention"
             sliding = kind == "sliding_attention"
             mixer = {"conv": "conv", "linear_attention": "linear",
-                     "mamba": "mamba"}.get(kind, "attention")
+                     "mamba": "mamba", "mamba2": "mamba2",
+                     "experts": None}.get(kind, "attention")
             dense_ffn = self.n_experts == 0 or i < self.n_dense_layers
             stack = ("dense_" if i < self.n_dense_layers else "") \
-                + ("" if mixer == "attention" else mixer + "_") + "layers"
+                + ("" if mixer == "attention" else f"{mixer}_") + "layers"
+            if self.one_part_layers:
+                dense_ffn = None if mixer else False
+                stack = stack if mixer else "expert_layers"
             if mixer == "mamba":
                 runs += not plan or (plan[-1].mixer, plan[-1].dense_ffn) \
                     != (mixer, dense_ffn)
@@ -382,8 +493,7 @@ class LlamaConfig:
                 stack, filled[stack], mixer, dense_ffn,
                 self.sliding_window if sliding else 0,
                 mixer == "attention" and (
-                    sliding or not self.layer_types
-                    or self.rope_full_attention)))
+                    sliding or not types or self.rope_full_attention)))
             filled[stack] += 1
         return plan
 
@@ -412,7 +522,11 @@ class LlamaConfig:
                             "linear_value_dim", "partial_rotary",
                             "shared_expert_gate", "mamba_d_state",
                             "mamba_dt_rank", "mamba_expand",
-                            "mamba_conv_bias", "loss_chunk")
+                            "mamba_conv_bias", "loss_chunk",
+                            "one_part_layers", "ssd_heads", "ssd_head_dim",
+                            "ssd_state", "ssd_groups", "ssd_chunk",
+                            "ffn_act", "moe_latent", "shared_d_ff",
+                            "mtp_layers", "mtp_types", "mtp_weight")
                 if getattr(self, f) != getattr(d, f)] \
             + (["qk_norm"] if self.qk_norm == "head" else [])
 
@@ -473,7 +587,38 @@ def llama_init(config, key):
         the leaves every configuration has, in the order it always did;
         ``x`` those of the leaves only the newer fields add, so that an
         older configuration's weights do not move."""
-        if mixer == "conv":
+        if mixer is None:        # an expert layer alone: its one norm
+            layers = {"mlp_norm": jnp.ones((L, c.d_model), pd)}
+        elif mixer == "mamba2":
+            # [z | x B C | dt] side by side, as Mamba-2 publishes them;
+            # B and C a group.
+            di, gn = c.ssd_d_inner, c.ssd_groups * c.ssd_state
+            mk = iter(jax.random.split(next(x), 2))
+            layers = {
+                "ssd_norm": jnp.ones((L, c.d_model), pd),
+                "ssd_in": dense(next(k), (L, c.d_model,
+                                          2 * di + 2 * gn + c.ssd_heads),
+                                c.d_model),
+                "ssd_conv": dense(next(k), (L, c.conv_taps, di + 2 * gn),
+                                  c.conv_taps),
+                # Mamba-2's start: A uniform over (1, 16) a head;
+                # softplus(ssd_dt_bias) log-uniform over (1e-3, 0.1)
+                # (``time_step_min`` .. ``time_step_max``), floored at
+                # ``time_step_floor`` 1e-4; D = 1.
+                "ssd_a_log": jnp.log(jax.random.uniform(
+                    next(mk), (L, c.ssd_heads), jnp.float32, 1.0, 16.0)
+                    ).astype(pd),
+                "ssd_dt_bias": _softplus_inverse(jnp.maximum(jnp.exp(
+                    jax.random.uniform(next(mk), (L, c.ssd_heads),
+                                       jnp.float32, jnp.log(1e-3),
+                                       jnp.log(0.1))), 1e-4)).astype(pd),
+                "ssd_d": jnp.ones((L, c.ssd_heads), pd),
+                "ssd_out_norm": jnp.ones((L, di), pd),
+                "ssd_out": dense(next(k), (L, di, c.d_model), di),
+            }
+            if c.mamba_conv_bias:
+                layers["ssd_conv_bias"] = jnp.zeros((L, di + 2 * gn), pd)
+        elif mixer == "conv":
             layers = {
                 "conv_norm": jnp.ones((L, c.d_model), pd),
                 "conv_in": dense(next(k), (L, c.d_model, 3 * c.d_model),
@@ -565,29 +710,38 @@ def llama_init(config, key):
         if c.post_norm:
             layers["post_attn_norm"] = jnp.ones((L, c.d_model), pd)
             layers["post_mlp_norm"] = jnp.ones((L, c.d_model), pd)
+        # A ``relu2`` FFN has no gate matrix, dense, routed or shared.
+        gated = c.ffn_act == "swiglu"
+        if dense_ffn is None:     # a mixer alone: its one norm was set
+            layers.pop("mlp_norm", None)
+            return layers
         if dense_ffn:
+            if gated:
+                layers["w_gate"] = dense(next(k), (L, c.d_model, c.d_ff),
+                                         c.d_model)
             layers.update({
-                "w_gate": dense(next(k), (L, c.d_model, c.d_ff),
-                                c.d_model),
                 "w_up": dense(next(k), (L, c.d_model, c.d_ff), c.d_model),
                 "w_down": dense(next(k), (L, c.d_ff, c.d_model), c.d_ff),
             })
             return layers
-        E, H, F = c.n_experts, c.experts_here, c.expert_width
+        E, H, F, l = c.n_experts, c.experts_here, c.expert_width, \
+            c.expert_d_in
+        layers["router"] = dense(next(k), (L, c.d_model, E), c.d_model)
+        if gated:
+            layers["moe_gate"] = dense(next(k), (L, H, l, F), l)
         layers.update({
-            "router": dense(next(k), (L, c.d_model, E), c.d_model),
-            "moe_gate": dense(next(k), (L, H, c.d_model, F), c.d_model),
-            "moe_up": dense(next(k), (L, H, c.d_model, F), c.d_model),
-            "moe_down": dense(next(k), (L, H, F, c.d_model), F),
+            "moe_up": dense(next(k), (L, H, l, F), l),
+            "moe_down": dense(next(k), (L, H, F, l), F),
         })
         if c.score_func == "sigmoid":
             # float32 whatever param_dtype: it is compared with scores.
             layers["expert_bias"] = jnp.zeros((L, E), jnp.float32)
         if c.n_shared_experts:
-            Fs = c.n_shared_experts * F
+            Fs = c.shared_width
+            if gated:
+                layers["shared_gate"] = dense(next(x), (L, c.d_model, Fs),
+                                              c.d_model)
             layers.update({
-                "shared_gate": dense(next(x), (L, c.d_model, Fs),
-                                     c.d_model),
                 "shared_up": dense(next(x), (L, c.d_model, Fs),
                                    c.d_model),
                 "shared_down": dense(next(x), (L, Fs, c.d_model), Fs),
@@ -595,33 +749,59 @@ def llama_init(config, key):
             if c.shared_expert_gate:
                 layers["shared_score"] = dense(next(x), (L, c.d_model, 1),
                                                c.d_model)
+        if c.moe_latent:
+            layers.update({
+                "moe_lat_down": dense(next(x), (L, c.d_model, l),
+                                      c.d_model),
+                "moe_lat_up": dense(next(x), (L, l, c.d_model), l),
+            })
         return layers
 
     # The stacks this model has, each with its kind and depth. "layers"
     # draws from ``k`` ahead of the embedding and the head, as it always
     # did; every other stack from a fold of its own.
-    stacks = {}
-    for spec in c.layer_plan():
-        stacks[spec.stack] = (spec.mixer, spec.dense_ffn, spec.index + 1)
     folds = {"dense_layers": 2, "conv_layers": 3, "dense_conv_layers": 4,
              "linear_layers": 5, "dense_linear_layers": 6}
-    params = {}
-    for at, name in enumerate(sorted(stacks, key=lambda n: n != "layers")):
-        mixer, dense_ffn, L = stacks[name]
-        if name == "layers":
-            dealt = k, iter(jax.random.split(jax.random.fold_in(key, 1), 8))
-        else:
-            # a mamba run's fold follows its place among the stacks
-            lead = jax.random.split(jax.random.fold_in(
-                key, folds.get(name, 16 + at)), 16)
-            dealt = iter(lead[:8]), iter(lead[8:])
-        params[name] = stack(*dealt, L, mixer, dense_ffn)
+
+    def stacks_of(plan, key, main):
+        """The stacks ``plan`` names, drawn from ``key``: the model's
+        (``main``: its key deals the embedding and the head too) or the
+        MTP module's."""
+        stacks, made = {}, {}
+        for spec in plan:
+            stacks[spec.stack] = (spec.mixer, spec.dense_ffn, spec.index + 1)
+        for at, name in enumerate(sorted(stacks,
+                                         key=lambda n: n != "layers")):
+            mixer, dense_ffn, L = stacks[name]
+            if name == "layers" and main is not None:
+                dealt = main, iter(jax.random.split(
+                    jax.random.fold_in(key, 1), 8))
+            else:
+                # a mamba run's fold follows its place among the stacks
+                lead = jax.random.split(jax.random.fold_in(
+                    key, folds.get(name, 16 + at)), 16)
+                dealt = iter(lead[:8]), iter(lead[8:])
+            made[name] = stack(*dealt, L, mixer, dense_ffn)
+        return made
+
+    params = stacks_of(c.layer_plan(), key, k)
     params["embed"] = (jax.random.normal(
         next(k), (c.vocab_size, c.d_model), jnp.float32) * 0.02).astype(pd)
     params["final_norm"] = jnp.ones(c.d_model, pd)
     if not c.tie_embeddings:
         params["lm_head"] = dense(next(k), (c.d_model, c.vocab_size),
                                   c.d_model)
+    if c.mtp_layers:
+        mkey = jax.random.fold_in(key, 64)
+        params["mtp"] = {
+            **stacks_of(c.layer_plan(mtp=True), mkey, None),
+            "token_norm": jnp.ones(c.d_model, pd),
+            "hidden_norm": jnp.ones(c.d_model, pd),
+            # [embedding ; hidden] side by side -> d_model
+            "eh_proj": dense(jax.random.fold_in(mkey, 1),
+                             (2 * c.d_model, c.d_model), 2 * c.d_model),
+            "final_norm": jnp.ones(c.d_model, pd),
+        }
     return params
 
 
@@ -667,6 +847,16 @@ def llama_partition_rules(pipeline=False):
         (r"layers/ssm_out", P(lead, None, "fsdp")),
         (r"layers/ssm_(x|dt|conv|a_log)$", P(lead, None, None)),
         (r"layers/ssm_(dt_bias|conv_bias|d)$", P(lead, None)),
+        # Mamba-2: as Mamba.
+        (r"layers/ssd_in", P(lead, "fsdp", None)),
+        (r"layers/ssd_out$", P(lead, None, "fsdp")),
+        (r"layers/ssd_conv$", P(lead, None, None)),
+        (r"layers/ssd_(dt_bias|conv_bias|a_log|d)$", P(lead, None)),
+        # The latent projections round the routed experts.
+        (r"layers/moe_lat_down", P(lead, "fsdp", None)),
+        (r"layers/moe_lat_up", P(lead, None, "fsdp")),
+        (r"mtp/eh_proj", P("fsdp", None)),
+        (r"mtp/\w+_norm", P(None)),
         (r"layers/(w|shared)_(gate|up)", P(lead, "fsdp", "tensor")),
         (r"layers/(w|shared)_down", P(lead, "tensor", "fsdp")),
         # MoE: experts shard over the "expert" mesh axis (EP); within an
@@ -1021,6 +1211,56 @@ def _mamba(x, lp, c, mesh, seq_axis):
         return y @ lp["ssm_out"].astype(dt)
 
 
+def _mamba2(x, lp, c, mesh, seq_axis):
+    """nemotron_h's Mamba-2 mixer, the token mixer of a ``mamba2`` layer,
+    on the residual stream ``x`` [B, T, D] -> what it adds: the layer's
+    norm; ``[z, xBC, r] = h W_in``; a depthwise causal convolution of
+    ``conv_taps`` taps (with its bias) and SiLU over ``xBC`` = ``[X, B,
+    C]``, ``B`` and ``C`` a group of heads; ``dt = softplus(r + b_dt)``
+    and ``A = -exp(A_log)`` a head, in float32; the SSD recurrence
+    (``ops/ssd.py``); the gate FIRST, ``y * SiLU(z)``, then an RMSNorm
+    over each group's channels; the output projection. Scopes: the two
+    matmuls ``hvd.ssd.proj``, the recurrence ``hvd.ssd.core``,
+    everything elementwise between them ``hvd.ssd.chain`` (the layer's
+    norm ``hvd.norm``)."""
+    from horovod_tpu.ops.ssd import ssd
+
+    if mesh is not None and (
+            (seq_axis and mesh.shape.get(seq_axis, 1) > 1)
+            or mesh.shape.get("tensor", 1) > 1):
+        raise ValueError(
+            "a mamba2 layer runs whole on each device of the data and "
+            "fsdp axes: its state passes from token to token (no "
+            "sequence axis) and its convolution, group norm and "
+            "recurrence see every head (no tensor axis yet)")
+    dt, f32 = c.compute_dtype, jnp.float32
+    b, t, _ = x.shape
+    H, G, N = c.ssd_heads, c.ssd_groups, c.ssd_state
+    di, gn = c.ssd_d_inner, G * N
+    h = _rmsnorm(x, lp["ssd_norm"].astype(dt), c.norm_eps)
+    with scope("hvd.ssd.proj"):
+        zxr = h @ lp["ssd_in"].astype(dt)
+    with scope("hvd.ssd.chain"):
+        z, xbc, r = jnp.split(zxr, [di, 2 * di + 2 * gn], axis=-1)
+        xbc = jax.nn.silu(_causal_taps(xbc, lp["ssd_conv"],
+                                       lp.get("ssd_conv_bias"))).astype(dt)
+        X, Bm, Cm = jnp.split(xbc, [di, di + gn], axis=-1)
+        step = jax.nn.softplus(r.astype(f32)
+                               + lp["ssd_dt_bias"].astype(f32))
+        rates = -jnp.exp(lp["ssd_a_log"].astype(f32))
+    with scope("hvd.ssd.core"):
+        y = ssd(X.reshape(b, t, H, -1), step, rates,
+                Bm.reshape(b, t, G, N), Cm.reshape(b, t, G, N),
+                lp["ssd_d"], c.ssd_chunk)
+    with scope("hvd.ssd.chain"):
+        y = y.reshape(b, t, G, di // G).astype(f32) \
+            * jax.nn.silu(z.astype(f32)).reshape(b, t, G, di // G)
+        y = (y * lax.rsqrt(jnp.mean(y * y, -1, keepdims=True) + c.norm_eps)
+             ).astype(dt).reshape(b, t, di) * lp["ssd_out_norm"].astype(dt)
+    with scope("hvd.ssd.proj"):
+        return y @ lp["ssd_out"].astype(dt)
+
+
 @partial(jax.custom_vjp, nondiff_argnums=(1,))
 def _top_k(probs, k):
     """``lax.top_k`` over the last axis whose VJP scatters by the
@@ -1147,9 +1387,10 @@ def _moe_ffn(h, lp, c, mesh):
     E, K = c.n_experts, c.n_experts_per_token
     C = max(int(T * K * c.capacity_factor / E), 1)
 
-    if c.n_experts_held:
-        raise ValueError("a share of the experts (n_experts_held) runs "
-                         "through the grouped dispatch only: "
+    if c.n_experts_held or c.moe_latent or c.ffn_act != "swiglu":
+        raise ValueError("a share of the experts (n_experts_held), "
+                         "latent experts (moe_latent) and a relu2 FFN "
+                         "run through the grouped dispatch only: "
                          "moe_impl='grouped'")
     gate_vals, gate_idx, aux = route_layer(h, lp, c)           # [B,T,K]
 
@@ -1214,6 +1455,12 @@ def _swiglu(h, gate, up, down, dt):
         @ down.astype(dt)
 
 
+@scope("hvd.ffn")
+def _relu2(h, up, down, dt):
+    """``ffn_act`` "relu2": two matrices, ``relu(h Wu)^2 Wd``."""
+    return jnp.square(jax.nn.relu(h @ up.astype(dt))) @ down.astype(dt)
+
+
 def _ffn(h, lp, c, mesh=None):
     """One layer's FFN on normalized activations: dense siglu MLP, or
     top-k expert routing (plus the shared expert, where the
@@ -1227,15 +1474,27 @@ def _ffn(h, lp, c, mesh=None):
         if _grouped_dispatch(c, mesh):
             from horovod_tpu.ops.grouped_moe import grouped_moe_ffn
 
-            y, aux = grouped_moe_ffn(h, lp, c)
+            rows = None
+            if c.moe_latent:
+                # The routed experts' rows in the latent space; the
+                # router (and the shared expert) read ``h`` itself.
+                with scope("hvd.moe.latent"):
+                    rows = h @ lp["moe_lat_down"].astype(dt)
+            y, aux = grouped_moe_ffn(h, lp, c, rows)
+            if c.moe_latent:
+                with scope("hvd.moe.latent"):
+                    y = y @ lp["moe_lat_up"].astype(dt)
         elif c.moe_impl not in ("auto", "gshard"):
             raise ValueError(f"unknown moe_impl {c.moe_impl!r}: "
                              "expected 'auto', 'grouped', or 'gshard'")
         else:
             y, aux = _moe_ffn(h, lp, c, mesh)
         if c.n_shared_experts:
-            shared = _swiglu(h, lp["shared_gate"], lp["shared_up"],
-                             lp["shared_down"], dt)
+            if c.ffn_act == "relu2":
+                shared = _relu2(h, lp["shared_up"], lp["shared_down"], dt)
+            else:
+                shared = _swiglu(h, lp["shared_gate"], lp["shared_up"],
+                                 lp["shared_down"], dt)
             if c.shared_expert_gate:
                 with scope("hvd.ffn"):
                     shared = shared * jax.nn.sigmoid(
@@ -1248,6 +1507,9 @@ def _ffn(h, lp, c, mesh=None):
     # The PRE-silu value is what must be saved — silu's own vjp needs
     # its primal input, so saving post-silu would still re-run the
     # matmul to regenerate it.
+    if c.ffn_act == "relu2":
+        return (_relu2(h, lp["w_up"], lp["w_down"], dt),
+                jnp.zeros((2, 0), jnp.float32))
     with scope("hvd.ffn"):
         gate_pre = checkpoint_name(h @ lp["w_gate"].astype(dt), "ffn_gate")
         up = checkpoint_name(h @ lp["w_up"].astype(dt), "ffn_up")
@@ -1294,9 +1556,29 @@ def _llama_hidden(params, tokens, config, mesh=None, seq_axis="seq"):
     """tokens [B, T] -> (what the head reads, [B, T, D] after the final
     norm; the MoE load-balancing loss): ``llama_forward`` less the
     head, which ``llama_loss`` may run in blocks of tokens."""
+    x, aux = _llama_stream(params, tokens, config, mesh, seq_axis)
+    return _final_norm(params, x, config), aux
+
+
+def _final_norm(params, x, c):
+    return _rmsnorm(x, params["final_norm"].astype(c.compute_dtype),
+                    c.norm_eps)
+
+
+def _llama_stream(params, tokens, config, mesh=None, seq_axis="seq"):
+    """tokens [B, T] -> (the residual stream after the last layer, BEFORE
+    the final norm; the MoE load-balancing loss)."""
     c = config
-    dt = c.compute_dtype
     b, t = tokens.shape
+    if _over_sequence(mesh, seq_axis):
+        whole = [f for f in ("one_part_layers", "mtp_layers")
+                 if getattr(c, f)]
+        if whole:
+            raise ValueError(
+                f"LlamaConfig fields {whole} run on no sequence-parallel "
+                "mesh axis (ring / ulysses) yet: a one-part layer's "
+                "recurrence and the MTP term's shift by a token see a "
+                "whole sequence")
 
     def constrain(x):
         return _constrain(x, mesh)
@@ -1336,7 +1618,7 @@ def _llama_hidden(params, tokens, config, mesh=None, seq_axis="seq"):
         x, balance = _run_layers(params, x, c, mesh, seq_axis)
         aux = moe_balance_loss(balance)
 
-    return _rmsnorm(x, params["final_norm"].astype(dt), c.norm_eps), aux
+    return x, aux
 
 
 def llama_expert_load(params, tokens, config):
@@ -1358,9 +1640,10 @@ def _embed(params, tokens, c):
     return x
 
 
-def _run_layers(params, x, c, mesh, seq_axis):
-    """The decoder stack on ``x`` [B, T, D]; returns (x, the expert
-    layers' balance statistics stacked [layers, 2, E]).
+def _run_layers(params, x, c, mesh, seq_axis, mtp=False):
+    """The decoder stack on ``x`` [B, T, D] (``mtp``: the MTP module's
+    layers, ``params`` its own); returns (x, the expert layers' balance
+    statistics stacked [layers, 2, E]).
 
     One algorithm, L layer bodies over stacked parameters, whose
     indexing is dynamic where the compiler can fuse it and static where
@@ -1389,7 +1672,7 @@ def _run_layers(params, x, c, mesh, seq_axis):
 
     Unrolled, program size and compile time are O(depth). A pipeline
     stage (``_stage_scan``) always scans: one layer program by contract."""
-    plan = c.layer_plan()
+    plan = c.layer_plan(mtp)
     kinds = {spec.kind for spec in plan}
     if len(kinds) == 1 and not _grouped_dispatch(c, mesh):
         return lax.scan(_build_layer_body(c, mesh, seq_axis), x,
@@ -1459,6 +1742,15 @@ def _validate_pipeline(c, b, mesh, seq_axis, n_stages):
     pipeline's)."""
     M = c.pipeline_microbatches or n_stages
     plan = c.layer_plan()
+    unscheduled = [f for f in ("one_part_layers", "ffn_act", "moe_latent",
+                               "shared_d_ff", "mtp_layers")
+                   if f in c.training_only_fields()]
+    if unscheduled:
+        raise ValueError(
+            f"LlamaConfig fields {unscheduled} have no pipeline schedule "
+            "yet: a stage scans ONE layer program of a mixer AND a "
+            "SwiGLU FFN over params['layers'], and the last stage's loss "
+            "has one term")
     if len({spec.kind for spec in plan}) > 1 \
             or plan[0].mixer != "attention" or c.tie_embeddings \
             or c.partial_rotary or c.shared_expert_gate:
@@ -1507,12 +1799,18 @@ def _build_layer_body(c, mesh, seq_axis, constrain_acts=True, kind=None):
     one a uniform model has); the FFN follows the parameters it is
     handed (``_ffn``)."""
     dt = c.compute_dtype
-    mixer, _, window, rope = kind or c.layer_plan()[0].kind
+    mixer, dense_ffn, window, rope = kind or c.layer_plan()[0].kind
+    # A layer of ONE part (``one_part_layers``): no mixer, or no FFN.
+    two_parts = mixer is not None and dense_ffn is not None
 
     def constrain(x):
         return _constrain(x, mesh) if constrain_acts else x
 
     def layer(x, lp):
+        if mixer is None:
+            return ffn(x, None, lp)
+        if dense_ffn is None:
+            return x + constrain(mix(x, lp)), jnp.zeros((2, 0), jnp.float32)
         return ffn(x, mix(x, lp), lp)
 
     def mix(x, lp, stage=lambda f: f):
@@ -1525,6 +1823,8 @@ def _build_layer_body(c, mesh, seq_axis, constrain_acts=True, kind=None):
             return _gated_delta_net(x, lp, c, mesh, seq_axis, stage)
         if mixer == "mamba":
             return _mamba(x, lp, c, mesh, seq_axis)
+        if mixer == "mamba2":
+            return _mamba2(x, lp, c, mesh, seq_axis)
         # Shapes from x, not the enclosing scope: under pipelining the
         # layer sees microbatches smaller than the full batch.
         bb, tt = x.shape[0], x.shape[1]
@@ -1563,11 +1863,13 @@ def _build_layer_body(c, mesh, seq_axis, constrain_acts=True, kind=None):
         return attn
 
     def ffn(x, mixed, lp):
-        """The mixer's output joins the stream; then the FFN's."""
-        if c.post_norm:
-            mixed = _rmsnorm(mixed, lp["post_attn_norm"].astype(dt),
-                             c.norm_eps)
-        x = x + constrain(mixed)
+        """The mixer's output (None: the layer has no mixer) joins the
+        stream; then the FFN's."""
+        if mixed is not None:
+            if c.post_norm:
+                mixed = _rmsnorm(mixed, lp["post_attn_norm"].astype(dt),
+                                 c.norm_eps)
+            x = x + constrain(mixed)
 
         h = _rmsnorm(x, lp["mlp_norm"].astype(dt), c.norm_eps)
         ff, aux = _ffn(h, lp, c, mesh)
@@ -1580,7 +1882,9 @@ def _build_layer_body(c, mesh, seq_axis, constrain_acts=True, kind=None):
     if c.remat == "dots":
         body = jax.checkpoint(
             layer, policy=jax.checkpoint_policies.dots_saveable)
-    elif c.remat == "attn":
+    elif c.remat == "attn" or (c.remat == "attn/ffn" and not two_parts):
+        # (A layer of one part under "attn/ffn": its one part under its
+        # one checkpoint.)
         # Full remat except the attention output and the flash kernel's
         # residuals (o + logsumexp — one [B,T,H*D] bf16 and one
         # [B,H,1,T] f32 per layer): saving flash_lse is what actually
@@ -1713,11 +2017,10 @@ def llama_loss(params, batch, config, mesh=None, seq_axis="seq"):
         raise ValueError(
             f"unknown pipeline_schedule {config.pipeline_schedule!r}: "
             "expected 'gpipe', '1f1b', or 'interleaved_1f1b'")
-    x, aux = _llama_hidden(params, batch["tokens"], config, mesh, seq_axis)
-    if config.loss_chunk:
-        nll = _token_nll_in_blocks(params, x, batch["targets"], config)
-    else:
-        nll = _token_nll(_head(params, x, config), batch["targets"])
+    stream, aux = _llama_stream(params, batch["tokens"], config, mesh,
+                                seq_axis)
+    nll = _head_nll(params, _final_norm(params, stream, config),
+                    batch["targets"], config)
     mask = batch.get("mask")
     with scope("hvd.loss"):
         if mask is None:
@@ -1727,7 +2030,50 @@ def llama_loss(params, batch, config, mesh=None, seq_axis="seq"):
             loss = jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
     if config.n_experts > 0 and config.moe_aux_weight:
         loss = loss + config.moe_aux_weight * aux
+    if config.mtp_layers:
+        loss = loss + config.mtp_weight * _mtp_loss(
+            params, stream, batch, config, mesh, seq_axis)
     return loss
+
+
+def _head_nll(params, x, targets, c):
+    """The head on the final norm's output ``x`` and the cross-entropy a
+    token, whole or (``loss_chunk``) in blocks of tokens."""
+    if c.loss_chunk:
+        return _token_nll_in_blocks(params, x, targets, c)
+    return _token_nll(_head(params, x, c), targets)
+
+
+def _mtp_loss(params, stream, batch, c, mesh, seq_axis):
+    """The multi-token-prediction term (DeepSeek-V3, section 2.2, one
+    module): position ``t`` of the main model's residual stream BEFORE
+    its final norm, ``stream`` [B, T, D], and the embedding of token
+    ``t+1`` (``targets[t]``), each under a norm of its own, side by side
+    through ``eh_proj``; the module's layers (``layer_plan(mtp=True)``:
+    the same layer programs, its own weights); its own final norm; the main
+    model's head; the cross-entropy against token ``t+2``
+    (``targets[t+1]``) over the positions that have one (a sequence's
+    last has none). The expert layers' balance statistics of the module
+    join no auxiliary term."""
+    mp, dt = params["mtp"], c.compute_dtype
+    targets = batch["targets"]
+    nxt = _embed(params, targets, c)
+    with scope("hvd.mtp"):
+        m = jnp.concatenate(
+            [_rms(nxt, mp["token_norm"].astype(dt), c.norm_eps),
+             _rms(stream, mp["hidden_norm"].astype(dt), c.norm_eps)], -1) \
+            @ mp["eh_proj"].astype(dt)
+    m, _ = _run_layers(mp, _constrain(m, mesh), c, mesh, seq_axis,
+                       mtp=True)
+    nll = _head_nll(params, _final_norm(mp, m, c),
+                    jnp.roll(targets, -1, axis=1), c)
+    with scope("hvd.mtp"):
+        has_target = jnp.arange(targets.shape[1]) < targets.shape[1] - 1
+        mask = has_target.astype(jnp.float32) * (
+            1.0 if batch.get("mask") is None
+            else batch["mask"].astype(jnp.float32))
+        mask = jnp.broadcast_to(mask, nll.shape)
+        return jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
 
 
 @scope("hvd.loss")

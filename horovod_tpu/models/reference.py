@@ -777,3 +777,220 @@ def jamba_loss(params, batch, cfg, vocab_rows=None):
                                -1)[..., 0]
     mask = batch.get("mask", jnp.ones_like(nll))
     return jnp.sum(nll * mask) / jnp.sum(mask)
+
+
+# ---------------------------------------------------------------------
+# NVIDIA-Nemotron-3-Super-120B-A12B (``model_type`` nemotron_h): Hugging
+# Face's modeling_nemotron_h.py; Mamba-2 (Dao & Gu, arXiv 2405.21060);
+# the router and the multi-token-prediction glue of DeepSeek-V3 (arXiv
+# 2412.19437, sections 2.1.2 and 2.2).
+#
+# Decoder, pre-norm, residual stream ``x`` [B, T, D], no position
+# encoding anywhere. A layer has ONE part, named by ``layer_types``
+# (``hybrid_override_pattern``: M, *, E): ``x = x + part(RMSNorm(x))``.
+#
+# - ``mamba2`` (``H = ssd_heads`` heads of ``P = ssd_head_dim``
+#   channels, ``N = ssd_state`` states, ``G = ssd_groups`` groups: head
+#   ``h`` reads group ``h // (H / G)``), a sequence at a time:
+#   1. ``[z, xBC, r] = h W_in`` (``ssd_in``, columns ``[z | xBC | dt]``,
+#      ``H P``, ``H P + 2 G N`` and ``H`` wide), no bias;
+#   2. ``xBC = SiLU(conv(xBC) + b_conv)``: depthwise, causal,
+#      ``conv_kernel`` taps (``ssd_conv``, ``ssd_conv_bias``);
+#      ``[X, B, C] = xBC``, ``H P``, ``G N``, ``G N`` wide;
+#   3. ``dt = softplus(r + dt_bias)`` (no clamp), ``A = -exp(A_log)`` a
+#      head, float32;
+#   4. a head's state ``S`` [P, N], ``S_0 = 0``; for each token:
+#      ``S_t = exp(dt_t A) S_{t-1} + dt_t X_t (x) B_t``;
+#      ``Y_t = S_t C_t + D X_t``;
+#   5. ``y = GroupRMSNorm(Y * SiLU(z))``: the gate FIRST, then an RMSNorm
+#      over each group's ``H P / G`` channels, gain ``ssd_out_norm``;
+#   6. ``y W_out`` (``ssd_out``), no bias;
+# - ``full_attention``: ``n_heads`` query heads on ``n_kv_heads``
+#   key/value heads (32 on 2), causal ``softmax(q k / sqrt(head_dim))
+#   v``, ``W_o``. No RoPE, no bias, no gate, no q/k norm;
+# - ``experts``: ``s = sigmoid(u W_r)``; the K experts with the largest
+#   ``s + expert_bias`` (``n_group`` 1: no group limit), weights
+#   ``route_scale * s / (sum of the chosen s + 1e-20)``; ``v = u
+#   W_lat_down``; ``r = sum over the chosen e of w_e relu(v W1_e)^2
+#   W2_e``; out ``= r W_lat_up + relu(u Ws1)^2 Ws2``: the router and the
+#   shared expert read the ``D``-wide ``u``, only the routed experts live
+#   in the latent space; no auxiliary term;
+# - ``logits = RMS_final(x) W_head`` (untied); MTP, one module, on the
+#   stream ``x`` BEFORE the final norm: ``m_t = [RMS_e(embed[token_{t+1}])
+#   ; RMS_h(x_t)] W_eh``; the module's layers (``mtp_types``) on ``m``;
+#   ``logits2 = RMS_mtp(m) W_head``; ``loss = CE(logits, token_{t+1}) +
+#   mtp_weight * CE(logits2, token_{t+2})``, the second over the
+#   positions that have a ``token_{t+2}``.
+#
+# Departures: parameters stored in bf16 are read as float32;
+# ``expert_bias`` is data; ``num_logits_to_keep``, ``use_mamba_kernels``
+# and ``moe_shared_expert_overlap`` are runtime keys and change no
+# result; no clamp on ``dt`` (``time_step_limit`` is no key of the
+# published config); where the latent projections stand, the glue's
+# order and ``mtp_weight`` are the family's, not keys (the
+# configuration's file lists them under ``assumed``). Step 4 is a
+# ``lax.scan`` over TOKENS exactly as written: no chunk, no kernel,
+# nothing of ops/ssd.py.
+# ---------------------------------------------------------------------
+
+def nemotronh_ssd(X, dt, A, Bm, Cm, D):
+    """Step 4 for ``X`` [B, T, H, P], ``dt`` [B, T, H], ``A``, ``D``
+    [H], ``Bm``, ``Cm`` [B, T, G, N], float32 -> ``Y`` [B, T, H, P]."""
+    b, _, heads, p = X.shape
+    rep = heads // Bm.shape[2]
+
+    def token(S, x):
+        X, dt, Bt, Ct = x               # [B, H, P], [B, H], [B, G, N]
+        Bt, Ct = jnp.repeat(Bt, rep, 1), jnp.repeat(Ct, rep, 1)
+        S = jnp.exp(dt * A)[..., None, None] * S \
+            + (dt[..., None] * X)[..., None] * Bt[:, :, None, :]
+        return S, jnp.einsum("bhpn,bhn->bhp", S, Ct) + D[:, None] * X
+
+    _, y = jax.lax.scan(
+        token, jnp.zeros((b, heads, p, Bm.shape[-1]), F32),
+        tuple(jnp.moveaxis(x, 1, 0) for x in (X, dt, Bm, Cm)))
+    return jnp.moveaxis(y, 0, 1)
+
+
+def nemotronh_mamba2_mixer(h, lp, cfg):
+    """The mamba2 mixer on normalized ``h`` [B, T, D] with one layer's
+    float32 parameters (steps 1-6 above)."""
+    b, t, _ = h.shape
+    H, G, N = cfg.ssd_heads, cfg.ssd_groups, cfg.ssd_state
+    di, gn = H * cfg.ssd_head_dim, G * N
+    zxr = h @ lp["ssd_in"]
+    z, xbc, r = zxr[..., :di], zxr[..., di:2 * di + 2 * gn], \
+        zxr[..., 2 * di + 2 * gn:]
+    taps, conv = lp["ssd_conv"].shape[0], jnp.zeros_like(xbc)
+    for j in range(taps):
+        back = taps - 1 - j                  # xBC as it was ``back`` ago
+        conv = conv + lp["ssd_conv"][j] * jnp.concatenate(
+            [jnp.zeros((b, back, xbc.shape[-1]), F32), xbc[:, :t - back]],
+            1)
+    xbc = jax.nn.silu(conv + lp.get("ssd_conv_bias", 0.0))
+    y = nemotronh_ssd(
+        xbc[..., :di].reshape(b, t, H, -1),
+        jax.nn.softplus(r + lp["ssd_dt_bias"]), -jnp.exp(lp["ssd_a_log"]),
+        xbc[..., di:di + gn].reshape(b, t, G, N),
+        xbc[..., di + gn:].reshape(b, t, G, N), lp["ssd_d"])
+    return _gated_group_norm(y.reshape(b, t, di), z, lp["ssd_out_norm"], G,
+                             cfg.norm_eps) @ lp["ssd_out"]
+
+
+def _gated_group_norm(y, z, gain, groups, eps):
+    """Step 5: the gate FIRST, then the RMSNorm over each group."""
+    y = (y * jax.nn.silu(z)).reshape(*y.shape[:-1], groups, -1)
+    y = y * jax.lax.rsqrt(jnp.mean(y * y, -1, keepdims=True) + eps)
+    return y.reshape(z.shape) * gain
+
+
+def _relu2_act(x):
+    return jnp.square(jax.nn.relu(x))
+
+
+def _relu2(h, up, down):
+    return _relu2_act(h @ up) @ down
+
+
+def nemotronh_expert_layer(h, lp, cfg):
+    """One expert layer on normalized ``h`` [B, T, D], in two parts:
+    ``(Shared(h), the routed sum over the experts ``lp`` holds, after the
+    up-projection out of the latent space)``. ``lp`` is one layer's
+    float32 parameters; its expert matrices hold experts ``first_expert
+    .. + n_experts_held - 1`` (all, where no share is set). The routed
+    part is linear in the experts: over all the shares it adds up to the
+    uncut layer's."""
+    first = cfg.first_expert
+    held = cfg.n_experts_held or cfg.n_experts
+    w = afmoe_route(h, lp, cfg)[..., first:first + held]
+    v = h @ lp["moe_lat_down"]
+    act = _relu2_act(jnp.einsum("btl,elf->btef", v, lp["moe_up"]))
+    y = jnp.einsum("btef,efl->btel", act, lp["moe_down"])
+    return (_relu2(h, lp["shared_up"], lp["shared_down"]),
+            jnp.einsum("bte,btel->btl", w, y) @ lp["moe_lat_up"])
+
+
+def _nemotronh_layers(stacks, types, x, cfg):
+    """The layers ``types`` on the stream ``x``, their parameters found
+    in ``stacks`` by their own count (``layers``, ``mamba2_layers``,
+    ``expert_layers``: a kind's layers in order)."""
+    hd = cfg.head_dim
+    rep = cfg.n_heads // cfg.n_kv_heads
+    b, t, _ = x.shape
+    mask = jnp.arange(t)[None, :] <= jnp.arange(t)[:, None]
+    names = {"mamba2": "mamba2_layers", "full_attention": "layers",
+             "experts": "expert_layers"}
+    for l, kind in enumerate(types):
+        at = sum(k == kind for k in types[:l])
+        lp = jax.tree.map(lambda w: w[at].astype(F32), stacks[names[kind]])
+        if kind == "mamba2":
+            x = x + nemotronh_mamba2_mixer(
+                _rms(x, lp["ssd_norm"], cfg.norm_eps), lp, cfg)
+        elif kind == "experts":
+            x = x + sum(nemotronh_expert_layer(
+                _rms(x, lp["mlp_norm"], cfg.norm_eps), lp, cfg))
+        else:
+            h = _rms(x, lp["attn_norm"], cfg.norm_eps)
+            q = (h @ lp["wq"]).reshape(b, t, cfg.n_heads, hd)
+            k = (h @ lp["wk"]).reshape(b, t, cfg.n_kv_heads, hd)
+            v = (h @ lp["wv"]).reshape(b, t, cfg.n_kv_heads, hd)
+            k, v = jnp.repeat(k, rep, 2), jnp.repeat(v, rep, 2)
+            s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / (hd ** 0.5)
+            p = jax.nn.softmax(jnp.where(mask, s, -jnp.inf), -1)
+            a = jnp.einsum("bhqk,bkhd->bqhd", p, v).reshape(b, t, -1)
+            x = x + a @ lp["wo"]
+    return x
+
+
+def nemotronh_forward(params, tokens, cfg, mtp_targets=None):
+    """tokens [B, T] -> logits [B, T, vocab] f32 (see the description
+    above); with ``mtp_targets`` [B, T] (token ``t+1`` a position) ->
+    (logits, the MTP module's logits). ``params`` is the program's tree,
+    any storage dtype."""
+    with jax.default_matmul_precision("highest"):
+        embed = params["embed"].astype(F32)
+        head = params["lm_head"].astype(F32)
+        x = _nemotronh_layers(params, cfg.layer_types, embed[tokens], cfg)
+        logits = _rms(x, params["final_norm"].astype(F32),
+                      cfg.norm_eps) @ head
+        if mtp_targets is None:
+            return logits
+        mp = jax.tree.map(lambda w: w.astype(F32), params["mtp"])
+        m = jnp.concatenate(
+            [_rms(embed[mtp_targets], mp["token_norm"], cfg.norm_eps),
+             _rms(x, mp["hidden_norm"], cfg.norm_eps)], -1) @ mp["eh_proj"]
+        m = _nemotronh_layers(mp, cfg.mtp_types, m, cfg)
+        return logits, _rms(m, mp["final_norm"], cfg.norm_eps) @ head
+
+
+def _token_after(targets):
+    """``targets`` holds token ``t+1`` a position: -> token ``t+2``."""
+    return jnp.roll(targets, -1, 1)
+
+
+def nemotronh_loss(params, batch, cfg, vocab_rows=None, terms=False):
+    """Mean token cross-entropy over the positions ``batch["mask"]``
+    keeps (all without one) plus, where ``cfg.mtp_layers``,
+    ``cfg.mtp_weight`` times the MTP module's against the token after
+    the target, over the positions that have one; no aux term.
+    ``vocab_rows``: the loss over the first that many rows of the
+    vocabulary. ``terms``: -> (the main term, the MTP term unweighted).
+    ``jax.grad`` of this is the reference gradient."""
+    def ce(logits, targets, mask):
+        logp = jax.nn.log_softmax(logits[..., :vocab_rows], -1)
+        nll = -jnp.take_along_axis(logp, targets[..., None], -1)[..., 0]
+        return jnp.sum(nll * mask) / jnp.sum(mask)
+
+    targets = batch["targets"]
+    mask = batch.get("mask", jnp.ones(targets.shape, F32)).astype(F32)
+    if not cfg.mtp_layers:
+        main = ce(nemotronh_forward(params, batch["tokens"], cfg), targets,
+                  mask)
+        return (main, 0.0) if terms else main
+    logits, logits2 = nemotronh_forward(params, batch["tokens"], cfg,
+                                        targets)
+    has_next = (jnp.arange(targets.shape[1]) < targets.shape[1] - 1
+                ).astype(F32)
+    main = ce(logits, targets, mask)
+    mtp = ce(logits2, _token_after(targets), mask * has_next)
+    return (main, mtp) if terms else main + cfg.mtp_weight * mtp

@@ -443,25 +443,49 @@ def held_chunks(n, chunk_rows, chunks):
     return jnp.clip((n + chunk_rows - 1) // chunk_rows, 1, chunks)
 
 
-def _held_chunk(c, R, hf, gate, up, down, w_sorted, order, ends, n, start):
-    """Sorted slots ``start .. start + R - 1`` of a share's layer
-    (:func:`_held_experts_ffn`) -> their part of the sum, [S, D]."""
-    S, K = hf.shape[0], c.n_experts_per_token
+def _expert_matrices(lp, c):
+    """An expert layer's matrices in the order :func:`_experts` takes
+    them: ``(gate, up, down)``, or ``(up, down)`` of a ``relu2`` FFN,
+    which has no gate leaf."""
+    names = ("moe_gate", "moe_up", "moe_down")[c.ffn_act == "relu2":]
+    return tuple(lp[name] for name in names)
+
+
+def _experts(c, x_sorted, matrices, sizes, w):
+    """The experts' FFN on rows in expert order ``x_sorted`` [M, d] with
+    the groups ``sizes``, each row's activation scaled by its gate
+    weight ``w`` [M] -> [M, d]: SwiGLU through three grouped GEMMs, or
+    (``c.ffn_act`` "relu2") ``relu(x Wu)^2 Wd`` through two."""
     dt = c.compute_dtype
+    *gate, up, down = matrices
+    # Residual names for the "moe" remat mode (save the expert-GEMM
+    # chain so backward re-runs NO grouped matmul): the PRE-silu gate
+    # is what silu's vjp needs; up pairs with it for the product rule.
+    if gate:
+        gate_pre = checkpoint_name(
+            _grouped_mm(x_sorted, gate[0].astype(dt), sizes), "moe_gate_act")
+    up = checkpoint_name(
+        _grouped_mm(x_sorted, up.astype(dt), sizes), "moe_up_act")
+    with scope("hvd.moe.experts"):
+        if gate:
+            act = jax.nn.silu(gate_pre) * up * w[:, None]
+        else:
+            act = jnp.square(jax.nn.relu(up)) * w[:, None]
+    return _grouped_mm(act, down.astype(dt), sizes)
+
+
+def _held_chunk(c, R, hf, matrices, w_sorted, order, ends, n, start):
+    """Sorted slots ``start .. start + R - 1`` of a share's layer
+    (:func:`_held_experts_ffn`) -> their part of the sum, [S, d]."""
+    S, K = hf.shape[0], c.n_experts_per_token
     tok = lax.dynamic_slice_in_dim(order, start, R) // K
     held = jnp.clip(n - start, 0, R)
     w = jnp.where(lax.iota(jnp.int32, R) < held,
                   lax.dynamic_slice_in_dim(w_sorted, start, R), 0)
     # each group's rows that fall inside this chunk
     sizes = jnp.diff(jnp.clip(ends, start, start + R), prepend=start)
-    x_sorted = _dispatch_held(hf.astype(dt), tok, held)
-    gate_pre = checkpoint_name(
-        _grouped_mm(x_sorted, gate.astype(dt), sizes), "moe_gate_act")
-    up = checkpoint_name(
-        _grouped_mm(x_sorted, up.astype(dt), sizes), "moe_up_act")
-    with scope("hvd.moe.experts"):
-        act = jax.nn.silu(gate_pre) * up * w[:, None]
-    y_sorted = _grouped_mm(act, down.astype(dt), sizes)
+    x_sorted = _dispatch_held(hf.astype(c.compute_dtype), tok, held)
+    y_sorted = _experts(c, x_sorted, matrices, sizes, w)
     return _combine_held(y_sorted, tok, held, S)
 
 
@@ -527,7 +551,8 @@ def _held_experts_ffn(hf, lp, c, gate_vals, gate_idx):
     """The routed part of an expert layer that holds experts
     ``c.first_expert .. + c.n_experts_held - 1`` of ``c.n_experts``:
     ``sum over the chosen experts held here of w_k Expert_k(h)`` for
-    the tokens ``hf`` [S, D], routed (``gate_vals``, ``gate_idx``
+    the tokens' rows ``hf`` [S, d] (``d`` the width the experts work in:
+    ``c.expert_d_in``), routed (``gate_vals``, ``gate_idx``
     [S, K]) over ALL experts. Dropless at any load: no slot of a held
     expert is left out.
 
@@ -558,7 +583,7 @@ def _held_experts_ffn(hf, lp, c, gate_vals, gate_idx):
     if (S * K) % chunks:
         chunks = 1
     R = S * K // chunks
-    operands = (hf, lp["moe_gate"], lp["moe_up"], lp["moe_down"], w_sorted)
+    operands = (hf, _expert_matrices(lp, c), w_sorted)
     slots = (order, ends, ends[-1])
     y = _held_chunk(c, R, *operands, *slots, 0)
     if chunks == 1:
@@ -566,10 +591,14 @@ def _held_experts_ffn(hf, lp, c, gate_vals, gate_idx):
     return _later_chunks(c, R, y, operands, slots)
 
 
-def grouped_moe_ffn(h, lp, c):
+def grouped_moe_ffn(h, lp, c, rows=None):
     """Dropless top-K routed expert FFN over ``h`` [B, T, D] with the
-    layer params ``lp`` (router [D, E], moe_gate/moe_up [E, D, F],
-    moe_down [E, F, D]). Returns (out [B, T, D], balance statistics) —
+    layer params ``lp`` (router [D, E], moe_gate/moe_up [E, d, F],
+    moe_down [E, F, d]; no moe_gate under ``c.ffn_act`` "relu2"). The
+    router reads ``h``; the experts read ``rows`` [B, T, d] where given
+    (latent experts, ``c.moe_latent``) and ``h`` where not, and the
+    result is as wide as what they read. Returns (out [B, T, d],
+    balance statistics) —
     the same contract, router math, gate normalization
     (``c.norm_topk_prob``) and load-balancing statistics as the GShard
     path (``models/llama.py:_moe_ffn``), with no capacity dropping
@@ -589,6 +618,9 @@ def grouped_moe_ffn(h, lp, c):
     from horovod_tpu.models.llama import route_layer
 
     gate_vals, gate_idx, aux = route_layer(hf, lp, c)          # [S, K]
+    if rows is not None:
+        D = rows.shape[-1]
+        hf = rows.reshape(S, D)
     if c.n_experts_held:
         y = _held_experts_ffn(hf, lp, c, gate_vals, gate_idx)
         return y.reshape(B, T, D), aux
@@ -609,16 +641,6 @@ def grouped_moe_ffn(h, lp, c):
     # saving them.
     x_sorted = _dispatch(hf.astype(dt), tok, inv)
 
-    # Residual names for the "moe" remat mode (save the expert-GEMM
-    # chain so backward re-runs NO grouped matmul): the PRE-silu gate
-    # is what silu's vjp needs; up pairs with it for the product rule.
-    gate_pre = checkpoint_name(
-        _grouped_mm(x_sorted, lp["moe_gate"].astype(dt), group_sizes),
-        "moe_gate_act")
-    up = checkpoint_name(
-        _grouped_mm(x_sorted, lp["moe_up"].astype(dt), group_sizes),
-        "moe_up_act")
-
     # The gate weight of each slot scales its ACTIVATION row (in the
     # compute dtype, in the elementwise pass that exists anyway), not
     # its output row: sum_k w * (a @ Wd) = sum_k (w * a) @ Wd. The
@@ -627,9 +649,7 @@ def grouped_moe_ffn(h, lp, c):
     # out of the silu * up backward pass at width F: nothing is saved
     # in slot order, and the down-projection's output is no residual of
     # anything.
-    with scope("hvd.moe.experts"):
-        act = jax.nn.silu(gate_pre) * up * w_sorted[:, None]
-    y_sorted = _grouped_mm(act, lp["moe_down"].astype(dt),
-                           group_sizes)            # [S*K, D]
+    y_sorted = _experts(c, x_sorted, _expert_matrices(lp, c), group_sizes,
+                        w_sorted)                  # [S*K, D]
     y = _combine(y_sorted, tok, inv)
     return y.reshape(B, T, D), aux

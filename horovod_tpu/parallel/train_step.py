@@ -94,7 +94,12 @@ def holds_two_gradients(params, opt):
     """Whether the device that holds ``params`` has room for a SECOND
     set of gradients beside the state of a step: parameters, optimizer
     state, the gradients being applied, the gradients of the step
-    enqueued behind them, and one gradient's worth of temporaries. Read
+    enqueued behind them, and TWO gradients' worth of temporaries (a
+    grad program's temporaries are not known before it is compiled; the
+    cells' read 1.2 gradients' worth, Mistral's 2.75 GB, to 1.7, 4.67 GB
+    beside 1.38 B parameters of one-part layers, which counted as one
+    filled the chip to 16.89 GB and made every enqueue wait for the
+    apply: PERF.md section 6, PR 50). Read
     off the device's own account (``memory_stats()["bytes_limit"]``); a
     device that gives none (the CPU), or parameters that are being
     traced, hold whatever is asked of them."""
@@ -107,7 +112,7 @@ def holds_two_gradients(params, opt):
     def size(tree):
         return sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(tree))
 
-    return size(params) * 4 + size(opt) <= stats["bytes_limit"]
+    return size(params) * 5 + size(opt) <= stats["bytes_limit"]
 
 
 # Gradient buffers that nothing reads any more, by what they hold (tree

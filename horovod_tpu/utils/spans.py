@@ -29,9 +29,9 @@ SCOPES = frozenset({
     "hvd.embed", "hvd.norm", "hvd.attn.proj", "hvd.attn.rope",
     "hvd.attn.core", "hvd.conv.proj", "hvd.conv.chain", "hvd.gdn.proj",
     "hvd.gdn.chain", "hvd.gdn.core", "hvd.ssm.proj", "hvd.ssm.chain",
-    "hvd.ssm.core", "hvd.ffn",
-    "hvd.moe.route", "hvd.moe.dispatch", "hvd.moe.experts",
-    "hvd.moe.combine", "hvd.head", "hvd.loss", "hvd.apply",
+    "hvd.ssm.core", "hvd.ssd.proj", "hvd.ssd.chain", "hvd.ssd.core",
+    "hvd.ffn", "hvd.moe.route", "hvd.moe.dispatch", "hvd.moe.experts",
+    "hvd.moe.combine", "hvd.moe.latent", "hvd.mtp", "hvd.head", "hvd.loss", "hvd.apply",
     "hvd.allreduce", "hvd.cnn.stem", "hvd.cnn.stage1", "hvd.cnn.stage2",
     "hvd.cnn.stage3", "hvd.cnn.stage4", "hvd.cnn.head"})
 

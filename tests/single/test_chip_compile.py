@@ -249,6 +249,23 @@ _TQ, _TKV = ((2, 8192, 32, 128), BF16), ((2, 8192, 4, 128), BF16)
                  (((20480, 512), BF16), ((32, 512, 2048), BF16),
                   ((32,), I32)),
                  id="megablox-gmm-qwen3next-share-down"),
+    # Nemotron-3-Super's attention at the chip cell's size: 32 heads on
+    # 2 (no group of 16 had run through the training kernels).
+    pytest.param(_flash_fwd_bwd,
+                 (((1, 8192, 32, 128), BF16),)
+                 + (((1, 8192, 2, 128), BF16),) * 2,
+                 id="flash-fwd+bwd-nemotron3super-b1s8192"),
+    # Its share: one chunk of 5,632 sorted slots (no multiple of the
+    # gathers' 2048-row block) into the 8 experts held, rows as wide as
+    # the latent space, expert width 2688 = 21 x 128: two matrices.
+    pytest.param(_gmm,
+                 (((5632, 1024), BF16), ((8, 1024, 2688), BF16),
+                  ((8,), I32)),
+                 id="megablox-gmm-nemotron3super-share-up"),
+    pytest.param(_gmm,
+                 (((5632, 2688), BF16), ((8, 2688, 1024), BF16),
+                  ((8,), I32)),
+                 id="megablox-gmm-nemotron3super-share-down"),
 ])
 def test_kernel_compiles_for_described_v5e(for_tpu, fn, shapes):
     assert "tpu_custom_call" in for_tpu(fn, *shapes)
@@ -451,6 +468,60 @@ def test_thirteen_layers_of_the_scan_lower_each_kernel_once(v5e_chip,
     assert text.count("tpu_custom_call") == 3
     assert text.count("call @_kernel_fwd") >= 25  # the sites are calls
     assert text.count("call @_kernel_bwd") == 13
+
+
+def _ssd_fwd_bwd(x, dt, A, Bm, Cm, D):
+    from horovod_tpu.ops.ssd import ssd
+
+    return jax.grad(lambda *a: ssd(*a).astype(F32).sum(),
+                    argnums=(0, 1, 2, 3, 4, 5))(x, dt, A, Bm, Cm, D)
+
+
+# Nemotron-3-Super's mamba2 mixer at the chip cell's size: B1 T8192, 128
+# heads of 64 channels, 128 states, B / C by 8 groups of 16 heads.
+_SSD = (((1, 8192, 128, 64), BF16), ((1, 8192, 128), F32), ((128,), F32),
+        ((1, 8192, 8, 128), BF16), ((1, 8192, 8, 128), BF16),
+        ((128,), F32))
+_SSD_KERNELS = ("hvd_ssd_fwd", "hvd_ssd_bwd")
+
+
+def test_ssd_compiles_for_described_v5e(for_tpu):
+    """The SSD recurrence at the chip cell's size, forward and backward,
+    as the chip's compiler takes it: the kernel pair, each by the name a
+    device trace shows (``kernel_metadata``), and no ``while`` over
+    tokens or chunks is left; the states kept are the 64 CHUNKS' ([64, 1,
+    8192, 128] float32), and nothing the size of the recurrence
+    materialised ([8192, 128, 64, 128] in any order) exists."""
+    text = for_tpu(_ssd_fwd_bwd, *_SSD)
+    for name in _SSD_KERNELS:
+        assert f'"kernel":"{name}"' in text, name
+    assert " while(" not in text
+    assert "f32[64,1,8192,128]" in text           # the states kept
+    assert not re.search(r"\[(1,)?(8192,128,64,128|8192,8192,128|"
+                         r"128,64,128,8192)\]", text)
+
+
+def test_five_layers_of_the_ssd_lower_each_kernel_once(v5e_chip, for_tpu):
+    """The set-up budget's guard (PERF.md section 6, PR 44's mechanism):
+    behind ONE jitted wrapper the five layers' forward, forward again
+    under remat, and backward lower to one private function a kernel
+    form that every site calls: the backward once, the forward twice
+    (keeping the chunks' states, and not): no more than one layer
+    does."""
+    from horovod_tpu.ops.ssd import ssd
+
+    def loss(x, dt, A, Bm, Cm, D):
+        for _ in range(5):
+            x = jax.checkpoint(ssd)(x, dt, A, Bm, Cm, D)
+        return x.astype(F32).sum()
+
+    args = [jax.ShapeDtypeStruct(s, d, sharding=v5e_chip) for s, d in _SSD]
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4, 5))).lower(
+        *args).as_text()
+    assert [text.count(name) for name in _SSD_KERNELS] == [2, 1]
+    assert text.count("tpu_custom_call") == 3
+    assert text.count("call @_kernel_fwd") >= 9   # the sites are calls
+    assert text.count("call @_kernel_bwd") == 5
 
 
 _CHAIN_KERNELS = ("hvd_gdn_chain_in_fwd", "hvd_gdn_chain_in_bwd",
