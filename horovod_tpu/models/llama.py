@@ -1211,6 +1211,31 @@ def _mamba(x, lp, c, mesh, seq_axis):
         return y @ lp["ssm_out"].astype(dt)
 
 
+def _ssd_chain_in(zxr, taps, bias, di, gn):
+    """The chain's first stage as an expression: ``zxr`` [B, T, 2 di + 2
+    gn + H] = ``[z, X, B, C, r]`` side by side -> the convolution (its
+    bias where there is one) and SiLU over ``[X, B, C]`` in float32,
+    rounded once; ``z`` and ``r`` as they stand. What runs off the TPU,
+    and what ``ops/ssd_chain.py:chain_in`` is held to."""
+    z, xbc, r = jnp.split(zxr, [di, 2 * di + 2 * gn], axis=-1)
+    xbc = jax.nn.silu(_causal_taps(xbc, taps, bias)).astype(zxr.dtype)
+    X, Bm, Cm = jnp.split(xbc, [di, di + gn], axis=-1)
+    return X, Bm, Cm, z, r
+
+
+def _ssd_chain_out(y, z, gain, groups, eps):
+    """The chain's second stage as an expression: the gate FIRST, ``y *
+    SiLU(z)`` in float32, then an RMSNorm over each of ``groups`` runs
+    of channels of ``y``, ``z`` [B, T, di], times the ``gain`` [di]
+    (``ops/ssd_chain.py:chain_out`` on the TPU)."""
+    dt, f32 = y.dtype, jnp.float32
+    b, t, di = y.shape
+    y = y.reshape(b, t, groups, di // groups).astype(f32) \
+        * jax.nn.silu(z.astype(f32)).reshape(b, t, groups, di // groups)
+    return (y * lax.rsqrt(jnp.mean(y * y, -1, keepdims=True) + eps)
+            ).astype(dt).reshape(b, t, di) * gain
+
+
 def _mamba2(x, lp, c, mesh, seq_axis):
     """nemotron_h's Mamba-2 mixer, the token mixer of a ``mamba2`` layer,
     on the residual stream ``x`` [B, T, D] -> what it adds: the layer's
@@ -1222,7 +1247,13 @@ def _mamba2(x, lp, c, mesh, seq_axis):
     over each group's channels; the output projection. Scopes: the two
     matmuls ``hvd.ssd.proj``, the recurrence ``hvd.ssd.core``,
     everything elementwise between them ``hvd.ssd.chain`` (the layer's
-    norm ``hvd.norm``)."""
+    norm ``hvd.norm``). The chain's two stages run as
+    ``ops/ssd_chain.py``'s kernel pairs (``hvd_ssd_chain_in_fwd`` /
+    ``_in_bwd`` / ``_out_fwd`` / ``_out_bwd``, in the matmuls' ``[B, T,
+    columns]``) where the operands live on a TPU and the columns are
+    whole lane slabs, as ``_ssd_chain_in`` / ``_ssd_chain_out``
+    elsewhere."""
+    from horovod_tpu.ops import ssd_chain
     from horovod_tpu.ops.ssd import ssd
 
     if mesh is not None and (
@@ -1237,14 +1268,16 @@ def _mamba2(x, lp, c, mesh, seq_axis):
     b, t, _ = x.shape
     H, G, N = c.ssd_heads, c.ssd_groups, c.ssd_state
     di, gn = c.ssd_d_inner, G * N
+    stage_one, stage_two = (
+        (ssd_chain.chain_in, ssd_chain.chain_out)
+        if ssd_chain.on_kernels(x, di, G, gn)
+        else (_ssd_chain_in, _ssd_chain_out))
     h = _rmsnorm(x, lp["ssd_norm"].astype(dt), c.norm_eps)
     with scope("hvd.ssd.proj"):
         zxr = h @ lp["ssd_in"].astype(dt)
     with scope("hvd.ssd.chain"):
-        z, xbc, r = jnp.split(zxr, [di, 2 * di + 2 * gn], axis=-1)
-        xbc = jax.nn.silu(_causal_taps(xbc, lp["ssd_conv"],
-                                       lp.get("ssd_conv_bias"))).astype(dt)
-        X, Bm, Cm = jnp.split(xbc, [di, di + gn], axis=-1)
+        X, Bm, Cm, z, r = stage_one(zxr, lp["ssd_conv"],
+                                    lp.get("ssd_conv_bias"), di, gn)
         step = jax.nn.softplus(r.astype(f32)
                                + lp["ssd_dt_bias"].astype(f32))
         rates = -jnp.exp(lp["ssd_a_log"].astype(f32))
@@ -1253,10 +1286,8 @@ def _mamba2(x, lp, c, mesh, seq_axis):
                 Bm.reshape(b, t, G, N), Cm.reshape(b, t, G, N),
                 lp["ssd_d"], c.ssd_chunk)
     with scope("hvd.ssd.chain"):
-        y = y.reshape(b, t, G, di // G).astype(f32) \
-            * jax.nn.silu(z.astype(f32)).reshape(b, t, G, di // G)
-        y = (y * lax.rsqrt(jnp.mean(y * y, -1, keepdims=True) + c.norm_eps)
-             ).astype(dt).reshape(b, t, di) * lp["ssd_out_norm"].astype(dt)
+        y = stage_two(y.reshape(b, t, di), z,
+                      lp["ssd_out_norm"].astype(dt), G, c.norm_eps)
     with scope("hvd.ssd.proj"):
         return y @ lp["ssd_out"].astype(dt)
 

@@ -189,11 +189,10 @@ def _fill(ext_ref, x_ref, halo_ref, first):
     ext_ref[h:] = x_ref[...].astype(F32)
 
 
-def _conv_silu(ext_ref, w, i, sub, h, dt):
+def _taps_sum(ext_ref, w, i, sub, h):
     """Pass ``i`` of a tile: (its tokens as they were 0, 1, ... ``taps -
-    1`` tokens ago, the convolution rounded to the compute dtype and
-    read back, its sigmoid). ``_causal_taps``' sum, term by term in its
-    order."""
+    1`` tokens ago, their convolution in float32). ``_causal_taps``'
+    sum, term by term in its order."""
     taps = w.shape[0]
     window = ext_ref[_rows(i, sub, h)]           # the halo, then the pass
     ago = [window[h:]] + [pltpu.roll(window, back, 0)[h:]
@@ -201,8 +200,33 @@ def _conv_silu(ext_ref, w, i, sub, h, dt):
     conv = ago[0] * w[taps - 1:]
     for back in range(1, taps):
         conv = conv + ago[back] * w[taps - 1 - back:taps - back]
+    return ago, conv
+
+
+def _conv_silu(ext_ref, w, i, sub, h, dt):
+    """:func:`_taps_sum` with the convolution rounded to the compute
+    dtype and read back, and its sigmoid."""
+    ago, conv = _taps_sum(ext_ref, w, i, sub, h)
     c = conv.astype(dt).astype(F32)
     return ago, c, _sigmoid(c)
+
+
+def _taps_transpose(dconv, after, ago, w, dx_ref, dw_ref, i, sub):
+    """The convolution's transpose over pass ``i``: from its cotangent
+    ``dconv`` [sub, W] float32 and that of the ``halo`` tokens ``after``
+    the pass, the input's cotangent into ``dx_ref`` (summed once, in
+    float32) and the taps' onto ``dw_ref``; -> the cotangent at the
+    pass's first ``halo`` tokens, which the pass before it reads."""
+    taps, h = w.shape[0], after.shape[0]
+    ext = jnp.concatenate([dconv, after], axis=0)
+    dx = dconv * w[taps - 1:]
+    for back in range(1, taps):
+        dx = dx + pltpu.roll(ext, sub + h - back, 0)[:sub] \
+            * w[taps - 1 - back:taps - back]
+    dx_ref[_rows(i, sub)] = dx.astype(dx_ref.dtype)
+    for back in range(taps):     # tap k met the token so far back
+        dw_ref[taps - 1 - back] += _partial_sums(dconv * ago[back])
+    return ext[:h]
 
 
 def _in_fwd_kernel(*refs, n, d, scale, sub):
@@ -289,7 +313,7 @@ def _in_bwd_kernel(x_ref, halo_ref, w_ref, dq_ref, dk_ref, dv_ref, dz_ref,
     taps' gradient accumulates in ``dw_ref`` over the sequence."""
     c, t = pl.program_id(1), pl.program_id(2)
     bt, _ = dx_ref.shape
-    h, taps, dt = halo_ref.shape[0], w_ref.shape[0], dx_ref.dtype
+    h, dt = halo_ref.shape[0], dx_ref.dtype
 
     def section(d_ref, unit):
         _fill(ext_ref, x_ref, halo_ref, t == pl.num_programs(2) - 1)
@@ -317,15 +341,8 @@ def _in_bwd_kernel(x_ref, halo_ref, w_ref, dq_ref, dk_ref, dv_ref, dz_ref,
                 dy = dy.astype(dt).astype(F32)
             # SiLU, then the cast the convolution's sum went through
             dconv = (dy * _silu_grad(c_, s)).astype(dt).astype(F32)
-            ext = jnp.concatenate([dconv, after], axis=0)
-            dx = dconv * w[taps - 1:]
-            for back in range(1, taps):
-                dx = dx + pltpu.roll(ext, sub + h - back, 0)[:sub] \
-                    * w[taps - 1 - back:taps - back]
-            dx_ref[_rows(i, sub)] = dx.astype(dt)
-            for back in range(taps):     # tap k met the token so far back
-                dw_ref[taps - 1 - back] += _partial_sums(dconv * ago[back])
-            return ext[:h]
+            return _taps_transpose(dconv, after, ago, w, dx_ref, dw_ref, i,
+                                   sub)
 
         next_ref[...] = lax.fori_loop(0, passes, one_pass, next_ref[...])
 

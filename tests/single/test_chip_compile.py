@@ -545,13 +545,42 @@ def _chain_fwd_bwd(qkvz, taps, gain):
     return jax.value_and_grad(loss, argnums=(0, 1, 2))(qkvz, taps, gain)
 
 
-def test_gdn_chain_compiles_for_described_v5e(for_tpu):
-    """The chain's two kernel pairs at the chip cell's size as the
-    chip's compiler takes them (blocks of 256 tokens by 8 heads' lanes, the
-    backward's cotangents parked where a column block has none), each
-    by the name a device trace shows."""
-    text = for_tpu(_chain_fwd_bwd, *_CHAIN)
-    for name in _CHAIN_KERNELS:
+_SSD_CHAIN_KERNELS = ("hvd_ssd_chain_in_fwd", "hvd_ssd_chain_in_bwd",
+                      "hvd_ssd_chain_out_fwd", "hvd_ssd_chain_out_bwd")
+# Nemotron-3-Super's mamba2 mixer at the chip cell's size: B1 T8192,
+# [z 8192, X 8192, B 1024, C 1024, r 128] side by side, four taps and
+# their bias, the gain of 8 groups of 1024 channels.
+_SSD_CHAIN = (((1, 8192, 18560), BF16), ((4, 10240), BF16),
+              ((10240,), BF16), ((8192,), BF16))
+
+
+def _ssd_chain_fwd_bwd(zxr, taps, bias, gain):
+    """The chain round a stand-in for the recurrence (``X`` scaled by
+    the group's ``B C`` and the head's ``r``: every output is read),
+    values and gradients."""
+    from horovod_tpu.ops import ssd_chain
+
+    def loss(zxr, taps, bias, gain):
+        X, Bm, Cm, z, r = ssd_chain.chain_in(zxr, taps, bias, 8192, 1024)
+        y = X * jnp.tile(Bm * Cm, (1, 1, 8)) * jnp.tile(r, (1, 1, 64))
+        return ssd_chain.chain_out(y, z, gain, 8, 1e-5).astype(F32).sum()
+
+    return jax.value_and_grad(loss, argnums=(0, 1, 2, 3))(
+        zxr, taps, bias, gain)
+
+
+@pytest.mark.parametrize("fn, shapes, names", [
+    (_chain_fwd_bwd, _CHAIN, _CHAIN_KERNELS),
+    (_ssd_chain_fwd_bwd, _SSD_CHAIN, _SSD_CHAIN_KERNELS)],
+    ids=["gdn", "ssd"])
+def test_chain_compiles_for_described_v5e(for_tpu, fn, shapes, names):
+    """A mixer's two chain kernel pairs at its chip cell's size as the
+    chip's compiler takes them (blocks of 256 tokens by 1024 lanes, the
+    backward's cotangents parked where a column block has none; the SSD
+    chain's last column block, ``r``'s 128 of 1024 lanes, hangs over the
+    array's edge), each by the name a device trace shows."""
+    text = for_tpu(fn, *shapes)
+    for name in names:
         assert f'"kernel":"{name}"' in text, name
 
 
@@ -601,6 +630,54 @@ def test_three_mixers_lower_each_chain_kernel_once_a_form(v5e_chip,
                            ("_out_fwd", 6), ("_out_bwd", 3)):
         assert len(re.findall(rf"call @{wrapper}(_\d+)?\(", text)) \
             == sites, wrapper
+
+
+def test_five_mamba2_mixers_lower_each_chain_kernel_once_a_form(v5e_chip,
+                                                               for_tpu):
+    """The set-up budget on the ``mamba2`` mixer at the chip cell's
+    size, five layers, a checkpoint a layer as remat "attn" wraps a
+    one-part layer: a Mosaic lowering a kernel FORM whatever the layers,
+    each site a call of its kernel's jitted wrapper. Both stages'
+    forward run ten times (the recurrence and the output projection's
+    gradient read them again) as TWO lowered functions of one text each,
+    the recomputation's with a jaxpr of its own
+    (``test_three_mixers_...``); the backward kernels once. Six
+    lowerings a program beside the recurrence's three, and no float32
+    of tokens x groups x channels."""
+    from horovod_tpu.models import llama
+
+    cfg = llama.LlamaConfig(
+        vocab_size=256, d_model=4096, n_layers=2, n_heads=32, n_kv_heads=2,
+        d_head=128, d_ff=512, norm_eps=1e-5, conv_taps=4,
+        mamba_conv_bias=True, one_part_layers=True,
+        layer_types=("mamba2", "full_attention"), ssd_heads=128,
+        ssd_head_dim=64, ssd_state=128, ssd_groups=8, ssd_chunk=128,
+        dtype="bfloat16", param_dtype="bfloat16", remat="attn")
+    leaves = {"ssd_norm": (4096,), "ssd_in": (4096, 18560),
+              "ssd_conv": (4, 10240), "ssd_conv_bias": (10240,),
+              "ssd_a_log": (128,), "ssd_dt_bias": (128,), "ssd_d": (128,),
+              "ssd_out_norm": (8192,), "ssd_out": (8192, 4096)}
+
+    def loss(x, lp):
+        for _ in range(5):
+            x = x + jax.checkpoint(
+                lambda x, lp: llama._mamba2(x, lp, cfg, None, None))(x, lp)
+        return x.astype(F32).sum()
+
+    def spec(shape):
+        return jax.ShapeDtypeStruct(shape, BF16, sharding=v5e_chip)
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        spec((1, 8192, 4096)), {k: spec(v) for k, v in leaves.items()}
+    ).as_text()
+    assert [text.count(name) for name in _SSD_CHAIN_KERNELS] == [2, 1, 2, 1]
+    assert [text.count(name) for name in _SSD_KERNELS] == [2, 1]
+    assert text.count("tpu_custom_call") == 9
+    for wrapper, sites in (("_in_fwd", 10), ("_in_bwd", 5),
+                           ("_out_fwd", 10), ("_out_bwd", 5)):
+        assert len(re.findall(rf"call @{wrapper}(_\d+)?\(", text)) \
+            == sites, wrapper
+    assert not re.search(r"tensor<1x8192x8x1024xf32>", text)
 
 
 def _seam(norm, theta, d):
