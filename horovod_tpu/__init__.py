@@ -23,6 +23,8 @@ Architecture (see SURVEY.md for the reference analysis):
                          ``horovod/runner/``).
 """
 
+import time as _time
+
 from horovod_tpu.version import __version__  # noqa: F401
 
 
@@ -31,3 +33,9 @@ def run(*args, **kwargs):
     from horovod_tpu.runner import run as _run
 
     return _run(*args, **kwargs)
+
+
+# The start-up mark ``hvd.imported`` (utils/spans.py, which imports jax
+# and so cannot be imported from here): the end of this import, on the
+# monotonic clock.
+_imported_at = _time.monotonic()

@@ -12,7 +12,7 @@ TPU-native path (psum over an ICI mesh inside jit), see
 """
 
 import os
-
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -21,7 +21,7 @@ import numpy as np
 from horovod_tpu.common import eager_ops
 from horovod_tpu.common.eager_ops import ReduceOp
 from horovod_tpu.jax import xla_ici
-from horovod_tpu.utils.spans import span
+from horovod_tpu.utils.spans import mark, span
 
 # Reference-compatible reduce-op aliases (horovod/torch/mpi_ops.py).
 Average = ReduceOp.AVERAGE
@@ -74,12 +74,14 @@ def init(jit_fusion=None):
     fusion (docs/fusion.md): ``False`` restores the unfused split-step
     schedule, ``True`` forces fusion on, ``None`` (default) follows the
     environment."""
+    mark("hvd.init")
     if jit_fusion is not None:
         from horovod_tpu.parallel import fusion as _fusion
 
         _fusion.set_jit_fusion(jit_fusion)
     _elastic_init_mod.init()
-    _maybe_enable_xla_data_plane()
+    mark("hvd.init.core")
+    _maybe_enable_xla_data_plane()   # hvd.init.plane: xla_ici's enable
 
 
 # Elastic reset tears the data plane down with the old topology; try to
@@ -140,16 +142,16 @@ def metrics():
     ``horovod_tpu.telemetry.MetricsScraper``; for per-step MFU/goodput
     accounting see ``horovod_tpu.telemetry.StepTimer``.
     """
-    from horovod_tpu import telemetry
-
-    return telemetry.snapshot()
+    return _basics.metrics_snapshot()
 
 
 def metrics_reset():
     """Zero the metrics registry (tests / interactive use)."""
-    from horovod_tpu import telemetry
-
-    telemetry.metrics_reset()
+    core = sys.modules.get("horovod_tpu.telemetry.core")
+    if core is not None:   # it also forgets who owns the open window
+        core.metrics_reset()
+    else:
+        _basics.metrics_reset()
 
 
 def events(last_n=0):

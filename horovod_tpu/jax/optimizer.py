@@ -16,7 +16,7 @@ import optax
 
 from horovod_tpu.jax import mpi_ops
 from horovod_tpu.jax.compression import Compression
-from horovod_tpu.utils.spans import span
+from horovod_tpu.utils.spans import span, step_begins, step_returns
 
 
 # Step scoping from the eager optimizer (docs/metrics.md "Step
@@ -63,31 +63,38 @@ def allreduce_gradients(grads, op=mpi_ops.Average,
     data plane the fused program reuses the gradients' HBM for the
     results, halving the collective's peak footprint.
     """
-    # The user's thread in two spans (docs/metrics.md "Program spans"):
-    # hvd.enqueue hands every leaf to the core, hvd.wait sleeps until
-    # the device plane has stored the last result. Between them, on the
-    # core's thread, lie its cycle and hvd.device_exec.
-    with span("hvd.enqueue") as s:
-        leaves, treedef = jax.tree.flatten(grads)
-        del grads  # with donate, no live ref may outlast the collective
-        compressed, ctxs = [], []
-        for leaf in leaves:
-            c, ctx = compression.compress(jnp.asarray(leaf))
-            compressed.append(c)
-            ctxs.append(ctx)
-        del leaves
-        if s.is_enabled():
-            s.set_metadata(tensors=len(compressed),
-                           bytes=sum(c.nbytes for c in compressed))
-        names = [f"{prefix}.{i}" for i in range(len(compressed))]
-        handles = mpi_ops._enqueue_grouped_allreduce(
-            compressed, names, op=op, donate=donate)
-        del compressed
-    with span("hvd.wait"):
-        results = [h.synchronize() for h in handles]
-    reduced = [compression.decompress(r, ctx)
-               for r, ctx in zip(results, ctxs)]
-    return jax.tree.unflatten(treedef, reduced)
+    # In the eager lane this call is where the library sees a step begin
+    # and return (the compile log's ``at_step``): the user's own grad
+    # program runs before it, their apply after it.
+    step_begins()
+    try:
+        # The user's thread in two spans (docs/metrics.md "Program spans"):
+        # hvd.enqueue hands every leaf to the core, hvd.wait sleeps until
+        # the device plane has stored the last result. Between them, on the
+        # core's thread, lie its cycle and hvd.device_exec.
+        with span("hvd.enqueue") as s:
+            leaves, treedef = jax.tree.flatten(grads)
+            del grads  # with donate, no live ref may outlast the collective
+            compressed, ctxs = [], []
+            for leaf in leaves:
+                c, ctx = compression.compress(jnp.asarray(leaf))
+                compressed.append(c)
+                ctxs.append(ctx)
+            del leaves
+            if s.is_enabled():
+                s.set_metadata(tensors=len(compressed),
+                               bytes=sum(c.nbytes for c in compressed))
+            names = [f"{prefix}.{i}" for i in range(len(compressed))]
+            handles = mpi_ops._enqueue_grouped_allreduce(
+                compressed, names, op=op, donate=donate)
+            del compressed
+        with span("hvd.wait"):
+            results = [h.synchronize() for h in handles]
+        reduced = [compression.decompress(r, ctx)
+                   for r, ctx in zip(results, ctxs)]
+        return jax.tree.unflatten(treedef, reduced)
+    finally:
+        step_returns()
 
 
 def DistributedGradientTransformation(optimizer, op=mpi_ops.Average,

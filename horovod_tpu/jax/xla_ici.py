@@ -35,7 +35,7 @@ from horovod_tpu.common import eager_ops, process_sets
 from horovod_tpu.common.basics import HorovodBasics
 from horovod_tpu.common.eager_ops import _DTYPE_TO_ENUM, ReduceOp
 from horovod_tpu.common.exceptions import HorovodInternalError
-from horovod_tpu.utils.spans import register_program, scope, span
+from horovod_tpu.utils.spans import mark, register_program, scope, span
 
 _basics = HorovodBasics()
 
@@ -221,6 +221,7 @@ class XlaIciDataPlane:
         _basics.lib.hvdtpu_set_device_callback(
             ctypes.cast(self._cb_ref, ctypes.c_void_p))
         self._active = True
+        mark("hvd.init.plane")
 
     def disable(self):
         if not self._active:

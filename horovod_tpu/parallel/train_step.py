@@ -66,7 +66,13 @@ from typing import Any, NamedTuple
 import jax
 import jax.numpy as jnp
 
-from horovod_tpu.utils.spans import files_itself, scope, span
+from horovod_tpu.utils.spans import (
+    files_itself,
+    scope,
+    span,
+    step_begins,
+    step_returns,
+)
 
 
 class TrainStep(NamedTuple):
@@ -208,10 +214,16 @@ def _wrap_step_telemetry(inner_step, telemetry, flops_programs):
 def _spanned(dispatch):
     """``step`` inside the program span ``hvd.spmd.step``: the host's
     dispatch of one step's programs, on the profiler's clock when a
-    trace is being taken (docs/metrics.md "Program spans")."""
+    trace is being taken (docs/metrics.md "Program spans"), and
+    counted where it begins and where it returns (``spans.steps_begun``:
+    the compile log's ``at_step``)."""
     def step(carry, batch):
-        with span("hvd.spmd.step"):
-            return dispatch(carry, batch)
+        step_begins()
+        try:
+            with span("hvd.spmd.step"):
+                return dispatch(carry, batch)
+        finally:
+            step_returns()
 
     return step
 

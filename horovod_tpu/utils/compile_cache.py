@@ -69,65 +69,166 @@ Who writes what, where (one directory, every process a writer):
 - Past the maximum size jax evicts the entries read longest ago, and
   their ranks' next launch compiles again. Measured sizes: PERF.md.
 
-What the cache did for this process is counted here too
-(:func:`compile_stats`): jax reports every trip through its compile path
-and every cache hit and miss to listeners, and
-:func:`enable_compile_cache` registers this module's, so every entry
-point that switches the cache on has the counter.
+What this process traced, lowered and compiled is logged here too
+(:func:`compile_events`, :func:`compile_stats`; docs/metrics.md "Set-up:
+the compile log and the start-up marks"): jax reports every trip through
+its trace, lowering and compile paths and every cache hit and miss to
+listeners, and :func:`enable_compile_cache` registers this module's, so
+every entry point that switches the cache on has the log.
 """
 
+import collections
 import inspect
 import logging
 import os
 import threading
 import warnings
 
+from horovod_tpu.utils import spans
+
 logger = logging.getLogger(__name__)
 
-_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_PHASES = {"/jax/core/compile/jaxpr_trace_duration": "trace",
+           "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+           "/jax/core/compile/backend_compile_duration": "compile"}
 _RETRIEVAL_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
-_COUNTED = {"/jax/compilation_cache/cache_hits": "cache_hits",
-            "/jax/compilation_cache/cache_misses": "cache_misses"}
+_CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": "hit",
+                 "/jax/compilation_cache/cache_misses": "miss"}
+
+CompileEvent = collections.namedtuple(
+    "CompileEvent", "program phase seconds cache cache_read_s t at_step "
+                    "in_step inner")
+CompileEvent.__doc__ = """One trip of one program through one phase of
+jax's compile path: ``program`` is the jitted function's bare name
+(``hvd_grad``; jax says ``jit(hvd_grad)`` when it lowers and compiles);
+``phase`` is ``trace`` (Python to jaxpr), ``lower`` (jaxpr to MLIR: the
+Mosaic lowerings of the kernels happen here) or ``compile`` (XLA, or the
+read of the persistent cache in its place); ``seconds`` is what jax
+timed. A ``compile`` record says what the persistent ``cache`` did
+(``hit``, ``miss`` = compiled and written, ``none`` = not cacheable,
+too quick to be kept, or the cache is off) and the ``cache_read_s`` of
+its seconds that went into reading and deserialising the entry (0.0
+unless a hit); both are ``None`` in the other phases. ``t`` is when the
+trip began, in seconds since the process began (``spans.since_start``);
+``at_step`` the steps begun by then (``spans.steps_begun()`` when the
+record is written) and ``in_step`` whether the last of them had not
+returned yet. jax also times every ``jit`` traced INSIDE another (each
+kernel's wrapper, each ``jax.numpy`` function: thousands of trips of a
+tenth of a millisecond in one grad program); such a trip is no record,
+its seconds are in the outermost one's already, and ``inner`` counts
+the trips that began and ended inside this one."""
+
+# A process of this repo compiles tens of programs of its own and a
+# hundred eager one-op programs (``convert_element_type``,
+# ``broadcast_in_dim``), which take the same three trips: 125 records in
+# a run of the Mistral cell (PR 52). The records stop here, the totals
+# go on.
+LOG_CAP = 4096
 
 _lock = threading.Lock()   # programs compile on the core's thread too
 _listening = False
-_stats = {"cache_hits": 0, "cache_misses": 0, "programs": 0,
-          "compile_path_s": 0.0, "cache_retrieval_s": 0.0}
+_thread = threading.local()   # trips under way (depth) and ended inside
+#                               the outermost (inner); the cache's word on
+#                               the compile under way (no name on that event)
+_log = []       # the first LOG_CAP CompileEvents
+_dropped = 0    # records past the cap: in the totals, not in the log
+_totals = {}    # program -> what its records add up to, never capped
+_TOTAL_KEYS = ("trace_s", "lower_s", "compile_s", "cache_read_s",
+               "backend_compiles", "cache_hits", "cache_misses")
+
+
+def _on_start(name, _value, **_):
+    if name in _PHASES:   # jax's timer is entered: record_scalar
+        _thread.depth = getattr(_thread, "depth", 0) + 1
 
 
 def _on_event(name, **_):
-    key = _COUNTED.get(name)
-    if key:
-        with _lock:
-            _stats[key] += 1
+    word = _CACHE_EVENTS.get(name)
+    if word:
+        _thread.cache = word
 
 
-def _on_duration(name, seconds, **_):
-    if name == _COMPILE_EVENT:
-        with _lock:
-            _stats["programs"] += 1
-            _stats["compile_path_s"] += seconds
-    elif name == _RETRIEVAL_EVENT:
-        with _lock:
-            _stats["cache_retrieval_s"] += seconds
+def _on_duration(name, seconds, fun_name="", **_):
+    global _dropped
+    if name == _RETRIEVAL_EVENT:
+        _thread.cache_read_s = seconds
+        return
+    phase = _PHASES.get(name)
+    if phase is None:
+        return
+    depth = _thread.depth = max(getattr(_thread, "depth", 1) - 1, 0)
+    if depth:   # inside another trip: its seconds are in that one's
+        _thread.inner = getattr(_thread, "inner", 0) + 1
+        if phase != "compile":
+            return
+        inner = 0
+    else:
+        inner, _thread.inner = getattr(_thread, "inner", 0), 0
+    cache = cache_read_s = None
+    if phase == "compile":
+        cache = getattr(_thread, "cache", "none")
+        cache_read_s = getattr(_thread, "cache_read_s", 0.0)
+        _thread.cache, _thread.cache_read_s = "none", 0.0
+    program = fun_name
+    if program.startswith("jit(") and program.endswith(")"):
+        program = program[4:-1]
+    now, begun = spans.since_start(), spans.steps_begun()
+    event = CompileEvent(
+        program, phase, seconds, cache, cache_read_s,
+        None if now is None else now - seconds, begun,
+        begun > spans.steps_returned(), inner)
+    with _lock:
+        if len(_log) < LOG_CAP:
+            _log.append(event)
+        else:
+            _dropped += 1
+        total = _totals.get(program)
+        if total is None:
+            total = _totals[program] = dict.fromkeys(_TOTAL_KEYS, 0)
+        if phase != "compile":
+            total[phase + "_s"] += seconds
+            return
+        total["compile_s"] += seconds - cache_read_s
+        total["cache_read_s"] += cache_read_s
+        total["backend_compiles"] += cache != "hit"
+        total["cache_hits"] += cache == "hit"
+        total["cache_misses"] += cache == "miss"
+
+
+def compile_events():
+    """The compile log: a :class:`CompileEvent` for each outermost trip
+    of a program through a phase of jax's compile path since
+    :func:`enable_compile_cache`, oldest first, the first
+    :data:`LOG_CAP` of them (``compile_stats()["events_dropped"]`` counts
+    the rest). "Which step recompiled?" is ``[e for e in compile_events()
+    if e.at_step > 0 and e.phase == "compile"]``."""
+    with _lock:
+        return list(_log)
 
 
 def compile_stats():
-    """What this process compiled since :func:`enable_compile_cache`:
+    """What this process compiled since :func:`enable_compile_cache`, as
+    the compile log adds up, the records past its cap included:
     ``cache_hits`` and ``cache_misses`` of the persistent cache (a miss
     is counted when the entry is written, so a program too small or too
     quick to be cached is neither), ``backend_compiles`` (programs that
     went through jax's compile path and were not fetched from the
     cache) and ``compile_s``, the seconds that took (time in the
     compile path less time spent reading the cache:
-    ``cache_retrieval_s``). All zero before the cache is switched on."""
+    ``cache_retrieval_s``); ``trace_s`` and ``lower_s``, the seconds in
+    jax's trace and lowering paths (a trip inside another is in the
+    outer one's seconds, and counted there); ``by_program``, the same
+    sums for each program (``cache_read_s`` there); ``events_dropped``.
+    A compile that an eager operation starts while an outer function is
+    being traced is in ``compile_s`` and in that ``trace_s``. All zero
+    before the cache is switched on."""
     with _lock:
-        s = dict(_stats)
-    return {"cache_hits": s["cache_hits"],
-            "cache_misses": s["cache_misses"],
-            "backend_compiles": s["programs"] - s["cache_hits"],
-            "compile_s": s["compile_path_s"] - s["cache_retrieval_s"],
-            "cache_retrieval_s": s["cache_retrieval_s"]}
+        by_program = {p: dict(total) for p, total in _totals.items()}
+        dropped = _dropped
+    stats = {key: sum(total[key] for total in by_program.values())
+             for key in _TOTAL_KEYS}
+    stats["cache_retrieval_s"] = stats.pop("cache_read_s")
+    return dict(stats, by_program=by_program, events_dropped=dropped)
 
 
 def _let_every_rank_write():
@@ -192,8 +293,9 @@ CHECKOUT_CACHE_DIR = os.path.join(
 
 
 def enable_compile_cache():
-    """Turn the persistent compilation cache on, and the counters of
-    :func:`compile_stats` with it; returns the cache's directory.
+    """Turn the persistent compilation cache on, and the compile log
+    (:func:`compile_events`, :func:`compile_stats`) with it; returns the
+    cache's directory.
 
     Must run before the process's first compile (the cache is
     initialized once, at first use).
@@ -204,6 +306,7 @@ def enable_compile_cache():
     with _lock:
         listen, _listening = not _listening, True
     if listen:
+        jax.monitoring.register_scalar_listener(_on_start)
         jax.monitoring.register_event_listener(_on_event)
         jax.monitoring.register_event_duration_secs_listener(_on_duration)
         _let_every_rank_write()
@@ -213,7 +316,7 @@ def enable_compile_cache():
     jax.config.update("jax_traceback_in_locations_limit", 0)
     jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
-    if placed:
-        return placed
-    jax.config.update("jax_compilation_cache_dir", CHECKOUT_CACHE_DIR)
-    return CHECKOUT_CACHE_DIR
+    if not placed:
+        jax.config.update("jax_compilation_cache_dir", CHECKOUT_CACHE_DIR)
+    spans.mark("hvd.cache.enabled")
+    return placed or CHECKOUT_CACHE_DIR

@@ -1,8 +1,12 @@
 """What the program itself writes into the profiler's trace
 (docs/metrics.md "Program spans" and "Device scopes"): host spans, one
 per layer boundary of the host's path, and device scopes, one per layer
-boundary of the compiled programs; never one per tensor. A leaf module:
-it imports ``jax`` and nothing of this package, so the lowest layers can
+boundary of the compiled programs; never one per tensor. Beside them,
+what the program keeps about its own start-up whether or not a trace is
+taken (docs/metrics.md "Set-up: the compile log and the start-up
+marks"): the marks, one clock read each, once a process, and the count
+of steps begun and returned. A leaf module: it imports ``jax`` and of
+this package only the moment its import ended, so the lowest layers can
 open a span or a scope without pulling in ``horovod_tpu.telemetry``.
 
 A host span is an interval on the profiler's clock. A device scope is
@@ -16,10 +20,14 @@ that :func:`scope_tables` can hand out the table of each by module name.
 """
 
 import collections
+import os
 import re
+import time
 
 import jax
 from jax.profiler import TraceAnnotation
+
+from horovod_tpu import _imported_at
 
 SPANS = frozenset({"hvd.enqueue", "hvd.device_exec", "hvd.wait",
                    "hvd.spmd.step"})
@@ -36,6 +44,12 @@ SCOPES = frozenset({
     "hvd.cnn.stage3", "hvd.cnn.stage4", "hvd.cnn.head"})
 
 PHASES = ("forward", "recomputed", "backward")
+
+# Which line stamps each: docs/metrics.md "Set-up: the compile log and
+# the start-up marks".
+MARKS = frozenset({
+    "hvd.imported", "hvd.cache.enabled", "hvd.init", "hvd.init.core",
+    "hvd.init.plane", "hvd.step.first", "hvd.step.first_dispatched"})
 
 
 def span(name, **carries):
@@ -64,6 +78,85 @@ def scope(name):
         raise ValueError(f"{name!r} is not a device scope: "
                          f"{sorted(SCOPES)}")
     return jax.named_scope(name)
+
+
+# ---------------------------------------------------------------------
+# Start-up marks and the step count
+
+
+def _process_began():
+    """``time.monotonic()`` at the moment this process began, by the
+    kernel's account: its start in clock ticks since boot
+    (``/proc/self/stat``, field 22) against the seconds since boot
+    (``/proc/uptime``), both to 10 ms. ``None`` where there is no
+    ``/proc``."""
+    try:
+        with open("/proc/self/stat") as f:
+            # (the command, field 2, may hold spaces and parentheses)
+            ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        with open("/proc/uptime") as f:
+            up = float(f.read().split()[0])
+        return time.monotonic() - (up - ticks / os.sysconf("SC_CLK_TCK"))
+    except (OSError, ValueError, IndexError):
+        return None
+
+
+_BEGAN = _process_began()
+_marks = {}
+_begun = _returned = 0   # steps; written on the user's thread alone
+
+
+def since_start(now=None):
+    """Seconds since the process began (``None`` where there is no
+    ``/proc``): the clock of the marks and of the compile log's ``t``."""
+    if _BEGAN is None:
+        return None
+    return (time.monotonic() if now is None else now) - _BEGAN
+
+
+def mark(name):
+    """Stamp the start-up mark ``name`` of :data:`MARKS` with
+    :func:`since_start`, the first time it is reached and never again: a
+    clock read and a dict store once a process, the same with a trace
+    running."""
+    if name not in MARKS:
+        raise ValueError(f"{name!r} is not a start-up mark: {sorted(MARKS)}")
+    if name not in _marks:
+        _marks[name] = since_start()
+
+
+def marks():
+    """``{name: seconds since the process began}`` of the marks reached
+    so far, in the order they were reached."""
+    return dict(_marks)
+
+
+def step_begins():
+    """The dispatch of a step begins (``_spanned``,
+    ``allreduce_gradients``): one integer add, no lock."""
+    global _begun
+    if not _begun:
+        mark("hvd.step.first")
+    _begun += 1
+
+
+def step_returns():
+    """The dispatch of that step has returned."""
+    global _returned
+    _returned += 1
+    if _returned == 1:
+        mark("hvd.step.first_dispatched")
+
+
+def steps_begun():
+    return _begun
+
+
+def steps_returned():
+    return _returned
+
+
+_marks["hvd.imported"] = since_start(_imported_at)
 
 
 # ---------------------------------------------------------------------

@@ -48,10 +48,13 @@ def _worker_traced(rank, size):
     from horovod_tpu.jax import xla_ici
     from horovod_tpu.jax.optimizer import allreduce_gradients
     from horovod_tpu.parallel import make_split_train_step
+    from horovod_tpu.utils import spans
 
+    steps = [(spans.steps_begun(), spans.steps_returned())]
     hvd.init()
     try:
         assert xla_ici.active()
+        marks = [spans.marks()]
         tree = {f"w{i}": jnp.full((8, i + 1), float(i), jnp.float32)
                 for i in range(LEAVES)}
         ts = make_split_train_step(
@@ -67,7 +70,11 @@ def _worker_traced(rank, size):
                                         batch)
             return [np.asarray(loss), np.asarray(params["w"])]
 
-        untraced = eager() + spmd()         # also warms every program
+        untraced = eager()                  # also warms every program
+        steps.append((spans.steps_begun(), spans.steps_returned()))
+        marks.append(spans.marks())
+        untraced += spmd()
+        steps.append((spans.steps_begun(), spans.steps_returned()))
         d_eager, d_spmd = tempfile.mkdtemp(), tempfile.mkdtemp()
         jax.profiler.start_trace(d_eager)
         traced = eager()
@@ -78,9 +85,12 @@ def _worker_traced(rank, size):
         bit_equal = all(np.array_equal(a, b) and np.array_equal(a, c)
                         for a, b, c in zip(untraced, traced,
                                            eager() + spmd()))
+        steps.append((spans.steps_begun(), spans.steps_returned()))
+        marks.append(spans.marks())
         return {"eager": _hvd_events(d_eager),
                 "spmd": _hvd_events(d_spmd), "bit_equal": bit_equal,
-                "bytes": sum(v.nbytes for v in tree.values())}
+                "bytes": sum(v.nbytes for v in tree.values()),
+                "steps": steps, "marks": marks}
     finally:
         hvd.shutdown()
 
@@ -142,6 +152,61 @@ def test_with_no_trace_the_helper_records_nothing():
         span("hvd.anything")
 
 
+def test_start_up_marks_are_stamped_once_in_the_order_reached(traced):
+    """docs/metrics.md "Set-up: the compile log and the start-up
+    marks": the worker reaches every mark but ``hvd.cache.enabled``
+    (tests keep the compile cache off)."""
+    from horovod_tpu.utils.spans import MARKS
+
+    at_init, after_a_step, at_the_end = traced["marks"]
+    order = ["hvd.imported", "hvd.init", "hvd.init.core", "hvd.init.plane",
+             "hvd.step.first", "hvd.step.first_dispatched"]
+    assert list(at_init) == order[:4]
+    assert list(after_a_step) == list(at_the_end) == order
+    assert set(order) | {"hvd.cache.enabled"} == MARKS
+    seconds = [at_the_end[name] for name in order]
+    assert seconds == sorted(seconds) and seconds[0] > 0
+    # five more steps and two traces later every mark reads as it did
+    assert after_a_step == at_the_end
+    assert {k: at_the_end[k] for k in at_init} == at_init
+
+
+def test_marks_are_a_closed_table(monkeypatch):
+    from horovod_tpu.utils import spans
+
+    with pytest.raises(ValueError, match="not a start-up mark"):
+        spans.mark("hvd.anything")
+    monkeypatch.setattr(spans, "_marks", {})
+    spans.mark("hvd.init")
+    first = spans.marks()
+    spans.mark("hvd.init")                 # reached again: not stamped
+    assert spans.marks() == first and list(first) == ["hvd.init"]
+    assert 0 < first["hvd.init"] <= spans.since_start()
+
+
+def test_each_lane_counts_a_step_where_it_begins_and_returns(traced):
+    """One ``allreduce_gradients`` or one split step is one step begun
+    and one returned: the worker makes three of each."""
+    assert traced["steps"] == [(0, 0), (1, 1), (2, 2), (6, 6)]
+
+
+def test_a_step_under_way_is_begun_and_not_returned():
+    from horovod_tpu.parallel import train_step
+    from horovod_tpu.utils import spans
+
+    def under_way(carry, batch):
+        if batch is None:
+            raise RuntimeError("a step that fails")
+        return spans.steps_begun() - spans.steps_returned()
+
+    step = train_step._spanned(under_way)
+    begun = spans.steps_begun()
+    assert step(None, 0) == 1
+    with pytest.raises(RuntimeError):
+        step(None, None)                   # a failed step has returned
+    assert spans.steps_begun() == spans.steps_returned() == begun + 2
+
+
 @pytest.mark.parametrize("module", [
     "horovod_tpu.utils.spans", "horovod_tpu.utils.compile_cache",
     "horovod_tpu.parallel.train_step", "horovod_tpu.jax.xla_ici"])
@@ -197,8 +262,11 @@ def test_a_step_makes_no_more_python_calls_for_filing_its_programs(
     # grad and apply; with microbatches the accumulating grad too
     assert first.count("first") == programs
     assert len(spans._PROGRAMS) == programs
-    steady = ["step", "span", "step"] + (
-        ["_split_microbatches", "<lambda>"] if microbatches > 1 else [])
+    # (the two counts of a step, spans.step_begins and step_returns,
+    # are the whole of what PR 52 put on this path)
+    steady = ["step", "step_begins", "span", "step"] + (
+        ["_split_microbatches", "<lambda>"] if microbatches > 1 else []
+    ) + ["step_returns"]
     assert sorted(second) == sorted(third)
     assert [c for c in second if c not in ("<lambda>",)] \
         == [c for c in steady if c != "<lambda>"]
