@@ -1292,10 +1292,50 @@ def _mamba2(x, lp, c, mesh, seq_axis):
         return y @ lp["ssd_out"].astype(dt)
 
 
+def _slot_holds(idx, n_experts):
+    """``[..., K, E]`` bool: slot k of a token holds expert e. A pick by
+    ``idx`` is a select on it under a sum, which XLA fuses into one
+    reduction: nothing ``[..., K, E]`` long reaches HBM. A token's K
+    indices are distinct, so every sum has at most ONE term that is not
+    zero and is exact in float32, what a gather and a scatter of scalars
+    give at 7-19 ns an element on the chip (PERF.md section 6, PR 54)."""
+    return idx[..., None] == lax.iota(idx.dtype, n_experts)
+
+
+def _unpick(idx, g, n_experts):
+    """The transpose of :func:`_pick`: ``g [..., K]`` put back at
+    ``idx`` in ``[..., E]``, zero where no slot chose."""
+    return jnp.where(_slot_holds(idx, n_experts), g[..., None], 0).sum(-2)
+
+
+@jax.custom_vjp
+def _pick(probs, idx):
+    """``probs [..., E]`` at ``idx [..., K]`` (distinct along K), equal
+    to ``jnp.take_along_axis(probs, idx, -1)`` and its VJP's scatter bit
+    for bit, as dense compare-and-select passes (:func:`_slot_holds`).
+    The VJP keeps ``idx`` and nothing of ``probs``' size."""
+    return jnp.where(_slot_holds(idx, probs.shape[-1]),
+                     probs[..., None, :], 0).sum(-1)
+
+
+def _pick_fwd(probs, idx):
+    return _pick(probs, idx), (idx, jnp.zeros((0, probs.shape[-1]),
+                                               probs.dtype))
+
+
+def _pick_bwd(res, g):
+    idx, like = res                  # ``like``: the width E, no data
+    return _unpick(idx, g, like.shape[-1]), None
+
+
+_pick.defvjp(_pick_fwd, _pick_bwd)
+
+
 @partial(jax.custom_vjp, nondiff_argnums=(1,))
 def _top_k(probs, k):
-    """``lax.top_k`` over the last axis whose VJP scatters by the
-    indices the FORWARD chose, named ``moe_gate_idx``: every remat mode
+    """``lax.top_k`` over the last axis whose VJP selects by the
+    indices the FORWARD chose (:func:`_unpick`), named ``moe_gate_idx``:
+    every remat mode
     that saves the sorted order (``_MOE_SAVE``) saves them with it.
     ``lax.top_k``'s own VJP reads the indices of a RECOMPUTED top-k. A
     recomputation that rounds two of a token's probabilities the other
@@ -1317,12 +1357,8 @@ def _top_k_fwd(probs, k):
 
 
 def _top_k_bwd(k, res, g):
-    idx, like = res                  # ``like``: the width E, no data
-    chosen = jax.linear_transpose(
-        lambda p: jnp.take_along_axis(p, idx, axis=-1,
-                                      mode="promise_in_bounds"),
-        jax.ShapeDtypeStruct(idx.shape[:-1] + like.shape[1:], like.dtype))
-    return chosen(g[0])
+    idx, like = res
+    return (_unpick(idx, g[0], like.shape[-1]),)
 
 
 _top_k.defvjp(_top_k_fwd, _top_k_bwd)
@@ -1331,10 +1367,11 @@ _top_k.defvjp(_top_k_fwd, _top_k_bwd)
 @scope("hvd.moe.route")
 def moe_route(h, router_w, n_experts_per_token, norm_topk_prob=True,
               score_func="softmax", bias=None, route_scale=1.0):
-    """The ONE router: f32 logits matmul, softmax, top-K, the K
-    probabilities renormalised (epsilon-guarded) where ``norm_topk_prob``
-    and as the softmax gave them where not, and the load-balancing
-    statistics of these tokens. ``score_func="sigmoid"`` scores each
+    """The ONE router: f32 logits matmul, softmax, top-K, the pick of
+    the K chosen probabilities (dense: :func:`_pick`), renormalised
+    (epsilon-guarded) where ``norm_topk_prob`` and as the softmax gave
+    them where not, and the load-balancing statistics of these tokens.
+    ``score_func="sigmoid"`` scores each
     expert by the sigmoid of its logit instead; a ``bias`` [E] (float32,
     data: no gradient) is added to the scores for the CHOICE of the K
     experts only, their weights are the scores themselves;
@@ -1363,7 +1400,7 @@ def moe_route(h, router_w, n_experts_per_token, norm_topk_prob=True,
             probs + lax.stop_gradient(bias.astype(jnp.float32)),
             n_experts_per_token)
         gate_idx = checkpoint_name(gate_idx, "moe_gate_idx")
-        gate_vals = jnp.take_along_axis(probs, gate_idx, axis=-1)
+        gate_vals = _pick(probs, gate_idx)
     if norm_topk_prob:
         gate_vals = gate_vals / jnp.maximum(
             gate_vals.sum(-1, keepdims=True), 1e-9)

@@ -457,7 +457,10 @@ def test_a_layer_pattern_has_no_pipeline_schedule():
 # scan, a layer body a layer (the printed jaxpr shares equal
 # sub-programs, so its counts are not twice a body's), and a loss that
 # differs from the scan's in the fourth digit at bf16 compute (in
-# float32 the two agree to 1e-6: test_expert_stack_unrolled.py).
+# float32 the two agree to 1e-6: test_expert_stack_unrolled.py), and at
+# PR 54, which took ``_top_k``'s scatter-add out of the router on
+# purpose (a select under a sum, ``models/llama.py:_unpick``: one
+# scatter-add fewer, the loss the same to the last digit).
 _OLD = {
     "dense": (LlamaConfig.tiny(),
               ["attn_norm", "mlp_norm", "w_down", "w_gate", "w_up", "wk",
@@ -472,7 +475,7 @@ _OLD = {
                "moe_up", "q_norm", "router", "wk", "wo", "wq", "wv"],
               6.124673366546631,
               {"scan": 0, "cond": 0, "sort": 3, "gather": 14,
-               "scatter-add": 3, "custom_vjp_call": 10, "dot_general": 99,
+               "scatter-add": 2, "custom_vjp_call": 10, "dot_general": 99,
                "top_k": 2}),
 }
 

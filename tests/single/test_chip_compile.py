@@ -13,6 +13,7 @@
 """
 
 import functools
+import math
 import os
 import re
 import subprocess
@@ -868,6 +869,41 @@ def test_routed_rows_move_through_bare_gathers_on_the_v5e(for_tpu):
     count = lambda op: len(re.findall(rf" {op}\(", text))   # noqa: E731
     assert (count("gather"), count("scatter"), count("sort")) == (5, 0, 2)
     assert not re.search(r"= bf16\[65536,2048\]\S* select\(", text)
+
+
+@pytest.mark.parametrize("S, D, E, K, score, biased", [
+    pytest.param(8192, 4096, 512, 22, "sigmoid", True, id="nemotron"),
+    pytest.param(16384, 2048, 512, 10, "softmax", False, id="qwen3next"),
+])
+def test_the_router_picks_its_scores_with_no_gather_on_the_v5e(
+        for_tpu, S, D, E, K, score, biased):
+    """``moe_route``, forward + backward, at the two cells' shapes where
+    the router weighs most, as the chip's compiler emits it (PR 54): the
+    pick of the K chosen scores and its transpose are selects under a
+    sum inside fusions (``models/llama.py:_pick``), so no gather, no
+    scatter, and nothing ``[tokens, K, E]`` long between two fusions."""
+    from horovod_tpu.models.llama import moe_route
+
+    def route(h, w, bias, weights, tilt):
+        def f(h, w):
+            vals, idx, balance = moe_route(
+                h, w, K, True, score, bias if biased else None, 5.0)
+            return ((vals * weights).sum() + (balance * tilt).sum(),
+                    idx)
+
+        return jax.value_and_grad(f, (0, 1), has_aux=True)(h, w)
+
+    text = for_tpu(route, ((1, S, D), BF16), ((D, E), F32), ((E,), F32),
+                   ((1, S, K), F32), ((2, E), F32))
+    count = lambda op: len(re.findall(rf" {op}\(", text))   # noqa: E731
+    assert (count("gather"), count("scatter")) == (0, 0)
+    fused = True                 # the computation a line stands in
+    for line in text.splitlines():
+        if line and not line[0].isspace():
+            fused = "fused_computation" in line.split("(")[0]
+        for dims in re.findall(r"= \(?\w+\[([\d,]+)\]", line):
+            n = math.prod(map(int, dims.split(",")))
+            assert fused or n < S * K * E, line
 
 
 def test_a_shares_gather_buffer_is_born_in_a_branch_and_never_zero_filled(

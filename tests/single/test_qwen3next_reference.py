@@ -344,7 +344,10 @@ def test_decode_serving_and_the_pipeline_refuse_the_new_fields(field):
 # at the parent commit (599fcd4) by this very code, to the last byte.
 # The two share models were read again at PR 41, which changed them on
 # purpose (two chunks a layer here: the second is a loop that follows
-# the rows held, ``grouped_moe._later_chunks``).
+# the rows held, ``grouped_moe._later_chunks``). The three with a router
+# were read again at PR 54, which changed them on purpose (the pick of
+# the K chosen scores and its transpose as selects under a sum,
+# ``models/llama.py:_pick``; "dense" stands as it was read).
 S, C = "sliding_attention", "conv"
 _TRINITY = dict(vocab_size=128, d_model=64, n_layers=5, n_heads=4,
                 n_kv_heads=2, d_head=32, d_ff=96, moe_d_ff=32,
@@ -370,9 +373,9 @@ _BEFORE = {
     "olmoe": (LlamaConfig.tiny(n_experts=8, n_experts_per_token=3,
                                qk_norm=True, norm_topk_prob=False,
                                moe_impl="grouped", remat="attn+moe"),
-              "06e01e0f4a8f2cd5", 233543),
-    "trinity": (LlamaConfig(**_TRINITY), "e2bcf510a72c62ed", 1015045),
-    "lfm2": (LlamaConfig(**_LFM2), "7715d55e3f3bc380", 1232205),
+              "19d57556ecb10f1a", 234792),
+    "trinity": (LlamaConfig(**_TRINITY), "8161ae6d2fb7892f", 1018007),
+    "lfm2": (LlamaConfig(**_LFM2), "20551dd593a4c1e1", 1246731),
 }
 
 
