@@ -27,6 +27,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
@@ -298,6 +299,48 @@ class LlamaConfig:
     mtp_layers: int = 0
     mtp_types: tuple = ()
     mtp_weight: float = 0.0
+    # muP's three scalings as MiniCPM publishes them (``scale_emb``,
+    # ``scale_depth / sqrt(num_hidden_layers)``, ``hidden_size /
+    # dim_model_base``), numbers of the model and no switches: the
+    # embedding times ``embed_mult`` (0: ``scale_embed`` decides), what
+    # a mixer or an FFN adds to the stream times ``residual_mult``, the
+    # final norm's output over ``logit_div`` in front of the head.
+    embed_mult: float = 0.0
+    residual_mult: float = 1.0
+    logit_div: float = 1.0
+    # Tokens a block of the dense SwiGLU (``_ffn``): the three [tokens,
+    # d_ff] activations exist a block at a time under a checkpoint,
+    # forward and backward, as ``loss_chunk``'s logits. 0: whole.
+    ffn_chunk: int = 0
+    # A ``sparse_attention`` layer (minicpm_sala's ``minicpm4`` mixer:
+    # InfLLM-V2; ``ops/sparse_attention.py`` has the five steps): the
+    # attention layer's leaves and projections (``qk_norm``,
+    # ``attn_gate`` as set; no RoPE), each token attending at most
+    # ``sparse_topk`` blocks of ``sparse_block`` keys a key/value group,
+    # chosen by scores against keys pooled ``sparse_kernel`` wide every
+    # ``sparse_stride``, the first ``sparse_init_blocks`` and the last
+    # ``sparse_window_blocks`` begun blocks always among them. A
+    # sequence of up to ``sparse_dense_len`` tokens runs the layer DENSE
+    # (the published ``dense_len``): plain causal attention.
+    sparse_block: int = 0
+    sparse_topk: int = 0
+    sparse_kernel: int = 0
+    sparse_stride: int = 0
+    sparse_init_blocks: int = 0
+    sparse_window_blocks: int = 0
+    sparse_dense_len: int = 0
+    # A ``lightning_attention`` layer (minicpm_sala's ``lightning-attn``
+    # mixer, ``_lightning``): ``lightning_heads`` heads of
+    # ``lightning_head_dim`` with a key and a value head each, a state
+    # a head that decays by a constant of the layer and head
+    # (``lightning_rates``: ALiBi's slopes times a factor that falls
+    # with the layer's place among ``lightning_depth`` PUBLISHED layers,
+    # 0 = ``n_layers``), the recurrence in chunks of ``lightning_chunk``
+    # tokens (``ops/ssd.py``).
+    lightning_heads: int = 0
+    lightning_head_dim: int = 0
+    lightning_chunk: int = 0
+    lightning_depth: int = 0
 
     def __post_init__(self):
         if self.layer_types and len(self.layer_types) != self.n_layers:
@@ -308,10 +351,34 @@ class LlamaConfig:
         one_part = ("mamba2", "full_attention", "experts")
         if any(t not in (one_part if self.one_part_layers else (
                 "sliding_attention", "full_attention", "conv",
-                "linear_attention", "mamba")) for t in types):
+                "linear_attention", "mamba", "sparse_attention",
+                "lightning_attention")) for t in types):
             raise ValueError(
                 f"unknown layer type in {types}: with one_part_layers "
                 f"{one_part}, without it no 'mamba2' and no 'experts'")
+        sizes = (self.sparse_block, self.sparse_topk, self.sparse_kernel,
+                 self.sparse_stride)
+        if ("sparse_attention" in types) != all(sizes) or (
+                "sparse_attention" not in types and any(
+                    sizes + (self.sparse_init_blocks,
+                             self.sparse_window_blocks,
+                             self.sparse_dense_len))):
+            raise ValueError(
+                "sparse_attention layers and the selection's sizes "
+                "(sparse_block, sparse_topk, sparse_kernel, "
+                f"sparse_stride) come together: {sizes}")
+        sizes = (self.lightning_heads, self.lightning_head_dim,
+                 self.lightning_chunk)
+        if ("lightning_attention" in types) != all(sizes) or (
+                "lightning_attention" not in types
+                and any(sizes + (self.lightning_depth,))):
+            raise ValueError(
+                "lightning_attention layers and their sizes "
+                "(lightning_heads, lightning_head_dim, lightning_chunk) "
+                f"come together: {sizes}")
+        if self.ffn_chunk < 0:
+            raise ValueError(f"ffn_chunk {self.ffn_chunk}: tokens a block "
+                             "of the dense FFN, 0 for whole")
         linear = "linear_attention" in types
         mamba, mamba2 = "mamba" in types, "mamba2" in types
         if ("conv" in types or linear or mamba or mamba2) \
@@ -459,7 +526,10 @@ class LlamaConfig:
         ``mamba_layers`` (a Mamba layer: the ``ssm_*`` leaves; a stack
         a RUN of consecutive mamba layers, the second run's
         ``mamba_1_layers`` and so on, so that each run is a whole stack
-        and ``_run_layers`` can scan it), and the leading dense layers
+        and ``_run_layers`` can scan it), ``sparse_layers`` (attention's
+        leaves, read by the block-sparse mixer), ``lightning_layers``
+        (``wq`` .. ``wo`` at the lightning heads' width, ``out_norm``,
+        ``wg``), and the leading dense layers
         of a sparse-expert model apart as
         ``dense_layers`` / ``dense_conv_layers``. Under
         ``one_part_layers`` a layer is a mixer alone (``dense_ffn``
@@ -477,6 +547,8 @@ class LlamaConfig:
             sliding = kind == "sliding_attention"
             mixer = {"conv": "conv", "linear_attention": "linear",
                      "mamba": "mamba", "mamba2": "mamba2",
+                     "sparse_attention": "sparse",
+                     "lightning_attention": "lightning",
                      "experts": None}.get(kind, "attention")
             dense_ffn = self.n_experts == 0 or i < self.n_dense_layers
             stack = ("dense_" if i < self.n_dense_layers else "") \
@@ -496,6 +568,21 @@ class LlamaConfig:
                     sliding or not types or self.rope_full_attention)))
             filled[stack] += 1
         return plan
+
+    def lightning_rates(self, stack):
+        """The decay rates of the lightning layers of ``stack``, [its
+        layers, heads] float32, negative: ``-s_n f_l`` with ``s_n = 2^(-8
+        (n + 1) / H)`` (Lightning Attention's slopes, arXiv:2401.04658)
+        and ``f_l = 1 - l / (L - 1) + 1e-5`` (MiniMax-01's layer factor,
+        arXiv:2501.08313), ``l`` the layer's place in ``layer_types`` and
+        ``L`` the PUBLISHED depth. Constants: no leaf holds them and no
+        gradient reaches them."""
+        at = np.array([i for i, spec in enumerate(self.layer_plan())
+                       if spec.stack == stack], np.float64)
+        H, L = self.lightning_heads, self.lightning_depth or self.n_layers
+        slopes = 2.0 ** (-8.0 * (np.arange(H) + 1) / H)
+        return -np.outer(1.0 - at / max(L - 1, 1) + 1e-5, slopes).astype(
+            np.float32)
 
     def layer_kinds(self):
         """One ``(dense_ffn, window, rope)`` a layer, in order: what
@@ -526,7 +613,14 @@ class LlamaConfig:
                             "one_part_layers", "ssd_heads", "ssd_head_dim",
                             "ssd_state", "ssd_groups", "ssd_chunk",
                             "ffn_act", "moe_latent", "shared_d_ff",
-                            "mtp_layers", "mtp_types", "mtp_weight")
+                            "mtp_layers", "mtp_types", "mtp_weight",
+                            "embed_mult", "residual_mult", "logit_div",
+                            "ffn_chunk", "sparse_block", "sparse_topk",
+                            "sparse_kernel", "sparse_stride",
+                            "sparse_init_blocks", "sparse_window_blocks",
+                            "sparse_dense_len", "lightning_heads",
+                            "lightning_head_dim", "lightning_chunk",
+                            "lightning_depth")
                 if getattr(self, f) != getattr(d, f)] \
             + (["qk_norm"] if self.qk_norm == "head" else [])
 
@@ -684,6 +778,24 @@ def llama_init(config, key):
             }
             if c.mamba_conv_bias:
                 layers["ssm_conv_bias"] = jnp.zeros((L, di), pd)
+        elif mixer == "lightning":
+            # attention's names at the lightning heads' width: a key and
+            # a value head a query head, one q/k gain for all heads, the
+            # output norm over the concatenation, the output gate.
+            ld, hw = c.lightning_head_dim, \
+                c.lightning_heads * c.lightning_head_dim
+            layers = {
+                "attn_norm": jnp.ones((L, c.d_model), pd),
+                "wq": dense(next(k), (L, c.d_model, hw), c.d_model),
+                "wk": dense(next(k), (L, c.d_model, hw), c.d_model),
+                "wv": dense(next(k), (L, c.d_model, hw), c.d_model),
+                "wo": dense(next(k), (L, hw, c.d_model), hw),
+                "q_norm": jnp.ones((L, ld), pd),
+                "k_norm": jnp.ones((L, ld), pd),
+                "out_norm": jnp.ones((L, hw), pd),
+                "wg": dense(next(x), (L, c.d_model, hw), c.d_model),
+                "mlp_norm": jnp.ones((L, c.d_model), pd),
+            }
         else:
             layers = {
                 "attn_norm": jnp.ones((L, c.d_model), pd),
@@ -826,7 +938,10 @@ def llama_partition_rules(pipeline=False):
         # k_norm: a gain vector per layer, replicated.
         (r"layers/.*norm", P(lead, None)),
         # "layers/" matches every stack of ``LlamaConfig.layer_plan``
-        # ("dense_layers/", "conv_layers/", ...): all shard alike.
+        # ("dense_layers/", "conv_layers/", "sparse_layers/",
+        # "lightning_layers/", ...): all shard alike; a lightning
+        # layer's ``out_norm`` is a gain with the norms above, its
+        # ``wg`` a projection with these.
         (r"layers/w[qkvg]$", P(lead, "fsdp", "tensor")),
         (r"layers/wo", P(lead, "tensor", "fsdp")),
         # The short convolution: projections like attention's, the taps
@@ -1292,6 +1407,96 @@ def _mamba2(x, lp, c, mesh, seq_axis):
         return y @ lp["ssd_out"].astype(dt)
 
 
+def _lightning(x, lp, c, mesh, seq_axis):
+    """minicpm_sala's ``lightning-attn`` mixer, the token mixer of a
+    ``lightning_attention`` layer, on the residual stream ``x`` [B, T, D]
+    -> what it adds: the layer's norm; ``q``, ``k``, ``v`` and the gate
+    ``z`` projected, ``H`` heads of ``d`` each; ``q`` and ``k`` under an
+    RMSNorm a head (one gain of ``d`` a projection) and RoPE over the
+    whole head; from ``S_0 = 0`` a state a head, ``S_t = lambda S_{t-1}
+    + k_t v_t^T``, ``o_t = S_t^T q_t / sqrt(d)`` with no denominator,
+    ``lambda = exp(rate)`` a constant of the layer and head
+    (``lp["lightning_rate"]``, ``LlamaConfig.lightning_rates``: no leaf);
+    an RMSNorm over the concatenated heads, the gate ``sigmoid(z)``; the
+    output projection. The recurrence IS ``ops/ssd.py``'s with ``x = v``,
+    ``B = k``, ``C = q / sqrt(d)``, ``dt = 1``, no ``D``, a group a head:
+    it runs there. Scopes: the five matmuls ``hvd.attn.proj``, the
+    recurrence ``hvd.lightning.core``, what stands between them
+    ``hvd.lightning.chain`` (float32 inside, rounded once a side)."""
+    from horovod_tpu.ops.ssd import ssd
+
+    if mesh is not None and (
+            (seq_axis and mesh.shape.get(seq_axis, 1) > 1)
+            or mesh.shape.get("tensor", 1) > 1):
+        raise ValueError(
+            "a lightning_attention layer runs whole on each device of "
+            "the data and fsdp axes: its state passes from token to "
+            "token (no sequence axis) and its output norm sees every "
+            "head (no tensor axis yet)")
+    dt, f32 = c.compute_dtype, jnp.float32
+    b, t, _ = x.shape
+    H, d = c.lightning_heads, c.lightning_head_dim
+    h = _rmsnorm(x, lp["attn_norm"].astype(dt), c.norm_eps)
+    with scope("hvd.attn.proj"):
+        q, k, v, z = (h @ lp[w].astype(dt) for w in ("wq", "wk", "wv", "wg"))
+    with scope("hvd.lightning.chain"):
+        freqs = c.rope_theta ** (-jnp.arange(0, d // 2, dtype=f32)
+                                 / (d // 2))
+        angles = jnp.arange(t, dtype=f32)[:, None] * freqs      # [T, d/2]
+        cos, sin = jnp.cos(angles)[:, None], jnp.sin(angles)[:, None]
+
+        def turned(y, gain, scale):
+            """The norm a head, the rotation (half-split, as ``_rope``)
+            and the scale in float32; each half rounded as it is made,
+            so that no float32 [B, T, H d] array stands in HBM."""
+            y = _rms(y.reshape(b, t, H, d).astype(f32), gain.astype(f32),
+                     c.norm_eps)
+            y1, y2 = jnp.split(y, 2, axis=-1)
+            return jnp.concatenate(
+                [((y1 * cos - y2 * sin) * scale).astype(dt),
+                 ((y1 * sin + y2 * cos) * scale).astype(dt)], -1)
+
+        q = turned(q, lp["q_norm"], d ** -0.5)
+        k = turned(k, lp["k_norm"], 1.0)
+    with scope("hvd.lightning.core"):
+        y = ssd(v.reshape(b, t, H, d), jnp.ones((b, t, H), f32),
+                lp["lightning_rate"], k, q, None, c.lightning_chunk,
+                "hvd.lightning.core")
+    with scope("hvd.lightning.chain"):
+        y = (_rms(y.reshape(b, t, H * d).astype(f32),
+                  lp["out_norm"].astype(f32), c.norm_eps)
+             * jax.nn.sigmoid(z.astype(f32))).astype(dt)
+    with scope("hvd.attn.proj"):
+        return y @ lp["wo"].astype(dt)
+
+
+def _sparse_core(q, k, v, c, mesh, seq_axis):
+    """A ``sparse_attention`` layer's attention on ``q`` [B, T, H, d],
+    ``k``, ``v`` [B, T, Hkv, d] past ``sparse_dense_len`` tokens: the
+    selection (``hvd.sparse.select``; its table named ``sparse_sel`` for
+    the remat policies, so that the backward reads the forward's choice
+    and chooses nothing again) and the attention over what it chose
+    (``hvd.sparse.core``), both ``ops/sparse_attention.py``'s."""
+    from horovod_tpu.ops import sparse_attention as sa
+
+    if mesh is not None and (
+            _over_sequence(mesh, seq_axis)
+            or mesh.shape.get("tensor", 1) > 1):
+        raise ValueError(
+            "a sparse_attention layer past its dense length runs whole "
+            "on each device of the data and fsdp axes: a token's blocks "
+            "are chosen over the whole sequence and by a group's heads "
+            "together (no sequence or tensor axis yet)")
+    with scope("hvd.sparse.select"):
+        table = checkpoint_name(sa.select_blocks(
+            q, k, block=c.sparse_block, topk=c.sparse_topk,
+            kernel=c.sparse_kernel, stride=c.sparse_stride,
+            init_blocks=c.sparse_init_blocks,
+            window_blocks=c.sparse_window_blocks), "sparse_sel")
+    with scope("hvd.sparse.core"):
+        return sa.sparse_attention(q, k, v, table, c.sparse_block)
+
+
 def _slot_holds(idx, n_experts):
     """``[..., K, E]`` bool: slot k of a token holds expert e. A pick by
     ``idx`` is a select on it under a sum, which XLA fuses into one
@@ -1578,11 +1783,32 @@ def _ffn(h, lp, c, mesh=None):
     if c.ffn_act == "relu2":
         return (_relu2(h, lp["w_up"], lp["w_down"], dt),
                 jnp.zeros((2, 0), jnp.float32))
+    if c.ffn_chunk and h.shape[0] * h.shape[1] > c.ffn_chunk:
+        return _swiglu_in_blocks(h, lp, c), jnp.zeros((2, 0), jnp.float32)
     with scope("hvd.ffn"):
         gate_pre = checkpoint_name(h @ lp["w_gate"].astype(dt), "ffn_gate")
         up = checkpoint_name(h @ lp["w_up"].astype(dt), "ffn_up")
         y = (jax.nn.silu(gate_pre) * up) @ lp["w_down"].astype(dt)
     return y, jnp.zeros((2, 0), jnp.float32)
+
+
+def _swiglu_in_blocks(h, lp, c):
+    """The dense SwiGLU on ``h`` [B, T, D] in blocks of ``c.ffn_chunk``
+    tokens (or the largest divisor of B*T under it), each under a
+    checkpoint inside a ``lax.map``: forward and backward hold one
+    block's three [block, d_ff] activations (1.07 GB each, whole, at
+    32,768 tokens of 16,384), and a matrix's gradient is the sum of the
+    blocks'. As ``_token_nll_in_blocks`` does for the head."""
+    from horovod_tpu.ops.flash_attention import _pick_block
+
+    dt = c.compute_dtype
+    b, t, d = h.shape
+    rows = _pick_block(b * t, c.ffn_chunk)
+    # cast once: a block's cotangent is added to ONE accumulator a matrix
+    w = tuple(lp[name].astype(dt) for name in ("w_gate", "w_up", "w_down"))
+    y = lax.map(jax.checkpoint(lambda hb: _swiglu(hb, *w, dt)),
+                h.reshape(b * t // rows, rows, d))
+    return y.reshape(b, t, d)
 
 
 def llama_forward(params, tokens, config, mesh=None, seq_axis="seq",
@@ -1609,6 +1835,8 @@ def _head(params, x, c):
     giving up the f32 logits downstream softmax stability needs)."""
     dt = c.compute_dtype
     with scope("hvd.head"):
+        if c.logit_div != 1.0:
+            x = x * jnp.asarray(1.0 / c.logit_div, x.dtype)
         if c.tie_embeddings:
             # The embedding matrix [vocab, D] contracted over D where it
             # lies: no transposed copy; its gradient is the sum of this
@@ -1703,8 +1931,8 @@ def llama_expert_load(params, tokens, config):
 @scope("hvd.embed")
 def _embed(params, tokens, c):
     x = params["embed"].astype(c.compute_dtype)[tokens]
-    if c.scale_embed:
-        x = x * jnp.asarray(c.d_model ** 0.5, x.dtype)
+    if c.embed_mult or c.scale_embed:
+        x = x * jnp.asarray(c.embed_mult or c.d_model ** 0.5, x.dtype)
     return x
 
 
@@ -1742,9 +1970,19 @@ def _run_layers(params, x, c, mesh, seq_axis, mtp=False):
     stage (``_stage_scan``) always scans: one layer program by contract."""
     plan = c.layer_plan(mtp)
     kinds = {spec.kind for spec in plan}
+
+    def stack_of(spec):
+        """The stack's leaves and, beside them, what its layers read
+        that is no leaf: a lightning layer's decay rates, a row a
+        layer."""
+        if spec.mixer != "lightning":
+            return params[spec.stack]
+        return {**params[spec.stack],
+                "lightning_rate": jnp.asarray(c.lightning_rates(spec.stack))}
+
     if len(kinds) == 1 and not _grouped_dispatch(c, mesh):
         return lax.scan(_build_layer_body(c, mesh, seq_axis), x,
-                        params[plan[0].stack], unroll=c.scan_unroll)
+                        stack_of(plan[0]), unroll=c.scan_unroll)
     from horovod_tpu.ops.grouped_moe import LayerOfStack
 
     bodies = {kind: _build_layer_body(c, mesh, seq_axis, kind=kind)
@@ -1772,13 +2010,13 @@ def _run_layers(params, x, c, mesh, seq_axis, mtp=False):
             # the program's end, where they are concatenated into the
             # stack's: 3.2 GB of a 1.6 B-parameter model's 4.56 GB of
             # temporaries (compiled for the described v5e, PR 47).
-            x, bal = lax.scan(bodies[spec.kind], x, params[spec.stack],
+            x, bal = lax.scan(bodies[spec.kind], x, stack_of(spec),
                               unroll=c.scan_unroll)
             balance.extend(bal[i] for i in range(depth[spec.stack]))
             at += depth[spec.stack]
             continue
         lp = {k: LayerOfStack(w, spec.index) if k in whole
-              else w[spec.index] for k, w in params[spec.stack].items()}
+              else w[spec.index] for k, w in stack_of(spec).items()}
         x, bal = bodies[spec.kind](x, lp)
         balance.append(bal)
         at += 1
@@ -1874,11 +2112,17 @@ def _build_layer_body(c, mesh, seq_axis, constrain_acts=True, kind=None):
     def constrain(x):
         return _constrain(x, mesh) if constrain_acts else x
 
+    def joins(y):
+        """What a part adds to the stream, times ``residual_mult``."""
+        if c.residual_mult != 1.0:
+            y = y * jnp.asarray(c.residual_mult, y.dtype)
+        return constrain(y)
+
     def layer(x, lp):
         if mixer is None:
             return ffn(x, None, lp)
         if dense_ffn is None:
-            return x + constrain(mix(x, lp)), jnp.zeros((2, 0), jnp.float32)
+            return x + joins(mix(x, lp)), jnp.zeros((2, 0), jnp.float32)
         return ffn(x, mix(x, lp), lp)
 
     def mix(x, lp, stage=lambda f: f):
@@ -1893,6 +2137,8 @@ def _build_layer_body(c, mesh, seq_axis, constrain_acts=True, kind=None):
             return _mamba(x, lp, c, mesh, seq_axis)
         if mixer == "mamba2":
             return _mamba2(x, lp, c, mesh, seq_axis)
+        if mixer == "lightning":
+            return _lightning(x, lp, c, mesh, seq_axis)
         # Shapes from x, not the enclosing scope: under pipelining the
         # layer sees microbatches smaller than the full batch.
         bb, tt = x.shape[0], x.shape[1]
@@ -1902,7 +2148,11 @@ def _build_layer_body(c, mesh, seq_axis, constrain_acts=True, kind=None):
         # elsewhere: which, is read off the input.
         from horovod_tpu.ops import qk_prep
 
-        head_major = qk_prep.on_kernels(
+        # A sparse layer past its dense length attends by tiles of
+        # neighbouring tokens: its kernels take the heads as the
+        # projections leave them, a token's side by side.
+        sparse = mixer == "sparse" and tt > c.sparse_dense_len
+        head_major = not sparse and qk_prep.on_kernels(
             x, c.head_dim, c.qk_norm == "head",
             (c.partial_rotary or c.head_dim) if rope else 0,
             _over_sequence(mesh, seq_axis))
@@ -1921,8 +2171,11 @@ def _build_layer_body(c, mesh, seq_axis, constrain_acts=True, kind=None):
         kk = checkpoint_name(kk, "rope_k")
         vv = checkpoint_name(vv, "attn_v")
         # remat="attn" save-names applied inside _attention (per path).
-        attn = _attention(q, kk, vv, mesh, seq_axis, c.seq_parallel,
-                          c.flash_block, window, head_major)
+        if sparse:
+            attn = _sparse_core(q, kk, vv, c, mesh, seq_axis)
+        else:
+            attn = _attention(q, kk, vv, mesh, seq_axis, c.seq_parallel,
+                              c.flash_block, window, head_major)
         with scope("hvd.attn.proj"):
             attn = attn.reshape(bb, tt, -1)
             if c.attn_gate:
@@ -1937,13 +2190,13 @@ def _build_layer_body(c, mesh, seq_axis, constrain_acts=True, kind=None):
             if c.post_norm:
                 mixed = _rmsnorm(mixed, lp["post_attn_norm"].astype(dt),
                                  c.norm_eps)
-            x = x + constrain(mixed)
+            x = x + joins(mixed)
 
         h = _rmsnorm(x, lp["mlp_norm"].astype(dt), c.norm_eps)
         ff, aux = _ffn(h, lp, c, mesh)
         if c.post_norm:
             ff = _rmsnorm(ff, lp["post_mlp_norm"].astype(dt), c.norm_eps)
-        x = x + constrain(ff)
+        x = x + joins(ff)
         return x, aux
 
     body = layer
@@ -1963,7 +2216,7 @@ def _build_layer_body(c, mesh, seq_axis, constrain_acts=True, kind=None):
         body = jax.checkpoint(
             layer,
             policy=jax.checkpoint_policies.save_only_these_names(
-                "attn_out", "flash_o", "flash_lse"))
+                "attn_out", "flash_o", "flash_lse", "sparse_sel"))
     elif c.remat == "attn/ffn":
         # "attn" with the mixer and the FFN each under a checkpoint of
         # its own (the mixer's output, [B,T,D], is saved between them),
@@ -1980,7 +2233,7 @@ def _build_layer_body(c, mesh, seq_axis, constrain_acts=True, kind=None):
         # under ONE checkpoint 9.00, this 7.74. No FLOP more.
         once = partial(jax.checkpoint,
                        policy=jax.checkpoint_policies.save_only_these_names(
-                           "attn_out", "flash_o", "flash_lse"))
+                           "attn_out", "flash_o", "flash_lse", "sparse_sel"))
         mix_once = partial(mix, stage=once) if mixer == "linear" \
             else once(mix)
         ffn_once = once(ffn)

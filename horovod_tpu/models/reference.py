@@ -994,3 +994,226 @@ def nemotronh_loss(params, batch, cfg, vocab_rows=None, terms=False):
     main = ce(logits, targets, mask)
     mtp = ce(logits2, _token_after(targets), mask * has_next)
     return (main, mtp) if terms else main + cfg.mtp_weight * mtp
+
+
+# ---------------------------------------------------------------------
+# MiniCPM-SALA (``model_type`` minicpm_sala; openbmb/MiniCPM-SALA's
+# config.json): InfLLM-V2 block-sparse attention (arXiv 2509.24663;
+# MiniCPM4, arXiv 2506.07900) beside Lightning linear attention (arXiv
+# 2401.04658; MiniMax-01, arXiv 2501.08313), under MiniCPM's muP
+# scalings.
+#
+# Decoder, pre-norm, residual stream ``x`` [B, T, D], no bias anywhere,
+# ``L`` the PUBLISHED depth (``lightning_depth``), ``r = residual_mult``
+# (``scale_depth / sqrt(L)``):
+#
+# - ``x_0 = embed_mult * E[token]`` (``scale_emb``); a layer: ``x <- x +
+#   r * mixer(RMSNorm_1(x))``, ``x <- x + r * FFN(RMSNorm_2(x))``,
+#   ``FFN(h) = (silu(h W_g) * (h W_u)) W_d``; logits ``=
+#   (RMSNorm_f(x) / logit_div) W_head`` (``hidden_size /
+#   dim_model_base``), untied;
+# - ``lightning_attention`` (published layer ``l``, head ``n`` of ``H``,
+#   ``d`` wide): ``q_t = RoPE_t(RMSNorm_q(h_t W_q)[n])``, ``k_t``
+#   likewise (the norm over each head's ``d``, one gain shared by the
+#   heads; half-split RoPE over the whole head), ``v_t = (h_t W_v)[n]``;
+#   ``S_0 = 0``, ``S_t = lambda S_{t-1} + k_t v_t^T``, ``o_t = S_t^T q_t
+#   / sqrt(d)`` (no denominator); ``lambda = exp(-s_n f_l)``, ``s_n =
+#   2^(-8 (n + 1) / H)``, ``f_l = 1 - l / (L - 1) + 1e-5``; ``y =
+#   (RMSNorm_o(concat_n o_t) * sigmoid(h_t W_z)) W_o``;
+# - ``sparse_attention`` (``n_heads`` query heads on ``n_kv_heads``
+#   key/value heads): ``q``, ``k`` under the same norm a head, NO RoPE;
+#   a token ``t`` and group ``g`` choose blocks by steps 1-5 of
+#   ``ops/sparse_attention.py``'s description (written out again in
+#   ``sala_selection`` below, from the text and not from that code);
+#   ONE softmax a query head over the keys ``s <= t`` of the chosen
+#   blocks, scores ``q . k / sqrt(d)``; ``y = (concat_n o_t * sigmoid(h_t
+#   W_z)) W_o``. A sequence of up to ``sparse_dense_len`` tokens runs
+#   the layer dense (plain causal attention).
+#
+# Departures, each a size or a form the published config.json has no key
+# for (the benchmark's configuration file lists them under ``assumed``):
+# the selection's sizes and rules (block 64, top 64, pooling 32 / 16,
+# one initial and 32 local blocks counted INSIDE the 64, a window is
+# admitted when it lies wholly at or before the token, ties to the lower
+# index) follow MiniCPM4's ``sparse_config``; step 2's normaliser is
+# exact where the family's inference code approximates it from keys
+# pooled four times coarser; the decay's form is Lightning Attention's
+# slopes with MiniMax-01's layer factor; ``mup_denominator`` scales the
+# family's learning rates and initialisations and enters no forward;
+# parameters stored in bf16 are read as float32. The recurrence is a
+# ``lax.scan`` over TOKENS exactly as written (no chunk, nothing of
+# ops/ssd.py), the sparse layer an explicit [T, T] mask (no block is
+# gathered, nothing of ops/sparse_attention.py).
+# ---------------------------------------------------------------------
+
+def sala_rates(cfg, layer):
+    """``-s_n f_l`` [H] of the published layer ``layer``."""
+    H, L = cfg.lightning_heads, cfg.lightning_depth or cfg.n_layers
+    n = jnp.arange(H, dtype=F32)
+    return -(2.0 ** (-8.0 * (n + 1.0) / H)) \
+        * (1.0 - layer / max(L - 1, 1) + 1e-5)
+
+
+def sala_recurrence(q, k, v, rates):
+    """``S_t = exp(rate) S_{t-1} + k_t v_t^T; o_t = S_t^T q_t`` from
+    ``S_0 = 0``, token by token: ``q``, ``k``, ``v`` [B, T, H, d]
+    float32, ``rates`` [H] -> [B, T, H, d]."""
+    def token(S, x):
+        qt, kt, vt = x                                        # [B, H, d]
+        S = jnp.exp(rates)[:, None, None] * S \
+            + kt[..., :, None] * vt[..., None, :]
+        return S, jnp.einsum("bhkv,bhk->bhv", S, qt)
+
+    b, _, h, d = q.shape
+    _, o = jax.lax.scan(token, jnp.zeros((b, h, d, d), F32),
+                        tuple(jnp.moveaxis(x, 1, 0) for x in (q, k, v)))
+    return jnp.moveaxis(o, 0, 1)
+
+
+def _half_split_rope(x, theta):
+    """``x`` [B, T, H, d] turned by its position, pairs ``(i, i + d/2)``."""
+    t, d = x.shape[1], x.shape[-1]
+    freqs = theta ** (-jnp.arange(d // 2, dtype=F32) / (d // 2))
+    angles = jnp.arange(t, dtype=F32)[:, None] * freqs        # [T, d/2]
+    cos, sin = jnp.cos(angles)[:, None, :], jnp.sin(angles)[:, None, :]
+    x1, x2 = x[..., :d // 2], x[..., d // 2:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
+
+
+def sala_lightning_mixer(h, lp, cfg, layer):
+    """The lightning mixer on normalized ``h`` [B, T, D] with one layer's
+    float32 parameters, ``layer`` its published index."""
+    b, t, _ = h.shape
+    H, d = cfg.lightning_heads, cfg.lightning_head_dim
+
+    def heads(w):
+        return (h @ lp[w]).reshape(b, t, H, d)
+
+    q = _half_split_rope(_rms(heads("wq"), lp["q_norm"], cfg.norm_eps),
+                         cfg.rope_theta)
+    k = _half_split_rope(_rms(heads("wk"), lp["k_norm"], cfg.norm_eps),
+                         cfg.rope_theta)
+    o = sala_recurrence(q, k, heads("wv"), sala_rates(cfg, layer)) \
+        / d ** 0.5
+    y = _rms(o.reshape(b, t, H * d), lp["out_norm"], cfg.norm_eps)
+    return (y * jax.nn.sigmoid(h @ lp["wg"])) @ lp["wo"]
+
+
+def sala_selection(q, k, cfg):
+    """``Sel(t, g)`` as a mask over blocks, bool [B, T, G, T / block],
+    for ``q`` [B, T, H, d], ``k`` [B, T, G, d] float32 (after their
+    norms), by the five steps, every intermediate whole:
+
+    1. ``kbar_j = mean(k[stride j : stride j + kernel])``, ``j = 0 .. (T
+       - kernel) / stride``;
+    2. ``p[t, n, j] = softmax_j(q[t, n] . kbar_j / sqrt(d))`` over the
+       ``j`` with ``stride j + kernel - 1 <= t``;
+    3. ``P[t, g, j]`` = the sum of ``p`` over the group's heads;
+    4. block ``b`` scores the max of ``P[t, g, j]`` over ``j`` in ``[r b
+       - 1, r b + r - 1]``, ``r = block / stride``, among the ``j`` that
+       exist and step 2 admits, -inf where none; blocks with ``block b >
+       t`` excluded;
+    5. the first ``init_blocks`` and the last ``window_blocks`` begun
+       blocks forced; then the best scoring others, one arg-max at a
+       time (the lower index on a tie), until ``topk`` are chosen or no
+       begun block is left."""
+    b, t, heads, d = q.shape
+    groups = k.shape[2]
+    block, stride, kernel = cfg.sparse_block, cfg.sparse_stride, \
+        cfg.sparse_kernel
+    nj, nb, r = (t - kernel) // stride + 1, t // block, block // stride
+    kbar = jnp.stack([jnp.mean(k[:, stride * j:stride * j + kernel], 1)
+                      for j in range(nj)], 1)                # [B, J, G, d]
+    at = jnp.arange(t)
+    admitted = (stride * jnp.arange(nj) + kernel - 1)[None, :] \
+        <= at[:, None]                                       # [T, J]
+    s = jnp.einsum("btgnd,bjgd->btgnj",
+                   q.reshape(b, t, groups, heads // groups, d), kbar) \
+        / d ** 0.5
+    s = jnp.where(admitted[:, None, None, :], s, -jnp.inf)
+    p = jnp.where(admitted[:, None, None, :],
+                  jnp.exp(s - jax.nn.logsumexp(
+                      jnp.where(admitted[:, None, None, :], s, -1e30), -1,
+                      keepdims=True)), 0.0)
+    P = jnp.where(admitted[:, None, :], jnp.sum(p, 3), -jnp.inf)
+    score = jnp.stack([
+        jnp.max(P[..., max(r * blk - 1, 0):min(r * blk + r, nj)], -1)
+        for blk in range(nb)], -1)                           # [B, T, G, nb]
+    blk = jnp.arange(nb)
+    own = (at // block)[:, None]
+    begun = (blk <= own)[None, :, None, :]
+    forced = begun & ((blk < cfg.sparse_init_blocks)
+                      | (blk > own - cfg.sparse_window_blocks)
+                      )[None, :, None, :]
+    sel = jnp.broadcast_to(forced, score.shape)
+    for _ in range(cfg.sparse_topk):
+        room = jnp.sum(sel, -1, keepdims=True) < cfg.sparse_topk
+        left = jnp.where(begun & ~sel, score, -jnp.inf)
+        best = jax.nn.one_hot(jnp.argmax(left, -1), nb, dtype=bool)
+        sel = sel | (best & room & jnp.isfinite(
+            jnp.max(left, -1, keepdims=True)))
+    return sel
+
+
+def sala_sparse_mixer(h, lp, cfg, dense=False):
+    """The sparse mixer on normalized ``h`` [B, T, D] with one layer's
+    float32 parameters; ``dense``: every earlier key (what a sequence of
+    up to ``sparse_dense_len`` tokens runs) -> (what it adds, the
+    selection [B, T, G, blocks] or None)."""
+    b, t, _ = h.shape
+    hd, groups = cfg.head_dim, cfg.n_kv_heads
+    rep = cfg.n_heads // groups
+    q = _rms((h @ lp["wq"]).reshape(b, t, cfg.n_heads, hd), lp["q_norm"],
+             cfg.norm_eps)
+    k = _rms((h @ lp["wk"]).reshape(b, t, groups, hd), lp["k_norm"],
+             cfg.norm_eps)
+    v = (h @ lp["wv"]).reshape(b, t, groups, hd)
+    mask = (jnp.arange(t)[None, :] <= jnp.arange(t)[:, None])[None, :, None]
+    sel = None
+    if not dense:
+        sel = jax.lax.stop_gradient(sala_selection(q, k, cfg))
+        mask = mask & jnp.repeat(sel, cfg.sparse_block, -1)  # [B, T, G, T]
+    mask = jnp.repeat(jnp.moveaxis(jnp.broadcast_to(
+        mask, (b, t, groups, t)), 2, 1), rep, 1)              # [B, H, T, T]
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, jnp.repeat(k, rep, 2)) / hd ** 0.5
+    p = jax.nn.softmax(jnp.where(mask, s, -jnp.inf), -1)
+    a = jnp.einsum("bhqk,bkhd->bqhd", p, jnp.repeat(v, rep, 2)).reshape(
+        b, t, -1)
+    return (a * jax.nn.sigmoid(h @ lp["wg"])) @ lp["wo"], sel
+
+
+def sala_forward(params, tokens, cfg, selections=None):
+    """tokens [B, T] -> logits [B, T, vocab] f32 (the description above).
+    ``params`` is the program's tree, any storage dtype; a layer's
+    parameters are found by ``cfg.layer_plan()``. ``selections``: a list
+    that gets each sparse layer's selection appended."""
+    with jax.default_matmul_precision("highest"):
+        x = cfg.embed_mult * params["embed"].astype(F32)[tokens]
+        r = cfg.residual_mult
+        for l, spec in enumerate(cfg.layer_plan()):
+            lp = jax.tree.map(lambda w: w[spec.index].astype(F32),
+                              params[spec.stack])
+            h = _rms(x, lp["attn_norm"], cfg.norm_eps)
+            if spec.mixer == "lightning":
+                y = sala_lightning_mixer(h, lp, cfg, l)
+            else:
+                y, sel = sala_sparse_mixer(
+                    h, lp, cfg, dense=tokens.shape[1] <= cfg.sparse_dense_len)
+                if selections is not None:
+                    selections.append(sel)
+            x = x + r * y
+            h = _rms(x, lp["mlp_norm"], cfg.norm_eps)
+            x = x + r * _swiglu(h, lp["w_gate"], lp["w_up"], lp["w_down"])
+        x = _rms(x, params["final_norm"].astype(F32), cfg.norm_eps)
+        return (x / cfg.logit_div) @ params["lm_head"].astype(F32)
+
+
+def sala_loss(params, batch, cfg):
+    """Mean token cross-entropy over the vocabulary rows held, over the
+    positions ``batch["mask"]`` keeps (all without one). ``jax.grad`` of
+    this is the reference gradient."""
+    logp = jax.nn.log_softmax(sala_forward(params, batch["tokens"], cfg), -1)
+    nll = -jnp.take_along_axis(logp, batch["targets"][..., None],
+                               -1)[..., 0]
+    mask = batch.get("mask", jnp.ones(nll.shape, F32)).astype(F32)
+    return jnp.sum(nll * mask) / jnp.sum(mask)

@@ -1,0 +1,527 @@
+"""Block-sparse attention over key blocks that are DATA (InfLLM-V2,
+arXiv:2509.24663; MiniCPM4's ``sparse_config``, arXiv:2506.07900): the
+selection, the attention over what it chose, forward and backward.
+
+``ops/flash_attention.py`` knows every tile's class before it runs, from
+the causal edge and a window. Here a token ``t`` of a key/value group
+``g`` (the ``H / G`` query heads that share one key head) attends the
+keys ``s <= t`` of the blocks in ``Sel(t, g)``, a set of at most ``topk``
+blocks of ``block`` keys that is known only on the device. That is why
+this is a file beside the flash kernels and not a mode of them: their
+grid, their index maps and their three classes of tile are compile-time
+facts of the causal edge, and nothing of that survives a set chosen a
+token (the table is an operand, the loop over key blocks is inside the
+kernel, the keys stay in VMEM whole).
+
+The selection (:func:`select_blocks`; no parameter, no gradient), a
+token ``t`` and group ``g``:
+
+1. pooled keys ``kbar_j = mean(k[stride j : stride j + kernel])`` for
+   every whole window ``j`` (the group's key head), rounded to ``k``'s
+   dtype;
+2. ``p[t, n, j] = softmax_j(q[t, n] . kbar_j / sqrt(d))`` over the ``j``
+   whose window lies wholly at or before ``t``, in float32, a head ``n``
+   of the group (the normaliser exact: the family's inference code
+   approximates it from coarser keys);
+3. ``P[t, g, j]`` = the sum of ``p`` over the group's heads;
+4. a block ``b`` scores ``max P[t, g, j]`` over the ``block / stride +
+   1`` windows ``j`` in ``[r b - 1, r b + r - 1]``, ``r = block /
+   stride`` (a max-pool with padding 1), among the ``j`` that exist and
+   step 2 admits; minus infinity where none does;
+5. forced: the first ``init_blocks`` blocks and the last
+   ``window_blocks`` begun ones, the token's own among them; ``Sel`` =
+   the forced blocks and the best scoring others up to ``topk`` in all,
+   every begun block where fewer have begun. Ties: the lower index.
+
+What leaves the selection is the table the kernels read: for a TILE of
+``TOKENS_A_TILE`` consecutive tokens and a key block, one int32 whose
+bit ``r`` says whether the tile's token ``r`` chose the block, ``[B, G,
+T / TOKENS_A_TILE, T / block]`` (:func:`chosen` unpacks it to the sets).
+
+The attention (:func:`sparse_attention`), one recurrence-free softmax a
+query head over exactly its token's set, on two carriers read off the
+operands (``ops/_platform.py``):
+
+- operands on a TPU: ``hvd_sparse_attn_fwd`` / ``hvd_sparse_attn_bwd``
+  by their ``kernel_metadata``. A grid step is one tile: the group's
+  ``H / G`` heads of ``TOKENS_A_TILE`` neighbouring tokens as the rows
+  of ONE matrix (256 rows at 16 heads), against the group's keys and
+  values, which stay in VMEM whole (``[T, d]`` each: 8.4 MB at T 32768,
+  d 128, bf16), so a block is a dynamic slice and no gather from HBM.
+  The tile walks the blocks begun before its last token, two
+  neighbours a visit (128 keys: a score tile's lanes full); its row of
+  the table arrives in SMEM, a pair no token of the tile chose costs two
+  scalar loads and a branch, a visited pair is masked row by row to the
+  tokens that chose each block (a shift of the block's word by the
+  row's token) and to the causal edge. Every row attends its own set and nothing
+  else; what the tile pays for is the UNION of its tokens' sets. The
+  backward is one kernel over the same tiles: ``s``, ``p = exp(s -
+  lse)``, ``dp`` and ``ds`` formed once a visited block, ``dq`` a tile,
+  ``dk`` and ``dv`` accumulated in float32 in VMEM (``[T, d]`` each, a
+  block's slice read and written where its queries find it: no search
+  for the queries that chose a block) and written once a group;
+- elsewhere: the same softmax under an explicit mask built from the
+  sets, a block of query rows at a time, differentiated by autodiff
+  (the CPU's path and the tests' reference for the kernels, which run
+  there in interpret mode under ``_INTERPRET``).
+
+Precision: scores, the softmax, ``lse`` and every accumulation float32;
+matmul operands in the operands' dtype (``p`` and ``ds`` rounded to it
+as they enter one); the selection's scores float32 from operands in
+their own dtype.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.ad_checkpoint import checkpoint_name
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from horovod_tpu.ops._platform import use_pallas
+from horovod_tpu.ops.flash_attention import (
+    _column_to_row, _pick_block, _row_to_column, _scaled)
+from horovod_tpu.utils.spans import scope
+
+F32 = jnp.float32
+_NEG = -1e30
+# Tests flip this to run the kernel pair in pallas interpret mode on the
+# CPU (as ``flash_attention._INTERPRET``).
+_INTERPRET = False
+# Neighbouring tokens whose heads (a group's) make one tile's rows; a
+# bit each in the table's int32.
+TOKENS_A_TILE = 16
+# Neighbouring blocks a visit takes side by side (where their number
+# divides): two blocks of 64 keys fill the 128 lanes.
+BLOCKS_A_VISIT = 2
+# Query rows a block of the selection's scores and of the explicit-mask
+# form: [rows, H / G, windows] float32 at a time.
+SELECT_ROWS = 512
+# The kernels hold a group's keys and values whole (the backward their
+# float32 gradients too: 50 MB at T 32768, d 128).
+VMEM_LIMIT = 100 * 1024 * 1024
+
+
+# ---------------------------------------------------------------------
+# The selection, in jax.numpy on either carrier.
+# ---------------------------------------------------------------------
+
+def pooled_keys(k, kernel, stride):
+    """``k`` [B, T, G, d] -> ``kbar`` [B, J, G, d] in ``k``'s dtype, ``J
+    = (T - kernel) / stride + 1`` whole windows, the mean in float32."""
+    B, T, G, d = k.shape
+    if kernel % stride or T % stride or T < kernel:
+        raise ValueError(f"pooling {kernel} keys every {stride} over {T}: "
+                         "the window is no multiple of the stride, or the "
+                         "sequence none, or shorter than a window")
+    sums = k.astype(F32).reshape(B, T // stride, stride, G, d).sum(2)
+    m, J = kernel // stride, (T - kernel) // stride + 1
+    return (sum(sums[:, i:i + J] for i in range(m)) / kernel).astype(k.dtype)
+
+
+def _block_scores(P, admitted, ratio, n_blocks):
+    """Step 4: ``P`` [..., J] (0 where not ``admitted``) -> [...,
+    n_blocks], the max over the windows ``[ratio b - 1, ratio b + ratio -
+    1]`` that exist and are admitted, -inf where none."""
+    P = jnp.where(admitted, P, -jnp.inf)
+    lead, J = P.shape[:-1], P.shape[-1]
+    # window j at index j + 1 of [-1 .. ratio n_blocks - 1]
+    P = jnp.concatenate(
+        [jnp.full(lead + (1,), -jnp.inf, P.dtype), P,
+         jnp.full(lead + (ratio * n_blocks - J,), -jnp.inf, P.dtype)], -1)
+    first = P[..., :-1].reshape(*lead, n_blocks, ratio).max(-1)
+    return jnp.maximum(first, P[..., ratio::ratio])
+
+
+def _rows_chosen(q, kbar, t0, *, block, topk, kernel, stride, init_blocks,
+                 window_blocks, n_blocks):
+    """Steps 2-5 for the query rows ``q`` [B, R, G, n, d] at positions
+    ``t0 ..`` against ``kbar`` [B, J, G, d] -> bool [B, R, G, n_blocks]."""
+    R, d = q.shape[1], q.shape[-1]
+    t = t0 + jnp.arange(R)
+    j = jnp.arange(kbar.shape[1])
+    admitted = (stride * j + kernel - 1)[None, :] <= t[:, None]    # [R, J]
+    s = jnp.einsum("brgnd,bjgd->brgnj", q, kbar,
+                   preferred_element_type=F32) * d ** -0.5
+    s = jnp.where(admitted[:, None, None, :], s, -jnp.inf)
+    top = jnp.max(s, -1, keepdims=True)
+    e = jnp.where(admitted[:, None, None, :],
+                  jnp.exp(s - jnp.where(jnp.isfinite(top), top, 0.0)), 0.0)
+    p = e / jnp.maximum(jnp.sum(e, -1, keepdims=True), 1e-30)
+    score = _block_scores(jnp.sum(p, 3), admitted[:, None, :],
+                          block // stride, n_blocks)          # [B, R, G, nb]
+    b = jnp.arange(n_blocks)
+    own = (t // block)[:, None]
+    begun = b <= own
+    forced = begun & ((b < init_blocks) | (b > own - window_blocks))
+    # forced first, then the begun by score, the not begun never; a
+    # begun block with no admitted window is forced (step 4)
+    key = jnp.where(forced[:, None, :], jnp.inf,
+                    jnp.where(begun[:, None, :], jnp.maximum(score, -1.0),
+                              -jnp.inf))
+    value, index = lax.top_k(key, min(topk, n_blocks))   # ties: lower index
+    picked = (index[..., None] == b) & (value[..., None] > -jnp.inf)
+    return jnp.any(picked, -2)
+
+
+def _pack(chosen):
+    """bool [B, T, G, nb] -> the kernels' table, int32 [B, G, T /
+    TOKENS_A_TILE, nb]: bit ``r`` of a word, the tile's token ``r``."""
+    B, T, G, nb = chosen.shape
+    R = TOKENS_A_TILE
+    bits = chosen.reshape(B, T // R, R, G, nb).astype(jnp.int32) \
+        << jnp.arange(R, dtype=jnp.int32)[:, None, None]
+    return jnp.moveaxis(jnp.sum(bits, 2), 2, 1)
+
+
+def chosen(table):
+    """The table -> the sets, bool [B, T, G, nb]."""
+    B, G, tiles, nb = table.shape
+    R = TOKENS_A_TILE
+    bits = (table[:, :, :, None, :] >> jnp.arange(
+        R, dtype=jnp.int32)[:, None]) & 1                 # [B, G, tiles, R, nb]
+    return jnp.moveaxis(bits.astype(bool), 1, 3).reshape(B, tiles * R, G, nb)
+
+
+def select_blocks(q, k, *, block, topk, kernel, stride, init_blocks,
+                  window_blocks):
+    """Steps 1-5 of the module's description for ``q`` [B, T, H, d] and
+    ``k`` [B, T, G, d] (after their norms; query head ``h`` reads key head
+    ``h // (H / G)``) -> the table, int32 [B, G, T / TOKENS_A_TILE, T /
+    block]. No gradient passes (``stop_gradient``)."""
+    B, T, H, d = q.shape
+    G = k.shape[2]
+    if T % block or T % TOKENS_A_TILE or block % stride or H % G:
+        raise ValueError(
+            f"a sequence of {T} in blocks of {block} keys, tiles of "
+            f"{TOKENS_A_TILE} tokens, strides of {stride}; {H} heads on "
+            f"{G}: each has to divide")
+    q, k = lax.stop_gradient(q), lax.stop_gradient(k)
+    kbar = pooled_keys(k, kernel, stride)
+    rows = _pick_block(T, SELECT_ROWS)
+    sizes = dict(block=block, topk=topk, kernel=kernel, stride=stride,
+                 init_blocks=init_blocks, window_blocks=window_blocks,
+                 n_blocks=T // block)
+    qs = jnp.moveaxis(q.reshape(B, T // rows, rows, G, H // G, d), 1, 0)
+    sel = lax.map(lambda x: _rows_chosen(x[0], kbar, x[1], **sizes),
+                  (qs, jnp.arange(T // rows) * rows))
+    return _pack(jnp.moveaxis(sel, 0, 1).reshape(B, T, G, T // block))
+
+
+# ---------------------------------------------------------------------
+# The attention under an explicit mask: the CPU's path.
+# ---------------------------------------------------------------------
+
+def _masked_rows(q, k, v, sel, t0, block):
+    """``q`` [B, R, G, n, d] at positions ``t0 ..``, ``k``, ``v`` [B, T,
+    G, d], ``sel`` bool [B, R, G, nb] -> [B, R, G, n, d]."""
+    R, T, d = q.shape[1], k.shape[1], q.shape[-1]
+    t, s_at = t0 + jnp.arange(R), jnp.arange(T)
+    allowed = jnp.repeat(sel, block, -1) \
+        & (s_at[None, :] <= t[:, None])[None, :, None, :]   # [B, R, G, T]
+    s = jnp.einsum("brgnd,bsgd->brgns", _scaled(q, d ** -0.5), k,
+                   preferred_element_type=F32)
+    s = jnp.where(allowed[:, :, :, None, :], s, _NEG)
+    p = jax.nn.softmax(s, -1).astype(v.dtype)
+    return jnp.einsum("brgns,bsgd->brgnd", p, v,
+                      preferred_element_type=F32).astype(q.dtype)
+
+
+def _masked_attention(q, k, v, table, block):
+    B, T, H, d = q.shape
+    G = k.shape[2]
+    rows = _pick_block(T, SELECT_ROWS)
+    sel = chosen(table)
+
+    def lead(a, *rest):
+        return jnp.moveaxis(a.reshape(B, T // rows, rows, *rest), 1, 0)
+
+    o = lax.map(
+        jax.checkpoint(lambda x: _masked_rows(x[0], k, v, x[1], x[2],
+                                              block)),
+        (lead(q, G, H // G, d), lead(sel, G, T // block),
+         jnp.arange(T // rows) * rows))
+    return jnp.moveaxis(o, 0, 1).reshape(B, T, H, d)
+
+
+# ---------------------------------------------------------------------
+# The Pallas TPU kernel pair: a group's keys and values in VMEM.
+# ---------------------------------------------------------------------
+
+def _allowed(words, visit, tile, rows, n, block):
+    """[rows, len(words) block] bool for a VISIT, ``len(words)``
+    neighbouring blocks side by side along the lanes (two: 128 keys fill
+    a vreg's lanes where one block of 64 fills half): row ``r`` is head
+    ``r % n`` of the tile's token ``r // n``; it sees a key where its
+    token chose the key's block (its bit of that block's word) and the
+    key is not after it."""
+    shape = (rows, len(words) * block)
+    token = lax.broadcasted_iota(jnp.int32, shape, 0) // n
+    col = lax.broadcasted_iota(jnp.int32, shape, 1)
+    word = jnp.full(shape, words[0], jnp.int32)
+    for i in range(1, len(words)):
+        word = jnp.where(col >= i * block, jnp.full(shape, words[i],
+                                                    jnp.int32), word)
+    return ((lax.shift_right_logical(word, token) & 1) == 1) \
+        & (visit * shape[1] + col <= tile * TOKENS_A_TILE + token)
+
+
+def _visits(tile, width):
+    """Visits (``width`` keys each) begun at or before the tile's last
+    token."""
+    return (tile * TOKENS_A_TILE + TOKENS_A_TILE - 1) // width + 1
+
+
+def _words(table_ref, visit, per):
+    """The table's words of the ``per`` blocks of ``visit``, and whether
+    any token of the tile chose any of them."""
+    words = [table_ref[0, visit * per + i] for i in range(per)]
+    any_ = words[0]
+    for w in words[1:]:
+        any_ = any_ | w
+    return words, any_ != 0
+
+
+def _fwd_kernel(table_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *, n,
+                block, per, scale):
+    """One tile: ``q_ref`` [rows, d] (token-major, a token's ``n`` heads
+    side by side down the rows), ``k_ref``, ``v_ref`` [T, d], its row of
+    the table in SMEM -> ``o_ref`` [rows, d], ``lse_ref`` [1, rows]."""
+    tile = pl.program_id(2)
+    rows, d = q_ref.shape
+    qs = _scaled(q_ref[...], scale)
+
+    width = per * block
+
+    def visit(b, carry):
+        words, chosen_here = _words(table_ref, b, per)
+
+        def attend(carry):
+            m, l, acc = carry
+            at = pl.ds(pl.multiple_of(b * width, width), width)
+            ok = _allowed(words, b, tile, rows, n, block)
+            s = jnp.where(ok, lax.dot_general(
+                qs, k_ref[at, :], (((1,), (1,)), ((), ())),
+                preferred_element_type=F32), _NEG)
+            m_new = jnp.maximum(m, jnp.max(s, -1, keepdims=True))
+            p = jnp.where(ok, jnp.exp(s - m_new), 0.0)
+            a = jnp.exp(m - m_new)
+            return (m_new, a * l + jnp.sum(p, -1, keepdims=True),
+                    a * acc + jnp.dot(p.astype(v_ref.dtype), v_ref[at, :],
+                                      preferred_element_type=F32))
+
+        return lax.cond(chosen_here, attend, lambda c: c, carry)
+
+    m, l, acc = lax.fori_loop(
+        0, _visits(tile, width), visit,
+        (jnp.full((rows, 1), _NEG, F32), jnp.zeros((rows, 1), F32),
+         jnp.zeros((rows, d), F32)))
+    o_ref[...] = (acc / l).astype(o_ref.dtype)
+    lse_ref[...] = _column_to_row(m + jnp.log(l))
+
+
+def _bwd_kernel(table_ref, q_ref, k_hbm, v_hbm, do_ref, lse_ref, delta_ref,
+                dq_ref, dk_hbm, dv_hbm, k_ref, v_ref, dk_ref, dv_ref, sem,
+                *, n, block, per, scale):
+    """One tile of the backward. The group's keys and values are copied
+    into VMEM once a group (``k_ref``, ``v_ref``: scratch, one buffer
+    each), their float32 gradients gathered there (``dk_ref``,
+    ``dv_ref``) and copied out behind the group's last tile."""
+    bi, g, tile = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    rows, d = q_ref.shape
+
+    def copies(pairs):
+        return [pltpu.make_async_copy(src, dst, sem.at[i])
+                for i, (src, dst) in enumerate(pairs)]
+
+    @pl.when(tile == 0)
+    def _start():
+        loads = copies([(k_hbm.at[bi, g], k_ref), (v_hbm.at[bi, g], v_ref)])
+        for c in loads:
+            c.start()
+        dk_ref[...] = jnp.zeros_like(dk_ref)
+        dv_ref[...] = jnp.zeros_like(dv_ref)
+        for c in loads:
+            c.wait()
+
+    q, do = q_ref[...], do_ref[...]
+    qs = _scaled(q, scale)
+    lse, delta = _row_to_column(lse_ref[...]), _row_to_column(delta_ref[...])
+
+    width = per * block
+
+    def visit(b, dq):
+        words, chosen_here = _words(table_ref, b, per)
+
+        def attend(dq):
+            at = pl.ds(pl.multiple_of(b * width, width), width)
+            kb, vb = k_ref[at, :], v_ref[at, :]
+            ok = _allowed(words, b, tile, rows, n, block)
+            s = lax.dot_general(qs, kb, (((1,), (1,)), ((), ())),
+                                preferred_element_type=F32)
+            p = jnp.where(ok, jnp.exp(s - lse), 0.0)
+            dp = lax.dot_general(do, vb, (((1,), (1,)), ((), ())),
+                                 preferred_element_type=F32)
+            ds = (p * (dp - delta)).astype(q.dtype)
+            across = (((0,), (0,)), ((), ()))           # over the rows
+            dv_ref[at, :] += lax.dot_general(
+                p.astype(do.dtype), do, across, preferred_element_type=F32)
+            dk_ref[at, :] += lax.dot_general(
+                ds, qs, across, preferred_element_type=F32)
+            return dq + jnp.dot(ds, kb, preferred_element_type=F32)
+
+        return lax.cond(chosen_here, attend, lambda dq: dq, dq)
+
+    dq = lax.fori_loop(0, _visits(tile, width), visit,
+                       jnp.zeros((rows, d), F32))
+    dq_ref[...] = (dq * scale).astype(dq_ref.dtype)
+
+    @pl.when(tile == pl.num_programs(2) - 1)
+    def _finish():
+        stores = copies([(dk_ref, dk_hbm.at[bi, g]),
+                         (dv_ref, dv_hbm.at[bi, g])])
+        for c in stores:
+            c.start()
+        for c in stores:
+            c.wait()
+
+
+def _blocks_a_visit(n_blocks):
+    """Neighbouring blocks a step of a tile's walk takes together."""
+    return BLOCKS_A_VISIT if n_blocks % BLOCKS_A_VISIT == 0 else 1
+
+
+def _params():
+    return pltpu.CompilerParams(
+        dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
+        vmem_limit_bytes=VMEM_LIMIT)
+
+
+def _tile_specs(rows, d, nb):
+    """A tile's blocks of the table [B, G, tiles, 1, nb] (SMEM), of a
+    row-major operand [B, G, tiles * rows, d] and of a row statistic [B,
+    G, tiles, 1, rows]."""
+    return (pl.BlockSpec((None, None, None, 1, nb),
+                         lambda b, g, i: (b, g, i, 0, 0),
+                         memory_space=pltpu.SMEM),
+            pl.BlockSpec((None, None, rows, d), lambda b, g, i: (b, g, i, 0)),
+            pl.BlockSpec((None, None, None, 1, rows),
+                         lambda b, g, i: (b, g, i, 0, 0)))
+
+
+@functools.partial(jax.jit, static_argnames=("n", "block", "interpret"))
+def _kernel_fwd(table, q, k, v, *, n, block, interpret):
+    """``table`` [B, G, tiles, nb]; ``q`` [B, G, T n, d] (token-major);
+    ``k``, ``v`` [B, G, T, d] -> (``o`` like ``q``, ``lse`` [B, G, tiles,
+    1, rows] float32). Jitted on its own: every site that enters it with
+    these shapes calls ONE lowered function."""
+    B, G, tiles, nb = table.shape
+    T, d = k.shape[2:]
+    rows = TOKENS_A_TILE * n
+    word, tile, stat = _tile_specs(rows, d, nb)
+    whole = pl.BlockSpec((None, None, T, d), lambda b, g, i: (b, g, 0, 0))
+    with scope("hvd.sparse.core"):
+        return pl.pallas_call(
+            functools.partial(_fwd_kernel, n=n, block=block,
+                              per=_blocks_a_visit(nb), scale=d ** -0.5),
+            grid=(B, G, tiles), in_specs=[word, tile, whole, whole],
+            out_specs=[tile, stat],
+            out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                       jax.ShapeDtypeStruct((B, G, tiles, 1, rows), F32)],
+            interpret=interpret,
+            metadata={"kernel": "hvd_sparse_attn_fwd"},
+            compiler_params=_params(),
+        )(table[:, :, :, None, :], q, k, v)
+
+
+@functools.partial(jax.jit, static_argnames=("n", "block", "interpret"))
+def _kernel_bwd(table, q, k, v, o, lse, do, *, n, block, interpret):
+    """-> (dq like ``q``, dk, dv like ``k`` in float32)."""
+    B, G, tiles, nb = table.shape
+    T, d = k.shape[2:]
+    rows = TOKENS_A_TILE * n
+    word, tile, stat = _tile_specs(rows, d, nb)
+    with scope("hvd.sparse.core"):
+        delta = jnp.sum(do.astype(F32) * o.astype(F32), -1).reshape(
+            lse.shape)
+        hbm = pl.BlockSpec(memory_space=pl.ANY)
+        kv32 = jax.ShapeDtypeStruct(k.shape, F32)
+        return pl.pallas_call(
+            functools.partial(_bwd_kernel, n=n, block=block,
+                              per=_blocks_a_visit(nb), scale=d ** -0.5),
+            grid=(B, G, tiles),
+            in_specs=[word, tile, hbm, hbm, tile, stat, stat],
+            out_specs=[tile, hbm, hbm],
+            out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype), kv32, kv32],
+            scratch_shapes=[pltpu.VMEM((T, d), k.dtype),
+                            pltpu.VMEM((T, d), v.dtype),
+                            pltpu.VMEM((T, d), F32),
+                            pltpu.VMEM((T, d), F32),
+                            pltpu.SemaphoreType.DMA((2,))],
+            interpret=interpret,
+            metadata={"kernel": "hvd_sparse_attn_bwd"},
+            compiler_params=_params(),
+        )(table[:, :, :, None, :], q, k, v, do, lse, delta)
+
+
+def _token_major(x, G):
+    """[B, T, H, d] -> [B, G, T H / G, d]: a token's heads of a group
+    side by side down the rows."""
+    B, T, H, d = x.shape
+    return jnp.moveaxis(x.reshape(B, T, G, H // G * d), 2, 1).reshape(
+        B, G, T * (H // G), d)
+
+
+def _head_minor(x, T):
+    """``_token_major``'s inverse -> [B, T, H, d]."""
+    B, G, _, d = x.shape
+    return jnp.moveaxis(x.reshape(B, G, T, -1), 1, 2).reshape(B, T, -1, d)
+
+
+def _group_major(x):
+    return jnp.moveaxis(x, 2, 1)            # [B, T, G, d] <-> [B, G, T, d]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _kernel_attention(q, k, v, table, block):
+    return _kernel_attention_fwd(q, k, v, table, block)[0]
+
+
+def _kernel_attention_fwd(q, k, v, table, block):
+    G = k.shape[2]
+    how = {"n": q.shape[2] // G, "block": block, "interpret": _INTERPRET}
+    qt, kt, vt = _token_major(q, G), _group_major(k), _group_major(v)
+    o, lse = _kernel_fwd(table, qt, kt, vt, **how)
+    # named as the flash kernels' residuals: what a remat policy saves so
+    # that the backward does not run the forward kernel again
+    o = checkpoint_name(o, "flash_o")
+    lse = checkpoint_name(lse, "flash_lse")
+    return _head_minor(o, q.shape[1]), (qt, kt, vt, table, o, lse)
+
+
+def _kernel_attention_bwd(block, res, do):
+    qt, kt, vt, table, o, lse = res
+    G, T = kt.shape[1], kt.shape[2]
+    dq, dk, dv = _kernel_bwd(
+        table, qt, kt, vt, o, lse, _token_major(do.astype(qt.dtype), G),
+        n=qt.shape[2] // T, block=block, interpret=_INTERPRET)
+    return (_head_minor(dq, T), _group_major(dk).astype(kt.dtype),
+            _group_major(dv).astype(vt.dtype), None)
+
+
+_kernel_attention.defvjp(_kernel_attention_fwd, _kernel_attention_bwd)
+
+
+def sparse_attention(q, k, v, table, block):
+    """``o`` [B, T, H, d]: query head ``h`` at token ``t`` attends, under
+    ONE softmax with scores ``q . k / sqrt(d)``, the keys ``s <= t`` of
+    the blocks (``block`` keys each) its token chose for its group ``h //
+    (H / G)``, as ``table`` (:func:`select_blocks`) has them. ``q`` [B,
+    T, H, d], ``k``, ``v`` [B, T, G, d]. Every token has to have chosen
+    a block that holds a key at or before it (its own: the selection
+    forces it). Differentiable in ``q``, ``k``, ``v``."""
+    if use_pallas("sparse_attention", (q, k, v), _INTERPRET):
+        return _kernel_attention(q, k, v, table, block)
+    return _masked_attention(q, k, v, table, block)

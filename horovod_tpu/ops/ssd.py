@@ -37,7 +37,10 @@ One recurrence, two carriers; which runs is read off the operands
   leave them, ``[B, T, H P]`` with tokens on the sublanes; a step works
   on slabs of 128 lanes (two heads of 64 channels side by side: one
   matmul serves both, and what differs a head is chosen by a select on
-  the lane). ``dt`` and ``gamma`` cross HBM lane-dense, ``[B, H, T / L,
+  the lane; a head of 128 channels is a slab alone, and where a group
+  is ONE head, Lightning Attention's ``G = H``, a step holds that head
+  and its ``dB`` / ``dC`` leave in the operands' dtype, with no
+  float32 parts to sum). ``dt`` and ``gamma`` cross HBM lane-dense, ``[B, H, T / L,
   L]`` float32, a sequence's block staying in VMEM, and are turned down
   the sublanes in the kernel (``gated_delta_rule._down``). The forward
   writes ``y`` and, where they are kept, the state each chunk STARTED
@@ -48,7 +51,9 @@ One recurrence, two carriers; which runs is read off the operands
   ``dgamma``, ``dB``, ``dC`` (the last two summed over a step's heads).
   Each kernel sits behind ONE jitted function, so that a program lowers
   the forward twice and the backward once whatever its depth
-  (``ops/gated_delta_rule.py`` says why);
+  (``ops/gated_delta_rule.py`` says why); the device scope its
+  instructions carry is the calling mixer's (``where``: a Mamba-2
+  layer's ``hvd.ssd.core``, a lightning layer's ``hvd.lightning.core``);
 - elsewhere: ``_scan_core``, a ``lax.scan`` over chunks of the same
   chunked form in ``jax.numpy`` under a ``custom_vjp`` that keeps the
   chunk-boundary states and runs one reverse pass (the CPU's path and
@@ -350,8 +355,8 @@ def _bwd_kernel(x_ref, dt_ref, gamma_ref, b_ref, c_ref, states_ref, dy_ref,
     # through p = (C B^T) * decay
     dp = jnp.stack(dps) * t.decay                          # [hb, L, L]
     dcb = jnp.sum(dp, axis=0).astype(dtype)
-    db_ref[...] = dB + _mm("ij,ik->jk", dcb, Cm)
-    dc_ref[...] = dC + _mm("ij,jk->ik", dcb, Bm)
+    db_ref[...] = (dB + _mm("ij,ik->jk", dcb, Cm)).astype(db_ref.dtype)
+    dc_ref[...] = (dC + _mm("ij,jk->ik", dcb, Bm)).astype(dc_ref.dtype)
     m = dp * cb
     dgamma_ref[:, row, :] = _along(jnp.stack(dgs) + _rowsum(m)) \
         - jnp.sum(m, axis=-2, keepdims=True)
@@ -395,14 +400,17 @@ def _specs(shape, N, hb, P, G, L, at):
 
 
 @functools.partial(jax.jit, static_argnames=("keep", "hb", "P", "G", "L",
-                                             "interpret"))
-def _kernel_fwd(x, dt, gamma, Bm, Cm, *, keep, hb, P, G, L, interpret):
+                                             "interpret", "where"))
+def _kernel_fwd(x, dt, gamma, Bm, Cm, *, keep, hb, P, G, L, interpret,
+                where="hvd.ssd.core"):
     """``x`` [B, T, H P]; ``dt``, ``gamma`` [B, H, T / L, L] float32;
     ``Bm``, ``Cm`` [B, T, G N] -> [``y`` like ``x``], and with ``keep``
     the state every chunk started from, [T / L, B, H P, N] float32.
     Jitted on its own: every site that enters it with these shapes calls
-    ONE lowered function (``gated_delta_rule._kernel_fwd``)."""
-    with scope("hvd.ssd.core"):
+    ONE lowered function (``gated_delta_rule._kernel_fwd``), whose
+    instructions carry the scope ``where`` (the mixer's: a Mamba-2
+    layer's ``hvd.ssd.core``, a lightning layer's its own)."""
+    with scope(where):
         B, T, HP = x.shape
         N = Bm.shape[-1] // G
         grid, wide, rows, token, kept, _ = _specs(
@@ -417,18 +425,22 @@ def _kernel_fwd(x, dt, gamma, Bm, Cm, *, keep, hb, P, G, L, interpret):
 
 
 @functools.partial(jax.jit, static_argnames=("hb", "P", "G", "L",
-                                             "interpret"))
+                                             "interpret", "where"))
 def _kernel_bwd(x, dt, gamma, Bm, Cm, states, dy, *, hb, P, G, L,
-                interpret):
+                interpret, where="hvd.ssd.core"):
     """-> (dx, ddt, dgamma, dBm, dCm) in their operands' shapes and
     dtypes; a group's ``dBm`` / ``dCm`` summed over its steps here."""
-    with scope("hvd.ssd.core"):
+    with scope(where):
         B, T, HP = x.shape
         steps = HP // P // hb
         N = Bm.shape[-1] // G
         grid, wide, rows, token, kept, part = _specs(
             x.shape, N, hb, P, G, L, lambda n: T // L - 1 - n)
-        parts = jax.ShapeDtypeStruct((B, T, steps * N), F32)
+        # a step that holds a whole group (Lightning Attention: a group a
+        # head) writes the group's dB / dC as they are; a group of
+        # several steps sums its float32 parts below
+        parts = jax.ShapeDtypeStruct(
+            (B, T, steps * N), Bm.dtype if steps == G else F32)
         dx, ddt, dgamma, dB, dC = _call(
             "hvd_ssd_bwd", functools.partial(_bwd_kernel, P=P),
             (x, dt, gamma, Bm, Cm, states, dy.astype(x.dtype)), grid,
@@ -440,18 +452,20 @@ def _kernel_bwd(x, dt, gamma, Bm, Cm, states, dy, *, hb, P, G, L,
             (hb * P, N), interpret)
 
         def group(d):
+            if steps == G:
+                return d
             return d.reshape(B, T, G, steps // G, N).sum(3).reshape(
                 Bm.shape).astype(Bm.dtype)
 
         return dx, ddt, dgamma, group(dB), group(dC)
 
 
-def _step(x, Bm, L):
+def _step(x, Bm, L, where="hvd.ssd.core"):
     """What a grid step takes of these operands [B, T, H, P] / [B, T, G,
-    N], and how it runs."""
+    N], and how it runs: the kernels' static arguments."""
     H, G = x.shape[2], Bm.shape[2]
     return {"hb": _pick_block(H // G, HEADS_A_STEP), "P": x.shape[3],
-            "G": G, "L": L, "interpret": _INTERPRET}
+            "G": G, "L": L, "interpret": _INTERPRET, "where": where}
 
 
 def _rows(gate, L):
@@ -474,22 +488,25 @@ def _flat(x, dt, gamma, Bm, Cm, L):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
-def _kernel_core(x, dt, gamma, Bm, Cm, chunk):
-    """``_scan_core`` on the kernel pair: same operands, same ``y``."""
-    return _kernel_fwd(*_flat(x, dt, gamma, Bm, Cm, chunk), keep=False,
-                       **_step(x, Bm, chunk))[0].reshape(x.shape)
+def _kernel_core(x, dt, gamma, Bm, Cm, how):
+    """``_scan_core`` on the kernel pair: same operands, same ``y``.
+    ``how``: :func:`_step`'s word for these operands, its items."""
+    how = dict(how)
+    return _kernel_fwd(*_flat(x, dt, gamma, Bm, Cm, how["L"]), keep=False,
+                       **how)[0].reshape(x.shape)
 
 
-def _kernel_core_fwd(x, dt, gamma, Bm, Cm, chunk):
-    flat = _flat(x, dt, gamma, Bm, Cm, chunk)
-    y, states = _kernel_fwd(*flat, keep=True, **_step(x, Bm, chunk))
+def _kernel_core_fwd(x, dt, gamma, Bm, Cm, how):
+    how = dict(how)
+    flat = _flat(x, dt, gamma, Bm, Cm, how["L"])
+    y, states = _kernel_fwd(*flat, keep=True, **how)
     return y.reshape(x.shape), (x, Bm, flat, states)
 
 
-def _kernel_core_bwd(chunk, res, dy):
+def _kernel_core_bwd(how, res, dy):
     x, Bm, flat, states = res
     dx, ddt, dgamma, dB, dC = _kernel_bwd(
-        *flat, states, dy.reshape(flat[0].shape), **_step(x, Bm, chunk))
+        *flat, states, dy.reshape(flat[0].shape), **dict(how))
     return (dx.reshape(x.shape), _tokens(ddt), _tokens(dgamma),
             dB.reshape(Bm.shape), dC.reshape(Bm.shape))
 
@@ -497,14 +514,20 @@ def _kernel_core_bwd(chunk, res, dy):
 _kernel_core.defvjp(_kernel_core_fwd, _kernel_core_bwd)
 
 
-def ssd(x, dt, A, Bm, Cm, D, chunk=CHUNK):
+def ssd(x, dt, A, Bm, Cm, D, chunk=CHUNK, where="hvd.ssd.core"):
     """``y`` [B, T, H, P], in ``x``'s dtype, of the recurrence above for
     ``x`` [B, T, H, P] (the convolved, activated input by heads), the
     step sizes ``dt`` [B, T, H] (positive: after the softplus), the
     decay rates ``A`` [H] (negative), a token's input and output maps
     ``Bm``, ``Cm`` [B, T, G, N], one a group of ``H / G`` consecutive
-    heads, and the skip ``D`` [H]. ``T`` is a multiple of ``chunk``.
-    Differentiable in all six."""
+    heads, and the skip ``D`` [H] (None: no skip, and no pass over ``x``
+    for it). ``T`` is a multiple of ``chunk``. Differentiable in all six.
+    Lightning Attention's layer (arXiv:2401.04658) is this recurrence
+    with ``x = v``, ``Bm = k``, ``Cm = q / sqrt(d)``, ``dt = 1``, ``A`` =
+    minus the head's decay rate, no ``D``, a group a head and ``P = N``
+    = the head's width: a step of the kernels then holds one head, whose
+    channels fill a 128-lane slab alone. ``where``: the device scope the
+    kernels' instructions carry."""
     B, T, H, P = x.shape
     G = Bm.shape[2]
     if T % chunk:
@@ -515,12 +538,16 @@ def ssd(x, dt, A, Bm, Cm, D, chunk=CHUNK):
     if H % G:
         raise ValueError(f"{H} heads are no multiple of {G} groups: each "
                          "group's B and C serve a whole number of heads")
-    dt, A, D = (a.astype(F32) for a in (dt, A, D))
+    dt, A = dt.astype(F32), A.astype(F32)
     gamma = jnp.cumsum((dt * A).reshape(B, T // chunk, chunk, H),
                        axis=2).reshape(B, T, H)
     operands = (x, dt, gamma, Bm.astype(x.dtype), Cm.astype(x.dtype))
     if use_pallas("ssd", operands, _INTERPRET):
-        y = _kernel_core(*operands, chunk)
+        y = _kernel_core(*operands,
+                         tuple(_step(x, Bm, chunk, where).items()))
     else:
         y = _scan_core(*operands, chunk)
-    return (y.astype(F32) + D[:, None] * x.astype(F32)).astype(x.dtype)
+    if D is None:
+        return y
+    return (y.astype(F32) + D.astype(F32)[:, None] * x.astype(F32)
+            ).astype(x.dtype)

@@ -105,7 +105,20 @@ def holds_two_gradients(params, opt):
     cells' read 1.2 gradients' worth, Mistral's 2.75 GB, to 1.7, 4.67 GB
     beside 1.38 B parameters of one-part layers, which counted as one
     filled the chip to 16.89 GB and made every enqueue wait for the
-    apply: PERF.md section 6, PR 50). Read
+    apply: PERF.md section 6, PR 50). The count is a guess from sizes
+    and cannot see the batch, so a twentieth of the device is kept for
+    what it misses: at 32,768 tokens a sequence the temporaries read
+    2.7 gradients' worth (6.43 GB beside 1.18 B parameters), the count
+    came to 98.1% of the device, the chip filled to 16.79 of 16.91 GB,
+    every enqueue waited and one run in eight held steps of twice the
+    time. The twentieth is a margin, not a measurement: it stands
+    between that cell and the nearest one that does hold two sets
+    (94.5%, fills 16.48 GB, the same program on either side of this
+    change: PERF.md section 6, PR 55), and what would replace the guess
+    is the compiled grad program's own ``memory_analysis`` (PERF.md
+    section 7: it costs a second compile where the guess was wrong, and
+    the adapters that ask this function have to be told the answer).
+    Read
     off the device's own account (``memory_stats()["bytes_limit"]``); a
     device that gives none (the CPU), or parameters that are being
     traced, hold whatever is asked of them."""
@@ -118,7 +131,7 @@ def holds_two_gradients(params, opt):
     def size(tree):
         return sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(tree))
 
-    return size(params) * 5 + size(opt) <= stats["bytes_limit"]
+    return size(params) * 5 + size(opt) <= 0.95 * stats["bytes_limit"]
 
 
 # Gradient buffers that nothing reads any more, by what they hold (tree

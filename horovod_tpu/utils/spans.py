@@ -38,6 +38,8 @@ SCOPES = frozenset({
     "hvd.attn.core", "hvd.conv.proj", "hvd.conv.chain", "hvd.gdn.proj",
     "hvd.gdn.chain", "hvd.gdn.core", "hvd.ssm.proj", "hvd.ssm.chain",
     "hvd.ssm.core", "hvd.ssd.proj", "hvd.ssd.chain", "hvd.ssd.core",
+    "hvd.sparse.select", "hvd.sparse.core", "hvd.lightning.chain",
+    "hvd.lightning.core",
     "hvd.ffn", "hvd.moe.route", "hvd.moe.dispatch", "hvd.moe.experts",
     "hvd.moe.combine", "hvd.moe.latent", "hvd.mtp", "hvd.head", "hvd.loss", "hvd.apply",
     "hvd.allreduce", "hvd.cnn.stem", "hvd.cnn.stage1", "hvd.cnn.stage2",

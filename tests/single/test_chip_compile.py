@@ -525,6 +525,154 @@ def test_five_layers_of_the_ssd_lower_each_kernel_once(v5e_chip, for_tpu):
     assert text.count("call @_kernel_bwd") == 5
 
 
+# MiniCPM-SALA's two mixers at the chip cell's size: B1 T32768, the
+# sparse layer's 32 query heads on 2 key/value heads of 128 with its
+# table (a word a tile of 16 tokens and block of 64 keys), the lightning
+# layer's 32 heads of 128 x 128 states, a group a head.
+_SPARSE_KERNELS = ("hvd_sparse_attn_fwd", "hvd_sparse_attn_bwd")
+_SALA_T = 32768
+_SPARSE = (((1, _SALA_T, 32, 128), BF16), ((1, _SALA_T, 2, 128), BF16),
+           ((1, _SALA_T, 2, 128), BF16),
+           ((1, 2, _SALA_T // 16, _SALA_T // 64), I32))
+_LIGHTNING = (((1, _SALA_T, 32, 128), BF16),) * 3
+_SELECTION = dict(block=64, topk=64, kernel=32, stride=16, init_blocks=1,
+                  window_blocks=32)
+
+
+def _sparse_fwd_bwd(q, k, v, table):
+    from horovod_tpu.ops.sparse_attention import sparse_attention
+
+    return jax.value_and_grad(
+        lambda q, k, v: sparse_attention(q, k, v, table, 64).astype(
+            F32).sum(), argnums=(0, 1, 2))(q, k, v)
+
+
+def _select(q, k):
+    from horovod_tpu.ops.sparse_attention import select_blocks
+
+    return select_blocks(q, k, **_SELECTION)
+
+
+def _lightning_fwd_bwd(q, k, v):
+    from horovod_tpu.ops.ssd import ssd
+
+    rates = -jnp.exp2(-8.0 * (jnp.arange(32, dtype=F32) + 1.0) / 32)
+    return jax.value_and_grad(
+        lambda q, k, v: ssd(v, jnp.ones(v.shape[:3], F32), rates, k, q,
+                            None, 128, "hvd.lightning.core").astype(
+            F32).sum(), argnums=(0, 1, 2))(q, k, v)
+
+
+def test_sparse_attention_compiles_for_described_v5e(for_tpu):
+    """The sparse pair at the chip cell's size as the chip's compiler
+    takes it: a group's keys and values whole in VMEM (the backward their
+    float32 gradients too), the table's row in SMEM, each kernel by the
+    name a device trace shows; no [T, T] plane and no dense mask exists;
+    and the selection beside it compiles with no kernel at all."""
+    text = for_tpu(_sparse_fwd_bwd, *_SPARSE)
+    for name in _SPARSE_KERNELS:
+        assert f'"kernel":"{name}"' in text, name
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 2
+    assert not re.search(r"\[(1,)?(2,|32,)?32768,(2,|16,|32,)*32768\]", text)
+    # the row statistics cross HBM lane-dense, a tile's a row
+    assert "f32[1,2,2048,1,256]" in text
+    text = for_tpu(_select, *_SPARSE[:2])
+    assert "tpu_custom_call" not in text
+    assert "s32[1,2,2048,512]" in text                # the table
+    assert not re.search(r"\[(1,)?32768,(2,)?(16,)?2047\]", text)
+
+
+def test_the_ssd_pair_takes_a_head_a_group_for_described_v5e(for_tpu):
+    """Lightning Attention's shapes through ``ops/ssd.py``: 32 heads of
+    128 channels x 128 states, a group a head, ``dt`` = 1, no ``D``: a
+    step holds one head, ``dB`` / ``dC`` leave in bf16 (no float32 [T,
+    32 x 128] parts), the states kept are the 256 chunks'."""
+    text = for_tpu(_lightning_fwd_bwd, *_LIGHTNING)
+    for name in _SSD_KERNELS:
+        assert f'"kernel":"{name}"' in text, name
+    assert " while(" not in text
+    assert "f32[256,1,4096,128]" in text          # the states kept
+    # what the backward kernel returns: dx, dB, dC in bf16, the gates'
+    # gradients lane-dense in float32, and no float32 [T, 32 x 128] part
+    at = text.index('"kernel":"hvd_ssd_bwd"')
+    head = text[text.rfind(" = (", 0, at):at]
+    result = head[:head.index("custom-call(")]
+    assert result.count("bf16[1,32768,4096]") == 3, result
+    assert "f32[1,32768,4096]" not in result
+
+
+def test_four_layers_of_sala_lower_each_kernel_once_a_direction(v5e_chip,
+                                                                for_tpu):
+    """A whole published period (a sparse layer, three lightning layers
+    under the layer scan) at the cell's sequence and a narrow model: the
+    grad program lowers the sparse pair once each and the SSD pair once
+    a direction (the forward twice: keeping the chunks' states, and
+    not), whatever the depth."""
+    from horovod_tpu.models import LlamaConfig, llama_init, llama_loss
+
+    cfg = LlamaConfig(
+        vocab_size=512, d_model=256, n_layers=4, n_heads=32, n_kv_heads=2,
+        d_head=128, d_ff=512, norm_eps=1e-6, rope_theta=10000.0,
+        layer_types=("sparse_attention",) + ("lightning_attention",) * 3,
+        qk_norm="head", attn_gate=True, embed_mult=12.0,
+        residual_mult=1.4 / 32 ** 0.5, logit_div=16.0, lightning_heads=32,
+        lightning_head_dim=128, lightning_chunk=128, lightning_depth=32,
+        sparse_block=64, sparse_topk=64, sparse_kernel=32, sparse_stride=16,
+        sparse_init_blocks=1, sparse_window_blocks=32,
+        sparse_dense_len=8192, ffn_chunk=2048, loss_chunk=2048,
+        dtype="bfloat16", param_dtype="bfloat16", remat="attn")
+    shapes = jax.eval_shape(lambda k: llama_init(cfg, k),
+                            jax.random.PRNGKey(0))
+    params = jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+        s.shape, s.dtype, sharding=v5e_chip), shapes)
+    batch = {k: jax.ShapeDtypeStruct((1, _SALA_T), I32, sharding=v5e_chip)
+             for k in ("tokens", "targets")}
+    text = jax.jit(jax.grad(lambda p, b: llama_loss(p, b, cfg))).lower(
+        params, batch).as_text()
+    assert [text.count(name) for name in _SPARSE_KERNELS] == [1, 1]
+    assert [text.count(name) for name in _SSD_KERNELS] == [2, 1]
+    assert "hvd_flash" not in text      # past the dense length: no flash
+    # a sequence at the dense length runs the flash kernels instead
+    short = {k: jax.ShapeDtypeStruct((1, 8192), I32, sharding=v5e_chip)
+             for k in batch}
+    text = jax.jit(jax.grad(lambda p, b: llama_loss(p, b, cfg))).lower(
+        params, short).as_text()
+    assert "hvd_flash_fwd" in text and "hvd_sparse_attn" not in text
+
+
+@pytest.mark.slow
+def test_the_sala_cells_grad_program_fits_the_described_v5e(v5e_chip,
+                                                            for_tpu):
+    """The cell's grad program at [1, 32768] with the file's ``remat``,
+    ``ffn_chunk``, ``loss_chunk`` and chunk of the recurrence compiles
+    for the described v5e within the 15.75 GiB its programs get, Adam's
+    two moments beside it, and to the peak the file records. Two minutes
+    and more of one core: not in tier-1, whose clock has 100 s to spare
+    (CHANGES.md, PR 55); every run of the cell on the chip proves the
+    fit again."""
+    sys.path.insert(0, REPO)
+    from chipbench import child
+
+    _, _, config, traffic = child.find_cell("minicpmsala.spmd.b1s32768")
+    model = child.load_file("models", "minicpmsala").Model(config, traffic)
+    shapes = jax.eval_shape(lambda k: model.init(k)[0],
+                            jax.random.PRNGKey(0))
+    params = jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+        s.shape, s.dtype, sharding=v5e_chip), shapes)
+    batch = {k: jax.ShapeDtypeStruct((1, _SALA_T), I32, sharding=v5e_chip)
+             for k in ("tokens", "targets")}
+    compiled = jax.jit(
+        lambda p, d: jax.value_and_grad(
+            lambda p, d: model.loss(p, (), d)[0])(p, d),
+        compiler_options=model.compiler_options).lower(
+        params, batch).compile()
+    peak = compiled.memory_analysis().peak_memory_in_bytes
+    moments = 2 * sum(s.size * s.dtype.itemsize
+                      for s in jax.tree.leaves(shapes))
+    assert peak + moments < 15.75 * 2 ** 30
+    assert abs(peak / 1e9 - config["assumed"]["compiled_peak_gb"]) < 0.2
+
+
 _CHAIN_KERNELS = ("hvd_gdn_chain_in_fwd", "hvd_gdn_chain_in_bwd",
                   "hvd_gdn_chain_out_fwd", "hvd_gdn_chain_out_bwd")
 # Qwen3-Next's linear mixer at the chip cell's size: B2 T8192, 16 key

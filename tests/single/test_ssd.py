@@ -131,6 +131,23 @@ def test_heads_as_wide_as_the_lanes(kernels):
     assert max(err.values()) < TOL["float32"], err
 
 
+def test_a_head_a_group_a_unit_step_and_no_skip(kernels):
+    """Lightning Attention's shapes: ``P = N = 128``, a group a head
+    (``hb`` = 1: a step's ``dB`` / ``dC`` are the group's and leave in
+    the operands' dtype), ``dt = 1`` and no ``D``: the kernel pair is the
+    recurrence, and ``D = None`` is ``D = 0`` without its pass."""
+    x, _, A, Bm, Cm, _, w = _operands(256, F32, b=1, h=2, p=128, g=2,
+                                      n=128, seed=4)
+    one, none = jnp.ones(x.shape[:3], F32), jnp.zeros((2,), F32)
+    Cm = Cm / 128 ** 0.5
+    got = _readings(lambda x, dt, A, Bm, Cm, D, chunk: ssd(
+        x, dt, A, Bm, Cm, None, chunk), 128)(x, one, A / 16, Bm, Cm, none, w)
+    ref = _readings(token_by_token, None)(x, one, A / 16, Bm, Cm, none, w)
+    err = _errs(got[:6], ref[:6])
+    assert max(err.values()) < TOL["float32"], err
+    assert float(jnp.abs(got[6]).max()) == 0.0       # no D, no gradient
+
+
 @pytest.mark.parametrize("case, match", [
     (dict(t=100), "no multiple"),
     (dict(t=128, h=3, g=2), "groups")])
