@@ -32,6 +32,17 @@ token ``t`` and group ``g``:
    ``window_blocks`` begun ones, the token's own among them; ``Sel`` =
    the forced blocks and the best scoring others up to ``topk`` in all,
    every begun block where fewer have begun. Ties: the lower index.
+   Nothing reads the ORDER of the chosen, so nothing is sorted
+   (:func:`_largest`): the set is fixed by the row's ``topk``-th largest
+   key and by how many of the keys equal to it belong. The keys' bits,
+   read as integers, keep the keys' order; the ``topk``-th largest is
+   the largest threshold that ``topk`` keys reach, found a bit a pass
+   from the top in 32 dense compare-and-count passes whatever ``topk``
+   is; the keys above it are chosen, and of the keys AT it the first by
+   index until the set is full, which is a running count along the row
+   and what sends a tie to the lower index (the forced keys tie by
+   construction, ``init_blocks + window_blocks`` of them a row). A
+   sequence of at most ``topk`` blocks needs no pass: every begun block.
 
 What leaves the selection is the table the kernels read: for a TILE of
 ``TOKENS_A_TILE`` consecutive tokens and a key block, one int32 whose
@@ -157,13 +168,54 @@ def _rows_chosen(q, kbar, t0, *, block, topk, kernel, stride, init_blocks,
     begun = b <= own
     forced = begun & ((b < init_blocks) | (b > own - window_blocks))
     # forced first, then the begun by score, the not begun never; a
-    # begun block with no admitted window is forced (step 4)
+    # begun block with no admitted window is forced (step 4). The set is
+    # the ``topk`` largest of these keys, equal keys to the lower index:
+    # the forced tie at +inf and fill the set from the first block up
+    # before any score is asked, begun blocks without a score tie at -1.0
+    # behind every score, and -inf is never chosen.
     key = jnp.where(forced[:, None, :], jnp.inf,
                     jnp.where(begun[:, None, :], jnp.maximum(score, -1.0),
                               -jnp.inf))
-    value, index = lax.top_k(key, min(topk, n_blocks))   # ties: lower index
-    picked = (index[..., None] == b) & (value[..., None] > -jnp.inf)
-    return jnp.any(picked, -2)
+    # the group beside the batch: a row's blocks along the lanes and the
+    # ROWS down the sublanes (2 groups there fill a quarter of a vreg:
+    # the selection alone 81 ms at the chip cell's size where this takes
+    # 50, PERF.md section 6, PR 56)
+    return jnp.moveaxis(_largest(jnp.moveaxis(key, 2, 1), topk), 1, 2)
+
+
+def _largest(key, count):
+    """bool like ``key`` [..., n] float32: which of a row's keys are among
+    its ``count`` largest, of equal keys those at the lower indices, a key
+    of -inf never: a top-k's set without its order, by counting (step 5
+    of the module's description) where the chip's top-k sorts keys and
+    indices whole."""
+    n = key.shape[-1]
+    if count >= n:
+        return key > -jnp.inf
+    # the floats' order as int32's: -inf < ... < -0.0 < +0.0 < ... < +inf
+    bits = lax.bitcast_convert_type(key, jnp.int32)
+    image = jnp.where(bits < 0, bits ^ jnp.int32(0x7FFFFFFF), bits)
+
+    def narrow(i, t):
+        # ``t``: the largest threshold found so far that ``count`` keys
+        # reach. In the order's unsigned form (the sign bit flipped) it
+        # grows a bit at a time from 0, which is int32's lowest.
+        higher = t ^ lax.shift_left(jnp.int32(1), 31 - i)
+        reach = jnp.sum(image >= higher[..., None], -1, dtype=jnp.int32)
+        return jnp.where(reach >= count, higher, t)
+
+    lowest = jnp.full(key.shape[:-1], jnp.iinfo(jnp.int32).min, jnp.int32)
+    t = lax.fori_loop(0, 32, narrow, lowest)[..., None]
+    above, tied = image > t, image == t
+    # A key's place among its row's tied ones, itself counted: a product
+    # with a triangle of ones on the MXU (0 / 1 operands and sums up to
+    # ``n`` are exact). The first ``count - above`` of them fill the set.
+    upto = jnp.tri(n, dtype=jnp.bfloat16).T             # [s, b]: s <= b
+    place = jnp.einsum("...s,sb->...b", tied.astype(jnp.bfloat16), upto,
+                       preferred_element_type=F32)
+    room = count - jnp.sum(above, -1, keepdims=True, dtype=jnp.int32)
+    return (above | (tied & (place <= room.astype(F32)))) \
+        & (key > -jnp.inf)
 
 
 def _pack(chosen):

@@ -568,7 +568,8 @@ def test_sparse_attention_compiles_for_described_v5e(for_tpu):
     takes it: a group's keys and values whole in VMEM (the backward their
     float32 gradients too), the table's row in SMEM, each kernel by the
     name a device trace shows; no [T, T] plane and no dense mask exists;
-    and the selection beside it compiles with no kernel at all."""
+    and the selection beside it compiles with no kernel at all and no
+    sort (its 64 of 512 blocks are a threshold found by counting)."""
     text = for_tpu(_sparse_fwd_bwd, *_SPARSE)
     for name in _SPARSE_KERNELS:
         assert f'"kernel":"{name}"' in text, name
@@ -578,6 +579,7 @@ def test_sparse_attention_compiles_for_described_v5e(for_tpu):
     assert "f32[1,2,2048,1,256]" in text
     text = for_tpu(_select, *_SPARSE[:2])
     assert "tpu_custom_call" not in text
+    assert not re.search(r"\bsort\b", text)
     assert "s32[1,2,2048,512]" in text                # the table
     assert not re.search(r"\[(1,)?32768,(2,)?(16,)?2047\]", text)
 

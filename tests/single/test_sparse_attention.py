@@ -114,6 +114,90 @@ def test_the_selection_by_hand():
         True, True, False, False, False, False]
 
 
+def _top_k_sets(key, count):
+    """The oracle: ``lax.top_k``'s values and indices turned into sets
+    (ties to the lower index, -inf never), as the selection formed them
+    before it counted."""
+    value, index = jax.lax.top_k(key, min(count, key.shape[-1]))
+    picked = (index[..., None] == jnp.arange(key.shape[-1])) \
+        & (value[..., None] > -jnp.inf)
+    return jnp.any(picked, -2)
+
+
+def _keys(case, rows=24, n=32, count=8):
+    """-> (keys [1, rows, 2, n] float32 as ``_rows_chosen`` forms them:
+    +inf the forced, -inf the not begun, scores floored at -1.0; how many
+    to choose)."""
+    rng = np.random.default_rng(sum(map(ord, case)))
+    key = (rng.random((1, rows, 2, n)) * 16).astype(np.float32)
+    r = np.arange(rows)[:, None, None]
+    at = np.arange(n)
+    if case.startswith("forced_run_"):
+        # a run of +inf (the window) behind a lone one (the first block)
+        length = {"shorter": count - 3, "equal": count - 1,
+                  "longer": count + 4}[case[len("forced_run_"):]]
+        start = 2 + r % (n - length - 2)
+        key[0] = np.where((at >= start) & (at < start + length), np.inf,
+                          key[0])
+        key[..., 0] = np.inf
+    elif case == "equal_scores_across_the_place":
+        key = np.round(key / 4)                  # five values, many of each
+    elif case == "the_floor_across_the_place":
+        # a few scores over the floor, then the not begun
+        key[0] = np.where(at < r % count, key[0], -1.0)
+        key[0, :, :, :n - 6] = key[0, :, :, rng.permutation(n - 6)].transpose(
+            1, 2, 0)
+        key[..., n - 6:] = -np.inf
+    elif case == "fewer_begun_than_chosen":
+        key[0] = np.where(at <= r % (count - 1), key[0], -np.inf)
+        key[0, :, :, 0] = np.inf
+    elif case == "as_many_chosen_as_blocks":
+        count = n
+        key[0] = np.where(at <= r, key[0], -np.inf)
+    elif case == "more_chosen_than_blocks":
+        count = 2 * n
+        key[0] = np.where(at <= r, key[0], -np.inf)
+    elif case == "a_negative_zero_beside_a_zero":
+        zero = np.where(rng.random(key.shape) < 0.5, 0.0, -0.0)
+        key = np.where(rng.random(key.shape) < 0.2, key, zero).astype(
+            np.float32)
+        key[..., n - 6:] = -np.inf
+    else:
+        assert case == "random_scores", case
+    return jnp.asarray(key), count
+
+
+@pytest.mark.parametrize("case", [
+    "random_scores", "forced_run_shorter", "forced_run_equal",
+    "forced_run_longer", "equal_scores_across_the_place",
+    "the_floor_across_the_place", "fewer_begun_than_chosen",
+    "as_many_chosen_as_blocks", "more_chosen_than_blocks",
+    "a_negative_zero_beside_a_zero"])
+def test_the_counted_threshold_chooses_what_top_k_chose(case):
+    """``_largest`` finds a row's set from its ``count``-th largest key,
+    by counting; the set is ``lax.top_k``'s bit for bit: on forced runs
+    shorter than, as long as and longer than the number to choose, on
+    equal scores and on the floor of -1.0 across the last place (the
+    lower index wins), where fewer blocks have begun than are chosen,
+    where every block is, and on the two zeros (the order is the bits':
+    -0.0 below +0.0)."""
+    key, count = _keys(case)
+    got = np.asarray(jax.jit(lambda k: sa._largest(k, count))(key))
+    want = np.asarray(jax.jit(lambda k: _top_k_sets(k, count))(key))
+    assert got.dtype == bool and got.shape == key.shape
+    assert (got == want).all(), np.argwhere((got != want).any(-1))
+    # the cases are what they say: ties stand across the last place, and
+    # a row never holds more than it may
+    assert (got.sum(-1) <= count).all()
+    assert not got[np.asarray(key) == -np.inf].any()
+    if "across_the_place" in case or "zero" in case:
+        k = np.asarray(key)
+        last = np.sort(k, -1)[..., -count, None]
+        assert ((k == last).sum(-1) > 1).any()
+        assert (((k == last) & ~got).any(-1) & ((k == last) & got).any(-1)
+                ).any()
+
+
 def test_the_table_packs_and_unpacks():
     sel = jax.random.bernoulli(jax.random.PRNGKey(0), 0.3, (2, 64, 2, 4))
     table = sa._pack(sel)
