@@ -54,6 +54,18 @@ def require_decodable(c):
     an output gate, ...) are training only until the cache and this
     module have them: refuse, never ignore one."""
     fields = c.training_only_fields()
+    if c.kv_lora_rank:
+        raise ValueError(
+            f"LlamaConfig fields {fields}: latent attention is training "
+            "only. Decode lacks a cache of the latent and the shared "
+            "rotated key (serving/kvcache.py holds k and v a head) and "
+            "the absorbed form of the up-projections "
+            "(ops/decode_attention.py takes one width for q, k and v)")
+    if c.hc_mult:
+        raise ValueError(
+            f"LlamaConfig fields {fields}: hyper-connections are training "
+            "only. Prefill and cached decode carry ONE residual stream "
+            "[B, T, D] from layer to layer, not hc_mult of them")
     if fields:
         raise ValueError(
             f"LlamaConfig fields {fields} are training only "

@@ -341,6 +341,41 @@ class LlamaConfig:
     lightning_head_dim: int = 0
     lightning_chunk: int = 0
     lightning_depth: int = 0
+    # Multi-head latent attention (DeepSeek-V2, section 2.1; the
+    # training form: keys and values built a head from the latent), the
+    # mixer of EVERY attention layer where ``kv_lora_rank`` is set: the
+    # query through a latent ``q_lora_rank`` wide and the keys and
+    # values through one ``kv_lora_rank`` wide, each latent under an
+    # RMSNorm; a head's query and key are ``qk_nope_head_dim`` values
+    # without a position beside ``qk_rope_head_dim`` rotated ones, the
+    # rotated key ONE for all heads; a head's value is ``v_head_dim``
+    # wide (``_latent_attention``; ``d_head`` and ``n_kv_heads`` are not
+    # read). ``rope_yarn``: the rotated slice's frequencies and the
+    # softmax scale under YaRN, ``(factor,
+    # original_max_position_embeddings, beta_fast, beta_slow, mscale,
+    # mscale_all_dim)``; empty: ``rope_theta``'s own and ``1 / sqrt(qk
+    # width)``.
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    rope_yarn: tuple = ()
+    # Manifold-constrained hyper-connections (arXiv 2512.24880): the
+    # residual stream is ``hc_mult`` streams a token (0: one, ``x +
+    # part(norm x)``), [B, n, T, D]; round every part (a mixer or a
+    # feed-forward part with its pre-norm) three sets of coefficients a
+    # token, from the RMS-normed streams through learned projections,
+    # say what the part reads (``sigmoid``), where its output is added
+    # (``2 sigmoid``) and how the streams mix: a doubly stochastic ``n x
+    # n`` matrix, ``hc_sinkhorn_iters`` Sinkhorn-Knopp iterations (sums
+    # guarded by ``hc_eps``) over ``exp`` of the logits clamped to
+    # ``hc_clamp`` (``_hyper_connection``). The stream starts as ``n``
+    # copies of the embedding and ends as their sum.
+    hc_mult: int = 0
+    hc_sinkhorn_iters: int = 0
+    hc_eps: float = 0.0
+    hc_clamp: tuple = ()
 
     def __post_init__(self):
         if self.layer_types and len(self.layer_types) != self.n_layers:
@@ -379,6 +414,30 @@ class LlamaConfig:
         if self.ffn_chunk < 0:
             raise ValueError(f"ffn_chunk {self.ffn_chunk}: tokens a block "
                              "of the dense FFN, 0 for whole")
+        sizes = (self.q_lora_rank, self.kv_lora_rank, self.qk_nope_head_dim,
+                 self.qk_rope_head_dim, self.v_head_dim)
+        if any(sizes) != all(sizes) or self.qk_rope_head_dim % 2 or (
+                self.rope_yarn and not (self.kv_lora_rank
+                                        and len(self.rope_yarn) == 6)):
+            raise ValueError(
+                "latent attention's five sizes (q_lora_rank, kv_lora_rank, "
+                "qk_nope_head_dim, qk_rope_head_dim, v_head_dim) come "
+                f"together, the rotated slice in pairs: {sizes}; rope_yarn "
+                "is its six numbers, or empty")
+        if self.kv_lora_rank and (
+                self.qk_norm or self.attn_gate or self.partial_rotary
+                or self.sliding_window or any(
+                    t != "full_attention" for t in types)):
+            raise ValueError(
+                "latent attention is every layer's mixer, with norms on "
+                "its two latents and its own rotated slice: no qk_norm, "
+                "attn_gate, partial_rotary, window or other mixer")
+        sizes = (self.hc_mult, self.hc_sinkhorn_iters, self.hc_eps,
+                 len(self.hc_clamp) == 2)
+        if any(sizes) != all(sizes) or self.hc_mult == 1:
+            raise ValueError(
+                "hyper-connections' sizes (hc_mult > 1, hc_sinkhorn_iters, "
+                f"hc_eps, hc_clamp's two edges) come together: {sizes}")
         linear = "linear_attention" in types
         mamba, mamba2 = "mamba" in types, "mamba2" in types
         if ("conv" in types or linear or mamba or mamba2) \
@@ -484,6 +543,44 @@ class LlamaConfig:
         return self.d_head or self.d_model // self.n_heads
 
     @property
+    def qk_head_dim(self):
+        """The width of a latent-attention head's query and key."""
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    def yarn(self):
+        """Latent attention's rotation and scale under ``rope_yarn``
+        (Hugging Face's DeepSeek-V3 form) -> (``inv_freq`` float32
+        [qk_rope_head_dim / 2], what cos and sin are multiplied by, the
+        softmax scale). ``inv_freq`` blends ``theta^(-2i/d)`` (kept
+        where a dimension turns more than ``beta_fast`` times in the
+        original length) and the same over ``factor`` (where fewer than
+        ``beta_slow``) by a linear ramp between the two correction
+        dimensions; the scale is ``m^2 / sqrt(qk width)``, ``m = 0.1
+        mscale_all_dim ln(factor) + 1``."""
+        d = self.qk_rope_head_dim
+        inv = self.rope_theta ** (-np.arange(0, d, 2, dtype=np.float64) / d)
+        scale = self.qk_head_dim ** -0.5
+        if not self.rope_yarn:
+            return inv.astype(np.float32), 1.0, scale
+        factor, original, fast, slow, mscale, all_dim = self.rope_yarn
+
+        def correction_dim(turns):
+            return d * np.log(original / (turns * 2 * np.pi)) \
+                / (2 * np.log(self.rope_theta))
+
+        def m(scale):
+            return 0.1 * scale * np.log(factor) + 1.0 if factor > 1 else 1.0
+
+        low = max(np.floor(correction_dim(fast)), 0)
+        high = min(np.ceil(correction_dim(slow)), d - 1)
+        ramp = np.clip((np.arange(d // 2) - low)
+                       / ((high if high != low else high + 0.001) - low),
+                       0, 1)
+        inv = inv / factor * ramp + inv * (1 - ramp)
+        return (inv.astype(np.float32), float(m(mscale) / m(all_dim)),
+                float(scale * m(all_dim) ** 2 if all_dim else scale))
+
+    @property
     def mamba_d_inner(self):
         """A mamba layer's channels."""
         return self.mamba_expand * self.d_model
@@ -550,8 +647,11 @@ class LlamaConfig:
                      "sparse_attention": "sparse",
                      "lightning_attention": "lightning",
                      "experts": None}.get(kind, "attention")
-            dense_ffn = self.n_experts == 0 or i < self.n_dense_layers
-            stack = ("dense_" if i < self.n_dense_layers else "") \
+            # (the leading dense layers are the MODEL's: none in the MTP
+            # module, which no configuration had beside them before)
+            leading = not mtp and i < self.n_dense_layers
+            dense_ffn = self.n_experts == 0 or leading
+            stack = ("dense_" if leading else "") \
                 + ("" if mixer == "attention" else f"{mixer}_") + "layers"
             if self.one_part_layers:
                 dense_ffn = None if mixer else False
@@ -620,7 +720,11 @@ class LlamaConfig:
                             "sparse_init_blocks", "sparse_window_blocks",
                             "sparse_dense_len", "lightning_heads",
                             "lightning_head_dim", "lightning_chunk",
-                            "lightning_depth")
+                            "lightning_depth", "q_lora_rank",
+                            "kv_lora_rank", "qk_nope_head_dim",
+                            "qk_rope_head_dim", "v_head_dim", "rope_yarn",
+                            "hc_mult", "hc_sinkhorn_iters", "hc_eps",
+                            "hc_clamp")
                 if getattr(self, f) != getattr(d, f)] \
             + (["qk_norm"] if self.qk_norm == "head" else [])
 
@@ -796,6 +900,26 @@ def llama_init(config, key):
                 "wg": dense(next(x), (L, c.d_model, hw), c.d_model),
                 "mlp_norm": jnp.ones((L, c.d_model), pd),
             }
+        elif c.kv_lora_rank:
+            # latent attention: [q_nope | q_rope] a head behind the
+            # query's latent, [c_kv | k_rope] side by side out of the
+            # stream, [k_nope | v] a head behind the keys' and values'
+            rq, rkv, H = c.q_lora_rank, c.kv_lora_rank, c.n_heads
+            layers = {
+                "attn_norm": jnp.ones((L, c.d_model), pd),
+                "wq_a": dense(next(k), (L, c.d_model, rq), c.d_model),
+                "q_a_norm": jnp.ones((L, rq), pd),
+                "wq_b": dense(next(x), (L, rq, H * c.qk_head_dim), rq),
+                "wkv_a": dense(next(k),
+                               (L, c.d_model, rkv + c.qk_rope_head_dim),
+                               c.d_model),
+                "kv_a_norm": jnp.ones((L, rkv), pd),
+                "wkv_b": dense(next(x), (L, rkv, H * (
+                    c.qk_nope_head_dim + c.v_head_dim)), rkv),
+                "wo": dense(next(k), (L, H * c.v_head_dim, c.d_model),
+                            H * c.v_head_dim),
+                "mlp_norm": jnp.ones((L, c.d_model), pd),
+            }
         else:
             layers = {
                 "attn_norm": jnp.ones((L, c.d_model), pd),
@@ -822,6 +946,28 @@ def llama_init(config, key):
         if c.post_norm:
             layers["post_attn_norm"] = jnp.ones((L, c.d_model), pd)
             layers["post_mlp_norm"] = jnp.ones((L, c.d_model), pd)
+        if c.hc_mult:
+            # A part's three sets of coefficients side by side, [pre |
+            # post | res]: the projections ``phi`` a stream, and in
+            # float32 whatever param_dtype (they enter float32
+            # arithmetic, 27 numbers a part) the three scalars ``alpha``
+            # and the biases. The start: the dynamic terms whole
+            # (``alpha`` 1 on projections of unit variance), so that
+            # what a part reads and where it writes differ by stream and
+            # token, and the mixing matrix leans to the identity
+            # (``2 I`` in the logits: 0.7 on the diagonal) without being
+            # it.
+            n = c.hc_mult
+            start = jnp.concatenate([jnp.zeros(2 * n, jnp.float32),
+                                     2.0 * jnp.eye(n).ravel()])
+            for part in ("attn",) * (mixer is not None) \
+                    + ("mlp",) * (dense_ffn is not None):
+                layers.update({
+                    f"hc_{part}_phi": dense(
+                        next(x), (L, n, c.d_model, n * (n + 2)),
+                        n * c.d_model),
+                    f"hc_{part}_alpha": jnp.ones((L, 3), jnp.float32),
+                    f"hc_{part}_bias": jnp.tile(start, (L, 1))})
         # A ``relu2`` FFN has no gate matrix, dense, routed or shared.
         gated = c.ffn_act == "swiglu"
         if dense_ffn is None:     # a mixer alone: its one norm was set
@@ -944,6 +1090,13 @@ def llama_partition_rules(pipeline=False):
         # ``wg`` a projection with these.
         (r"layers/w[qkvg]$", P(lead, "fsdp", "tensor")),
         (r"layers/wo", P(lead, "tensor", "fsdp")),
+        # Latent attention: the down-projections split like the stream,
+        # the up-projections by heads.
+        (r"layers/w(q|kv)_a", P(lead, "fsdp", None)),
+        (r"layers/w(q|kv)_b", P(lead, None, "tensor")),
+        # Hyper-connections: a projection a stream; 27 numbers a part.
+        (r"layers/hc_\w+_phi", P(lead, None, "fsdp", None)),
+        (r"layers/hc_\w+_(alpha|bias)", P(lead, None)),
         # The short convolution: projections like attention's, the taps
         # (one weight a channel and tap) replicated.
         (r"layers/conv_in", P(lead, "fsdp", "tensor")),
@@ -995,20 +1148,27 @@ _rmsnorm = scope("hvd.norm")(_rms)
 
 
 @scope("hvd.attn.rope")
-def _rope(x, positions, theta, rotary=0):
+def _rope(x, positions, theta, rotary=0, freqs=None, mult=1.0):
     """Rotary embedding (half-split) of ``x`` [B, T, H, D]; positions
     are GLOBAL indices [B, T] so sequence sharding stays correct.
     ``rotary``: the leading dimensions of a head that turn (their own
     two halves paired, frequencies over ``rotary``), the rest pass as
-    they are; 0 = the whole head."""
+    they are; 0 = the whole head. ``freqs`` [D / 2]: the frequencies
+    where they are not ``theta``'s own, cos and sin times ``mult``
+    (``LlamaConfig.yarn``)."""
     rest = None
     if rotary and rotary < x.shape[-1]:
         x, rest = x[..., :rotary], x[..., rotary:]
     b, t, h, d = x.shape
-    freqs = theta ** (-jnp.arange(0, d // 2, dtype=jnp.float32) / (d // 2))
+    if freqs is None:
+        freqs = theta ** (-jnp.arange(0, d // 2, dtype=jnp.float32)
+                          / (d // 2))
     angles = positions[:, :, None].astype(jnp.float32) * freqs  # [B,T,d/2]
-    cos = jnp.cos(angles)[:, :, None, :].astype(x.dtype)
-    sin = jnp.sin(angles)[:, :, None, :].astype(x.dtype)
+    def table(f):
+        t = f(angles)[:, :, None, :]
+        return (t * mult if mult != 1.0 else t).astype(x.dtype)
+
+    cos, sin = table(jnp.cos), table(jnp.sin)
     x1, x2 = jnp.split(x, 2, axis=-1)
     turned = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
     return turned if rest is None else jnp.concatenate([turned, rest], -1)
@@ -1130,6 +1290,140 @@ def _prepared_qkv(h, lp, c, positions, rope, mesh):
              for g in ("q_norm", "k_norm")]
     return qk_prep(*flat, *gains, positions, c.rope_theta if rope else None,
                    c.head_dim, c.norm_eps, _kernel_mesh(mesh))
+
+
+def _one_key_for_all_heads(k_r, heads):
+    """Latent attention's rotated key ``k_r`` [B, T, 1, dr] as every
+    head reads it: the SAME ``dr`` values."""
+    return jnp.broadcast_to(k_r, (*k_r.shape[:2], heads, k_r.shape[3]))
+
+
+def _latent_attention(h, lp, c, positions, mesh, seq_axis):
+    """Multi-head latent attention on normalized ``h`` [B, T, D] -> what
+    the layer adds (DeepSeek-V2, section 2.1, the training form):
+    ``c_q = RMSNorm(h W_qa)``, ``[q_n | q_r] = c_q W_qb`` a head;
+    ``[c_kv | k_r] = h W_kva``, ``c_kv`` under its RMSNorm, ``[k_n | v]
+    = c_kv W_kvb`` a head; ``q = [q_n | RoPE(q_r)]``, ``k = [k_n |
+    RoPE(k_r)]`` with ONE ``k_r`` for all heads
+    (``LlamaConfig.yarn``: frequencies and scale); causal softmax
+    attention at the handed-in scale with queries and keys
+    ``qk_head_dim`` wide beside values ``v_head_dim`` wide (the flash
+    kernels on the chip); ``W_o``. The two latents and ``k_r`` carry the
+    names ``mla_c_q``, ``mla_c_kv``, ``mla_k_r``: what the "attn" remat
+    modes save of the projections (1,344 values a token at Xing4's
+    sizes, where ``q``, ``k``, ``v`` are 16,384), so that the backward
+    pass re-runs the up-projections and not the down-projections.
+    Scopes: the five matmuls and the two latent norms ``hvd.mla.proj``,
+    the rotation ``hvd.attn.rope``, the assembly of ``q`` and ``k`` and
+    the attention ``hvd.mla.core``."""
+    from horovod_tpu.ops.flash_attention import flash_attention
+
+    if _over_sequence(mesh, seq_axis):
+        raise ValueError(
+            "latent attention runs on no sequence-parallel mesh axis "
+            "yet: ring and ulysses take one width for q, k and v")
+    dt = c.compute_dtype
+    b, t, _ = h.shape
+    H, dn, rkv = c.n_heads, c.qk_nope_head_dim, c.kv_lora_rank
+    freqs, mult, scale = c.yarn()
+    turn = partial(_rope, positions=positions, theta=None,
+                   freqs=jnp.asarray(freqs), mult=mult)
+    with scope("hvd.mla.proj"):
+        c_q = _rms(h @ lp["wq_a"].astype(dt), lp["q_a_norm"].astype(dt),
+                   c.norm_eps)
+        c_kv, k_r = jnp.split(h @ lp["wkv_a"].astype(dt), [rkv], axis=-1)
+        c_kv = _rms(c_kv, lp["kv_a_norm"].astype(dt), c.norm_eps)
+        c_q = checkpoint_name(c_q, "mla_c_q")
+        c_kv = checkpoint_name(c_kv, "mla_c_kv")
+        k_r = checkpoint_name(k_r, "mla_k_r")
+        q = (c_q @ lp["wq_b"].astype(dt)).reshape(b, t, H, c.qk_head_dim)
+        kv = (c_kv @ lp["wkv_b"].astype(dt)).reshape(
+            b, t, H, dn + c.v_head_dim)
+    q_r, k_r = turn(q[..., dn:]), turn(k_r[:, :, None, :])
+    with scope("hvd.mla.core"):
+        q = jnp.concatenate([q[..., :dn], q_r], -1)
+        k = jnp.concatenate([kv[..., :dn],
+                             _one_key_for_all_heads(k_r, H)], -1)
+        blocks = {"block_q": c.flash_block, "block_k": c.flash_block} \
+            if c.flash_block else {}
+        o = flash_attention(q, k, kv[..., dn:], causal=True,
+                            mesh=_kernel_mesh(mesh), scale=scale, **blocks)
+    with scope("hvd.mla.proj"):
+        return o.reshape(b, t, -1) @ lp["wo"].astype(dt)
+
+
+# ``H_post = _HC_POST_SCALE * sigmoid(.)``: a part's output may be added
+# to a stream up to twice over (mHC, arXiv 2512.24880).
+_HC_POST_SCALE = 2.0
+
+
+def _sinkhorn(logits, iters, eps, clamp):
+    """Float32 ``logits`` [n, n, tokens] (row, column, token: the tokens
+    on the lanes, where [tokens, n, n] would fill a 128-lane tile with
+    four values) -> the matrices ``exp(clamp(logits))`` after ``iters``
+    Sinkhorn-Knopp iterations: rows over (their sum + ``eps``), then
+    columns over (theirs + ``eps``). Plain arithmetic under a
+    ``lax.scan``, so the gradient passes through every iteration; a
+    loop, not ``iters`` copies of the body: every part holds the
+    iterations three times over (forward, recomputed, backward), and
+    unrolled they made the grad program's executable 28% larger (past
+    what the compile cache it was measured under would keep) for a step
+    6% shorter. The trade is open: PERF.md section 7, PR 57."""
+    def iteration(m, _):
+        m = m / (m.sum(1, keepdims=True) + eps)
+        return m / (m.sum(0, keepdims=True) + eps), None
+
+    return lax.scan(iteration, jnp.exp(jnp.clip(logits, *clamp)), None,
+                    length=iters)[0]
+
+
+def _hc_coefficients(X, phi, alpha, bias, c):
+    """The three sets of coefficients of one part from the streams ``X``
+    [B, n, T, D] -> float32 (``H_pre`` [B, n, T], ``H_post`` [B, n, T],
+    ``H_res`` [B, n, n, T]: row, column). ``x~ Phi`` is ``(x Phi) / rms``:
+    the streams multiply ``phi`` [n, D, n (n + 2)] as they are stored
+    (the compute dtype's operands, float32 accumulation, a stream a
+    matmul), the RMS over all ``n D`` values (no gain) divides the 24
+    results; everything from there on is float32."""
+    f32, n = jnp.float32, c.hc_mult
+    b, _, t, d = X.shape
+    proj = sum(jnp.matmul(X[:, i], phi[i].astype(X.dtype),
+                          preferred_element_type=f32) for i in range(n))
+    square = jnp.square(X.astype(f32)).sum((1, 3)) / (n * d)     # [B, T]
+    proj = proj * lax.rsqrt(square + c.norm_eps)[..., None]
+    # tokens last from here: [B, 24, T]
+    proj = jnp.swapaxes(proj, 1, 2)
+    scale = alpha.astype(f32)[np.repeat(np.arange(3), [n, n, n * n])]
+    raw = proj * scale[:, None] + bias.astype(f32)[:, None]
+    pre, post, res = jnp.split(raw, [n, 2 * n], axis=1)
+    res = jnp.moveaxis(res.reshape(b, n, n, t), 0, 2)     # [n, n, B, T]
+    res = _sinkhorn(res.reshape(n, n, b * t), c.hc_sinkhorn_iters,
+                    c.hc_eps, c.hc_clamp).reshape(n, n, b, t)
+    return (jax.nn.sigmoid(pre), _HC_POST_SCALE * jax.nn.sigmoid(post),
+            jnp.moveaxis(res, 2, 0))
+
+
+def _hyper_connection(X, lp, name, c, part):
+    """One part round the streams ``X`` [B, n, T, D] (mHC, arXiv
+    2512.24880): ``u = sum_i H_pre[i] X[i]`` is what ``part`` reads
+    ([B, T, D] -> (its output, its aux)), and ``X'[i] = sum_j H_res[i,
+    j] X[j] + H_post[i] part(u)``; the coefficients from ``lp``'s
+    ``hc_<name>_*`` leaves (``_hc_coefficients``). The streams are read
+    and written in the compute dtype, the sums over streams run in
+    float32; elementwise passes a stream, never a matmul of 4 x 4."""
+    f32, n = jnp.float32, c.hc_mult
+    with scope("hvd.hc.mix"):
+        pre, post, res = _hc_coefficients(
+            X, lp[f"hc_{name}_phi"], lp[f"hc_{name}_alpha"],
+            lp[f"hc_{name}_bias"], c)
+        Xf = X.astype(f32)
+        u = (pre[..., None] * Xf).sum(1).astype(X.dtype)
+    y, aux = part(u)
+    with scope("hvd.hc.mix"):
+        mixed = sum(res[:, :, j, :, None] * Xf[:, j, None]
+                    for j in range(n))
+        out = mixed + post[..., None] * y.astype(f32)[:, None]
+        return out.astype(X.dtype), aux
 
 
 @scope("hvd.conv.chain")
@@ -1968,6 +2262,21 @@ def _run_layers(params, x, c, mesh, seq_axis, mtp=False):
 
     Unrolled, program size and compile time are O(depth). A pipeline
     stage (``_stage_scan``) always scans: one layer program by contract."""
+    if c.hc_mult:
+        # Hyper-connections: ``hc_mult`` copies in, their sum out.
+        streams = jnp.broadcast_to(x[:, None], (x.shape[0], c.hc_mult,
+                                                *x.shape[1:]))
+        streams, balance = _run_layer_plan(params, _constrain(streams, mesh),
+                                           c, mesh, seq_axis, mtp)
+        with scope("hvd.hc.mix"):
+            return streams.astype(jnp.float32).sum(1).astype(x.dtype), \
+                balance
+    return _run_layer_plan(params, x, c, mesh, seq_axis, mtp)
+
+
+def _run_layer_plan(params, x, c, mesh, seq_axis, mtp):
+    """``_run_layers`` on the stream as the layers carry it ([B, T, D],
+    or [B, n, T, D] under hyper-connections)."""
     plan = c.layer_plan(mtp)
     kinds = {spec.kind for spec in plan}
 
@@ -2027,8 +2336,11 @@ def _run_layers(params, x, c, mesh, seq_axis, mtp=False):
 def _constrain(x, mesh):
     if mesh is None:
         return x
+    spec = _activation_spec(mesh)
+    if x.ndim == 4:     # [B, n, T, D]: the streams of hyper-connections
+        spec = P(spec[0], None, *spec[1:])
     return lax.with_sharding_constraint(
-        x, jax.sharding.NamedSharding(mesh, _activation_spec(mesh)))
+        x, jax.sharding.NamedSharding(mesh, spec))
 
 
 def _stage_scan(body):
@@ -2049,14 +2361,17 @@ def _validate_pipeline(c, b, mesh, seq_axis, n_stages):
     M = c.pipeline_microbatches or n_stages
     plan = c.layer_plan()
     unscheduled = [f for f in ("one_part_layers", "ffn_act", "moe_latent",
-                               "shared_d_ff", "mtp_layers")
+                               "shared_d_ff", "mtp_layers", "kv_lora_rank",
+                               "hc_mult")
                    if f in c.training_only_fields()]
     if unscheduled:
         raise ValueError(
             f"LlamaConfig fields {unscheduled} have no pipeline schedule "
             "yet: a stage scans ONE layer program of a mixer AND a "
-            "SwiGLU FFN over params['layers'], and the last stage's loss "
-            "has one term")
+            "SwiGLU FFN over params['layers'], the last stage's loss "
+            "has one term, and what crosses a stage boundary is ONE "
+            "stream [B, T, D] (hyper-connections carry hc_mult; latent "
+            "attention's leaves have no stage layout)")
     if len({spec.kind for spec in plan}) > 1 \
             or plan[0].mixer != "attention" or c.tie_embeddings \
             or c.partial_rotary or c.shared_expert_gate:
@@ -2144,6 +2459,8 @@ def _build_layer_body(c, mesh, seq_axis, constrain_acts=True, kind=None):
         bb, tt = x.shape[0], x.shape[1]
         positions = jnp.broadcast_to(jnp.arange(tt), (bb, tt))
         h = _rmsnorm(x, lp["attn_norm"].astype(dt), c.norm_eps)
+        if c.kv_lora_rank:    # its rotated slice is its own, whatever rope
+            return _latent_attention(h, lp, c, positions, mesh, seq_axis)
         # One pass on the chip (ops/qk_prep.py), the expressions
         # elsewhere: which, is read off the input.
         from horovod_tpu.ops import qk_prep
@@ -2199,6 +2516,42 @@ def _build_layer_body(c, mesh, seq_axis, constrain_acts=True, kind=None):
         x = x + joins(ff)
         return x, aux
 
+    saved = ("attn_out", "flash_o", "flash_lse", "sparse_sel", "mla_c_q",
+             "mla_c_kv", "mla_k_r")
+    if c.hc_mult:
+        # The same two parts round ``hc_mult`` streams [B, n, T, D]:
+        # each reads ``_hyper_connection``'s blend and is added where
+        # its coefficients say.
+        def hc_mix(x, lp):
+            def part(u):
+                y = mix(u, lp)
+                if c.post_norm:
+                    y = _rmsnorm(y, lp["post_attn_norm"].astype(dt),
+                                 c.norm_eps)
+                return joins(y), None
+            return _hyper_connection(x, lp, "attn", c, part)[0]
+
+        def hc_ffn(x, lp):
+            def part(u):
+                ff, aux = _ffn(_rmsnorm(u, lp["mlp_norm"].astype(dt),
+                                        c.norm_eps), lp, c, mesh)
+                if c.post_norm:
+                    ff = _rmsnorm(ff, lp["post_mlp_norm"].astype(dt),
+                                  c.norm_eps)
+                return joins(ff), aux
+            x, aux = _hyper_connection(x, lp, "mlp", c, part)
+            return constrain(x), aux
+
+        def streams_layer(x, lp):
+            if mixer is None:
+                return hc_ffn(x, lp)
+            x = constrain(hc_mix(x, lp))
+            if dense_ffn is None:
+                return x, jnp.zeros((2, 0), jnp.float32)
+            return hc_ffn(x, lp)
+
+        layer = streams_layer
+
     body = layer
     if c.remat == "dots":
         body = jax.checkpoint(
@@ -2215,8 +2568,7 @@ def _build_layer_body(c, mesh, seq_axis, constrain_acts=True, kind=None):
         # (profiled r3: ~12% of the step).
         body = jax.checkpoint(
             layer,
-            policy=jax.checkpoint_policies.save_only_these_names(
-                "attn_out", "flash_o", "flash_lse", "sparse_sel"))
+            policy=jax.checkpoint_policies.save_only_these_names(*saved))
     elif c.remat == "attn/ffn":
         # "attn" with the mixer and the FFN each under a checkpoint of
         # its own (the mixer's output, [B,T,D], is saved between them),
@@ -2233,13 +2585,19 @@ def _build_layer_body(c, mesh, seq_axis, constrain_acts=True, kind=None):
         # under ONE checkpoint 9.00, this 7.74. No FLOP more.
         once = partial(jax.checkpoint,
                        policy=jax.checkpoint_policies.save_only_these_names(
-                           "attn_out", "flash_o", "flash_lse", "sparse_sel"))
-        mix_once = partial(mix, stage=once) if mixer == "linear" \
-            else once(mix)
-        ffn_once = once(ffn)
+                           *saved))
+        if c.hc_mult:   # a part WITH its stream mixing under each
+            mix_once, ffn_once = once(hc_mix), once(hc_ffn)
 
-        def body(x, lp):
-            return ffn_once(x, mix_once(x, lp), lp)
+            def body(x, lp):
+                return ffn_once(constrain(mix_once(x, lp)), lp)
+        else:
+            mix_once = partial(mix, stage=once) if mixer == "linear" \
+                else once(mix)
+            ffn_once = once(ffn)
+
+            def body(x, lp):
+                return ffn_once(x, mix_once(x, lp), lp)
     elif c.remat in ("attn+moe", "moe") and not _grouped_dispatch(c, mesh):
         # These modes save residuals only grouped_moe_ffn emits; under
         # GShard dispatch (mesh present or moe_impl="gshard") or a
@@ -2365,19 +2723,16 @@ def _head_nll(params, x, targets, c):
     return _token_nll(_head(params, x, c), targets)
 
 
-def _mtp_loss(params, stream, batch, c, mesh, seq_axis):
-    """The multi-token-prediction term (DeepSeek-V3, section 2.2, one
-    module): position ``t`` of the main model's residual stream BEFORE
-    its final norm, ``stream`` [B, T, D], and the embedding of token
-    ``t+1`` (``targets[t]``), each under a norm of its own, side by side
-    through ``eh_proj``; the module's layers (``layer_plan(mtp=True)``:
-    the same layer programs, its own weights); its own final norm; the main
-    model's head; the cross-entropy against token ``t+2``
-    (``targets[t+1]``) over the positions that have one (a sequence's
-    last has none). The expert layers' balance statistics of the module
-    join no auxiliary term."""
+def _mtp_hidden(params, stream, targets, c, mesh, seq_axis):
+    """What the head reads of the MTP module, [B, T, D] after the
+    module's own final norm: position ``t`` of the main model's residual
+    stream BEFORE its final norm, ``stream`` [B, T, D] (under
+    hyper-connections the SUM of its streams), and the embedding of
+    token ``t+1`` (``targets[t]``), each under a norm of its own, side by
+    side through ``eh_proj``; the module's layers
+    (``layer_plan(mtp=True)``: the same layer programs, its own
+    weights)."""
     mp, dt = params["mtp"], c.compute_dtype
-    targets = batch["targets"]
     nxt = _embed(params, targets, c)
     with scope("hvd.mtp"):
         m = jnp.concatenate(
@@ -2386,7 +2741,18 @@ def _mtp_loss(params, stream, batch, c, mesh, seq_axis):
             @ mp["eh_proj"].astype(dt)
     m, _ = _run_layers(mp, _constrain(m, mesh), c, mesh, seq_axis,
                        mtp=True)
-    nll = _head_nll(params, _final_norm(mp, m, c),
+    return _final_norm(mp, m, c)
+
+
+def _mtp_loss(params, stream, batch, c, mesh, seq_axis):
+    """The multi-token-prediction term (DeepSeek-V3, section 2.2, one
+    module): :func:`_mtp_hidden` through the main model's head; the
+    cross-entropy against token ``t+2`` (``targets[t+1]``) over the
+    positions that have one (a sequence's last has none). The expert
+    layers' balance statistics of the module join no auxiliary term."""
+    targets = batch["targets"]
+    nll = _head_nll(params, _mtp_hidden(params, stream, targets, c, mesh,
+                                        seq_axis),
                     jnp.roll(targets, -1, axis=1), c)
     with scope("hvd.mtp"):
         has_target = jnp.arange(targets.shape[1]) < targets.shape[1] - 1

@@ -3,11 +3,14 @@ beyond the dense decoder. What the program's kernels, sorts, scans and
 remat modes are compared against (tests/single/test_olmoe_reference.py,
 tests/single/test_afmoe_reference.py, tests/single/test_lfm2_reference.py,
 tests/single/test_qwen3next_reference.py,
-tests/single/test_jamba_reference.py; the chip benchmark keeps copies of
-its own, chipbench/models/olmoe.py, afmoe.py, lfm2moe.py, qwen3next.py
-and jamba.py). OLMoE first; Trinity-Mini (afmoe), LFM2-8B-A1B
-(lfm2_moe), Qwen3-Next-80B-A3B (qwen3_next) and AI21-Jamba2-3B (jamba)
-below it, each with its own description.
+tests/single/test_jamba_reference.py, test_nemotronh_reference.py,
+test_sala_reference.py, test_xing4_reference.py; the chip benchmark
+keeps copies of its own, chipbench/models/olmoe.py, afmoe.py,
+lfm2moe.py, qwen3next.py, jamba.py, nemotronh.py, minicpmsala.py and
+xing4.py). OLMoE first; Trinity-Mini (afmoe), LFM2-8B-A1B (lfm2_moe),
+Qwen3-Next-80B-A3B (qwen3_next), AI21-Jamba2-3B (jamba),
+Nemotron-3-Super (nemotron_h), MiniCPM-SALA (minicpm_sala) and
+Xing4.0-29B-A4B (xing4_0) below it, each with its own description.
 
 OLMoE (arXiv:2409.02060; Hugging Face ``modeling_olmoe.py``), as
 published:
@@ -1217,3 +1220,245 @@ def sala_loss(params, batch, cfg):
                                -1)[..., 0]
     mask = batch.get("mask", jnp.ones(nll.shape, F32)).astype(F32)
     return jnp.sum(nll * mask) / jnp.sum(mask)
+
+
+# ---------------------------------------------------------------------
+# Xing4.0-29B-A4B (``model_type`` xing4_0; XingChen-AGI/Xing4.0-29B-A4B's
+# config.json). Every layer: multi-head latent attention and a
+# feed-forward part (the first ``first_k_dense_replace`` a dense SwiGLU,
+# the others a shared expert beside the K of E routed experts), each
+# round a residual path of ``n = hc_mult`` streams mixed by
+# manifold-constrained hyper-connections; one multi-token-prediction
+# module. With ``h`` a part's input [D]:
+#
+# - attention (DeepSeek-V2, arXiv 2405.04434, section 2.1, the training
+#   form): ``c_q = RMSNorm(h W_qa)``, ``[q_n | q_r] = c_q W_qb`` a head;
+#   ``[c_kv | k_r] = h W_kva``, ``c_kv = RMSNorm(c_kv)``, ``[k_n | v] =
+#   c_kv W_kvb`` a head; ``q = [q_n | RoPE(q_r)]``, ``k = [k_n |
+#   RoPE(k_r)]``, ONE ``k_r`` for all heads; ``o = softmax(q k^T s,
+#   causal) v`` with ``s = m^2 / sqrt(d_qk)``, ``m = 0.1 mscale_all_dim
+#   ln(factor) + 1``; ``out = o W_o``. RoPE's frequencies are YaRN's
+#   (Hugging Face's DeepSeek-V3 form): ``theta^(-2i/d)`` and the same
+#   over ``factor`` blended by a linear ramp between the correction
+#   dimensions of ``beta_fast`` and ``beta_slow`` turns in the original
+#   length; cos and sin times ``m(mscale) / m(mscale_all_dim)``;
+# - the residual path (mHC, arXiv 2512.24880) round a part ``F`` (with
+#   its pre-norm), a token's streams ``X`` in R^{n x D}, ``x~ =
+#   vec(X) / rms(vec(X))``: ``H~_pre = a_pre (x~ Phi_pre) + b_pre``,
+#   ``H~_post`` alike, ``H~_res = a_res mat(x~ Phi_res) + b_res``;
+#   ``H_pre = sigmoid(H~_pre)``, ``H_post = 2 sigmoid(H~_post)``,
+#   ``H_res`` = ``hc_sinkhorn_iters`` times {rows over (their sum +
+#   hc_eps); columns over (theirs + hc_eps)} of ``exp(clamp(H~_res))``;
+#   ``u = sum_i H_pre[i] X[i]``, ``X'[i] = sum_j H_res[i, j] X[j] +
+#   H_post[i] F(u)``. In: ``n`` copies of the embedding; out: their sum;
+# - experts (DeepSeek-V3, arXiv 2412.19437, section 2.1): ``s =
+#   sigmoid(h W_r)`` over all E; the K largest by ``s + bias``; weights
+#   the chosen ``s`` over their sum, times ``routed_scaling_factor``;
+#   ``Shared(h) + sum_k w_k Expert_k(h)``, every one a SwiGLU;
+# - MTP (DeepSeek-V3, section 2.2, one module): ``[RMSNorm(E[t+1]) ;
+#   RMSNorm(stream)] W_eh`` through one expert layer of its own (from
+#   ``n`` copies to their sum, as the model), a final norm of its own,
+#   the model's head, against token ``t+2``, weight ``mtp_weight``;
+#   ``stream`` is the SUM of the model's streams before its final norm.
+#
+# Written as the sections above: float32 at "highest" matmul precision,
+# an explicit mask a head, the Sinkhorn loop a Python loop, the experts
+# a loop over the ones held, the streams ``[B, T, n, D]`` as the
+# equations have them (the program carries ``[B, n, T, D]``); it reads
+# the program's tree (``hc_<part>_phi`` [n, D, n (n + 2)] is ``[Phi_pre
+# | Phi_post | Phi_res]`` with its rows a stream) and the
+# ``LlamaConfig`` as data. Departures from the published description,
+# which gives the residual path's four sizes and no more: everything of
+# it above but those sizes is this repo's reading of the paper (where
+# the clamp and ``hc_eps`` stand, the norm without a gain, ``n`` copies
+# in and a sum out); the norms on the two latents, the half-split
+# pairing of the rotated dimensions, the YaRN formulas and ``m^2`` in
+# the scale are DeepSeek-V3's as Hugging Face has them;
+# ``e_score_correction_bias`` is read as data (zero, untrained), with no
+# auxiliary term and no group limit (``n_group`` 1); the MTP module's
+# form, its weight and how it meets the streams are assumed. A share of
+# the experts (``n_experts_held``) computes the shared expert and the
+# routed sum over the experts held.
+# ---------------------------------------------------------------------
+
+def xing4_yarn(cfg):
+    """-> (inverse frequencies [d_r / 2], what cos and sin are
+    multiplied by, the softmax scale), from ``cfg.rope_yarn = (factor,
+    original length, beta_fast, beta_slow, mscale, mscale_all_dim)``."""
+    import math
+
+    d, base = cfg.qk_rope_head_dim, cfg.rope_theta
+    factor, original, fast, slow, mscale, all_dim = cfg.rope_yarn
+
+    def correction(turns):   # the dimension that turns ``turns`` times
+        return d * math.log(original / (turns * 2 * math.pi)) \
+            / (2 * math.log(base))
+
+    def m(s):
+        return 0.1 * s * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+    low = max(math.floor(correction(fast)), 0)
+    high = min(math.ceil(correction(slow)), d - 1)
+    if low == high:
+        high += 0.001
+    inv = []
+    for i in range(d // 2):
+        plain = base ** (-2.0 * i / d)
+        ramp = min(max((i - low) / (high - low), 0.0), 1.0)
+        inv.append(plain / factor * ramp + plain * (1.0 - ramp))
+    scale = (cfg.qk_nope_head_dim + d) ** -0.5
+    if all_dim:
+        scale *= m(all_dim) ** 2
+    return jnp.asarray(inv, F32), m(mscale) / m(all_dim), scale
+
+
+def xing4_attention(h, lp, cfg):
+    """Latent attention on normalized ``h`` [B, T, D] with one layer's
+    float32 parameters ``lp``: a masked softmax a head."""
+    b, t, _ = h.shape
+    H, dn, dr = cfg.n_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    inv, mult, scale = xing4_yarn(cfg)
+    ang = jnp.arange(t, dtype=F32)[:, None] * inv              # [T, dr/2]
+    cos, sin = jnp.cos(ang) * mult, jnp.sin(ang) * mult
+
+    def rope(x):    # [B, T, dr], half-split
+        x1, x2 = x[..., :dr // 2], x[..., dr // 2:]
+        return jnp.concatenate([x1 * cos - x2 * sin,
+                                x1 * sin + x2 * cos], -1)
+
+    c_q = _rms(h @ lp["wq_a"], lp["q_a_norm"], cfg.norm_eps)
+    q = (c_q @ lp["wq_b"]).reshape(b, t, H, dn + dr)
+    ckv = h @ lp["wkv_a"]
+    c_kv = _rms(ckv[..., :cfg.kv_lora_rank], lp["kv_a_norm"], cfg.norm_eps)
+    k_r = rope(ckv[..., cfg.kv_lora_rank:])          # one for all heads
+    kv = (c_kv @ lp["wkv_b"]).reshape(b, t, H, dn + cfg.v_head_dim)
+    mask = jnp.arange(t)[None, :] <= jnp.arange(t)[:, None]
+    heads = []
+    for n in range(H):
+        qn = jnp.concatenate([q[:, :, n, :dn], rope(q[:, :, n, dn:])], -1)
+        kn = jnp.concatenate([kv[:, :, n, :dn], k_r], -1)
+        s = jnp.einsum("bqd,bkd->bqk", qn, kn) * scale
+        p = jax.nn.softmax(jnp.where(mask, s, -jnp.inf), -1)
+        heads.append(jnp.einsum("bqk,bkd->bqd", p, kv[:, :, n, dn:]))
+    return jnp.concatenate(heads, -1) @ lp["wo"]
+
+
+def xing4_sinkhorn(logits, cfg):
+    """``logits`` [..., n, n] -> ``exp(clamp(logits))`` after
+    ``hc_sinkhorn_iters`` iterations of {rows over (their sum + hc_eps);
+    columns over (theirs + hc_eps)}."""
+    m = jnp.exp(jnp.clip(logits, cfg.hc_clamp[0], cfg.hc_clamp[1]))
+    for _ in range(cfg.hc_sinkhorn_iters):
+        m = m / (jnp.sum(m, -1, keepdims=True) + cfg.hc_eps)
+        m = m / (jnp.sum(m, -2, keepdims=True) + cfg.hc_eps)
+    return m
+
+
+def xing4_hc_coefficients(X, lp, part, cfg):
+    """A token's streams ``X`` [..., n, D] -> (``H_pre`` [..., n],
+    ``H_post`` [..., n], ``H_res`` [..., n, n]) of the part ``part``
+    ("attn" or "mlp") of the layer ``lp``."""
+    n = cfg.hc_mult
+    flat = X.reshape(*X.shape[:-2], -1)
+    x = flat * jax.lax.rsqrt(jnp.mean(flat * flat, -1, keepdims=True)
+                             + cfg.norm_eps)
+    proj = x @ lp[f"hc_{part}_phi"].reshape(flat.shape[-1], n * (n + 2))
+    a, bias = lp[f"hc_{part}_alpha"], lp[f"hc_{part}_bias"]
+    pre = a[0] * proj[..., :n] + bias[:n]
+    post = a[1] * proj[..., n:2 * n] + bias[n:2 * n]
+    res = (a[2] * proj[..., 2 * n:] + bias[2 * n:]).reshape(
+        *proj.shape[:-1], n, n)
+    return (jax.nn.sigmoid(pre), 2.0 * jax.nn.sigmoid(post),
+            xing4_sinkhorn(res, cfg))
+
+
+def xing4_hyper_connection(X, lp, part, cfg, F):
+    """``X`` [B, T, n, D] -> ``X'``: the part ``F`` ([B, T, D] -> [B, T,
+    D]) round the streams."""
+    pre, post, res = xing4_hc_coefficients(X, lp, part, cfg)
+    y = F(jnp.einsum("bti,btid->btd", pre, X))
+    return jnp.einsum("btij,btjd->btid", res, X) \
+        + post[..., None] * y[:, :, None, :]
+
+
+def xing4_expert_layer(h, lp, cfg):
+    """The FFN of one expert layer on normalized ``h`` [B, T, D], in two
+    parts: ``(Shared(h), the routed sum over the experts ``lp`` holds)``,
+    an expert at a time. The routed part is linear in the experts: over
+    all the shares it adds up to the uncut layer's."""
+    first = cfg.first_expert
+    w = afmoe_route(h, lp, cfg)
+    routed = jnp.zeros_like(h)
+    for e in range(cfg.n_experts_held or cfg.n_experts):
+        routed = routed + w[..., first + e, None] * _swiglu(
+            h, lp["moe_gate"][e], lp["moe_up"][e], lp["moe_down"][e])
+    return (_swiglu(h, lp["shared_gate"], lp["shared_up"],
+                    lp["shared_down"]), routed)
+
+
+def _xing4_layers(stacks, dense, experts, x, cfg):
+    """``dense`` leading dense layers (``stacks["dense_layers"]``) and
+    ``experts`` expert layers (``stacks["layers"]``) on ``x`` [B, T, D]:
+    ``hc_mult`` copies in, their sum out."""
+    X = jnp.repeat(x[:, :, None, :], cfg.hc_mult, 2)
+    for l in range(dense + experts):
+        stack, at = ("dense_layers", l) if l < dense \
+            else ("layers", l - dense)
+        lp = jax.tree.map(lambda w: w[at].astype(F32), stacks[stack])
+        X = xing4_hyper_connection(
+            X, lp, "attn", cfg, lambda u: xing4_attention(
+                _rms(u, lp["attn_norm"], cfg.norm_eps), lp, cfg))
+
+        def ffn(u):
+            h = _rms(u, lp["mlp_norm"], cfg.norm_eps)
+            if l < dense:
+                return _swiglu(h, lp["w_gate"], lp["w_up"], lp["w_down"])
+            return sum(xing4_expert_layer(h, lp, cfg))
+
+        X = xing4_hyper_connection(X, lp, "mlp", cfg, ffn)
+    return jnp.sum(X, 2)
+
+
+def xing4_forward(params, tokens, cfg, mtp_targets=None):
+    """tokens [B, T] -> logits [B, T, vocab] f32 (see the description
+    above); with ``mtp_targets`` [B, T] (token ``t+1`` a position) ->
+    (logits, the MTP module's logits). ``params`` is the program's tree,
+    any storage dtype."""
+    with jax.default_matmul_precision("highest"):
+        embed = params["embed"].astype(F32)
+        head = params["lm_head"].astype(F32)
+        x = _xing4_layers(params, cfg.n_dense_layers,
+                          cfg.n_layers - cfg.n_dense_layers, embed[tokens],
+                          cfg)
+        logits = _rms(x, params["final_norm"].astype(F32),
+                      cfg.norm_eps) @ head
+        if mtp_targets is None:
+            return logits
+        mp = jax.tree.map(lambda w: w.astype(F32), params["mtp"])
+        m = jnp.concatenate(
+            [_rms(embed[mtp_targets], mp["token_norm"], cfg.norm_eps),
+             _rms(x, mp["hidden_norm"], cfg.norm_eps)], -1) @ mp["eh_proj"]
+        m = _xing4_layers(mp, 0, len(cfg.mtp_types), m, cfg)
+        return logits, _rms(m, mp["final_norm"], cfg.norm_eps) @ head
+
+
+def xing4_loss(params, batch, cfg, vocab_rows=None, terms=False):
+    """Mean token cross-entropy over the positions ``batch["mask"]``
+    keeps (all without one) plus ``cfg.mtp_weight`` times the MTP
+    module's against the token after the target, over the positions
+    that have one; no aux term. ``vocab_rows``, ``terms``: as
+    :func:`nemotronh_loss`. ``jax.grad`` of this is the reference
+    gradient."""
+    def ce(logits, targets, mask):
+        logp = jax.nn.log_softmax(logits[..., :vocab_rows], -1)
+        nll = -jnp.take_along_axis(logp, targets[..., None], -1)[..., 0]
+        return jnp.sum(nll * mask) / jnp.sum(mask)
+
+    targets = batch["targets"]
+    mask = batch.get("mask", jnp.ones(targets.shape, F32)).astype(F32)
+    logits, logits2 = xing4_forward(params, batch["tokens"], cfg, targets)
+    has_next = (jnp.arange(targets.shape[1]) < targets.shape[1] - 1
+                ).astype(F32)
+    main = ce(logits, targets, mask)
+    mtp = ce(logits2, _token_after(targets), mask * has_next)
+    return (main, mtp) if terms else main + cfg.mtp_weight * mtp

@@ -216,7 +216,7 @@ def _fwd_kernel(*refs, scale, causal, has_bias, has_offsets, window=0):
     q_ref, k_ref, v_ref, *rest = refs
     bias_ref = rest.pop(0) if has_bias else None
     o_ref, lse_ref, qs_ref, acc_ref, m_ref, l_ref = rest
-    bq, d = q_ref.shape
+    bq = q_ref.shape[0]
     bk = k_ref.shape[0]
     jj = pl.program_id(3)
     n_jj = pl.num_programs(3)
@@ -224,7 +224,7 @@ def _fwd_kernel(*refs, scale, causal, has_bias, has_offsets, window=0):
     @pl.when(jj == 0)
     def _init():
         qs_ref[:, :] = _scaled(q_ref[:, :], scale)
-        acc_ref[:, :] = jnp.zeros((bq, d), jnp.float32)
+        acc_ref[:, :] = jnp.zeros(acc_ref.shape, jnp.float32)
         m_ref[:, :] = jnp.full((bq, 1), _NEG, jnp.float32)
         l_ref[:, :] = jnp.zeros((bq, 1), jnp.float32)
 
@@ -294,7 +294,7 @@ def _bwd_kernel(*refs, scale, causal, has_bias, has_offsets, window=0):
     @pl.when(iq == 0)
     def _init_dkv():
         dk_acc[:, :] = jnp.zeros((bk, d), jnp.float32)
-        dv_acc[:, :] = jnp.zeros((bk, d), jnp.float32)
+        dv_acc[:, :] = jnp.zeros(dv_acc.shape, jnp.float32)
 
     @pl.when(jk == 0)
     def _init_dq():
@@ -394,10 +394,10 @@ def _pick_block(t, want):
     return b
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash(q, k, v, causal, block_q, block_k, window=0):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash(q, k, v, causal, block_q, block_k, window=0, scale=None):
     o, _ = _flash_fwd_impl(q, k, v, None, causal, block_q, block_k,
-                           window=window)
+                           window=window, scale=scale)
     return o
 
 
@@ -408,15 +408,18 @@ def _flash_biased(q, k, v, bias, causal, block_q, block_k):
 
 
 def _flash_fwd_impl(q, k, v, bias, causal, block_q, block_k,
-                    offsets=None, window=0):
+                    offsets=None, window=0, scale=None):
     b, h, t, d = q.shape
     tk = k.shape[2]
+    # Queries and keys are ``d`` wide, values and the output ``dv``
+    # (latent attention: 192 beside 128); one width where they agree.
+    dv = v.shape[3]
     # GQA-native: k/v arrive UNREPEATED ([B, Hkv, T, D]); each query
     # head's block specs index kv-head hi // n_rep, so the n_rep-fold
     # expansion never materializes in HBM (the repeat would cost a copy
     # per call and double the saved k/v residuals).
     n_rep = h // k.shape[1]
-    scale = d ** -0.5
+    scale = d ** -0.5 if scale is None else scale
     grid = (b, h, t // block_q, tk // block_k)
     has_bias = bias is not None
     has_offsets = offsets is not None
@@ -444,13 +447,16 @@ def _flash_fwd_impl(q, k, v, bias, causal, block_q, block_k,
                          // block_k, 0, n_jj - 1)
         return jnp.clip(ji, first, last)
 
-    kv_spec = pl.BlockSpec(
-        (None, None, block_k, d),
-        lambda bi, hi, qi, ji, *a: (bi, hi // n_rep, live(qi, ji, a), 0))
+    def kv_spec(width):
+        return pl.BlockSpec(
+            (None, None, block_k, width),
+            lambda bi, hi, qi, ji, *a: (bi, hi // n_rep,
+                                        live(qi, ji, a), 0))
+
     in_specs = [
         pl.BlockSpec((None, None, block_q, d),
                      lambda bi, hi, qi, ji, *a: (bi, hi, qi, 0)),
-        kv_spec, kv_spec,
+        kv_spec(d), kv_spec(dv),
     ]
     args = [q, k, v]
     if has_bias:
@@ -460,18 +466,18 @@ def _flash_fwd_impl(q, k, v, bias, causal, block_q, block_k,
                                                      live(qi, ji, a))))
         args.append(bias)
     out_specs = [
-        pl.BlockSpec((None, None, block_q, d),
+        pl.BlockSpec((None, None, block_q, dv),
                      lambda bi, hi, qi, ji, *a: (bi, hi, qi, 0)),
         pl.BlockSpec((None, None, 1, block_q),
                      lambda bi, hi, qi, ji, *a: (bi, hi, 0, qi)),
     ]
     out_shape = [
-        jax.ShapeDtypeStruct(q.shape, q.dtype),
+        jax.ShapeDtypeStruct((b, h, t, dv), q.dtype),
         jax.ShapeDtypeStruct((b, h, 1, t), jnp.float32),
     ]
     scratch = [
         pltpu.VMEM((block_q, d), q.dtype),       # scaled q
-        pltpu.VMEM((block_q, d), jnp.float32),   # acc
+        pltpu.VMEM((block_q, dv), jnp.float32),  # acc
         pltpu.VMEM((block_q, 1), jnp.float32),   # m
         pltpu.VMEM((block_q, 1), jnp.float32),   # l
     ]
@@ -480,9 +486,9 @@ def _flash_fwd_impl(q, k, v, bias, causal, block_q, block_k,
                             window=window)
 
 
-def _flash_fwd(q, k, v, causal, block_q, block_k, window=0):
+def _flash_fwd(q, k, v, causal, block_q, block_k, window=0, scale=None):
     o, lse = _flash_fwd_impl(q, k, v, None, causal, block_q, block_k,
-                             window=window)
+                             window=window, scale=scale)
     # Residuals named for remat policies: an outer checkpoint_name on
     # the returned o covers only the PRIMAL output — the residual o/lse
     # here are distinct jaxpr vars, and leaving them unnamed makes
@@ -502,26 +508,37 @@ def _flash_biased_fwd(q, k, v, bias, causal, block_q, block_k):
     return o, (q, k, v, bias, o, lse)
 
 
-def _bwd_vmem_bytes(t, block_q, block_k, d, itemsize):
+def _bwd_vmem_bytes(t, block_q, block_k, d, itemsize, dv=None):
     """VMEM the one-pass backward asks for: its blocks (the pipeline
     holds each twice; a [1, block_q] f32 block of lse or delta fills
     whole 8-sublane tiles), its scratch (dq for the whole [T, D] slice,
     dk and dv for one block) and room for the f32 / operand-dtype
     planes of one score tile (s, p, dp, ds and their casts: six f32
-    planes' worth)."""
-    blocks = (3 * block_q + 4 * block_k) * d * itemsize \
+    planes' worth). ``dv``: the width of the values where it is not
+    ``d`` (q, dq, k, dk are ``d`` wide; do, v and its gradient ``dv``).
+    Heads of two widths count the 128-lane tiles each width touches
+    (192 takes two); heads of ONE width count ``d`` as it stands, as
+    they always have: ``lfm2-8b-a1b``'s heads are 64 wide, and its
+    program's text, this limit in it, is held to the byte."""
+    if dv is None:
+        dv = d
+    else:
+        d, dv = (-(-w // 128) * 128 for w in (d, dv))
+    blocks = ((2 * block_q + 2 * block_k) * d
+              + (block_q + 2 * block_k) * dv) * itemsize \
         + 2 * 8 * block_q * 4
-    scratch = (t + 2 * block_k) * d * 4
+    scratch = ((t + block_k) * d + block_k * dv) * 4
     return 2 * blocks + scratch + 6 * block_q * block_k * 4
 
 
 def _flash_bwd_impl(q, k, v, bias, o, lse, do, causal, block_q, block_k,
-                    offsets=None, dlse=None, window=0):
+                    offsets=None, dlse=None, window=0, scale=None):
     b, h, t, d = q.shape
     hkv = k.shape[1]
     tk = k.shape[2]
+    dv_w = v.shape[3]
     n_rep = h // hkv
-    scale = d ** -0.5
+    scale = d ** -0.5 if scale is None else scale
     has_bias = bias is not None
     has_offsets = offsets is not None
     delta = (do.astype(jnp.float32)
@@ -555,16 +572,21 @@ def _flash_bwd_impl(q, k, v, bias, o, lse, do, causal, block_q, block_k,
                         // block_q, 0, n_iq - 1)
         return jnp.clip(iq, first, last)
 
-    q_spec = pl.BlockSpec(
-        (None, None, block_q, d),
-        lambda bi, hi, jk, iq, *a: (bi, hi, live(jk, iq, a), 0))
-    kv_spec = pl.BlockSpec(
-        (None, None, block_k, d),
-        lambda bi, hi, jk, iq, *a: (bi, hi // n_rep, jk, 0))
+    def q_spec(width):
+        return pl.BlockSpec(
+            (None, None, block_q, width),
+            lambda bi, hi, jk, iq, *a: (bi, hi, live(jk, iq, a), 0))
+
+    def kv_spec(width):
+        return pl.BlockSpec(
+            (None, None, block_k, width),
+            lambda bi, hi, jk, iq, *a: (bi, hi // n_rep, jk, 0))
+
     row_spec = pl.BlockSpec(
         (None, None, 1, block_q),
         lambda bi, hi, jk, iq, *a: (bi, hi, 0, live(jk, iq, a)))
-    in_specs = [q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec]
+    in_specs = [q_spec(d), kv_spec(d), kv_spec(dv_w), q_spec(dv_w),
+                row_spec, row_spec]
     args = [q, k, v, do, lse, delta]
     if has_bias:
         in_specs.append(
@@ -583,35 +605,38 @@ def _flash_bwd_impl(q, k, v, bias, o, lse, do, causal, block_q, block_k,
     # kv-head's n_rep sharing query heads happens outside the kernel
     # (one cheap XLA reduction — keeps the kernel free of cross-kv-head
     # accumulation state).
-    dkv_spec = pl.BlockSpec((None, None, block_k, d),
+    def dkv_spec(width):
+        return pl.BlockSpec((None, None, block_k, width),
                             lambda bi, hi, jk, iq, *a: (bi, hi, jk, 0))
+
     dq, dk, dv = _pallas_dispatch(
         "hvd_flash_bwd_fused", kernel, (b, h, n_jk, n_iq), in_specs,
-        [dq_spec, dkv_spec, dkv_spec],
+        [dq_spec, dkv_spec(d), dkv_spec(dv_w)],
         [
             jax.ShapeDtypeStruct(q.shape, q.dtype),
             jax.ShapeDtypeStruct((b, h, tk, d), k.dtype),
-            jax.ShapeDtypeStruct((b, h, tk, d), v.dtype),
+            jax.ShapeDtypeStruct((b, h, tk, dv_w), v.dtype),
         ],
         args, offsets,
         [pltpu.VMEM((n_iq, block_q, d), jnp.float32),
          pltpu.VMEM((block_k, d), jnp.float32),
-         pltpu.VMEM((block_k, d), jnp.float32)],
-        vmem_limit_bytes=_bwd_vmem_bytes(t, block_q, block_k, d,
-                                         q.dtype.itemsize),
+         pltpu.VMEM((block_k, dv_w), jnp.float32)],
+        vmem_limit_bytes=_bwd_vmem_bytes(
+            t, block_q, block_k, d, q.dtype.itemsize,
+            None if dv_w == d else dv_w),
         window=window)
     if n_rep > 1:
         dk = dk.astype(jnp.float32).reshape(b, hkv, n_rep, tk, d) \
             .sum(axis=2).astype(k.dtype)
-        dv = dv.astype(jnp.float32).reshape(b, hkv, n_rep, tk, d) \
+        dv = dv.astype(jnp.float32).reshape(b, hkv, n_rep, tk, dv_w) \
             .sum(axis=2).astype(v.dtype)
     return dq, dk, dv
 
 
-def _flash_bwd(causal, block_q, block_k, window, res, do):
+def _flash_bwd(causal, block_q, block_k, window, scale, res, do):
     q, k, v, o, lse = res
     return _flash_bwd_impl(q, k, v, None, o, lse, do, causal, block_q,
-                           block_k, window=window)
+                           block_k, window=window, scale=scale)
 
 
 def _flash_biased_bwd(causal, block_q, block_k, res, do):
@@ -716,7 +741,7 @@ def _kernel_mesh_specs(mesh, batch_size, heads, kv_heads):
 
 
 def _head_major(qt, kt, vt, kv_bias=None, *, causal, block_q, block_k,
-                window):
+                window, scale=None):
     """The kernels on their own layout: ``qt`` [B, H, T, D], ``kt``,
     ``vt`` [B, Hkv, T, D] (the kernels index kv-head = query-head //
     n_rep, so GQA expansion never hits HBM) -> [B, T, H, D]."""
@@ -727,7 +752,7 @@ def _head_major(qt, kt, vt, kv_bias=None, *, causal, block_q, block_k,
         bias = kv_bias.astype(jnp.float32)[:, None, :]  # [B, 1, Tk]
         o = _flash_biased(qt, kt, vt, bias, causal, bq, bk)
     else:
-        o = _flash(qt, kt, vt, causal, bq, bk, window)
+        o = _flash(qt, kt, vt, causal, bq, bk, window, scale)
     return o.transpose(0, 2, 1, 3)
 
 
@@ -751,7 +776,8 @@ def _over_mesh(kernel, mesh, operands, heads_at):
 
 
 def flash_attention_head_major(q, k, v, causal=True, block_q=1024,
-                               block_k=1024, mesh=None, window=0):
+                               block_k=1024, mesh=None, window=0,
+                               scale=None):
     """:func:`flash_attention` for operands that arrive as the kernels
     take them (``ops/qk_prep.py`` writes them so): ``q`` [B, H, T, D],
     ``k``, ``v`` [B, Hkv, T, D] -> [B, T, H, D], with no transpose in
@@ -760,15 +786,15 @@ def flash_attention_head_major(q, k, v, causal=True, block_q=1024,
         raise ValueError("flash_attention: a window needs causal=True")
     if not use_pallas("flash_attention", (q, k, v), _INTERPRET):
         return flash_attention(*(x.transpose(0, 2, 1, 3) for x in (q, k, v)),
-                               causal=causal, window=window)
+                               causal=causal, window=window, scale=scale)
     return _over_mesh(
         functools.partial(_head_major, causal=causal, block_q=block_q,
-                          block_k=block_k, window=window),
+                          block_k=block_k, window=window, scale=scale),
         mesh, (q, k, v), 1)
 
 
 def flash_attention(q, k, v, causal=True, kv_bias=None, block_q=1024,
-                    block_k=1024, mesh=None, window=0):
+                    block_k=1024, mesh=None, window=0, scale=None):
     """Flash attention. q,k,v: [B, T, H, D] (framework layout; kv heads
     may be fewer — GQA is handled natively: the kernels index kv-head
     ``query_head // n_rep``, so the expansion never materializes in
@@ -787,6 +813,11 @@ def flash_attention(q, k, v, causal=True, kv_bias=None, block_q=1024,
     no matmul), mask the tiles an edge crosses and run the rest bare;
     ``tile_counts`` says how many of each. 0: every earlier key.
 
+    ``v`` may be another width than ``q`` and ``k`` ([B, T, Hkv, Dv]:
+    latent attention's 192 beside 128); the result is [B, T, H, Dv].
+    ``scale`` multiplies the scores (calls without a bias only); None:
+    ``1 / sqrt(D)`` of the queries' width.
+
     Operands on a TPU: pallas kernel. Elsewhere: the XLA blockwise
     implementation (same math, used by CPU tests).
 
@@ -804,6 +835,10 @@ def flash_attention(q, k, v, causal=True, kv_bias=None, block_q=1024,
     if window and (not causal or kv_bias is not None):
         raise ValueError("flash_attention: a window needs causal=True "
                          "and no kv_bias")
+    if kv_bias is not None and (scale is not None
+                                or v.shape[-1] != q.shape[-1]):
+        raise ValueError("flash_attention: a kv_bias call takes no scale "
+                         "and one width for q, k and v")
     n_rep = q.shape[2] // k.shape[2]
     # _INTERPRET forces the pallas path off-TPU so tests cover the real
     # kernel code (interpret mode) instead of the reference math.
@@ -821,13 +856,14 @@ def flash_attention(q, k, v, causal=True, kv_bias=None, block_q=1024,
         from horovod_tpu.parallel.ring_attention import blockwise_attention
 
         return checkpoint_name(
-            blockwise_attention(q, k, v, causal=causal, window=window),
+            blockwise_attention(q, k, v, causal=causal, window=window,
+                                scale=scale),
             "attn_out")
 
     def kernel(q, k, v, kv_bias=None):
         return _head_major(*(x.transpose(0, 2, 1, 3) for x in (q, k, v)),
                            kv_bias, causal=causal, block_q=block_q,
-                           block_k=block_k, window=window)
+                           block_k=block_k, window=window, scale=scale)
 
     return _over_mesh(kernel, mesh,
                       (q, k, v) if kv_bias is None else (q, k, v, kv_bias), 2)

@@ -69,18 +69,19 @@ def _combine(o, m, l, o_blk, m_blk, l_blk):
 
 
 def blockwise_attention(q, k, v, causal=True, q_offset=0, kv_offset=0,
-                        window=0):
+                        window=0, scale=None):
     """Plain (single-device) attention with global-position causal mask.
 
     q: [B, Tq, H, D]; k, v: [B, Tk, Hkv, D]. The offsets give the global
     index of the first q/kv position (used by ring steps and by decode).
     ``window`` W > 0: sliding-window attention, each row sees its last W
     keys (itself included); what ``ops.flash_attention(window=W)`` runs
-    off the TPU.
+    off the TPU. ``v`` may be another width than ``q`` and ``k``;
+    ``scale`` multiplies the scores (None: ``1 / sqrt(D)``).
     """
     n_rep = q.shape[2] // k.shape[2]
     k, v = _repeat_kv(k, n_rep), _repeat_kv(v, n_rep)
-    scale = q.shape[-1] ** -0.5
+    scale = q.shape[-1] ** -0.5 if scale is None else scale
     q_pos = q_offset + jnp.arange(q.shape[1])
     kv_pos = kv_offset + jnp.arange(k.shape[1])
     o, m, l = _attn_block(q, k, v, q_pos, kv_pos, causal, scale, window)

@@ -39,7 +39,7 @@ SCOPES = frozenset({
     "hvd.gdn.chain", "hvd.gdn.core", "hvd.ssm.proj", "hvd.ssm.chain",
     "hvd.ssm.core", "hvd.ssd.proj", "hvd.ssd.chain", "hvd.ssd.core",
     "hvd.sparse.select", "hvd.sparse.core", "hvd.lightning.chain",
-    "hvd.lightning.core",
+    "hvd.lightning.core", "hvd.mla.proj", "hvd.mla.core", "hvd.hc.mix",
     "hvd.ffn", "hvd.moe.route", "hvd.moe.dispatch", "hvd.moe.experts",
     "hvd.moe.combine", "hvd.moe.latent", "hvd.mtp", "hvd.head", "hvd.loss", "hvd.apply",
     "hvd.allreduce", "hvd.cnn.stem", "hvd.cnn.stage1", "hvd.cnn.stage2",

@@ -96,6 +96,16 @@ def _flash_window_fwd_bwd(q, k, v):
         argnums=(0, 1, 2))(q, k, v)
 
 
+def _flash_latent_fwd_bwd(q, k, v):
+    """Latent attention's call: values another width than queries and
+    keys, the softmax scale handed in (Xing4.0: ``m^2 / sqrt(192)``)."""
+    from horovod_tpu.ops import flash_attention
+
+    return jax.grad(lambda *a: flash_attention(
+        *a, causal=True, scale=1.4159 ** 2 / 192 ** 0.5).astype(F32).sum(),
+        argnums=(0, 1, 2))(q, k, v)
+
+
 def _flash_chunk(q, k, v, q_off, kv_off):
     # One ring-attention step, kernel layout [B, H, T, D], with traced
     # global offsets; the lse cotangent exercises the folded backward.
@@ -267,9 +277,68 @@ _TQ, _TKV = ((2, 8192, 32, 128), BF16), ((2, 8192, 4, 128), BF16)
                  (((5632, 2688), BF16), ((8, 2688, 1024), BF16),
                   ((8,), I32)),
                  id="megablox-gmm-nemotron3super-share-down"),
+    # Xing4.0's latent attention at the chip cell's size: 32 heads whose
+    # queries and keys are 192 wide (one and a half lane tiles: no such
+    # width had run through the kernels) beside values 128 wide.
+    pytest.param(_flash_latent_fwd_bwd,
+                 (((1, 8192, 32, 192), BF16),) * 2
+                 + (((1, 8192, 32, 128), BF16),),
+                 id="flash-fwd+bwd-xing4-b1s8192-192-128"),
+    # Its share: 32,768 sorted slots into the 8 experts held, width 1024.
+    pytest.param(_gmm,
+                 (((32768, 3584), BF16), ((8, 3584, 1024), BF16),
+                  ((8,), I32)),
+                 id="megablox-gmm-xing4-share-gate-up"),
+    pytest.param(_gmm,
+                 (((32768, 1024), BF16), ((8, 1024, 3584), BF16),
+                  ((8,), I32)),
+                 id="megablox-gmm-xing4-share-down"),
 ])
 def test_kernel_compiles_for_described_v5e(for_tpu, fn, shapes):
     assert "tpu_custom_call" in for_tpu(fn, *shapes)
+
+
+# sha256 of the StableHLO text (the serialized Mosaic kernels in it) that
+# a flash call with ONE width for q, k and v and no scale lowers to for
+# the described v5e, at a small shape, as the commit before latent
+# attention's widths and scale gave it (38c2b3c, with
+# ``jax_traceback_in_locations_limit`` 0 as ``enable_compile_cache``
+# sets it: a kernel is serialized with its locations): the cells that
+# ran before lower to the text they lowered to.
+_FLASH_TEXT_BEFORE = {
+    "plain": "6a872146a9794b17f0adb79dcad6d27f7c334a3bb733d764087b9b20"
+             "61c72607",
+    "window": "2af7471246f09261b7dc8bef2fe606e5186269b2839cfbc64a9ab035"
+              "0ee16364"}
+
+
+@pytest.mark.parametrize("kind, shapes, kw", [
+    ("plain", (((2, 512, 4, 128), BF16), ((2, 512, 2, 128), BF16),
+               ((2, 512, 2, 128), BF16)), {}),
+    ("window", (((1, 2048, 4, 128), BF16), ((1, 2048, 2, 128), BF16),
+                ((1, 2048, 2, 128), BF16)), {"window": 512})],
+    ids=["plain", "window"])
+def test_a_flash_call_of_one_width_lowers_to_the_text_it_did(
+        for_tpu, v5e_chip, kind, shapes, kw):
+    import hashlib
+
+    from horovod_tpu.ops import flash_attention
+
+    def grads(q, k, v, w):
+        return jax.grad(lambda q, k, v: jnp.sum(
+            flash_attention(q, k, v, **kw).astype(F32) * w.astype(F32)),
+            argnums=(0, 1, 2))(q, k, v)
+
+    was = jax.config.jax_traceback_in_locations_limit
+    jax.config.update("jax_traceback_in_locations_limit", 0)
+    try:
+        args = [jax.ShapeDtypeStruct(s, d, sharding=v5e_chip)
+                for s, d in shapes + (shapes[0],)]
+        text = jax.jit(grads).lower(*args).as_text()
+    finally:
+        jax.config.update("jax_traceback_in_locations_limit", was)
+    assert hashlib.sha256(text.encode()).hexdigest() \
+        == _FLASH_TEXT_BEFORE[kind]
 
 
 # An f32 array one wide under the default tiling: every value fills a
@@ -673,6 +742,40 @@ def test_the_sala_cells_grad_program_fits_the_described_v5e(v5e_chip,
                       for s in jax.tree.leaves(shapes))
     assert peak + moments < 15.75 * 2 ** 30
     assert abs(peak / 1e9 - config["assumed"]["compiled_peak_gb"]) < 0.2
+
+
+@pytest.mark.slow
+def test_the_xing4_cells_grad_program_fits_the_described_v5e(v5e_chip,
+                                                             for_tpu):
+    """The cell's grad program at [1, 8192] with the file's ``remat`` and
+    ``loss_chunk`` compiles for the described v5e within the 15.75 GiB
+    its programs get, Adam's two moments beside it, and holds the flash
+    pair by name and no other named Mosaic call
+    (``moe_gmm_ms_per_step`` takes every other one for megablox's). Five
+    minutes of one core: not in tier-1 (CHANGES.md, PR 57); every run of
+    the cell on the chip proves the fit again."""
+    sys.path.insert(0, REPO)
+    from chipbench import child
+
+    _, _, config, traffic = child.find_cell("xing4.spmd.b1s8192")
+    model = child.load_file("models", "xing4").Model(config, traffic)
+    shapes = jax.eval_shape(lambda k: model.init(k)[0],
+                            jax.random.PRNGKey(0))
+    params = jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+        s.shape, s.dtype, sharding=v5e_chip), shapes)
+    batch = {k: jax.ShapeDtypeStruct((1, 8192), I32, sharding=v5e_chip)
+             for k in ("tokens", "targets")}
+    compiled = jax.jit(
+        lambda p, d: jax.value_and_grad(
+            lambda p, d: model.loss(p, (), d)[0])(p, d),
+        compiler_options=model.compiler_options).lower(
+        params, batch).compile()
+    peak = compiled.memory_analysis().peak_memory_in_bytes
+    moments = 2 * sum(s.size * s.dtype.itemsize
+                      for s in jax.tree.leaves(shapes))
+    assert peak + moments < 15.75 * 2 ** 30
+    named = set(re.findall(r'"kernel":"([a-z_0-9]+)"', compiled.as_text()))
+    assert named == {"hvd_flash_fwd", "hvd_flash_bwd_fused"}
 
 
 _CHAIN_KERNELS = ("hvd_gdn_chain_in_fwd", "hvd_gdn_chain_in_bwd",

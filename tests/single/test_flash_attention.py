@@ -766,3 +766,72 @@ def test_head_major_entry_is_the_public_entry(interpret, window, mesh_shape):
     _assert_grads_close(got_grads, ref_grads)
     with pytest.raises(ValueError, match="window"):
         fa_mod.flash_attention_head_major(q, k, v, causal=False, window=20)
+
+
+# -- latent attention's call: values another width, the scale handed in
+
+
+def _latent_operands(B=1, T=64, H=4, dqk=24, dv=16):
+    ks = jax.random.split(jax.random.PRNGKey(7), 4)
+    return (jax.random.normal(ks[0], (B, T, H, dqk), jnp.float32),
+            jax.random.normal(ks[1], (B, T, H, dqk), jnp.float32),
+            jax.random.normal(ks[2], (B, T, H, dv), jnp.float32),
+            jax.random.normal(ks[3], (B, T, H, dv), jnp.float32))
+
+
+@pytest.mark.parametrize("scale", [None, 1.4159 ** 2 / 24 ** 0.5])
+@pytest.mark.parametrize("blocks", [(64, 64), (16, 32)])
+def test_values_of_another_width_and_a_scale_handed_in(scale, blocks):
+    """Queries and keys one and a half times as wide as the values (the
+    published 192 / 128 in small) through the kernels in interpret mode
+    against ``blockwise_attention``, forward and the three gradients."""
+    from horovod_tpu.ops import flash_attention
+    from horovod_tpu.parallel.ring_attention import blockwise_attention
+
+    q, k, v, w = _latent_operands()
+
+    def grads(attn, **kw):
+        def f(q, k, v):
+            out = attn(q, k, v, causal=True, scale=scale, **kw)
+            return jnp.sum(out * w), out
+        return jax.grad(f, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+
+    got, out = grads(flash_attention, block_q=blocks[0], block_k=blocks[1])
+    want, out_want = grads(blockwise_attention)
+    assert out.shape == v.shape
+    np.testing.assert_allclose(out, out_want, rtol=2e-4, atol=2e-4)
+    for g, r in zip(got, want):
+        assert g.shape == r.shape
+        np.testing.assert_allclose(g, r, rtol=2e-4, atol=2e-4)
+    # the handed-in scale is no 1 / sqrt(D): leaving it out shows
+    if scale is not None:
+        plain = flash_attention(q, k, v, causal=True)
+        assert float(jnp.abs(plain - out).max()) > 1e-2
+
+
+def test_one_width_and_no_scale_is_bit_for_bit_what_it_was():
+    """The call every other configuration makes: the default scale is
+    ``1 / sqrt(D)`` handed in, to the bit, forward and backward."""
+    from horovod_tpu.ops import flash_attention
+
+    q, k, _, _ = _latent_operands()
+    v = k * 0.5 + 1.0
+
+    def grads(**kw):
+        return jax.grad(lambda q, k, v: jnp.sum(jnp.square(flash_attention(
+            q, k, v, causal=True, block_q=32, block_k=32, **kw))),
+            argnums=(0, 1, 2))(q, k, v)
+
+    for a, b in zip(grads(), grads(scale=q.shape[-1] ** -0.5)):
+        np.testing.assert_array_equal(a, b)
+
+
+def test_a_bias_takes_no_scale_and_one_width():
+    from horovod_tpu.ops import flash_attention
+
+    q, k, v, _ = _latent_operands()
+    bias = jnp.zeros((1, 64), jnp.float32)
+    with pytest.raises(ValueError, match="no scale and one width"):
+        flash_attention(q, k, v, causal=False, kv_bias=bias)
+    with pytest.raises(ValueError, match="no scale and one width"):
+        flash_attention(q, k, k, causal=False, kv_bias=bias, scale=0.1)
