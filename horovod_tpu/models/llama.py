@@ -1364,11 +1364,15 @@ def _sinkhorn(logits, iters, eps, clamp):
     Sinkhorn-Knopp iterations: rows over (their sum + ``eps``), then
     columns over (theirs + ``eps``). Plain arithmetic under a
     ``lax.scan``, so the gradient passes through every iteration; a
-    loop, not ``iters`` copies of the body: every part holds the
-    iterations three times over (forward, recomputed, backward), and
-    unrolled they made the grad program's executable 28% larger (past
-    what the compile cache it was measured under would keep) for a step
-    6% shorter. The trade is open: PERF.md section 7, PR 57."""
+    loop, not ``iters`` copies of the body, on THIS path, the
+    expression's: unrolled into the XLA program they made the grad
+    program's executable 28% larger for a step 6% shorter (PR 57). On
+    the chip the iterations run inside ``ops/hc_mix.py``'s kernels, on a
+    tile of tokens in VMEM, as a loop there too (a kernel's body is
+    traced and lowered in every process, so a copy of the body is paid
+    in set-up, and the chain of divisions gains nothing from lying
+    flat): the trade is closed for the chip (PERF.md section 6,
+    PR 58)."""
     def iteration(m, _):
         m = m / (m.sum(1, keepdims=True) + eps)
         return m / (m.sum(0, keepdims=True) + eps), None
@@ -1377,14 +1381,34 @@ def _sinkhorn(logits, iters, eps, clamp):
                     length=iters)[0]
 
 
-def _hc_coefficients(X, phi, alpha, bias, c):
+def _hc_sizes(c):
+    """What ``ops/hc_mix.py``'s kernels take of the arithmetic, read as
+    the program is traced (``_HC_POST_SCALE`` among them: a program a
+    value)."""
+    from horovod_tpu.ops.hc_mix import Sizes
+
+    return Sizes(int(c.hc_sinkhorn_iters), float(c.hc_eps),
+                 tuple(float(x) for x in c.hc_clamp), float(c.norm_eps),
+                 float(_HC_POST_SCALE))
+
+
+def _hc_coefficients(X, phi, alpha, bias, c, sharded=False):
     """The three sets of coefficients of one part from the streams ``X``
     [B, n, T, D] -> float32 (``H_pre`` [B, n, T], ``H_post`` [B, n, T],
     ``H_res`` [B, n, n, T]: row, column). ``x~ Phi`` is ``(x Phi) / rms``:
     the streams multiply ``phi`` [n, D, n (n + 2)] as they are stored
     (the compute dtype's operands, float32 accumulation, a stream a
     matmul), the RMS over all ``n D`` values (no gain) divides the 24
-    results; everything from there on is float32."""
+    results; everything from there on is float32. One algorithm, two
+    carriers: where ``hc_mix.on_kernels`` says so (streams on a TPU,
+    whole lane slabs and tiles, a carry no mesh axis divides:
+    ``sharded``) what comes back is what ``hvd_hc_pre_fwd`` computed,
+    the program's own coefficients; elsewhere this expression, which is
+    also what the kernels are held to."""
+    from horovod_tpu.ops import hc_mix
+
+    if hc_mix.on_kernels(X, sharded):
+        return hc_mix.coefficients(X, phi, alpha, bias, _hc_sizes(c))
     f32, n = jnp.float32, c.hc_mult
     b, _, t, d = X.shape
     proj = sum(jnp.matmul(X[:, i], phi[i].astype(X.dtype),
@@ -1403,19 +1427,33 @@ def _hc_coefficients(X, phi, alpha, bias, c):
             jnp.moveaxis(res, 2, 0))
 
 
-def _hyper_connection(X, lp, name, c, part):
+def _hyper_connection(X, lp, name, c, part, sharded=False):
     """One part round the streams ``X`` [B, n, T, D] (mHC, arXiv
     2512.24880): ``u = sum_i H_pre[i] X[i]`` is what ``part`` reads
     ([B, T, D] -> (its output, its aux)), and ``X'[i] = sum_j H_res[i,
     j] X[j] + H_post[i] part(u)``; the coefficients from ``lp``'s
     ``hc_<name>_*`` leaves (``_hc_coefficients``). The streams are read
     and written in the compute dtype, the sums over streams run in
-    float32; elementwise passes a stream, never a matmul of 4 x 4."""
+    float32; elementwise passes a stream, never a matmul of 4 x 4.
+
+    One algorithm, two carriers, chosen by what the code observes
+    (``hc_mix.on_kernels``: the streams on a TPU, ``D`` whole lane
+    slabs, the tokens whole tiles, and no mesh axis dividing the carry,
+    ``sharded``): on the chip two Pallas kernel pairs in the carry's own
+    layout, ``hvd_hc_pre_fwd`` / ``_bwd`` ahead of the part and
+    ``hvd_hc_post_fwd`` / ``_bwd`` behind it (``ops/hc_mix.py``: one
+    read of ``X`` each, the iterations in VMEM, ``dX`` rounded once, no
+    float32 copy of the carry in HBM); elsewhere the expression below,
+    which is also the kernels' reference in the tests."""
+    from horovod_tpu.ops import hc_mix
+
+    leaves = (lp[f"hc_{name}_phi"], lp[f"hc_{name}_alpha"],
+              lp[f"hc_{name}_bias"])
+    if hc_mix.on_kernels(X, sharded):
+        return hc_mix.hyper_connection(X, *leaves, part, _hc_sizes(c))
     f32, n = jnp.float32, c.hc_mult
     with scope("hvd.hc.mix"):
-        pre, post, res = _hc_coefficients(
-            X, lp[f"hc_{name}_phi"], lp[f"hc_{name}_alpha"],
-            lp[f"hc_{name}_bias"], c)
+        pre, post, res = _hc_coefficients(X, *leaves, c, sharded)
         Xf = X.astype(f32)
         u = (pre[..., None] * Xf).sum(1).astype(X.dtype)
     y, aux = part(u)
@@ -2521,7 +2559,11 @@ def _build_layer_body(c, mesh, seq_axis, constrain_acts=True, kind=None):
     if c.hc_mult:
         # The same two parts round ``hc_mult`` streams [B, n, T, D]:
         # each reads ``_hyper_connection``'s blend and is added where
-        # its coefficients say.
+        # its coefficients say. A mesh axis that divides the carry
+        # (``_activation_spec``: the batch, the sequence) keeps it on
+        # the expression (GSPMD cannot partition a Mosaic call).
+        carry_sharded = mesh is not None and any(
+            mesh.shape.get(a, 1) > 1 for a in ("data", "fsdp", "seq"))
         def hc_mix(x, lp):
             def part(u):
                 y = mix(u, lp)
@@ -2529,7 +2571,8 @@ def _build_layer_body(c, mesh, seq_axis, constrain_acts=True, kind=None):
                     y = _rmsnorm(y, lp["post_attn_norm"].astype(dt),
                                  c.norm_eps)
                 return joins(y), None
-            return _hyper_connection(x, lp, "attn", c, part)[0]
+            return _hyper_connection(x, lp, "attn", c, part,
+                                     carry_sharded)[0]
 
         def hc_ffn(x, lp):
             def part(u):
@@ -2539,7 +2582,7 @@ def _build_layer_body(c, mesh, seq_axis, constrain_acts=True, kind=None):
                     ff = _rmsnorm(ff, lp["post_mlp_norm"].astype(dt),
                                   c.norm_eps)
                 return joins(ff), aux
-            x, aux = _hyper_connection(x, lp, "mlp", c, part)
+            x, aux = _hyper_connection(x, lp, "mlp", c, part, carry_sharded)
             return constrain(x), aux
 
         def streams_layer(x, lp):

@@ -750,8 +750,9 @@ def test_the_xing4_cells_grad_program_fits_the_described_v5e(v5e_chip,
     """The cell's grad program at [1, 8192] with the file's ``remat`` and
     ``loss_chunk`` compiles for the described v5e within the 15.75 GiB
     its programs get, Adam's two moments beside it, and holds the flash
-    pair by name and no other named Mosaic call
-    (``moe_gmm_ms_per_step`` takes every other one for megablox's). Five
+    pair and the hyper-connections' two pairs (``ops/hc_mix.py``) by name
+    and no other named Mosaic call (``moe_gmm_ms_per_step`` takes every
+    call without ``hvd_flash`` in its name for megablox's). Five
     minutes of one core: not in tier-1 (CHANGES.md, PR 57); every run of
     the cell on the chip proves the fit again."""
     sys.path.insert(0, REPO)
@@ -775,7 +776,7 @@ def test_the_xing4_cells_grad_program_fits_the_described_v5e(v5e_chip,
                       for s in jax.tree.leaves(shapes))
     assert peak + moments < 15.75 * 2 ** 30
     named = set(re.findall(r'"kernel":"([a-z_0-9]+)"', compiled.as_text()))
-    assert named == {"hvd_flash_fwd", "hvd_flash_bwd_fused"}
+    assert named == {"hvd_flash_fwd", "hvd_flash_bwd_fused", *_HC_KERNELS}
 
 
 _CHAIN_KERNELS = ("hvd_gdn_chain_in_fwd", "hvd_gdn_chain_in_bwd",
@@ -821,6 +822,88 @@ def _ssd_chain_fwd_bwd(zxr, taps, bias, gain):
 
     return jax.value_and_grad(loss, argnums=(0, 1, 2, 3))(
         zxr, taps, bias, gain)
+
+
+_HC_KERNELS = ("hvd_hc_pre_fwd", "hvd_hc_pre_bwd", "hvd_hc_post_fwd",
+               "hvd_hc_post_bwd")
+# Xing4.0's four streams at the chip cell's size: B1 T8192 D3584, a
+# part's three leaves (float32, as the parameters are kept), and the
+# stand-in part's gain.
+_HC = (((1, 4, 8192, 3584), BF16), ((4, 3584, 24), F32), ((3,), F32),
+       ((24,), F32), ((3584,), BF16))
+
+
+class _HcSizes:
+    hc_mult, hc_sinkhorn_iters, hc_eps = 4, 20, 1e-6
+    hc_clamp, norm_eps = (-30.0, 30.0), 1e-6
+
+
+def _hc_fwd_bwd(X, phi, alpha, bias, gain):
+    """One part round the streams on the two kernel pairs, the part a
+    stand-in that reads ``u``: values and gradients."""
+    from horovod_tpu.models import llama
+
+    def loss(X, phi, alpha, bias, gain):
+        lp = {"hc_a_phi": phi, "hc_a_alpha": alpha, "hc_a_bias": bias}
+        out, _ = llama._hyper_connection(
+            X, lp, "a", _HcSizes, lambda u: (u * gain, None))
+        return (out.astype(F32) ** 2).sum()
+
+    return jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))(
+        X, phi, alpha, bias, gain)
+
+
+def test_hc_mix_compiles_for_described_v5e(for_tpu):
+    """A part's stream mixing at the chip cell's size as the chip's
+    compiler takes it (a grid step a tile of 128 tokens of all four
+    streams at full width, the VMEM it asks for by name), each of the
+    four kernels by the name a device trace shows, and no float32 copy
+    of the carry among the results of the program's instructions (inside
+    a fusion the stand-in loss squares one, in registers)."""
+    text = for_tpu(_hc_fwd_bwd, *_HC)
+    for name in _HC_KERNELS:
+        assert f'"kernel":"{name}"' in text, name
+    entry = text[text.index("\nENTRY "):]
+    assert "= bf16[1,4,8192,3584]" in entry
+    assert "= f32[1,4,8192,3584]" not in entry
+
+
+def test_three_parts_lower_each_hc_kernel_once_a_form(v5e_chip, for_tpu):
+    """The set-up budget (``test_three_mixers_...``) on the
+    hyper-connections at the chip cell's size: three parts, a checkpoint
+    a part as remat "attn/ffn" wraps them. A Mosaic lowering a kernel
+    FORM whatever the parts, each site a call of its kernel's jitted
+    wrapper: ``hvd_hc_pre_fwd`` six times as TWO lowered functions (the
+    recomputation's comes with a jaxpr of its own), ``hvd_hc_post_fwd``
+    three times and not six (nothing reads the recomputed ``X'``), the
+    backward kernels three times each. Five lowerings a program, not
+    fifteen."""
+    from horovod_tpu.models import llama
+
+    once = functools.partial(
+        jax.checkpoint, policy=jax.checkpoint_policies.save_only_these_names(
+            "attn_out", "flash_o", "flash_lse"))
+
+    def loss(X, phi, alpha, bias, gain):
+        lp = {"hc_a_phi": phi, "hc_a_alpha": alpha, "hc_a_bias": bias}
+
+        def part(X, lp):
+            return llama._hyper_connection(
+                X, lp, "a", _HcSizes, lambda u: (u * gain, None))[0]
+
+        for _ in range(3):
+            X = once(part)(X, lp)
+        return X.astype(F32).sum()
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        *(jax.ShapeDtypeStruct(s, d, sharding=v5e_chip) for s, d in _HC)
+    ).as_text()
+    assert [text.count(name) for name in _HC_KERNELS] == [2, 1, 1, 1]
+    assert text.count("tpu_custom_call") == 5
+    for wrapper, sites in (("_pre_fwd", 6), ("_pre_bwd", 3),
+                           ("_post_fwd", 3), ("_post_bwd", 3)):
+        assert len(re.findall(rf"call @{wrapper}(_\d+)?\(", text)) \
+            == sites, wrapper
 
 
 @pytest.mark.parametrize("fn, shapes, names", [
