@@ -147,13 +147,6 @@ class LlamaConfig:
     # "ulysses" (all-to-all head/sequence reshard — needs
     # n_heads % seq_size == 0, cheaper at short per-device sequences).
     seq_parallel: str = "ring"
-    # Unroll factor of the DENSE layer scan (1 = rolled, n_layers =
-    # fully unrolled): scheduling only, at the price of compile time
-    # and program size; no cell or example sets it and no reading on
-    # this chip says it pays. It governs nothing else: a stack of
-    # grouped expert layers and a layer pattern run unrolled whatever it
-    # says (``_run_layers`` says why), a pipeline stage always scans.
-    scan_unroll: int = 1
     # Pallas flash-attention block size (both the q and k grid blocks;
     # 0 = the kernel default, 1024 — the measured optimum of
     # {256,512,1024,2048}² at t2048, docs/benchmarks.md r4). Exposed so
@@ -2278,11 +2271,10 @@ def _run_layers(params, x, c, mesh, seq_axis, mtp=False):
     it cannot:
 
     - a uniform DENSE stack (or GShard experts: einsums) is ONE
-      ``lax.scan`` of the layer body, ``unroll=c.scan_unroll``: XLA
-      fuses the scan's ``dynamic-slice`` into the matmul that reads the
-      weight and its ``dynamic-update-slice`` into the one that writes
-      the gradient, so the rolled loop costs nothing and the program is
-      O(1) in depth;
+      ``lax.scan`` of the layer body: XLA fuses the scan's
+      ``dynamic-slice`` into the matmul that reads the weight and its
+      ``dynamic-update-slice`` into the one that writes the gradient, so
+      the rolled loop costs nothing and the program is O(1) in depth;
     - a uniform stack of GROUPED expert layers (``_grouped_dispatch``)
       runs unrolled, each body on a STATIC index of ``params["layers"]``.
       Its matrices feed megablox custom calls, which no fusion enters:
@@ -2329,7 +2321,7 @@ def _run_layer_plan(params, x, c, mesh, seq_axis, mtp):
 
     if len(kinds) == 1 and not _grouped_dispatch(c, mesh):
         return lax.scan(_build_layer_body(c, mesh, seq_axis), x,
-                        stack_of(plan[0]), unroll=c.scan_unroll)
+                        stack_of(plan[0]))
     from horovod_tpu.ops.grouped_moe import LayerOfStack
 
     bodies = {kind: _build_layer_body(c, mesh, seq_axis, kind=kind)
@@ -2357,8 +2349,7 @@ def _run_layer_plan(params, x, c, mesh, seq_axis, mtp):
             # the program's end, where they are concatenated into the
             # stack's: 3.2 GB of a 1.6 B-parameter model's 4.56 GB of
             # temporaries (compiled for the described v5e, PR 47).
-            x, bal = lax.scan(bodies[spec.kind], x, stack_of(spec),
-                              unroll=c.scan_unroll)
+            x, bal = lax.scan(bodies[spec.kind], x, stack_of(spec))
             balance.extend(bal[i] for i in range(depth[spec.stack]))
             at += depth[spec.stack]
             continue

@@ -7,9 +7,21 @@ test_tensorflow2_keras.py (SURVEY.md §4).
 import numpy as np
 import pytest
 
-from tests.utils_mp import run_ranks
+from tests import utils_mp
 
 _TF_ENV = {"TF_CPP_MIN_LOG_LEVEL": "3", "CUDA_VISIBLE_DEVICES": ""}
+
+
+@pytest.fixture(scope="module")
+def tf_world():
+    """``tf_world(size)``: this module's ranks, up since the first test
+    that asked for that size: they have imported TensorFlow and run
+    ``hvd.init()`` ONCE (fifteen seconds a world; a body takes under
+    one), and each test hands them its own body (tests/utils_mp.py:
+    ``World``). A body calls neither ``init`` nor ``shutdown``."""
+    with utils_mp.worlds("horovod_tpu.tensorflow", env=_TF_ENV) as world:
+        yield world
+
 
 def _assert_ok_or_loud_skip(results, n):
     """The native-op tests must never pass vacuously: when the op
@@ -20,7 +32,6 @@ def _assert_ok_or_loud_skip(results, n):
                     "(tf2xla headers missing) — in-jit collectives NOT "
                     "exercised")
     assert results == ["ok"] * n
-
 
 
 def test_async_build_never_blocks_init(tmp_path, monkeypatch):
@@ -78,74 +89,64 @@ def _worker_tf_ops(rank, size):
     import tensorflow as tf
     import horovod_tpu.tensorflow as hvd
 
-    hvd.init()
-    try:
-        assert hvd.rank() == rank and hvd.size() == size
+    assert hvd.rank() == rank and hvd.size() == size
 
-        r = hvd.allreduce(tf.fill([4, 3], float(rank)), op=hvd.Sum)
-        np.testing.assert_allclose(r.numpy(), sum(range(size)))
+    r = hvd.allreduce(tf.fill([4, 3], float(rank)), op=hvd.Sum)
+    np.testing.assert_allclose(r.numpy(), sum(range(size)))
 
-        avg = hvd.allreduce(tf.fill([5], float(rank)))
-        np.testing.assert_allclose(avg.numpy(), sum(range(size)) / size)
+    avg = hvd.allreduce(tf.fill([5], float(rank)))
+    np.testing.assert_allclose(avg.numpy(), sum(range(size)) / size)
 
-        g = hvd.allgather(tf.fill([rank + 1, 2], float(rank)))
-        assert g.shape == (sum(range(1, size + 1)), 2)
+    g = hvd.allgather(tf.fill([rank + 1, 2], float(rank)))
+    assert g.shape == (sum(range(1, size + 1)), 2)
 
-        b = hvd.broadcast(tf.fill([3], float(rank)), root_rank=size - 1)
-        np.testing.assert_allclose(b.numpy(), float(size - 1))
+    b = hvd.broadcast(tf.fill([3], float(rank)), root_rank=size - 1)
+    np.testing.assert_allclose(b.numpy(), float(size - 1))
 
-        outs = hvd.grouped_allreduce(
-            [tf.fill([2], float(rank + i)) for i in range(3)], op=hvd.Sum)
-        for i, o in enumerate(outs):
-            np.testing.assert_allclose(o.numpy(),
-                                       sum(rk + i for rk in range(size)))
+    outs = hvd.grouped_allreduce(
+        [tf.fill([2], float(rank + i)) for i in range(3)], op=hvd.Sum)
+    for i, o in enumerate(outs):
+        np.testing.assert_allclose(o.numpy(),
+                                   sum(rk + i for rk in range(size)))
 
-        # broadcast_variables
-        v = tf.Variable(tf.fill([4], float(rank)))
-        hvd.broadcast_variables([v], root_rank=0)
-        np.testing.assert_allclose(v.numpy(), 0.0)
-        return "ok"
-    finally:
-        hvd.shutdown()
+    # broadcast_variables
+    v = tf.Variable(tf.fill([4], float(rank)))
+    hvd.broadcast_variables([v], root_rank=0)
+    np.testing.assert_allclose(v.numpy(), 0.0)
+    return "ok"
 
 
 @pytest.mark.parametrize("size", [2])
-def test_tf_ops(size):
-    assert run_ranks(_worker_tf_ops, size, env=_TF_ENV, timeout=180) \
-        == ["ok"] * size
+def test_tf_ops(tf_world, size):
+    assert tf_world(size).run(_worker_tf_ops, timeout=180) == ["ok"] * size
 
 
 def _worker_gradient_tape(rank, size):
     import tensorflow as tf
     import horovod_tpu.tensorflow as hvd
 
-    hvd.init()
-    try:
-        w = tf.Variable([[1.0], [2.0]])
-        x = tf.constant([[float(rank + 1), 0.0]])
-        with hvd.DistributedGradientTape(tf.GradientTape()) as tape:
-            y = tf.reduce_sum(tf.matmul(x, w))
-        (gw,) = tape.gradient(y, [w])
-        # dy/dw = x^T; averaged across ranks
-        exp = np.array([[np.mean([rk + 1 for rk in range(size)])], [0.0]])
-        np.testing.assert_allclose(gw.numpy(), exp)
+    w = tf.Variable([[1.0], [2.0]])
+    x = tf.constant([[float(rank + 1), 0.0]])
+    with hvd.DistributedGradientTape(tf.GradientTape()) as tape:
+        y = tf.reduce_sum(tf.matmul(x, w))
+    (gw,) = tape.gradient(y, [w])
+    # dy/dw = x^T; averaged across ranks
+    exp = np.array([[np.mean([rk + 1 for rk in range(size)])], [0.0]])
+    np.testing.assert_allclose(gw.numpy(), exp)
 
-        # fp16 compression path
-        with hvd.DistributedGradientTape(tf.GradientTape(),
-                                         compression=hvd.Compression.fp16) \
-                as tape2:
-            y2 = tf.reduce_sum(tf.matmul(x, w))
-        (gw2,) = tape2.gradient(y2, [w])
-        assert gw2.dtype == tf.float32
-        np.testing.assert_allclose(gw2.numpy(), exp, rtol=1e-3)
-        return "ok"
-    finally:
-        hvd.shutdown()
+    # fp16 compression path
+    with hvd.DistributedGradientTape(tf.GradientTape(),
+                                     compression=hvd.Compression.fp16) \
+            as tape2:
+        y2 = tf.reduce_sum(tf.matmul(x, w))
+    (gw2,) = tape2.gradient(y2, [w])
+    assert gw2.dtype == tf.float32
+    np.testing.assert_allclose(gw2.numpy(), exp, rtol=1e-3)
+    return "ok"
 
 
-def test_distributed_gradient_tape():
-    assert run_ranks(_worker_gradient_tape, 2, env=_TF_ENV, timeout=180) \
-        == ["ok"] * 2
+def test_distributed_gradient_tape(tf_world):
+    assert tf_world(2).run(_worker_gradient_tape, timeout=180) == ["ok"] * 2
 
 
 def _worker_jit_compiled_train_step(rank, size):
@@ -157,50 +158,45 @@ def _worker_jit_compiled_train_step(rank, size):
     import horovod_tpu.tensorflow as hvd
     from horovod_tpu.tensorflow import mpi_ops
 
-    hvd.init()
-    try:
-        if mpi_ops._load_native() is None:
-            return "skip"  # no TF headers in this env: fallback only
+    if mpi_ops._load_native() is None:
+        return "skip"  # no TF headers in this env: fallback only
 
-        w = tf.Variable([[1.0], [2.0]])
-        opt = tf.keras.optimizers.SGD(0.5)
+    w = tf.Variable([[1.0], [2.0]])
+    opt = tf.keras.optimizers.SGD(0.5)
 
-        @tf.function(jit_compile=True)
-        def train_step(x):
-            with hvd.DistributedGradientTape(tf.GradientTape()) as tape:
-                y = tf.reduce_sum(tf.matmul(x, w))
-            grads = tape.gradient(y, [w])
-            opt.apply_gradients(zip(grads, [w]))
-            return grads[0]
+    @tf.function(jit_compile=True)
+    def train_step(x):
+        with hvd.DistributedGradientTape(tf.GradientTape()) as tape:
+            y = tf.reduce_sum(tf.matmul(x, w))
+        grads = tape.gradient(y, [w])
+        opt.apply_gradients(zip(grads, [w]))
+        return grads[0]
 
-        x = tf.constant([[float(rank + 1), 0.0]])
-        gw = train_step(x)
-        exp = np.array([[np.mean([rk + 1 for rk in range(size)])], [0.0]])
-        np.testing.assert_allclose(gw.numpy(), exp)
-        # the update actually applied the AVERAGED gradient, identically
-        # on every rank
-        np.testing.assert_allclose(w.numpy(), [[1.0 - 0.5 * exp[0, 0]],
-                                               [2.0]])
-        # replay: the compiled program re-negotiates the same tensor
-        # names each step (response-cache steady state)
-        gw2 = train_step(x)
-        np.testing.assert_allclose(gw2.numpy(), exp)
+    x = tf.constant([[float(rank + 1), 0.0]])
+    gw = train_step(x)
+    exp = np.array([[np.mean([rk + 1 for rk in range(size)])], [0.0]])
+    np.testing.assert_allclose(gw.numpy(), exp)
+    # the update actually applied the AVERAGED gradient, identically
+    # on every rank
+    np.testing.assert_allclose(w.numpy(), [[1.0 - 0.5 * exp[0, 0]],
+                                           [2.0]])
+    # replay: the compiled program re-negotiates the same tensor
+    # names each step (response-cache steady state)
+    gw2 = train_step(x)
+    np.testing.assert_allclose(gw2.numpy(), exp)
 
-        # in-jit broadcast, from a non-zero root
-        @tf.function(jit_compile=True)
-        def bstep(t):
-            return hvd.broadcast(t, root_rank=size - 1, name="jit.b") * 2.0
+    # in-jit broadcast, from a non-zero root
+    @tf.function(jit_compile=True)
+    def bstep(t):
+        return hvd.broadcast(t, root_rank=size - 1, name="jit.b") * 2.0
 
-        out = bstep(tf.fill([3], float(rank)))
-        np.testing.assert_allclose(out.numpy(), 2.0 * (size - 1))
-        return "ok"
-    finally:
-        hvd.shutdown()
+    out = bstep(tf.fill([3], float(rank)))
+    np.testing.assert_allclose(out.numpy(), 2.0 * (size - 1))
+    return "ok"
 
 
-def test_jit_compiled_train_step():
-    results = run_ranks(_worker_jit_compiled_train_step, 2, env=_TF_ENV,
-                        timeout=300)
+def test_jit_compiled_train_step(tf_world):
+    results = tf_world(2).run(_worker_jit_compiled_train_step, timeout=300)
     _assert_ok_or_loud_skip(results, 2)
 
 
@@ -212,37 +208,32 @@ def _worker_jit_managed_ops(rank, size):
     import horovod_tpu.tensorflow as hvd
     from horovod_tpu.tensorflow import mpi_ops
 
-    hvd.init()
-    try:
-        if mpi_ops._load_native() is None:
-            return "skip"
+    if mpi_ops._load_native() is None:
+        return "skip"
 
-        @tf.function(jit_compile=True)
-        def step(t):
-            g = hvd.allgather(t, name="jm.ag")              # [2s, 3]
-            rs = hvd.reducescatter(g, op=hvd.Sum, name="jm.rs")  # [2, 3]
-            a = hvd.alltoall(t, name="jm.a2a")              # [2, 3]
-            return g, rs, a
+    @tf.function(jit_compile=True)
+    def step(t):
+        g = hvd.allgather(t, name="jm.ag")              # [2s, 3]
+        rs = hvd.reducescatter(g, op=hvd.Sum, name="jm.rs")  # [2, 3]
+        a = hvd.alltoall(t, name="jm.a2a")              # [2, 3]
+        return g, rs, a
 
-        t = tf.fill([2, 3], float(rank + 1))
-        g, rs, a = step(t)
-        exp_g = np.repeat(np.arange(1, size + 1, dtype=np.float32), 2)
-        np.testing.assert_allclose(g.numpy(), exp_g[:, None] * np.ones(3))
-        # summed-then-scattered: this rank holds its own 2 rows x size
-        np.testing.assert_allclose(rs.numpy(), size * (rank + 1))
-        # equal-split alltoall: one row from every rank
-        exp_a = np.repeat(np.arange(1, size + 1, dtype=np.float32),
-                          2 // size if size <= 2 else 1)[:2]
-        np.testing.assert_allclose(np.sort(a.numpy()[:, 0]),
-                                   np.sort(exp_a))
-        return "ok"
-    finally:
-        hvd.shutdown()
+    t = tf.fill([2, 3], float(rank + 1))
+    g, rs, a = step(t)
+    exp_g = np.repeat(np.arange(1, size + 1, dtype=np.float32), 2)
+    np.testing.assert_allclose(g.numpy(), exp_g[:, None] * np.ones(3))
+    # summed-then-scattered: this rank holds its own 2 rows x size
+    np.testing.assert_allclose(rs.numpy(), size * (rank + 1))
+    # equal-split alltoall: one row from every rank
+    exp_a = np.repeat(np.arange(1, size + 1, dtype=np.float32),
+                      2 // size if size <= 2 else 1)[:2]
+    np.testing.assert_allclose(np.sort(a.numpy()[:, 0]),
+                               np.sort(exp_a))
+    return "ok"
 
 
-def test_jit_managed_collectives():
-    results = run_ranks(_worker_jit_managed_ops, 2, env=_TF_ENV,
-                        timeout=300)
+def test_jit_managed_collectives(tf_world):
+    results = tf_world(2).run(_worker_jit_managed_ops, timeout=300)
     _assert_ok_or_loud_skip(results, 2)
 
 
@@ -253,37 +244,32 @@ def _worker_native_process_sets(rank, size):
     import horovod_tpu.tensorflow as hvd
     from horovod_tpu.tensorflow import mpi_ops
 
-    hvd.init()
-    try:
-        if mpi_ops._load_native() is None:
-            return "skip"
-        evens = hvd.add_process_set([r for r in range(size) if r % 2 == 0])
-        odds = hvd.add_process_set([r for r in range(size) if r % 2 == 1])
-        hvd.barrier()
-        mine = evens if rank % 2 == 0 else odds
-        peers = [r for r in range(size) if r % 2 == rank % 2]
+    if mpi_ops._load_native() is None:
+        return "skip"
+    evens = hvd.add_process_set([r for r in range(size) if r % 2 == 0])
+    odds = hvd.add_process_set([r for r in range(size) if r % 2 == 1])
+    hvd.barrier()
+    mine = evens if rank % 2 == 0 else odds
+    peers = [r for r in range(size) if r % 2 == rank % 2]
 
-        out = hvd.allreduce(tf.fill([3], float(rank + 1)), op=hvd.Sum,
-                            name="nps.ar", process_set_id=mine)
-        np.testing.assert_allclose(out.numpy(),
-                                   sum(r + 1 for r in peers))
+    out = hvd.allreduce(tf.fill([3], float(rank + 1)), op=hvd.Sum,
+                        name="nps.ar", process_set_id=mine)
+    np.testing.assert_allclose(out.numpy(),
+                               sum(r + 1 for r in peers))
 
-        @tf.function(jit_compile=True)
-        def j(t):
-            return hvd.allreduce(t, op=hvd.Sum, name="nps.jar",
-                                 process_set_id=mine) * 2.0
+    @tf.function(jit_compile=True)
+    def j(t):
+        return hvd.allreduce(t, op=hvd.Sum, name="nps.jar",
+                             process_set_id=mine) * 2.0
 
-        out = j(tf.fill([2], float(rank + 1)))
-        np.testing.assert_allclose(out.numpy(),
-                                   2.0 * sum(r + 1 for r in peers))
-        return "ok"
-    finally:
-        hvd.shutdown()
+    out = j(tf.fill([2], float(rank + 1)))
+    np.testing.assert_allclose(out.numpy(),
+                               2.0 * sum(r + 1 for r in peers))
+    return "ok"
 
 
-def test_native_ops_process_sets():
-    results = run_ranks(_worker_native_process_sets, 4, env=_TF_ENV,
-                        timeout=300)
+def test_native_ops_process_sets(tf_world):
+    results = tf_world(4).run(_worker_native_process_sets, timeout=300)
     _assert_ok_or_loud_skip(results, 4)
 
 
@@ -295,38 +281,33 @@ def _worker_keras_jit_compile_fit(rank, size):
     import horovod_tpu.keras as hvd
     from horovod_tpu.tensorflow import mpi_ops
 
-    hvd.init()
-    try:
-        if mpi_ops._load_native() is None:
-            return "skip"
-        tf.keras.utils.set_random_seed(42 + rank)
-        model = tf.keras.Sequential([
-            tf.keras.layers.Dense(4, input_shape=(8,)),
-            tf.keras.layers.Dense(1),
-        ])
-        opt = hvd.DistributedOptimizer(tf.keras.optimizers.SGD(0.1))
-        model.compile(optimizer=opt, loss="mse", jit_compile=True)
-        hvd.broadcast_variables(model.variables, root_rank=0, prefix="m")
-        x = tf.random.stateless_uniform([16, 8], seed=[rank, 1])
-        y = tf.random.stateless_uniform([16, 1], seed=[rank, 2])
-        model.fit(x, y, batch_size=8, epochs=2, verbose=0)
+    if mpi_ops._load_native() is None:
+        return "skip"
+    tf.keras.utils.set_random_seed(42 + rank)
+    model = tf.keras.Sequential([
+        tf.keras.layers.Dense(4, input_shape=(8,)),
+        tf.keras.layers.Dense(1),
+    ])
+    opt = hvd.DistributedOptimizer(tf.keras.optimizers.SGD(0.1))
+    model.compile(optimizer=opt, loss="mse", jit_compile=True)
+    hvd.broadcast_variables(model.variables, root_rank=0, prefix="m")
+    x = tf.random.stateless_uniform([16, 8], seed=[rank, 1])
+    y = tf.random.stateless_uniform([16, 1], seed=[rank, 2])
+    model.fit(x, y, batch_size=8, epochs=2, verbose=0)
 
-        import horovod_tpu.tensorflow as hvdtf
+    import horovod_tpu.tensorflow as hvdtf
 
-        for i, v in enumerate(model.trainable_variables):
-            g = hvdtf.allgather(tf.reshape(v, [1, -1]),
-                                name=f"kjc.{i}").numpy()
-            for row in g[1:]:
-                np.testing.assert_allclose(row, g[0], rtol=1e-5,
-                                           atol=1e-6)
-        return "ok"
-    finally:
-        hvd.shutdown()
+    for i, v in enumerate(model.trainable_variables):
+        g = hvdtf.allgather(tf.reshape(v, [1, -1]),
+                            name=f"kjc.{i}").numpy()
+        for row in g[1:]:
+            np.testing.assert_allclose(row, g[0], rtol=1e-5,
+                                       atol=1e-6)
+    return "ok"
 
 
-def test_keras_jit_compile_fit():
-    results = run_ranks(_worker_keras_jit_compile_fit, 2, env=_TF_ENV,
-                        timeout=300)
+def test_keras_jit_compile_fit(tf_world):
+    results = tf_world(2).run(_worker_keras_jit_compile_fit, timeout=300)
     _assert_ok_or_loud_skip(results, 2)
 
 
@@ -334,42 +315,38 @@ def _worker_keras(rank, size):
     import tensorflow as tf
     import horovod_tpu.keras as hvd
 
-    hvd.init()
-    try:
-        tf.keras.utils.set_random_seed(42 + rank)
-        model = tf.keras.Sequential(
-            [tf.keras.layers.Dense(4, input_shape=(8,)),
-             tf.keras.layers.Dense(1)])
-        opt = hvd.DistributedOptimizer(tf.keras.optimizers.SGD(0.1))
+    tf.keras.utils.set_random_seed(42 + rank)
+    model = tf.keras.Sequential(
+        [tf.keras.layers.Dense(4, input_shape=(8,)),
+         tf.keras.layers.Dense(1)])
+    opt = hvd.DistributedOptimizer(tf.keras.optimizers.SGD(0.1))
 
-        # broadcast weights from rank 0 (diverged seeds above)
-        hvd.broadcast_variables(model.variables, root_rank=0,
-                                prefix="model")
+    # broadcast weights from rank 0 (diverged seeds above)
+    hvd.broadcast_variables(model.variables, root_rank=0,
+                            prefix="model")
 
-        x = tf.random.stateless_uniform([4, 8], seed=[rank, 1])
-        y = tf.random.stateless_uniform([4, 1], seed=[rank, 2])
-        with tf.GradientTape() as tape:
-            loss = tf.reduce_mean((model(x) - y) ** 2)
-        grads = tape.gradient(loss, model.trainable_variables)
-        opt.apply_gradients(zip(grads, model.trainable_variables))
+    x = tf.random.stateless_uniform([4, 8], seed=[rank, 1])
+    y = tf.random.stateless_uniform([4, 1], seed=[rank, 2])
+    with tf.GradientTape() as tape:
+        loss = tf.reduce_mean((model(x) - y) ** 2)
+    grads = tape.gradient(loss, model.trainable_variables)
+    opt.apply_gradients(zip(grads, model.trainable_variables))
 
-        # all ranks converge to identical weights
-        import horovod_tpu.tensorflow as hvdtf
+    # all ranks converge to identical weights
+    import horovod_tpu.tensorflow as hvdtf
 
-        for i, v in enumerate(model.trainable_variables):
-            gathered = hvdtf.allgather(
-                tf.reshape(v, [1, -1]), name=f"check.{i}")
-            arr = gathered.numpy()
-            for row in arr[1:]:
-                np.testing.assert_allclose(row, arr[0], rtol=1e-5,
-                                           atol=1e-6)
-        return "ok"
-    finally:
-        hvd.shutdown()
+    for i, v in enumerate(model.trainable_variables):
+        gathered = hvdtf.allgather(
+            tf.reshape(v, [1, -1]), name=f"check.{i}")
+        arr = gathered.numpy()
+        for row in arr[1:]:
+            np.testing.assert_allclose(row, arr[0], rtol=1e-5,
+                                       atol=1e-6)
+    return "ok"
 
 
-def test_keras_optimizer():
-    assert run_ranks(_worker_keras, 2, env=_TF_ENV, timeout=240) == ["ok"] * 2
+def test_keras_optimizer(tf_world):
+    assert tf_world(2).run(_worker_keras, timeout=240) == ["ok"] * 2
 
 
 def _worker_keras_fit(rank, size):
@@ -381,40 +358,35 @@ def _worker_keras_fit(rank, size):
 
     import horovod_tpu.keras as hvd
 
-    hvd.init()
-    try:
-        tf.keras.utils.set_random_seed(42 + rank)
-        model = tf.keras.Sequential(
-            [tf.keras.layers.Dense(4, input_shape=(8,)),
-             tf.keras.layers.Dense(1)])
-        opt = hvd.DistributedOptimizer(tf.keras.optimizers.SGD(0.05))
-        assert isinstance(opt, tf.keras.optimizers.Optimizer)
-        model.compile(optimizer=opt, loss="mse")
+    tf.keras.utils.set_random_seed(42 + rank)
+    model = tf.keras.Sequential(
+        [tf.keras.layers.Dense(4, input_shape=(8,)),
+         tf.keras.layers.Dense(1)])
+    opt = hvd.DistributedOptimizer(tf.keras.optimizers.SGD(0.05))
+    assert isinstance(opt, tf.keras.optimizers.Optimizer)
+    model.compile(optimizer=opt, loss="mse")
 
-        rng = np.random.RandomState(7 + rank)  # different data per rank
-        x = rng.rand(32, 8).astype(np.float32)
-        y = rng.rand(32, 1).astype(np.float32)
-        model.fit(
-            x, y, batch_size=8, epochs=1, verbose=0,
-            callbacks=[hvd.callbacks.BroadcastGlobalVariablesCallback(0)])
+    rng = np.random.RandomState(7 + rank)  # different data per rank
+    x = rng.rand(32, 8).astype(np.float32)
+    y = rng.rand(32, 1).astype(np.float32)
+    model.fit(
+        x, y, batch_size=8, epochs=1, verbose=0,
+        callbacks=[hvd.callbacks.BroadcastGlobalVariablesCallback(0)])
 
-        # Averaged grads + identical starting weights => identical weights.
-        import horovod_tpu.tensorflow as hvdtf
+    # Averaged grads + identical starting weights => identical weights.
+    import horovod_tpu.tensorflow as hvdtf
 
-        for i, v in enumerate(model.trainable_variables):
-            gathered = hvdtf.allgather(
-                tf.reshape(v, [1, -1]), name=f"fitcheck.{i}")
-            arr = gathered.numpy()
-            for row in arr[1:]:
-                np.testing.assert_allclose(row, arr[0], atol=1e-5)
-        return "ok"
-    finally:
-        hvd.shutdown()
+    for i, v in enumerate(model.trainable_variables):
+        gathered = hvdtf.allgather(
+            tf.reshape(v, [1, -1]), name=f"fitcheck.{i}")
+        arr = gathered.numpy()
+        for row in arr[1:]:
+            np.testing.assert_allclose(row, arr[0], atol=1e-5)
+    return "ok"
 
 
-def test_keras_model_fit():
-    assert run_ranks(_worker_keras_fit, 2, env=_TF_ENV,
-                     timeout=300) == ["ok"] * 2
+def test_keras_model_fit(tf_world):
+    assert tf_world(2).run(_worker_keras_fit, timeout=300) == ["ok"] * 2
 
 
 def _worker_keras_sum_once(rank, size):
@@ -426,24 +398,19 @@ def _worker_keras_sum_once(rank, size):
 
     import horovod_tpu.keras as hvd
 
-    hvd.init()
-    try:
-        v = tf.Variable([1.0, 2.0])
-        opt = hvd.DistributedOptimizer(tf.keras.optimizers.SGD(1.0),
-                                       op=hvd.Sum)
-        grad = tf.constant([float(rank + 1), 0.0])
-        opt.apply_gradients([(grad, v)])
-        # sum of (rank+1) over 2 ranks = 3; v[0] = 1 - 1.0*3 = -2
-        expected = 1.0 - sum(r + 1 for r in range(size))
-        np.testing.assert_allclose(v.numpy()[0], expected, atol=1e-6)
-        return "ok"
-    finally:
-        hvd.shutdown()
+    v = tf.Variable([1.0, 2.0])
+    opt = hvd.DistributedOptimizer(tf.keras.optimizers.SGD(1.0),
+                                   op=hvd.Sum)
+    grad = tf.constant([float(rank + 1), 0.0])
+    opt.apply_gradients([(grad, v)])
+    # sum of (rank+1) over 2 ranks = 3; v[0] = 1 - 1.0*3 = -2
+    expected = 1.0 - sum(r + 1 for r in range(size))
+    np.testing.assert_allclose(v.numpy()[0], expected, atol=1e-6)
+    return "ok"
 
 
-def test_keras_allreduce_applied_once():
-    assert run_ranks(_worker_keras_sum_once, 2, env=_TF_ENV,
-                     timeout=240) == ["ok"] * 2
+def test_keras_allreduce_applied_once(tf_world):
+    assert tf_world(2).run(_worker_keras_sum_once, timeout=240) == ["ok"] * 2
 
 
 def _worker_sync_bn(rank, size):
@@ -455,28 +422,23 @@ def _worker_sync_bn(rank, size):
 
     import horovod_tpu.tensorflow as hvd
 
-    hvd.init()
-    try:
-        bn = hvd.SyncBatchNormalization(momentum=0.0, epsilon=0.0)
-        # rank 0 feeds zeros, rank 1 feeds twos -> global mean 1, var 1
-        x = tf.fill([4, 3], float(rank * 2))
-        y = bn(x, training=True)
-        np.testing.assert_allclose(bn.moving_mean.numpy(), 1.0, atol=1e-5)
-        np.testing.assert_allclose(bn.moving_variance.numpy(), 1.0,
-                                   atol=1e-5)
-        expected = (rank * 2 - 1.0) / 1.0  # (x - mean)/sqrt(var)
-        np.testing.assert_allclose(y.numpy(), expected, atol=1e-4)
-        # eval path uses moving stats, no collective
-        y_eval = bn(tf.fill([2, 3], 1.0), training=False)
-        np.testing.assert_allclose(y_eval.numpy(), 0.0, atol=1e-4)
-        return "ok"
-    finally:
-        hvd.shutdown()
+    bn = hvd.SyncBatchNormalization(momentum=0.0, epsilon=0.0)
+    # rank 0 feeds zeros, rank 1 feeds twos -> global mean 1, var 1
+    x = tf.fill([4, 3], float(rank * 2))
+    y = bn(x, training=True)
+    np.testing.assert_allclose(bn.moving_mean.numpy(), 1.0, atol=1e-5)
+    np.testing.assert_allclose(bn.moving_variance.numpy(), 1.0,
+                               atol=1e-5)
+    expected = (rank * 2 - 1.0) / 1.0  # (x - mean)/sqrt(var)
+    np.testing.assert_allclose(y.numpy(), expected, atol=1e-4)
+    # eval path uses moving stats, no collective
+    y_eval = bn(tf.fill([2, 3], 1.0), training=False)
+    np.testing.assert_allclose(y_eval.numpy(), 0.0, atol=1e-4)
+    return "ok"
 
 
-def test_sync_batch_norm():
-    assert run_ranks(_worker_sync_bn, 2, env=_TF_ENV,
-                     timeout=240) == ["ok"] * 2
+def test_sync_batch_norm(tf_world):
+    assert tf_world(2).run(_worker_sync_bn, timeout=240) == ["ok"] * 2
 
 
 def _worker_sync_bn_graph_mode(rank, size):
@@ -487,31 +449,27 @@ def _worker_sync_bn_graph_mode(rank, size):
 
     import horovod_tpu.tensorflow as hvd
 
-    hvd.init()
-    try:
-        bn = hvd.SyncBatchNormalization(momentum=0.0, epsilon=0.0)
+    bn = hvd.SyncBatchNormalization(momentum=0.0, epsilon=0.0)
 
-        @tf.function
-        def run(x, training):
-            return bn(x, training=training)
+    @tf.function
+    def run(x, training):
+        return bn(x, training=training)
 
-        x = tf.fill([4, 3], float(rank * 2))
-        y = run(x, tf.constant(True))
-        np.testing.assert_allclose(bn.moving_mean.numpy(), 1.0, atol=1e-5)
-        np.testing.assert_allclose(y.numpy(), rank * 2 - 1.0, atol=1e-4)
-        y_eval = run(tf.fill([2, 3], 1.0), tf.constant(False))
-        np.testing.assert_allclose(y_eval.numpy(), 0.0, atol=1e-4)
-        # config round-trips through JSON (no live objects inside)
-        import json
-        json.dumps(bn.get_config())
-        return "ok"
-    finally:
-        hvd.shutdown()
+    x = tf.fill([4, 3], float(rank * 2))
+    y = run(x, tf.constant(True))
+    np.testing.assert_allclose(bn.moving_mean.numpy(), 1.0, atol=1e-5)
+    np.testing.assert_allclose(y.numpy(), rank * 2 - 1.0, atol=1e-4)
+    y_eval = run(tf.fill([2, 3], 1.0), tf.constant(False))
+    np.testing.assert_allclose(y_eval.numpy(), 0.0, atol=1e-4)
+    # config round-trips through JSON (no live objects inside)
+    import json
+    json.dumps(bn.get_config())
+    return "ok"
 
 
-def test_sync_batch_norm_graph_mode():
-    assert run_ranks(_worker_sync_bn_graph_mode, 2, env=_TF_ENV,
-                     timeout=240) == ["ok"] * 2
+def test_sync_batch_norm_graph_mode(tf_world):
+    assert tf_world(2).run(_worker_sync_bn_graph_mode, timeout=240) \
+        == ["ok"] * 2
 
 
 def _worker_keras_grad_aggregation(rank, size):
@@ -522,51 +480,47 @@ def _worker_keras_grad_aggregation(rank, size):
 
     import horovod_tpu.keras as hvd
 
-    hvd.init()
-    try:
-        opt = hvd.DistributedOptimizer(
-            tf.keras.optimizers.SGD(learning_rate=1.0),
-            backward_passes_per_step=3)
-        v = tf.Variable([10.0, 10.0])
-        # rank r applies grads (r+1)*[1,1] three times; the boundary
-        # update is avg over passes (= (r+1)) then avg over ranks
-        # (= 1.5 for 2 ranks), lr 1.0.
-        for step in range(3):
-            opt.apply([tf.constant([float(rank + 1)] * 2)], [v])
-            if step < 2:
-                np.testing.assert_allclose(v.numpy(), 10.0, atol=1e-6,
-                                           err_msg=f"moved at step {step}")
-        delta = sum(i + 1 for i in range(size)) / size
-        np.testing.assert_allclose(v.numpy(), 10.0 - delta, atol=1e-5)
-        # iterations counts EVERY backward pass (LR schedules keyed on it
-        # must not run N times slow), and a second cycle works
-        # (accumulators reset).
-        assert int(opt.iterations.numpy()) == 3
-        for _ in range(3):
-            opt.apply([tf.constant([float(rank + 1)] * 2)], [v])
-        np.testing.assert_allclose(v.numpy(), 10.0 - 2 * delta, atol=1e-5)
-        assert int(opt.iterations.numpy()) == 6
+    opt = hvd.DistributedOptimizer(
+        tf.keras.optimizers.SGD(learning_rate=1.0),
+        backward_passes_per_step=3)
+    v = tf.Variable([10.0, 10.0])
+    # rank r applies grads (r+1)*[1,1] three times; the boundary
+    # update is avg over passes (= (r+1)) then avg over ranks
+    # (= 1.5 for 2 ranks), lr 1.0.
+    for step in range(3):
+        opt.apply([tf.constant([float(rank + 1)] * 2)], [v])
+        if step < 2:
+            np.testing.assert_allclose(v.numpy(), 10.0, atol=1e-6,
+                                       err_msg=f"moved at step {step}")
+    delta = sum(i + 1 for i in range(size)) / size
+    np.testing.assert_allclose(v.numpy(), 10.0 - delta, atol=1e-5)
+    # iterations counts EVERY backward pass (LR schedules keyed on it
+    # must not run N times slow), and a second cycle works
+    # (accumulators reset).
+    assert int(opt.iterations.numpy()) == 3
+    for _ in range(3):
+        opt.apply([tf.constant([float(rank + 1)] * 2)], [v])
+    np.testing.assert_allclose(v.numpy(), 10.0 - 2 * delta, atol=1e-5)
+    assert int(opt.iterations.numpy()) == 6
 
-        # Same behavior under tf.function (slot/accumulator creation must
-        # happen outside the traced cond).
-        opt2 = hvd.DistributedOptimizer(
-            tf.keras.optimizers.SGD(learning_rate=1.0),
-            backward_passes_per_step=2)
-        v2 = tf.Variable([4.0])
+    # Same behavior under tf.function (slot/accumulator creation must
+    # happen outside the traced cond).
+    opt2 = hvd.DistributedOptimizer(
+        tf.keras.optimizers.SGD(learning_rate=1.0),
+        backward_passes_per_step=2)
+    v2 = tf.Variable([4.0])
 
-        @tf.function
-        def train_step(g):
-            opt2.apply([g], [v2])
+    @tf.function
+    def train_step(g):
+        opt2.apply([g], [v2])
 
-        train_step(tf.constant([float(rank + 1)]))
-        np.testing.assert_allclose(v2.numpy(), 4.0, atol=1e-6)
-        train_step(tf.constant([float(rank + 1)]))
-        np.testing.assert_allclose(v2.numpy(), 4.0 - delta, atol=1e-5)
-        return "ok"
-    finally:
-        hvd.shutdown()
+    train_step(tf.constant([float(rank + 1)]))
+    np.testing.assert_allclose(v2.numpy(), 4.0, atol=1e-6)
+    train_step(tf.constant([float(rank + 1)]))
+    np.testing.assert_allclose(v2.numpy(), 4.0 - delta, atol=1e-5)
+    return "ok"
 
 
-def test_keras_gradient_aggregation():
-    assert run_ranks(_worker_keras_grad_aggregation, 2, env=_TF_ENV,
-                     timeout=240) == ["ok"] * 2
+def test_keras_gradient_aggregation(tf_world):
+    assert tf_world(2).run(_worker_keras_grad_aggregation, timeout=240) \
+        == ["ok"] * 2

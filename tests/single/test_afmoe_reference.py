@@ -3,8 +3,15 @@ float32 reference (horovod_tpu/models/reference.py): logits, loss and
 every gradient leaf under each remat mode, the layer pattern scanned and
 unrolled, the share of the experts (eight shares sum to the whole layer,
 the shared expert counted once; no held slot dropped at any load), the
-vocabulary slice, and that the older configurations build what they
-always did. Small sizes, CPU.
+vocabulary slice. Small sizes, CPU. (That the older configurations build
+what they always did: tests/single/test_older_configurations.py.)
+
+What a case pays for (tests/conftest.py): ``_cfg()`` is the smallest
+depth that holds every kind of layer once (a dense window layer, an
+expert window layer, an expert full layer: three layers), and the remat
+sweep, the share's other shapes, the vocabulary slice and the load cases
+compile THAT; two periods and a layer left over are the one ``deeper``
+case.
 
 The tolerance is tests/single/test_olmoe_reference.py's: program and
 reference both compute in float32 and differ in the order of float32
@@ -16,6 +23,8 @@ each move the result by whole percents.
 """
 
 import dataclasses
+import functools
+from unittest import mock
 
 import jax
 import jax.numpy as jnp
@@ -43,12 +52,14 @@ S, F = "sliding_attention", "full_attention"
 
 
 def _cfg(**kw):
-    """The cell's shape in small: one leading dense layer, one period of
-    three window layers and a full one; experts 4..7 of 16 held."""
-    base = dict(vocab_size=128, d_model=64, n_layers=5, n_heads=4,
+    """The cell's kinds of layer, each once: a leading dense layer (a
+    window layer), an expert window layer and an expert full layer;
+    experts 4..7 of 16 held. The cell's period (three window layers and
+    a full one) and its repetition: the ``deeper`` case."""
+    base = dict(vocab_size=128, d_model=64, n_layers=3, n_heads=4,
                 n_kv_heads=2, d_head=32, d_ff=96, moe_d_ff=32,
                 rope_theta=10000.0, n_experts=16, n_experts_per_token=4,
-                n_dense_layers=1, layer_types=(S, S, S, S, F),
+                n_dense_layers=1, layer_types=(S, S, F),
                 sliding_window=6, n_shared_experts=1,
                 score_func="sigmoid", norm_topk_prob=True,
                 route_scale=2.826, scale_embed=True, attn_gate=True,
@@ -92,12 +103,16 @@ def _err(got, ref):
 # called eagerly, jax compiles these programs a primitive at a time
 # (27 + 13 s for one configuration's two gradients where the jitted
 # ones take 5 + 4). The EAGER call, which users make too, stays in
-# ``test_older_configurations_build_the_tree_and_program_they_did``.
+# tests/single/test_older_configurations.py.
 _forward = jax.jit(llama_forward, static_argnums=2)
 _ref_forward = jax.jit(afmoe_forward, static_argnums=2)
+_loss = jax.jit(llama_loss, static_argnums=2)
+_ref_loss = jax.jit(afmoe_loss, static_argnums=2,
+                    static_argnames="vocab_rows")
 _loss_and_grads = jax.jit(jax.value_and_grad(llama_loss), static_argnums=2)
 _ref_loss_and_grads = jax.jit(jax.value_and_grad(afmoe_loss),
                               static_argnums=2)
+_expert_load = jax.jit(llama_expert_load, static_argnums=2)
 
 
 def _all_readings(forward, loss):
@@ -218,12 +233,12 @@ def test_loss_over_the_vocabulary_slice():
     held = dict(full, embed=full["embed"][:32],
                 lm_head=full["lm_head"][:, :32])
     batch = _batch(cfg)
-    want = afmoe_loss(full, batch, uncut, vocab_rows=32)
-    assert abs(float(llama_loss(held, batch, cfg)) - float(want)) \
+    want = _ref_loss(full, batch, uncut, vocab_rows=32)
+    assert abs(float(_loss(held, batch, cfg)) - float(want)) \
         < TOL * float(want)
-    assert abs(float(afmoe_loss(held, batch, cfg)) - float(want)) \
+    assert abs(float(_ref_loss(held, batch, cfg)) - float(want)) \
         < TOL * float(want)
-    assert abs(float(afmoe_loss(full, batch, uncut)) - float(want)) > 0.1
+    assert abs(float(_ref_loss(full, batch, uncut)) - float(want)) > 0.1
 
 
 @pytest.mark.parametrize("load", ["all", "none", "even"])
@@ -234,9 +249,10 @@ def test_no_held_slot_is_dropped_at_any_load(load):
     reference each time."""
     cfg = _cfg(n_experts_held=2)       # 2 of 16 held, 4 a token
     params = _params(cfg)
-    bias = jnp.zeros((4, 16))
+    # the load is DATA: one compiled program serves the three
+    bias = jnp.zeros_like(params["layers"]["expert_bias"])
     if load != "even":
-        bias = jnp.full((4, 16), 4.0 if load == "none" else -4.0) \
+        bias = jnp.full_like(bias, 4.0 if load == "none" else -4.0) \
             .at[:, 4:6].set(-4.0 if load == "none" else 4.0)
     params["layers"]["expert_bias"] = bias
     batch = _batch(cfg, (2, 128))
@@ -245,8 +261,8 @@ def test_no_held_slot_is_dropped_at_any_load(load):
     assert abs(float(loss) - float(ref_loss)) < TOL * float(ref_loss)
     for name in ("moe_gate", "moe_down", "router", "shared_up", "wg"):
         assert _err(grads["layers"][name], ref["layers"][name]) < TOL, name
-    held = np.asarray(jax.jit(lambda p, t: llama_expert_load(p, t, cfg))(
-        params, batch["tokens"]))[:, 4:6].sum(-1)
+    held = np.asarray(_expert_load(params, batch["tokens"], cfg))[
+        :, 4:6].sum(-1)
     slots = batch["tokens"].size * 4
     chunk = slots // (16 // (2 * grouped_moe._HELD_ROW_BOUND))
     assert chunk == 256 == 2 * slots * 2 // 16
@@ -277,10 +293,50 @@ def _share_layer(rows, held=2):
     return cfg, lp, hf, idx, w
 
 
+def _dense_share(hf, w, lp, idx):
+    """The two held experts' weighted outputs summed per token, by a
+    dense count."""
+    out = 0.0
+    for e in range(2):
+        we = jnp.sum(jnp.where(idx == 4 + e, w, 0.0), -1)
+        y = (jax.nn.silu(hf @ lp["moe_gate"][e])
+             * (hf @ lp["moe_up"][e])) @ lp["moe_down"][e]
+        out = out + we[:, None] * y
+    return out
+
+
+@pytest.fixture(scope="module")
+def share_and_its_vjp():
+    """``block -> program``: ``(hf, w, lp, idx, cot) -> (the share's
+    output, its gradients; the dense count's output, its gradients)``,
+    compiled once a block: the routing ``idx`` is DATA, and the twelve
+    cases below differ in nothing else. ``_HELD_BLOCK`` is read while
+    tracing, so each block has a FUNCTION of its own that sets it: jax
+    keys its trace cache on the function and the operands' avals, which
+    are the same for every block, and one function jitted twice would
+    run the first block's program for both."""
+    cfg = _share_layer(0)[0]
+
+    @functools.cache
+    def program(block):
+        def both(hf, w, lp, idx, cot):
+            with mock.patch.object(grouped_moe, "_HELD_BLOCK", block):
+                got, vjp = jax.vjp(
+                    lambda hf, w, lp: grouped_moe._held_experts_ffn(
+                        hf, lp, cfg, w, idx), hf, w, lp)
+                grads = vjp(cot)
+            ref, ref_vjp = jax.vjp(lambda hf, w, lp: _dense_share(
+                hf, w, lp, idx), hf, w, lp)
+            return got, grads, ref, ref_vjp(cot)
+        return jax.jit(both)
+
+    return program
+
+
 @pytest.mark.parametrize("block", [64, 2048])
 @pytest.mark.parametrize("rows", [0, 100, 256, 257, 700, 1024])
 def test_the_chunks_of_the_share_cover_exactly_the_held_rows(
-        rows, block, monkeypatch):
+        rows, block, share_and_its_vjp):
     """``_held_experts_ffn`` alone with a hand-made routing that sends
     exactly ``rows`` of 1024 slots to the 2 held experts (chunks of 256:
     none, part of the first, the first whole, one row into the second,
@@ -288,28 +344,13 @@ def test_the_chunks_of_the_share_cover_exactly_the_held_rows(
     summed per token, and their gradients, against a dense count. The
     rows of a chunk are gathered in four blocks of 64, and as one block
     (2048, the chip's, does not divide 256)."""
-    monkeypatch.setattr(grouped_moe, "_HELD_BLOCK", block)
     cfg, lp, hf, idx, w = _share_layer(rows)
-
-    def program(hf, w, lp):
-        return grouped_moe._held_experts_ffn(hf, lp, cfg, w, idx)
-
-    def dense(hf, w, lp):
-        out = 0.0
-        for e in range(2):
-            we = jnp.sum(jnp.where(idx == 4 + e, w, 0.0), -1)
-            y = (jax.nn.silu(hf @ lp["moe_gate"][e])
-                 * (hf @ lp["moe_up"][e])) @ lp["moe_down"][e]
-            out = out + we[:, None] * y
-        return out
-
     assert int(jnp.sum((idx == 4) | (idx == 5))) == rows
     cot = jax.random.normal(jax.random.PRNGKey(rows + 1), hf.shape)
-    got, vjp = jax.vjp(jax.jit(program), hf, w, lp)
-    ref, ref_vjp = jax.vjp(jax.jit(dense), hf, w, lp)
+    got, grads, ref, ref_grads = share_and_its_vjp(block)(hf, w, lp, idx,
+                                                          cot)
     assert _err(got, ref) < TOL or (rows == 0 and not np.any(got))
-    for g, r, name in zip(jax.tree.leaves(vjp(cot)),
-                          jax.tree.leaves(ref_vjp(cot)),
+    for g, r, name in zip(jax.tree.leaves(grads), jax.tree.leaves(ref_grads),
                           ["hf", "w"] + sorted(lp)):
         if np.any(np.asarray(r)):
             assert _err(g, r) < TOL, name
@@ -337,6 +378,20 @@ def _row_movements(jaxpr):
                      else eqn.invars[2]).aval
             if moved.ndim == 2:
                 yield name, moved.shape[0], inside_while
+
+
+def test_each_block_of_the_share_is_a_program_of_its_own(share_and_its_vjp):
+    """The two programs the twelve cases above run gather what their
+    block says: rows in blocks of 64, and a chunk of 256 as one block
+    under the chip's 2048 (which does not divide it). Were one traced
+    function served for both, the second set would be the first's."""
+    cfg, lp, hf, idx, w = _share_layer(700)
+    gathered = {
+        block: {rows for name, rows, _ in _row_movements(
+            share_and_its_vjp(block).trace(hf, w, lp, idx, hf).jaxpr.jaxpr)
+            if name == "gather"}
+        for block in (64, 2048)}
+    assert gathered == {64: {64}, 2048: {256}}, gathered
 
 
 def test_the_shares_gathers_are_no_chunk_long_and_its_sums_are(monkeypatch):
@@ -400,8 +455,8 @@ def test_a_share_needs_the_grouped_dispatch():
 
 
 @pytest.mark.parametrize("bad", [
-    dict(layer_types=(S, F)), dict(layer_types=("banded",) * 5),
-    dict(sliding_window=0), dict(n_dense_layers=5),
+    dict(layer_types=(S, F)), dict(layer_types=("banded",) * 3),
+    dict(sliding_window=0), dict(n_dense_layers=3),
     dict(score_func="tanh"), dict(qk_norm="row"),
     dict(first_expert=14, n_experts_held=4), dict(n_experts_held=-1),
 ])
@@ -446,59 +501,3 @@ def test_a_layer_pattern_has_no_pipeline_schedule():
     mesh = jax.sharding.Mesh(np.array(jax.devices()[:2]), ("pipe",))
     with pytest.raises(ValueError, match="layer pattern"):
         _validate_pipeline(_cfg(), 2, mesh, "seq", 2)
-
-
-# What the older configurations build: the parameter tree leaf for leaf,
-# the loss on seeded weights to the last digit, and a grad program with
-# the operations it had at the parent commit (counted there, commit
-# a7fcac2: the whole jaxpr texts were compared once, equal but for the
-# address of a remat policy's closure). "olmoe" was counted again at
-# PR 33, which runs a grouped expert stack unrolled on purpose: no
-# scan, a layer body a layer (the printed jaxpr shares equal
-# sub-programs, so its counts are not twice a body's), and a loss that
-# differs from the scan's in the fourth digit at bf16 compute (in
-# float32 the two agree to 1e-6: test_expert_stack_unrolled.py), and at
-# PR 54, which took ``_top_k``'s scatter-add out of the router on
-# purpose (a select under a sum, ``models/llama.py:_unpick``: one
-# scatter-add fewer, the loss the same to the last digit).
-_OLD = {
-    "dense": (LlamaConfig.tiny(),
-              ["attn_norm", "mlp_norm", "w_down", "w_gate", "w_up", "wk",
-               "wo", "wq", "wv"], 5.90579891204834,
-              {"scan": 2, "cond": 0, "sort": 0, "gather": 2,
-               "scatter-add": 2, "custom_vjp_call": 0, "dot_general": 38,
-               "top_k": 0}),
-    "olmoe": (LlamaConfig.tiny(n_experts=8, n_experts_per_token=3,
-                               qk_norm=True, norm_topk_prob=False,
-                               moe_impl="grouped", remat="attn+moe"),
-              ["attn_norm", "k_norm", "mlp_norm", "moe_down", "moe_gate",
-               "moe_up", "q_norm", "router", "wk", "wo", "wq", "wv"],
-              6.124673366546631,
-              {"scan": 0, "cond": 0, "sort": 3, "gather": 14,
-               "scatter-add": 2, "custom_vjp_call": 10, "dot_general": 99,
-               "top_k": 2}),
-}
-
-
-@pytest.mark.parametrize("which", sorted(_OLD))
-def test_older_configurations_build_the_tree_and_program_they_did(which):
-    """The loss to the last bit, by the EAGER call of ``llama_loss``:
-    this file's one case that runs the model a primitive at a time, as
-    a user without ``jax.jit`` does."""
-    import collections
-    import re
-
-    cfg, leaves, loss, counts = _OLD[which]
-    params = llama_init(cfg, jax.random.PRNGKey(0))
-    assert sorted(params) == ["embed", "final_norm", "layers", "lm_head"]
-    assert sorted(params["layers"]) == leaves
-    assert params["layers"]["wq"].shape == (2, 64, 64)
-    if which == "olmoe":
-        assert params["layers"]["q_norm"].shape == (2, 64)
-        assert params["layers"]["moe_gate"].shape == (2, 8, 64, 128)
-    batch = _batch(dataclasses.replace(cfg, vocab_size=256))
-    assert float(llama_loss(params, batch, cfg)) == loss
-    text = str(jax.make_jaxpr(jax.value_and_grad(
-        lambda p, b: llama_loss(p, b, cfg)))(params, batch))
-    seen = collections.Counter(re.findall(r"= ([a-z_\-]+)[\[ ]", text))
-    assert {k: seen[k] for k in counts} == counts

@@ -4,7 +4,6 @@ keep their ``lax.scan`` (``models/llama.py:_run_layers``; PERF.md
 section 6, PR 33). The values are the scan's; only the indexing, and
 with it the copies in front of and behind the grouped GEMMs, differ."""
 
-import dataclasses
 import re
 
 import jax
@@ -76,16 +75,12 @@ def _scans(fn, *args):
     ("auto-no-mesh", 0, 0),     # "auto" with no mesh IS the grouped path
     ("gshard", 1, 2),           # einsums: XLA fuses the indexing
     ("dense", 1, 2),            # forward scan + its transpose
-    ("dense-unroll-2", 1, 2),   # scan_unroll schedules, it does not unroll
 ])
 def test_which_stacks_scan(which, forward, grad):
     cfg = {"grouped": _moe(),
            "auto-no-mesh": _moe(moe_impl="auto"),
            "gshard": _moe(moe_impl="gshard", remat="attn"),
-           "dense": LlamaConfig.tiny(dtype="float32"),
-           "dense-unroll-2": LlamaConfig.tiny(dtype="float32", n_layers=4,
-                                              scan_unroll=2)}[which]
-    assert cfg.scan_unroll == (2 if which == "dense-unroll-2" else 1)
+           "dense": LlamaConfig.tiny(dtype="float32")}[which]
     params = llama_init(cfg, jax.random.PRNGKey(0))
     batch = _batch(cfg)
     assert _scans(lambda p: llama_forward(p, batch["tokens"], cfg),
@@ -147,24 +142,6 @@ def test_the_scan_it_replaced_did_index_dynamically(monkeypatch):
             if re.search(r" dynamic-(update-)?slice\(", line)
             and any(s in line for s in shapes)]
     assert hits
-
-
-def test_scan_unroll_schedules_the_dense_scan_only():
-    """What ``scan_unroll`` still governs: the dense scan's values and
-    gradients do not depend on it (the MoE twin of this test,
-    ``test_llama.py::test_scan_unroll_is_scheduling_only``, now compares
-    two unrolled programs)."""
-    cfg0 = LlamaConfig.tiny(dtype="float32", n_layers=4, remat="attn")
-    params = llama_init(cfg0, jax.random.PRNGKey(0))
-    batch = _batch(cfg0)
-    ref_loss, _, ref_grads = _loss_logits_grads(cfg0, params, batch)
-    loss, _, grads = _loss_logits_grads(
-        dataclasses.replace(cfg0, scan_unroll=4), params, batch)
-    np.testing.assert_allclose(float(loss), float(ref_loss), rtol=1e-6)
-    jax.tree.map(
-        lambda a, b: np.testing.assert_allclose(
-            np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-6),
-        grads, ref_grads)
 
 
 # --- the whole stack handed to the grouped GEMM (ops/grouped_moe.py) ---

@@ -3,12 +3,13 @@ coverage the TPU code paths (incl. the bias branches) get without a
 chip. Values AND grads compare against reference-math attention.
 """
 
+import functools
+import importlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-
-import importlib
 
 # The package re-exports the flash_attention FUNCTION under the same
 # name, shadowing the submodule attribute — resolve the module directly.
@@ -30,6 +31,27 @@ def _qkv(seed=0, B=1, T=32, H=2, D=8):
             jax.random.normal(k3, shape, jnp.float32))
 
 
+def _grad(*args, **kwargs):
+    """``jax.grad``, compiled: evaluated eagerly, the reference math is
+    a compile a primitive, four times what its kernel's case costs."""
+    return jax.jit(jax.grad(*args, **kwargs))
+
+
+def _out_and_grads(fn, w, *operands):
+    """``fn(*operands)`` and the gradients of ``sum(fn * w)`` in the
+    operands, one compiled program: a kernel's forward under
+    ``jax.grad`` is the call it makes alone (``_flash`` and
+    ``_flash_fwd`` both run ``_flash_fwd_impl``), so one interpreted
+    forward serves the values and the gradients."""
+    def loss(*a):
+        out = fn(*a)
+        return (out * w).sum(), out
+    (_, out), grads = jax.jit(jax.value_and_grad(
+        loss, tuple(range(len(operands))), has_aux=True))(*operands)
+    return out, grads
+
+
+@functools.partial(jax.jit, static_argnames="causal")
 def _ref(q, k, v, bias=None, causal=False):
     d = q.shape[-1]
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k) / (d ** 0.5)
@@ -75,8 +97,8 @@ def test_biased_kernel_grads_match_reference():
     def fr(q, k, v):
         return (_ref(q, k, v, bias=bias) ** 2).sum()
 
-    g = jax.grad(f, (0, 1, 2))(q, k, v)
-    gr = jax.grad(fr, (0, 1, 2))(q, k, v)
+    g = _grad(f, (0, 1, 2))(q, k, v)
+    gr = _grad(fr, (0, 1, 2))(q, k, v)
     for a, b, name in zip(g, gr, "qkv"):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=5e-3, atol=5e-4,
@@ -110,8 +132,8 @@ def test_gqa_kernel_values_and_grads(causal):
     def fr(q, k, v):
         return (_ref(q, rep(k), rep(v), causal=causal) ** 2).sum()
 
-    g = jax.grad(f, (0, 1, 2))(q, k, v)
-    gr = jax.grad(fr, (0, 1, 2))(q, k, v)
+    g = _grad(f, (0, 1, 2))(q, k, v)
+    gr = _grad(fr, (0, 1, 2))(q, k, v)
     for a, b_, name in zip(g, gr, "qkv"):
         assert a.shape == b_.shape
         np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
@@ -152,14 +174,14 @@ def test_chunked_offsets_kernel_matches_reference(causal, D):
         return (jnp.exp(lse[0] - new)[..., None] * o[0]
                 + jnp.exp(lse[1] - new)[..., None] * o[1])
 
-    out = merged(q, k, v)
+    out = jax.jit(merged)(q, k, v)
     ref = _ref(q, k, v, causal=causal)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-4, atol=2e-4)
 
-    g = jax.grad(lambda *a: (merged(*a) ** 2).sum(), (0, 1, 2))(q, k, v)
-    gr = jax.grad(lambda *a: (_ref(*a, causal=causal) ** 2).sum(),
-                  (0, 1, 2))(q, k, v)
+    g = _grad(lambda *a: (merged(*a) ** 2).sum(), (0, 1, 2))(q, k, v)
+    gr = _grad(lambda *a: (_ref(*a, causal=causal) ** 2).sum(),
+               (0, 1, 2))(q, k, v)
     for a, b, name in zip(g, gr, "qkv"):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=5e-3, atol=5e-4,
@@ -294,7 +316,7 @@ def test_zero_valid_key_rows_zero_output_and_grads():
         oo, _ = run(qq, kk, vv)
         return (oo[:, :, :8] ** 2).sum() + oo[:, :, :8].sum()
 
-    dq, dk, dv = jax.grad(loss, (0, 1, 2))(q, k, v)
+    dq, dk, dv = _grad(loss, (0, 1, 2))(q, k, v)
     for g, name in zip((dq, dk, dv), "qkv"):
         np.testing.assert_array_equal(
             np.asarray(g), 0.0, err_msg=f"d{name} leaked")
@@ -305,6 +327,8 @@ def test_zero_valid_key_rows_zero_output_and_grads():
 # and straddling tiles all occur, and compares values and all three
 # gradients with reference math.
 
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6),
+                   static_argnames=("causal", "window"))
 def _chunk_ref(q, k, v, q_off, kv_off, causal=True, window=0, bias=None):
     """Reference for one chunk with GLOBAL positions: (o, lse [B,H,T],
     rows with a valid key [1,1,T]). A row without one has output 0 (its
@@ -342,17 +366,13 @@ def test_unequal_blocks_values_and_grads(block_q, block_k):
     w = jax.random.normal(jax.random.PRNGKey(4), q.shape)
     assert all(fa_mod.tile_counts(96, 96, block_q, block_k, True))
 
-    def f(*a):
-        return (fa_mod._flash(*a, True, block_q, block_k) * w).sum()
-
-    def fr(*a):
-        return (_ref(*a, causal=True) * w).sum()
-
-    np.testing.assert_allclose(
-        np.asarray(fa_mod._flash(q, k, v, True, block_q, block_k)),
-        np.asarray(_ref(q, k, v, causal=True)), rtol=2e-4, atol=2e-4)
-    _assert_grads_close(jax.grad(f, (0, 1, 2))(q, k, v),
-                        jax.grad(fr, (0, 1, 2))(q, k, v))
+    out, grads = _out_and_grads(
+        lambda *a: fa_mod._flash(*a, True, block_q, block_k), w, q, k, v)
+    ref, ref_grads = _out_and_grads(
+        lambda *a: _ref(*a, causal=True), w, q, k, v)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-4, atol=2e-4)
+    _assert_grads_close(grads, ref_grads)
 
 
 @pytest.mark.parametrize("causal", [False, True])
@@ -366,18 +386,13 @@ def test_gqa_four_query_heads_a_kv_head(causal):
     assert fa_mod.tile_counts(T, T, 16, 16, causal) == \
         ((3, 3, 3) if causal else (0, 9, 0))
 
-    def f(*a):
-        return (fa_mod._flash(*a, causal, 16, 16) * w).sum()
-
-    def fr(*a):
-        return (_chunk_ref(*a, 0, 0, causal)[0] * w).sum()
-
-    np.testing.assert_allclose(
-        np.asarray(fa_mod._flash(q, k, v, causal, 16, 16)),
-        np.asarray(_chunk_ref(q, k, v, 0, 0, causal)[0]),
-        rtol=2e-4, atol=2e-4)
-    _assert_grads_close(jax.grad(f, (0, 1, 2))(q, k, v),
-                        jax.grad(fr, (0, 1, 2))(q, k, v))
+    out, grads = _out_and_grads(
+        lambda *a: fa_mod._flash(*a, causal, 16, 16), w, q, k, v)
+    ref, ref_grads = _out_and_grads(
+        lambda *a: _chunk_ref(*a, 0, 0, causal)[0], w, q, k, v)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-4, atol=2e-4)
+    _assert_grads_close(grads, ref_grads)
 
 
 # (q_offset, kv_offset) of a 48-row chunk against a 48-key chunk at
@@ -421,9 +436,9 @@ def test_offset_chunks_with_lse_cotangent(chunk):
         np.asarray(jnp.where(has_key, lse_ref, 0.0)), rtol=2e-4, atol=2e-4)
     assert np.all(np.asarray(lse)[~np.broadcast_to(has_key, lse.shape)]
                   < -1e29)
-    got = jax.grad(loss(run), (0, 1, 2))(q, k, v)
-    want = jax.grad(loss(lambda *a: _chunk_ref(*a, q_off, kv_off)),
-                    (0, 1, 2))(q, k, v)
+    got = _grad(loss(run), (0, 1, 2))(q, k, v)
+    want = _grad(loss(lambda *a: _chunk_ref(*a, q_off, kv_off)),
+                 (0, 1, 2))(q, k, v)
     _assert_grads_close(got, want)
     if chunk == "future":
         for g in got:
@@ -454,8 +469,8 @@ def test_bias_with_a_fully_padded_batch_row(causal):
         np.asarray(out[0]),
         np.asarray(_ref(q, k, v, bias=bias, causal=causal)[0]),
         rtol=2e-4, atol=2e-4)
-    got = jax.grad(f, (0, 1, 2))(q, k, v)
-    _assert_grads_close(got, jax.grad(fr, (0, 1, 2))(q, k, v))
+    got = _grad(f, (0, 1, 2))(q, k, v)
+    _assert_grads_close(got, _grad(fr, (0, 1, 2))(q, k, v))
     for g in got:
         np.testing.assert_array_equal(np.asarray(g[1]), 0.0)
 
@@ -577,19 +592,23 @@ def test_lane_wide_blocks(case):
 
     has_key = _chunk_ref(q, k, v, q_off, kv_off)[2]
 
-    def loss(fn):
+    def readings(fn):
+        """One compiled program a side: the values, the statistic and
+        the three gradients."""
         def _l(*a):
             o, lse = fn(*a)
             extra = 0.0 if kind != "offsets" else \
                 (jnp.where(has_key, lse, 0.0) * u).sum()
-            return (o * w).sum() + extra
-        return _l
+            return (o * w).sum() + extra, (o, lse)
+        (_, (o, lse)), grads = jax.jit(jax.value_and_grad(
+            _l, (0, 1, 2), has_aux=True))(q, k, v)
+        return o, lse, grads
 
-    o_ref, lse_ref = ref(q, k, v)
-    np.testing.assert_allclose(np.asarray(run(q, k, v)[0]),
-                               np.asarray(o_ref), rtol=2e-4, atol=2e-4)
-    _assert_grads_close(jax.grad(loss(run), (0, 1, 2))(q, k, v),
-                        jax.grad(loss(ref), (0, 1, 2))(q, k, v))
+    o, _, grads = readings(run)
+    o_ref, lse_ref, ref_grads = readings(ref)
+    np.testing.assert_allclose(np.asarray(o), np.asarray(o_ref),
+                               rtol=2e-4, atol=2e-4)
+    _assert_grads_close(grads, ref_grads)
     # The statistic the forward hands the backward (the residual named
     # flash_lse) and a ring step its merge.
     offsets = jnp.array([q_off, kv_off], jnp.int32) \
@@ -608,6 +627,7 @@ def test_lane_wide_blocks(case):
 # where i - W < j <= i.
 # ---------------------------------------------------------------------
 
+@functools.partial(jax.jit, static_argnums=3)
 def _band_ref(q, k, v, window):
     """Kernel layout [B, H(kv), T, D], an explicit band mask."""
     rep = q.shape[1] // k.shape[1]
@@ -670,14 +690,12 @@ def test_window_kernel_values_and_grads(t, bq, bk, window):
     k = jax.random.normal(ks[1], (2, 2, t, 8), jnp.float32)
     v = jax.random.normal(ks[2], (2, 2, t, 8), jnp.float32)
     w = jax.random.normal(ks[3], q.shape)
-    out = fa_mod._flash(q, k, v, True, bq, bk, window)
-    np.testing.assert_allclose(np.asarray(out),
-                               np.asarray(_band_ref(q, k, v, window)),
+    out, got = _out_and_grads(
+        lambda *a: fa_mod._flash(*a, True, bq, bk, window), w, q, k, v)
+    want, ref = _out_and_grads(
+        lambda *a: _band_ref(*a, window), w, q, k, v)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                rtol=2e-4, atol=2e-4)
-    got = jax.grad(lambda *a: (fa_mod._flash(*a, True, bq, bk, window)
-                               * w).sum(), (0, 1, 2))(q, k, v)
-    ref = jax.grad(lambda *a: (_band_ref(*a, window) * w).sum(),
-                   (0, 1, 2))(q, k, v)
     _assert_grads_close(got, ref)
     if window >= t:   # a window wider than the sequence changes nothing
         np.testing.assert_allclose(
@@ -794,7 +812,7 @@ def test_values_of_another_width_and_a_scale_handed_in(scale, blocks):
         def f(q, k, v):
             out = attn(q, k, v, causal=True, scale=scale, **kw)
             return jnp.sum(out * w), out
-        return jax.grad(f, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+        return _grad(f, argnums=(0, 1, 2), has_aux=True)(q, k, v)
 
     got, out = grads(flash_attention, block_q=blocks[0], block_k=blocks[1])
     want, out_want = grads(blockwise_attention)
@@ -818,7 +836,7 @@ def test_one_width_and_no_scale_is_bit_for_bit_what_it_was():
     v = k * 0.5 + 1.0
 
     def grads(**kw):
-        return jax.grad(lambda q, k, v: jnp.sum(jnp.square(flash_attention(
+        return _grad(lambda q, k, v: jnp.sum(jnp.square(flash_attention(
             q, k, v, causal=True, block_q=32, block_k=32, **kw))),
             argnums=(0, 1, 2))(q, k, v)
 

@@ -79,13 +79,23 @@ def test_stage_one_is_the_expression(kernels, dtype, T, tokens, a_pass):
     def got(qkvz, w):
         return _by_heads(module.chain_in(qkvz, w, hk, hv), d)
 
-    for name, a, b in zip("qkvz", got(qkvz, w), ref(qkvz, w)):
+    def readings(f):
+        """One compiled program a side: the four outputs and the two
+        gradients (evaluated eagerly the expression is a compile a
+        primitive; the kernel's forward under ``jax.grad`` is the call
+        it makes alone)."""
+        def loss(*x):
+            outs = f(*x)
+            return _weighted(outs, weights), outs
+        (_, outs), grads = jax.jit(jax.value_and_grad(
+            loss, (0, 1), has_aux=True))(qkvz, w)
+        return outs, grads
+
+    (outs, grads), (ref_outs, ref_grads) = readings(got), readings(ref)
+    for name, a, b in zip("qkvz", outs, ref_outs):
         assert a.dtype == dtype
         _close(a, b, dtype, name)
-    grads = [jax.jit(jax.grad(
-        lambda *x, f=f: _weighted(f(*x), weights), (0, 1)))(qkvz, w)
-        for f in (got, ref)]
-    for name, a, b in zip(("d qkvz", "d taps"), *grads):
+    for name, a, b in zip(("d qkvz", "d taps"), grads, ref_grads):
         assert a.dtype == dtype
         _close(a, b, dtype, name)
 
@@ -95,8 +105,8 @@ def test_stage_one_with_as_many_key_heads_as_value_heads(kernels):
     qkvz, w, weights = _stage_one(F32, 1, 16, 2, 2, 16, taps=3)
     for f in (lambda *x: _by_heads(module.chain_in(*x, 2, 2), 16),
               lambda *x: llama._gdn_chain_in(*x, 2, 2, 16, 16)):
-        weights.append(jax.grad(
-            lambda *x: _weighted(f(*x), weights[:4]), (0, 1))(qkvz, w))
+        weights.append(jax.jit(jax.grad(
+            lambda *x: _weighted(f(*x), weights[:4]), (0, 1)))(qkvz, w))
     for name, a, b in zip(("d qkvz", "d taps"), *weights[4:]):
         _close(a, b, F32, name)
 
@@ -136,8 +146,10 @@ def test_stage_two_is_the_expression(kernels, dtype, T, tokens, heads):
                                 eps).reshape(o.shape)
 
     ref = llama._gdn_chain_out
-    assert got(o, z, gain, eps).dtype == dtype
-    _close(got(o, z, gain, eps), ref(o, z, gain, eps), dtype, "out")
+    out = jax.jit(got, static_argnums=3)(o, z, gain, eps)
+    assert out.dtype == dtype
+    _close(out, jax.jit(ref, static_argnums=3)(o, z, gain, eps), dtype,
+           "out")
     grads = [jax.jit(jax.grad(loss(f), (0, 1, 2)))(o, z, gain)
              for f in (got, ref)]
     for name, a, b in zip(("d o", "d z", "d gain"), *grads):

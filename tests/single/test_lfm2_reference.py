@@ -4,9 +4,16 @@ and every gradient leaf under each remat mode, conv layers beside
 attention layers unrolled over parameter stacks by kind of layer and a
 uniform conv stack scanned, dense and expert FFNs, the tied and sliced
 vocabulary, the share of the experts with no shared expert beside it
-(four shares sum to the whole layer), the convolution's causality, and
-that the older configurations build what they always did. Small sizes,
-CPU.
+(four shares sum to the whole layer), and the convolution's causality.
+Small sizes, CPU. (That the older configurations build what they always
+did: tests/single/test_older_configurations.py.)
+
+What a case pays for (tests/conftest.py): ``_cfg()`` is the smallest
+depth that holds every kind of layer and every parameter stack (the
+leading dense conv layer and ONE period: five layers), and the remat
+sweep, the share's other shapes and the vocabulary slice compile THAT;
+the period's repetition (stacks two deep beside a stack six deep) is the
+one ``published-head`` case, two dense conv layers and two periods.
 
 The tolerance is tests/single/test_afmoe_reference.py's: program and
 reference both compute in float32 and differ in the order of float32
@@ -44,13 +51,13 @@ STACKS = ("dense_conv_layers", "dense_layers", "conv_layers", "layers")
 
 
 def _cfg(**kw):
-    """The cell's shape in small: a leading dense conv layer, then two
-    periods of (attention, conv, conv, conv); experts 2..3 of 8 held;
+    """The cell's shape in small: a leading dense conv layer, then ONE
+    period of (attention, conv, conv, conv); experts 2..3 of 8 held;
     heads 16 wide; the head tied to the embedding."""
-    base = dict(vocab_size=128, d_model=64, n_layers=9, n_heads=4,
+    base = dict(vocab_size=128, d_model=64, n_layers=5, n_heads=4,
                 n_kv_heads=2, d_ff=96, moe_d_ff=32, rope_theta=1e6,
                 n_experts=8, n_experts_per_token=4, n_dense_layers=1,
-                layer_types=(C,) + (A, C, C, C) * 2, conv_taps=3,
+                layer_types=(C,) + (A, C, C, C), conv_taps=3,
                 rope_full_attention=True, tie_embeddings=True,
                 score_func="sigmoid", norm_topk_prob=True,
                 route_scale=1.0, qk_norm="head", first_expert=2,
@@ -91,7 +98,7 @@ def _err(got, ref):
 # Compiled once a configuration (``cfg`` static), as the cell runs them:
 # called eagerly, jax compiles these programs a primitive at a time. The
 # EAGER call, which users make too, stays in
-# ``test_the_models_the_benchmark_had_build_what_they_built``.
+# tests/single/test_older_configurations.py.
 _forward = jax.jit(llama_forward, static_argnums=2)
 _ref_forward = jax.jit(lfm2_forward, static_argnums=2)
 _loss = jax.jit(llama_loss, static_argnums=2)
@@ -188,7 +195,8 @@ def test_parameter_stacks_by_kind_of_layer():
     ``lm_head``; every leaf finds a partition rule of its own rank."""
     import re
 
-    cfg = _cfg()
+    # two periods, as the cell repeats them: nothing is compiled here
+    cfg = _cfg(n_layers=9, layer_types=(C,) + (A, C, C, C) * 2)
     params = llama_init(cfg, jax.random.PRNGKey(0))
     assert sorted(params) == ["conv_layers", "dense_conv_layers", "embed",
                               "final_norm", "layers"]
@@ -305,8 +313,8 @@ def test_loss_over_the_tied_vocabulary_slice():
 
 
 @pytest.mark.parametrize("bad", [
-    dict(conv_taps=0), dict(layer_types=(A,) * 9),
-    dict(layer_types=("convolution",) * 9)])
+    dict(conv_taps=0), dict(layer_types=(A,) * 5),
+    dict(layer_types=("convolution",) * 5)])
 def test_conv_layers_and_their_taps_come_together(bad):
     with pytest.raises(ValueError):
         _cfg(**bad)
@@ -341,50 +349,3 @@ def test_conv_layers_and_a_tied_head_have_no_pipeline_schedule(field):
     mesh = jax.sharding.Mesh(np.array(jax.devices()[:2]), ("pipe",))
     with pytest.raises(ValueError, match="no pipeline schedule"):
         _validate_pipeline(LlamaConfig.tiny(**field), 2, mesh, "seq", 2)
-
-
-# What the models the benchmark already had build, after the stacks went
-# by kind of layer: the tree's names, and the loss on seeded weights to
-# the last digit (read at the parent commit, c001471).
-S, F = "sliding_attention", "full_attention"
-_TRINITY = dict(vocab_size=128, d_model=64, n_layers=5, n_heads=4,
-                n_kv_heads=2, d_head=32, d_ff=96, moe_d_ff=32,
-                rope_theta=10000.0, n_experts=16, n_experts_per_token=4,
-                n_dense_layers=1, layer_types=(S, S, S, S, F),
-                sliding_window=6, n_shared_experts=1,
-                score_func="sigmoid", norm_topk_prob=True,
-                route_scale=2.826, scale_embed=True, attn_gate=True,
-                post_norm=True, qk_norm="head", first_expert=4,
-                n_experts_held=4, moe_impl="grouped", moe_aux_weight=0.0,
-                dtype="float32", param_dtype="float32", remat=False)
-_BEFORE = {
-    "dense": (LlamaConfig.tiny(), ["layers"], 5.90579891204834),
-    "olmoe": (LlamaConfig.tiny(n_experts=8, n_experts_per_token=3,
-                               qk_norm=True, norm_topk_prob=False,
-                               moe_impl="grouped", remat="attn+moe"),
-              ["layers"], 6.124673366546631),
-    "trinity": (LlamaConfig(**_TRINITY), ["dense_layers", "layers"],
-                5.229442119598389),
-    "trinity-bf16": (LlamaConfig(**dict(_TRINITY, dtype="bfloat16",
-                                        remat="attn")),
-                     ["dense_layers", "layers"], 5.231811046600342),
-}
-
-
-@pytest.mark.parametrize("which", sorted(_BEFORE))
-def test_the_models_the_benchmark_had_build_what_they_built(which):
-    """The loss to the last bit, by the EAGER call of ``llama_loss``:
-    this file's cases that run the model a primitive at a time, as a
-    user without ``jax.jit`` does."""
-    cfg, stacks, loss = _BEFORE[which]
-    params = llama_init(cfg, jax.random.PRNGKey(0))
-    assert sorted(params) == sorted(stacks + ["embed", "final_norm",
-                                              "lm_head"])
-    assert all(s.mixer == "attention" for s in cfg.layer_plan())
-    shape = (2, 16)
-    tokens = jax.random.randint(jax.random.PRNGKey(1), shape, 0,
-                                128 if "trinity" in which else 256)
-    batch = {"tokens": tokens, "targets": jnp.roll(tokens, -1, 1)}
-    assert float(llama_loss(params, batch, cfg)) == loss
-    if "trinity" in which:   # full_attention without RoPE, as ever
-        assert [s.rope for s in cfg.layer_plan()] == [True] * 4 + [False]

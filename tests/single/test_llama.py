@@ -7,6 +7,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
+import pytest
 
 from horovod_tpu import parallel
 from horovod_tpu.models import (
@@ -17,6 +18,30 @@ from horovod_tpu.models import (
     llama_partition_rules,
 )
 from horovod_tpu.parallel.sharding import apply_sharding, named_sharding
+
+
+# One compiled program a configuration: evaluated eagerly the model is
+# a compile a primitive (7 to 10 s a call), and
+# ``test_forward_shapes_and_determinism`` is the file's case that makes
+# the EAGER call, which users make too.
+_forward = jax.jit(llama_forward, static_argnums=2,
+                   static_argnames="return_aux")
+_grads = jax.jit(jax.grad(llama_loss), static_argnums=2)
+
+
+def _loss_and_grads(cfg, params, batch):
+    return jax.jit(jax.value_and_grad(
+        lambda p: llama_loss(p, batch, cfg)))(params)
+
+
+def _assert_same_loss_and_grads(got, ref, atol=lambda b: 1e-6):
+    """The file's bounds for "a pure scheduling choice": the loss to
+    1e-6, every gradient leaf to rtol 1e-5 and ``atol`` of the leaf."""
+    np.testing.assert_allclose(float(got[0]), float(ref[0]), rtol=1e-6)
+    jax.tree.map(
+        lambda a, b: np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=1e-5, atol=atol(b)),
+        got[1], ref[1])
 
 
 def test_forward_shapes_and_determinism():
@@ -37,8 +62,8 @@ def test_causality():
     params = llama_init(cfg, jax.random.PRNGKey(0))
     t1 = jnp.zeros((1, 8), jnp.int32)
     t2 = t1.at[0, 7].set(5)
-    l1 = llama_forward(params, t1, cfg)
-    l2 = llama_forward(params, t2, cfg)
+    l1 = _forward(params, t1, cfg)
+    l2 = _forward(params, t2, cfg)
     np.testing.assert_allclose(np.asarray(l1[0, :7]), np.asarray(l2[0, :7]),
                                rtol=1e-5, atol=1e-6)
     assert not np.allclose(np.asarray(l1[0, 7]), np.asarray(l2[0, 7]))
@@ -86,38 +111,42 @@ def test_sharded_train_step_matches_single_device():
                                    atol=1e-6)
 
 
-def test_flash_block_is_pure_scheduling():
+def _interpret_flash_kernels(monkeypatch):
+    import importlib
+
+    monkeypatch.setattr(
+        importlib.import_module("horovod_tpu.ops.flash_attention"),
+        "_INTERPRET", True)
+
+
+@pytest.fixture(scope="module")
+def flash_block_model():
+    """-> (cfg, params, batch, the kernel-default loss and gradients on
+    the interpret-mode kernels)."""
+    cfg = LlamaConfig.tiny(dtype="float32", n_layers=2, remat=False)
+    params = llama_init(cfg, jax.random.PRNGKey(0))
+    tokens = jax.random.randint(jax.random.PRNGKey(3), (2, 32), 0,
+                                cfg.vocab_size)
+    batch = {"tokens": tokens, "targets": jnp.roll(tokens, -1, 1)}
+    with pytest.MonkeyPatch.context() as monkeypatch:
+        _interpret_flash_kernels(monkeypatch)
+        return cfg, params, batch, _loss_and_grads(cfg, params, batch)
+
+
+@pytest.mark.parametrize("block", [16, 512])  # below t=32 / clamped to it
+def test_flash_block_is_pure_scheduling(block, flash_block_model,
+                                        monkeypatch):
     """LlamaConfig.flash_block (the sweep knob for the pallas
     q/k grid blocks) must not change the math: loss and grads match the
     kernel-default config. Runs the REAL pallas kernels in interpret
     mode (the XLA fallback ignores the block args, which would make
     this test vacuous on CPU) — an oversized block exercises
     _pick_block's clamp-to-sequence too."""
-    import dataclasses
-    import importlib
-
-    fa_mod = importlib.import_module("horovod_tpu.ops.flash_attention")
-
-    cfg = LlamaConfig.tiny(dtype="float32", n_layers=2, remat=False)
-    params = llama_init(cfg, jax.random.PRNGKey(0))
-    tokens = jax.random.randint(jax.random.PRNGKey(3), (2, 32), 0,
-                                cfg.vocab_size)
-    batch = {"tokens": tokens, "targets": jnp.roll(tokens, -1, 1)}
-    fa_mod._INTERPRET = True
-    try:
-        ref_l, ref_g = jax.value_and_grad(llama_loss)(params, batch, cfg)
-        for block in (16, 512):  # clamped to t=32 / below it
-            cfg_b = dataclasses.replace(cfg, flash_block=block)
-            l, g = jax.value_and_grad(llama_loss)(params, batch, cfg_b)
-            np.testing.assert_allclose(float(l), float(ref_l),
-                                       rtol=1e-6)
-            jax.tree.map(
-                lambda a, b: np.testing.assert_allclose(
-                    np.asarray(a), np.asarray(b), rtol=1e-5,
-                    atol=1e-6),
-                ref_g, g)
-    finally:
-        fa_mod._INTERPRET = False
+    cfg, params, batch, ref = flash_block_model
+    _interpret_flash_kernels(monkeypatch)
+    _assert_same_loss_and_grads(
+        _loss_and_grads(dataclasses.replace(cfg, flash_block=block), params,
+                        batch), ref)
 
 
 def test_seq_parallel_forward_matches():
@@ -126,7 +155,7 @@ def test_seq_parallel_forward_matches():
     params = llama_init(cfg, jax.random.PRNGKey(0))
     tokens = jax.random.randint(jax.random.PRNGKey(3), (2, 32), 0,
                                 cfg.vocab_size)
-    ref = llama_forward(params, tokens, cfg)
+    ref = _forward(params, tokens, cfg)
 
     mesh = parallel.create_mesh(data=2, seq=4)
     shardings = parallel.shard_params(params, mesh, llama_partition_rules())
@@ -147,7 +176,7 @@ def test_seq_parallel_ulysses_matches():
     params = llama_init(cfg, jax.random.PRNGKey(0))
     tokens = jax.random.randint(jax.random.PRNGKey(3), (2, 32), 0,
                                 cfg.vocab_size)
-    ref = llama_forward(params, tokens, cfg)
+    ref = _forward(params, tokens, cfg)
 
     mesh = parallel.create_mesh(data=2, seq=4)
     shardings = parallel.shard_params(params, mesh, llama_partition_rules())
@@ -168,7 +197,7 @@ def test_moe_forward_and_aux():
         cfg.n_layers, cfg.n_experts, cfg.d_model, cfg.d_ff)
     tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 16), 0,
                                 cfg.vocab_size)
-    logits, aux = llama_forward(params, tokens, cfg, return_aux=True)
+    logits, aux = _forward(params, tokens, cfg, return_aux=True)
     assert logits.shape == (2, 16, cfg.vocab_size)
     assert np.isfinite(np.asarray(logits)).all()
     # All K choices counted: K at perfectly uniform routing, E at
@@ -187,11 +216,11 @@ def test_moe_routing_is_sparse():
     # probability (routing is data-dependent).
     tokens = jax.random.randint(jax.random.PRNGKey(1), (4, 32), 0,
                                 cfg.vocab_size)
-    ref = np.asarray(llama_forward(params, tokens, cfg))
+    ref = np.asarray(_forward(params, tokens, cfg))
     mutated = jax.tree.map(lambda x: x, params)
     mutated["layers"]["moe_down"] = (
         params["layers"]["moe_down"].at[:, 0].set(0.0))
-    out = np.asarray(llama_forward(mutated, tokens, cfg))
+    out = np.asarray(_forward(mutated, tokens, cfg))
     changed = ~np.isclose(ref, out).all(axis=-1)  # [B, T] per-token
     assert changed.any(), "no token used expert 0"
     assert not changed.all(), "zeroing one expert changed every token"
@@ -262,7 +291,7 @@ def test_pipeline_forward_matches_single_device():
     params = llama_init(cfg, jax.random.PRNGKey(0))
     tokens = jax.random.randint(jax.random.PRNGKey(1), (4, 16), 0,
                                 cfg.vocab_size)
-    ref = np.asarray(llama_forward(params, tokens, cfg))
+    ref = np.asarray(_forward(params, tokens, cfg))
 
     mesh = parallel.create_mesh(pipe=2, fsdp=2, tensor=2,
                                 devices=jax.devices()[:8])
@@ -323,7 +352,7 @@ def test_pipeline_with_moe():
     params = llama_init(cfg, jax.random.PRNGKey(0))
     tokens = jax.random.randint(jax.random.PRNGKey(1), (4, 16), 0,
                                 cfg.vocab_size)
-    ref = np.asarray(llama_forward(params, tokens, cfg))
+    ref = np.asarray(_forward(params, tokens, cfg))
 
     mesh = parallel.create_mesh(pipe=2, expert=2, tensor=2,
                                 devices=jax.devices()[:8])
@@ -381,7 +410,7 @@ def test_param_dtype_bf16():
     assert all(l.dtype == jnp.bfloat16 for l in jax.tree.leaves(params))
     tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 16), 0,
                                 cfg.vocab_size)
-    out = llama_forward(params, tokens, cfg)
+    out = _forward(params, tokens, cfg)
     assert bool(jnp.isfinite(out.astype(jnp.float32)).all())
     loss = llama_loss(params, {"tokens": tokens,
                                "targets": jnp.roll(tokens, -1, 1)}, cfg)
@@ -566,7 +595,7 @@ def test_fused_adam_matches_optax():
     from horovod_tpu.parallel import fused_adam
 
     cfg, params, batch = _tiny_train_setup()
-    grads = jax.grad(llama_loss)(params, batch, cfg)
+    grads = _grads(params, batch, cfg)
 
     tx = optax.adam(1e-2)
     opt = tx.init(params)
@@ -593,7 +622,7 @@ def test_fused_master_adam_matches_split_master():
     from horovod_tpu.parallel import fused_master_adam, master_weights
 
     cfg, params, batch = _tiny_train_setup()
-    grads = jax.grad(llama_loss)(params, batch, cfg)
+    grads = _grads(params, batch, cfg)
 
     mw = master_weights(optax.adam(1e-2))
     mw_state = mw.init(params)
@@ -649,7 +678,8 @@ def test_split_step_with_fused_master_trains():
     assert losses[-1] < losses[0], losses
 
 
-def test_apply_jit_emits_no_donation_warning(hvdlint):
+@pytest.mark.parametrize("optimizer", ["fused_master_adam", "optax.adam"])
+def test_apply_jit_emits_no_donation_warning(optimizer, hvdlint):
     """The split step's apply jit must donate ONLY buffers XLA can
     actually alias (params + optimizer state; gradients have no
     matching output). The fp32-master path used to warn "Some donated
@@ -673,105 +703,69 @@ def test_apply_jit_emits_no_donation_warning(hvdlint):
                                 cfg.vocab_size)
     batch = {"tokens": tokens, "targets": jnp.roll(tokens, -1, 1)}
 
-    for tx in (fused_master_adam(1e-2), optax.adam(1e-2)):
-        ts = make_split_train_step(
-            lambda p, d: llama_loss(p, d, cfg), tx, microbatches=2)
-        carry0 = jax.eval_shape(ts.init, params)
-        hvdlint(ts.step, (carry0, batch))
-        with warnings.catch_warnings(record=True) as caught:
-            warnings.simplefilter("always")
-            loss, carry = ts.step(ts.init(params), batch)
-            jax.block_until_ready(loss)
-        bad = [w for w in caught
-               if "donated buffers were not usable" in str(w.message)]
-        assert not bad, (type(tx).__name__, [str(w.message)
-                                             for w in bad])
+    tx = {"fused_master_adam": fused_master_adam,
+          "optax.adam": optax.adam}[optimizer](1e-2)
+    ts = make_split_train_step(
+        lambda p, d: llama_loss(p, d, cfg), tx, microbatches=2)
+    carry0 = jax.eval_shape(ts.init, params)
+    hvdlint(ts.step, (carry0, batch))
+    with warnings.catch_warnings(record=True) as caught:
+        warnings.simplefilter("always")
+        loss, carry = ts.step(ts.init(params), batch)
+        jax.block_until_ready(loss)
+    bad = [w for w in caught
+           if "donated buffers were not usable" in str(w.message)]
+    assert not bad, [str(w.message) for w in bad]
 
 
-def test_remat_modes_agree_on_gradients():
+def _remat_free(cfg0):
+    """-> (cfg0, params, batch, loss and gradients under no remat)."""
+    params = llama_init(cfg0, jax.random.PRNGKey(0))
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 16), 0,
+                                cfg0.vocab_size)
+    batch = {"tokens": tokens, "targets": jnp.roll(tokens, -1, 1)}
+    return cfg0, params, batch, _loss_and_grads(cfg0, params, batch)
+
+
+@pytest.fixture(scope="module")
+def dense_without_remat():
+    return _remat_free(LlamaConfig.tiny(dtype="float32", n_layers=2,
+                                        remat=False))
+
+
+@pytest.fixture(scope="module")
+def moe_without_remat():
+    return _remat_free(LlamaConfig.tiny_moe(dtype="float32", n_layers=2,
+                                            remat=False))
+
+
+@pytest.mark.parametrize("mode", ["attn", "attn+gate", "attn+gate+qkv",
+                                  "attn+ffn", "dots", "full"])
+def test_remat_modes_agree_on_gradients(mode, dense_without_remat):
     """Every remat policy is a pure scheduling choice: loss and grads
     must match remat=False bit-for-bit-ish (f32 tolerances). Covers the
     r4 'attn+gate'/'attn+ffn' modes whose saved FFN residuals must not
     change the math."""
-    cfg0 = LlamaConfig.tiny(dtype="float32", n_layers=2, remat=False)
-    params = llama_init(cfg0, jax.random.PRNGKey(0))
-    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 16), 0,
-                                cfg0.vocab_size)
-    batch = {"tokens": tokens, "targets": jnp.roll(tokens, -1, 1)}
-
-    def loss_and_grads(remat):
-        cfg = dataclasses.replace(cfg0, remat=remat)
-        return jax.jit(jax.value_and_grad(
-            lambda p: llama_loss(p, batch, cfg)))(params)
-
-    ref_loss, ref_grads = loss_and_grads(False)
-    for mode in ("attn", "attn+gate", "attn+gate+qkv", "attn+ffn",
-                 "dots", "full"):
-        loss, grads = loss_and_grads(mode)
-        np.testing.assert_allclose(float(loss), float(ref_loss),
-                                   rtol=1e-6, err_msg=mode)
-        jax.tree.map(
-            lambda a, b: np.testing.assert_allclose(
-                np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-6,
-                err_msg=mode),
-            grads, ref_grads)
+    cfg0, params, batch, ref = dense_without_remat
+    _assert_same_loss_and_grads(_loss_and_grads(
+        dataclasses.replace(cfg0, remat=mode), params, batch), ref)
 
 
-def test_remat_modes_agree_on_gradients_moe():
+# attn+moe / moe cover the grouped path's saved residuals (the sorted
+# order, its inverse and the gate weights in that order; pre-silu gate
+# and up) — remat must stay scheduling-only.
+@pytest.mark.parametrize("mode", ["attn", "attn+gate", "attn+gate+qkv",
+                                  "attn+ffn", "attn+moe", "moe", "dots",
+                                  "full"])
+def test_remat_modes_agree_on_gradients_moe(mode, moe_without_remat):
     """Same scheduling-only contract for the MoE layer — covers the
     saved moe_dispatch/moe_combine residuals under attn+gate."""
-    cfg0 = LlamaConfig.tiny_moe(dtype="float32", n_layers=2, remat=False)
-    params = llama_init(cfg0, jax.random.PRNGKey(0))
-    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 16), 0,
-                                cfg0.vocab_size)
-    batch = {"tokens": tokens, "targets": jnp.roll(tokens, -1, 1)}
-
-    def loss_and_grads(remat):
-        cfg = dataclasses.replace(cfg0, remat=remat)
-        return jax.jit(jax.value_and_grad(
-            lambda p: llama_loss(p, batch, cfg)))(params)
-
-    ref_loss, ref_grads = loss_and_grads(False)
-    # attn+moe / moe cover the grouped path's saved residuals (the
-    # sorted order, its inverse and the gate weights in that order;
-    # pre-silu gate and up) — remat must stay scheduling-only.
-    for mode in ("attn", "attn+gate", "attn+gate+qkv", "attn+ffn",
-                 "attn+moe", "moe", "dots", "full"):
-        loss, grads = loss_and_grads(mode)
-        np.testing.assert_allclose(float(loss), float(ref_loss),
-                                   rtol=1e-6, err_msg=mode)
-        jax.tree.map(
-            lambda a, b: np.testing.assert_allclose(
-                np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-6,
-                err_msg=mode),
-            grads, ref_grads)
-
-
-def test_scan_unroll_is_scheduling_only():
-    """scan_unroll must not change values or gradients."""
-    cfg0 = LlamaConfig.tiny_moe(dtype="float32", n_layers=4, remat="attn")
-    params = llama_init(cfg0, jax.random.PRNGKey(0))
-    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 16), 0,
-                                cfg0.vocab_size)
-    batch = {"tokens": tokens, "targets": jnp.roll(tokens, -1, 1)}
-
-    def loss_and_grads(unroll):
-        cfg = dataclasses.replace(cfg0, scan_unroll=unroll)
-        return jax.jit(jax.value_and_grad(
-            lambda p: llama_loss(p, batch, cfg)))(params)
-
-    ref_loss, ref_grads = loss_and_grads(1)
-    loss, grads = loss_and_grads(4)
-    np.testing.assert_allclose(float(loss), float(ref_loss), rtol=1e-6)
-    jax.tree.map(
-        lambda a, b: np.testing.assert_allclose(
-            np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-6),
-        grads, ref_grads)
+    cfg0, params, batch, ref = moe_without_remat
+    _assert_same_loss_and_grads(_loss_and_grads(
+        dataclasses.replace(cfg0, remat=mode), params, batch), ref)
 
 
 def test_unknown_remat_mode_rejected():
-    import pytest
-
     cfg = LlamaConfig.tiny(dtype="float32", remat="bogus")
     params = llama_init(cfg, jax.random.PRNGKey(0))
     tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 8), 0,
@@ -784,8 +778,6 @@ def test_moe_remat_modes_rejected_without_grouped_dispatch():
     """attn+moe / moe save residuals only grouped_moe_ffn emits — a
     dense config or a forced-GShard one must fail loudly instead of
     silently degrading to plain attn remat."""
-    import pytest
-
     tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 8), 0, 256)
     dense = LlamaConfig.tiny(dtype="float32", remat="attn+moe")
     with pytest.raises(ValueError, match="grouped MoE dispatch"):
@@ -815,12 +807,17 @@ def _seam_model(**kw):
     return cfg, params, {"tokens": tokens, "targets": jnp.roll(tokens, -1, 1)}
 
 
-def _loss_and_grads(cfg, params, batch):
-    return jax.jit(jax.value_and_grad(
-        lambda p: llama_loss(p, batch, cfg)))(params)
+@pytest.fixture(scope="module")
+def seam_on_the_expressions():
+    """-> (cfg, params, batch, loss and gradients by the expressions,
+    no kernel in the program)."""
+    cfg, params, batch = _seam_model()
+    return cfg, params, batch, _loss_and_grads(cfg, params, batch)
 
 
-def test_seam_kernels_match_the_expressions(monkeypatch):
+@pytest.mark.parametrize("mode", [False, "attn", "attn+gate+qkv"])
+def test_seam_kernels_match_the_expressions(mode, seam_on_the_expressions,
+                                            monkeypatch):
     """A ``qk_norm="head"`` model with the seam on its kernel pair
     (interpret mode; the attention behind it on the reference math, fed
     head-major) against the expressions ``_head_proj`` + ``_rope``: loss
@@ -832,21 +829,12 @@ def test_seam_kernels_match_the_expressions(monkeypatch):
     16,384, where the kernels' backward adds in another order)."""
     from horovod_tpu.ops import qk_prep
 
-    cfg, params, batch = _seam_model()
-    ref_loss, ref_grads = _loss_and_grads(cfg, params, batch)
+    cfg, params, batch, ref = seam_on_the_expressions
     monkeypatch.setattr(qk_prep, "_INTERPRET", True)
     monkeypatch.setattr(qk_prep, "TOKENS_A_STEP", 16)
-    for mode in (False, "attn", "attn+gate+qkv"):
-        loss, grads = _loss_and_grads(
-            dataclasses.replace(cfg, remat=mode), params, batch)
-        np.testing.assert_allclose(float(loss), float(ref_loss),
-                                   rtol=1e-6, err_msg=str(mode))
-        jax.tree.map(
-            lambda a, b: np.testing.assert_allclose(
-                np.asarray(a), np.asarray(b), rtol=1e-5,
-                atol=1e-6 * max(1.0, float(jnp.abs(b).max())),
-                err_msg=str(mode)),
-            grads, ref_grads)
+    _assert_same_loss_and_grads(
+        _loss_and_grads(dataclasses.replace(cfg, remat=mode), params, batch),
+        ref, atol=lambda b: 1e-6 * max(1.0, float(jnp.abs(b).max())))
 
 
 def test_seam_kernels_hand_the_remat_policies_their_names(monkeypatch):

@@ -72,8 +72,10 @@ def test_the_pick_and_its_transpose_are_the_gather_and_the_scatter(
         E, K, score, with_bias, norm, scale):
     h, router_w, bias, g, _ = _inputs(E, K, with_bias)
     probs = jax.random.uniform(jax.random.PRNGKey(K), TOKENS + (E,))
-    idx = jax.jit(lambda: moe_route(
-        h, router_w, K, norm, score, bias, scale)[1])()
+    # operands as ARGUMENTS: closed over they are constants, and the
+    # compiler folds the router (a sort of 320 rows of E) in its evaluator
+    idx = jax.jit(lambda h, w, b: moe_route(
+        h, w, K, norm, score, b, scale)[1])(h, router_w, bias)
 
     want, scatter = jax.vjp(
         lambda p: jnp.take_along_axis(p, idx, axis=-1), probs)

@@ -122,15 +122,16 @@ def test_the_two_terms_and_the_logits():
     cfg = _cfg()
     params, batch = _params(cfg), _batch(cfg)
     with jax.default_matmul_precision("highest"):
-        logits = jax.jit(lambda p: llama_forward(p, batch["tokens"], cfg))(
-            params)
-        want, _ = jax.jit(lambda p: ref.nemotronh_forward(
-            p, batch["tokens"], cfg, batch["targets"]))(params)
-        main, mtp = jax.jit(lambda p: ref.nemotronh_loss(
-            p, batch, cfg, terms=True))(params)
-        loss = jax.jit(lambda p: llama_loss(p, batch, cfg))(params)
-        alone = jax.jit(lambda p: llama_loss(
-            p, batch, dataclasses.replace(cfg, mtp_weight=1.0)))(params)
+        # one program a side: the model runs once in each
+        logits, loss, alone = jax.jit(lambda p: (
+            llama_forward(p, batch["tokens"], cfg),
+            llama_loss(p, batch, cfg),
+            llama_loss(p, batch, dataclasses.replace(cfg, mtp_weight=1.0))
+        ))(params)
+        want, (main, mtp) = jax.jit(lambda p: (
+            ref.nemotronh_forward(p, batch["tokens"], cfg,
+                                  batch["targets"])[0],
+            ref.nemotronh_loss(p, batch, cfg, terms=True)))(params)
     assert float(jnp.max(jnp.abs(logits - want))
                  / jnp.max(jnp.abs(want))) < TOL
     assert abs(float(loss) - float(main + 0.1 * mtp)) < TOL * float(loss)

@@ -82,6 +82,10 @@ def _err(got, ref):
 _forward = jax.jit(llama_forward, static_argnums=2)
 _ref_forward = jax.jit(olmoe_forward, static_argnums=2)
 _loss_and_grads = jax.jit(jax.value_and_grad(llama_loss), static_argnums=2)
+# the decode path's two halves, the layer and the position as data
+_prefill = jax.jit(gen._prefill, static_argnums=(2, 3))
+_attend_step = jax.jit(gen._attend_step, static_argnums=2)
+_lm_logits = jax.jit(gen._lm_logits, static_argnums=2)
 
 
 def _all_readings(forward, loss):
@@ -177,15 +181,15 @@ def test_prefill_then_cached_decode_against_the_full_forward(batch):
     t0, n_new = 12, 4
     ref, _ = _ref_forward(params, tokens, cfg)
 
-    x, cache_k, cache_v = gen._prefill(params, tokens[:, :t0], cfg, n_new)
-    assert _err(gen._lm_logits(params, x, cfg), ref[:, :t0]) < TOL
+    x, cache_k, cache_v = _prefill(params, tokens[:, :t0], cfg, n_new)
+    assert _err(_lm_logits(params, x, cfg), ref[:, :t0]) < TOL
     for pos in range(t0, t0 + n_new):
         x = params["embed"][tokens[:, pos]]
         for li in range(cfg.n_layers):
             lp = jax.tree.map(lambda w: w[li], params["layers"])
-            x, cache_k, cache_v = gen._attend_step(
+            x, cache_k, cache_v = _attend_step(
                 x, lp, cfg, cache_k, cache_v, li, jnp.int32(pos))
-        assert _err(gen._lm_logits(params, x, cfg), ref[:, pos]) < TOL, pos
+        assert _err(_lm_logits(params, x, cfg), ref[:, pos]) < TOL, pos
 
 
 @pytest.mark.parametrize("remat", ["attn", "attn+moe", "moe", "full"])

@@ -5,9 +5,10 @@ under each remat mode for the published pattern (linear_attention x 3,
 full_attention), RoPE over a part of a head against a rotation written
 out by hand, the shared expert's gate, the share of the experts tied to
 the model (four shares and ONE shared expert sum to the uncut layer),
-the new stack under ``layer_plan`` and the partition rules, what the
-configuration refuses, and that the older configurations lower to the
-text they lowered to. Small sizes, CPU.
+the new stack under ``layer_plan`` and the partition rules, and what
+the configuration refuses. Small sizes, CPU. (That the older
+configurations lower to the text they lowered to:
+tests/single/test_older_configurations.py.)
 
 Tolerance: program and reference both compute in float32. The attention
 layer and the experts differ in the order of float32 additions (2e-5 of
@@ -21,7 +22,6 @@ position, a gate left out or a head served by the wrong key head move.
 
 import dataclasses
 import functools
-import hashlib
 
 import jax
 import jax.numpy as jnp
@@ -337,61 +337,3 @@ def test_decode_serving_and_the_pipeline_refuse_the_new_fields(field):
     mesh = jax.sharding.Mesh(np.array(jax.devices()[:2]), ("pipe",))
     with pytest.raises(ValueError, match="no pipeline schedule"):
         _validate_pipeline(cfg, 2, mesh, "seq", 2)
-
-
-# What the configurations the benchmark already had lower to with the
-# new fields at their defaults: the text of the gradient program, read
-# at the parent commit (599fcd4) by this very code, to the last byte.
-# The two share models were read again at PR 41, which changed them on
-# purpose (two chunks a layer here: the second is a loop that follows
-# the rows held, ``grouped_moe._later_chunks``). The three with a router
-# were read again at PR 54, which changed them on purpose (the pick of
-# the K chosen scores and its transpose as selects under a sum,
-# ``models/llama.py:_pick``; "dense" stands as it was read).
-S, C = "sliding_attention", "conv"
-_TRINITY = dict(vocab_size=128, d_model=64, n_layers=5, n_heads=4,
-                n_kv_heads=2, d_head=32, d_ff=96, moe_d_ff=32,
-                rope_theta=10000.0, n_experts=16, n_experts_per_token=4,
-                n_dense_layers=1, layer_types=(S, S, S, S, A),
-                sliding_window=6, n_shared_experts=1,
-                score_func="sigmoid", norm_topk_prob=True,
-                route_scale=2.826, scale_embed=True, attn_gate=True,
-                post_norm=True, qk_norm="head", first_expert=4,
-                n_experts_held=4, moe_impl="grouped", moe_aux_weight=0.0,
-                dtype="bfloat16", param_dtype="float32", remat="attn")
-_LFM2 = dict(vocab_size=128, d_model=64, n_layers=9, n_heads=4,
-             n_kv_heads=2, d_ff=96, moe_d_ff=32, rope_theta=1e6,
-             n_experts=8, n_experts_per_token=4, n_dense_layers=1,
-             layer_types=(C,) + (A, C, C, C) * 2, conv_taps=3,
-             rope_full_attention=True, tie_embeddings=True,
-             score_func="sigmoid", norm_topk_prob=True, route_scale=1.0,
-             qk_norm="head", first_expert=2, n_experts_held=2,
-             moe_impl="grouped", moe_aux_weight=0.0, dtype="bfloat16",
-             param_dtype="float32", remat="attn")
-_BEFORE = {
-    "dense": (LlamaConfig.tiny(remat="attn"), "b353182b28726edf", 93403),
-    "olmoe": (LlamaConfig.tiny(n_experts=8, n_experts_per_token=3,
-                               qk_norm=True, norm_topk_prob=False,
-                               moe_impl="grouped", remat="attn+moe"),
-              "19d57556ecb10f1a", 234792),
-    "trinity": (LlamaConfig(**_TRINITY), "8161ae6d2fb7892f", 1018007),
-    "lfm2": (LlamaConfig(**_LFM2), "20551dd593a4c1e1", 1246731),
-}
-
-
-@pytest.mark.parametrize("which", sorted(_BEFORE))
-def test_the_models_the_benchmark_had_lower_to_the_text_they_did(which):
-    cfg, digest, length = _BEFORE[which]
-    assert not set(cfg.training_only_fields()) & {
-        "linear_key_heads", "linear_value_heads", "linear_key_dim",
-        "linear_value_dim", "partial_rotary", "shared_expert_gate"}
-    params = jax.eval_shape(lambda k: llama_init(cfg, k),
-                            jax.random.PRNGKey(0))
-    assert not [k for stack in params.values() if isinstance(stack, dict)
-                for k in stack if k.startswith(("gdn_", "shared_score"))]
-    tokens = jax.ShapeDtypeStruct((2, 16), jnp.int32)
-    text = jax.jit(jax.value_and_grad(lambda p, t: llama_loss(
-        p, {"tokens": t, "targets": t}, cfg))).lower(params,
-                                                      tokens).as_text()
-    assert (len(text), hashlib.sha256(text.encode()).hexdigest()[:16]) \
-        == (length, digest)

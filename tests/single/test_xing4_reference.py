@@ -129,9 +129,17 @@ def _off(cfg, params, batch, want, want_grads, patched=False):
 
 
 @pytest.fixture(scope="module")
-def the_reference():
+def the_model():
     cfg = _cfg()
-    params, batch = _params(cfg), _batch(cfg)
+    return cfg, _params(cfg), _batch(cfg)
+
+
+@pytest.fixture(scope="module")
+def the_reference(the_model):
+    """The reference's loss and every gradient leaf beside the model:
+    for the cases that compare with them, and no other (fifteen seconds
+    wherever a worker first asks)."""
+    cfg, params, batch = the_model
     want, want_grads = _reference(cfg)(params, batch)
     return cfg, params, batch, want, want_grads
 
@@ -154,8 +162,8 @@ def test_loss_and_every_gradient_leaf_against_the_reference(the_reference,
     assert worst[0] <= GRAD_TOL, worst
 
 
-def test_the_two_terms(the_reference):
-    cfg, params, batch, _, _ = the_reference
+def test_the_two_terms(the_model):
+    cfg, params, batch = the_model
     main, mtp = jax.jit(lambda p: ref.xing4_loss(p, batch, cfg,
                                                  terms=True))(params)
     both = jax.jit(lambda p: llama_loss(p, batch, cfg))(params)
@@ -258,11 +266,11 @@ def test_a_planted_fault_is_refused(the_reference, monkeypatch, plant):
     assert off > 10, off
 
 
-def test_a_missing_clamp_is_refused(the_reference):
+def test_a_missing_clamp_is_refused(the_model):
     """Logits of 40 in ``H_res`` (a bias moved there): the reference
     clamps them to 30 before ``exp``, and a program that does not
     stands off."""
-    cfg, params, batch, _, _ = the_reference
+    cfg, params, batch = the_model
     bias = params["layers"]["hc_attn_bias"].at[:, 2 * cfg.hc_mult].set(40.0)
     params = {**params, "layers": {**params["layers"],
                                    "hc_attn_bias": bias}}
@@ -317,11 +325,11 @@ def test_the_iterations_against_the_reference(logits):
     np.testing.assert_array_equal(done, at_the_clamp)
 
 
-def test_the_coefficients_against_the_reference(the_reference):
+def test_the_coefficients_against_the_reference(the_model):
     """``_hc_coefficients`` in the program's layout [B, n, T, D] against
     the reference's [B, T, n, D]; at the seed ``H_res`` is not the
     identity and ``H_pre``, ``H_post`` differ by stream and token."""
-    cfg, params, _, _, _ = the_reference
+    cfg, params, _ = the_model
     lp = jax.tree.map(lambda w: w[0], params["layers"])
     X = jax.random.normal(jax.random.PRNGKey(4), (2, 16, cfg.hc_mult,
                                                   cfg.d_model), F32)
@@ -356,17 +364,21 @@ def test_the_shares_add_up_to_the_uncut_layer():
     lp = jax.tree.map(lambda w: w[0], _params(cfg)["layers"])
     h = jax.random.normal(jax.random.PRNGKey(5), (2, 16, cfg.d_model), F32)
     with jax.default_matmul_precision("highest"):
-        shared, routed = ref.xing4_expert_layer(h, lp, cfg)
+        shared, routed = jax.jit(
+            lambda h, lp: ref.xing4_expert_layer(h, lp, cfg))(h, lp)
         total = 0.0
         for first in (0, 4):
             share = dataclasses.replace(cfg, first_expert=first,
                                         n_experts_held=4)
             held = {k: (w[first:first + 4] if k.startswith("moe_") else w)
                     for k, w in lp.items()}
-            once, part = ref.xing4_expert_layer(h, held, share)
+            once, part = jax.jit(lambda h, lp, share=share:
+                                 ref.xing4_expert_layer(h, lp, share))(
+                h, held)
             np.testing.assert_allclose(once, shared, rtol=1e-6)
             total = total + part
-            got, _ = llama._ffn(h, held, share)
+            got = jax.jit(lambda h, lp, share=share:
+                          llama._ffn(h, lp, share)[0])(h, held)
             np.testing.assert_allclose(got, once + part, rtol=2e-4,
                                        atol=2e-6)
     np.testing.assert_allclose(total, routed, rtol=1e-5, atol=1e-6)
