@@ -59,18 +59,34 @@ operands (``ops/_platform.py``):
   of ONE matrix (256 rows at 16 heads), against the group's keys and
   values, which stay in VMEM whole (``[T, d]`` each: 8.4 MB at T 32768,
   d 128, bf16), so a block is a dynamic slice and no gather from HBM.
-  The tile walks the blocks begun before its last token, two
-  neighbours a visit (128 keys: a score tile's lanes full); its row of
-  the table arrives in SMEM, a pair no token of the tile chose costs two
-  scalar loads and a branch, a visited pair is masked row by row to the
-  tokens that chose each block (a shift of the block's word by the
-  row's token) and to the causal edge. Every row attends its own set and nothing
-  else; what the tile pays for is the UNION of its tokens' sets. The
-  backward is one kernel over the same tiles: ``s``, ``p = exp(s -
-  lse)``, ``dp`` and ``ds`` formed once a visited block, ``dq`` a tile,
-  ``dk`` and ``dv`` accumulated in float32 in VMEM (``[T, d]`` each, a
-  block's slice read and written where its queries find it: no search
-  for the queries that chose a block) and written once a group;
+  A visit is two neighbouring blocks (128 keys: a score tile's lanes
+  full); the tile's row of the table arrives in SMEM. A tile first
+  LISTS its walk: a scalar loop over the visits before its own writes
+  those some token of the tile chose to a list in SMEM with no branch
+  (every visit is stored at the list's end, the end moves on past a
+  chosen one). Then it makes them with none: its OWN visit first (the
+  one that holds the tile's tokens, so the only one with a causal edge;
+  every token chose its own block, so from here on a row's running
+  maximum is a score and a masked score's ``exp`` is 0 by itself), then
+  the listed ones, ``VISITS_A_STEP`` a softmax step of the forward: as
+  many ``q k^T`` products, ONE running-max update (the tiles' maximum
+  element by element, then one reduction along the lanes a row), one
+  rescale of ``l`` and the accumulator, one row sum (the tiles of ``p``
+  added element by element in float32, then one reduction), the ``p v``
+  products added up. Every visited pair is masked row by row to the
+  tokens that chose each block (the block's word against the row's
+  token's bit). The list's tail is padded to a whole step with visits
+  whose words read 0: nobody chose them, ``p`` is 0 there. Every row
+  attends its own set and nothing else; what the tile pays for is the
+  UNION of its tokens' sets. The backward is one kernel over the same
+  tiles and the same list, as many visits a trip of its loop:
+  ``s``, ``p = exp(s - lse)``, ``dp`` and ``ds`` formed once a visited
+  pair, each product written across the trip's visits (the compiler
+  schedules in the program's order: visit after visit ran them one
+  behind another), ``dq`` a tile, ``dk`` and ``dv`` accumulated in
+  float32 in VMEM (``[T, d]`` each, a pair's slice read and written
+  where its queries find it: no search for the queries that chose a
+  block; a padded visit adds zeros) and written once a group;
 - elsewhere: the same softmax under an explicit mask built from the
   sets, a block of query rows at a time, differentiated by autodiff
   (the CPU's path and the tests' reference for the kernels, which run
@@ -107,6 +123,14 @@ TOKENS_A_TILE = 16
 # Neighbouring blocks a visit takes side by side (where their number
 # divides): two blocks of 64 keys fill the 128 lanes.
 BLOCKS_A_VISIT = 2
+# Listed visits a step of a tile's walk takes together: a softmax step of
+# the forward (one running max, one rescale, one pass of row reductions
+# for all of them) and a trip of the backward's loop (each visit whole; a
+# product of one overlaps the mask and ``exp`` of the next). By the chip
+# at T 32768 and 121 visits a tile (PERF.md, PR 60): 4 / 8 a step 82.1 /
+# 77.2 ms a forward (16 no better), 2 / 4 / 8 a step 208.6 / 145.1 / 135.5
+# a backward; a list's padded tail grows with it (2.8% of a walk at 8).
+VISITS_A_STEP = 8
 # Query rows a block of the selection's scores and of the explicit-mask
 # form: [rows, H / G, windows] float32 at a time.
 SELECT_ROWS = 512
@@ -302,81 +326,123 @@ def _masked_attention(q, k, v, table, block):
 # The Pallas TPU kernel pair: a group's keys and values in VMEM.
 # ---------------------------------------------------------------------
 
-def _allowed(words, visit, tile, rows, n, block):
+def _token_bits(rows, n, width):
+    """[rows, width] int32: row ``r`` is head ``r % n`` of the tile's
+    token ``r // n``; that token's bit of a table word."""
+    return lax.shift_left(jnp.int32(1), lax.broadcasted_iota(
+        jnp.int32, (rows, width), 0) // n)
+
+
+def _allowed(words, bit, block, edge=None):
     """[rows, len(words) block] bool for a VISIT, ``len(words)``
     neighbouring blocks side by side along the lanes (two: 128 keys fill
-    a vreg's lanes where one block of 64 fills half): row ``r`` is head
-    ``r % n`` of the tile's token ``r // n``; it sees a key where its
-    token chose the key's block (its bit of that block's word) and the
-    key is not after it."""
-    shape = (rows, len(words) * block)
-    token = lax.broadcasted_iota(jnp.int32, shape, 0) // n
-    col = lax.broadcasted_iota(jnp.int32, shape, 1)
-    word = jnp.full(shape, words[0], jnp.int32)
+    a vreg's lanes where one block of 64 fills half): a row (``bit``:
+    :func:`_token_bits`) sees a key where its token chose the key's block
+    (its bit of that block's word) and, in the tile's own visit, the key
+    is not after it (``edge``: :func:`_not_after`)."""
+    col = lax.broadcasted_iota(jnp.int32, (1, bit.shape[1]), 1)
+    word = jnp.full(col.shape, words[0], jnp.int32)
     for i in range(1, len(words)):
-        word = jnp.where(col >= i * block, jnp.full(shape, words[i],
-                                                    jnp.int32), word)
-    return ((lax.shift_right_logical(word, token) & 1) == 1) \
-        & (visit * shape[1] + col <= tile * TOKENS_A_TILE + token)
+        word = jnp.where(col >= i * block, words[i], word)
+    ok = (word & bit) != 0
+    return ok if edge is None else ok & edge
 
 
-def _visits(tile, width):
-    """Visits (``width`` keys each) begun at or before the tile's last
-    token."""
-    return (tile * TOKENS_A_TILE + TOKENS_A_TILE - 1) // width + 1
+def _not_after(rows, n, width, behind):
+    """[rows, width] bool, the causal edge in a tile's OWN visit, whose
+    first key lies ``behind`` keys behind the tile's first token: the
+    keys at or before a row's token (a listed visit lies wholly before
+    the tile and has no edge)."""
+    shape = (rows, width)
+    return lax.broadcasted_iota(jnp.int32, shape, 1) <= behind \
+        + lax.broadcasted_iota(jnp.int32, shape, 0) // n
 
 
 def _words(table_ref, visit, per):
-    """The table's words of the ``per`` blocks of ``visit``, and whether
-    any token of the tile chose any of them."""
-    words = [table_ref[0, visit * per + i] for i in range(per)]
-    any_ = words[0]
-    for w in words[1:]:
-        any_ = any_ | w
-    return words, any_ != 0
+    """The table's words of the ``per`` blocks of ``visit``."""
+    return [table_ref[0, visit * per + i] for i in range(per)]
 
 
-def _fwd_kernel(table_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *, n,
-                block, per, scale):
+def _list_visits(table_ref, list_ref, own, per):
+    """Write the visits before ``own`` that some token of the tile chose
+    to ``list_ref`` (SMEM), in order and without a branch (every visit
+    is stored at the list's end, and the end moves on past a chosen
+    one), then visits 0 up to a whole step; -> how many are listed."""
+    def note(b, count):
+        words = _words(table_ref, b, per)
+        list_ref[count] = b
+        return count + (functools.reduce(jnp.bitwise_or, words) != 0
+                        ).astype(jnp.int32)
+
+    count = lax.fori_loop(0, own, note, jnp.int32(0))
+    for i in range(VISITS_A_STEP - 1):
+        list_ref[count + i] = 0
+    return count
+
+
+def _listed(table_ref, list_ref, at, count, per):
+    """The list's entry ``at`` -> (the visit, its words); a padded entry
+    (at or past ``count``) reads words of 0: nobody chose it."""
+    visit = list_ref[at]
+    return visit, [jnp.where(at < count, w, 0)
+                   for w in _words(table_ref, visit, per)]
+
+
+def _fwd_kernel(table_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, list_ref,
+                *, n, block, per, scale):
     """One tile: ``q_ref`` [rows, d] (token-major, a token's ``n`` heads
     side by side down the rows), ``k_ref``, ``v_ref`` [T, d], its row of
-    the table in SMEM -> ``o_ref`` [rows, d], ``lse_ref`` [1, rows]."""
+    the table in SMEM -> ``o_ref`` [rows, d], ``lse_ref`` [1, rows];
+    ``list_ref``: the tile's list of visits (SMEM scratch)."""
     tile = pl.program_id(2)
     rows, d = q_ref.shape
     qs = _scaled(q_ref[...], scale)
-
     width = per * block
+    bit = _token_bits(rows, n, width)
+    own = tile * TOKENS_A_TILE // width
+    count = _list_visits(table_ref, list_ref, own, per)
 
-    def visit(b, carry):
-        words, chosen_here = _words(table_ref, b, per)
+    def scores(visit, words, edge=None):
+        at = pl.ds(pl.multiple_of(visit * width, width), width)
+        s = lax.dot_general(qs, k_ref[at, :], (((1,), (1,)), ((), ())),
+                            preferred_element_type=F32)
+        return at, jnp.where(_allowed(words, bit, block, edge), s, _NEG)
 
-        def attend(carry):
-            m, l, acc = carry
-            at = pl.ds(pl.multiple_of(b * width, width), width)
-            ok = _allowed(words, b, tile, rows, n, block)
-            s = jnp.where(ok, lax.dot_general(
-                qs, k_ref[at, :], (((1,), (1,)), ((), ())),
-                preferred_element_type=F32), _NEG)
-            m_new = jnp.maximum(m, jnp.max(s, -1, keepdims=True))
-            p = jnp.where(ok, jnp.exp(s - m_new), 0.0)
-            a = jnp.exp(m - m_new)
-            return (m_new, a * l + jnp.sum(p, -1, keepdims=True),
-                    a * acc + jnp.dot(p.astype(v_ref.dtype), v_ref[at, :],
-                                      preferred_element_type=F32))
+    def weighted(p, at):
+        return jnp.dot(p.astype(v_ref.dtype), v_ref[at, :],
+                       preferred_element_type=F32)
 
-        return lax.cond(chosen_here, attend, lambda c: c, carry)
+    # The tile's own visit first: every row finds its token's own key
+    # there, so ``m`` is a score from here on and a masked score's
+    # ``exp(_NEG - m)`` is 0 with no second select.
+    at, s = scores(own, _words(table_ref, own, per), _not_after(
+        rows, n, width, tile * TOKENS_A_TILE - own * width))
+    m = jnp.max(s, -1, keepdims=True)
+    p = jnp.exp(s - m)
+    carry = m, jnp.sum(p, -1, keepdims=True), weighted(p, at)
 
-    m, l, acc = lax.fori_loop(
-        0, _visits(tile, width), visit,
-        (jnp.full((rows, 1), _NEG, F32), jnp.zeros((rows, 1), F32),
-         jnp.zeros((rows, d), F32)))
+    def step(i, carry):
+        m, l, acc = carry
+        ats, ss = zip(*(
+            scores(*_listed(table_ref, list_ref, i * VISITS_A_STEP + j,
+                            count, per)) for j in range(VISITS_A_STEP)))
+        m_new = jnp.maximum(m, jnp.max(functools.reduce(jnp.maximum, ss),
+                                       -1, keepdims=True))
+        a = jnp.exp(m - m_new)
+        ps = [jnp.exp(s - m_new) for s in ss]
+        return (m_new,
+                a * l + jnp.sum(functools.reduce(jnp.add, ps), -1,
+                                keepdims=True),
+                a * acc + functools.reduce(jnp.add, map(weighted, ps, ats)))
+
+    m, l, acc = lax.fori_loop(0, pl.cdiv(count, VISITS_A_STEP), step, carry)
     o_ref[...] = (acc / l).astype(o_ref.dtype)
     lse_ref[...] = _column_to_row(m + jnp.log(l))
 
 
 def _bwd_kernel(table_ref, q_ref, k_hbm, v_hbm, do_ref, lse_ref, delta_ref,
                 dq_ref, dk_hbm, dv_hbm, k_ref, v_ref, dk_ref, dv_ref, sem,
-                *, n, block, per, scale):
+                list_ref, *, n, block, per, scale):
     """One tile of the backward. The group's keys and values are copied
     into VMEM once a group (``k_ref``, ``v_ref``: scratch, one buffer
     each), their float32 gradients gathered there (``dk_ref``,
@@ -401,33 +467,46 @@ def _bwd_kernel(table_ref, q_ref, k_hbm, v_hbm, do_ref, lse_ref, delta_ref,
     q, do = q_ref[...], do_ref[...]
     qs = _scaled(q, scale)
     lse, delta = _row_to_column(lse_ref[...]), _row_to_column(delta_ref[...])
-
     width = per * block
+    bit = _token_bits(rows, n, width)
+    own = tile * TOKENS_A_TILE // width
+    count = _list_visits(table_ref, list_ref, own, per)
 
-    def visit(b, dq):
-        words, chosen_here = _words(table_ref, b, per)
-
-        def attend(dq):
-            at = pl.ds(pl.multiple_of(b * width, width), width)
-            kb, vb = k_ref[at, :], v_ref[at, :]
-            ok = _allowed(words, b, tile, rows, n, block)
-            s = lax.dot_general(qs, kb, (((1,), (1,)), ((), ())),
-                                preferred_element_type=F32)
-            p = jnp.where(ok, jnp.exp(s - lse), 0.0)
-            dp = lax.dot_general(do, vb, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=F32)
-            ds = (p * (dp - delta)).astype(q.dtype)
-            across = (((0,), (0,)), ((), ()))           # over the rows
+    def attend(visits, edge=None):
+        """The part of ``dq`` of ``visits`` [(visit, words), ...], a
+        product after another ACROSS them (the scheduler follows the
+        program's order: visit by visit it ran them one behind another);
+        their ``dk``, ``dv`` added in place (a padded visit adds zeros)."""
+        ats = [pl.ds(pl.multiple_of(b * width, width), width)
+               for b, _ in visits]
+        ss = [lax.dot_general(qs, k_ref[at, :], (((1,), (1,)), ((), ())),
+                              preferred_element_type=F32) for at in ats]
+        dps = [lax.dot_general(do, v_ref[at, :], (((1,), (1,)), ((), ())),
+                               preferred_element_type=F32) for at in ats]
+        ps = [jnp.where(_allowed(words, bit, block, edge),
+                        jnp.exp(s - lse), 0.0)
+              for s, (_, words) in zip(ss, visits)]
+        dss = [(p * (dp - delta)).astype(q.dtype) for p, dp in zip(ps, dps)]
+        across = (((0,), (0,)), ((), ()))               # over the rows
+        for at, p in zip(ats, ps):
             dv_ref[at, :] += lax.dot_general(
                 p.astype(do.dtype), do, across, preferred_element_type=F32)
+        for at, ds in zip(ats, dss):
             dk_ref[at, :] += lax.dot_general(
                 ds, qs, across, preferred_element_type=F32)
-            return dq + jnp.dot(ds, kb, preferred_element_type=F32)
+        return functools.reduce(jnp.add, [
+            jnp.dot(ds, k_ref[at, :], preferred_element_type=F32)
+            for at, ds in zip(ats, dss)])
 
-        return lax.cond(chosen_here, attend, lambda dq: dq, dq)
+    def trip(i, dq):
+        return dq + attend([
+            _listed(table_ref, list_ref, i * VISITS_A_STEP + j, count, per)
+            for j in range(VISITS_A_STEP)])
 
-    dq = lax.fori_loop(0, _visits(tile, width), visit,
-                       jnp.zeros((rows, d), F32))
+    dq = lax.fori_loop(
+        0, pl.cdiv(count, VISITS_A_STEP), trip,
+        attend([(own, _words(table_ref, own, per))], _not_after(
+            rows, n, width, tile * TOKENS_A_TILE - own * width)))
     dq_ref[...] = (dq * scale).astype(dq_ref.dtype)
 
     @pl.when(tile == pl.num_programs(2) - 1)
@@ -440,9 +519,21 @@ def _bwd_kernel(table_ref, q_ref, k_hbm, v_hbm, do_ref, lse_ref, delta_ref,
             c.wait()
 
 
-def _blocks_a_visit(n_blocks):
-    """Neighbouring blocks a step of a tile's walk takes together."""
-    return BLOCKS_A_VISIT if n_blocks % BLOCKS_A_VISIT == 0 else 1
+def _blocks_a_visit(n_blocks, block):
+    """Neighbouring blocks a visit takes together. A tile's tokens have
+    to lie in ONE visit, its own (the only one with a causal edge)."""
+    per = BLOCKS_A_VISIT if n_blocks % BLOCKS_A_VISIT == 0 else 1
+    if per * block % TOKENS_A_TILE:
+        raise ValueError(
+            f"visits of {per * block} keys under tiles of {TOKENS_A_TILE} "
+            "tokens: a tile has to lie in one visit")
+    return per
+
+
+def _list_scratch(n_blocks, per):
+    """A tile's list of visits: at most every visit before its own, and
+    the padding to a whole step."""
+    return pltpu.SMEM((n_blocks // per + VISITS_A_STEP,), jnp.int32)
 
 
 def _params():
@@ -473,15 +564,17 @@ def _kernel_fwd(table, q, k, v, *, n, block, interpret):
     T, d = k.shape[2:]
     rows = TOKENS_A_TILE * n
     word, tile, stat = _tile_specs(rows, d, nb)
+    per = _blocks_a_visit(nb, block)
     whole = pl.BlockSpec((None, None, T, d), lambda b, g, i: (b, g, 0, 0))
     with scope("hvd.sparse.core"):
         return pl.pallas_call(
-            functools.partial(_fwd_kernel, n=n, block=block,
-                              per=_blocks_a_visit(nb), scale=d ** -0.5),
+            functools.partial(_fwd_kernel, n=n, block=block, per=per,
+                              scale=d ** -0.5),
             grid=(B, G, tiles), in_specs=[word, tile, whole, whole],
             out_specs=[tile, stat],
             out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
                        jax.ShapeDtypeStruct((B, G, tiles, 1, rows), F32)],
+            scratch_shapes=[_list_scratch(nb, per)],
             interpret=interpret,
             metadata={"kernel": "hvd_sparse_attn_fwd"},
             compiler_params=_params(),
@@ -495,14 +588,15 @@ def _kernel_bwd(table, q, k, v, o, lse, do, *, n, block, interpret):
     T, d = k.shape[2:]
     rows = TOKENS_A_TILE * n
     word, tile, stat = _tile_specs(rows, d, nb)
+    per = _blocks_a_visit(nb, block)
     with scope("hvd.sparse.core"):
         delta = jnp.sum(do.astype(F32) * o.astype(F32), -1).reshape(
             lse.shape)
         hbm = pl.BlockSpec(memory_space=pl.ANY)
         kv32 = jax.ShapeDtypeStruct(k.shape, F32)
         return pl.pallas_call(
-            functools.partial(_bwd_kernel, n=n, block=block,
-                              per=_blocks_a_visit(nb), scale=d ** -0.5),
+            functools.partial(_bwd_kernel, n=n, block=block, per=per,
+                              scale=d ** -0.5),
             grid=(B, G, tiles),
             in_specs=[word, tile, hbm, hbm, tile, stat, stat],
             out_specs=[tile, hbm, hbm],
@@ -511,7 +605,8 @@ def _kernel_bwd(table, q, k, v, o, lse, do, *, n, block, interpret):
                             pltpu.VMEM((T, d), v.dtype),
                             pltpu.VMEM((T, d), F32),
                             pltpu.VMEM((T, d), F32),
-                            pltpu.SemaphoreType.DMA((2,))],
+                            pltpu.SemaphoreType.DMA((2,)),
+                            _list_scratch(nb, per)],
             interpret=interpret,
             metadata={"kernel": "hvd_sparse_attn_bwd"},
             compiler_params=_params(),

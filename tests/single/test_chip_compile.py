@@ -12,6 +12,7 @@
 4. Peak tables know this chip and refuse what they do not know.
 """
 
+import base64
 import functools
 import math
 import os
@@ -637,12 +638,23 @@ def test_sparse_attention_compiles_for_described_v5e(for_tpu):
     takes it: a group's keys and values whole in VMEM (the backward their
     float32 gradients too), the table's row in SMEM, each kernel by the
     name a device trace shows; no [T, T] plane and no dense mask exists;
-    and the selection beside it compiles with no kernel at all and no
-    sort (its 64 of 512 blocks are a threshold found by counting)."""
+    the forward walks its list with loops alone (no conditional is left
+    in its body; the backward's two are its group's first and last
+    tile); and the selection beside it compiles with no kernel at all
+    and no sort (its 64 of 512 blocks are a threshold found by
+    counting)."""
     text = for_tpu(_sparse_fwd_bwd, *_SPARSE)
     for name in _SPARSE_KERNELS:
         assert f'"kernel":"{name}"' in text, name
     assert text.count("custom_call_target=\"tpu_custom_call\"") == 2
+    # a kernel's body travels as MLIR bytecode, its operations' names in
+    # the clear
+    ops = {name: base64.b64decode(re.search(
+        r'"body":"([^"]+)"', text[text.index(f'"kernel":"{name}"'):])[1])
+        for name in _SPARSE_KERNELS}
+    assert all(b"scf.for" in body for body in ops.values())
+    assert b"scf.if" not in ops["hvd_sparse_attn_fwd"]
+    assert b"scf.if" in ops["hvd_sparse_attn_bwd"]
     assert not re.search(r"\[(1,)?(2,|32,)?32768,(2,|16,|32,)*32768\]", text)
     # the row statistics cross HBM lane-dense, a tile's a row
     assert "f32[1,2,2048,1,256]" in text

@@ -72,20 +72,118 @@ def test_the_kernel_pair_is_the_masked_softmax(kernels, dtype, tol):
         assert err < tol, (name, err)
 
 
-def test_a_row_attends_its_own_set_and_nothing_else(kernels):
-    """Two tokens of one tile with different sets: a value planted in a
-    block only the second chose reaches the second's rows alone."""
-    t, block = 64, 16
-    q, k, v, _ = _operands(t, F32, h=2, g=1)
-    sel = np.zeros((1, t, 1, t // block), bool)
-    sel[0, :, 0, 0] = True
+def _spread(t, block, visits_of):
+    """bool [1, t, 1, t / block]: every token its own block; a tile whose
+    own visit is ``own`` (``per`` blocks a visit) also the visits
+    ``visits_of(own)`` [0 .. own), visit ``i`` of them by the tile's token
+    ``i % 16`` ALONE, in its first block where ``i`` is even and its last
+    where odd: what a softmax step takes side by side was chosen by
+    different tokens, and a block beside a chosen one by nobody."""
+    nb = t // block
+    per = sa.BLOCKS_A_VISIT if nb % sa.BLOCKS_A_VISIT == 0 else 1
+    sel = np.zeros((1, t, 1, nb), bool)
     sel[0, np.arange(t), 0, np.arange(t) // block] = True
-    sel[0, 49, 0, 1] = True                      # token 49 alone: block 1
-    v = jnp.zeros_like(v).at[0, 16:32].set(1.0)   # block 1's values
-    o = sa.sparse_attention(q, k, v, sa._pack(jnp.asarray(sel)), block)
-    reached = np.asarray(jnp.abs(o).sum((0, 2, 3)) > 0)
-    assert reached[49] and reached[16:32].all()
-    assert not reached[32:49].any() and not reached[50:].any()
+    for tile in range(t // sa.TOKENS_A_TILE):
+        first = tile * sa.TOKENS_A_TILE
+        for i, visit in enumerate(visits_of(first // (per * block))):
+            sel[0, first + i % sa.TOKENS_A_TILE, 0,
+                visit * per + (i % 2) * (per - 1)] = True
+    return sel
+
+
+@pytest.mark.parametrize("t, visits_of", [
+    (64, None), (320, lambda own: range(own))],
+    ids=["two_tokens_of_a_tile", "a_step_of_pairs_chosen_by_different_tokens"])
+def test_a_row_attends_its_own_set_and_nothing_else(kernels, t, visits_of):
+    """Every block's values point their own way, so a row's output says
+    which blocks reached it: its token's set, each block with a key at
+    or before the token, and no other. Two tokens of one tile with
+    different sets; and twenty tiles whose listed visits were each chosen
+    by another token of the tile, several of them a softmax step."""
+    block = 16
+    q, k, _, _ = _operands(t, F32, h=2, g=1)
+    if visits_of is None:
+        sel = np.zeros((1, t, 1, t // block), bool)
+        sel[0, :, 0, 0] = True
+        sel[0, np.arange(t), 0, np.arange(t) // block] = True
+        sel[0, 49, 0, 1] = True                  # token 49 alone: block 1
+    else:
+        sel = _spread(t, block, visits_of)
+    nb = t // block
+    v = jnp.repeat(jnp.eye(nb, 32, dtype=F32), block, 0)[None, :, None, :]
+    o = jax.jit(lambda q, k, v, table: sa.sparse_attention(
+        q, k, v, table, block))(q, k, v, sa._pack(jnp.asarray(sel)))
+    reached = np.asarray(o)[0, :, :, :nb] > 0              # [t, h, nb]
+    assert (reached == sel[0, :, 0, None, :]).all(), np.argwhere(
+        reached != sel[0, :, 0, None, :])[:8]
+
+
+# What the walk's list can get wrong: a tile (the last ones of the
+# sequence have the most visits before their own) lists no visit, one
+# fewer than a step of the walk takes, as many, one more, or every visit.
+_LISTS = {
+    "empty_but_for_its_own_pair": lambda own: range(0),
+    "a_visit_short_of_a_step": lambda own: range(
+        min(own, sa.VISITS_A_STEP - 1)),
+    "a_whole_step": lambda own: range(min(own, sa.VISITS_A_STEP)),
+    "a_visit_over_a_step": lambda own: range(
+        own - min(own, sa.VISITS_A_STEP + 1), own),
+    "every_begun_pair": lambda own: range(own),
+}
+
+
+@pytest.fixture(scope="module")
+def pair_and_mask():
+    """(the pair's readings, the masked form's) with the table an
+    ARGUMENT: the cases of one length share a compiled program."""
+    def readings(attend):
+        def loss(q, k, v, w, table):
+            o = attend(q, k, v, table, 16).astype(F32)
+            return jnp.sum(o * w), o
+
+        def run(q, k, v, w, table):
+            grads, o = jax.grad(loss, argnums=(0, 1, 2), has_aux=True)(
+                q, k, v, w, table)
+            return (o,) + grads
+        return jax.jit(run)
+    return readings(sa.sparse_attention), readings(sa._masked_attention)
+
+
+@pytest.mark.parametrize("case", sorted(_LISTS))
+@pytest.mark.parametrize("blocks_a_visit", [2, 1])
+def test_the_walk_lists_what_a_tile_chose(kernels, pair_and_mask,
+                                          blocks_a_visit, case):
+    """The pair against the masked form, forward and the three gradients
+    in float32 (the order of float32 additions: 2e-5 of the largest
+    entry), on tables that fix the LENGTH of a tile's list: none but its
+    own pair, a step's worth less one, a step's worth, one more, every
+    begun pair; two blocks a visit, and one where their number is odd
+    (the padded tail of a list is then made of visits nobody chose)."""
+    visits = sa.VISITS_A_STEP + 2
+    t = visits * blocks_a_visit * 16 if blocks_a_visit == 2 \
+        else (visits + visits % 2 + 1) * 16
+    assert (t // 16) % 2 == blocks_a_visit % 2
+    q, k, v, w = _operands(t, F32, h=2, g=1, seed=3)
+    if case == "every_begun_pair":       # by every token: plain causal
+        sel = np.tril(np.ones((t // 16, t // 16), bool)).repeat(16, 0)[
+            None, :, None, :]
+    else:
+        sel = _spread(t, 16, _LISTS[case])
+    table = sa._pack(jnp.asarray(sel))
+    pair, mask = pair_and_mask
+    with jax.default_matmul_precision("highest"):
+        got, want = pair(q, k, v, w, table), mask(q, k, v, w, table)
+    for name, a, b in zip(("o", "dq", "dk", "dv"), got, want):
+        err = float(jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b)))
+        assert err < 2e-5, (name, err)
+
+
+def test_a_tile_lies_in_one_visit(kernels):
+    """The own visit is the one with a causal edge: blocks narrower than
+    a tile's tokens are refused by name."""
+    q, k, v, _ = _operands(64, F32, h=2, g=1)
+    with pytest.raises(ValueError, match="a tile has to lie in one visit"):
+        sa.sparse_attention(q, k, v, jnp.zeros((1, 1, 4, 16), jnp.int32), 4)
 
 
 def test_the_selection_by_hand():
