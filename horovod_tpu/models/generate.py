@@ -66,6 +66,21 @@ def require_decodable(c):
             f"LlamaConfig fields {fields}: hyper-connections are training "
             "only. Prefill and cached decode carry ONE residual stream "
             "[B, T, D] from layer to layer, not hc_mult of them")
+    if c.post_norm == "only":
+        raise ValueError(
+            f"LlamaConfig fields {fields}: output-only norms are training "
+            "only. Prefill and the cached step norm each part's INPUT "
+            "with attn_norm and mlp_norm, leaves this tree does not "
+            "have, and pass no part's output through post_attn_norm or "
+            "post_mlp_norm")
+    if c.linear_beta_max != 1.0:
+        raise ValueError(
+            f"LlamaConfig fields {fields}: a linear_attention layer's "
+            "write strength is training only. Decode lacks the layer "
+            "itself: a cache of its recurrent state (a [key_dim, "
+            "value_dim] matrix a head beside the KV cache's keys and "
+            "values, and the last conv_taps - 1 projected tokens) and "
+            "the delta rule's one-token step that would read beta")
     if fields:
         raise ValueError(
             f"LlamaConfig fields {fields} are training only "

@@ -206,10 +206,16 @@ class LlamaConfig:
     scale_embed: bool = False
     # Attention's output is gated before ``wo``: ``a * sigmoid(h @ wg)``.
     attn_gate: bool = False
-    # Four norms a layer: the attention's and the FFN's OUTPUT pass an
-    # RMSNorm of their own (``post_attn_norm``, ``post_mlp_norm``)
-    # before joining the residual stream.
-    post_norm: bool = False
+    # Where a layer's norms sit. False: on each part's INPUT
+    # (``attn_norm`` or the mixer's own, ``mlp_norm``). True: four
+    # norms a layer, the attention's and the FFN's OUTPUT pass an
+    # RMSNorm of their own besides (``post_attn_norm``,
+    # ``post_mlp_norm``) before joining the residual stream. ``"only"``:
+    # the output norms ALONE, ``x + norm(part(x))`` (OLMo 2's reordered
+    # norm, arXiv:2501.00656): the tree has no ``attn_norm``,
+    # ``gdn_norm`` or ``mlp_norm`` leaf; attention and
+    # ``linear_attention`` mixers.
+    post_norm: "bool | str" = False
     # The share of an expert-parallel deployment this program holds:
     # experts ``first_expert .. first_expert + n_experts_held - 1`` of
     # each expert layer (0 held = all). The router scores and chooses
@@ -230,6 +236,12 @@ class LlamaConfig:
     linear_value_heads: int = 0
     linear_key_dim: int = 0
     linear_value_dim: int = 0
+    # The largest write strength of a ``linear_attention`` layer's delta
+    # rule: ``beta = linear_beta_max * sigmoid(b)``. 1: qwen3_next's; 2
+    # (``linear_allow_neg_eigval``): with unit keys the write's
+    # transition ``I - beta k k^T`` then has its eigenvalue along ``k``
+    # in (-1, 1) instead of (0, 1) (arXiv:2411.12537).
+    linear_beta_max: float = 1.0
     # RoPE turns the FIRST ``partial_rotary`` dimensions of a head and
     # passes the rest (``partial_rotary_factor`` x ``head_dim``; 0 = the
     # whole head).
@@ -501,6 +513,28 @@ class LlamaConfig:
                 f"{self.linear_value_heads} value heads are no multiple "
                 f"of {self.linear_key_heads} key heads: each key head "
                 "serves a whole number of value heads")
+        if self.linear_beta_max != 1.0 and not (
+                linear and 0.0 < self.linear_beta_max <= 2.0):
+            raise ValueError(
+                f"linear_beta_max {self.linear_beta_max}: the largest "
+                "write strength of a linear_attention layer's delta rule "
+                "(there is none without such a layer), in (0, 2]: past 2 "
+                "a write's transition I - beta k k^T grows along k")
+        if self.post_norm not in (False, True, "only"):
+            raise ValueError(f"unknown post_norm {self.post_norm!r}")
+        if self.post_norm == "only" and (
+                self.kv_lora_rank or self.hc_mult or self.one_part_layers
+                or any(t not in ("full_attention", "sliding_attention",
+                                 "linear_attention") for t in types)):
+            raise ValueError(
+                "post_norm 'only' norms the OUTPUT of an attention or "
+                "linear_attention mixer and of the FFN: latent attention "
+                "norms its latents behind an input norm, the other "
+                "mixers (conv, mamba, mamba2, sparse, lightning) norm "
+                "their input inside their own programs, a hyper-"
+                "connection part reads a blend whose scale no output "
+                "norm sets, and a layer of one part has one norm, its "
+                f"input's: {types or 'full_attention'}")
         if self.partial_rotary % 2 or self.partial_rotary > self.head_dim:
             raise ValueError(
                 f"partial_rotary {self.partial_rotary}: RoPE turns pairs "
@@ -699,7 +733,8 @@ class LlamaConfig:
                             "rope_full_attention", "conv_taps",
                             "tie_embeddings", "linear_key_heads",
                             "linear_value_heads", "linear_key_dim",
-                            "linear_value_dim", "partial_rotary",
+                            "linear_value_dim", "linear_beta_max",
+                            "partial_rotary",
                             "shared_expert_gate", "mamba_d_state",
                             "mamba_dt_rank", "mamba_expand",
                             "mamba_conv_bias", "loss_chunk",
@@ -939,6 +974,9 @@ def llama_init(config, key):
         if c.post_norm:
             layers["post_attn_norm"] = jnp.ones((L, c.d_model), pd)
             layers["post_mlp_norm"] = jnp.ones((L, c.d_model), pd)
+        if c.post_norm == "only":     # no part's input is normed
+            for name in ("attn_norm", "gdn_norm", "mlp_norm"):
+                layers.pop(name, None)
         if c.hc_mult:
             # A part's three sets of coefficients side by side, [pre |
             # post | res]: the projections ``phi`` a stream, and in
@@ -1138,6 +1176,14 @@ def _rms(x, scale, eps):
 
 
 _rmsnorm = scope("hvd.norm")(_rms)
+
+
+def _input_norm(x, lp, name, c):
+    """A part's input: the stream under the part's norm ``lp[name]``,
+    or (``post_norm`` "only": no such leaf) as it stands."""
+    if c.post_norm == "only":
+        return x
+    return _rmsnorm(x, lp[name].astype(c.compute_dtype), c.norm_eps)
 
 
 @scope("hvd.attn.rope")
@@ -1544,8 +1590,11 @@ def _gated_delta_net(x, lp, c, mesh, seq_axis, stage=lambda f: f):
     checkpoint of its own, so that the first's residuals and the
     rule's are never alive together). The chain round the rule runs as
     ``ops/gdn_chain.py``'s kernel pairs where the operands live on a TPU
-    (and keys and values are one size), as ``_gdn_chain_in`` /
-    ``_gdn_chain_out`` elsewhere."""
+    (keys and values of one width, or of two whose columns fall into
+    steps of whole lane groups: ``gdn_chain.on_kernels``), as
+    ``_gdn_chain_in`` / ``_gdn_chain_out`` elsewhere. ``post_norm``
+    "only" leaves the layer's norm out (the stream enters as it is);
+    ``linear_beta_max`` scales ``beta``."""
     from horovod_tpu.ops import gdn_chain
     from horovod_tpu.ops.gated_delta_rule import gated_delta_rule
 
@@ -1561,24 +1610,29 @@ def _gated_delta_net(x, lp, c, mesh, seq_axis, stage=lambda f: f):
     b, t, _ = x.shape
     hk, hv = c.linear_key_heads, c.linear_value_heads
     dk, dv = c.linear_key_dim, c.linear_value_dim
-    kernels = gdn_chain.on_kernels(x, dk, dv)
+    kernels = gdn_chain.on_kernels(x, dk, dv, hk, hv)
 
     def before(x, lp):
-        h = _rmsnorm(x, lp["gdn_norm"].astype(dt), c.norm_eps)
+        h = _input_norm(x, lp, "gdn_norm", c)
         with scope("hvd.gdn.proj"):
             qkvz = h @ lp["gdn_in"].astype(dt)
             ba = h @ lp["gdn_ba"].astype(dt)
         with scope("hvd.gdn.chain"):
             if kernels:     # heads side by side, z too: chain_out's
-                q, k, v, z = gdn_chain.chain_in(qkvz, lp["gdn_conv"], hk, hv)
-                q, k, v = (a.reshape(b, t, -1, dk) for a in (q, k, v))
+                q, k, v, z = gdn_chain.chain_in(qkvz, lp["gdn_conv"], hk, hv,
+                                                dk, dv)
+                q, k = (a.reshape(b, t, hk, dk) for a in (q, k))
+                v = v.reshape(b, t, hv, dv)
             else:
                 q, k, v, z = _gdn_chain_in(qkvz, lp["gdn_conv"], hk, hv,
                                            dk, dv)
             bb, aa = jnp.split(ba.astype(f32), 2, axis=-1)
             g = -jnp.exp(lp["gdn_a_log"].astype(f32)) * jax.nn.softplus(
                 aa + lp["gdn_dt_bias"].astype(f32))
-            return q, k, v, z, g, jax.nn.sigmoid(bb)
+            beta = jax.nn.sigmoid(bb)
+            if c.linear_beta_max != 1.0:
+                beta = c.linear_beta_max * beta
+            return q, k, v, z, g, beta
 
     def rule_and_after(q, k, v, z, g, beta, lp):
         with scope("hvd.gdn.chain"):
@@ -2401,6 +2455,15 @@ def _validate_pipeline(c, b, mesh, seq_axis, n_stages):
             "has one term, and what crosses a stage boundary is ONE "
             "stream [B, T, D] (hyper-connections carry hc_mult; latent "
             "attention's leaves have no stage layout)")
+    if c.post_norm == "only" or c.linear_beta_max != 1.0:
+        raise ValueError(
+            "post_norm 'only' and linear_beta_max have no pipeline "
+            "schedule yet: a stage's partition of params['layers'] and "
+            "its schedules were written and are tested for layers that "
+            "norm each part's INPUT (attn_norm and mlp_norm a stage's "
+            "leaves; output-only norms have neither), and no stage "
+            "holds the linear_attention mixer whose write strength "
+            "linear_beta_max scales")
     if len({spec.kind for spec in plan}) > 1 \
             or plan[0].mixer != "attention" or c.tie_embeddings \
             or c.partial_rotary or c.shared_expert_gate:
@@ -2487,7 +2550,7 @@ def _build_layer_body(c, mesh, seq_axis, constrain_acts=True, kind=None):
         # layer sees microbatches smaller than the full batch.
         bb, tt = x.shape[0], x.shape[1]
         positions = jnp.broadcast_to(jnp.arange(tt), (bb, tt))
-        h = _rmsnorm(x, lp["attn_norm"].astype(dt), c.norm_eps)
+        h = _input_norm(x, lp, "attn_norm", c)
         if c.kv_lora_rank:    # its rotated slice is its own, whatever rope
             return _latent_attention(h, lp, c, positions, mesh, seq_axis)
         # One pass on the chip (ops/qk_prep.py), the expressions
@@ -2538,7 +2601,7 @@ def _build_layer_body(c, mesh, seq_axis, constrain_acts=True, kind=None):
                                  c.norm_eps)
             x = x + joins(mixed)
 
-        h = _rmsnorm(x, lp["mlp_norm"].astype(dt), c.norm_eps)
+        h = _input_norm(x, lp, "mlp_norm", c)
         ff, aux = _ffn(h, lp, c, mesh)
         if c.post_norm:
             ff = _rmsnorm(ff, lp["post_mlp_norm"].astype(dt), c.norm_eps)

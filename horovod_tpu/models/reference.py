@@ -515,9 +515,11 @@ def qwen3next_delta_rule(q, k, v, g, beta):
     return jnp.moveaxis(o, 0, 1)
 
 
-def qwen3next_gated_delta_net(h, lp, cfg):
+def qwen3next_gated_delta_net(h, lp, cfg, beta_max=1.0):
     """The ``linear_attention`` mixer on normalized ``h`` [B, T, D] with
-    one layer's float32 parameters: steps 1-6 above."""
+    one layer's float32 parameters: steps 1-6 above. ``beta_max``: step
+    3's write strength is ``beta_max * sigmoid(b)`` (Olmo-Hybrid's 2;
+    1 is the published Qwen3-Next)."""
     b, t, _ = h.shape
     hk, hv = cfg.linear_key_heads, cfg.linear_value_heads
     dk, dv = cfg.linear_key_dim, cfg.linear_value_dim
@@ -538,6 +540,8 @@ def qwen3next_gated_delta_net(h, lp, cfg):
     k = unit(u[..., kw:2 * kw].reshape(b, t, hk, dk))
     v = u[..., 2 * kw:].reshape(b, t, hv, dv)
     beta = jax.nn.sigmoid(ba[..., :hv])
+    if beta_max != 1.0:
+        beta = beta_max * beta
     g = -jnp.exp(lp["gdn_a_log"]) * jax.nn.softplus(
         ba[..., hv:] + lp["gdn_dt_bias"])
     o = qwen3next_delta_rule(jnp.repeat(q, hv // hk, 2),
@@ -1462,3 +1466,91 @@ def xing4_loss(params, batch, cfg, vocab_rows=None, terms=False):
     main = ce(logits, targets, mask)
     mtp = ce(logits2, _token_after(targets), mask * has_next)
     return (main, mtp) if terms else main + cfg.mtp_weight * mtp
+
+
+# ---------------------------------------------------------------------
+# Olmo-Hybrid-7B (Allen AI, ``model_type`` ``olmo_hybrid``), from its
+# published ``config.json`` and the Olmo family's published block. No
+# bias anywhere, eps ``rms_norm_eps``. A layer norms each part's OUTPUT
+# and nothing else (OLMo 2's reordered norm, arXiv:2501.00656 section
+# 3): ``x = x + RMS(Mixer(x))``, then ``x = x + RMS(SwiGLU(x))``, plain
+# gains (the program's leaves ``post_attn_norm``, ``post_mlp_norm``; no
+# ``attn_norm``, ``gdn_norm`` or ``mlp_norm`` exists). Every layer's FFN
+# is a dense SwiGLU.
+#
+# - a ``linear_attention`` layer's mixer, Gated DeltaNet as
+#   flash-linear-attention's ``GatedDeltaNet`` and Qwen3-Next's steps
+#   1-6 above have it (``Hk`` = ``Hv`` = 30 heads, ``dk`` 96, ``dv``
+#   192: keys and values of TWO widths), with ONE line changed
+#   (``linear_allow_neg_eigval``, arXiv:2411.12537): step 3's write
+#   strength is ``beta = 2 sigmoid(b)``, so that with unit keys the
+#   write's transition ``I - beta k k^T`` has its eigenvalue along ``k``
+#   in (-1, 1);
+# - a ``full_attention`` layer's: ``q = RMS(h W_q)``, ``k = RMS(h
+#   W_k)``, each over its WHOLE projected width before the split into
+#   heads (the family's QK-norm), ``v = h W_v``; NO position encoding
+#   (``rope_theta`` null: the recurrences order the tokens); causal
+#   ``softmax(q k / sqrt(head_dim)) v``, as many key/value heads as
+#   query heads; ``a W_o``;
+# - ``logits = RMS_final(x) W_head``, untied; loss = mean token
+#   cross-entropy.
+#
+# The share: as for afmoe above (``vocab_rows``). What the published
+# ``config.json`` has no key for (where the norms sit, the q/k norm, the
+# mixer's internals) is listed with its source under ``assumed`` in
+# ``chipbench/configs/olmo-hybrid-7b.json``. The recurrence is a
+# ``lax.scan`` over TOKENS exactly as written
+# (:func:`qwen3next_delta_rule`, which takes any ``dk``, ``dv`` and
+# ``beta``): no chunks, no WY form, nothing of
+# ops/gated_delta_rule.py or ops/gdn_chain.py.
+# ---------------------------------------------------------------------
+
+def olmohybrid_gated_delta_net(h, lp, cfg):
+    """The ``linear_attention`` mixer on the stream ``h`` [B, T, D] (no
+    norm before it) with one layer's float32 parameters: Qwen3-Next's
+    steps 1-6 with ``beta = cfg.linear_beta_max * sigmoid(b)``."""
+    return qwen3next_gated_delta_net(h, lp, cfg, cfg.linear_beta_max)
+
+
+def olmohybrid_forward(params, tokens, cfg):
+    """tokens [B, T] -> logits [B, T, vocab] f32 (see the description
+    above). ``params`` is the program's tree, any storage dtype."""
+    hd, rep = cfg.head_dim, cfg.n_heads // cfg.n_kv_heads
+    b, t = tokens.shape
+    mask = jnp.arange(t)[None, :] <= jnp.arange(t)[:, None]
+    with jax.default_matmul_precision("highest"):
+        x = params["embed"].astype(F32)[tokens]
+        for l in range(cfg.n_layers):
+            lp = _qwen3next_layer(params, cfg, l)
+            if cfg.layer_types[l] == "linear_attention":
+                mixed = olmohybrid_gated_delta_net(x, lp, cfg)
+            else:
+                q = _rms(x @ lp["wq"], lp["q_norm"], cfg.norm_eps)
+                k = _rms(x @ lp["wk"], lp["k_norm"], cfg.norm_eps)
+                q = q.reshape(b, t, cfg.n_heads, hd)
+                k = jnp.repeat(k.reshape(b, t, cfg.n_kv_heads, hd), rep, 2)
+                v = jnp.repeat((x @ lp["wv"]).reshape(
+                    b, t, cfg.n_kv_heads, hd), rep, 2)
+                s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / (hd ** 0.5)
+                p = jax.nn.softmax(jnp.where(mask, s, -jnp.inf), -1)
+                mixed = jnp.einsum("bhqk,bkhd->bqhd", p, v).reshape(
+                    b, t, -1) @ lp["wo"]
+            x = x + _rms(mixed, lp["post_attn_norm"], cfg.norm_eps)
+            x = x + _rms(_swiglu(x, lp["w_gate"], lp["w_up"],
+                                 lp["w_down"]),
+                         lp["post_mlp_norm"], cfg.norm_eps)
+        x = _rms(x, params["final_norm"].astype(F32), cfg.norm_eps)
+        return x @ params["lm_head"].astype(F32)
+
+
+def olmohybrid_loss(params, batch, cfg, vocab_rows=None):
+    """Mean token cross-entropy over the positions ``batch["mask"]``
+    keeps (all without one); no aux term. ``vocab_rows``: as
+    :func:`qwen3next_loss`. ``jax.grad`` of this is the reference
+    gradient."""
+    logits = olmohybrid_forward(params, batch["tokens"], cfg)
+    logp = jax.nn.log_softmax(logits[..., :vocab_rows], -1)
+    nll = -jnp.take_along_axis(logp, batch["targets"][..., None],
+                               -1)[..., 0]
+    mask = batch.get("mask", jnp.ones_like(nll))
+    return jnp.sum(nll * mask) / jnp.sum(mask)

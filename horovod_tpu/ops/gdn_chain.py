@@ -48,6 +48,38 @@ reduction a row.
   the backward accumulates the gain's gradient over the (then
   sequential) token axis, summed over batch and heads outside.
 
+Keys and values of TWO widths, neither a lane tile (30 heads of 96 and
+of 192: three quarters and one and a half tiles; :func:`on_kernels` says
+where): the same four calls under the same names, on ONE STRIP. ``q``
+ends at column 2880 = 22.5 lane tiles, so ``k`` starts in the middle of
+one and neither ``q``'s columns nor ``k``'s fall into blocks of whole
+tiles; ``[q | k]`` TOGETHER do (60 key heads = 45 tiles), four key heads
+or two value heads to a LANE GROUP of three tiles (:func:`_group`). So
+stage one walks ``qkvz``'s convolved columns as they lie, ``[q | k]`` as
+one run of key heads and ``v`` behind it, in column blocks of whole lane
+groups (``LANES_A_STEP``: 1152 lanes, 12 key or 6 value heads), and
+writes ONE strip ``[q | k | v]``: grid ``(B, columns / width, T / bt)``
+forward, the same plus ``z``'s blocks backward, one cotangent strip
+``[dq | dk | dv]`` in. Where ``q`` ends inside a block (column 2880 is
+the middle of block 2) a lane knows its scale by its column
+(:func:`_strip_scale`). A head's norm is a reduction over lanes that
+start and end inside a tile: :func:`_head_sums` sums each head of a lane
+group under its mask and lays the sum back under it, so no value is cut
+or shifted off a tile's edge. The caller cuts the strip into ``q``,
+``k``, ``v`` in front of the rule's own chunking copy and lays the three
+cotangents side by side behind the copy that un-chunks them. (XLA does
+NOT fuse the cuts into those copies: of the scope's 57.0 ms a step in
+the cell, the four kernels are 30.8; the reshapes between ``[B, T, H
+d]`` and ``[B, T, H, d]``, a relayout where ``d`` is no lane tile,
+11.9; the cuts 7.2; the ``concatenate`` 3.5: PERF.md section 7, what a
+rule that reads the strip by an index map would remove.) Why not the
+other two ways:
+columns padded to whole tiles a head (96 -> 128, 192 -> 256) cost a
+third more bytes through HBM in every pass and a repacking copy besides,
+for kernels that wait for HBM already; a layout with the heads on the
+sublanes is the reshape of the whole array that this module exists to
+avoid. Stage two's steps take six value heads (nine tiles).
+
 The names are the calls' ``kernel_metadata``, what a device trace shows.
 Each kernel sits behind ONE jitted function, so a program pays one
 Mosaic lowering a kernel whatever the number of layers and phases
@@ -58,6 +90,7 @@ kernels run there in interpret mode under ``_INTERPRET``).
 """
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -79,23 +112,60 @@ TOKENS_A_STEP = 256
 HEADS_A_STEP = 8
 TOKENS_A_PASS = 32
 UNIT_EPS = 1e-6      # under the root of a key head's squared length
+# Keys and values of TWO widths: the lanes one grid step takes of the
+# strip (or the largest whole number of lane groups under it).
+LANES_A_STEP = 1152
 _PACKED = 16         # rows of a packed bfloat16 tile, and of a halo
 _SUBLANES = 8        # rows of a float32 tile, and of a partial sum
+_LANES = 128         # lanes of a tile
 
 
-def on_kernels(x, key_dim, value_dim):
+def _group(d):
+    """The fewest lanes that are whole heads of ``d`` AND whole lane
+    tiles (96 -> 384: four heads on three tiles; 128 -> 128)."""
+    return d * _LANES // math.gcd(d, _LANES)
+
+
+def _strip_width(key_heads, value_heads, key_dim, value_dim):
+    """The lanes a grid step takes where keys and values differ in
+    width, or None where ``qkvz`` has no such step: a whole number of
+    both widths' lane groups that divides ``[q | k]`` and ``v`` (so
+    ``v``, ``z`` and the taps' columns start on a block's edge), the
+    largest under ``LANES_A_STEP`` or else ONE such."""
+    g = math.lcm(_group(key_dim), _group(value_dim))
+    qk, v = 2 * key_heads * key_dim, value_heads * value_dim
+    if not key_heads or not value_heads or qk % g or v % g:
+        return None
+    n = math.gcd(qk, v) // g
+    return g * max(m for m in range(1, n + 1)
+                   if n % m == 0 and (m == 1 or m * g <= LANES_A_STEP))
+
+
+def on_kernels(x, key_dim, value_dim, key_heads=0, value_heads=0):
     """True where the chain runs as the kernel pairs: the mixer's input
-    ``x`` on a TPU (or ``_INTERPRET``, the tests' switch) and keys and
-    values of one width (``qkvz`` is then whole heads side by side)."""
-    return key_dim == value_dim and use_pallas("gdn_chain", (x,),
-                                               _INTERPRET)
+    ``x`` on a TPU (or ``_INTERPRET``, the tests' switch) and either
+    keys and values of one width (``qkvz`` is then whole heads side by
+    side) or, given the head counts, of two widths whose columns fall
+    into steps of whole lane groups (:func:`_strip_width`)."""
+    return (key_dim == value_dim or _strip_width(
+        key_heads, value_heads, key_dim, value_dim) is not None) \
+        and use_pallas("gdn_chain", (x,), _INTERPRET)
 
 
-def _tiling(T, heads):
+def _heads_a_step(heads, d):
+    """Heads a step of ``[.., heads * d]``: the largest divisor under
+    ``HEADS_A_STEP`` whose lanes are whole tiles, or (no such divisor:
+    the tests' narrow heads) the largest."""
+    whole = [h for h in range(1, min(heads, HEADS_A_STEP) + 1)
+             if heads % h == 0 and h * d % _LANES == 0]
+    return max(whole) if whole else _pick_block(heads, HEADS_A_STEP)
+
+
+def _tiling(T, heads, d):
     """(tokens a step, tokens a pass, heads a step), and how it runs."""
     bt = _pick_block(T, TOKENS_A_STEP)
     return {"bt": bt, "sub": _pick_block(bt, TOKENS_A_PASS),
-            "hb": _pick_block(heads, HEADS_A_STEP), "interpret": _INTERPRET}
+            "hb": _heads_a_step(heads, d), "interpret": _INTERPRET}
 
 
 def _halo(bt, taps):
@@ -153,11 +223,30 @@ def _silu_grad(x, s):
 
 def _head_sums(x, d):
     """[rows, heads * d] -> the same shape: every lane holds the sum
-    over its head's ``d`` lanes."""
-    return jnp.concatenate(
-        [jnp.broadcast_to(jnp.sum(x[:, j:j + d], -1, keepdims=True),
-                          (x.shape[0], d))
-         for j in range(0, x.shape[1], d)], axis=-1)
+    over its head's ``d`` lanes. Heads of whole lane tiles: a slice, a
+    lane reduction and a broadcast a head. Heads that start and end
+    inside a tile (96, 192): a lane group (:func:`_group`) at a time,
+    every head of it summed under its mask and laid back under it; no
+    value is cut or moved off a tile's edge."""
+    rows, width = x.shape
+    g = _group(d)
+    if g == d or width % g:
+        return jnp.concatenate(
+            [jnp.broadcast_to(jnp.sum(x[:, j:j + d], -1, keepdims=True),
+                              (rows, d))
+             for j in range(0, width, d)], axis=-1)
+    lane = lax.broadcasted_iota(jnp.int32, (rows, g), 1)
+    heads = [jnp.logical_and(lane >= j, lane < j + d)
+             for j in range(0, g, d)]
+    groups = []
+    for at in range(0, width, g):
+        part, sums = x[:, at:at + g], None
+        for mine in heads:
+            total = jnp.sum(jnp.where(mine, part, 0.0), -1, keepdims=True)
+            sums = jnp.broadcast_to(total, (rows, g)) if sums is None \
+                else jnp.where(mine, total, sums)
+        groups.append(sums)
+    return groups[0] if len(groups) == 1 else jnp.concatenate(groups, -1)
 
 
 def _partial_sums(x):
@@ -229,6 +318,45 @@ def _taps_transpose(dconv, after, ago, w, dx_ref, dw_ref, i, sub):
     return ext[:h]
 
 
+def _times(y, unit):
+    """``y`` times a head's scale: a number (1.0: as it stands) or a
+    row of one a lane."""
+    return y if isinstance(unit, float) and unit == 1.0 else y * unit
+
+
+def _strip_scale(width, key_width, scale):
+    """The scale of each lane of column block ``program_id(1)`` of the
+    strip ``[q | k]``, [1, width] float32: ``q``'s columns, the first
+    ``key_width``, carry ``scale``; ``k``'s carry 1."""
+    lane = pl.program_id(1) * width \
+        + lax.broadcasted_iota(jnp.int32, (1, width), 1)
+    return jnp.where(lane < key_width, scale, 1.0).astype(F32)
+
+
+def _fwd_section(ext_ref, x_ref, halo_ref, w_ref, out_ref, at, first,
+                 unit, d, sub):
+    """A tile of ``qkvz``'s convolved columns (the sequence's ``first``
+    or not) -> ``out_ref``'s lanes from ``at``: taps, SiLU and, with
+    ``unit`` (:func:`_times`), the unit vector a head of ``d`` times
+    it."""
+    bt, width = x_ref.shape
+    h, dt = halo_ref.shape[0], out_ref.dtype
+    _fill(ext_ref, x_ref, halo_ref, first)
+    w = w_ref[...].astype(F32)
+
+    def one_pass(i, carry):
+        _, c, s = _conv_silu(ext_ref, w, i, sub, h, dt)
+        y = (c * s).astype(dt)
+        if unit is not None:
+            y = y.astype(F32)
+            y = y * lax.rsqrt(_head_sums(y * y, d) + UNIT_EPS)
+            y = _times(y, unit).astype(dt)
+        out_ref[_rows(i, sub), at:at + width] = y
+        return carry
+
+    lax.fori_loop(0, bt // sub, one_pass, 0)
+
+
 def _in_fwd_kernel(*refs, n, d, scale, sub):
     """``refs``: ``n`` tiles of ``qkvz`` (``q``'s heads, ``k``'s, then
     ``v``'s in ``n - 2`` pieces), their halos, their taps; ``q``, ``k``,
@@ -236,29 +364,33 @@ def _in_fwd_kernel(*refs, n, d, scale, sub):
     xs, halos, ws = refs[:n], refs[n:2 * n], refs[2 * n:3 * n]
     q_ref, k_ref, v_ref, ext_ref = refs[3 * n:]
     first = pl.program_id(2) == 0
-    bt, width = q_ref.shape
-    h, dt = halos[0].shape[0], q_ref.dtype
+    width = q_ref.shape[1]
 
-    def section(x_ref, halo_ref, w_ref, out_ref, at, unit):
-        _fill(ext_ref, x_ref, halo_ref, first)
-        w = w_ref[...].astype(F32)
+    def section(j, out_ref, at, unit):
+        _fwd_section(ext_ref, xs[j], halos[j], ws[j], out_ref, at, first,
+                     unit, d, sub)
 
-        def one_pass(i, carry):
-            _, c, s = _conv_silu(ext_ref, w, i, sub, h, dt)
-            y = (c * s).astype(dt)
-            if unit is not None:
-                y = y.astype(F32)
-                y = y * lax.rsqrt(_head_sums(y * y, d) + UNIT_EPS)
-                y = (y if unit == 1.0 else y * unit).astype(dt)
-            out_ref[_rows(i, sub), at:at + width] = y
-            return carry
-
-        lax.fori_loop(0, bt // sub, one_pass, 0)
-
-    section(xs[0], halos[0], ws[0], q_ref, 0, scale)
-    section(xs[1], halos[1], ws[1], k_ref, 0, 1.0)
+    section(0, q_ref, 0, scale)
+    section(1, k_ref, 0, 1.0)
     for j in range(n - 2):
-        section(xs[2 + j], halos[2 + j], ws[2 + j], v_ref, j * width, None)
+        section(2 + j, v_ref, j * width, None)
+
+
+def _strip_fwd_kernel(x_ref, halo_ref, w_ref, y_ref, ext_ref, *, nqk,
+                      key_width, d, scale, sub):
+    """Keys and values of two widths: column block ``c`` of ``qkvz``'s
+    convolved columns as they lie, ``[q | k]`` one strip of key heads
+    (blocks under ``nqk``; where ``q`` ends inside a block a lane knows
+    its scale by its column), then ``v``'s."""
+    c, first = pl.program_id(1), pl.program_id(2) == 0
+    lanes = _strip_scale(y_ref.shape[1], key_width, scale)
+
+    def section(unit):
+        _fwd_section(ext_ref, x_ref, halo_ref, w_ref, y_ref, 0, first,
+                     unit, d, sub)
+
+    pl.when(c < nqk)(lambda: section(lanes))
+    pl.when(c >= nqk)(lambda: section(None))
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -303,6 +435,61 @@ def _in_fwd(qkvz, taps, *, hk, hv, bt, halo, sub, hb, interpret):
         return q, k, v, qkvz[:, :, (2 * hk + hv) * d:]
 
 
+def _column_blocks(width, bt, last, first, end, tokens=None,
+                   token_block=None):
+    """A backward call's view of an operand that has only the column
+    blocks [first, end) of ``d qkvz``'s: ``tokens`` (a tile's ``bt``)
+    by ``width``, the token tiles counted down from ``last``
+    (``token_block`` maps the grid step otherwise); any other column
+    block parks on block 0 (fetched once, as long as the index
+    stands)."""
+    token_block = token_block or (lambda t: last - t)
+
+    def index(b, c, t):
+        mine = jnp.logical_and(c >= first, c < end)
+        return tuple(jnp.where(mine, i, 0) for i in (
+            b, token_block(t), c - first))
+    return pl.BlockSpec((None, tokens or bt, width), index)
+
+
+def _bwd_section(x_ref, halo_ref, w_ref, d_ref, dx_ref, dw_ref, ext_ref,
+                 next_ref, t, first, unit, d, sub):
+    """A tile of ``d qkvz``'s convolved columns from the cotangent in
+    ``d_ref``, the token tiles walked from the last (``_in_bwd_kernel``
+    says how: ``t`` is the grid step, ``first`` whether its tile is the
+    sequence's first); ``unit`` and ``d`` as :func:`_fwd_section`'s."""
+    bt, _ = dx_ref.shape
+    h, dt = halo_ref.shape[0], dx_ref.dtype
+    _fill(ext_ref, x_ref, halo_ref, first)
+
+    @pl.when(t == 0)
+    def _start():
+        next_ref[...] = jnp.zeros_like(next_ref)
+        dw_ref[...] = jnp.zeros_like(dw_ref)
+
+    w = w_ref[...].astype(F32)
+    passes = bt // sub
+
+    def one_pass(j, after):
+        i = passes - 1 - j
+        ago, c_, s = _conv_silu(ext_ref, w, i, sub, h, dt)
+        dy = d_ref[_rows(i, sub)].astype(F32)
+        if unit is not None:
+            # through the unit vector: y -> y * rsqrt(|y|^2 + eps)
+            y = (c_ * s).astype(dt).astype(F32)
+            rs = lax.rsqrt(_head_sums(y * y, d) + UNIT_EPS)
+            u = y * rs
+            dy = _times(dy, unit)
+            dy = rs * (dy - u * _head_sums(dy * u, d))
+            dy = dy.astype(dt).astype(F32)
+        # SiLU, then the cast the convolution's sum went through
+        dconv = (dy * _silu_grad(c_, s)).astype(dt).astype(F32)
+        return _taps_transpose(dconv, after, ago, w, dx_ref, dw_ref, i,
+                               sub)
+
+    next_ref[...] = lax.fori_loop(0, passes, one_pass, next_ref[...])
+
+
 def _in_bwd_kernel(x_ref, halo_ref, w_ref, dq_ref, dk_ref, dv_ref, dz_ref,
                    dx_ref, dw_ref, ext_ref, next_ref, *, nq, nx, d, scale,
                    sub):
@@ -312,39 +499,11 @@ def _in_bwd_kernel(x_ref, halo_ref, w_ref, dq_ref, dk_ref, dv_ref, dz_ref,
     tokens of the tile AFTER this one, which the step before left; the
     taps' gradient accumulates in ``dw_ref`` over the sequence."""
     c, t = pl.program_id(1), pl.program_id(2)
-    bt, _ = dx_ref.shape
-    h, dt = halo_ref.shape[0], dx_ref.dtype
+    first = t == pl.num_programs(2) - 1
 
     def section(d_ref, unit):
-        _fill(ext_ref, x_ref, halo_ref, t == pl.num_programs(2) - 1)
-
-        @pl.when(t == 0)
-        def _start():
-            next_ref[...] = jnp.zeros_like(next_ref)
-            dw_ref[...] = jnp.zeros_like(dw_ref)
-
-        w = w_ref[...].astype(F32)
-        passes = bt // sub
-
-        def one_pass(j, after):
-            i = passes - 1 - j
-            ago, c_, s = _conv_silu(ext_ref, w, i, sub, h, dt)
-            dy = d_ref[_rows(i, sub)].astype(F32)
-            if unit is not None:
-                # through the unit vector: y -> y * rsqrt(|y|^2 + eps)
-                y = (c_ * s).astype(dt).astype(F32)
-                rs = lax.rsqrt(_head_sums(y * y, d) + UNIT_EPS)
-                u = y * rs
-                if unit != 1.0:
-                    dy = dy * unit
-                dy = rs * (dy - u * _head_sums(dy * u, d))
-                dy = dy.astype(dt).astype(F32)
-            # SiLU, then the cast the convolution's sum went through
-            dconv = (dy * _silu_grad(c_, s)).astype(dt).astype(F32)
-            return _taps_transpose(dconv, after, ago, w, dx_ref, dw_ref, i,
-                                   sub)
-
-        next_ref[...] = lax.fori_loop(0, passes, one_pass, next_ref[...])
+        _bwd_section(x_ref, halo_ref, w_ref, d_ref, dx_ref, dw_ref, ext_ref,
+                     next_ref, t, first, unit, d, sub)
 
     pl.when(c < nq)(lambda: section(dq_ref, scale))
     pl.when(jnp.logical_and(c >= nq, c < 2 * nq))(
@@ -369,15 +528,7 @@ def _in_bwd(qkvz, taps, dq, dk, dv, dz, *, hk, hv, bt, halo, sub, hb,
         nx, last = 2 * nq + nv, T // bt - 1
         keep = _kept_rows(sub)
 
-        def tile(first, end, tokens=bt, token_block=lambda t: last - t):
-            """The column blocks [first, end) of an operand that has
-            only those; any other block parks on block 0 (fetched once,
-            as long as the index stands)."""
-            def index(b, c, t):
-                mine = jnp.logical_and(c >= first, c < end)
-                return tuple(jnp.where(mine, i, 0) for i in (
-                    b, token_block(t), c - first))
-            return pl.BlockSpec((None, tokens, width), index)
+        tile = functools.partial(_column_blocks, width, bt, last)
 
         dx, dw = _call(
             "hvd_gdn_chain_in_bwd",
@@ -402,14 +553,15 @@ def _in_bwd(qkvz, taps, dq, dk, dv, dz, *, hk, hv, bt, halo, sub, hb,
         return dx, dw.sum((0, 2)).astype(taps.dtype)
 
 
-def _in_step(qkvz, taps, hk):
-    step = _tiling(qkvz.shape[1], hk)
+def _in_step(qkvz, taps, hk, hv):
+    step = _tiling(qkvz.shape[1], hk, qkvz.shape[2] // (2 * hk + 2 * hv))
     return {"halo": _halo(step["bt"], taps.shape[0]), **step}
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
 def _kernel_in(qkvz, taps, hk, hv):
-    return _in_fwd(qkvz, taps, hk=hk, hv=hv, **_in_step(qkvz, taps, hk))
+    return _in_fwd(qkvz, taps, hk=hk, hv=hv,
+                   **_in_step(qkvz, taps, hk, hv))
 
 
 def _kernel_in_fwd(qkvz, taps, hk, hv):
@@ -419,22 +571,146 @@ def _kernel_in_fwd(qkvz, taps, hk, hv):
 def _kernel_in_bwd(hk, hv, res, grads):
     qkvz, taps = res
     return _in_bwd(qkvz, taps, *grads, hk=hk, hv=hv,
-                   **_in_step(qkvz, taps, hk))
+                   **_in_step(qkvz, taps, hk, hv))
 
 
 _kernel_in.defvjp(_kernel_in_fwd, _kernel_in_bwd)
 
 
-def chain_in(qkvz, taps, key_heads, value_heads):
-    """Stage one on the kernels: ``qkvz`` [B, T, (2 hk + 2 hv) d] in the
-    compute dtype and the taps [taps, (2 hk + hv) d] -> ``q``, ``k``
-    [B, T, hk d] (unit vectors a head, ``q`` times ``d^-1/2``), ``v``
-    and ``z`` [B, T, hv d]. One ``d`` for keys and values, ``hk``
-    dividing ``hv``. Differentiable in both."""
+# Keys and values of two widths: the strip (the module's docstring).
+
+@functools.partial(jax.jit, static_argnames=(
+    "kw", "vw", "dk", "width", "bt", "halo", "sub", "interpret"))
+def _strip_fwd(qkvz, taps, *, kw, vw, dk, width, bt, halo, sub, interpret):
+    """-> the strip ``[q | k | v]`` [B, T, 2 kw + vw]."""
+    with scope("hvd.gdn.chain"):
+        B, T, _ = qkvz.shape
+        nx = (2 * kw + vw) // width
+
+        def rows(tokens, token_block):
+            return pl.BlockSpec((None, tokens, width),
+                                lambda b, c, t: (b, token_block(t), c))
+
+        tile = rows(bt, lambda t: t)
+        return _call(
+            "hvd_gdn_chain_in_fwd",
+            functools.partial(_strip_fwd_kernel, nqk=2 * kw // width,
+                              key_width=kw, d=dk, scale=dk ** -0.5,
+                              sub=sub),
+            (qkvz, qkvz, taps), (B, nx, T // bt),
+            [tile,
+             rows(halo, lambda t: jnp.maximum(t * (bt // halo) - 1, 0)),
+             pl.BlockSpec((taps.shape[0], width), lambda b, c, t: (0, c))],
+            tile, jax.ShapeDtypeStruct((B, T, nx * width), qkvz.dtype),
+            [pltpu.VMEM((halo + bt, width), F32)], False, interpret)
+
+
+def _strip_bwd_kernel(x_ref, halo_ref, w_ref, dy_ref, dz_ref, dx_ref,
+                      dw_ref, ext_ref, next_ref, *, nqk, nx, key_width, d,
+                      scale, sub):
+    """``_in_bwd_kernel`` over the strip: one cotangent ``[dq | dk |
+    dv]`` whose blocks lie as ``qkvz``'s do."""
+    c, t = pl.program_id(1), pl.program_id(2)
+    first = t == pl.num_programs(2) - 1
+    lanes = _strip_scale(dx_ref.shape[1], key_width, scale)
+
+    def section(unit):
+        _bwd_section(x_ref, halo_ref, w_ref, dy_ref, dx_ref, dw_ref,
+                     ext_ref, next_ref, t, first, unit, d, sub)
+
+    pl.when(c < nqk)(lambda: section(lanes))
+    pl.when(jnp.logical_and(c >= nqk, c < nx))(lambda: section(None))
+
+    @pl.when(c >= nx)
+    def _z():
+        dx_ref[...] = dz_ref[...]
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "kw", "vw", "dk", "width", "bt", "halo", "sub", "interpret"))
+def _strip_bwd(qkvz, taps, dy, dz, *, kw, vw, dk, width, bt, halo, sub,
+               interpret):
+    """-> ``d qkvz`` whole, and the taps' gradient in their dtype."""
+    with scope("hvd.gdn.chain"):
+        B, T, _ = qkvz.shape
+        ntaps = taps.shape[0]
+        nx, nz, last = (2 * kw + vw) // width, vw // width, T // bt - 1
+        keep = _kept_rows(sub)
+
+        tile = functools.partial(_column_blocks, width, bt, last)
+
+        dx, dw = _call(
+            "hvd_gdn_chain_in_bwd",
+            functools.partial(_strip_bwd_kernel, nqk=2 * kw // width,
+                              nx=nx, key_width=kw, d=dk, scale=dk ** -0.5,
+                              sub=sub),
+            (qkvz, qkvz, taps, dy, dz), (B, nx + nz, T // bt),
+            [tile(0, nx),
+             tile(0, nx, halo, lambda t: jnp.maximum(
+                 (last - t) * (bt // halo) - 1, 0)),
+             pl.BlockSpec((ntaps, width),
+                          lambda b, c, t: (0, jnp.minimum(c, nx - 1))),
+             tile(0, nx), tile(nx, nx + nz)],
+            [pl.BlockSpec((None, bt, width),
+                          lambda b, c, t: (b, last - t, c)),
+             pl.BlockSpec((None, ntaps, keep, width), lambda b, c, t: (
+                 b, 0, 0, jnp.minimum(c, nx - 1)))],
+            [jax.ShapeDtypeStruct(qkvz.shape, qkvz.dtype),
+             jax.ShapeDtypeStruct((B, ntaps, keep, taps.shape[1]), F32)],
+            [pltpu.VMEM((halo + bt, width), F32),
+             pltpu.VMEM((halo, width), F32)], True, interpret)
+        return dx, dw.sum((0, 2)).astype(taps.dtype)
+
+
+def _strip_step(qkvz, taps, hk, hv, dk, dv):
+    bt = _pick_block(qkvz.shape[1], TOKENS_A_STEP)
+    return {"kw": hk * dk, "vw": hv * dv, "dk": dk,
+            "width": _strip_width(hk, hv, dk, dv), "bt": bt,
+            "halo": _halo(bt, taps.shape[0]),
+            "sub": _pick_block(bt, TOKENS_A_PASS), "interpret": _INTERPRET}
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5))
+def _kernel_strip(qkvz, taps, hk, hv, dk, dv):
+    kw, vw = hk * dk, hv * dv
+    y = _strip_fwd(qkvz, taps, **_strip_step(qkvz, taps, hk, hv, dk, dv))
+    return (y[:, :, :kw], y[:, :, kw:2 * kw], y[:, :, 2 * kw:],
+            qkvz[:, :, 2 * kw + vw:])
+
+
+def _kernel_strip_fwd(qkvz, taps, hk, hv, dk, dv):
+    return _kernel_strip(qkvz, taps, hk, hv, dk, dv), (qkvz, taps)
+
+
+def _kernel_strip_bwd(hk, hv, dk, dv, res, grads):
+    qkvz, taps = res
+    return _strip_bwd(qkvz, taps, jnp.concatenate(grads[:3], axis=-1),
+                      grads[3], **_strip_step(qkvz, taps, hk, hv, dk, dv))
+
+
+_kernel_strip.defvjp(_kernel_strip_fwd, _kernel_strip_bwd)
+
+
+def chain_in(qkvz, taps, key_heads, value_heads, key_dim=0, value_dim=0):
+    """Stage one on the kernels: ``qkvz`` [B, T, 2 hk dk + 2 hv dv] in
+    the compute dtype and the taps [taps, 2 hk dk + hv dv] -> ``q``,
+    ``k`` [B, T, hk dk] (unit vectors a head, ``q`` times ``dk^-1/2``),
+    ``v`` and ``z`` [B, T, hv dv]. Without the widths: one ``d`` for
+    keys and values, ``hk`` dividing ``hv``. With two that differ: the
+    strip (:func:`on_kernels` says where it has a step).
+    Differentiable in both operands."""
     if value_heads % key_heads:
         raise ValueError(f"{value_heads} value heads are no multiple of "
                          f"{key_heads} key heads")
-    return _kernel_in(qkvz, taps, key_heads, value_heads)
+    if key_dim == value_dim:
+        return _kernel_in(qkvz, taps, key_heads, value_heads)
+    if _strip_width(key_heads, value_heads, key_dim, value_dim) is None:
+        raise ValueError(
+            f"{key_heads} key heads of {key_dim} and {value_heads} value "
+            f"heads of {value_dim}: [q | k] and v do not fall into blocks "
+            "of whole lane groups of both widths")
+    return _kernel_strip(qkvz, taps, key_heads, value_heads, key_dim,
+                         value_dim)
 
 
 # ---------------------------------------------------------------------
@@ -542,7 +818,7 @@ def _out_bwd(o, z, gain, dy, *, eps, bt, sub, hb, interpret):
 
 
 def _out_step(o, gain):
-    return _tiling(o.shape[1], o.shape[2] // gain.shape[0])
+    return _tiling(o.shape[1], o.shape[2] // gain.shape[0], gain.shape[0])
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))

@@ -460,6 +460,22 @@ def test_gated_delta_rule_compiles_for_described_v5e(for_tpu):
     assert len(made) <= 5, made
 
 
+def test_the_rule_at_two_widths_compiles_for_described_v5e(for_tpu):
+    """Olmo-Hybrid's delta rule at the chip cell's size (B2 T8192, 30
+    heads, keys 96 and values 192 wide: neither a lane tile, each padded
+    to the next in every tiled buffer), forward and backward: the kernel
+    pair by name, six heads a step, the states kept the 128 chunks' at
+    96 x 192, no scan over tokens."""
+    key, value = ((2, 8192, 30, 96), BF16), ((2, 8192, 30, 192), BF16)
+    gate = ((2, 8192, 30), F32)
+    text = for_tpu(_delta_rule_fwd_bwd, key, key, value, gate, gate)
+    for name in _RULE_KERNELS:
+        assert f'"kernel":"{name}"' in text, name
+    assert " while(" not in text
+    assert "f32[128,2,30,96,192]" in text         # the states kept
+    assert "[8192,2,30," not in text              # no token-major scan
+
+
 def test_three_layers_of_the_rule_lower_each_kernel_once(for_tpu, v5e_chip):
     """The set-up budget's guard (PERF.md section 6, PR 44). A
     ``pallas_call`` is lowered to Mosaic at every site of every trace,
@@ -793,10 +809,67 @@ def test_the_xing4_cells_grad_program_fits_the_described_v5e(v5e_chip,
 
 _CHAIN_KERNELS = ("hvd_gdn_chain_in_fwd", "hvd_gdn_chain_in_bwd",
                   "hvd_gdn_chain_out_fwd", "hvd_gdn_chain_out_bwd")
+
+
+@pytest.mark.slow
+def test_the_olmohybrid_cells_grad_program_fits_the_described_v5e(
+        v5e_chip, for_tpu):
+    """The cell's grad program at [2, 8192] with the file's ``remat``
+    and ``loss_chunk`` compiles for the described v5e within the 15.75
+    GiB its programs get, Adam's two moments AND a second set of
+    gradients beside it (the step runs one ahead), and holds the flash
+    pair, the rule's pair and the chain's two pairs by name, and no
+    float32 array of the convolved columns (the chain's expression). Two
+    minutes of one core: not in tier-1; every run of the cell on the
+    chip proves the fit again (PR 61: 9.29 GiB, 8.70 of them
+    temporaries, beside 3.46 of moments)."""
+    sys.path.insert(0, REPO)
+    from chipbench import child
+
+    _, _, config, traffic = child.find_cell("olmohybrid.spmd.b2s8192")
+    model = child.load_file("models", "olmohybrid").Model(config, traffic)
+    shapes = jax.eval_shape(lambda k: model.init(k)[0],
+                            jax.random.PRNGKey(0))
+    params = jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+        s.shape, s.dtype, sharding=v5e_chip), shapes)
+    batch = {k: jax.ShapeDtypeStruct((2, 8192), I32, sharding=v5e_chip)
+             for k in ("tokens", "targets")}
+    lowered = jax.jit(
+        lambda p, d: jax.value_and_grad(
+            lambda p, d: model.loss(p, (), d)[0])(p, d),
+        compiler_options=model.compiler_options).lower(params, batch)
+    assert model.check_lowering(lowered.as_text(), True) is None
+    compiled = lowered.compile()
+    peak = compiled.memory_analysis().peak_memory_in_bytes
+    state = sum(s.size * s.dtype.itemsize for s in jax.tree.leaves(shapes))
+    assert peak + 3 * state < 15.75 * 2 ** 30
+    text = compiled.as_text()
+    named = set(re.findall(r'"kernel":"([a-z_0-9]+)"', text))
+    assert named == {"hvd_flash_fwd", "hvd_flash_bwd_fused",
+                     "hvd_gdn_rule_fwd", "hvd_gdn_rule_bwd",
+                     *_CHAIN_KERNELS}
+    assert "f32[2,8192,11520]" not in text
 # Qwen3-Next's linear mixer at the chip cell's size: B2 T8192, 16 key
 # heads serving 32 value heads, all 128 wide, four taps.
 _CHAIN = (((2, 8192, 96 * 128), BF16), ((4, 64 * 128), BF16),
           ((128,), BF16))
+
+
+# Olmo-Hybrid's linear mixer at the chip cell's size: B2 T8192, 30 key
+# heads of 96 and 30 value heads of 192 (the strip), four taps.
+_CHAIN_TWO = (((2, 8192, 17280), BF16), ((4, 11520), BF16), ((192,), BF16))
+
+
+def _chain_two_widths_fwd_bwd(qkvz, taps, gain):
+    """As ``_chain_fwd_bwd``, keys 96 and values 192 wide."""
+    from horovod_tpu.ops import gdn_chain
+
+    def loss(qkvz, taps, gain):
+        q, k, v, z = gdn_chain.chain_in(qkvz, taps, 30, 30, 96, 192)
+        o = v * jnp.tile(q * k, (1, 1, 2))
+        return gdn_chain.chain_out(o, z, gain, 1e-6).astype(F32).sum()
+
+    return jax.value_and_grad(loss, argnums=(0, 1, 2))(qkvz, taps, gain)
 
 
 def _chain_fwd_bwd(qkvz, taps, gain):
@@ -920,17 +993,22 @@ def test_three_parts_lower_each_hc_kernel_once_a_form(v5e_chip, for_tpu):
 
 @pytest.mark.parametrize("fn, shapes, names", [
     (_chain_fwd_bwd, _CHAIN, _CHAIN_KERNELS),
-    (_ssd_chain_fwd_bwd, _SSD_CHAIN, _SSD_CHAIN_KERNELS)],
-    ids=["gdn", "ssd"])
+    (_ssd_chain_fwd_bwd, _SSD_CHAIN, _SSD_CHAIN_KERNELS),
+    (_chain_two_widths_fwd_bwd, _CHAIN_TWO, _CHAIN_KERNELS)],
+    ids=["gdn", "ssd", "gdn-two-widths"])
 def test_chain_compiles_for_described_v5e(for_tpu, fn, shapes, names):
     """A mixer's two chain kernel pairs at its chip cell's size as the
     chip's compiler takes them (blocks of 256 tokens by 1024 lanes, the
     backward's cotangents parked where a column block has none; the SSD
     chain's last column block, ``r``'s 128 of 1024 lanes, hangs over the
-    array's edge), each by the name a device trace shows."""
+    array's edge; keys 96 and values 192 wide: blocks of 256 tokens by
+    1152 lanes of the strip, three lane groups of four key or two value
+    heads), each by the name a device trace shows, and no float32 array
+    of the convolved columns beside them."""
     text = for_tpu(fn, *shapes)
     for name in names:
         assert f'"kernel":"{name}"' in text, name
+    assert "f32[2,8192,11520]" not in text
 
 
 def test_three_mixers_lower_each_chain_kernel_once_a_form(v5e_chip,

@@ -58,6 +58,8 @@ def _operands(t, decay, write, seed=0, shape=(2, 3, 16, 24)):
     beta = jax.nn.sigmoid(2 * jax.random.normal(ks[4], (b, t, h))) \
         if beta is None else beta + 0.02 * (
             jax.random.uniform(ks[4], (b, t, h)) - 0.5)
+    if write == "to2":      # over (0, 2): the eigenvalue along k in (-1, 1)
+        beta = 2.0 * beta
     return (unit(ks[0]) * dk ** -0.5, unit(ks[1]),
             jax.random.normal(ks[2], (b, t, h, dv)),
             -decay * jax.random.uniform(ks[3], (b, t, h), minval=0.5,
@@ -83,7 +85,7 @@ def _readings(rule, *operands):
     return (out,) + grads
 
 
-@pytest.mark.parametrize("write", ["near0", "near1", "mixed"])
+@pytest.mark.parametrize("write", ["near0", "near1", "mixed", "to2"])
 @pytest.mark.parametrize("decay", [0.01, 5.0])
 @pytest.mark.parametrize("chunks", [1, 2, 5])
 def test_chunked_form_is_the_recurrence(chunks, decay, write):
@@ -244,20 +246,29 @@ def test_a_step_takes_a_divisor_of_the_heads(kernels):
         assert module._step(q) == {"hb": takes, "interpret": True}
 
 
-@pytest.mark.parametrize("chunks,heads", [(1, 8), (3, 2), (2, 1)])
+@pytest.mark.parametrize("chunks,heads,shape,write", [
+    (1, 8, (1, 3, 16, 24), "mixed"), (3, 2, (1, 3, 16, 24), "mixed"),
+    (2, 1, (1, 3, 16, 24), "mixed"),
+    # 30 heads, six a step, keys 96 and values 192 wide (neither a lane
+    # tile), write strengths over (0, 2)
+    (2, 8, (1, 30, 96, 192), "to2")])
 def test_the_rule_on_the_kernel_pair_is_the_recurrence(
-        kernels, chunks, heads):
+        kernels, chunks, heads, shape, write):
     """``gated_delta_rule``'s value and five gradients with the kernels
     forming the factors and carrying the state (the inverse, and the
     way back through it, XLA's), weak decay and strong, ``dk`` 16
     beside ``dv`` 24, three heads."""
     kernels(heads)
+    if shape[1] == 30:
+        assert module._step(jnp.zeros((1, 1, 30, 16, 96)))["hb"] == 6
     # not ``_GRADS``: that jit has traced the rule on the scan
     readings = jax.jit(jax.grad(_weighted(gated_delta_rule),
                                 argnums=(0, 1, 2, 3, 4), has_aux=True))
     for decay in (0.01, 5.0):
-        operands = _operands(chunks * CHUNK, decay, "mixed", seed=chunks,
-                             shape=(1, 3, 16, 24))
+        operands = _operands(chunks * CHUNK, decay, write, seed=chunks,
+                             shape=shape)
+        if write == "to2":
+            assert 1.5 < float(jnp.max(operands[4])) <= 2.0
         with jax.default_matmul_precision("highest"):
             grads, out = readings(*operands)
             ref = _readings(token_by_token, *operands)

@@ -38,19 +38,25 @@ def _close(got, ref, dtype, what):
     assert err < (2e-5 if dtype == F32 else 2.5e-2), (what, err)
 
 
-def _stage_one(dtype, B, T, hk, hv, d, taps=4, seed=0):
+def _stage_one(dtype, B, T, hk, hv, d, taps=4, seed=0, dv=None):
+    dv = dv or d
     ks = jax.random.split(jax.random.PRNGKey(seed), 6)
-    qkvz = jax.random.normal(ks[0], (B, T, (2 * hk + 2 * hv) * d), F32)
-    w = 0.5 * jax.random.normal(ks[1], (taps, (2 * hk + hv) * d), F32)
-    weights = [jax.random.normal(k, (B, T, h, d), F32)
-               for k, h in zip(ks[2:], (hk, hk, hv, hv))]
+    qkvz = jax.random.normal(ks[0], (B, T, 2 * hk * d + 2 * hv * dv), F32)
+    w = 0.5 * jax.random.normal(ks[1], (taps, 2 * hk * d + hv * dv), F32)
+    weights = [jax.random.normal(k, (B, T, h, width), F32)
+               for k, h, width in zip(ks[2:], (hk, hk, hv, hv),
+                                      (d, d, dv, dv))]
     return qkvz.astype(dtype), w.astype(dtype), weights
 
 
-def _by_heads(outs, d):
+ONE = (2, 4, 8, 16, 16)     # batch, heads and ONE width of keys and values
+
+
+def _by_heads(outs, d, dv=None):
     """The kernels' ``[B, T, heads * d]`` as the expression's ``[B, T,
     heads, d]``."""
-    return tuple(a.reshape(*a.shape[:2], -1, d) for a in outs)
+    return tuple(a.reshape(*a.shape[:2], -1, width)
+                 for a, width in zip(outs, (d, d, dv or d, dv or d)))
 
 
 def _weighted(outs, weights):
@@ -58,26 +64,33 @@ def _weighted(outs, weights):
 
 
 @pytest.mark.parametrize("dtype", [F32, BF16], ids=["float32", "bfloat16"])
-@pytest.mark.parametrize("T, tokens, a_pass", [
-    (8, 8, 4),      # one tile: the first tokens see zeros
-    (32, 8, 4),     # several: a tile's first tokens see the tile before
-    (24, 12, 4),    # a halo of 3 = taps - 1 divides the tile of 12 ...
-    (20, 10, 5),    # ... of 10 only a halo of 5 does
-    (16, 8, 2),     # a pass shorter than the halo
-])
-def test_stage_one_is_the_expression(kernels, dtype, T, tokens, a_pass):
+@pytest.mark.parametrize("T, tokens, a_pass, sizes", [
+    (8, 8, 4, ONE),      # one tile: the first tokens see zeros
+    (32, 8, 4, ONE),     # several: a tile's first tokens see the tile before
+    (24, 12, 4, ONE),    # a halo of 3 = taps - 1 divides the tile of 12 ...
+    (20, 10, 5, ONE),    # ... of 10 only a halo of 5 does
+    (16, 8, 2, ONE),     # a pass shorter than the halo
+    # keys 96 and values 192 wide, 30 heads of each (the strip: ``q``
+    # ends in the middle of a lane tile and of a step's 1152 lanes)
+    (32, 16, 8, (1, 30, 30, 96, 192)),
+    # ... a lane group a step, one key head serving two value heads
+    (16, 8, 8, (2, 2, 4, 96, 192)),
+], ids=lambda x: "x".join(map(str, x)) if isinstance(x, tuple) else None)
+def test_stage_one_is_the_expression(kernels, dtype, T, tokens, a_pass,
+                                     sizes):
     """``hvd_gdn_chain_in_fwd`` / ``_bwd``: ``q``, ``k``, ``v``, ``z``
     and the gradients of ``qkvz`` and of the taps, with fewer key heads
-    than value heads and two blocks of heads."""
-    B, hk, hv, d = 2, 4, 8, 16
+    than value heads and two blocks of heads; and with keys and values
+    of two widths, neither a lane tile."""
+    B, hk, hv, d, dv = sizes
     kernels(tokens, 2, a_pass)
-    qkvz, w, weights = _stage_one(dtype, B, T, hk, hv, d)
+    qkvz, w, weights = _stage_one(dtype, B, T, hk, hv, d, dv=dv)
 
     def ref(qkvz, w):
-        return llama._gdn_chain_in(qkvz, w, hk, hv, d, d)
+        return llama._gdn_chain_in(qkvz, w, hk, hv, d, dv)
 
     def got(qkvz, w):
-        return _by_heads(module.chain_in(qkvz, w, hk, hv), d)
+        return _by_heads(module.chain_in(qkvz, w, hk, hv, d, dv), d, dv)
 
     def readings(f):
         """One compiled program a side: the four outputs and the two
@@ -125,12 +138,14 @@ def test_taps_that_reach_past_a_tile_are_refused(kernels):
 
 
 @pytest.mark.parametrize("dtype", [F32, BF16], ids=["float32", "bfloat16"])
-@pytest.mark.parametrize("T, tokens, heads", [(8, 8, 4), (32, 8, 2),
-                                              (24, 12, 3)])
-def test_stage_two_is_the_expression(kernels, dtype, T, tokens, heads):
+@pytest.mark.parametrize("T, tokens, heads, d", [
+    (8, 8, 4, 16), (32, 8, 2, 16), (24, 12, 3, 16),
+    # 30 heads of 192: six a step, a head a lane tile and a half
+    (16, 8, 8, 192)])
+def test_stage_two_is_the_expression(kernels, dtype, T, tokens, heads, d):
     """``hvd_gdn_chain_out_fwd`` / ``_bwd``: the gated norm and the
     gradients of ``o``, ``z`` and the gain."""
-    B, H, d, eps = 2, 6 if heads == 3 else 4, 16, 1e-6
+    B, H, eps = 2, {3: 6, 8: 30}.get(heads, 4), 1e-6
     kernels(tokens, heads, 4)
     ks = jax.random.split(jax.random.PRNGKey(1), 4)
     o, z, weight = (jax.random.normal(k, (B, T, H, d), F32) for k in ks[:3])
@@ -152,20 +167,29 @@ def test_stage_two_is_the_expression(kernels, dtype, T, tokens, heads):
            "out")
     grads = [jax.jit(jax.grad(loss(f), (0, 1, 2)))(o, z, gain)
              for f in (got, ref)]
+    if dtype == BF16:
+        # the gain's gradient sums B x T x heads products: the kernel in
+        # float32, the expression in bfloat16, which at 960 of them is
+        # the further of the two from float32 on the same operands
+        exact = jax.jit(jax.grad(loss(ref), 2))(
+            o.astype(F32), z.astype(F32), gain.astype(F32))
+        grads[1] = grads[1][:2] + (exact,)
     for name, a, b in zip(("d o", "d z", "d gain"), *grads):
         assert a.dtype == dtype
         _close(a, b, dtype, name)
 
 
-def _mixer(dtype):
+def _mixer(dtype, sizes=(2, 4, 16, 16), **more):
     """One ``linear_attention`` layer's leaves and an input: two key
     heads serving four value heads, 16 wide, four taps."""
+    hk, hv, dk, dv = sizes
     cfg = llama.LlamaConfig(
         vocab_size=64, d_model=32, n_layers=2, n_heads=2, n_kv_heads=1,
         d_head=16, d_ff=64, norm_eps=1e-6, conv_taps=4,
         layer_types=("linear_attention", "full_attention"),
-        linear_key_heads=2, linear_value_heads=4, linear_key_dim=16,
-        linear_value_dim=16, dtype=dtype, param_dtype=dtype, remat="attn")
+        linear_key_heads=hk, linear_value_heads=hv, linear_key_dim=dk,
+        linear_value_dim=dv, dtype=dtype, param_dtype=dtype, remat="attn",
+        **more)
     params = llama.llama_init(cfg, jax.random.PRNGKey(0))
     lp = {name: w[0] for name, w in params["linear_layers"].items()
           if name.startswith("gdn_")}
@@ -190,11 +214,19 @@ def _l2(got, ref):
     return np.linalg.norm(got - ref) / np.linalg.norm(ref)
 
 
-def test_the_mixer_is_the_same_on_both_carriers(kernels, monkeypatch):
+@pytest.mark.parametrize("sizes, more", [
+    ((2, 4, 16, 16), {}),
+    # keys 96 and values 192 wide on the strip, write strengths to 2,
+    # no norm of the mixer's input
+    ((2, 2, 96, 192), {"linear_beta_max": 2.0, "post_norm": "only"})],
+    ids=["one-width", "two-widths"])
+def test_the_mixer_is_the_same_on_both_carriers(kernels, monkeypatch, sizes,
+                                                more):
     """``_gated_delta_net`` whole in float32, values and the gradients
     of its input and of every leaf it reads: the expressions and the
     scan, then the chain's kernels and the rule's."""
-    cfg, lp, x = _mixer("float32")
+    cfg, lp, x = _mixer("float32", sizes, **more)
+    assert ("gdn_norm" in lp) == (not more)
     ref = _mixer_readings(cfg, lp, x)
     kernels(16, 2, 8)
     monkeypatch.setattr(gated_delta_rule, "_INTERPRET", True)
@@ -223,10 +255,37 @@ def test_the_mixer_in_bfloat16_is_no_further_from_float32(
         assert mine < 1.25 * theirs + 2e-3, (name, mine, theirs)
 
 
-def test_keys_and_values_of_two_widths_take_the_expression(kernels):
+def test_keys_and_values_of_two_widths_take_the_strip_where_it_has_a_step(
+        kernels, monkeypatch):
+    """One width: the kernels. Two: the strip where ``[q | k]`` and
+    ``v`` fall into blocks of whole lane groups of both widths (given
+    the head counts), else the expression."""
     kernels()
     x = jnp.zeros((1, 8, 8))
     assert module.on_kernels(x, 16, 16) and not module.on_kernels(x, 8, 16)
+    assert module._group(96) == module._group(192) == 384
+    assert module._group(128) == 128 and module._group(64) == 128
+    # 60 key heads of 96 are 15 groups, 30 value heads of 192 too: three
+    # groups a step under 1152 lanes, one under 384
+    assert module._strip_width(30, 30, 96, 192) == 1152
+    assert module.on_kernels(x, 96, 192, 30, 30)
+    monkeypatch.setattr(module, "LANES_A_STEP", 384)
+    assert module._strip_width(30, 30, 96, 192) == 384
+    assert module._strip_width(2, 2, 96, 192) == 384
+    # 15 value heads of 192 end in the middle of a group; 3 key heads of
+    # 96 make [q | k] a group and a half
+    assert module._strip_width(30, 15, 96, 192) is None
+    assert module._strip_width(3, 3, 96, 192) is None
+    assert not module.on_kernels(x, 96, 192, 30, 15)
+    qkvz, w, _ = _stage_one(F32, 1, 8, 3, 3, 96, dv=192)
+    with pytest.raises(ValueError, match="whole lane groups"):
+        module.chain_in(qkvz, w, 3, 3, 96, 192)
+    # six heads of 192 a step are nine lane tiles; of 16 (the tests'),
+    # the largest divisor
+    monkeypatch.setattr(module, "HEADS_A_STEP", 8)
+    assert module._heads_a_step(30, 192) == 6
+    assert module._heads_a_step(32, 128) == 8
+    assert module._heads_a_step(6, 16) == 6
 
 
 def test_interpret_mode_on_a_tpu_is_refused(kernels, monkeypatch):
