@@ -1584,11 +1584,14 @@ def _gated_delta_net(x, lp, c, mesh, seq_axis, stage=lambda f: f):
     and ``k`` L2-normalised a head, ``q`` scaled by ``dk^-1/2``; ``beta
     = sigmoid(b)`` and ``g = -exp(A_log) * softplus(a + dt_bias)`` a
     value head and token, in float32. The rule and what follows it: the
-    gated delta rule (``ops/gated_delta_rule.py``), a key head repeated
-    for the value heads it serves; ``RMSNorm(o) * SiLU(z)`` a head; the
-    output projection. ``stage`` wraps each (remat "attn/ffn": a
-    checkpoint of its own, so that the first's residuals and the
-    rule's are never alive together). The chain round the rule runs as
+    gated delta rule (``ops/gated_delta_rule.py``) on ``q``, ``k`` [B, T,
+    hk dk] and ``v`` [B, T, hv dv] as the first stage leaves them, heads
+    side by side, a key head serving ``hv / hk`` value heads by its
+    index (nothing is reshaped or repeated between the chain and the
+    rule); ``RMSNorm(o) * SiLU(z)`` a head of ``o`` [B, T, hv dv] as the
+    rule leaves it; the output projection. ``stage`` wraps each (remat
+    "attn/ffn": a checkpoint of its own, so that the first's residuals
+    and the rule's are never alive together). The chain round the rule runs as
     ``ops/gdn_chain.py``'s kernel pairs where the operands live on a TPU
     (keys and values of one width, or of two whose columns fall into
     steps of whole lane groups: ``gdn_chain.on_kernels``), as
@@ -1621,11 +1624,10 @@ def _gated_delta_net(x, lp, c, mesh, seq_axis, stage=lambda f: f):
             if kernels:     # heads side by side, z too: chain_out's
                 q, k, v, z = gdn_chain.chain_in(qkvz, lp["gdn_conv"], hk, hv,
                                                 dk, dv)
-                q, k = (a.reshape(b, t, hk, dk) for a in (q, k))
-                v = v.reshape(b, t, hv, dv)
             else:
                 q, k, v, z = _gdn_chain_in(qkvz, lp["gdn_conv"], hk, hv,
                                            dk, dv)
+                q, k, v = (a.reshape(b, t, -1) for a in (q, k, v))
             bb, aa = jnp.split(ba.astype(f32), 2, axis=-1)
             g = -jnp.exp(lp["gdn_a_log"].astype(f32)) * jax.nn.softplus(
                 aa + lp["gdn_dt_bias"].astype(f32))
@@ -1635,18 +1637,15 @@ def _gated_delta_net(x, lp, c, mesh, seq_axis, stage=lambda f: f):
             return q, k, v, z, g, beta
 
     def rule_and_after(q, k, v, z, g, beta, lp):
-        with scope("hvd.gdn.chain"):
-            q, k = (jnp.repeat(a, hv // hk, axis=2) for a in (q, k))
         with scope("hvd.gdn.core"):
-            o = gated_delta_rule(q, k, v, g, beta)
+            o = gated_delta_rule(q, k, v, g, beta, key_heads=hk)
         with scope("hvd.gdn.chain"):
             gain = lp["gdn_out_norm"].astype(dt)
             if kernels:
-                o = gdn_chain.chain_out(o.reshape(b, t, hv * dv), z, gain,
-                                        c.norm_eps)
+                o = gdn_chain.chain_out(o, z, gain, c.norm_eps)
             else:
-                o = _gdn_chain_out(o, z, gain, c.norm_eps).reshape(
-                    b, t, hv * dv)
+                o = _gdn_chain_out(o.reshape(b, t, hv, dv), z, gain,
+                                   c.norm_eps).reshape(b, t, hv * dv)
         with scope("hvd.gdn.proj"):
             return o @ lp["gdn_out"].astype(dt)
 

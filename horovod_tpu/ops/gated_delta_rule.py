@@ -23,13 +23,28 @@ WY form)::
     O  = (Q * exp(gamma)) S + tril(Q K^T * exp(gamma_i - gamma_j)) V'
     S <- exp(gamma_C) S + (K * exp(gamma_C - gamma))^T V'
 
+How the operands lie: TOKEN-MAJOR, as the projections' matmuls and
+``ops/gdn_chain.py``'s kernels leave and take them: ``q``, ``k`` [B, T,
+hk dk], ``v`` and ``o`` [B, T, hv dv], the tokens on the sublanes and a
+token's heads side by side on the lanes. (Read as ``[B, T, H, d]`` the
+same bytes are tiled with the HEADS on the sublanes: a reshape between
+the two is a copy of the whole array on a TPU, and a chunk-major ``[B,
+N, H, C, d]`` a transposing copy more.) ``[B, T, H d]`` viewed as ``[B,
+N, C, H d]`` is free. A key head serves ``r = hv / hk`` value heads in a
+row (value head ``h`` reads key head ``h // r``) and is INDEXED, never
+repeated in HBM.
+
 Nothing but ``S`` crosses a chunk, so the work falls into three parts:
 
 1. what leads to ``T``: the decays ``gamma`` and ``exp(gamma_i -
    gamma_j)``, ``K K^T``, ``A`` and the solve, for ALL chunks at once,
-   by ordinary batched operations that autodiff differentiates. ``T``
-   is born here, in XLA (``lax.linalg.triangular_solve``), rounded to
-   the compute dtype, on every carrier;
+   by ordinary batched operations that autodiff differentiates. ``K
+   K^T`` is formed a KEY head (``k`` copied head-major ``[B, N, hk, C,
+   dk]`` for it, the one copy of an operand that is left, and that
+   product's share of ``dk`` copied back); ``A``, the solve and ``T``
+   are a value head's (``beta`` and ``gamma`` are). ``T`` is born here,
+   in XLA (``lax.linalg.triangular_solve``), rounded to the compute
+   dtype, on every carrier, ``[B, N, hv, C, C]``;
 2. the WY factors, each a function of a chunk's own ``q``, ``k``, ``v``,
    ``gamma``, ``beta`` and ``T``::
 
@@ -51,7 +66,7 @@ never off an option:
   time: ``qg``, ``p``, ``u``, ``w``, ``kd`` (134 MB each at B2 T8192 H32
   d128, ``p`` 67) and their five cotangents are born and die there, in
   the forward, the forward under remat and the backward; none crosses
-  HBM, none is a residual. ``hvd_gdn_rule_fwd`` walks a grid ``(B, H /
+  HBM, none is a residual. ``hvd_gdn_rule_fwd`` walks a grid ``(B, hv /
   hb, T / C)`` whose last axis, the chunks, is sequential; the state of
   ``hb`` heads lives in a float32 VMEM scratch for the whole sequence
   and never crosses HBM (but where the states are kept).
@@ -59,9 +74,35 @@ never off an option:
   first with the state's cotangent in the scratch: it forms the factors
   again, runs the scan's reverse step, and takes the factors'
   cotangents on to the operands' in the same step (the equations
-  below). ``q``, ``k``, ``v`` and ``T`` are read where part 1 leaves
-  them, ``[B, N, H, C, ...]`` (an index map takes block ``(b, n, h)``
-  as readily as ``(n, b, h)``); only the kept states are chunk-major.
+  below). One set of kernel bodies behind TWO block layouts, chosen by
+  the operands' shapes (``_token_major_step``):
+
+  - token-major: the block of ``q``, ``k``, ``v``, ``o``, ``do``,
+    ``dq``, ``dk``, ``dv`` is ``(C, hb d)`` of ``[B, T, H d]`` at ``(b,
+    chunk, h)``, a chunk's rows of a step's heads side by side. Inside,
+    a head is a lane slice at a multiple of ``d`` (``_heads``), a key
+    head's taken once for each of the ``r`` value heads it serves;
+    ``dq`` and ``dk`` are summed over those ``r`` in float32 before the
+    one rounding (``_put``). No copy of ``q``, ``v``, ``o`` or their
+    cotangents is left in the program. ``hb`` is a divisor of the heads
+    that gives whole lane tiles in both strips (``(hb / r) dk`` and
+    ``hb dv`` multiples of 128: eight heads at 128 wide), every slice
+    then on a tile's edge; or, widths with no such divisor (30 heads 96
+    and 192 wide: heads of 96 want steps of four), ALL the heads, a
+    block as wide as the array being legal whatever its lanes: a head
+    is then a lane window wherever it falls, the step's state is the
+    whole layer's (2.8 MiB padded) and the call asks for its VMEM by
+    name. On the chip, the rule alone forward and backward at B2 T8192:
+    26.04 -> 18.81 ms at 32 heads of 128 on 16 key heads, 30.17 -> 21.17
+    at 30 of 96 / 192 (PERF.md, PR 62);
+  - chunk-major, where the whole width's state would not fit either
+    (``_WHOLE_WIDTH_STATE``): ``q``, ``k``, ``v`` copied to ``[B, N, H,
+    C, d]`` (a key head repeated by that copy), ``o`` and the
+    cotangents copied back, a block the ``hb`` heads of a chunk.
+
+  Either way ``T`` is read where part 1 leaves it, ``[B, N, hv, C, C]``
+  (an index map takes block ``(b, n, h)`` as readily as ``(n, b, h)``);
+  only the kept states are chunk-major, ``[N, B, hv, dk, dv]``.
   The gates cross HBM lane-dense, ``[B, H, N, C]`` float32, a
   sequence's block staying in VMEM and a chunk's row ``[hb, 1, C]``
   read from it; a gate scales ROWS of a ``[C, d]`` tile, so the kernel
@@ -76,9 +117,10 @@ never off an option:
   once;
 - elsewhere: ``_scan_rule``, the factors by batched operations that
   autodiff differentiates, each through HBM, and ``_scan_state``, a
-  ``lax.scan`` over chunk-major operands under a ``custom_vjp`` (the
-  CPU's path, and the tests' reference for the kernels, which run there
-  in interpret mode under ``_INTERPRET``).
+  ``lax.scan`` over chunk-major operands under a ``custom_vjp``, behind
+  the chunk-major layout's copies (the CPU's path, and the tests'
+  reference for the kernels, which run there in interpret mode under
+  ``_INTERPRET``).
 
 Either way the forward keeps the state each chunk STARTED from (T/C x
 [B, H, dk, dv] float32: 537 MB at B2 T8192 H32 d128, alive for one
@@ -116,8 +158,9 @@ token's row, ``cols()`` down a column)::
     dgamma_C += ddc * exp(gamma_C)
                 + sum(exp(gamma_C - gamma) * rows(dkd * K))
 
-``dk`` has a second share (``K K^T``'s) and ``dbeta``, ``dgamma`` a
-second each (``A``'s): autodiff's, through part 1, added outside.
+``dk`` has a second share (``K K^T``'s, a key head's) and ``dbeta``,
+``dgamma`` a second each (``A``'s): autodiff's, through part 1, added
+outside.
 
 The seam left for the inverse: ``T`` enters the kernels as an operand
 and ``dT`` leaves as a result. A kernel that forms ``T`` itself (a block
@@ -129,8 +172,10 @@ arguments, so none overflows), the inverse (forward substitution, not a
 Neumann series: stable whatever the keys), the state and every
 accumulation are float32; the matmuls take operands in the compute dtype
 (``q``'s; the inverse, the factors and the state rounded to it as they
-enter one) and accumulate in float32. Both carriers round at the same
-places: on the same operands ``o`` is the same to the last bit.
+enter one) and accumulate in float32. Both carriers and both block
+layouts round at the same places: on the same operands the two layouts
+give ``o`` the same to the last bit, and the scan too wherever its
+matmuls add a row's products in the kernels' order.
 """
 
 import functools
@@ -154,6 +199,15 @@ _INTERPRET = False
 # What one grid step of a kernel takes: a chunk of so many heads (a
 # batched matmul), or of the largest divisor of H under it.
 HEADS_A_STEP = 8
+_LANES = 128         # lanes of a tile
+_SUBLANES = 8        # rows of a float32 tile
+# Token-major blocks of the WHOLE width (heads of no lane tile's width):
+# taken where the float32 state of all the heads, its tiles padded, is
+# under so many bytes (30 heads of 96 x 192 hold 2.8 MiB, and the
+# backward then wants between 32 and 48 MiB of VMEM where a kernel is
+# given 16 unasked), and the VMEM such a step asks for by name.
+_WHOLE_WIDTH_STATE = 4 * 2 ** 20
+_WHOLE_WIDTH_VMEM = 100 * 2 ** 20
 
 
 def _mm(spec, a, b):
@@ -352,6 +406,33 @@ def _chunk_bwd(q, k, v, gamma, beta, inv, S, dS, do):
     return dq, dk, dv, dinv, dgamma, _along(dbeta), dS
 
 
+def _heads(ref, hb, d):
+    """A step's ``hb`` heads [hb, C, d] of a block of ``q``, ``k``,
+    ``v`` or ``do``. Chunk-major the block is that already. Token-major
+    it is ``[C, (hb / r) d]``, a head a lane slice at a multiple of
+    ``d``, and a key head is taken once for each of the ``r`` value
+    heads it serves (no copy of it in HBM)."""
+    if len(ref.shape) == 3:
+        return ref[...]
+    r = hb * d // ref.shape[-1]
+    return jnp.stack([ref[:, i // r * d:(i // r + 1) * d]
+                      for i in range(hb)])
+
+
+def _put(ref, x):
+    """``_heads``'s way back for ``x`` [hb, C, d] float32: the block as
+    it lies, or a head a lane slice, the ``r`` value heads of a key head
+    summed in float32 before the one rounding."""
+    if len(ref.shape) == 3:
+        ref[...] = x.astype(ref.dtype)
+        return
+    hb, _, d = x.shape
+    r = hb * d // ref.shape[-1]
+    for i in range(hb // r):
+        ref[:, i * d:(i + 1) * d] = functools.reduce(
+            jnp.add, [x[i * r + j] for j in range(r)]).astype(ref.dtype)
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, gamma_ref, beta_ref, inv_ref, o_ref,
                 *rest):
     """One grid step: a chunk of ``hb`` heads from the state in
@@ -359,6 +440,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, gamma_ref, beta_ref, inv_ref, o_ref,
     (the last, sequential one). ``rest`` = (states_ref, S_ref) where
     the states are kept, else (S_ref,)."""
     S_ref = rest[-1]
+    hb, dk, dv = S_ref.shape
     n = pl.program_id(2)
     row = pl.ds(n, 1)
 
@@ -370,9 +452,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, gamma_ref, beta_ref, inv_ref, o_ref,
     if len(rest) == 2:
         rest[0][...] = S
     o, S_ref[...] = _chunk_fwd(
-        q_ref[...], k_ref[...], v_ref[...], gamma_ref[:, row, :],
-        beta_ref[:, row, :], inv_ref[...], S)
-    o_ref[...] = o.astype(o_ref.dtype)
+        _heads(q_ref, hb, dk), _heads(k_ref, hb, dk), _heads(v_ref, hb, dv),
+        gamma_ref[:, row, :], beta_ref[:, row, :], inv_ref[...], S)
+    _put(o_ref, o)
 
 
 def _bwd_kernel(q_ref, k_ref, v_ref, gamma_ref, beta_ref, inv_ref,
@@ -381,6 +463,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, gamma_ref, beta_ref, inv_ref,
     """The reverse pass: grid step ``n`` holds chunk ``N - 1 - n`` (the
     index maps count down); ``dS_ref`` is the cotangent of the state
     that chunk ends with."""
+    hb, wk, wv = dS_ref.shape
     n = pl.program_id(2)
     row = pl.ds(pl.num_programs(2) - 1 - n, 1)
 
@@ -389,52 +472,67 @@ def _bwd_kernel(q_ref, k_ref, v_ref, gamma_ref, beta_ref, inv_ref,
         dS_ref[...] = jnp.zeros_like(dS_ref)
 
     dq, dk, dv, dinv, dgamma, dbeta, dS_ref[...] = _chunk_bwd(
-        q_ref[...], k_ref[...], v_ref[...], gamma_ref[:, row, :],
-        beta_ref[:, row, :], inv_ref[...], states_ref[...], dS_ref[...],
-        do_ref[...])
+        _heads(q_ref, hb, wk), _heads(k_ref, hb, wk), _heads(v_ref, hb, wv),
+        gamma_ref[:, row, :], beta_ref[:, row, :], inv_ref[...],
+        states_ref[...], dS_ref[...], _heads(do_ref, hb, wv))
     for ref, x in ((dq_ref, dq), (dk_ref, dk), (dv_ref, dv),
                    (dinv_ref, dinv)):
-        ref[...] = x.astype(ref.dtype)
+        _put(ref, x)
     # a row of a block that stays for the whole sequence
     dgamma_ref[:, row, :] = dgamma
     dbeta_ref[:, row, :] = dbeta
 
 
-def _call(name, kernel, hb, operands, grid, in_specs, out_specs, out_shape,
-          interpret):
+def _call(name, kernel, state, operands, grid, in_specs, out_specs,
+          out_shape, interpret):
     """``metadata`` is the name a device trace shows of the call
     (``ops/flash_attention.py:_pallas_dispatch``). Batch and heads in
-    any order, a sequence's chunks one after another; the scratch is the
-    state (or its cotangent) of a step's heads."""
-    dk, dv = operands[0].shape[-1], operands[2].shape[-1]
+    any order, a sequence's chunks one after another; the scratch
+    ``state`` (hb, dk, dv) is the state (or its cotangent) of a step's
+    heads. A step of more heads than ``HEADS_A_STEP`` (the whole width)
+    asks for its VMEM by name."""
     return pl.pallas_call(
         kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
         out_shape=out_shape, interpret=interpret,
-        scratch_shapes=[pltpu.VMEM((hb, dk, dv), F32)],
+        scratch_shapes=[pltpu.VMEM(state, F32)],
         metadata={"kernel": name},
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_WHOLE_WIDTH_VMEM
+            if state[0] > HEADS_A_STEP else None),
     )(*operands)
 
 
-def _specs(q, v, hb, at):
-    """(grid, and the block specs) of ``hb`` heads of a chunk a step,
-    ``at(n)`` the chunk grid step ``n`` takes: ``raw``, the six
-    operands' in their order (``q``, ``k``, ``v``, ``inv`` ``[B, N, H,
-    C, ...]``; a gate's ``[B, H, N, C]``, a sequence's staying in VMEM),
-    and ``states`` over the kept ``[N, B, H, dk, dv]``."""
-    B, N, H, C, dk = q.shape
-    dv = v.shape[-1]
+def _specs(q, v, inv, hk, hb, at):
+    """(grid, the block specs, the scratch's shape) of ``hb`` value
+    heads of a chunk a step, ``at(n)`` the chunk grid step ``n`` takes:
+    ``raw``, the six operands' in their order, and ``states`` over the
+    kept ``[N, B, H, dk, dv]``. ``q``, ``k`` (of ``hk`` key heads), ``v``
+    token-major, ``[B, T, heads d]``: a chunk's rows of a step's heads
+    side by side on the lanes, a key head's block index the index of the
+    ``hb`` value heads it serves ``H / hk`` of. Chunk-major, ``[B, N, H,
+    C, d]``: the heads of a chunk. ``inv`` ``[B, N, H, C, C]``; a gate's
+    ``[B, H, N, C]``, a sequence's staying in VMEM."""
+    B, N, H, C, _ = inv.shape
 
-    def chunks(last):
-        return pl.BlockSpec((None, None, hb, C, last),
+    def of_chunks(d):
+        return pl.BlockSpec((None, None, hb, C, d),
                             lambda b, h, n: (b, at(n), h, 0, 0))
 
+    def of_tokens(lanes):
+        return pl.BlockSpec((None, C, lanes), lambda b, h, n: (b, at(n), h))
+
+    if q.ndim == 5:
+        dk, dv = q.shape[-1], v.shape[-1]
+        keys, values = of_chunks(dk), of_chunks(dv)
+    else:
+        dk, dv = q.shape[-1] // hk, v.shape[-1] // H
+        keys, values = of_tokens(hb * hk // H * dk), of_tokens(hb * dv)
     rows = pl.BlockSpec((None, hb, N, C), lambda b, h, n: (b, h, 0, 0))
     states = pl.BlockSpec((None, None, hb, dk, dv),
                           lambda b, h, n: (at(n), b, h, 0, 0))
-    raw = [chunks(dk), chunks(dk), chunks(dv), rows, rows, chunks(C)]
-    return (B, H // hb, N), raw, states
+    raw = [keys, keys, values, rows, rows, of_chunks(C)]
+    return (B, H // hb, N), raw, states, (hb, dk, dv)
 
 
 def _rows(gate):
@@ -443,9 +541,10 @@ def _rows(gate):
     return jnp.swapaxes(gate, 1, 2)
 
 
-@functools.partial(jax.jit, static_argnames=("keep", "hb", "interpret"))
-def _kernel_fwd(q, k, v, gamma, beta, inv, *, keep, hb, interpret):
-    """-> [``o`` [B, N, H, C, dv]], and with ``keep`` the state every
+@functools.partial(jax.jit,
+                   static_argnames=("keep", "hk", "hb", "interpret"))
+def _kernel_fwd(q, k, v, gamma, beta, inv, *, keep, hk, hb, interpret):
+    """-> [``o``, as ``v`` lies], and with ``keep`` the state every
     chunk started from, [N, B, H, dk, dv] float32. Jitted on its own:
     every site that enters it with these shapes calls ONE lowered
     function, so a program pays a Mosaic lowering a form and not one a
@@ -454,24 +553,26 @@ def _kernel_fwd(q, k, v, gamma, beta, inv, *, keep, hb, interpret):
     this function; the call site's (its scope, its phase) stands before
     it only where the compiler inlines the call."""
     with scope("hvd.gdn.core"):
-        B, N, H, _, dk = q.shape
-        grid, raw, states = _specs(q, v, hb, lambda n: n)
+        B, N, H = inv.shape[:3]
+        grid, raw, states, state = _specs(q, v, inv, hk, hb, lambda n: n)
         out_shape = [jax.ShapeDtypeStruct(v.shape, v.dtype)] + [
-            jax.ShapeDtypeStruct((N, B, H, dk, v.shape[-1]), F32)] * keep
-        return _call("hvd_gdn_rule_fwd", _fwd_kernel, hb,
+            jax.ShapeDtypeStruct((N, B, H) + state[1:], F32)] * keep
+        return _call("hvd_gdn_rule_fwd", _fwd_kernel, state,
                      (q, k, v, _rows(gamma), _rows(beta), inv), grid, raw,
                      [raw[2]] + [states] * keep, out_shape, interpret)
 
 
-@functools.partial(jax.jit, static_argnames=("hb", "interpret"))
-def _kernel_bwd(q, k, v, gamma, beta, inv, states, do, *, hb, interpret):
+@functools.partial(jax.jit, static_argnames=("hk", "hb", "interpret"))
+def _kernel_bwd(q, k, v, gamma, beta, inv, states, do, *, hk, hb,
+                interpret):
     """-> the six gradients, in their operands' shapes and dtypes."""
     with scope("hvd.gdn.core"):
-        N = q.shape[1]
-        grid, raw, kept = _specs(q, v, hb, lambda n: N - 1 - n)
+        N = inv.shape[1]
+        grid, raw, kept, state = _specs(q, v, inv, hk, hb,
+                                        lambda n: N - 1 - n)
         gates = (_rows(gamma), _rows(beta))
         *grads, dgamma, dbeta = _call(
-            "hvd_gdn_rule_bwd", _bwd_kernel, hb,
+            "hvd_gdn_rule_bwd", _bwd_kernel, state,
             (q, k, v, *gates, inv, states, do.astype(q.dtype)), grid,
             raw + [kept, raw[2]], [raw[i] for i in (0, 1, 2, 5, 3, 4)],
             [jax.ShapeDtypeStruct(x.shape, x.dtype)
@@ -480,25 +581,50 @@ def _kernel_bwd(q, k, v, gamma, beta, inv, states, do, *, hb, interpret):
         return dq, dk, dv, _rows(dgamma), _rows(dbeta), dinv
 
 
-def _step(q):
-    """What a grid step takes of these operands, and how it runs."""
-    return {"hb": _pick_block(q.shape[2], HEADS_A_STEP),
-            "interpret": _INTERPRET}
+def _chunk_major_step(heads):
+    """The heads a grid step takes of chunk-major operands."""
+    return _pick_block(heads, HEADS_A_STEP)
 
 
-@jax.custom_vjp
-def _kernel_rule(q, k, v, gamma, beta, inv):
-    """``_scan_rule`` on the kernel pair: same operands, same ``o``."""
-    return _kernel_fwd(q, k, v, gamma, beta, inv, keep=False, **_step(q))[0]
+def _token_major_step(hk, hv, dk, dv):
+    """The value heads a grid step takes of token-major operands: the
+    largest divisor of the heads under ``HEADS_A_STEP`` that is whole
+    key heads' value heads and whose strips, ``(hb / r) dk`` of ``q``
+    and ``k`` and ``hb dv`` of ``v``, are whole lane tiles, so that a
+    head is a lane slice on a tile's edge. Widths with no such step (96
+    and 192 wide on 30 heads: heads of 96 want steps of four): ALL the
+    heads, a block as wide as the array being legal whatever its lanes
+    (a head is then a lane window wherever it falls), where their state
+    is small enough to live in VMEM (``_WHOLE_WIDTH_STATE``); else None,
+    the chunk-major blocks."""
+    r = hv // hk
+    whole = [h for h in range(r, min(hv, HEADS_A_STEP) + 1, r)
+             if hv % h == 0 and h // r * dk % _LANES == 0
+             and h * dv % _LANES == 0]
+    if whole:
+        return max(whole)
+    state = 4 * hv * -(-dk // _SUBLANES) * _SUBLANES \
+        * -(-dv // _LANES) * _LANES
+    return hv if state <= _WHOLE_WIDTH_STATE else None
 
 
-def _kernel_rule_fwd(q, k, v, gamma, beta, inv):
-    o, states = _kernel_fwd(q, k, v, gamma, beta, inv, keep=True, **_step(q))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _kernel_rule(hk, hb, q, k, v, gamma, beta, inv):
+    """``_scan_rule`` on the kernel pair, ``hb`` value heads a step:
+    same operands, same ``o``, chunk-major, or ``q``, ``k`` (of ``hk``
+    key heads), ``v`` and ``o`` token-major."""
+    return _kernel_fwd(q, k, v, gamma, beta, inv, keep=False, hk=hk, hb=hb,
+                       interpret=_INTERPRET)[0]
+
+
+def _kernel_rule_fwd(hk, hb, q, k, v, gamma, beta, inv):
+    o, states = _kernel_fwd(q, k, v, gamma, beta, inv, keep=True, hk=hk,
+                            hb=hb, interpret=_INTERPRET)
     return o, (q, k, v, gamma, beta, inv, states)
 
 
-def _kernel_rule_bwd(res, do):
-    return _kernel_bwd(*res, do, **_step(res[0]))
+def _kernel_rule_bwd(hk, hb, res, do):
+    return _kernel_bwd(*res, do, hk=hk, hb=hb, interpret=_INTERPRET)
 
 
 _kernel_rule.defvjp(_kernel_rule_fwd, _kernel_rule_bwd)
@@ -535,25 +661,37 @@ def _scan_rule(q, k, v, gamma, beta, inv, decay):
 
 
 def _rule_of_chunks(q, k, v, gamma, beta, inv, decay):
-    """Everything downstream of the inverse, on operands ``[B, N, H, C,
-    ...]`` (the gates ``[B, N, H, C]`` float32) -> ``o`` [B, N, H, C,
-    dv]. Operands on a TPU: the kernel pair, which forms its own decays.
-    Elsewhere the factors in XLA and the scan."""
+    """Everything downstream of the inverse, on chunk-major operands
+    ``[B, N, H, C, ...]`` (the gates ``[B, N, H, C]`` float32) -> ``o``
+    [B, N, H, C, dv]. Operands on a TPU: the kernel pair, which forms
+    its own decays. Elsewhere the factors in XLA and the scan."""
     operands = (q, k, v, gamma, beta, inv)
     if use_pallas("gated_delta_rule", operands, _INTERPRET):
-        return _kernel_rule(*operands)
+        H = q.shape[2]
+        return _kernel_rule(H, _chunk_major_step(H), *operands)
     return _scan_rule(*operands, decay)
 
 
-def gated_delta_rule(q, k, v, g, beta, chunk=CHUNK):
-    """``o`` [B, T, H, dv] of the rule above for ``q``, ``k`` [B, T, H,
-    dk] (as they enter the rule: normalised, ``q`` scaled), ``v`` [B, T,
-    H, dv], the log-decays ``g`` <= 0 and the write strengths ``beta``
-    [B, T, H] (read as float32). ``T`` is a multiple of ``chunk``.
-    Differentiable in all five. One head a value head: a key head that
-    serves several is repeated by the caller."""
-    B, T, H, dk = q.shape
-    dv = v.shape[-1]
+def gated_delta_rule(q, k, v, g, beta, chunk=CHUNK, key_heads=None):
+    """``o`` [B, T, hv dv] of the rule above for ``q``, ``k`` [B, T, hk
+    dk] (as they enter the rule: normalised, ``q`` scaled) of
+    ``key_heads`` key heads (a value head's own where None), ``v`` [B,
+    T, hv dv], token-major as the chain round the rule leaves and takes
+    them, the log-decays ``g`` <= 0 and the write strengths ``beta`` [B,
+    T, hv] (read as float32). ``T`` is a multiple of ``chunk``.
+    Differentiable in all five. A key head serves ``hv / hk`` value
+    heads in a row and is never repeated in HBM where the kernels read
+    token-major blocks. Operands by heads, ``[B, T, H, d]`` (``o``
+    likewise): the same bytes, read as token-major."""
+    if q.ndim == 4:
+        B, T, hk, _ = q.shape
+        o = gated_delta_rule(*(x.reshape(B, T, -1) for x in (q, k, v)),
+                             g, beta, chunk, hk)
+        return o.reshape(v.shape)
+    B, T, _ = q.shape
+    hv = g.shape[-1]
+    hk = key_heads or hv
+    dk, dv, r = q.shape[-1] // hk, v.shape[-1] // hv, hv // hk
     if T % chunk:
         raise ValueError(
             f"gated_delta_rule works in chunks of {chunk} tokens: a "
@@ -561,23 +699,40 @@ def gated_delta_rule(q, k, v, g, beta, chunk=CHUNK):
             "with beta = 0 and g = 0 writes and forgets nothing)")
     N, dt = T // chunk, q.dtype
 
-    def chunked(x):
-        """[B, T, H, ...] -> [B, N, H, C, ...]"""
-        return jnp.moveaxis(x.reshape(B, N, chunk, *x.shape[2:]), 2, 3)
+    def chunked(x, *heads):
+        """[B, T, heads..] -> [B, N, heads[0], C, ..]: a copy"""
+        return jnp.moveaxis(x.reshape(B, N, chunk, *heads), 2, 3)
 
-    q, k, v, g, beta = (chunked(x) for x in (
-        q, k, v, g.astype(F32), beta.astype(F32)))
-    gamma = jnp.cumsum(g, -1)                                # [B,N,H,C]
+    g, beta = (chunked(x.astype(F32), hv) for x in (g, beta))
+    gamma = jnp.cumsum(g, -1)                                # [B,N,hv,C]
     decay = _decay(gamma)
     i, j = lax.iota(jnp.int32, chunk)[:, None], lax.iota(jnp.int32, chunk)
-    kk = _mm("bnhck,bnhjk->bnhcj", k, k)
-    a = jnp.where(i > j, beta[..., None] * kk * decay, 0.0)
+    # K K^T a KEY head, read by the r value heads it serves: a broadcast
+    # inside the product with their gates, and the systems solved as
+    # [B, N, hk, r, C, C] (reshaped to a value head's first, XLA wrote
+    # the broadcast to HBM: 1.9 ms a step at 16 key heads of 32). A key
+    # head a value head, the shapes are the value heads' own: a unit
+    # axis there cost the backward a third solve a layer.
+    kc = chunked(k, hk, dk)
+    kk, rows, fade = _mm("bnhck,bnhjk->bnhcj", kc, kc), beta[..., None], decay
+    if r > 1:
+        kk = kk[:, :, :, None]
+        rows, fade = (x.reshape(B, N, hk, r, *x.shape[3:])
+                      for x in (rows, fade))
+    a = jnp.where(i > j, rows * kk * fade, 0.0)
     eye = jnp.eye(chunk, dtype=F32)
     # (I + A)^-1 by forward substitution against the identity, float32;
     # then one operand of two MXU matmuls like any other.
     inv = lax.linalg.triangular_solve(
         a + eye, jnp.broadcast_to(eye, a.shape), left_side=True,
-        lower=True, unit_diagonal=True).astype(dt)
-    o = _rule_of_chunks(q, k, v, gamma, beta, inv, decay)
-    # [B, N, H, C, dv] -> [B, T, H, dv]
-    return jnp.moveaxis(o, 2, 3).reshape(B, T, H, dv)
+        lower=True, unit_diagonal=True).astype(dt).reshape(
+            B, N, hv, chunk, chunk)
+    hb = _token_major_step(hk, hv, dk, dv)
+    if hb and use_pallas("gated_delta_rule", (q, k, v, g, beta),
+                         _INTERPRET):
+        return _kernel_rule(hk, hb, q, k, v, gamma, beta, inv)
+    # chunk-major: a transposing copy each way, a key head repeated
+    q, kc = (jnp.repeat(x, r, axis=2) if r > 1 else x
+             for x in (chunked(q, hk, dk), kc))
+    o = _rule_of_chunks(q, kc, chunked(v, hv, dv), gamma, beta, inv, decay)
+    return jnp.moveaxis(o, 2, 3).reshape(B, T, hv * dv)

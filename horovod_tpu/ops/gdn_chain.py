@@ -66,12 +66,14 @@ the middle of block 2) a lane knows its scale by its column
 start and end inside a tile: :func:`_head_sums` sums each head of a lane
 group under its mask and lays the sum back under it, so no value is cut
 or shifted off a tile's edge. The caller cuts the strip into ``q``,
-``k``, ``v`` in front of the rule's own chunking copy and lays the three
-cotangents side by side behind the copy that un-chunks them. (XLA does
-NOT fuse the cuts into those copies: of the scope's 57.0 ms a step in
-the cell, the four kernels are 30.8; the reshapes between ``[B, T, H
-d]`` and ``[B, T, H, d]``, a relayout where ``d`` is no lane tile,
-11.9; the cuts 7.2; the ``concatenate`` 3.5: PERF.md section 7, what a
+``k``, ``v`` for the rule, which reads them as they lie, ``[B, T, H
+d]`` (``ops/gated_delta_rule.py``, all 30 heads a grid step), and lays
+the three cotangents side by side behind it. (XLA makes copies of the
+cuts: of the scope's 45.2 ms a step in the cell, the four kernels are
+30.8, the cuts 7.3, the gates 3.6, the ``concatenate`` 3.5; the
+reshapes between ``[B, T, H d]`` and ``[B, T, H, d]``, a relayout where
+``d`` is no lane tile, were 11.9 more until the rule took the strip's
+own layout: PERF.md sections 5 and 7, PR 62; what is left is what a
 rule that reads the strip by an index map would remove.) Why not the
 other two ways:
 columns padded to whole tiles a head (96 -> 128, 192 -> 256) cost a

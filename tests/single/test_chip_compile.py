@@ -387,13 +387,17 @@ def test_flash_row_statistics_cross_hbm_lane_dense(for_tpu, fn, shapes,
     assert statistic in text
 
 
-def _delta_rule_fwd_bwd(q, k, v, g, beta):
+def _delta_rule_fwd_bwd(q, k, v, g, beta, key_heads=None):
     from horovod_tpu.ops.gated_delta_rule import gated_delta_rule
 
-    return jax.grad(lambda *a: gated_delta_rule(*a).astype(F32).sum(),
-                    argnums=(0, 1, 2, 3, 4))(q, k, v, g, beta)
+    return jax.grad(lambda *a: gated_delta_rule(
+        *a, key_heads=key_heads).astype(F32).sum(),
+        argnums=(0, 1, 2, 3, 4))(q, k, v, g, beta)
 
 
+# token-major, as the chain round the rule leaves and takes them: 16 key
+# heads and 32 value heads of 128
+_RULE_KEYS, _RULE_VALUES = ((2, 8192, 2048), BF16), ((2, 8192, 4096), BF16)
 _RULE_HEAD, _RULE_GATE = ((2, 8192, 32, 128), BF16), ((2, 8192, 32), F32)
 _RULE_KERNELS = ("hvd_gdn_rule_fwd", "hvd_gdn_rule_bwd")
 _INSTRUCTION = re.compile(
@@ -421,59 +425,87 @@ def _entry_instructions(text):
 
 def test_gated_delta_rule_compiles_for_described_v5e(for_tpu):
     """Qwen3-Next's delta rule at the chip cell's size (B2 T8192, 32
-    value heads of 128), forward and backward, as the chip's compiler
-    takes it: everything downstream of the inverse is the kernel pair,
-    each by the name a device trace shows (``kernel_metadata``), and no
-    ``while`` over chunks is left; the states kept are the 128 CHUNKS'
-    ([128, 2, 32, 128, 128] float32), no operand is token-major (a scan
-    over the 8192 tokens would slice one), and the triangular systems
-    are 64 wide.
+    value heads of 128 on 16 key heads), forward and backward, as the
+    chip's compiler takes it: everything downstream of the inverse is
+    the kernel pair, each by the name a device trace shows
+    (``kernel_metadata``), and no ``while`` over chunks is left; the
+    states kept are the 128 CHUNKS' ([128, 2, 32, 128, 128] float32), no
+    operand is token-major by heads (a scan over the 8192 tokens would
+    slice one), and the triangular systems are 64 wide.
 
     What PR 49 is for: the kernels take ``q``, ``k``, ``v``, the two
     gates and the inverse, and no factor; ``qg``, ``u``, ``w``, ``kd``
-    (134 MB each) and their cotangents never cross HBM. What is left of
-    that shape outside the kernels: the chunked ``q``, ``k``, ``v``,
-    ``o``'s cotangent, and ``dk``'s two shares added (the kernel's and
-    ``K K^T``'s)."""
-    text = for_tpu(_delta_rule_fwd_bwd, _RULE_HEAD, _RULE_HEAD, _RULE_HEAD,
-                   _RULE_GATE, _RULE_GATE)
+    (134 MB each) and their cotangents never cross HBM. What PR 62 is
+    for: they take ``q``, ``k``, ``v`` and ``do`` and leave ``o``,
+    ``dq``, ``dk``, ``dv`` TOKEN-MAJOR, as the program's arguments and
+    results lie (``q`` and ``k`` at their 16 key heads): no array of a
+    chunked value head's shape is left anywhere, and of a chunked key
+    head's only ``k`` on its way into ``K K^T`` and that product's share
+    of ``dk`` on its way back."""
+    text = for_tpu(functools.partial(_delta_rule_fwd_bwd, key_heads=16),
+                   _RULE_KEYS, _RULE_KEYS, _RULE_VALUES, _RULE_GATE,
+                   _RULE_GATE)
     for name in _RULE_KERNELS:
         assert f'"kernel":"{name}"' in text, name
     assert " while(" not in text
     assert "f32[128,2,32,128,128]" in text        # the states kept
     assert "[8192,2,32," not in text              # no token-major scan
-    assert re.search(r"f32\[2,128,32,(1,)?64,64\]", text)   # (I + A)^-1
+    assert re.search(r"f32\[2,128,(32|16,2),(1,)?64,64\]", text)  # (I + A)^-1
 
-    wide, square = "bf16[2,128,32,64,128]", "bf16[2,128,32,64,64]"
-    gate, states = "f32[2,32,128,64]", "f32[128,2,32,128,128]"
+    keys, values = "bf16[2,8192,2048]", "bf16[2,8192,4096]"
+    square, gate = "bf16[2,128,32,64,64]", "f32[2,32,128,64]"
+    states = "f32[128,2,32,128,128]"
     entry, kernels = _entry_instructions(text)
     calls = {kernel: (entry[call][0],
                       [entry[x][0][0] for x in entry[call][2]])
              for kernel, call in kernels.items()}
-    raw = [wide, wide, wide, gate, gate, square]  # q k v gamma beta inv
-    assert calls["hvd_gdn_rule_fwd"] == ([wide, states], raw)
+    raw = [keys, keys, values, gate, gate, square]  # q k v gamma beta inv
+    assert calls["hvd_gdn_rule_fwd"] == ([values, states], raw)
     assert calls["hvd_gdn_rule_bwd"] == (
-        [wide, wide, wide, square, gate, gate], raw + [states, wide])
-    made = [name for name, (types, opcode, _) in entry.items()
-            if types == [wide] and opcode not in (
-                "get-tuple-element", "bitcast", "parameter")]
-    assert len(made) <= 5, made
+        [keys, keys, values, square, gate, gate], raw + [states, values])
+    assert "[2,128,32,64,128]" not in text        # a chunked value head's
+    chunked = [name for name, (types, opcode, _) in entry.items()
+               if types == ["bf16[2,128,16,64,128]"] and opcode not in (
+                   "get-tuple-element", "bitcast", "parameter")]
+    assert len(chunked) <= 3, chunked
+
+
+def test_the_rule_by_heads_compiles_for_described_v5e(for_tpu):
+    """Operands by heads, ``[B, T, H, d]`` (the benchmark's comparison
+    with the recurrence hands them over so): read as token-major, the
+    same kernel pair on the same blocks."""
+    text = for_tpu(_delta_rule_fwd_bwd, _RULE_HEAD, _RULE_HEAD, _RULE_HEAD,
+                   _RULE_GATE, _RULE_GATE)
+    entry, kernels = _entry_instructions(text)
+    wide = "bf16[2,8192,4096]"
+    assert entry[kernels["hvd_gdn_rule_fwd"]][0][0] == wide
+    assert entry[kernels["hvd_gdn_rule_bwd"]][0][:3] == [wide] * 3
 
 
 def test_the_rule_at_two_widths_compiles_for_described_v5e(for_tpu):
     """Olmo-Hybrid's delta rule at the chip cell's size (B2 T8192, 30
-    heads, keys 96 and values 192 wide: neither a lane tile, each padded
-    to the next in every tiled buffer), forward and backward: the kernel
-    pair by name, six heads a step, the states kept the 128 chunks' at
-    96 x 192, no scan over tokens."""
-    key, value = ((2, 8192, 30, 96), BF16), ((2, 8192, 30, 192), BF16)
+    heads, keys 96 and values 192 wide: neither a lane tile), forward
+    and backward, token-major as the chain leaves its operands: 30
+    heads of 96 have no step of whole lane tiles, so the kernel pair by
+    name takes the WHOLE width a step (a head a lane window wherever it
+    falls, the VMEM asked for by name: unasked, the chip's compiler
+    refuses the forward at 21 MiB of 16), the states kept the 128
+    chunks' at 96 x 192, no scan over tokens and no chunk-major copy of
+    an operand."""
+    key, value = ((2, 8192, 30 * 96), BF16), ((2, 8192, 30 * 192), BF16)
     gate = ((2, 8192, 30), F32)
-    text = for_tpu(_delta_rule_fwd_bwd, key, key, value, gate, gate)
+    text = for_tpu(functools.partial(_delta_rule_fwd_bwd, key_heads=30),
+                   key, key, value, gate, gate)
     for name in _RULE_KERNELS:
         assert f'"kernel":"{name}"' in text, name
     assert " while(" not in text
     assert "f32[128,2,30,96,192]" in text         # the states kept
     assert "[8192,2,30," not in text              # no token-major scan
+    entry, kernels = _entry_instructions(text)
+    assert entry[kernels["hvd_gdn_rule_fwd"]][0][0] == "bf16[2,8192,5760]"
+    assert entry[kernels["hvd_gdn_rule_bwd"]][0][:3] == [
+        "bf16[2,8192,2880]", "bf16[2,8192,2880]", "bf16[2,8192,5760]"]
+    assert "[2,128,30,64,192]" not in text        # a chunked value head's
 
 
 def test_three_layers_of_the_rule_lower_each_kernel_once(for_tpu, v5e_chip):
@@ -489,11 +521,13 @@ def test_three_layers_of_the_rule_lower_each_kernel_once(for_tpu, v5e_chip):
 
     def loss(q, k, v, g, beta):
         for _ in range(3):
-            v = jax.checkpoint(gated_delta_rule)(q, k, v, g, beta)
+            v = jax.checkpoint(functools.partial(
+                gated_delta_rule, key_heads=16))(q, k, v, g, beta)
         return v.astype(F32).sum()
 
     args = [jax.ShapeDtypeStruct(s, d, sharding=v5e_chip)
-            for s, d in (_RULE_HEAD,) * 3 + (_RULE_GATE,) * 2]
+            for s, d in (_RULE_KEYS,) * 2 + (_RULE_VALUES,)
+            + (_RULE_GATE,) * 2]
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
         *args).as_text()
     assert [text.count(name) for name in _RULE_KERNELS] == [2, 1]

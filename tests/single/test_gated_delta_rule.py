@@ -243,7 +243,7 @@ def test_a_step_takes_a_divisor_of_the_heads(kernels):
     q = jnp.zeros((1, 1, 6, 16, 16))
     for want, takes in ((8, 6), (4, 3), (2, 2), (1, 1)):
         kernels(want)
-        assert module._step(q) == {"hb": takes, "interpret": True}
+        assert module._chunk_major_step(q.shape[2]) == takes
 
 
 @pytest.mark.parametrize("chunks,heads,shape,write", [
@@ -260,7 +260,7 @@ def test_the_rule_on_the_kernel_pair_is_the_recurrence(
     beside ``dv`` 24, three heads."""
     kernels(heads)
     if shape[1] == 30:
-        assert module._step(jnp.zeros((1, 1, 30, 16, 96)))["hb"] == 6
+        assert module._chunk_major_step(30) == 6
     # not ``_GRADS``: that jit has traced the rule on the scan
     readings = jax.jit(jax.grad(_weighted(gated_delta_rule),
                                 argnums=(0, 1, 2, 3, 4), has_aux=True))
@@ -302,3 +302,199 @@ def test_interpret_mode_on_a_tpu_is_refused(kernels, monkeypatch):
     operands = _chunk_operands(1, 1, 2, 16, 16, 16, jnp.float32)[:6]
     with pytest.raises(RuntimeError, match="interpret mode"):
         module._rule_of_chunks(*operands, module._decay(operands[3]))
+
+
+# ---------------------------------------------------------------------
+# Token-major operands: ``q``, ``k`` [B, T, hk dk], ``v``, ``o`` [B, T,
+# hv dv], a head a lane slice of a block, a key head indexed.
+# ---------------------------------------------------------------------
+
+WIDE = 128      # a lane tile: the narrowest head with a token-major step
+
+
+def _token_major(dtype, hk, hv, t=2 * CHUNK, d=WIDE, seed=0, dv=None):
+    """``_operands`` at ``hk`` key heads, rounded to ``dtype``, as the
+    chain hands them over: (``q``, ``k`` [1, T, hk d], ``v`` [1, T, hv
+    dv], ``g``, ``beta`` [1, T, hv] float32, a weight for ``o``)."""
+    dv = dv or d
+    q, k, _, _, _, _ = _operands(t, 0.3, "mixed", seed, (1, hk, d, dv))
+    _, _, v, g, beta, w = _operands(t, 0.3, "mixed", seed, (1, hv, d, dv))
+    return tuple(x.astype(dtype).reshape(1, t, -1)
+                 for x in (q, k, v)) + (g, beta, w.astype(dtype).reshape(
+                     1, t, -1))
+
+
+def _token_major_readings(hk, *operands):
+    """(``o``, the five gradients of ``sum(o * w)``): a jit a call, so
+    that each traces the carrier the module stands on."""
+    def f(q, k, v, g, beta, w):
+        o = gated_delta_rule(q, k, v, g, beta, key_heads=hk)
+        return jnp.sum(o.astype(jnp.float32) * w.astype(jnp.float32)), o
+
+    grads, o = jax.jit(jax.grad(f, argnums=(0, 1, 2, 3, 4),
+                                has_aux=True))(*operands)
+    return (o,) + grads
+
+
+@pytest.fixture
+def blocks_seen(monkeypatch):
+    """-> a list that every ``_kernel_fwd`` from then on appends its
+    ``q``'s rank (3 token-major, 5 chunk-major) and heads a step to."""
+    seen, kernel_fwd = [], module._kernel_fwd
+    monkeypatch.setattr(module, "_kernel_fwd", lambda *a, **kw: (
+        seen.append((a[0].ndim, kw["hb"])), kernel_fwd(*a, **kw))[1])
+    return seen
+
+
+def _by_value_heads(operands, hk, hv):
+    """Token-major operands as the recurrence takes them: float32 by
+    heads, a key head REPEATED for the value heads it serves."""
+    q, k, v, g, beta, w = (x.astype(jnp.float32) for x in operands)
+    b, t, _ = q.shape
+    q, k = (jnp.repeat(x.reshape(b, t, hk, -1), hv // hk, axis=2)
+            for x in (q, k))
+    return q, k, v.reshape(b, t, hv, -1), g, beta, w.reshape(b, t, hv, -1)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("hk,hv,heads", [(2, 2, 2), (2, 4, 2), (1, 2, 8)],
+                         ids=["a-key-head-a-value-head", "one-serves-two",
+                              "one-serves-two-in-one-step"])
+def test_the_token_major_pair_is_the_scan_and_the_recurrence(
+        kernels, dtype, hk, hv, heads):
+    """The kernel pair on token-major blocks (a chunk's heads lane
+    slices of ``[C, hb d]``, a key head read once for its value heads,
+    ``dq`` and ``dk`` summed over them in the kernel) against the scan
+    on the same operands chunked and repeated by a copy: in float32
+    ``o`` to the last bit and the gradients to where float32 sums differ
+    in order; in bfloat16 to a step of it (128 products a sum: the CPU's
+    batched matmul and the interpreter's add them in another order, and
+    a factor now and then rounds the other way; the repeated form rounds
+    each value head's share of ``dq``, ``dk`` and then adds). And
+    against the recurrence token by token on the repeated operands."""
+    operands = _token_major(dtype, hk, hv)
+    with jax.default_matmul_precision(
+            "highest" if dtype == jnp.float32 else "default"):
+        ref = _token_major_readings(hk, *operands)
+        kernels(heads)
+        assert module._token_major_step(hk, hv, WIDE, WIDE) == min(heads, hv)
+        got = _token_major_readings(hk, *operands)
+        slow = _readings(token_by_token, *_by_value_heads(operands, hk, hv))
+    b, t, _ = operands[0].shape
+    for name, a, b_, c in zip(NAMES, got, ref, slow):
+        assert a.dtype == b_.dtype and a.shape == b_.shape, name
+        a, b_ = a.astype(jnp.float32), b_.astype(jnp.float32)
+        scale = float(jnp.max(jnp.abs(b_)))
+        err = float(jnp.max(jnp.abs(a - b_)))
+        if dtype == jnp.float32:
+            step = 0.0 if name == "out" else 1e-4 if name in (
+                "dg", "dbeta") else 2e-6
+        else:
+            step = 1e-3 if name in ("dg", "dbeta") else 2.0 ** -7
+        assert err <= step * scale, (name, err)
+        # the recurrence's gradients of the REPEATED q, k: a key head's
+        # is the sum over the value heads it serves
+        c = c.reshape(b, t, hv, -1)
+        if name in ("dq", "dk"):
+            c = c.reshape(b, t, hk, hv // hk, -1).sum(3)
+        err = float(jnp.max(jnp.abs(a - c.reshape(a.shape)))
+                    / jnp.max(jnp.abs(c)))
+        assert err < (TOL if dtype == jnp.float32 else 3e-2), (name, err)
+
+
+@pytest.mark.parametrize("hk,hv,dk,dv", [
+    (2, 2, WIDE, WIDE), (1, 2, WIDE, WIDE),
+    # no step of whole lane tiles: the whole width, three heads' lane
+    # windows of 16 and 24 wherever they fall
+    (3, 3, 16, 24)],
+    ids=lambda x: str(x))
+def test_both_block_layouts_give_the_same_bits(kernels, monkeypatch,
+                                               blocks_seen, hk, hv, dk, dv):
+    """One set of kernel bodies behind two block layouts: the
+    token-major blocks run at a lane tile's width and, all the heads a
+    step, at widths with no step of whole tiles; told that neither fits
+    (the whole width's state too large), the same operands go
+    chunk-major by a copy (a key head repeated) and ``o`` is the same to
+    the last bit, the gradients to a step of bfloat16 (``dq``, ``dk``:
+    summed before the rounding or after)."""
+    operands = _token_major(jnp.bfloat16, hk, hv, d=dk, dv=dv, seed=1)
+    kernels(2)
+    step = 2 if dk == WIDE else hv
+    assert module._token_major_step(hk, hv, dk, dv) == step
+    got = _token_major_readings(hk, *operands)
+    assert set(blocks_seen) == {(3, step)}
+    monkeypatch.setattr(module, "_token_major_step", lambda *a: None)
+    ref = _token_major_readings(hk, *operands)
+    assert {rank for rank, _ in blocks_seen} == {3, 5}
+    for name, a, b in zip(NAMES, got, ref):
+        assert a.dtype == b.dtype and a.shape == b.shape, name
+        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+        err = float(jnp.max(jnp.abs(a - b)))
+        step = 2.0 ** -7 if name in ("dq", "dk") else 2e-6
+        assert err <= (0.0 if name in ("out", "dv") else
+                       step * float(jnp.max(jnp.abs(b)))), (name, err)
+
+
+@pytest.mark.parametrize("sizes,heads,step", [
+    # whole lane tiles in both strips: the largest such divisor
+    ((16, 32, 128, 128), 8, 8), ((2, 4, 64, 128), 8, 4),
+    ((64, 64, 96, 192), 8, 8), ((1, 4, 128, 128), 8, 4),
+    # none (heads of 96 want steps of four and 30 has no such divisor;
+    # an odd number of 64-wide key heads; fewer heads a step than a key
+    # head serves): the whole width
+    ((30, 30, 96, 192), 8, 30), ((3, 3, 16, 24), 2, 3),
+    ((2, 4, 64, 128), 2, 4), ((16, 32, 128, 128), 1, 32),
+    # ... but where its state (46 x 96 x 256 float32) is over 4 MiB:
+    # the chunk-major blocks
+    ((46, 46, 96, 192), 8, None)],
+    ids=lambda s: "x".join(map(str, s)) if isinstance(s, tuple) else None)
+def test_the_block_layout_is_read_off_the_operands_shapes(
+        kernels, sizes, heads, step):
+    kernels(heads)
+    assert module._token_major_step(*sizes) == step
+
+
+def test_a_width_with_no_whole_tile_step_takes_the_whole_width(
+        kernels, monkeypatch, blocks_seen):
+    """Three heads 16 and 24 wide from token-major operands: ONE grid
+    step a chunk takes them all, and ``o`` is the scan's bit for bit;
+    with the whole width's state ruled too large, the chunk-major
+    blocks, two heads a step, and the same bits again."""
+    hv, dk, dv = 3, 16, 24
+    kernels(2)
+    q, k, v, g, beta, _ = _operands(2 * CHUNK, 0.3, "mixed",
+                                    shape=(1, hv, dk, dv))
+    flat = [x.astype(jnp.bfloat16).reshape(1, 2 * CHUNK, -1)
+            for x in (q, k, v)]
+    whole = jax.jit(lambda *a: gated_delta_rule(*a))(*flat, g, beta)
+    assert blocks_seen == [(3, 3)]
+    assert whole.shape == (1, 2 * CHUNK, hv * dv)
+    monkeypatch.setattr(module, "_WHOLE_WIDTH_STATE", 0)
+    chunked = jax.jit(lambda *a: gated_delta_rule(*a))(*flat, g, beta)
+    assert blocks_seen == [(3, 3), (5, 1)]
+    monkeypatch.setattr(module, "_INTERPRET", False)
+    scan = jax.jit(lambda *a: gated_delta_rule(*a))(*flat, g, beta)
+    for got in (whole, chunked):
+        assert float(jnp.max(jnp.abs(got.astype(jnp.float32)
+                                     - scan.astype(jnp.float32)))) == 0.0
+
+
+def test_operands_by_heads_are_read_as_token_major():
+    """``[B, T, H, d]`` holds the same bytes as ``[B, T, H d]``: the
+    rule by heads (the recurrence's layout, and the benchmark's) is the
+    rule token-major, reshaped."""
+    q, k, v, g, beta, _ = _operands(CHUNK, 0.3, "mixed")
+    flat = [x.reshape(2, CHUNK, -1) for x in (q, k, v)]
+    by_heads = jax.jit(gated_delta_rule)(q, k, v, g, beta)
+    assert by_heads.shape == v.shape
+    token_major = jax.jit(gated_delta_rule)(*flat, g, beta)
+    assert token_major.shape == flat[2].shape
+    assert float(jnp.max(jnp.abs(by_heads.reshape(flat[2].shape)
+                                 - token_major))) == 0.0
+    # fewer key heads than value heads: ``key_heads`` says how many
+    served = jax.jit(lambda *a: gated_delta_rule(*a, key_heads=1))(
+        flat[0][..., :16], flat[1][..., :16], flat[2], g, beta)
+    ref = token_by_token(*(jnp.repeat(x[:, :, :1], 3, axis=2)
+                           for x in (q, k)), v, g, beta)
+    assert float(jnp.max(jnp.abs(served.reshape(v.shape) - ref))) < 1e-5

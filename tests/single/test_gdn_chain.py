@@ -216,10 +216,13 @@ def _l2(got, ref):
 
 @pytest.mark.parametrize("sizes, more", [
     ((2, 4, 16, 16), {}),
+    # a lane tile wide: the rule's kernels read the chain's ``[B, T, H
+    # d]`` as it lies, one key head serving two value heads
+    ((1, 2, 128, 128), {}),
     # keys 96 and values 192 wide on the strip, write strengths to 2,
     # no norm of the mixer's input
     ((2, 2, 96, 192), {"linear_beta_max": 2.0, "post_norm": "only"})],
-    ids=["one-width", "two-widths"])
+    ids=["one-width", "a-lane-tile-wide", "two-widths"])
 def test_the_mixer_is_the_same_on_both_carriers(kernels, monkeypatch, sizes,
                                                 more):
     """``_gated_delta_net`` whole in float32, values and the gradients
@@ -230,6 +233,8 @@ def test_the_mixer_is_the_same_on_both_carriers(kernels, monkeypatch, sizes,
     ref = _mixer_readings(cfg, lp, x)
     kernels(16, 2, 8)
     monkeypatch.setattr(gated_delta_rule, "_INTERPRET", True)
+    # eight heads a step a lane tile wide, else the whole width
+    assert gated_delta_rule._token_major_step(*sizes) == sizes[1]
     got = _mixer_readings(cfg, lp, x)
     for name in ref:
         _close(got[name], ref[name], F32, name)
@@ -295,3 +300,116 @@ def test_interpret_mode_on_a_tpu_is_refused(kernels, monkeypatch):
     monkeypatch.setattr(_platform, "operand_platform", lambda *a: "tpu")
     with pytest.raises(RuntimeError, match="interpret mode"):
         module.on_kernels(jnp.zeros((1, 8, 8)), 16, 16)
+
+
+# ---------------------------------------------------------------------
+# The seam between the chain and the rule: ``[B, T, H d]`` on both sides.
+# ---------------------------------------------------------------------
+
+def _seam_operands(hk, hv, d, T=128, seed=3):
+    qkvz, taps, _ = _stage_one(F32, 1, T, hk, hv, d, seed=seed)
+    ks = jax.random.split(jax.random.PRNGKey(seed + 1), 4)
+    g = -0.3 * jax.random.uniform(ks[0], (1, T, hv), minval=0.5, maxval=1.5)
+    beta = jax.nn.sigmoid(2 * jax.random.normal(ks[1], (1, T, hv)))
+    gain = 1.0 + 0.1 * jax.random.normal(ks[2], (d,))
+    weight = jax.random.normal(ks[3], (1, T, hv * d))
+    return (qkvz, taps, g, beta, gain), weight
+
+
+def test_chain_in_rule_chain_out_with_no_reshape_between(kernels,
+                                                         monkeypatch):
+    """``chain_in`` -> the rule -> ``chain_out``, each taking what the
+    one before leaves, ``[B, T, H d]``, and a key head never repeated,
+    against the expression it replaces: ``_gdn_chain_in`` by heads, the
+    key heads repeated, the rule by heads on the scan, ``_gdn_chain_out``
+    by heads. Values and the gradients of all five operands, float32,
+    one key head serving two value heads a lane tile wide."""
+    hk, hv, d, eps = 1, 2, 128, 1e-6
+    operands, weight = _seam_operands(hk, hv, d)
+
+    def seam(qkvz, taps, g, beta, gain):
+        q, k, v, z = module.chain_in(qkvz, taps, hk, hv, d, d)
+        o = gated_delta_rule.gated_delta_rule(q, k, v, g, beta,
+                                              key_heads=hk)
+        return module.chain_out(o, z, gain, eps)
+
+    def expression(qkvz, taps, g, beta, gain):
+        q, k, v, z = llama._gdn_chain_in(qkvz, taps, hk, hv, d, d)
+        q, k = (jnp.repeat(a, hv // hk, axis=2) for a in (q, k))
+        o = gated_delta_rule.gated_delta_rule(q, k, v, g, beta)
+        return llama._gdn_chain_out(o, z, gain, eps).reshape(weight.shape)
+
+    def readings(f):
+        def loss(*x):
+            out = f(*x)
+            return jnp.sum(out * weight), out
+        (_, out), grads = jax.jit(jax.value_and_grad(
+            loss, tuple(range(5)), has_aux=True))(*operands)
+        return (out,) + grads
+
+    with jax.default_matmul_precision("highest"):
+        ref = readings(expression)
+        kernels(16, 2, 8)
+        monkeypatch.setattr(gated_delta_rule, "_INTERPRET", True)
+        assert gated_delta_rule._token_major_step(hk, hv, d, d) == 2
+        got = readings(seam)
+    for name, a, b in zip(("out", "d qkvz", "d taps", "d g", "d beta",
+                           "d gain"), got, ref):
+        _close(a, b, F32, name)
+
+
+def _eqns(jaxpr):
+    """Every equation of a traced program, the bodies of its calls,
+    checkpoints and custom derivatives too, but not a kernel's own."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+def test_the_mixer_at_qwen3next_heads_copies_no_operand_of_the_rule(
+        kernels, monkeypatch):
+    """The traced program of two ``linear_attention`` mixers at
+    Qwen3-Next's head geometry (16 key heads serving 32 value heads, 128
+    wide; a short sequence), forward and gradient, kernels on: nothing
+    repeats or broadcasts ``q`` or ``k`` to the value heads, nothing
+    transposes an array of ``v``'s or ``o``'s size, and of ``q``'s size
+    only ``k`` on its way into ``K K^T`` (part 1 of the rule) and that
+    product's share of ``dk`` on its way back; the rule's kernels are
+    ONE lowered function a form (the forward keeping its states and
+    not, the backward) however many layers and phases call them."""
+    hk, hv, d, T = 16, 32, 128, 128
+    cfg, lp, _ = _mixer("bfloat16", (hk, hv, d, d))
+    x = jnp.zeros((1, T, 32), BF16)
+    kernels(64, 8, 32)
+    monkeypatch.setattr(gated_delta_rule, "_INTERPRET", True)
+
+    def two_layers(x, lp):
+        for _ in range(2):
+            x = x + llama._gated_delta_net(x, lp, cfg, None, None,
+                                           jax.checkpoint)
+        return jnp.sum(x.astype(F32))
+
+    keys, values = T * hk * d, T * hv * d
+    # a layer: ``k`` forward, again under the checkpoint, ``dk`` back
+    for program, k_moves in ((two_layers, 2),
+                             (jax.grad(two_layers, (0, 1)), 2 * 3)):
+        moved = {keys: 0, values: 0}
+        for eqn in _eqns(jax.make_jaxpr(program)(x, lp).jaxpr):
+            name = eqn.primitive.name
+            sizes = [v.aval.size for v in eqn.outvars]
+            if name == "transpose" and sizes[0] in moved:
+                moved[sizes[0]] += 1
+            # a key head laid out for each value head it serves
+            assert not (name in ("broadcast_in_dim", "gather", "concatenate")
+                        and sizes[0] == T * hv * d
+                        and eqn.outvars[0].aval.dtype == BF16), eqn
+        assert moved == {keys: k_moves, values: 0}, moved
+    text = jax.jit(jax.grad(two_layers, (0, 1))).lower(x, lp).as_text()
+    assert text.count("func.func private @_kernel_fwd") == 2
+    assert text.count("func.func private @_kernel_bwd") == 1
+    # (the last layer's own forward is dead code under a linear loss)
+    assert text.count("call @_kernel_fwd") >= 3
+    assert text.count("call @_kernel_bwd") == 2

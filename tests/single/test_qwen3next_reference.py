@@ -136,7 +136,8 @@ def test_remat_attn_ffn_checkpoints_a_linear_mixer_in_two_stages(
     """What each checkpoint of the forward program hands on: under
     "attn" a layer at a time (the stream and the aux term); under
     "attn/ffn" a linear_attention layer in three, what the rule reads
-    (q, k at their key heads, v, z, g, beta), the mixer's output, the
+    (q, k at their key heads and v token-major, z, g, beta), the mixer's
+    output, the
     FFN's, and the attention layer in two."""
     cfg = _cfg(remat=remat)
     params = jax.eval_shape(lambda k: llama_init(cfg, k),
@@ -147,7 +148,7 @@ def test_remat_attn_ffn_checkpoints_a_linear_mixer_in_two_stages(
     assert [len(e.outvars) for e in found] == regions
     if remat == "attn/ffn":
         assert [v.aval.shape for v in found[0].outvars] == [
-            (2, 64, 2, 8), (2, 64, 2, 8), (2, 64, 4, 16), (2, 64, 4, 16),
+            (2, 64, 16), (2, 64, 16), (2, 64, 64), (2, 64, 4, 16),
             (2, 64, 4), (2, 64, 4)]
 
 
