@@ -1333,7 +1333,10 @@ def _prepared_qkv(h, lp, c, positions, rope, mesh):
 
 def _one_key_for_all_heads(k_r, heads):
     """Latent attention's rotated key ``k_r`` [B, T, 1, dr] as every
-    head reads it: the SAME ``dr`` values."""
+    head reads it: the SAME ``dr`` values. The expressions' form, off the
+    TPU; on the chip ``ops/mla_prep.py``'s forward kernel stores the one
+    rotated ``k_r`` behind every head's ``k_n``, and its backward sums
+    ``dk``'s rotated slices over the heads."""
     return jnp.broadcast_to(k_r, (*k_r.shape[:2], heads, k_r.shape[3]))
 
 
@@ -1352,10 +1355,16 @@ def _latent_attention(h, lp, c, positions, mesh, seq_axis):
     modes save of the projections (1,344 values a token at Xing4's
     sizes, where ``q``, ``k``, ``v`` are 16,384), so that the backward
     pass re-runs the up-projections and not the down-projections.
-    Scopes: the five matmuls and the two latent norms ``hvd.mla.proj``,
-    the rotation ``hvd.attn.rope``, the assembly of ``q`` and ``k`` and
-    the attention ``hvd.mla.core``."""
-    from horovod_tpu.ops.flash_attention import flash_attention
+    Scopes: the five matmuls and the two latent norms ``hvd.mla.proj``;
+    the rotation, the assembly of ``q`` and ``k`` and the attention
+    ``hvd.mla.core``: on the chip ONE kernel pair, ``hvd_mla_prep_fwd`` /
+    ``_bwd``, takes the up-projections' outputs as the matmuls leave
+    them and writes ``q``, ``k``, ``v`` head-major for the flash kernels
+    (``ops/mla_prep.py``; ``mla_prep.on_kernels`` says where). Elsewhere
+    the expressions below run, the rotation under ``hvd.attn.rope``."""
+    from horovod_tpu.ops import mla_prep
+    from horovod_tpu.ops.flash_attention import (
+        flash_attention, flash_attention_head_major)
 
     if _over_sequence(mesh, seq_axis):
         raise ValueError(
@@ -1365,8 +1374,7 @@ def _latent_attention(h, lp, c, positions, mesh, seq_axis):
     b, t, _ = h.shape
     H, dn, rkv = c.n_heads, c.qk_nope_head_dim, c.kv_lora_rank
     freqs, mult, scale = c.yarn()
-    turn = partial(_rope, positions=positions, theta=None,
-                   freqs=jnp.asarray(freqs), mult=mult)
+    seam = mla_prep.on_kernels(h, H, dn, c.qk_rope_head_dim, c.v_head_dim)
     with scope("hvd.mla.proj"):
         c_q = _rms(h @ lp["wq_a"].astype(dt), lp["q_a_norm"].astype(dt),
                    c.norm_eps)
@@ -1375,18 +1383,28 @@ def _latent_attention(h, lp, c, positions, mesh, seq_axis):
         c_q = checkpoint_name(c_q, "mla_c_q")
         c_kv = checkpoint_name(c_kv, "mla_c_kv")
         k_r = checkpoint_name(k_r, "mla_k_r")
-        q = (c_q @ lp["wq_b"].astype(dt)).reshape(b, t, H, c.qk_head_dim)
-        kv = (c_kv @ lp["wkv_b"].astype(dt)).reshape(
-            b, t, H, dn + c.v_head_dim)
-    q_r, k_r = turn(q[..., dn:]), turn(k_r[:, :, None, :])
-    with scope("hvd.mla.core"):
-        q = jnp.concatenate([q[..., :dn], q_r], -1)
-        k = jnp.concatenate([kv[..., :dn],
-                             _one_key_for_all_heads(k_r, H)], -1)
-        blocks = {"block_q": c.flash_block, "block_k": c.flash_block} \
-            if c.flash_block else {}
-        o = flash_attention(q, k, kv[..., dn:], causal=True,
-                            mesh=_kernel_mesh(mesh), scale=scale, **blocks)
+        q = c_q @ lp["wq_b"].astype(dt)
+        kv = c_kv @ lp["wkv_b"].astype(dt)
+        if not seam:
+            q = q.reshape(b, t, H, c.qk_head_dim)
+            kv = kv.reshape(b, t, H, dn + c.v_head_dim)
+    attend = {"causal": True, "mesh": _kernel_mesh(mesh), "scale": scale}
+    if c.flash_block:
+        attend.update(block_q=c.flash_block, block_k=c.flash_block)
+    if seam:
+        with scope("hvd.mla.core"):
+            o = flash_attention_head_major(
+                *mla_prep.mla_prep(q, kv, k_r, positions, freqs, mult, dn,
+                                   attend["mesh"]), **attend)
+    else:
+        turn = partial(_rope, positions=positions, theta=None,
+                       freqs=jnp.asarray(freqs), mult=mult)
+        q_r, k_r = turn(q[..., dn:]), turn(k_r[:, :, None, :])
+        with scope("hvd.mla.core"):
+            q = jnp.concatenate([q[..., :dn], q_r], -1)
+            k = jnp.concatenate([kv[..., :dn],
+                                 _one_key_for_all_heads(k_r, H)], -1)
+            o = flash_attention(q, k, kv[..., dn:], **attend)
     with scope("hvd.mla.proj"):
         return o.reshape(b, t, -1) @ lp["wo"].astype(dt)
 

@@ -298,17 +298,18 @@ def _bwd(yq, yk, gq, gk, table, dq, dk, dv, *, eps, bt, interpret):
                             for dg, g in zip(out[3:], (gq, gk))))
 
 
-def _step(T):
-    """Whole packed tiles of tokens a step (``on_kernels`` saw to it
-    that the sequence is made of them)."""
+def _step(T, tokens, interpret):
+    """Whole packed tiles of tokens a step, ``tokens`` at most
+    (``on_kernels`` saw to it that the sequence is made of them)."""
     return {"bt": _PACKED * _pick_block(T // _PACKED,
-                                        max(TOKENS_A_STEP // _PACKED, 1)),
-            "interpret": _INTERPRET}
+                                        max(tokens // _PACKED, 1)),
+            "interpret": interpret}
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
 def _kernel(yq, yk, yv, gq, gk, table, eps):
-    return _fwd(yq, yk, yv, gq, gk, table, eps=eps, **_step(yq.shape[1]))
+    return _fwd(yq, yk, yv, gq, gk, table, eps=eps,
+                **_step(yq.shape[1], TOKENS_A_STEP, _INTERPRET))
 
 
 def _kernel_fwd(yq, yk, yv, gq, gk, table, eps):
@@ -320,8 +321,9 @@ def _kernel_fwd(yq, yk, yv, gq, gk, table, eps):
 
 def _kernel_bwd(eps, res, grads):
     yq, yk, gq, gk, table = res
-    dyq, dyk, dyv, dgq, dgk = _bwd(yq, yk, gq, gk, table, *grads, eps=eps,
-                                   **_step(table.shape[1]))
+    dyq, dyk, dyv, dgq, dgk = _bwd(
+        yq, yk, gq, gk, table, *grads, eps=eps,
+        **_step(table.shape[1], TOKENS_A_STEP, _INTERPRET))
     # The table is made of positions: nothing reads its cotangent.
     return dyq, dyk, dyv, dgq, dgk, jnp.zeros_like(table)
 

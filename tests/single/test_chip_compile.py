@@ -812,8 +812,9 @@ def test_the_xing4_cells_grad_program_fits_the_described_v5e(v5e_chip,
     """The cell's grad program at [1, 8192] with the file's ``remat`` and
     ``loss_chunk`` compiles for the described v5e within the 15.75 GiB
     its programs get, Adam's two moments beside it, and holds the flash
-    pair and the hyper-connections' two pairs (``ops/hc_mix.py``) by name
-    and no other named Mosaic call (``moe_gmm_ms_per_step`` takes every
+    pair, latent attention's seam (``ops/mla_prep.py``) and the
+    hyper-connections' two pairs (``ops/hc_mix.py``) by name and no other
+    named Mosaic call (``moe_gmm_ms_per_step`` takes every
     call without ``hvd_flash`` in its name for megablox's). Five
     minutes of one core: not in tier-1 (CHANGES.md, PR 57); every run of
     the cell on the chip proves the fit again."""
@@ -838,7 +839,8 @@ def test_the_xing4_cells_grad_program_fits_the_described_v5e(v5e_chip,
                       for s in jax.tree.leaves(shapes))
     assert peak + moments < 15.75 * 2 ** 30
     named = set(re.findall(r'"kernel":"([a-z_0-9]+)"', compiled.as_text()))
-    assert named == {"hvd_flash_fwd", "hvd_flash_bwd_fused", *_HC_KERNELS}
+    assert named == {"hvd_flash_fwd", "hvd_flash_bwd_fused",
+                     "hvd_mla_prep_fwd", "hvd_mla_prep_bwd", *_HC_KERNELS}
 
 
 _CHAIN_KERNELS = ("hvd_gdn_chain_in_fwd", "hvd_gdn_chain_in_bwd",
@@ -1179,6 +1181,72 @@ def test_qk_prep_compiles_for_described_v5e(for_tpu, what, B, T, H, Hkv, d,
                    ((d,), BF16), ((d,), BF16))
     for name in ("hvd_qk_prep_fwd", "hvd_qk_prep_bwd"):
         assert f'"kernel":"{name}"' in text, (what, name)
+
+
+def _latent_seam(yq, ykv, k_r):
+    """Latent attention's seam at Xing4.0's widths, both directions (as
+    ``_seam``'s)."""
+    from horovod_tpu.ops.mla_prep import mla_prep
+
+    B, T = yq.shape[:2]
+    positions = jnp.broadcast_to(jnp.arange(T), (B, T))
+    freqs = 1e4 ** (-jnp.arange(0, 64, 2, dtype=F32) / 64)
+    return jax.grad(lambda *a: sum(
+        (x.astype(F32) ** 2).sum()
+        for x in mla_prep(*a, positions, freqs, 1.2, 128)), (0, 1, 2))(
+        yq, ykv, k_r)
+
+
+def test_mla_prep_compiles_for_described_v5e(v5e_chip, for_tpu):
+    """Latent attention's seam at the Xing4.0 cell's size (beside
+    ``flash-fwd+bwd-xing4-b1s8192-192-128``: 8192 tokens, 32 heads 128 +
+    64 beside 128 wide, so an odd head's slab starts in the middle of a
+    lane tile) as the chip's compiler takes it, each kernel by the name a
+    device trace shows and with the VMEM it asks for by itself: a step's
+    blocks twice over (a 192-wide row of ``q`` and ``k`` lies 256 wide
+    there) and 8 MiB for a group's values, under the chip's 128 MiB."""
+    from horovod_tpu.ops import mla_prep
+
+    lowered = jax.jit(_latent_seam).lower(*(
+        jax.ShapeDtypeStruct((1, 8192, n), BF16, sharding=v5e_chip)
+        for n in (32 * 192, 32 * 256, 64)))
+    asked = [int(n) for n in re.findall(
+        r'scoped_memory_configs[^}]*size\W+22\W+(\d+)', lowered.as_text())]
+    blocks = mla_prep.TOKENS_A_STEP * (
+        2 * (32 * (192 + 256 + 2 * 256 + 128) + 128) + 4 * 128)
+    assert asked == [2 * blocks + (8 << 20)] * 2 and asked[0] < 100 << 20
+    text = lowered.compile().as_text()
+    for name in ("hvd_mla_prep_fwd", "hvd_mla_prep_bwd"):
+        assert f'"kernel":"{name}"' in text, name
+
+
+def test_latent_layers_lower_the_seam_once_a_direction(v5e_chip, monkeypatch):
+    """Three latent-attention layers at 128 + 64 beside 128 under remat
+    "attn", lowered for the described chip: the seam's forward kernel
+    twice (the recomputation comes with a jaxpr of its own) and its
+    backward once WHATEVER the layers, beside the flash pair; and no
+    transpose makes a 192-wide ``[B, H, T, 192]`` (q, k) any more, none a
+    second ``[B, H, T, 128]`` (v): what is left is ``do``'s way in."""
+    from horovod_tpu.models import LlamaConfig, llama_init, llama_loss
+
+    monkeypatch.setattr(_platform, "operand_platform", lambda *a: "tpu")
+    cfg = LlamaConfig(vocab_size=512, d_model=256, n_layers=3, n_heads=2,
+                      n_kv_heads=2, d_ff=512, q_lora_rank=64,
+                      kv_lora_rank=64, qk_nope_head_dim=128,
+                      qk_rope_head_dim=64, v_head_dim=128, dtype="bfloat16",
+                      param_dtype="bfloat16", remat="attn")
+    tokens = jax.ShapeDtypeStruct((2, 2048), I32, sharding=v5e_chip)
+    params = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=v5e_chip),
+        jax.eval_shape(lambda k: llama_init(cfg, k), jax.random.PRNGKey(0)))
+    text = jax.jit(jax.grad(lambda p, d: llama_loss(p, d, cfg))).lower(
+        params, {"tokens": tokens, "targets": tokens}).as_text()
+    assert text.count("hvd_mla_prep_fwd") == 2
+    assert text.count("hvd_mla_prep_bwd") == 1
+    assert "hvd_flash_fwd" in text and "hvd_flash_bwd_fused" in text
+    made = set(re.findall(
+        r"stablehlo\.transpose.*-> tensor<2x2x2048x(\d+)x", text))
+    assert made == {"128"}, made
 
 
 def test_layers_on_the_seam_lower_two_kernels_and_transpose_no_operand(

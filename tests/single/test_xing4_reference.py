@@ -162,6 +162,38 @@ def test_loss_and_every_gradient_leaf_against_the_reference(the_reference,
     assert worst[0] <= GRAD_TOL, worst
 
 
+def test_the_seam_on_its_kernels_against_the_reference(monkeypatch):
+    """Heads 128 + 64 beside 128 wide, the widths at which latent
+    attention's seam runs as ``ops/mla_prep.py``'s kernel pair (in
+    interpret mode here), under the cell's remat mode: the logits, the
+    loss and every gradient leaf at the limits of the small model. The
+    forward traces two seams (the dense layer's and the expert stack's),
+    the step a third (the MTP module's)."""
+    from horovod_tpu.ops import mla_prep
+
+    calls, plain = [], mla_prep.mla_prep
+
+    def counted(yq, *rest):
+        calls.append(yq.shape)
+        return plain(yq, *rest)
+
+    monkeypatch.setattr(mla_prep, "_INTERPRET", True)
+    monkeypatch.setattr(mla_prep, "mla_prep", counted)
+    cfg = _cfg(n_heads=2, n_kv_heads=2, qk_nope_head_dim=128,
+               qk_rope_head_dim=64, v_head_dim=128, remat="attn")
+    params, batch = _params(cfg), _batch(cfg)
+    logits = jax.jit(lambda p: llama.llama_forward(p, batch["tokens"],
+                                                   cfg))(params)
+    want = jax.jit(lambda p: ref.xing4_forward(p, batch["tokens"],
+                                               cfg))(params)
+    assert calls == [(2, 16, 2 * 192)] * 2, calls
+    assert float(jnp.linalg.norm(logits - want)
+                 / jnp.linalg.norm(want)) <= GRAD_TOL
+    want, want_grads = _reference(cfg)(params, batch)
+    assert _off(cfg, params, batch, want, want_grads) <= 1
+    assert len(calls) == 2 + 3
+
+
 def test_the_two_terms(the_model):
     cfg, params, batch = the_model
     main, mtp = jax.jit(lambda p: ref.xing4_loss(p, batch, cfg,
