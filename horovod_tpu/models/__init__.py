@@ -8,6 +8,7 @@ for the sharding/ring-attention machinery in ``horovod_tpu.parallel``.
 
 from horovod_tpu.models.llama import (  # noqa: F401
     LlamaConfig,
+    llama_exit_terms,
     llama_expert_load,
     llama_forward,
     llama_init,

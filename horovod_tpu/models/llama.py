@@ -381,6 +381,19 @@ class LlamaConfig:
     hc_sinkhorn_iters: int = 0
     hc_eps: float = 0.0
     hc_clamp: tuple = ()
+    # A looped decoder (LoopLM: Ouro, arXiv 2510.25741 section 3;
+    # ``total_ut_steps``): the ONE stack of layers and the final norm
+    # run ``loop_steps`` times with the same weights, a trip's output
+    # the next trip's input (1: a plain decoder, no leaf and no
+    # instruction more). After EVERY trip an exit: the head's logits and
+    # a gate a token, ``sigmoid(h . exit_gate_w + exit_gate_b)``, the
+    # share of what has not left yet that leaves here; the last trip
+    # takes the rest. ``llama_loss`` is the expectation of the exits'
+    # cross-entropies under that distribution less
+    # ``exit_entropy_weight`` times its entropy (``_exit_loss``);
+    # ``llama_forward`` gives the last trip's logits.
+    loop_steps: int = 1
+    exit_entropy_weight: float = 0.0
 
     def __post_init__(self):
         if self.layer_types and len(self.layer_types) != self.n_layers:
@@ -496,6 +509,16 @@ class LlamaConfig:
                 f"{self.mtp_types} and mtp_weight {self.mtp_weight} come "
                 "together, for ONE module (modules that share weights "
                 "over depths are not implemented)")
+        if self.loop_steps < 1 or (self.loop_steps == 1
+                                   and self.exit_entropy_weight) or (
+                self.loop_steps > 1 and (self.n_experts
+                                         or self.mtp_layers)):
+            raise ValueError(
+                f"loop_steps {self.loop_steps} (exit_entropy_weight "
+                f"{self.exit_entropy_weight}): trips of ONE dense stack, "
+                "at least one; the entropy is of the exits of more than "
+                "one; expert layers' balance statistics a trip and the "
+                "MTP module's stream have no place in the exits' loss")
         if self.loss_chunk < 0:
             raise ValueError(f"loss_chunk {self.loss_chunk}: tokens a "
                              "block of the loss's head, 0 for whole "
@@ -752,7 +775,8 @@ class LlamaConfig:
                             "kv_lora_rank", "qk_nope_head_dim",
                             "qk_rope_head_dim", "v_head_dim", "rope_yarn",
                             "hc_mult", "hc_sinkhorn_iters", "hc_eps",
-                            "hc_clamp")
+                            "hc_clamp", "loop_steps",
+                            "exit_entropy_weight")
                 if getattr(self, f) != getattr(d, f)] \
             + (["qk_norm"] if self.qk_norm == "head" else [])
 
@@ -1080,6 +1104,11 @@ def llama_init(config, key):
     if not c.tie_embeddings:
         params["lm_head"] = dense(next(k), (c.d_model, c.vocab_size),
                                   c.d_model)
+    if c.loop_steps > 1:
+        # The exits' gate, a Linear(d_model, 1) with a bias.
+        params["exit_gate_w"] = dense(jax.random.fold_in(key, 65),
+                                      (c.d_model,), c.d_model)
+        params["exit_gate_b"] = jnp.zeros(1, pd)
     if c.mtp_layers:
         mkey = jax.random.fold_in(key, 64)
         params["mtp"] = {
@@ -1166,6 +1195,7 @@ def llama_partition_rules(pipeline=False):
         (r"layers/moe_(gate|up)", P(lead, "expert", "fsdp", "tensor")),
         (r"layers/moe_down", P(lead, "expert", "tensor", "fsdp")),
         (r"final_norm", P(None)),
+        (r"exit_gate", P(None)),
         (r"lm_head", P("fsdp", "tensor")),
     ]
 
@@ -2248,6 +2278,9 @@ def _llama_hidden(params, tokens, config, mesh=None, seq_axis="seq"):
     """tokens [B, T] -> (what the head reads, [B, T, D] after the final
     norm; the MoE load-balancing loss): ``llama_forward`` less the
     head, which ``llama_loss`` may run in blocks of tokens."""
+    if config.loop_steps > 1:   # the last trip's exit; no expert layers
+        return _llama_exits(params, tokens, config, mesh, seq_axis)[-1], \
+            jnp.zeros((), jnp.float32)
     x, aux = _llama_stream(params, tokens, config, mesh, seq_axis)
     return _final_norm(params, x, config), aux
 
@@ -2262,33 +2295,7 @@ def _llama_stream(params, tokens, config, mesh=None, seq_axis="seq"):
     the final norm; the MoE load-balancing loss)."""
     c = config
     b, t = tokens.shape
-    if _over_sequence(mesh, seq_axis):
-        whole = [f for f in ("one_part_layers", "mtp_layers")
-                 if getattr(c, f)]
-        if whole:
-            raise ValueError(
-                f"LlamaConfig fields {whole} run on no sequence-parallel "
-                "mesh axis (ring / ulysses) yet: a one-part layer's "
-                "recurrence and the MTP term's shift by a token see a "
-                "whole sequence")
-
-    def constrain(x):
-        return _constrain(x, mesh)
-
-    # Layout contract for the vocab lookup: tokens are pinned to the
-    # activation layout (batch over data/fsdp, seq over seq) so the SPMD
-    # partitioner picks INDEX-passthrough for the gather — each device
-    # all-gathers the (small) table shard and gathers its own token
-    # block, and the output is born in the activation layout. Without the
-    # pin it picks operand-passthrough (output sharded over the table's d
-    # axis) and then "involuntary full rematerialization" to reshard
-    # [B,T,D] into the batch/seq layout.
-    if mesh is not None:
-        tokens = lax.with_sharding_constraint(
-            tokens, jax.sharding.NamedSharding(mesh, P(("data", "fsdp"),
-                                                       "seq")))
-    x = _embed(params, tokens, c)
-    x = constrain(x)
+    x = _embedded(params, tokens, c, mesh, seq_axis)
 
     n_stages = mesh.shape.get("pipe", 1) if mesh is not None else 1
     if n_stages > 1:
@@ -2311,6 +2318,161 @@ def _llama_stream(params, tokens, config, mesh=None, seq_axis="seq"):
         aux = moe_balance_loss(balance)
 
     return x, aux
+
+
+def _embedded(params, tokens, c, mesh, seq_axis):
+    """tokens [B, T] -> their embeddings [B, T, D] in the activation
+    layout: what the first layer reads."""
+    if _over_sequence(mesh, seq_axis):
+        whole = [f for f in ("one_part_layers", "mtp_layers")
+                 if getattr(c, f)]
+        if whole:
+            raise ValueError(
+                f"LlamaConfig fields {whole} run on no sequence-parallel "
+                "mesh axis (ring / ulysses) yet: a one-part layer's "
+                "recurrence and the MTP term's shift by a token see a "
+                "whole sequence")
+
+    # Layout contract for the vocab lookup: tokens are pinned to the
+    # activation layout (batch over data/fsdp, seq over seq) so the SPMD
+    # partitioner picks INDEX-passthrough for the gather — each device
+    # all-gathers the (small) table shard and gathers its own token
+    # block, and the output is born in the activation layout. Without the
+    # pin it picks operand-passthrough (output sharded over the table's d
+    # axis) and then "involuntary full rematerialization" to reshard
+    # [B,T,D] into the batch/seq layout.
+    if mesh is not None:
+        tokens = lax.with_sharding_constraint(
+            tokens, jax.sharding.NamedSharding(mesh, P(("data", "fsdp"),
+                                                       "seq")))
+    return _constrain(_embed(params, tokens, c), mesh)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _for_each_trip(shared, trips):
+    """The leaves every trip shares, once a trip. Forward: the same
+    arrays ``trips`` times. Backward: a shared leaf's gradient is the
+    SUM of its visits', taken here in float32 and rounded once, under
+    ``hvd.loop`` (left to autodiff it is an ``add_any`` in the leaves'
+    dtype under no scope of ours)."""
+    return (shared,) * trips
+
+
+def _for_each_trip_fwd(shared, trips):
+    return (shared,) * trips, None
+
+
+def _for_each_trip_bwd(trips, _, visits):
+    with scope("hvd.loop"):
+        return (jax.tree.map(
+            lambda *g: sum(x.astype(jnp.float32) for x in g).astype(
+                g[0].dtype), *visits),)
+
+
+_for_each_trip.defvjp(_for_each_trip_fwd, _for_each_trip_bwd)
+
+
+def _llama_exits(params, tokens, c, mesh, seq_axis):
+    """tokens [B, T] -> what the ``loop_steps`` exits read, [R, B, T,
+    D]: a trip is the stack of layers and then the final norm, on the
+    weights every trip shares; its output is what that trip's exit reads
+    AND what the next trip starts from (the first: the embeddings).
+
+    The trips are a Python loop, each a ``_run_layers`` of its own (for
+    a uniform dense stack ONE ``lax.scan`` of the layer body): the
+    backward pass then holds one stacked gradient a trip and
+    ``_for_each_trip`` sums them in one pass. As a ``lax.scan`` over
+    trips with the stack closed over, the transposed scan adds a whole
+    stacked gradient a trip in the leaves' dtype and copies every
+    visit's saved activations into and out of a buffer a trip: 12 layers
+    of 2048 x 5632 at 2 x 4096 tokens, four trips, remat ``attn``, on
+    one v5e chip 1,306.9 ms a step and 6.84 GB of temporaries against
+    1,272.6 ms and 5.40 GB for this form (PERF.md section 6, PR 64)."""
+    n_stages = mesh.shape.get("pipe", 1) if mesh is not None else 1
+    if n_stages > 1:    # refuses ``loop_steps`` by name
+        _validate_pipeline(c, tokens.shape[0], mesh, seq_axis, n_stages)
+    shared = {k: params[k] for k in {spec.stack for spec in c.layer_plan()}
+              | {"final_norm"}}
+    x = _embedded(params, tokens, c, mesh, seq_axis)
+    exits = []
+    for mine in _for_each_trip(shared, c.loop_steps):
+        x, _ = _run_layers(mine, x, c, mesh, seq_axis)
+        with scope("hvd.loop"):
+            x = _constrain(_rms(
+                x, mine["final_norm"].astype(c.compute_dtype), c.norm_eps),
+                mesh)
+        exits.append(x)
+    return jnp.stack(exits)
+
+
+def _exit_log_probs(s):
+    """The exits' gate logits ``s`` [R, ...] float32 -> the log of the
+    exit distribution [R, ...]: ``log p_t = log sigmoid(s_t) + sum_{j<t}
+    log sigmoid(-s_j)``, the last exit's what is left, ``sum_{j<R} log
+    sigmoid(-s_j)``."""
+    stays = jnp.cumsum(jax.nn.log_sigmoid(-s[:-1]), 0)
+    return jnp.concatenate(
+        [jax.nn.log_sigmoid(s[:1]),
+         jax.nn.log_sigmoid(s[1:-1]) + stays[:-1], stays[-1:]], 0)
+
+
+def _exit_terms(params, exits, batch, c):
+    """What the looped decoder's loss is made of, a token and exit, from
+    what the exits read, ``exits`` [R, B, T, D]: the cross-entropy of
+    each exit's logits through the ONE head, [R, B, T], and the log of
+    the exit distribution, [R, B, T], in float32 from the gates
+    ``lambda_t = sigmoid(h_t . w + b)``: ``p_t = lambda_t prod_{j<t} (1
+    - lambda_j)``, the last exit taking what is left (``lambda_R``
+    enters nothing).
+
+    The head reads the R exits as R x B sequences: one pass of
+    ``_head_nll``, whose blocks (``loss_chunk``) add the head's gradient
+    into one accumulator."""
+    R, b, t, d = exits.shape
+    nll = _head_nll(params, exits.reshape(R * b, t, d),
+                    jnp.tile(batch["targets"], (R, 1)), c).reshape(R, b, t)
+    with scope("hvd.exit"):
+        return nll, _exit_log_probs(
+            jnp.einsum("rbtd,d->rbt", exits,
+                       params["exit_gate_w"].astype(c.compute_dtype),
+                       preferred_element_type=jnp.float32)
+            + params["exit_gate_b"].astype(jnp.float32))
+
+
+def _masked_mean(x, mask):
+    """The mean of ``x`` [..., B, T] over the tokens ``mask`` [B, T]
+    keeps (None: all)."""
+    if mask is None:
+        return jnp.mean(x, (-2, -1))
+    mask = mask.astype(jnp.float32)
+    return jnp.sum(x * mask, (-2, -1)) / jnp.maximum(jnp.sum(mask), 1.0)
+
+
+def _exit_loss(params, exits, batch, c):
+    """The looped decoder's loss (Ouro's stage-I objective, joint:
+    nothing is detached): a token's R cross-entropies in expectation
+    under its exit distribution, less ``exit_entropy_weight`` times that
+    distribution's entropy (:func:`_exit_terms`); the (masked) mean over
+    tokens."""
+    nll, log_p = _exit_terms(params, exits, batch, c)
+    with scope("hvd.exit"):
+        per_token = jnp.sum(jnp.exp(log_p) * (
+            nll + c.exit_entropy_weight * log_p), 0)
+    with scope("hvd.loss"):
+        return _masked_mean(per_token, batch.get("mask"))
+
+
+def llama_exit_terms(params, batch, config, mesh=None, seq_axis="seq"):
+    """What a looped decoder's ``llama_loss`` is made of on ``batch``,
+    each the (masked) mean over tokens: (the cross-entropy of every exit
+    [R], the exit distribution [R], its entropy). The loss is ``sum(p x
+    cross-entropy)`` a TOKEN before the mean, so these do not add up to
+    it; they are what a training run watches beside it."""
+    nll, log_p = _exit_terms(params, _llama_exits(
+        params, batch["tokens"], config, mesh, seq_axis), batch, config)
+    p, mask = jnp.exp(log_p), batch.get("mask")
+    return (_masked_mean(nll, mask), _masked_mean(p, mask),
+            _masked_mean(-jnp.sum(p * log_p, 0), mask))
 
 
 def llama_expert_load(params, tokens, config):
@@ -2462,7 +2624,7 @@ def _validate_pipeline(c, b, mesh, seq_axis, n_stages):
     plan = c.layer_plan()
     unscheduled = [f for f in ("one_part_layers", "ffn_act", "moe_latent",
                                "shared_d_ff", "mtp_layers", "kv_lora_rank",
-                               "hc_mult")
+                               "hc_mult", "loop_steps")
                    if f in c.training_only_fields()]
     if unscheduled:
         raise ValueError(
@@ -2471,7 +2633,9 @@ def _validate_pipeline(c, b, mesh, seq_axis, n_stages):
             "SwiGLU FFN over params['layers'], the last stage's loss "
             "has one term, and what crosses a stage boundary is ONE "
             "stream [B, T, D] (hyper-connections carry hc_mult; latent "
-            "attention's leaves have no stage layout)")
+            "attention's leaves have no stage layout), ONCE (a looped "
+            "stack wants a circular schedule: a micro-batch round every "
+            "stage loop_steps times)")
     if c.post_norm == "only" or c.linear_beta_max != 1.0:
         raise ValueError(
             "post_norm 'only' and linear_beta_max have no pipeline "
@@ -2810,6 +2974,9 @@ def llama_loss(params, batch, config, mesh=None, seq_axis="seq"):
         raise ValueError(
             f"unknown pipeline_schedule {config.pipeline_schedule!r}: "
             "expected 'gpipe', '1f1b', or 'interleaved_1f1b'")
+    if config.loop_steps > 1:
+        return _exit_loss(params, _llama_exits(
+            params, batch["tokens"], config, mesh, seq_axis), batch, config)
     stream, aux = _llama_stream(params, batch["tokens"], config, mesh,
                                 seq_axis)
     nll = _head_nll(params, _final_norm(params, stream, config),

@@ -1554,3 +1554,122 @@ def olmohybrid_loss(params, batch, cfg, vocab_rows=None):
                                -1)[..., 0]
     mask = batch.get("mask", jnp.ones_like(nll))
     return jnp.sum(nll * mask) / jnp.sum(mask)
+
+
+# ---------------------------------------------------------------------
+# Ouro-2.6B (ByteDance, ``model_type`` ``ouro``; "Scaling Latent
+# Reasoning via Looped Language Models", arXiv:2510.25741, section 3;
+# ``modeling_ouro.py`` beside the published ``config.json``): a LoopLM.
+# ONE stack of ``L`` layers, run ``R = total_ut_steps`` times with the
+# same weights; no bias anywhere, eps ``rms_norm_eps``, ``N(.; g)`` an
+# RMSNorm with gain ``g``.
+#
+# - ``h^(0) = E[x]``;
+# - a trip ``t = 1..R``: ``u_0 = h^(t-1)``; for each layer ``l``, four
+#   norms: ``a = u + N(Attn_l(N(u; g1_l)); g2_l)``, ``u' = a +
+#   N(SwiGLU_l(N(a; g3_l)); g4_l)`` (the program's leaves ``attn_norm``,
+#   ``post_attn_norm``, ``mlp_norm``, ``post_mlp_norm``); then ``h^(t) =
+#   N(u_L; g_f)``: the final norm closes EVERY trip, and its output is
+#   both what the trip's exit reads and what the next trip starts from;
+# - ``Attn``: ``q, k, v = z W_q, z W_k, z W_v``, as many key/value heads
+#   as query heads, half-split RoPE over the whole head (the Llama
+#   pairing), causal ``softmax(q k / sqrt(head_dim)) v``, ``W_o``;
+# - an exit a trip: logits ``z^(t) = h^(t) W_head`` (ONE head, untied);
+#   gate ``s_t = h^(t) . w_g + b_g``, ``lambda_t = sigmoid(s_t)``, a
+#   number a token;
+# - the exit distribution a token: ``p_t = lambda_t prod_{j<t} (1 -
+#   lambda_j)`` for ``t < R``, ``p_R = prod_{j<R} (1 - lambda_j)``: the
+#   last trip takes what is left, ``lambda_R`` enters nothing;
+# - the loss (the paper's stage-I objective, joint: nothing detached):
+#   ``mean_i [ sum_t p_{t,i} CE(z_i^(t), y_i) - beta H(p_{.,i}) ]``,
+#   ``H(p) = - sum_t p_t log p_t``, ``beta`` the program's
+#   ``exit_entropy_weight``.
+#
+# What the published ``config.json`` has no key for (the four norms and
+# their order, the norm inside the loop, the gate's form and start,
+# ``beta``) is listed with its source under ``assumed`` in
+# ``chipbench/configs/ouro-2.6b.json``; if the published
+# ``modeling_ouro.py`` or the paper departs from a line above, the
+# published form wins. Departures known: ``early_exit_threshold`` (1:
+# every trip is run) is an inference setting and is not read; the
+# paper's later stages lower ``beta`` and its stage II trains the gate
+# alone against a detached improvement signal: not here.
+#
+# Trips and layers are Python loops; nothing of ``models/llama.py``'s
+# scan, blocks or kernels. ``trip_layers``: a stack of layers a TRIP
+# (``R`` copies of the shared stack, each visit reading its own), so
+# that ``jax.grad`` gives the gradient of each visit apart: their sum is
+# what a shared leaf's gradient has to be.
+# ---------------------------------------------------------------------
+
+def ouro_forward(params, tokens, cfg, trip_layers=None):
+    """tokens [B, T] -> (logits [R, B, T, vocab], gate logits [R, B, T]),
+    float32, an entry an exit. ``params`` is the program's tree, any
+    storage dtype."""
+    hd = cfg.head_dim
+    b, t = tokens.shape
+
+    def rope(x):
+        return _half_split_rope(x, cfg.rope_theta)
+
+    mask = jnp.arange(t)[None, :] <= jnp.arange(t)[:, None]
+    p = jax.tree.map(lambda w: w.astype(F32), params)
+    logits, gates = [], []
+    with jax.default_matmul_precision("highest"):
+        h = p["embed"][tokens]
+        for trip in range(cfg.loop_steps):
+            stack = p["layers"] if trip_layers is None else jax.tree.map(
+                lambda w: w.astype(F32), trip_layers[trip])
+            for l in range(cfg.n_layers):
+                lp = jax.tree.map(lambda w: w[l], stack)
+                z = _rms(h, lp["attn_norm"], cfg.norm_eps)
+                q = rope((z @ lp["wq"]).reshape(b, t, cfg.n_heads, hd))
+                k = rope((z @ lp["wk"]).reshape(b, t, cfg.n_heads, hd))
+                v = (z @ lp["wv"]).reshape(b, t, cfg.n_heads, hd)
+                s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / (hd ** 0.5)
+                att = jax.nn.softmax(jnp.where(mask, s, -jnp.inf), -1)
+                mixed = jnp.einsum("bhqk,bkhd->bqhd", att, v).reshape(
+                    b, t, -1) @ lp["wo"]
+                h = h + _rms(mixed, lp["post_attn_norm"], cfg.norm_eps)
+                z = _rms(h, lp["mlp_norm"], cfg.norm_eps)
+                h = h + _rms(_swiglu(z, lp["w_gate"], lp["w_up"],
+                                     lp["w_down"]),
+                             lp["post_mlp_norm"], cfg.norm_eps)
+            h = _rms(h, p["final_norm"], cfg.norm_eps)
+            logits.append(h @ p["lm_head"])
+            gates.append(h @ p["exit_gate_w"] + p["exit_gate_b"][0])
+    return jnp.stack(logits), jnp.stack(gates)
+
+
+def ouro_exit_distribution(gates):
+    """Gate logits [R, ...] -> the exit distribution [R, ...]: ``p_t =
+    sigmoid(s_t) prod_{j<t} sigmoid(-s_j)``, the last exit what is
+    left."""
+    lam = jax.nn.sigmoid(gates)
+    left, p = jnp.ones_like(lam[0]), []
+    for t in range(gates.shape[0] - 1):
+        p.append(lam[t] * left)
+        left = left * (1.0 - lam[t])
+    return jnp.stack(p + [left])
+
+
+def ouro_loss(params, batch, cfg, trip_layers=None, terms=False):
+    """The expected exit loss less ``cfg.exit_entropy_weight`` times the
+    exit distribution's entropy, the mean over the positions
+    ``batch["mask"]`` keeps (all without one). ``terms``: also its
+    parts, (the mean cross-entropy of each exit [R], the mean exit
+    distribution [R], the mean entropy). ``jax.grad`` of this is the
+    reference gradient."""
+    logits, gates = ouro_forward(params, batch["tokens"], cfg, trip_layers)
+    targets = jnp.broadcast_to(batch["targets"], gates.shape)
+    nll = -jnp.take_along_axis(jax.nn.log_softmax(logits, -1),
+                               targets[..., None], -1)[..., 0]
+    p = ouro_exit_distribution(gates)
+    entropy = -jnp.sum(p * jnp.log(p), 0)
+    mask = batch.get("mask", jnp.ones_like(entropy))
+
+    def mean(x):
+        return jnp.sum(x * mask, (-2, -1)) / jnp.sum(mask)
+
+    loss = mean(jnp.sum(p * nll, 0) - cfg.exit_entropy_weight * entropy)
+    return (loss, (mean(nll), mean(p), mean(entropy))) if terms else loss

@@ -41,7 +41,8 @@ SCOPES = frozenset({
     "hvd.sparse.select", "hvd.sparse.core", "hvd.lightning.chain",
     "hvd.lightning.core", "hvd.mla.proj", "hvd.mla.core", "hvd.hc.mix",
     "hvd.ffn", "hvd.moe.route", "hvd.moe.dispatch", "hvd.moe.experts",
-    "hvd.moe.combine", "hvd.moe.latent", "hvd.mtp", "hvd.head", "hvd.loss", "hvd.apply",
+    "hvd.moe.combine", "hvd.moe.latent", "hvd.mtp", "hvd.loop", "hvd.exit",
+    "hvd.head", "hvd.loss", "hvd.apply",
     "hvd.allreduce", "hvd.cnn.stem", "hvd.cnn.stage1", "hvd.cnn.stage2",
     "hvd.cnn.stage3", "hvd.cnn.stage4", "hvd.cnn.head"})
 

@@ -885,6 +885,52 @@ def test_the_olmohybrid_cells_grad_program_fits_the_described_v5e(
                      "hvd_gdn_rule_fwd", "hvd_gdn_rule_bwd",
                      *_CHAIN_KERNELS}
     assert "f32[2,8192,11520]" not in text
+def test_the_ouro_cells_grad_program_fits_the_described_v5e(v5e_chip,
+                                                            for_tpu):
+    """The looped cell's grad program at [2, 4096], four trips of twelve
+    layers under the file's ``remat`` and ``loss_chunk``, lowers with the
+    four exits side by side and compiles for the described v5e: the
+    flash pair and the q/k seam's pair by name, the sum of the shared leaves' gradients over
+    the trips under ``hvd.loop`` reading all four at once, and its
+    temporaries under 9.5 GB (PR 64 read 9,162,386,432 B, a peak of
+    7.99 GB with the parameters and the gradients it hands back, beside
+    2.67 GB of moments and a second set of gradients: the step runs one
+    ahead). A form of the loop that kept more alive (the trips as a
+    ``lax.scan``: 9.76 GB of temporaries, a peak of 9.44) fails the
+    bound. A minute of one core: the one whole-program compile of
+    tier-1; every run of the cell on the chip proves the fit again."""
+    sys.path.insert(0, REPO)
+    from chipbench import child
+
+    _, _, config, traffic = child.find_cell("ouro.spmd.b2s4096")
+    model = child.load_file("models", "ouro").Model(config, traffic)
+    shapes = jax.eval_shape(lambda k: model.init(k)[0],
+                            jax.random.PRNGKey(0))
+    params = jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+        s.shape, s.dtype, sharding=v5e_chip), shapes)
+    batch = {k: jax.ShapeDtypeStruct((2, 4096), I32, sharding=v5e_chip)
+             for k in ("tokens", "targets")}
+    lowered = jax.jit(
+        lambda p, d: jax.value_and_grad(
+            lambda p, d: model.loss(p, (), d)[0])(p, d),
+        compiler_options=model.compiler_options).lower(params, batch)
+    assert model.check_lowering(lowered.as_text(), True) is None
+    compiled = lowered.compile()
+    memory = compiled.memory_analysis()
+    state = sum(s.size * s.dtype.itemsize for s in jax.tree.leaves(shapes))
+    assert state == 2 * 666_996_737
+    assert memory.temp_size_in_bytes < 9.5e9
+    assert memory.peak_memory_in_bytes + 3 * state < 15.75 * 2 ** 30
+    text = compiled.as_text()
+    assert set(re.findall(r'"kernel":"([a-z_0-9]+)"', text)) == {
+        "hvd_flash_fwd", "hvd_flash_bwd_fused", "hvd_qk_prep_fwd",
+        "hvd_qk_prep_bwd"}
+    sums = [line for line in text.splitlines()
+            if " = bf16[12,2048,5632]" in line and "fusion(" in line
+            and "hvd.loop" in line]
+    assert sums and all(line.count("%while") == 4 for line in sums), sums
+
+
 # Qwen3-Next's linear mixer at the chip cell's size: B2 T8192, 16 key
 # heads serving 32 value heads, all 128 wide, four taps.
 _CHAIN = (((2, 8192, 96 * 128), BF16), ((4, 64 * 128), BF16),

@@ -109,6 +109,13 @@ ROWS = {
         text=("8161ae6d2fb7892f", 1018007)),
     "lfm2": dict(cfg=LlamaConfig(**_LFM2),
                  text=("20551dd593a4c1e1", 1246731)),
+    # PR 64's own row: four trips of ONE stack of four-norm layers under
+    # the cell's remat mode and a blocked head, read at PR 64.
+    "ouro": dict(
+        cfg=LlamaConfig.tiny(n_kv_heads=4, post_norm=True, norm_eps=1e-6,
+                             loop_steps=4, exit_entropy_weight=0.1,
+                             loss_chunk=16, remat="attn"),
+        text=("67ffbf4aaaefdde5", 423326)),
 }
 
 
@@ -166,10 +173,14 @@ def _text(which, built):
     assert not set(cfg.training_only_fields()) & {
         "linear_key_heads", "linear_value_heads", "linear_key_dim",
         "linear_value_dim", "partial_rotary", "shared_expert_gate"}
+    assert which == "ouro" or not set(cfg.training_only_fields()) & {
+        "loop_steps", "exit_entropy_weight"}
     params = jax.eval_shape(lambda k: llama_init(cfg, k),
                             jax.random.PRNGKey(0))
     assert not [k for stack in params.values() if isinstance(stack, dict)
                 for k in stack if k.startswith(("gdn_", "shared_score"))]
+    assert which == "ouro" or not [k for k in params
+                                   if k.startswith("exit_gate")]
     tokens = jax.ShapeDtypeStruct((2, 16), jnp.int32)
     text = jax.jit(jax.value_and_grad(lambda p, t: llama_loss(
         p, {"tokens": t, "targets": t}, cfg))).lower(params,
